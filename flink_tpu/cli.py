@@ -909,4 +909,8 @@ def main(argv: Optional[list[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    # the process entry, not main(): tests call main() in-process and must
+    # not switch their interpreter's compile cache on
+    from .utils.compile_cache import place_compile_cache
+    place_compile_cache()
     sys.exit(main())

@@ -2,9 +2,15 @@
 
 See native.cpp for what lives here and why (the reference's FRocksDB /
 lz4-JNI / Unsafe analog layer). The .so is compiled on first import with
-g++ -O3 (cached next to the source, rebuilt when the source is newer) and
-loaded via ctypes; every function has a numpy/zlib fallback so the package
-works without a toolchain.
+g++ -O3 and loaded via ctypes; every function has a numpy/zlib fallback so
+the package works without a toolchain.
+
+The artifact is named by a hash of native.cpp plus the compile command
+(``_native-<hash>.so``) and built whenever that exact file is absent: a
+checkout copied to another machine keeps neither mtimes nor this machine's
+CPU, so nothing is reused on the strength of a timestamp, nothing is
+compiled ``-march=native``, and any other ``_native*.so`` lying in the
+directory is ignored.
 
 Public surface:
     NATIVE_AVAILABLE          -- True when the C++ library loaded
@@ -18,6 +24,7 @@ Public surface:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -33,23 +40,35 @@ __all__ = [
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cpp")
-_SO = os.path.join(_HERE, "_native.so")
+_BUILD_CMD = ("g++", "-O3", "-shared", "-fPIC")
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> bool:
+def _artifact_path() -> str:
+    digest = hashlib.sha256(" ".join(_BUILD_CMD).encode())
+    with open(_SRC, "rb") as f:
+        digest.update(f.read())
+    return os.path.join(_HERE, f"_native-{digest.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> bool:
+    # compile to a private name and rename: a concurrent importer (tests
+    # and workers start several processes at once) never loads a
+    # half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        cmd = ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-               "-o", _SO, _SRC]
-        r = subprocess.run(cmd, capture_output=True, timeout=120)
+        r = subprocess.run([*_BUILD_CMD, "-o", tmp, _SRC],
+                           capture_output=True, timeout=120)
         if r.returncode != 0:
-            # -march=native can be unsupported in sandboxes; retry plain
-            cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", _SO, _SRC]
-            r = subprocess.run(cmd, capture_output=True, timeout=120)
-        return r.returncode == 0
+            return False
+        os.replace(tmp, so)
+        return True
     except (OSError, subprocess.TimeoutExpired):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -58,11 +77,10 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None:
             return _lib
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                if not _build():
-                    return None
-            lib = ctypes.CDLL(_SO)
+            so = _artifact_path()
+            if not os.path.exists(so) and not _build(so):
+                return None
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         # signatures

@@ -116,11 +116,17 @@ def plan_exchange(dest: jax.Array, valid: jax.Array, n_dev: int,
     """
     B = dest.shape[0]
     d = jnp.where(valid, dest, jnp.int32(n_dev))
-    order = jnp.argsort(d, stable=True)
+    # Every reduction names int32: under x64 (which the state path turns
+    # on) argsort/sum/cumsum widen to int64, and the caller's pmax of
+    # n_rounds would then be an s64 max all-reduce, which the TPU refuses
+    # ("Supported lowering only of Sum all reduce").
+    order = jnp.argsort(d, stable=True).astype(jnp.int32)
     sd = d[order]
-    counts = jnp.sum(jax.nn.one_hot(d, n_dev + 1, dtype=jnp.int32), axis=0)
+    counts = jnp.sum(jax.nn.one_hot(d, n_dev + 1, dtype=jnp.int32), axis=0,
+                     dtype=jnp.int32)
     offsets = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1]])
+        [jnp.zeros(1, jnp.int32),
+         jnp.cumsum(counts, dtype=jnp.int32)[:-1]])
     rank = jnp.arange(B, dtype=jnp.int32) - offsets[sd]
     deepest = jnp.max(counts[:n_dev])
     n_rounds = (deepest + jnp.int32(cap - 1)) // jnp.int32(cap)

@@ -31,8 +31,9 @@ import numpy as np
 from .mesh import DATA_AXIS, make_mesh, shard_ranges
 
 __all__ = ["AxisRule", "DEFAULT_AXIS_RULES", "DECLARED_AXES",
-           "parse_axis_rules", "match_partition_rules", "shard_map_compat",
-           "local_shape", "ShardingPlan", "MeshRuntime", "MESH_RUNTIME"]
+           "parse_axis_rules", "match_partition_rules",
+           "shard_map_unchecked", "local_shape", "ShardingPlan",
+           "MeshRuntime", "MESH_RUNTIME"]
 
 # Every mesh axis a collective may legally name. The Tier-A lint rule
 # TPU102 (analysis/ast_rules.py) resolves collective axis arguments against
@@ -121,24 +122,13 @@ def match_partition_rules(rules: Sequence[AxisRule], tree: Any):
         treedef, [spec_for(_path_str(p)) for p, _ in flat])
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`shard_map` across the jax versions this repo targets: newer
-    releases expose ``jax.shard_map`` with ``check_vma``; 0.4.x has only
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Both
-    checks are disabled — the step emits a psum'd replicated scalar next
-    to sharded state, which the static replication checker rejects."""
+def shard_map_unchecked(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: the step emits a
+    psum'd replicated scalar next to sharded state, which the static
+    checker rejects."""
     import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # jax with jax.shard_map but pre-check_vma
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as esm
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def local_shape(global_shape: Sequence[int], spec, axis_sizes: dict
@@ -233,7 +223,7 @@ class ShardingPlan:
             lambda x, s: jax.device_put(x, self.sharding(s)), tree, specs)
 
     def shard_map(self, f, in_specs, out_specs):
-        return shard_map_compat(f, self.mesh, in_specs, out_specs)
+        return shard_map_unchecked(f, self.mesh, in_specs, out_specs)
 
     def ranges(self, max_parallelism: int, base=None):
         """Contiguous key-group range per mesh position (see

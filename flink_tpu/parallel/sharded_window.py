@@ -56,7 +56,8 @@ from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
 from .exchange import bucket_capacity, exchange_round, plan_exchange
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
     key_groups_device, shard_ranges
-from .plan import ShardingPlan, match_partition_rules, shard_map_compat
+from .plan import ShardingPlan, match_partition_rules, \
+    shard_map_unchecked
 
 __all__ = ["AggDef", "ShardedWindowState", "ShardedWindowAgg",
            "global_topk", "local_signature"]
@@ -124,7 +125,7 @@ def _step_program(sig, max_parallelism: int, axis_name: str,
     """The sharded fold step. The returned dispatcher takes the concrete
     Mesh as its first argument and binds the shard_map program per mesh
     inside this one cache entry: the cache key stays local-shape-only
-    while the executable still closes over the mesh jax 0.4.x requires."""
+    while the executable closes over the mesh shard_map needs."""
     _, agg_sig, cap, ring = sig
     aggs = _aggs_from_sig(agg_sig)
     MP = max_parallelism
@@ -197,7 +198,7 @@ def _step_program(sig, max_parallelism: int, axis_name: str,
                 "panes": 0, "valid": 0}
         sp = match_partition_rules(rules, skel)
         state_specs = (sp["table"], sp["accs"], sp["dropped"])
-        mapped = shard_map_compat(
+        mapped = shard_map_unchecked(
             shard_body, mesh,
             in_specs=state_specs + (sp["keys"], sp["cols"], sp["panes"],
                                     sp["valid"], P(), P()),

@@ -25,7 +25,6 @@ Sites (see docs/ROBUSTNESS.md for where each is threaded):
     rpc.heartbeat     drop-style: a worker heartbeat frame is lost
     rpc.send          a worker<->coordinator control frame send
     sink.invoke       delivering a batch to a sink function/writer
-    bench.probe       the bench backend-availability probe
     net.connect       establishing (or re-establishing) a data-plane
                       TCP connection — a trip is one failed attempt,
                       absorbed by the reconnect loop's deadline
@@ -100,7 +99,6 @@ FAULT_SITES = (
     "checkpoint.corrupt", "checkpoint.truncate",
     "rpc.heartbeat", "rpc.send", "sink.invoke",
     "tier.evict", "tier.prefetch",
-    "bench.probe",
     "net.connect", "net.sever", "net.delay", "net.zombie",
     "sched.admit", "sched.shed",
     "coord.crash", "ha.lease",

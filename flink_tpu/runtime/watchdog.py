@@ -12,8 +12,8 @@ Two mechanisms:
 
 * **Deadline-bounded calls** (``WATCHDOG.run`` / ``stall_bounded``):
   every blocking site — ``device.compile``, ``device.execute``,
-  ``transfer.h2d/d2h``, ``checkpoint.write/load``, ``rpc.send``,
-  ``bench.probe`` — runs on a supervised worker thread with a per-site
+  ``transfer.h2d/d2h``, ``checkpoint.write/load``, ``rpc.send`` —
+  runs on a supervised worker thread with a per-site
   configurable deadline (``watchdog.*`` config keys). Expiry abandons
   the worker and raises a typed :class:`StallError` to the caller, which
   feeds the PR-2 degradation ladder: a stall is transient (backoff-
@@ -117,7 +117,6 @@ class Watchdog:
         "rpc.send": "RPC_TIMEOUT",
         "tier.evict": "TIER_TIMEOUT",
         "tier.prefetch": "TIER_TIMEOUT",
-        "bench.probe": "PROBE_TIMEOUT",
         "aot.warmup": "AOT_WARMUP_TIMEOUT",  # lint: key-ok watchdog site label, not a config key
     }
 

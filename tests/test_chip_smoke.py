@@ -1,0 +1,88 @@
+"""chip_smoke.py's legs at tiny size on the CPU: the same functions the
+chip run calls, numpy-reference comparison included (the Pallas leg in
+interpret mode), and the refusal to run any leg without a TPU."""
+
+import numpy as np
+import pytest
+
+import chip_smoke
+
+TINY = dict(n_keys=1000, batch=1 << 10, n_events=1 << 13, seed=3)
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return chip_smoke.q5_reference(TINY["n_keys"], TINY["n_events"],
+                                   TINY["batch"], TINY["seed"])
+
+
+@pytest.fixture(scope="module")
+def host_leg(reference):
+    return chip_smoke.leg_q5_single(
+        "q5-tiny-host", capacity=1 << 12, device=False, topk=50,
+        reference=reference, **TINY)
+
+
+def test_reference_counts_every_event_w_times(reference):
+    # each event lands in exactly W sliding windows
+    total = sum(int(bids.sum()) for bids, _rev in reference.values())
+    assert total == chip_smoke.WINDOW_PANES * TINY["n_events"]
+
+
+def test_device_born_leg_matches_reference(reference):
+    report, rows = chip_smoke.leg_q5_single(
+        "q5-tiny-device", capacity=1 << 12, device=True, topk=50,
+        reference=reference, **TINY)
+    assert report["h2d_bytes"] == 0 and report["device_born"]
+    assert report["windows"] == len(reference)
+    assert report["rows"] == len(rows["auction"]) == 50 * len(reference)
+
+
+def test_host_born_leg_matches_reference(host_leg, reference):
+    report, _rows = host_leg
+    assert report["h2d_bytes"] > 0
+    assert report["windows"] == len(reference)
+    assert all(report[k] == 0 for k in chip_smoke.FALLBACK_COUNTERS)
+
+
+def test_mesh_leg_equals_single_chip_on_distinct_devices(host_leg,
+                                                         reference):
+    import jax
+    _report, host_rows = host_leg
+    report, _rows = chip_smoke.leg_q5_mesh(
+        capacity_per_device=1 << 12, topk=50, reference=reference,
+        single_chip_rows=host_rows, **TINY)
+    assert report["equals_single_chip"]
+    assert len(set(report["state_device_ids"])) == len(jax.devices()) > 1
+
+
+def test_check_rows_rejects_a_wrong_answer(host_leg, reference):
+    _report, rows = host_leg
+    wrong = dict(rows, bids=rows["bids"] + (np.arange(len(rows["bids"]))
+                                            == 0))
+    with pytest.raises(AssertionError):
+        chip_smoke.check_rows(wrong, reference, 50)
+    # dropping a key that is strictly above the threshold is caught too
+    end = rows["window_end"][0]
+    top = np.argmax(np.where(rows["window_end"] == end, rows["bids"], -1))
+    keep = np.arange(len(rows["bids"])) != top
+    with pytest.raises(AssertionError):
+        chip_smoke.check_rows({k: v[keep] for k, v in rows.items()},
+                              reference, 50)
+
+
+def test_pallas_leg_in_interpret_mode():
+    report = chip_smoke.leg_pallas_topk(sizes=(1 << 12, 5000), k=100,
+                                        interpret=True)
+    assert report["interpret"] is True and report["x64"] is True
+
+
+def test_main_refuses_to_run_without_a_tpu(monkeypatch, capsys):
+    def no_leg(*_a, **_kw):
+        raise AssertionError("a leg ran on a CPU backend")
+
+    for name in ("leg_q5_single", "leg_q5_mesh", "leg_pallas_topk",
+                 "q5_reference"):
+        monkeypatch.setattr(chip_smoke, name, no_leg)
+    assert chip_smoke.main([]) != 0
+    assert capsys.readouterr().out == ""

@@ -314,16 +314,3 @@ def test_mesh_inc_programs_match_full_merge():
     # the incremental fire consumes the view in both emit shapes
     agg.fire_inc(state, view, None, None)
     agg.fire_inc(state, view, "s", 4)
-
-
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="jax.shard_map unavailable (mesh runtime "
-                           "untestable on this jax)")
-def test_mesh_runtime_equivalence():
-    """End-to-end mesh job equivalence between fire modes (requires the
-    shard_map-backed mesh runtime)."""
-    from flink_tpu.parallel.sharded_window import ShardedWindowAgg
-
-    agg_full = ShardedWindowAgg(
-        [("s", "sum", jnp.int64)], capacity=64, ring=8, n_dev=1)
-    assert agg_full is not None

@@ -151,3 +151,32 @@ def test_compressed_checkpoint_storage_roundtrip(tmp_path):
     arr = loaded.task_snapshots["v0#0"]["chain"]["op"]["keyed"][
         "backend"]["t"][0][1]
     assert np.array_equal(arr, np.arange(100))
+
+
+def test_only_the_hash_named_artifact_is_loaded(tmp_path, monkeypatch):
+    """The loaded library is _native-<hash of native.cpp + build command>.so;
+    a stray _native.so (say, built for another machine's CPU and carried
+    over by a copy of the checkout) is never opened, and a changed source
+    or flag names a different artifact, so it is rebuilt."""
+    import shutil
+
+    here = tmp_path / "native"
+    here.mkdir()
+    shutil.copy(native._SRC, here / "native.cpp")
+    (here / "_native.so").write_bytes(b"not an ELF file")
+    monkeypatch.setattr(native, "_HERE", str(here))
+    monkeypatch.setattr(native, "_SRC", str(here / "native.cpp"))
+    monkeypatch.setattr(native, "_lib", None)
+    want = native._artifact_path()
+    assert os.path.basename(want).startswith("_native-")
+    assert "-march=native" not in native._BUILD_CMD
+    lib = native._load()
+    assert lib is not None and lib._name == want
+    assert sorted(p.name for p in here.glob("*.so")) == sorted(
+        ["_native.so", os.path.basename(want)])
+    monkeypatch.setattr(native, "_BUILD_CMD", (*native._BUILD_CMD, "-O2"))
+    assert native._artifact_path() != want
+    with open(here / "native.cpp", "a") as f:
+        f.write("\n// edited\n")
+    monkeypatch.setattr(native, "_BUILD_CMD", native._BUILD_CMD[:-1])
+    assert native._artifact_path() != want

@@ -167,3 +167,41 @@ def test_global_topk_fewer_valid_than_k():
     assert ok_h.sum() == 2
     kept = np.asarray(jax.device_get(idx))[ok_h]
     np.testing.assert_array_equal(sorted(kept), [6, 11])
+
+
+# -- what a v5e refuses to lower (found on the chip, PR 22) ------------------
+# Every job runs with x64 on, and the TPU all-reduces a 64-bit value only
+# for sum: `lax.pmax` of an int64 failed with "UNIMPLEMENTED: Supported
+# lowering only of Sum all reduce", on a mesh of ONE device already.
+
+def test_plan_exchange_is_int32_under_x64():
+    from flink_tpu.parallel.exchange import plan_exchange
+
+    assert jax.config.jax_enable_x64
+    dest = jnp.arange(64, dtype=jnp.int32) % 3
+    plan = plan_exchange(dest, jnp.arange(64) % 7 != 0, 3, 16)
+    assert {f: getattr(plan, f).dtype.name for f in plan._fields} == {
+        "order": "int32", "sd": "int32", "rank": "int32",
+        "n_rounds": "int32"}
+    assert int(plan.n_rounds) == 2          # ceil(19 valid of dest 0 / 16)
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_mesh_step_has_no_64bit_nonsum_collective(n_dev):
+    from flink_tpu.analysis.jaxpr_rules import _iter_eqns
+
+    agg = ShardedWindowAgg(
+        make_mesh(n_dev), [AggDef("bids", "count", jnp.int64),
+                           AggDef("revenue", "sum", jnp.int64)],
+        capacity=1 << 8, ring=8, max_parallelism=MP)
+    D, B = n_dev, 32
+    jaxpr = jax.make_jaxpr(agg.step)(
+        agg.init_state(), jnp.zeros((D, B), jnp.int64),
+        {"revenue": jnp.zeros((D, B), jnp.int64)},
+        jnp.zeros((D, B), jnp.int64), jnp.ones((D, B), bool))
+    collectives = [e for e in _iter_eqns(jaxpr.jaxpr)
+                   if e.primitive.name in ("pmax", "pmin", "all_gather")]
+    assert any(e.primitive.name == "pmax" for e in collectives)
+    wide = [(e.primitive.name, v.aval.dtype.name) for e in collectives
+            for v in e.invars if v.aval.dtype.itemsize > 4]
+    assert not wide, wide

@@ -21,7 +21,7 @@ from flink_tpu.parallel.exchange import (bucket_capacity, exchange_round,
                                          keyby_exchange, plan_exchange)
 from flink_tpu.parallel.mesh import (DATA_AXIS, device_index_for_key_groups,
                                      make_mesh, shard_ranges)
-from flink_tpu.parallel.plan import shard_map_compat
+from flink_tpu.parallel.plan import shard_map_unchecked
 
 ensure_x64()
 
@@ -119,7 +119,7 @@ def _exchange_hists(D, dest, keys, valid, cap=None):
             (jnp.int32(0), jnp.zeros(K, jnp.int32)))
         return hist[None], n_rounds[None].astype(jnp.int32)
 
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         body, mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS), P(DATA_AXIS)),
         out_specs=(P(DATA_AXIS), P(DATA_AXIS)))

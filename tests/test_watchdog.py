@@ -4,7 +4,6 @@ the REST/metrics/bench stall surfaces. All hang injections use tiny
 delays; the `stall` marker arms the conftest SIGALRM wall-clock guard so
 a watchdog regression fails the suite instead of hanging it."""
 
-import sys
 import threading
 import time
 from collections import deque
@@ -85,13 +84,13 @@ def test_configure_adopts_per_site_deadlines():
     assert not WATCHDOG.enabled
     WATCHDOG.reset()
     assert WATCHDOG.enabled
-    assert WATCHDOG.deadline_for("bench.probe") == 75.0
+    assert WATCHDOG.deadline_for("device.execute") == 300.0
 
 
 def test_on_stall_hook_runs_on_expiry():
     killed = []
     with pytest.raises(StallError):
-        WATCHDOG.run("bench.probe", lambda: time.sleep(2.0),
+        WATCHDOG.run("rpc.send", lambda: time.sleep(2.0),
                      deadline=0.02, on_stall=lambda: killed.append(1))
     assert killed == [1]
 
@@ -436,22 +435,3 @@ def test_fs_checkpoint_load_is_stall_bounded(tmp_path):
         storage.load(cp.external_path)
     faults_mod.FAULTS.reset()
     assert storage.load(cp.external_path).checkpoint_id == 1
-
-
-def test_bench_probe_stall_degrades_with_watchdog_trip():
-    sys.path.insert(0, "/root/repo")
-    try:
-        from bench import probe_backend
-    finally:
-        sys.path.pop(0)
-
-    rec = probe_backend(timeout_s=0.25,
-                        _cmd=[sys.executable, "-c",
-                              "import time; time.sleep(30)"])
-    assert rec["error"] == "tpu_unreachable"
-    assert rec["watchdog_trips"] >= 1
-    assert "stalled" in rec["detail"]
-    # a healthy probe still reports its platform
-    rec = probe_backend(timeout_s=30.0,
-                        _cmd=[sys.executable, "-c", "print('cpu')"])
-    assert rec == {"platform": "cpu", "probe_s": rec["probe_s"]}
