@@ -19,7 +19,8 @@ from .core import AnalysisContext, Finding, rule
 # --------------------------------------------------------------------------
 # TPU301 — span inventory: code spans == SPAN_INVENTORY == OBSERVABILITY.md
 
-_SPAN_CALL_RE = re.compile(r'\.span\(\s*"(\w+)",\s*"(\w+)"')
+_SPAN_CALL_RE = re.compile(
+    r'\.(?:span|stage|open_stage)\(\s*"(\w+)",\s*"(\w+)"')
 _SPAN_DOC_ROW = re.compile(r"^\| `(\w+)` \| `(\w+)` \|")
 
 

@@ -293,13 +293,7 @@ def _cmd_trace_dump(args) -> int:
         except OSError as e:
             print(f"trace-dump: cannot fetch {url}: {e}", file=sys.stderr)
             return 1
-        spans = [Span(scope=d["scope"], name=d["name"],
-                      start_ms=d["start_ms"], end_ms=d["end_ms"],
-                      attributes=d.get("attributes") or {},
-                      trace_id=d.get("trace_id", ""),
-                      span_id=d.get("span_id", ""),
-                      parent_id=d.get("parent_id", ""))
-                 for d in payload.get("spans", [])]
+        spans = [Span.from_dict(d) for d in payload.get("spans", [])]
     else:
         spans = TRACER.retained_spans()
     if args.output:

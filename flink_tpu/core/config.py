@@ -796,8 +796,11 @@ class TraceOptions:
     MAX_RETAINED = key("traces.max-retained").int_type().default_value(
         4096, "Most recent completed spans the in-memory reporter (the "
               "REST /jobs/<name>/traces and CLI trace-dump surface) "
-              "retains. Older spans are evicted and counted in the "
-              "spans_dropped_total device-stats counter.")
+              "retains; stage spans (the per-batch / per-watermark / "
+              "per-fire intervals of the mailbox loops, a dozen a "
+              "batch) are kept apart, 16 times as many. Older spans "
+              "are evicted and counted in the spans_dropped_total "
+              "device-stats counter.")
     FLIGHT_CAPACITY = key("traces.flight-recorder.capacity").int_type() \
         .default_value(
             512, "Ring size of the always-on flight recorder: how many "

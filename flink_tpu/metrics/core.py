@@ -232,6 +232,18 @@ class TaskMetrics:
                 lambda: timers.backpressured_ms_per_s)
         g.gauge("busyTimeRatio", lambda: timers.busy_ratio)
 
+    def bind_input_gates(self, gates) -> None:
+        """Expose, per input gate, how long the element polled last sat
+        in its channel (``inputQueueResidenceMs``; ``input1...`` /
+        ``input2...`` on a two-input task). The channel stamps each
+        element once at ``put``; a growing reading is a consumer that
+        falls behind its producer."""
+        for i, gate in enumerate(gates):
+            name = ("inputQueueResidenceMs" if len(gates) == 1
+                    else f"input{i + 1}QueueResidenceMs")
+            self.group.gauge(name,
+                             lambda g=gate: g.last_residence_ns / 1e6)
+
     def bind_progress(self, progress) -> None:
         """Expose the task's progress-epoch age as a gauge
         (``lastProgressAgeMs``) — the per-task stall-supervision surface

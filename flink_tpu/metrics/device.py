@@ -80,6 +80,9 @@ class DeviceStats:
         self._panes_sealed = 0
         self._batches_coalesced = 0
         self._fire_merge_rows = 0
+        # drain accounting (PR 25): non-blocking drains that found the
+        # oldest queued fire's device->host copy not landed yet
+        self._fire_unready_polls = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -316,6 +319,15 @@ class DeviceStats:
     def note_fire_merge_rows(self, n: int) -> None:
         with self._lock:
             self._fire_merge_rows += int(n)
+
+    def note_fire_unready_poll(self) -> None:
+        with self._lock:
+            self._fire_unready_polls += 1
+
+    @property
+    def fire_unready_polls(self) -> int:
+        with self._lock:
+            return self._fire_unready_polls
 
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
@@ -571,6 +583,7 @@ class DeviceStats:
                 "panes_sealed_total": self._panes_sealed,
                 "batches_coalesced_total": self._batches_coalesced,
                 "fire_merge_rows_read": self._fire_merge_rows,
+                "fire_unready_polls_total": self._fire_unready_polls,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -672,6 +685,7 @@ class DeviceStats:
             self._panes_sealed = 0
             self._batches_coalesced = 0
             self._fire_merge_rows = 0
+            self._fire_unready_polls = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -1079,6 +1093,9 @@ def bind_device_metrics(registry) -> None:
     g.gauge("panes_sealed_total", lambda: s.panes_sealed)
     g.gauge("batches_coalesced_total", lambda: s.batches_coalesced)
     g.gauge("fire_merge_rows_read", lambda: s.fire_merge_rows)
+    # async fire drain (prometheus:
+    # flink_tpu_device_fire_unready_polls_total)
+    g.gauge("fire_unready_polls_total", lambda: s.fire_unready_polls)
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

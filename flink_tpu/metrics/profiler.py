@@ -45,6 +45,12 @@ parameter — down to the tuple element, e.g. ``shape[1]: 64 -> 128`` —
 changed.  ``recompiles != 0`` regressions become one CLI table
 (``python -m flink_tpu.cli profile <job>``) instead of a grep hunt.
 
+What is timed is the HOST'S DISPATCH CALL of each program: JAX returns
+from it before the device has run the program, so ``self_ms`` /
+``device_ms`` here are dispatch time, not time on the device (that comes
+from a ``jax.profiler`` trace; PERF.md section 3). The Perfetto counter
+track says so: ``dispatch_ms:<site>``.
+
 Durations are measured with ``time.perf_counter()`` and clamped to be
 non-negative; timestamps come from the monotonic-anchored ``now_ms()``
 (TPU501: no wall clock in span paths).  All mutation happens under one
@@ -528,7 +534,9 @@ class DeviceLedger:
 
     def trace_counters(self) -> List[dict]:
         """Recent (ts_ms, site, ms) samples for the Perfetto counter
-        tracks (``chrome_trace_events(counters=...)``)."""
+        tracks (``chrome_trace_events(counters=...)``, rendered as
+        ``dispatch_ms:<site>``: what is timed is the host's dispatch
+        call, not the program on the device)."""
         with self._lock:
             return [{"ts_ms": ts, "site": site, "ms": ms}
                     for ts, site, ms in self._samples]

@@ -13,11 +13,20 @@ wire-portable (trace_id, span_id) pair; it crosses process boundaries on
 crosses thread boundaries via an explicit ``parent=`` argument or the
 thread-local ambient context pushed by ``with tracer.span(...)``.
 
-Clocks: span timestamps are *reported* as epoch milliseconds (the
-reference Span contract) but *measured* on the monotonic clock — the
-epoch offset is sampled once at import and added to ``time.monotonic()``
-— so a wall-clock step (NTP slew, manual date change) can never produce
-a negative ``duration_ms``.
+Clocks: span timestamps are epoch NANOSECONDS (``start_ns``/``end_ns``;
+``start_ms``/``end_ms``/``duration_ms`` are derived, the reference Span
+contract) *measured* on the monotonic clock — the epoch offset is
+sampled once at import and added to ``time.monotonic_ns()`` — so a
+wall-clock step (NTP slew, manual date change) can never produce a
+negative duration, and a 300 us dispatch has a duration.
+
+Stage spans (:meth:`Tracer.stage` / :meth:`Tracer.open_stage`) are the
+per-batch / per-watermark / per-fire intervals of the mailbox loop. Each
+one is ALSO a ``jax.profiler.TraceAnnotation`` named ``<scope>.<Name>``
+with the span's attributes as its arguments, so under a profiler session
+the same interval sits in the ``.xplane.pb`` on the device trace's clock
+(a reader pairs the two by ``(name, task, seq)`` and checks the clocks
+against each other). With no session the annotation is a flag test.
 
 Reporters are pluggable (:class:`TraceReporter`): a bounded in-memory
 ring for REST/CLI inspection, a Chrome trace-event (Perfetto-loadable)
@@ -51,19 +60,25 @@ __all__ = [
     "Span", "SpanBuilder", "TraceContext", "TraceReporter",
     "InMemoryTraceReporter", "FlightRecorder", "Tracer",
     "TRACER", "FLIGHT_RECORDER", "chrome_trace_events",
-    "current_context", "use_context", "now_ms",
+    "current_context", "use_context", "now_ms", "now_ns", "Stage",
     "record_flight_event", "dump_flight_recorder", "SPAN_INVENTORY",
 ]
 
-# Epoch offset sampled once at import: now_ms() is monotonic-derived but
-# reports epoch milliseconds, so durations are immune to wall-clock steps
+# Epoch offset sampled once at import: now_ns() is monotonic-derived but
+# reports epoch nanoseconds, so durations are immune to wall-clock steps
 # while start times still line up with log timestamps.
-_EPOCH_OFFSET_MS = time.time() * 1000.0 - time.monotonic() * 1000.0  # lint: wall-clock-ok sampled ONCE at import to anchor the monotonic clock
+_EPOCH_OFFSET_NS = time.time_ns() - time.monotonic_ns()  # lint: wall-clock-ok sampled ONCE at import to anchor the monotonic clock
+_NS_PER_MS = 1_000_000
+
+
+def now_ns() -> int:
+    """Epoch nanoseconds measured on the monotonic clock."""
+    return time.monotonic_ns() + _EPOCH_OFFSET_NS
 
 
 def now_ms() -> int:
     """Epoch milliseconds measured on the monotonic clock."""
-    return int(time.monotonic() * 1000.0 + _EPOCH_OFFSET_MS)
+    return now_ns() // _NS_PER_MS
 
 
 def _new_id() -> str:
@@ -96,26 +111,49 @@ class TraceContext:
 class Span:
     scope: str
     name: str
-    start_ms: int
-    end_ms: int
+    start_ns: int
+    end_ns: int
     attributes: dict = field(default_factory=dict)
     trace_id: str = ""
     span_id: str = ""
     parent_id: str = ""
 
     @property
+    def start_ms(self) -> int:
+        return self.start_ns // _NS_PER_MS
+
+    @property
+    def end_ms(self) -> int:
+        return self.end_ns // _NS_PER_MS
+
+    @property
     def duration_ms(self) -> int:
         return self.end_ms - self.start_ms
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
 
     def to_dict(self) -> dict:
         return {
             "scope": self.scope, "name": self.name,
             "start_ms": self.start_ms, "end_ms": self.end_ms,
             "duration_ms": self.duration_ms,
+            "start_ns": self.start_ns, "end_ns": self.end_ns,
             "trace_id": self.trace_id, "span_id": self.span_id,
             "parent_id": self.parent_id,
             "attributes": dict(self.attributes),
         }
+
+    @staticmethod
+    def from_dict(d: dict) -> "Span":
+        """Inverse of ``to_dict`` (REST / CLI); dicts written before the
+        ns fields existed carry milliseconds only."""
+        start_ns = d.get("start_ns", int(d["start_ms"]) * _NS_PER_MS)
+        end_ns = d.get("end_ns", int(d["end_ms"]) * _NS_PER_MS)
+        return Span(d["scope"], d["name"], int(start_ns), int(end_ns),
+                    dict(d.get("attributes") or {}), d.get("trace_id", ""),
+                    d.get("span_id", ""), d.get("parent_id", ""))
 
 
 # ---------------------------------------------------------------------------
@@ -162,7 +200,7 @@ class SpanBuilder:
         self._tracer = tracer
         self._scope = scope
         self._name = name
-        self._start_ms = now_ms()
+        self._start_ns = now_ns()
         self._attrs: dict = {}
         if parent is None:
             parent = current_context()
@@ -189,14 +227,14 @@ class SpanBuilder:
         return self
 
     def set_start_ts(self, start_ms: int) -> "SpanBuilder":
-        self._start_ms = int(start_ms)
+        self._start_ns = int(start_ms) * _NS_PER_MS
         return self
 
     def finish(self, end_ms: Optional[int] = None) -> Span:
-        end = now_ms() if end_ms is None else int(end_ms)
-        if end < self._start_ms:        # wall-clock step / caller skew
-            end = self._start_ms
-        span = Span(self._scope, self._name, self._start_ms, end,
+        end = now_ns() if end_ms is None else int(end_ms) * _NS_PER_MS
+        if end < self._start_ns:        # wall-clock step / caller skew
+            end = self._start_ns
+        span = Span(self._scope, self._name, self._start_ns, end,
                     dict(self._attrs), self._trace_id, self._span_id,
                     self._parent_id)
         if not self._finished:
@@ -205,7 +243,7 @@ class SpanBuilder:
         return span
 
     def __enter__(self) -> "SpanBuilder":
-        self._start_ms = now_ms()
+        self._start_ns = now_ns()
         self._ctx_cm = use_context(self.context)
         self._ctx_cm.__enter__()
         return self
@@ -218,46 +256,225 @@ class SpanBuilder:
         self.finish()
 
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported lazily: this module
+    stays importable (tpu-lint, docs tooling) without jax."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:  # noqa: BLE001 - no profiler: ring spans only
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+def _annotation_args(attrs: dict) -> dict:
+    """The profiler encodes an annotation's arguments into its name as
+    ``#k=v,k=v#``, so a string value may hold neither ``#`` nor ``,``: a
+    task id ``v3#0`` rides as ``v3/0`` (the span keeps the real one)."""
+    return {k: v.replace("#", "/").replace(",", ";")
+            if isinstance(v, str) else v for k, v in attrs.items()}
+
+
+class Stage:
+    """One stage interval of a mailbox loop (a batch, a watermark, a
+    fire, a wait), recorded on both clocks from ONE pair of timestamps:
+
+    * a ``jax.profiler.TraceAnnotation`` named ``<scope>.<Name>`` whose
+      arguments are the attributes, so that under a profiler session the
+      interval is in the ``.xplane.pb`` beside the device's events;
+    * when ``traces.enabled``, a :class:`Span` with the same attributes,
+      parented on ``parent`` or the ambient context.
+
+    ``with tracer.stage(...)`` pushes the stage as the ambient parent of
+    spans started inside the block. ``tracer.open_stage(...)`` returns an
+    open stage that a LATER mailbox turn closes (``close``); it neither
+    reads nor pushes the ambient stack: with no ``parent`` it is the root
+    of its own trace. ``total`` names a ``(dict, key)`` the duration in
+    seconds is added to on close, so a stage total and its spans come
+    from one timing site.
+
+    Every stage carries ``task`` (the mailbox thread's name, which is the
+    task id) and the caller's ``seq``; ``(scope.Name, task, seq)``
+    identifies the interval in both records. Stages run per batch, per
+    watermark and per fire, never per record or per poll, and a stage
+    that is opened is reported: a caller that cannot know beforehand
+    whether an attempt is an interval at all (a source read that may
+    return nothing) stamps ``now_ns()`` before it and opens the stage
+    afterwards with ``start_ns=`` that stamp. The span then starts at the
+    stamp; the annotation, which cannot be backdated, starts where the
+    stage was opened (a source's ``read_ms`` later)."""
+
+    __slots__ = ("_tracer", "scope", "name", "attrs", "_parent", "_total",
+                 "_ann", "_late", "_ctx", "_ctx_cm", "_open", "start_ns",
+                 "end_ns")
+
+    def __init__(self, tracer: "Tracer", scope: str, name: str,
+                 parent: Optional[TraceContext], total: Optional[tuple],
+                 attrs: dict, start_ns: Optional[int] = None):
+        self._tracer = tracer
+        self.scope = scope
+        self.name = name
+        attrs.setdefault("task", threading.current_thread().name)
+        self.attrs = attrs
+        self._parent = parent
+        self._total = total
+        self._ctx: Optional[TraceContext] = None
+        self._ctx_cm: Optional[use_context] = None
+        self._open = True
+        self.end_ns = 0
+        ann = _annotation_cls()
+        if ann and ann.is_enabled():
+            self._ann = ann(f"{scope}.{name}", **_annotation_args(attrs))
+            self._ann.__enter__()
+            self._late: Optional[dict] = {}
+        else:
+            self._ann = self._late = None
+        self.start_ns = now_ns() if start_ns is None else start_ns
+
+    @property
+    def context(self) -> Optional[TraceContext]:
+        """This stage's identity, for parenting children from a later
+        mailbox turn; None while tracing is off (children then start
+        their own trace, which is discarded all the same)."""
+        if self._ctx is None and self._tracer.enabled:
+            parent = self._parent
+            self._ctx = TraceContext(
+                parent.trace_id if parent else _new_id(), _new_id())
+        return self._ctx
+
+    def set(self, key: str, value: Any) -> "Stage":
+        """Attribute known only once the stage runs (rows, fires); the
+        annotation receives it when the stage closes."""
+        self.attrs[key] = value
+        if self._late is not None:
+            self._late[key] = value
+        return self
+
+    def count(self, key: str) -> None:
+        """Add one to a counting attribute (a fire's ``unready_polls``)."""
+        self.set(key, self.attrs.get(key, 0) + 1)
+
+    @property
+    def duration_ns(self) -> int:
+        return (self.end_ns if not self._open else now_ns()) - self.start_ns
+
+    @property
+    def duration_s(self) -> float:
+        return self.duration_ns / 1e9
+
+    @property
+    def duration_ms(self) -> float:
+        return self.duration_ns / 1e6
+
+    def close(self, **attrs: Any) -> None:
+        if not self._open:
+            return
+        for k, v in attrs.items():
+            self.set(k, v)
+        self.end_ns = now_ns()
+        self._open = False
+        if self._ann is not None:
+            if self._late:
+                self._ann.set_metadata(**_annotation_args(self._late))
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self._total is not None:
+            d, key = self._total
+            d[key] = d.get(key, 0.0) + (self.end_ns - self.start_ns) / 1e9
+        if self._tracer.enabled:
+            ctx = self.context
+            parent = self._parent
+            self._tracer._report(Span(
+                self.scope, self.name, self.start_ns, self.end_ns,
+                dict(self.attrs), ctx.trace_id, ctx.span_id,
+                parent.span_id if parent else ""), stage=True)
+
+    def __enter__(self) -> "Stage":
+        ctx = self.context
+        if ctx is not None:
+            self._ctx_cm = use_context(ctx)
+            self._ctx_cm.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ctx_cm is not None:
+            self._ctx_cm.__exit__(exc_type, exc, tb)
+            self._ctx_cm = None
+        if self._open:
+            if exc_type is not None:
+                self.attrs["error"] = True
+            self.close()
+
+
 class TraceReporter:
     """Receives completed spans (reference TraceReporter.addSpan)."""
 
     def add_span(self, span: Span) -> None:
         raise NotImplementedError
 
+    def add_stage_span(self, span: Span) -> None:
+        """A completed stage span (:class:`Stage`): a dozen per batch
+        where the other spans come per checkpoint or per fault. The two
+        built-in reporters keep them in a ring of their own, so that the
+        steady stream cannot evict the rare spans."""
+        self.add_span(span)
+
 
 class InMemoryTraceReporter(TraceReporter):
-    """Bounded in-memory span ring for tests, REST and the CLI. Retains
-    the most recent ``max_retained`` spans (``traces.max-retained``);
-    evictions are counted into DEVICE_STATS as ``spans_dropped_total``."""
+    """Bounded in-memory span rings for tests, REST and the CLI: the most
+    recent ``max_retained`` spans (``traces.max-retained``) and, apart
+    from them, ``STAGE_FACTOR`` times as many stage spans, which come a
+    dozen per batch where the others come per checkpoint or per fault.
+    Evictions from either are counted into DEVICE_STATS as
+    ``spans_dropped_total``."""
+
+    STAGE_FACTOR = 16
 
     def __init__(self, max_retained: int = 4096):
         self.spans: list[Span] = []
+        self.stage_spans: list[Span] = []
         self.max_retained = int(max_retained)
         self.dropped = 0
         self._lock = threading.Lock()
 
-    def add_span(self, span: Span) -> None:
+    def _add(self, ring: list, limit: int, span: Span) -> None:
         excess = 0
         with self._lock:
-            self.spans.append(span)
-            if len(self.spans) > self.max_retained:
-                excess = len(self.spans) - self.max_retained
-                del self.spans[:excess]
+            ring.append(span)
+            if len(ring) > limit:
+                excess = len(ring) - limit
+                del ring[:excess]
                 self.dropped += excess
         if excess:
             _note_spans_dropped(excess)
 
+    def add_span(self, span: Span) -> None:
+        self._add(self.spans, self.max_retained, span)
+
+    def add_stage_span(self, span: Span) -> None:
+        self._add(self.stage_spans, self.STAGE_FACTOR * self.max_retained,
+                  span)
+
     def by_name(self, name: str) -> list[Span]:
-        with self._lock:
-            return [s for s in self.spans if s.name == name]
+        return [s for s in self.snapshot() if s.name == name]
 
     def snapshot(self) -> list[Span]:
+        """Both rings as one list, in the order the spans ended."""
         with self._lock:
-            return list(self.spans)
+            if not self.stage_spans:
+                return list(self.spans)
+            return sorted(self.spans + self.stage_spans,
+                          key=lambda s: s.end_ns)
 
     def clear(self) -> None:
         with self._lock:
             self.spans.clear()
+            self.stage_spans.clear()
             self.dropped = 0
 
 
@@ -285,6 +502,9 @@ class FlightRecorder(TraceReporter):
         self.min_dump_interval_s = min_dump_interval_s
         self.dumps: list[dict] = []
         self._ring: deque = deque(maxlen=int(capacity))
+        # stage spans apart, as in the in-memory reporter: a dump holds
+        # the last ``capacity`` of each
+        self._stages: deque = deque(maxlen=int(capacity))
         self._last_dump_ms: Dict[str, int] = {}
         self._lock = threading.Lock()
 
@@ -295,12 +515,19 @@ class FlightRecorder(TraceReporter):
     def set_capacity(self, capacity: int) -> None:
         with self._lock:
             self._ring = deque(self._ring, maxlen=max(1, int(capacity)))
+            self._stages = deque(self._stages, maxlen=max(1, int(capacity)))
 
-    def add_span(self, span: Span) -> None:
+    def _add(self, ring: deque, span: Span) -> None:
         entry = {"type": "span", "ts_ms": span.end_ms}
         entry.update(span.to_dict())
         with self._lock:
-            self._ring.append(entry)
+            ring.append(entry)
+
+    def add_span(self, span: Span) -> None:
+        self._add(self._ring, span)
+
+    def add_stage_span(self, span: Span) -> None:
+        self._add(self._stages, span)
 
     def record_event(self, kind: str, **fields: Any) -> None:
         entry = {"type": "event", "kind": kind, "ts_ms": now_ms()}
@@ -311,6 +538,10 @@ class FlightRecorder(TraceReporter):
     def snapshot(self) -> list[dict]:
         with self._lock:
             return list(self._ring)
+
+    def stage_snapshot(self) -> list[dict]:
+        with self._lock:
+            return list(self._stages)
 
     def dump(self, reason: str, **fields: Any) -> Optional[str]:
         """Write the current ring to a timestamped file; returns the path,
@@ -323,13 +554,15 @@ class FlightRecorder(TraceReporter):
                 return None
             self._last_dump_ms[reason] = ts
             entries = list(self._ring)
+            stages = list(self._stages)
         directory = self.dump_dir or os.path.join(
             tempfile.gettempdir(), "flink_tpu_flight")
         safe = re.sub(r"[^A-Za-z0-9._-]", "_", reason) or "fault"
         path = os.path.join(directory, f"flight-{safe}-{ts}.json")
         payload = {"reason": reason, "dumped_at_ms": ts,
                    "pid": os.getpid(), "entry_count": len(entries),
-                   "context": dict(fields), "entries": entries}
+                   "context": dict(fields), "entries": entries,
+                   "stages": stages}
         try:
             os.makedirs(directory, exist_ok=True)
             tmp = path + ".tmp"
@@ -349,6 +582,7 @@ class FlightRecorder(TraceReporter):
     def reset(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._stages.clear()
             self.dumps.clear()
             self._last_dump_ms.clear()
 
@@ -367,18 +601,36 @@ class Tracer:
              parent: Optional[TraceContext] = None) -> SpanBuilder:
         return SpanBuilder(self, scope, name, parent=parent)
 
-    def _report(self, span: Span) -> None:
+    def stage(self, scope: str, name: str,
+              parent: Optional[TraceContext] = None,
+              total: Optional[tuple] = None,
+              start_ns: Optional[int] = None, **attrs: Any) -> Stage:
+        """A stage interval as a context manager (see :class:`Stage`)."""
+        return Stage(self, scope, name, parent or current_context(), total,
+                     attrs, start_ns)
+
+    def open_stage(self, scope: str, name: str,
+                   parent: Optional[TraceContext] = None,
+                   total: Optional[tuple] = None, **attrs: Any) -> Stage:
+        """A stage interval that is not a ``with`` block: opened at one
+        mailbox turn, closed at a later one."""
+        return Stage(self, scope, name, parent, total, attrs)
+
+    def _report(self, span: Span, stage: bool = False) -> None:
         if not self.enabled:
             return
         for r in self._reporters:
             try:
-                r.add_span(span)
+                if stage:
+                    r.add_stage_span(span)
+                else:
+                    r.add_span(span)
             except Exception:  # noqa: BLE001 - reporters must not kill jobs
                 pass
 
     def retained_spans(self) -> list[Span]:
-        """Spans held by the first attached in-memory reporter (the REST
-        / CLI inspection surface)."""
+        """Spans held by the first attached in-memory reporter, stage
+        spans included (the REST / CLI inspection surface)."""
         for r in self._reporters:
             if isinstance(r, InMemoryTraceReporter):
                 return r.snapshot()
@@ -416,20 +668,23 @@ class Tracer:
 def chrome_trace_events(spans: Iterable[Span], pid: int = 0,
                         counters: Optional[Iterable[dict]] = None) -> dict:
     """Render spans as a Chrome trace-event JSON object (the ``ph: "X"``
-    complete-event form) loadable in Perfetto / chrome://tracing. Scopes
+    complete-event form, microseconds from the spans' ns clock) loadable
+    in Perfetto / chrome://tracing. Scopes
     map to tids so each subsystem gets its own track; causal ids ride in
     ``args`` for tree reconstruction.
 
-    ``counters`` takes device-time ledger samples
+    ``counters`` takes ledger samples
     (``DEVICE_LEDGER.trace_counters()``: dicts with ``ts_ms``/``site``/
     ``ms``) and renders them as ``ph: "C"`` counter tracks — one
-    ``device_ms:<site>`` series per dispatch site, alongside the span
-    tracks."""
+    ``dispatch_ms:<site>`` series per dispatch site, alongside the span
+    tracks. The ledger times the HOST's dispatch call (the enqueue, which
+    returns before the device runs the program), so the track is named
+    for that; device time comes from a ``jax.profiler`` trace."""
     tids: Dict[str, int] = {}
     events: List[dict] = []
     for c in counters or ():
         events.append({
-            "name": f"device_ms:{c['site']}", "cat": "profiler",
+            "name": f"dispatch_ms:{c['site']}", "cat": "profiler",
             "ph": "C", "ts": int(c["ts_ms"]) * 1000, "pid": pid,
             "args": {"ms": round(float(c["ms"]), 4)},
         })
@@ -444,8 +699,8 @@ def chrome_trace_events(spans: Iterable[Span], pid: int = 0,
             args[k] = v if isinstance(v, (int, float, bool, str)) else str(v)
         events.append({
             "name": span.name, "cat": span.scope, "ph": "X",
-            "ts": span.start_ms * 1000,
-            "dur": max(span.duration_ms, 0) * 1000,
+            "ts": span.start_ns // 1000,
+            "dur": max(span.duration_ns, 0) // 1000,
             "pid": pid, "tid": tid, "args": args,
         })
     for scope, tid in sorted(tids.items(), key=lambda kv: kv[1]):
@@ -560,8 +815,17 @@ SPAN_INVENTORY: tuple = (
     ("sched", "Shed",
      "runtime/stream_task.py _admission_gate — overloaded micro-batch "
      "quarantined to the dead-letter output"),
+    ("task", "ProcessBatch",
+     "runtime/stream_task.py OneInput/TwoInputStreamTask.invoke — one "
+     "dequeued batch through the operator chain (stage span: rows, "
+     "queued_ms, queue_depth)"),
     ("task", "SourceBatch",
-     "runtime/stream_task.py — one source read→emit mailbox cycle"),
+     "runtime/stream_task.py — one source read→emit mailbox cycle "
+     "(stage span)"),
+    ("task", "WaitInput",
+     "runtime/stream_task.py OneInput/TwoInputStreamTask.invoke — first "
+     "empty input poll → the next event, one span per wait (stage span: "
+     "polls); its durations are the task's idle time"),
     ("tier", "Evict",
      "state/tpu_backend.py _evict_cold_groups — cold key groups paged "
      "to the host-warm tier + device table rebuild"),
@@ -571,4 +835,33 @@ SPAN_INVENTORY: tuple = (
     ("watchdog", "Stall",
      "runtime/watchdog.py _note_trip — deadline expiry at a guarded "
      "site"),
+    ("window", "Drain",
+     "runtime/operators/slice_control.py AsyncFireQueue._drain_stage, "
+     "used by device_window / mesh_window _materialize — device_get of a "
+     "fire's outputs + host selection/sort; child of Fire (stage span)"),
+    ("window", "Emit",
+     "runtime/operators/slice_control.py AsyncFireQueue._emit_stage — "
+     "building the window's rows + output.emit; child of Fire (stage "
+     "span: rows)"),
+    ("window", "Fire",
+     "runtime/operators/slice_control.py — root of one span tree per "
+     "fired window: _fire entry → its rows emitted, closed from a later "
+     "mailbox turn when fires are async (stage span: window_end_ms, "
+     "rows, d2h_bytes, unready_polls)"),
+    ("window", "FireDispatch",
+     "runtime/operators/slice_control.py _fire_window — the host's "
+     "dispatch of one fire: guarded fire program(s) + ring-row reset; "
+     "child of Fire (stage span)"),
+    ("window", "IngestDispatch",
+     "runtime/operators/device_window.py + "
+     "runtime/operators/device_session.py — host time to enqueue one "
+     "batch's ingest programs (stage span: programs)"),
+    ("window", "Upload",
+     "runtime/operators/device_window.py _fold_packed / "
+     "_to_device_batch — pack + the one host→device copy; device/H2D "
+     "nests under it (stage span: bytes)"),
+    ("window", "Watermark",
+     "runtime/operators/slice_control.py "
+     "SliceControlPlane.process_watermark — one watermark through the "
+     "window operator (stage span: watermark_ms, fires, since_batch_ms)"),
 )

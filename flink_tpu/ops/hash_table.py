@@ -104,16 +104,17 @@ def lookup(table_keys: jax.Array, keys: jax.Array) -> jax.Array:
 
     def body(state):
         base, slot, done = state
-        idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
-            jnp.int32)
-        entry = table_keys[idx]                              # [n, CHUNK]
-        is_key = entry == keys[:, None]
-        is_empty = entry == jnp.int64(EMPTY_KEY)
-        pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
-        pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
-        found = (~done) & (pos_found < pos_empty)
-        fslot = jnp.take_along_axis(
-            idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
+        with jax.named_scope("probe.gather"):
+            idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
+                jnp.int32)
+            entry = table_keys[idx]                          # [n, CHUNK]
+            is_key = entry == keys[:, None]
+            is_empty = entry == jnp.int64(EMPTY_KEY)
+            pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
+            pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
+            found = (~done) & (pos_found < pos_empty)
+            fslot = jnp.take_along_axis(
+                idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
         slot = jnp.where(found, fslot, slot)
         done = done | found | (pos_empty < C)  # empty first => absent
         base = jnp.where(done, base, base + jnp.uint32(CHUNK))
@@ -150,26 +151,33 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
 
     def body(state):
         table, base, slot, done = state
-        idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
-            jnp.int32)
-        entry = table[idx]                                   # [n, CHUNK]
-        is_key = entry == keys[:, None]
-        is_empty = entry == jnp.int64(EMPTY_KEY)
-        pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
-        pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
-        found = (~done) & (pos_found < pos_empty)
-        fslot = jnp.take_along_axis(
-            idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
+        # named regions: one probe round's ops carry probe.gather (the
+        # [n, CHUNK] window read + match) or probe.claim (the scatter-min
+        # that claims empties + its read-back) in their name path (HLO
+        # op_name; the tf_op stat of an op's metadata in a TPU trace),
+        # whatever fusion numbers the compiler assigns
+        with jax.named_scope("probe.gather"):
+            idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
+                jnp.int32)
+            entry = table[idx]                               # [n, CHUNK]
+            is_key = entry == keys[:, None]
+            is_empty = entry == jnp.int64(EMPTY_KEY)
+            pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
+            pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
+            found = (~done) & (pos_found < pos_empty)
+            fslot = jnp.take_along_axis(
+                idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
         # claim the window's first empty; losers of the scatter-min resume
         # from the contested slot next iteration
-        want = (~done) & ~found & (pos_empty < C)
-        cslot = jnp.take_along_axis(
-            idx, jnp.minimum(pos_empty, C - 1)[:, None], axis=1)[:, 0]
-        claim_idx = jnp.where(want, cslot, jnp.int32(0))
-        claim_val = jnp.where(want, keys, jnp.int64(EMPTY_KEY))
-        table = table.at[claim_idx].min(claim_val)
-        entry2 = table[cslot]
-        won = want & (entry2 == keys)
+        with jax.named_scope("probe.claim"):
+            want = (~done) & ~found & (pos_empty < C)
+            cslot = jnp.take_along_axis(
+                idx, jnp.minimum(pos_empty, C - 1)[:, None], axis=1)[:, 0]
+            claim_idx = jnp.where(want, cslot, jnp.int32(0))
+            claim_val = jnp.where(want, keys, jnp.int64(EMPTY_KEY))
+            table = table.at[claim_idx].min(claim_val)
+            entry2 = table[cslot]
+            won = want & (entry2 == keys)
         slot = jnp.where(found, fslot, slot)
         slot = jnp.where(won, cslot, slot)
         done = done | found | won

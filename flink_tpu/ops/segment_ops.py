@@ -96,10 +96,11 @@ def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
                  values: jax.Array, valid: jax.Array) -> jax.Array:
     """Fold a batch into a flat accumulator: acc[flat_idx] op= values,
     masked by ``valid`` (invalid rows fold the identity into slot 0)."""
-    identity = AGG_INITS[kind](acc.dtype)
-    idx = jnp.where(valid, flat_idx, 0)
-    vals = jnp.where(valid, values.astype(acc.dtype), identity)
-    return AGG_FOLDS[kind](acc, idx, vals)
+    with jax.named_scope("fold.scatter"):
+        identity = AGG_INITS[kind](acc.dtype)
+        idx = jnp.where(valid, flat_idx, 0)
+        vals = jnp.where(valid, values.astype(acc.dtype), identity)
+        return AGG_FOLDS[kind](acc, idx, vals)
 
 
 def pane_window_merge(kind: str, acc: jax.Array,

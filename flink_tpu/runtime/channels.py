@@ -34,6 +34,10 @@ DEFAULT_CAPACITY = 64  # queued elements per channel before backpressure
 class Channel:
     """One logical edge subtask->subtask."""
 
+    #: how long the element returned by the last ``poll`` sat in the
+    #: channel (0 where an implementation does not stamp its elements)
+    last_residence_ns = 0
+
     def put(self, element: Any, timeout: Optional[float] = None) -> bool:
         raise NotImplementedError
 
@@ -56,16 +60,20 @@ class LocalChannel(Channel):
             # backpressure path deterministically without losing data
             return False
         try:
-            self._q.put(element, timeout=timeout)
+            # stamped once per element (a batch, a watermark): the reader
+            # learns how long it queued (task/ProcessBatch.queued_ms)
+            self._q.put((element, time.monotonic_ns()), timeout=timeout)
             return True
         except queue.Full:
             return False
 
     def poll(self) -> Optional[Any]:
         try:
-            return self._q.get_nowait()
+            element, put_ns = self._q.get_nowait()
         except queue.Empty:
             return None
+        self.last_residence_ns = time.monotonic_ns() - put_ns
+        return element
 
     def size(self) -> int:
         return self._q.qsize()
@@ -146,6 +154,7 @@ class GateEvent:
     kind: str  # "batch" | "watermark" | "barrier" | "end" | "latency" | "idle"
     value: Any = None
     channel: int = -1
+    queued_ns: int = 0  # residence of the element in its channel
 
 
 class InputGate:
@@ -180,6 +189,9 @@ class InputGate:
         self._combined_wm = MIN_TIMESTAMP
         self._rr = 0                         # fair round-robin pointer
         self.alignment_start: float = 0.0
+        # channel residence of the last element polled (the per-gate
+        # inputQueueResidenceMs gauge reads it)
+        self.last_residence_ns = 0
         # unaligned capture state
         self._capturing: set[int] = set()    # channels still pre-barrier
         self._capture_barrier: Optional[CheckpointBarrier] = None
@@ -276,12 +288,21 @@ class InputGate:
             i = (self._rr + off) % n
             if self._blocked[i] or self._ended[i]:
                 continue
-            e = self.channels[i].poll()
+            ch = self.channels[i]
+            e = ch.poll()
             if e is None:
                 continue
             self._rr = (i + 1) % n
-            return self._classify(i, e)
+            ev = self._classify(i, e)
+            self.last_residence_ns = ch.last_residence_ns
+            if ev is not None:
+                ev.queued_ns = ch.last_residence_ns
+            return ev
         return None
+
+    def queue_depth(self) -> int:
+        """Elements queued over all channels right now."""
+        return sum(ch.size() for ch in self.channels)
 
     def _classify(self, i: int, e: Any) -> Optional[GateEvent]:
         if isinstance(e, RecordBatch):

@@ -57,6 +57,7 @@ import numpy as np
 
 from ...core.elements import Watermark
 from ...core.records import RecordBatch, Schema
+from ...metrics.tracing import TRACER
 from ...metrics.device import DEVICE_STATS, instrumented_program_cache, \
     pytree_nbytes
 from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, \
@@ -345,6 +346,7 @@ class DeviceSessionWindowOperator(OneInputOperator):
         self._fired_boundary = _NEG
         self.fire_latencies_ms: list[float] = []
         self.stage_s = {"ingest": 0.0, "fire": 0.0, "drain": 0.0}
+        self._batch_seq = 0  # ordinal of the batch being ingested (spans)
         # gap-closed sessions awaiting their watermark, as columnar numpy
         # chunks {"k","s","e","c", aggs...} (filled by the step's eager
         # in-batch finalization; emitted once the watermark passes)
@@ -421,7 +423,12 @@ class DeviceSessionWindowOperator(OneInputOperator):
                     "device session windows need an integer key column; "
                     f"{self._key_column!r} is {key_dtype}")
             self._register_aggs(batch.schema)
-        t0 = time.perf_counter()
+        self._batch_seq += 1
+        with TRACER.stage("window", "IngestDispatch", seq=self._batch_seq,
+                          total=(self.stage_s, "ingest"), rows=batch.n):
+            self._ingest(batch)
+
+    def _ingest(self, batch: RecordBatch) -> None:
         keys = np.asarray(batch.column(self._key_column)).astype(np.int64)
         ts = np.asarray(batch.timestamps, np.int64)
         order = np.lexsort((ts, keys))
@@ -493,7 +500,6 @@ class DeviceSessionWindowOperator(OneInputOperator):
             self._pending.append(chunk)
         self._late_dev = late
         self._backend.set_dirty_mask(dirty)
-        self.stage_s["ingest"] += time.perf_counter() - t0
 
     def process_watermark(self, watermark: Watermark) -> None:
         self.current_watermark = watermark.timestamp

@@ -38,6 +38,7 @@ from ...core.elements import Watermark
 from ...core.records import MIN_TIMESTAMP, RecordBatch, Schema
 from ...metrics.device import DEVICE_STATS, instrumented_program_cache, \
     pytree_nbytes
+from ...metrics.tracing import TRACER
 from ..faults import DeviceGuard, DeviceSegmentError, FAULTS, \
     fire_with_retries
 from ..watchdog import WATCHDOG, stall_bounded
@@ -229,11 +230,16 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
 
     @jax.jit
     def fire_fn(table, arrays, pane_rows, rows_valid, dropped):
+        # named regions (module names stay, the benchmark matches them):
+        # an op's name path (HLO op_name; tf_op in a TPU trace's op
+        # metadata) says fire.merge / fire.topk, whatever fusion number
+        # the compiler gave it
         def merge(kind, arr):
-            sub = arr[pane_rows]                        # [W, cap]
-            ident = AGG_INITS[kind](arr.dtype)
-            sub = jnp.where(rows_valid[:, None], sub, ident)
-            return AGG_MERGES[kind](sub, axis=0)
+            with jax.named_scope("fire.merge"):
+                sub = arr[pane_rows]                    # [W, cap]
+                ident = AGG_INITS[kind](arr.dtype)
+                sub = jnp.where(rows_valid[:, None], sub, ident)
+                return AGG_MERGES[kind](sub, axis=0)
 
         def merge_at(kind, arr, idx):
             # winner-only merge: ONE [W, k] two-axis gather instead of a
@@ -241,10 +247,11 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
             # ever emit, so secondary aggregates never pay the
             # full-capacity read. (NOT arr[pane_rows][:, idx]: the
             # chained form materializes the [W, cap] intermediate.)
-            sub = arr[pane_rows[:, None], idx[None, :]]
-            ident = AGG_INITS[kind](arr.dtype)
-            sub = jnp.where(rows_valid[:, None], sub, ident)
-            return AGG_MERGES[kind](sub, axis=0)
+            with jax.named_scope("fire.merge"):
+                sub = arr[pane_rows[:, None], idx[None, :]]
+                ident = AGG_INITS[kind](arr.dtype)
+                sub = jnp.where(rows_valid[:, None], sub, ident)
+                return AGG_MERGES[kind](sub, axis=0)
 
         count = merge("count", arrays["__count__"])
         emit = (table != jnp.int64(EMPTY_KEY)) & (count > 0)
@@ -260,10 +267,11 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
                 ranked = s / jnp.maximum(count, 1).astype(s.dtype)
             else:
                 ranked = merge(rk_kind, arrays[rk_name])
-            _vals, idx, ok = _masked_topk(ranked, emit, topk,
-                                          value_bits=topk_value_bits)
-            keys = jnp.take(table, idx)
-            count_k = jnp.take(count, idx)
+            with jax.named_scope("fire.topk"):
+                _vals, idx, ok = _masked_topk(ranked, emit, topk,
+                                              value_bits=topk_value_bits)
+                keys = jnp.take(table, idx)
+                count_k = jnp.take(count, idx)
             out = {}
             for kind, out_name in agg_sig:
                 if out_name == rk_name:
@@ -414,10 +422,11 @@ def _fire_inc_program(agg_sig: tuple, topk: Optional[int],
                 ranked = s / jnp.maximum(count, 1).astype(s.dtype)
             else:
                 ranked = view[rk_name]
-            _vals, idx, ok = _masked_topk(ranked, emit, topk,
-                                          value_bits=topk_value_bits)
-            keys = jnp.take(table, idx)
-            count_k = jnp.take(count, idx)
+            with jax.named_scope("fire.topk"):
+                _vals, idx, ok = _masked_topk(ranked, emit, topk,
+                                              value_bits=topk_value_bits)
+                keys = jnp.take(table, idx)
+                count_k = jnp.take(count, idx)
             out = {}
             for kind, out_name in agg_sig:
                 if out_name == rk_name:
@@ -544,11 +553,13 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # aggregate dtypes are known
         self._fused_spec = None     # (source, subtask, parallelism)
         self._fused_chain = None    # runtime.compiled.FusedChain
-        # wall-clock per hot-path stage (bench breakdown): ingest = pack +
-        # upload + fold dispatch, fire = fire dispatch, drain = result
-        # materialization + emit
+        # wall-clock per hot-path stage (bench breakdown), each the sum of
+        # its stage spans' durations (one timing site, metrics/tracing.py
+        # Stage): ingest = window/Upload + window/IngestDispatch, fire =
+        # window/FireDispatch, drain = window/Drain + window/Emit
         self.stage_s: dict[str, float] = {"ingest": 0.0, "fire": 0.0,
                                           "drain": 0.0}
+        self._batch_seq = 0  # ordinal of the batch being ingested (spans)
 
     # -- lifecycle ---------------------------------------------------------
     def setup(self, ctx: OperatorContext, output: Output) -> None:
@@ -665,6 +676,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
 
     # -- data path ---------------------------------------------------------
     def process_batch(self, batch: RecordBatch) -> None:
+        self._last_batch_ns = time.monotonic_ns()
         if batch.n == 0:
             return
         if self._coalesce_target > 1:
@@ -697,7 +709,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             batch = self._screen_nonfinite(batch)
             if batch.n == 0:
                 return
-        t0 = time.perf_counter()
+        self._batch_seq += 1
         from ...core.device_records import LazyDeviceBatch
         if (self._fused_spec is not None
                 and isinstance(batch, LazyDeviceBatch)
@@ -738,12 +750,26 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         else:
             keys = batch.column(self._key_column).astype(np.int64)
             self._ingest(batch, keys)
-        self.stage_s["ingest"] += time.perf_counter() - t0
 
     @property
     def _spill_deferred(self) -> bool:
         return (self._defer and self._backend is not None
                 and self._backend.hbm_budget > 0)
+
+    # -- stage spans of one batch (metrics/tracing.py Stage) ---------------
+    def _upload_stage(self):
+        """window/Upload: packing a host batch + its host->device copy
+        (device/H2D nests under it); the caller sets ``bytes``."""
+        return TRACER.stage("window", "Upload", seq=self._batch_seq,
+                            total=(self.stage_s, "ingest"))
+
+    def _dispatch_stage(self, programs: Optional[int] = None):
+        """window/IngestDispatch: the host's time to enqueue the batch's
+        ingest programs (the device runs them later)."""
+        attrs = {} if programs is None else {"programs": programs}
+        return TRACER.stage("window", "IngestDispatch",
+                            seq=self._batch_seq,
+                            total=(self.stage_s, "ingest"), **attrs)
 
     def _to_device_batch(self, batch: RecordBatch) -> DeviceRecordBatch:
         ts = batch.timestamps
@@ -758,11 +784,14 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
 
         # deadline-bounded idempotent upload (pure function of host data:
         # a stall-abandoned attempt re-runs safely)
-        cols, dts = stall_bounded("transfer.h2d", upload,
-                                  scope="device_window")
+        with self._upload_stage() as up:
+            cols, dts = stall_bounded("transfer.h2d", upload,
+                                      scope="device_window")
+            nbytes = pytree_nbytes(cols) + dts.nbytes
+            up.set("bytes", nbytes)
+            DEVICE_STATS.note_h2d(nbytes, batch.n)
         schema = Schema([(f.name, f.dtype) for f in batch.schema.fields
                          if f.name in cols])
-        DEVICE_STATS.note_h2d(pytree_nbytes(cols) + dts.nbytes, batch.n)
         return DeviceRecordBatch(schema, cols, dts,
                                  int(ts.min()), int(ts.max()))
 
@@ -939,8 +968,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 fo, np.int64(n))
 
         try:
-            table, new_arrays, dropped, late, dirty, stage, touch, token = \
-                self._guard.run(dispatch)
+            with self._dispatch_stage(programs=1):
+                table, new_arrays, dropped, late, dirty, stage, touch, \
+                    token = self._guard.run(dispatch)
         except DeviceSegmentError as e:
             if self._on_segment_failure(e, batch):
                 return  # poisoned batch quarantined; state untouched
@@ -1014,8 +1044,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                              self._backend.dirty_mask, fo)
 
         try:
-            table, new_arrays, dropped, late, dirty, viol, last, token = \
-                self._guard.run(dispatch)
+            with self._dispatch_stage(programs=1):
+                table, new_arrays, dropped, late, dirty, viol, last, \
+                    token = self._guard.run(dispatch)
         except DeviceSegmentError as e:
             if self._on_segment_failure(e, batch):
                 return  # poisoned batch quarantined; state untouched
@@ -1144,8 +1175,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 jnp.asarray(_pad(slots, np.int32(0))), valid, vals)
 
         try:
-            out, dirty, token = self._guard.run(
-                dispatch, sites=("transfer.h2d", "device.execute"))
+            with self._dispatch_stage(programs=1):
+                out, dirty, token = self._guard.run(
+                    dispatch, sites=("transfer.h2d", "device.execute"))
         except DeviceSegmentError as e:
             if e.poison:
                 self._dead_letter(self._host_view(batch))
@@ -1194,55 +1226,61 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             self._fold_packed(batch, keys, panes % self._ring)
             return
         ring_idx = panes % self._ring
-        slots = self._backend.slots_for_batch(keys)
-        valid = slots >= 0
-        self._backend.fold_batch("__count__", slots,
-                                 np.ones(batch.n, np.int64), valid,
-                                 ring_idx=ring_idx)
-        for a in self._aggs:
-            if a.kind == "count":
-                continue
-            col = batch.column(a.field)
-            name = f"{a.out_name}.sum" if a.kind == "avg" else a.out_name
-            self._backend.fold_batch(name, slots, col, valid,
+        with self._dispatch_stage():
+            slots = self._backend.slots_for_batch(keys)
+            valid = slots >= 0
+            self._backend.fold_batch("__count__", slots,
+                                     np.ones(batch.n, np.int64), valid,
                                      ring_idx=ring_idx)
+            for a in self._aggs:
+                if a.kind == "count":
+                    continue
+                col = batch.column(a.field)
+                name = (f"{a.out_name}.sum" if a.kind == "avg"
+                        else a.out_name)
+                self._backend.fold_batch(name, slots, col, valid,
+                                         ring_idx=ring_idx)
 
     def _fold_packed(self, batch: RecordBatch, keys: np.ndarray,
                      ring_idx: np.ndarray) -> None:
         """Pack keys + ring rows + every aggregate column into one [C, B]
         int64 buffer (floats bit-cast via float64), upload once, slice on
         device. Zero host round-trips per batch."""
-        rows = [keys, ring_idx]
-        col_meta: list[tuple[str, bool]] = []
-        for a in self._aggs:
-            if a.kind == "count":
-                continue
-            col = np.asarray(batch.column(a.field))
-            name = f"{a.out_name}.sum" if a.kind == "avg" else a.out_name
-            if np.issubdtype(col.dtype, np.floating):
-                rows.append(np.ascontiguousarray(
-                    col.astype(np.float64)).view(np.int64))
-                col_meta.append((name, True))
-            else:
-                rows.append(col.astype(np.int64))
-                col_meta.append((name, False))
-        packed = np.stack(rows)
-        buf = stall_bounded("transfer.h2d",
-                            lambda: jnp.asarray(packed),  # the ONE upload
-                            scope="device_window")
-        DEVICE_STATS.note_h2d(buf.nbytes, batch.n)
-        slots = self._backend.slots_for_batch_device(buf[0])
-        dring = buf[1]
-        valid = slots >= 0
-        self._backend.fold_batch("__count__", slots,
-                                 jnp.ones(batch.n, jnp.int64), valid,
-                                 ring_idx=dring)
-        for i, (name, is_float) in enumerate(col_meta):
-            vals = buf[2 + i]
-            if is_float:
-                vals = jax.lax.bitcast_convert_type(vals, jnp.float64)
-            self._backend.fold_batch(name, slots, vals, valid,
+        with self._upload_stage() as up:
+            rows = [keys, ring_idx]
+            col_meta: list[tuple[str, bool]] = []
+            for a in self._aggs:
+                if a.kind == "count":
+                    continue
+                col = np.asarray(batch.column(a.field))
+                name = (f"{a.out_name}.sum" if a.kind == "avg"
+                        else a.out_name)
+                if np.issubdtype(col.dtype, np.floating):
+                    rows.append(np.ascontiguousarray(
+                        col.astype(np.float64)).view(np.int64))
+                    col_meta.append((name, True))
+                else:
+                    rows.append(col.astype(np.int64))
+                    col_meta.append((name, False))
+            packed = np.stack(rows)
+            up.set("bytes", packed.nbytes)
+            buf = stall_bounded("transfer.h2d",
+                                lambda: jnp.asarray(packed),  # ONE upload
+                                scope="device_window")
+            DEVICE_STATS.note_h2d(buf.nbytes, batch.n)
+        with self._dispatch_stage(programs=2 + len(col_meta)):
+            slots = self._backend.slots_for_batch_device(buf[0])
+            dring = buf[1]
+            valid = slots >= 0
+            self._backend.fold_batch("__count__", slots,
+                                     jnp.ones(batch.n, jnp.int64), valid,
                                      ring_idx=dring)
+            for i, (name, is_float) in enumerate(col_meta):
+                vals = buf[2 + i]
+                if is_float:
+                    vals = jax.lax.bitcast_convert_type(vals, jnp.float64)
+                self._backend.fold_batch(name, slots, vals, valid,
+                                         ring_idx=dring)
 
     # -- firing (fire loop lives in SliceControlPlane) ----------------------
     # A fire is ONE compiled program (pane merge for every aggregate +
@@ -1253,15 +1291,13 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
     # fires so it never overtakes them downstream.
 
     def _fire(self, p_end: int) -> None:
-        t_fire = time.perf_counter()
         W = self._window_panes
         # never read panes below min_seen: they hold no data and their ring
-        # rows may be occupied by live FUTURE panes (row aliasing)
+        # rows may be occupied by live FUTURE panes (row aliasing); that
+        # the window has a pane at all, _fire_window has checked
         first = max(p_end - W, self._min_seen_pane)
-        if first >= p_end:
-            return
         if self._inc_enabled:
-            self._fire_incremental(p_end, first, t_fire)
+            self._fire_incremental(p_end, first)
             return
         rows = [(p % self._ring) for p in range(first, p_end)]
         DEVICE_STATS.note_fire_merge_rows(len(rows))
@@ -1299,7 +1335,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         if p_end - W >= self._min_seen_pane:
             self._backend.reset_ring_row((p_end - W) % self._ring)
         self._refresh_late(block=True)
-        self.stage_s["fire"] += time.perf_counter() - t_fire
 
     # -- incremental fire engine -------------------------------------------
     def _inc_sigs(self) -> tuple[tuple, tuple]:
@@ -1339,8 +1374,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                     ring=2 * self._tree_size, role="window")
                 self._inc_dirty = True
 
-    def _fire_incremental(self, p_end: int, first: int,
-                          t_fire: float) -> None:
+    def _fire_incremental(self, p_end: int, first: int) -> None:
         """O(capacity) fire: seal the newest pane into the running window
         state (or rebuild it from the pane planes when stale), then read
         the merged view — outputs byte-identical to the full-merge path
@@ -1418,7 +1452,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         if p_end - W >= self._min_seen_pane:
             self._backend.reset_ring_row((p_end - W) % self._ring)
         self._refresh_late(block=True)
-        self.stage_s["fire"] += time.perf_counter() - t_fire
 
     def _fire_array_names(self) -> list[str]:
         names = ["__count__"]
@@ -1451,8 +1484,18 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         return keys, res
 
     def _materialize(self, item) -> None:
-        t_drain = time.perf_counter()
-        p_end, outs, host_part, t0 = item
+        p_end, outs, host_part, t0, fire = item
+        with self._drain_stage(fire):
+            keys, results, d2h_bytes = self._drain_rows(outs, host_part)
+        if len(keys):
+            with self._emit_stage(fire, len(keys)):
+                self._emit_rows(p_end, keys, results)
+        self._note_latency(t0)
+        self._close_fire(fire, len(keys), d2h_bytes)
+
+    def _drain_rows(self, outs, host_part):
+        """One fire's rows on the host: the ONE device_get + selection /
+        canonical order. Returns (keys, results, d2h bytes)."""
         if self._guard is None or self._guard.active:
             # ONE deadline-bounded transfer for everything (device_get is
             # idempotent: a stall-abandoned read re-runs safely)
@@ -1499,10 +1542,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             keys = keys[order]
             results = {n: v[order] for n, v in results.items()}
         DEVICE_STATS.note_d2h(d2h_bytes, len(keys))
-        if len(keys):
-            self._emit_rows(p_end, keys, results)
-        self._note_latency(t0)
-        self.stage_s["drain"] += time.perf_counter() - t_drain
+        return keys, results, d2h_bytes
 
     def _emit_rows(self, p_end: int, keys: np.ndarray,
                    results: dict[str, np.ndarray]) -> None:

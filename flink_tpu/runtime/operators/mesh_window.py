@@ -40,7 +40,7 @@ from ...core.keygroups import hash_batch, key_groups_for_hash_batch
 from ...core.records import RecordBatch, Schema
 from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, make_table
 from ...ops.segment_ops import AGG_INITS, make_accumulator
-from ...metrics.device import DEVICE_STATS
+from ...metrics.device import DEVICE_STATS, pytree_nbytes
 from ...parallel.mesh import make_mesh, shard_ranges
 from ...parallel.sharded_window import (
     AggDef, ShardedWindowAgg, ShardedWindowState,
@@ -236,6 +236,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
 
     # -- data path ---------------------------------------------------------
     def process_batch(self, batch: RecordBatch) -> None:
+        self._last_batch_ns = time.monotonic_ns()
         if self._pending:
             self._drain(block=False)
         if batch.n == 0:
@@ -381,18 +382,17 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             return None
         return self._plane_name(self._aggs[0])
 
+    def _window_holds_data(self, p_end: int) -> bool:
+        return self._agg is not None and super()._window_holds_data(p_end)
+
     def _fire(self, p_end: int) -> None:
-        if self._agg is None:
-            return
-        t_fire = time.perf_counter()
         W = self._window_panes
         # never read panes below min_seen: they hold no data and their ring
-        # rows may be occupied by live FUTURE panes (row aliasing)
+        # rows may be occupied by live FUTURE panes (row aliasing); that
+        # the window has a pane at all, _fire_window has checked
         first = max(p_end - W, self._min_seen_pane)
-        if first >= p_end:
-            return
         if self._inc_enabled:
-            self._fire_incremental(p_end, first, t_fire)
+            self._fire_incremental(p_end, first)
             return
         rows = [(p % self._ring) for p in range(first, p_end)]
         # constant [W] shape so the fire program compiles once
@@ -407,11 +407,8 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         if p_end - W >= self._min_seen_pane:
             self._state = self._agg.retire_row(self._state,
                                                (p_end - W) % self._ring)
-        self.stage_s["fire"] = self.stage_s.get("fire", 0.0) + (
-            time.perf_counter() - t_fire)
 
-    def _fire_incremental(self, p_end: int, first: int,
-                          t_fire: float) -> None:
+    def _fire_incremental(self, p_end: int, first: int) -> None:
         """O(capacity) fire: consume the running window view kept by the
         pane-seal programs instead of re-merging all W ring rows. Dirty
         state (restore, grow, boundary jump, write into a sealed pane)
@@ -452,29 +449,31 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         if p_end - W >= self._min_seen_pane:
             self._state = self._agg.retire_row(self._state,
                                                (p_end - W) % self._ring)
-        self.stage_s["fire"] = self.stage_s.get("fire", 0.0) + (
-            time.perf_counter() - t_fire)
 
     def _materialize(self, item: tuple) -> None:
-        p_end, outs, _unused, t0 = item
-        host = jax.device_get(outs)       # ONE transfer for everything
-        if self._topk is not None:
-            keys_k, ok, results, dropped, occ = host
-            self._apply_health(dropped, occ)
-            sel = np.asarray(ok)
-            keys = np.asarray(keys_k)[sel]
-            res = {n: np.asarray(v)[sel] for n, v in results.items()}
-        else:
-            table, emit, results, dropped, occ = host
-            self._apply_health(dropped, occ)
-            mask = np.asarray(emit).reshape(-1)
-            idx = np.flatnonzero(mask)
-            keys = np.asarray(table).reshape(-1)[idx]
-            res = {n: np.asarray(v).reshape(-1)[idx]
-                   for n, v in results.items()}
+        p_end, outs, _unused, t0, fire = item
+        with self._drain_stage(fire):
+            host = jax.device_get(outs)   # ONE transfer for everything
+            d2h_bytes = pytree_nbytes(host)
+            if self._topk is not None:
+                keys_k, ok, results, dropped, occ = host
+                self._apply_health(dropped, occ)
+                sel = np.asarray(ok)
+                keys = np.asarray(keys_k)[sel]
+                res = {n: np.asarray(v)[sel] for n, v in results.items()}
+            else:
+                table, emit, results, dropped, occ = host
+                self._apply_health(dropped, occ)
+                mask = np.asarray(emit).reshape(-1)
+                idx = np.flatnonzero(mask)
+                keys = np.asarray(table).reshape(-1)[idx]
+                res = {n: np.asarray(v).reshape(-1)[idx]
+                       for n, v in results.items()}
         if len(keys):
-            self._emit_rows(p_end, keys, res)
+            with self._emit_stage(fire, len(keys)):
+                self._emit_rows(p_end, keys, res)
         self._note_latency(t0)
+        self._close_fire(fire, len(keys), d2h_bytes)
 
     def _emit_rows(self, p_end: int, keys: np.ndarray, host: dict) -> None:
         count_name = next(a.name for a in self._agg.aggs

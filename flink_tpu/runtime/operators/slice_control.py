@@ -27,6 +27,7 @@ import numpy as np
 
 from ...core.elements import Watermark
 from ...core.records import RecordBatch
+from ...metrics.tracing import TRACER, Stage
 
 __all__ = ["SliceControlPlane", "AsyncFireQueue", "CoalescingIngest"]
 
@@ -118,22 +119,52 @@ class AsyncFireQueue:
     the copy lands, and watermarks are held behind their fires so they
     never overtake results downstream. The hot loop never blocks on a
     fire. Subclasses implement ``_materialize(item)``; an item is a tuple
-    whose second element is the fire's device-output pytree."""
+    (pane boundary, device-output pytree, ..., the window's open
+    ``window/Fire`` stage): the stage is the root of one span tree per
+    fired window, which ``_materialize`` parents its ``window/Drain`` and
+    ``window/Emit`` on, from a later mailbox turn, and closes once the
+    window's rows are emitted."""
 
     _async: bool
 
     def _init_async_fires(self) -> None:
         self._pending: deque = deque()
+        # the window/Fire stage of the fire being dispatched right now,
+        # until _enqueue_fire takes it into the pending item
+        self._cur_fire: Optional[Stage] = None
 
     def _enqueue_fire(self, item: tuple) -> None:
         import jax
 
         for leaf in jax.tree_util.tree_leaves(item[1]):
             leaf.copy_to_host_async()
-        if self._async:
-            self._pending.append(item)
-        else:
-            self._materialize(item)
+        # synchronous fires queue too: _fire_window drains them as soon
+        # as the dispatch stage is over, so Drain and Emit follow
+        # FireDispatch inside the window's Fire in both modes
+        fire, self._cur_fire = self._cur_fire, None
+        self._pending.append((*item, fire))
+
+    def _open_fire_stage(self, p_end: int) -> Stage:
+        end_ms = p_end * self._pane + self._offset
+        return TRACER.open_stage("window", "Fire", seq=end_ms,
+                                 window_end_ms=end_ms)
+
+    def _drain_stage(self, fire: Stage) -> Stage:
+        """window/Drain: the device_get of a fire's outputs + the host
+        selection / sort, a child of the window's Fire."""
+        return TRACER.stage("window", "Drain", parent=fire.context,
+                            seq=fire.attrs["seq"],
+                            total=(self.stage_s, "drain"))
+
+    def _emit_stage(self, fire: Stage, rows: int) -> Stage:
+        """window/Emit: building the window's rows + output.emit."""
+        return TRACER.stage("window", "Emit", parent=fire.context,
+                            seq=fire.attrs["seq"], rows=rows,
+                            total=(self.stage_s, "drain"))
+
+    def _close_fire(self, fire: Stage, rows: int, d2h_bytes: int) -> None:
+        fire.close(rows=rows, d2h_bytes=d2h_bytes,
+                   unready_polls=fire.attrs.get("unready_polls", 0))
 
     def _drain(self, block: bool = False) -> None:
         import jax
@@ -147,6 +178,9 @@ class AsyncFireQueue:
             if not block and not all(
                     leaf.is_ready()
                     for leaf in jax.tree_util.tree_leaves(head[1])):
+                head[-1].count("unready_polls")
+                from ...metrics.device import DEVICE_STATS
+                DEVICE_STATS.note_fire_unready_poll()
                 return
             self._pending.popleft()
             self._materialize(head)
@@ -186,6 +220,10 @@ class SliceControlPlane:
         # dispatch->drain themselves.
         self.fire_latencies_ms: list[float] = []
         self._record_fire_latency = True
+        # host clock at the start of this operator's last batch and the
+        # ordinal of its watermarks (window/Watermark since_batch_ms, seq)
+        self._last_batch_ns = 0
+        self._watermarks = 0
 
     # -- metadata ----------------------------------------------------------
     def _control_meta(self) -> dict:
@@ -250,6 +288,20 @@ class SliceControlPlane:
 
     # -- firing ------------------------------------------------------------
     def process_watermark(self, watermark: Watermark) -> None:
+        self._watermarks += 1
+        # how long after this operator's last batch began the watermark
+        # reached it (-1 before any batch)
+        since_batch_ms = (
+            round((time.monotonic_ns() - self._last_batch_ns) / 1e6, 3)
+            if self._last_batch_ns else -1.0)
+        with TRACER.stage("window", "Watermark", seq=self._watermarks,
+                          watermark_ms=watermark.timestamp,
+                          since_batch_ms=since_batch_ms) as turn:
+            turn.set("fires", self._process_watermark(watermark))
+
+    def _process_watermark(self, watermark: Watermark) -> int:
+        """Returns how many windows the watermark fired."""
+        fires = 0
         self.current_watermark = watermark.timestamp
         self._pre_fire_flush()
         # a window ending at pane boundary p_end fires when
@@ -263,12 +315,7 @@ class SliceControlPlane:
                 start = max(start, self._fired_boundary)
             last = min(wm_pane_end, self._max_seen_pane + self._window_panes)
             for p_end in range(start, last + 1):
-                t0 = time.perf_counter()
-                self._fire(p_end)
-                if (self._record_fire_latency
-                        and len(self.fire_latencies_ms) < _MAX_FIRE_SAMPLES):
-                    self.fire_latencies_ms.append(
-                        (time.perf_counter() - t0) * 1e3)
+                fires += self._fire_window(p_end)
         # the boundary tracks the watermark even when no data has arrived
         # yet or no window fired, so records behind the watermark are
         # dropped as late exactly like the host operator
@@ -276,6 +323,38 @@ class SliceControlPlane:
                 or wm_pane_end + 1 > self._fired_boundary):
             self._fired_boundary = wm_pane_end + 1
         self._emit_watermark_out(watermark)
+        return fires
+
+    def _window_holds_data(self, p_end: int) -> bool:
+        """Whether the window ending at pane boundary ``p_end`` has a
+        pane to read: never one below min_seen (those hold no data and
+        their ring rows may alias live FUTURE panes)."""
+        return max(p_end - self._window_panes, self._min_seen_pane) < p_end
+
+    def _fire_window(self, p_end: int) -> bool:
+        """One window's fire as the start of its span tree: the root
+        window/Fire stays open in the pending item until the window's
+        rows are emitted (a later mailbox turn when fires are async);
+        window/FireDispatch, its first child, is the host's part now. A
+        window that holds no data opens no stage."""
+        if not self._window_holds_data(p_end):
+            return False
+        fire = self._cur_fire = self._open_fire_stage(p_end)
+        with TRACER.stage("window", "FireDispatch", parent=fire.context,
+                          seq=fire.attrs["seq"],
+                          total=(self.stage_s, "fire")):
+            self._fire(p_end)
+        fired = self._cur_fire is None       # _enqueue_fire took it
+        if not fired:
+            # _fire enqueued nothing after all: the tree ends here
+            self._cur_fire = None
+            self._close_fire(fire, 0, 0)
+        elif not self._async:
+            self._drain(block=True)
+        if (self._record_fire_latency
+                and len(self.fire_latencies_ms) < _MAX_FIRE_SAMPLES):
+            self.fire_latencies_ms.append(fire.duration_ms)
+        return fired
 
     def _emit_watermark_out(self, watermark: Watermark) -> None:
         """Hook: async-firing operators hold the watermark behind its

@@ -33,7 +33,7 @@ from ..core.elements import (
 from ..core.records import MIN_TIMESTAMP, RecordBatch
 from ..core.watermarks import WatermarkStrategy
 from ..connectors.core import SinkWriter, Source, SourceReader
-from ..metrics.tracing import TRACER, TraceContext, now_ms
+from ..metrics.tracing import TRACER, TraceContext, now_ms, now_ns
 from ..state.backend import OperatorStateBackend
 from .channels import GateEvent, InputGate
 from .operators.base import OperatorChain, OperatorContext, Output
@@ -198,11 +198,22 @@ class StreamTask:
         # input and routes the task into the restart path
         from .watchdog import TaskProgress
         self.progress = TaskProgress()
+        # the open task/WaitInput stage (first empty poll -> next event),
+        # how many polls it has seen, and the ordinals its spans carry
+        self._wait = None
+        self._wait_polls = 0
+        self._waits = 0
+        self._batches = 0
         metrics = getattr(ctx, "metrics", None)
         if metrics is not None and hasattr(metrics, "bind_io_timers"):
             metrics.bind_io_timers(self.io_timers)
         if metrics is not None and hasattr(metrics, "bind_progress"):
             metrics.bind_progress(self.progress)
+
+    def _bind_gate_metrics(self, gates: list) -> None:
+        metrics = getattr(self.ctx, "metrics", None)
+        if metrics is not None and hasattr(metrics, "bind_input_gates"):
+            metrics.bind_input_gates(gates)
 
     def all_writers(self):
         yield from self.writers
@@ -279,6 +290,34 @@ class StreamTask:
         no gate and are never flagged (a quiet source is idle by
         definition; its blocking sites are watchdogged individually)."""
         return False
+
+    # -- input wait (task/WaitInput) ---------------------------------------
+    def _note_empty_poll(self) -> None:
+        """An input poll found nothing: the first one of a run opens the
+        task/WaitInput stage, the rest only count."""
+        if self._wait is None:
+            self._waits += 1
+            self._wait = TRACER.open_stage("task", "WaitInput",
+                                           seq=self._waits)
+            self._wait_polls = 0
+        self._wait_polls += 1
+
+    def _end_wait(self) -> None:
+        """The next event arrived (or the input ended): close the wait;
+        its measured length is the task's idle time."""
+        wait = self._wait
+        if wait is not None:
+            self._wait = None
+            wait.close(polls=self._wait_polls)
+            self.io_timers.idle_s += wait.duration_s
+
+    def _process_batch_stage(self, ev: GateEvent, gate: InputGate):
+        """The task/ProcessBatch stage around one dequeued batch."""
+        self._batches += 1
+        return TRACER.stage("task", "ProcessBatch", seq=self._batches,
+                            rows=ev.value.n,
+                            queued_ms=round(ev.queued_ns / 1e6, 3),
+                            queue_depth=gate.queue_depth())
 
     # -- helpers -----------------------------------------------------------
     def _advance_processing_time(self, chain: Optional[OperatorChain]) -> None:
@@ -508,14 +547,21 @@ class SourceStreamTask(StreamTask):
                     break
                 if verdict == "shed":
                     continue
-            t0 = time.perf_counter()
+            # a read is a task/SourceBatch stage only once it returns rows:
+            # an unbounded or paced source is asked about 1 kHz while it
+            # has nothing, and stages never run per poll
+            read_ns = now_ns()
             batch = self.reader.read_batch(self.current_batch_size)
-            read_dt = time.perf_counter() - t0
+            read_dt = (now_ns() - read_ns) / 1e9
             self.stage_s["read"] += read_dt
             self.io_timers.busy_s += read_dt
             if batch is None:  # exhausted (bounded)
                 break
             if batch.n:
+                cycle = TRACER.stage("task", "SourceBatch",
+                                     start_ns=read_ns,
+                                     seq=self._batches + 1, records=batch.n,
+                                     read_ms=round(read_dt * 1e3, 3))
                 if self.ctx.metrics is not None:
                     self.ctx.metrics.records_in.inc(batch.n)
                 batch = self.ws.assign_timestamps(batch)
@@ -524,25 +570,16 @@ class SourceStreamTask(StreamTask):
                 if idle:
                     idle = False
                     self.broadcast_all(WatermarkStatus(True))
-                t0 = time.perf_counter()
                 if self.chain is not None:
                     self.chain.process_batch(batch)
                 else:
                     out.emit(batch)
-                emit_dt = time.perf_counter() - t0
+                self._batches += 1
+                emit_dt = cycle.duration_s - read_dt
+                cycle.close(emit_ms=round(emit_dt * 1e3, 3))
                 self.stage_s["emit"] += emit_dt
                 self.io_timers.busy_s += emit_dt
                 self.progress.bump()
-                if TRACER.enabled:
-                    # one mailbox-loop cycle: read + chain/emit phases
-                    end = now_ms()
-                    (TRACER.span("task", "SourceBatch")
-                     .set_attribute("task", self.task_id)
-                     .set_attribute("records", batch.n)
-                     .set_attribute("read_ms", round(read_dt * 1e3, 3))
-                     .set_attribute("emit_ms", round(emit_dt * 1e3, 3))
-                     .set_start_ts(end - int((read_dt + emit_dt) * 1e3))
-                     .finish(end))
                 if adaptive:
                     # desired = throughput x target; EMA toward it. At the
                     # fixpoint one batch takes exactly target seconds.
@@ -614,6 +651,7 @@ class TwoInputStreamTask(StreamTask):
         super().__init__(task_id, ctx, writers, reporter, config)
         self.gates = [gate1, gate2]
         self.chain = chain
+        self._bind_gate_metrics(self.gates)
         self._gate_barrier: list = [None, None]
         self._unaligned_pending = None
         self._restored_inflight: list[list] = [[], []]
@@ -712,27 +750,32 @@ class TwoInputStreamTask(StreamTask):
             if ev is None:
                 if all(g.all_ended() for g in self.gates):
                     break
+                self._note_empty_poll()
                 self._advance_processing_time(self.chain)
                 time.sleep(0.0005)
-                self.io_timers.idle_s += 0.0005
                 continue
-            t0 = time.perf_counter()
+            self._end_wait()
             if ev.kind == "batch":
                 if self.ctx.metrics is not None:
                     self.ctx.metrics.records_in.inc(ev.value.n)
-                self.chain.process_batch_n(gi, ev.value)
-            elif ev.kind == "watermark":
-                self.chain.process_watermark_n(gi, ev.value)
-            elif ev.kind == "barrier":
-                self._on_barrier(gi, ev.value)
-            elif ev.kind == "latency":
-                self.chain.process_latency_marker(ev.value)
-            elif ev.kind == "idle":
-                self.broadcast_all(ev.value)
-            self.io_timers.busy_s += time.perf_counter() - t0
+                with self._process_batch_stage(ev, self.gates[gi]) as turn:
+                    self.chain.process_batch_n(gi, ev.value)
+                self.io_timers.busy_s += turn.duration_s
+            else:
+                t0 = time.perf_counter()
+                if ev.kind == "watermark":
+                    self.chain.process_watermark_n(gi, ev.value)
+                elif ev.kind == "barrier":
+                    self._on_barrier(gi, ev.value)
+                elif ev.kind == "latency":
+                    self.chain.process_latency_marker(ev.value)
+                elif ev.kind == "idle":
+                    self.broadcast_all(ev.value)
+                self.io_timers.busy_s += time.perf_counter() - t0
             self.progress.bump()
             self._advance_processing_time(self.chain)
 
+        self._end_wait()
         if not self._cancelled.is_set():
             self._maybe_finish_unaligned()
             self.chain.finish()
@@ -753,6 +796,7 @@ class OneInputStreamTask(StreamTask):
         super().__init__(task_id, ctx, writers, reporter, config)
         self.gate = gate
         self.chain = chain
+        self._bind_gate_metrics([gate])
         self._restored_inflight: list = []
         self._unaligned_pending = None  # (barrier, snapshot) awaiting capture
 
@@ -816,30 +860,35 @@ class OneInputStreamTask(StreamTask):
                 self._maybe_finish_unaligned()
                 if self.gate.all_ended():
                     break
+                self._note_empty_poll()
                 self._advance_processing_time(self.chain)
                 time.sleep(0.0005)
-                self.io_timers.idle_s += 0.0005
                 continue
-            t0 = time.perf_counter()
+            self._end_wait()
             if ev.kind == "batch":
                 if self.ctx.metrics is not None:
                     self.ctx.metrics.records_in.inc(ev.value.n)
-                self.chain.process_batch(ev.value)
-            elif ev.kind == "watermark":
-                self.chain.process_watermark(ev.value)
-            elif ev.kind == "barrier":
-                self._on_barrier(ev.value)
-            elif ev.kind == "latency":
-                # through the chain, not past it: every operator records
-                # its source->here latency before forwarding downstream
-                self.chain.process_latency_marker(ev.value)
-            elif ev.kind == "idle":
-                self.broadcast_all(ev.value)
-            self.io_timers.busy_s += time.perf_counter() - t0
+                with self._process_batch_stage(ev, self.gate) as turn:
+                    self.chain.process_batch(ev.value)
+                self.io_timers.busy_s += turn.duration_s
+            else:
+                t0 = time.perf_counter()
+                if ev.kind == "watermark":
+                    self.chain.process_watermark(ev.value)
+                elif ev.kind == "barrier":
+                    self._on_barrier(ev.value)
+                elif ev.kind == "latency":
+                    # through the chain, not past it: every operator
+                    # records its source->here latency before forwarding
+                    self.chain.process_latency_marker(ev.value)
+                elif ev.kind == "idle":
+                    self.broadcast_all(ev.value)
+                self.io_timers.busy_s += time.perf_counter() - t0
             self.progress.bump()
             self._maybe_finish_unaligned()
             self._advance_processing_time(self.chain)
 
+        self._end_wait()
         if not self._cancelled.is_set():
             self._maybe_finish_unaligned()
             self.chain.finish()

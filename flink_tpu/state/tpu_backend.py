@@ -91,10 +91,12 @@ def _reset_row_program(sig: tuple):
     @partial(jax.jit, donate_argnums=donate)
     def reset(arrays: tuple, row):
         out = []
-        for (kind, _dt, _shape), a in zip(sig, arrays):
-            fill = jnp.full((1,) + a.shape[1:], AGG_INITS[kind](a.dtype),
-                            a.dtype)
-            out.append(jax.lax.dynamic_update_slice_in_dim(a, fill, row, 0))
+        with jax.named_scope("fire.reset"):
+            for (kind, _dt, _shape), a in zip(sig, arrays):
+                fill = jnp.full((1,) + a.shape[1:],
+                                AGG_INITS[kind](a.dtype), a.dtype)
+                out.append(
+                    jax.lax.dynamic_update_slice_in_dim(a, fill, row, 0))
         return tuple(out)
 
     return reset

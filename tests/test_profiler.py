@@ -377,7 +377,7 @@ def test_trace_counters_render_as_chrome_counter_tracks(ledger):
     trace = chrome_trace_events([], counters=counters)
     tracks = [e for e in trace["traceEvents"] if e["ph"] == "C"]
     assert {e["name"] for e in tracks} \
-        == {"device_ms:mesh.step", "device_ms:mesh.fire"}
+        == {"dispatch_ms:mesh.step", "dispatch_ms:mesh.fire"}
     assert tracks[0]["args"]["ms"] == pytest.approx(1.25)
     json.dumps(trace)  # must stay serialisable
 

@@ -109,7 +109,7 @@ def test_monotonic_clock_clamps_backwards_end():
     handing a skewed end never yields a negative duration."""
     mem = InMemoryTraceReporter()
     sb = Tracer([mem]).span("unit", "Clamp")
-    sp = sb.finish(end_ms=sb._start_ms - 500)
+    sp = sb.finish(end_ms=sb._start_ns // 1_000_000 - 500)
     assert sp.end_ms == sp.start_ms and sp.duration_ms == 0
     # and now_ms tracks epoch time closely enough to line up with logs
     from flink_tpu.metrics.tracing import now_ms
@@ -461,7 +461,7 @@ def _valid_trace_event_json(doc: dict) -> None:
             continue
         if ev["ph"] == "C":
             # device-time ledger counter tracks (one per dispatch site)
-            assert ev["name"].startswith("device_ms:")
+            assert ev["name"].startswith("dispatch_ms:")
             assert ev["cat"] == "profiler"
             assert isinstance(ev["ts"], int) and ev["ts"] > 0
             assert isinstance(ev["args"]["ms"], (int, float))
