@@ -83,6 +83,15 @@ class DeviceStats:
         # drain accounting (PR 25): non-blocking drains that found the
         # oldest queued fire's device->host copy not landed yet
         self._fire_unready_polls = 0
+        # hash-probe accounting (PR 26): rows probed by the deferred ingest
+        # path, rows still unresolved after the probe's read-only first
+        # window (the tail its claiming loop carries), and batches whose
+        # tail did not fit the narrow loop and looped at full width. Device
+        # counters, handed over without a sync (state/tpu_backend.py), so
+        # they trail the device by a batch or two until a blocking flush
+        self._probe_rows = 0
+        self._probe_tail_rows = 0
+        self._probe_wide_batches = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -328,6 +337,20 @@ class DeviceStats:
     def fire_unready_polls(self) -> int:
         with self._lock:
             return self._fire_unready_polls
+
+    def note_probe(self, rows: int, tail_rows: int,
+                   wide_batches: int) -> None:
+        with self._lock:
+            self._probe_rows += int(rows)
+            self._probe_tail_rows += int(tail_rows)
+            self._probe_wide_batches += int(wide_batches)
+
+    @property
+    def probe_counts(self) -> tuple[int, int, int]:
+        """(rows probed, tail rows, wide batches)."""
+        with self._lock:
+            return (self._probe_rows, self._probe_tail_rows,
+                    self._probe_wide_batches)
 
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
@@ -584,6 +607,9 @@ class DeviceStats:
                 "batches_coalesced_total": self._batches_coalesced,
                 "fire_merge_rows_read": self._fire_merge_rows,
                 "fire_unready_polls_total": self._fire_unready_polls,
+                "probe_rows_total": self._probe_rows,
+                "probe_tail_rows_total": self._probe_tail_rows,
+                "probe_wide_batches_total": self._probe_wide_batches,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -686,6 +712,8 @@ class DeviceStats:
             self._batches_coalesced = 0
             self._fire_merge_rows = 0
             self._fire_unready_polls = 0
+            self._probe_rows = self._probe_tail_rows = 0
+            self._probe_wide_batches = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -1096,6 +1124,12 @@ def bind_device_metrics(registry) -> None:
     # async fire drain (prometheus:
     # flink_tpu_device_fire_unready_polls_total)
     g.gauge("fire_unready_polls_total", lambda: s.fire_unready_polls)
+    # hash probe (prometheus: flink_tpu_device_probe_rows_total /
+    # flink_tpu_device_probe_tail_rows_total /
+    # flink_tpu_device_probe_wide_batches_total)
+    g.gauge("probe_rows_total", lambda: s.probe_counts[0])
+    g.gauge("probe_tail_rows_total", lambda: s.probe_counts[1])
+    g.gauge("probe_wide_batches_total", lambda: s.probe_counts[2])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

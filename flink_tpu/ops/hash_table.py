@@ -6,22 +6,33 @@ slot; this table maps unbounded keys onto those static-shape arrays entirely
 on device, so the per-batch hot path never touches the host.
 
 Algorithm: linear probing over a power-of-two table with a vectorized
-parallel insert, probing in CHUNK-slot windows. Each iteration, every
-unresolved record gathers its next CHUNK consecutive probe slots in one
-[B, CHUNK] read (consecutive slots share cache lines / vector lanes, so a
-window costs little more than a single slot — measured 2.3x over one-slot
-probing at 50% load on CPU) and resolves the window at once: the first
-match wins; otherwise records that see EMPTY race to claim the window's
-FIRST empty slot with a single ``scatter-min`` (deterministic winner =
-smallest key); losers resume from the contested slot. Claims only target
-slots read as EMPTY in the same iteration, so occupied slots are never
-corrupted; duplicate keys follow identical probe sequences and claim the
-same first-empty slot (the loser sees its own key and resolves). The
-insert-only invariant (empties never reappear) guarantees a present key
-can never sit behind an empty slot in its probe sequence, so
-first-match-before-first-empty decides containment. Bounded probe count
-returns an ``ok`` mask instead of looping forever (host rehashes on
-overflow).
+parallel insert, probing in CHUNK-slot windows. A probe round gathers, for
+every row it carries, the next CHUNK consecutive probe slots in one
+[rows, CHUNK] read and resolves the window at once: the first match wins;
+otherwise rows that see EMPTY race to claim the window's FIRST empty slot
+with a single ``scatter-min`` (deterministic winner = smallest key); losers
+resume from the contested slot. Claims only target slots read as EMPTY in
+the same round, so occupied slots are never corrupted; duplicate keys
+follow identical probe sequences and claim the same first-empty slot (the
+loser sees its own key and resolves). The insert-only invariant (empties
+never reappear) guarantees a present key can never sit behind an empty
+slot in its probe sequence, so first-match-before-first-empty decides
+containment. Bounded probe count returns an ``ok`` mask instead of looping
+forever (host rehashes on overflow).
+
+What a round costs on the chip (TPU v5e, 2^24 slots, PERF.md sections 5
+and 6): per row carried, about 110 ns for the window's two gathers (the
+int64 table is two 32-bit halves) and about 100 ns for the claim, whatever
+the width, and the row with the longest probe chain sets the round count
+for every row beside it. At load 0.6 that is 5 to 9 rounds while 98.6% of
+resident keys sit within CHUNK slots of their home. So ``lookup_or_insert``
+carries a row only while it is unresolved: one read-only window at full
+width, then the rounds over the compacted tail (1-2% of a batch of
+resident keys), at full width only when the tail does not fit (mostly new
+keys: cold start, prefill, growth). CHUNK = 8 is the window at which those
+shares were measured; it was first sized for a CPU cache line (2.3x over
+one-slot probing at 50% load on the CPU), which is no argument here: on
+the chip a window costs what its CHUNK gathered elements cost.
 
 Keys are int64 with EMPTY = int64 max as the sentinel (a real key equal to
 the sentinel is remapped by the caller — see state/tpu_backend.py).
@@ -54,7 +65,19 @@ __all__ = ["EMPTY_KEY", "make_table", "lookup", "lookup_or_insert",
 
 EMPTY_KEY = np.int64(np.iinfo(np.int64).max)
 MAX_PROBES = 128
-CHUNK = 8  # probe-window width: one 64-byte cache line of int64 slots
+CHUNK = 8  # probe-window width, in slots (see the module docstring)
+# Batches of at least this many rows compact their unresolved rows after the
+# first window. Measured on the v5e against a 2^24-slot table at load 0.6,
+# resident keys (PERF.md section 6, PR 26): 2^12 rows 3.6 ms for 9.4, 2^14
+# 6.4 for 27.6, 2^16 26 for 150; at 2^10 within a tenth (2.7 for 3.1), at
+# 2^8 the plain loop wins (2.2 for 2.3): fixed per-op costs.
+_COMPACT_MIN_ROWS = 1 << 12
+# The narrow loop's widths are n >> s, narrowest first. n / 64 holds the tail of a batch of
+# resident keys at load 0.6 (1.4% of its distinct keys' rows) with one hot
+# key displaced; n / 16 holds what a dozen displaced hot keys add. Two
+# widths because a round costs what its lanes cost: 48.5 ms a 2^18-row
+# batch with both against 73.0 with n / 16 alone (508 before).
+_TAIL_SHIFTS = (6, 4)
 
 
 def sanitize_keys_device(keys: jax.Array) -> jax.Array:
@@ -90,110 +113,185 @@ def hash_keys_device(keys: jax.Array) -> jax.Array:
     return h
 
 
+def _window(table: jax.Array, keys: jax.Array, h0: jax.Array,
+            base: jax.Array, mask: jax.Array):
+    """One CHUNK-slot window of every row's probe sequence, read and matched:
+    (hit, fslot, pos_empty, eslot). ``hit``: the row's key sits in the window
+    before its first EMPTY (``fslot`` is where); ``pos_empty``: offset of the
+    window's first EMPTY (CHUNK if none) and ``eslot`` its slot. The ONE
+    window read that ``lookup`` and every round of ``lookup_or_insert``
+    share; its ops carry probe.gather in their name path (HLO op_name; the
+    tf_op stat of an op's metadata in a TPU trace), whatever fusion numbers
+    the compiler assigns."""
+    offs = jnp.arange(CHUNK, dtype=jnp.uint32)
+    rng = jnp.arange(CHUNK, dtype=jnp.int32)
+    C = jnp.int32(CHUNK)
+    with jax.named_scope("probe.gather"):
+        idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
+            jnp.int32)
+        entry = table[idx]                                   # [n, CHUNK]
+        is_key = entry == keys[:, None]
+        is_empty = entry == jnp.int64(EMPTY_KEY)
+        pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
+        pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
+        # (the slots by take_along_axis, not by arithmetic on the offsets:
+        # 3.7 ms of a 34 ms window at n = 2^18 on the v5e, but with the
+        # arithmetic the compiler kept only one half of the 64-bit table in
+        # its fast memory space and the window's two gathers took 48 ms
+        # for 30: PERF.md section 6, PR 26)
+        fslot = jnp.take_along_axis(
+            idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
+        eslot = jnp.take_along_axis(
+            idx, jnp.minimum(pos_empty, C - 1)[:, None], axis=1)[:, 0]
+    return pos_found < pos_empty, fslot, pos_empty, eslot
+
+
+def _unfinished(base: jax.Array, done: jax.Array) -> jax.Array:
+    return ((~done) & (base < MAX_PROBES)).any()
+
+
 @jax.jit
 def lookup(table_keys: jax.Array, keys: jax.Array) -> jax.Array:
     """Find slots for keys; -1 where absent. Vectorized bounded probing in
     CHUNK-slot windows (first empty before first match => absent)."""
-    cap = table_keys.shape[0]
-    mask = jnp.uint32(cap - 1)
+    mask = jnp.uint32(table_keys.shape[0] - 1)
     h0 = hash_keys_device(keys) & mask
     n = keys.shape[0]
-    offs = jnp.arange(CHUNK, dtype=jnp.uint32)
-    rng = jnp.arange(CHUNK, dtype=jnp.int32)
-    C = jnp.int32(CHUNK)
 
     def body(state):
         base, slot, done = state
-        with jax.named_scope("probe.gather"):
-            idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
-                jnp.int32)
-            entry = table_keys[idx]                          # [n, CHUNK]
-            is_key = entry == keys[:, None]
-            is_empty = entry == jnp.int64(EMPTY_KEY)
-            pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
-            pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
-            found = (~done) & (pos_found < pos_empty)
-            fslot = jnp.take_along_axis(
-                idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
-        slot = jnp.where(found, fslot, slot)
-        done = done | found | (pos_empty < C)  # empty first => absent
+        hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base, mask)
+        slot = jnp.where((~done) & hit, fslot, slot)
+        done = done | hit | (pos_empty < CHUNK)  # empty first => absent
         base = jnp.where(done, base, base + jnp.uint32(CHUNK))
         return base, slot, done
 
-    def cond(state):
-        base, _slot, done = state
-        return ((~done) & (base < MAX_PROBES)).any()
-
     init = (jnp.zeros(n, jnp.uint32), jnp.full(n, -1, jnp.int32),
             jnp.zeros(n, bool))
-    _, slot, _ = jax.lax.while_loop(cond, body, init)
+    _, slot, _ = jax.lax.while_loop(lambda s: _unfinished(s[0], s[2]), body,
+                                    init)
     return slot
 
 
-@jax.jit
+def _advance(base: jax.Array, done: jax.Array,
+             pos_empty: jax.Array) -> jax.Array:
+    """Where an unresolved row reads next: from the window's first EMPTY
+    (which it wanted, and lost or has yet to claim), else the next window."""
+    step = jnp.where(pos_empty < CHUNK, pos_empty, CHUNK).astype(jnp.uint32)
+    return jnp.where(done, base, base + step)
+
+
+def _claim_loop(table: jax.Array, keys: jax.Array, h0: jax.Array,
+                mask: jax.Array, base: jax.Array, slot: jax.Array,
+                done: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Probe rounds until every row is resolved or out of probes, at the
+    width of ``keys``: read a window, take a match, else claim the window's
+    first EMPTY with one ``scatter-min`` (smallest key wins; losers resume
+    from the contested slot). Returns (table, slot)."""
+
+    def body(state):
+        table, base, slot, done = state
+        hit, fslot, pos_empty, eslot = _window(table, keys, h0, base, mask)
+        found = (~done) & hit
+        # the scatter-min stays DIRECTLY in the loop body under probe.claim:
+        # the benchmark's probe_rounds_p50 counts the loop's rounds by the
+        # name path /while/body/probe.claim/scatter-min
+        with jax.named_scope("probe.claim"):
+            want = (~done) & ~hit & (pos_empty < CHUNK)
+            claim_idx = jnp.where(want, eslot, jnp.int32(0))
+            claim_val = jnp.where(want, keys, jnp.int64(EMPTY_KEY))
+            table = table.at[claim_idx].min(claim_val)
+            won = want & (table[eslot] == keys)
+        slot = jnp.where(found, fslot, jnp.where(won, eslot, slot))
+        done = done | found | won
+        return table, _advance(base, done, pos_empty), slot, done
+
+    table, _base, slot, _done = jax.lax.while_loop(
+        lambda s: _unfinished(s[1], s[3]), body, (table, base, slot, done))
+    return table, slot
+
+
+def _tail_widths(n: int) -> tuple[int, ...]:
+    """Widths of the narrow loop for a batch of ``n`` rows, ascending;
+    none where the batch is too small for compaction to pay (it then
+    probes at full width from the start, claiming in every round)."""
+    if n < _COMPACT_MIN_ROWS:
+        return ()
+    return tuple(n >> s for s in _TAIL_SHIFTS)
+
+
+@partial(jax.jit, static_argnames=("stats",))
 def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
-                     valid: jax.Array | None = None
-                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                     valid: jax.Array | None = None, stats: bool = False):
     """Find-or-claim slots for a batch of keys.
 
     Returns (new_table_keys, slots int32, ok bool). Records that exhaust
     MAX_PROBES report ok=False with slot=-1 (host should rehash bigger).
     Rows where ``valid`` is False never probe or claim (slot=-1, ok=False) —
     the sharded exchange feeds padded batches through here.
+
+    A batch of ``_COMPACT_MIN_ROWS`` or more reads its first window at full
+    width WITHOUT claiming (in steady state nearly every key is resident
+    there and nobody wants a slot), then compacts the rows still
+    unresolved into the narrowest of ``_tail_widths(n)`` that holds them
+    and runs the probe rounds over those lanes alone; with more unresolved
+    rows than the widest (cold start, prefill, growth) the rounds run at
+    full width from where the first window left off. One program either
+    way (a ``lax.switch`` on a device scalar, nothing for the host to
+    sync on); smaller batches run the rounds at full width from the
+    start, as every batch did before. The result is a pure function of
+    (table, keys, valid) on every path.
+
+    ``stats=True`` (static) appends an int32[3]: rows probed, rows that
+    entered a claiming loop (unresolved after the read-only window; every
+    probed row of a batch below the compaction width), and 1 if that loop
+    ran at full width else 0.
     """
-    cap = table_keys.shape[0]
-    mask = jnp.uint32(cap - 1)
+    mask = jnp.uint32(table_keys.shape[0] - 1)
     h0 = hash_keys_device(keys) & mask
     n = keys.shape[0]
-    offs = jnp.arange(CHUNK, dtype=jnp.uint32)
-    rng = jnp.arange(CHUNK, dtype=jnp.int32)
-    C = jnp.int32(CHUNK)
+    widths = _tail_widths(n)
+    done = (jnp.zeros(n, bool) if valid is None else ~valid.astype(bool))
+    base = jnp.zeros(n, jnp.uint32)
+    slot = jnp.full(n, -1, jnp.int32)
+    rows = jnp.sum(~done, dtype=jnp.int32)
+    if not widths:
+        n_tail, wide = rows, jnp.int32(1)
+        table, slot = _claim_loop(table_keys, keys, h0, mask, base, slot,
+                                  done)
+    else:
+        hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base, mask)
+        slot = jnp.where((~done) & hit, fslot, slot)
+        done = done | hit
+        base = _advance(base, done, pos_empty)
+        n_tail = jnp.sum(~done, dtype=jnp.int32)
 
-    def body(state):
-        table, base, slot, done = state
-        # named regions: one probe round's ops carry probe.gather (the
-        # [n, CHUNK] window read + match) or probe.claim (the scatter-min
-        # that claims empties + its read-back) in their name path (HLO
-        # op_name; the tf_op stat of an op's metadata in a TPU trace),
-        # whatever fusion numbers the compiler assigns
-        with jax.named_scope("probe.gather"):
-            idx = (((h0 + base)[:, None] + offs[None, :]) & mask).astype(
-                jnp.int32)
-            entry = table[idx]                               # [n, CHUNK]
-            is_key = entry == keys[:, None]
-            is_empty = entry == jnp.int64(EMPTY_KEY)
-            pos_found = jnp.min(jnp.where(is_key, rng[None], C), axis=1)
-            pos_empty = jnp.min(jnp.where(is_empty, rng[None], C), axis=1)
-            found = (~done) & (pos_found < pos_empty)
-            fslot = jnp.take_along_axis(
-                idx, jnp.minimum(pos_found, C - 1)[:, None], axis=1)[:, 0]
-        # claim the window's first empty; losers of the scatter-min resume
-        # from the contested slot next iteration
-        with jax.named_scope("probe.claim"):
-            want = (~done) & ~found & (pos_empty < C)
-            cslot = jnp.take_along_axis(
-                idx, jnp.minimum(pos_empty, C - 1)[:, None], axis=1)[:, 0]
-            claim_idx = jnp.where(want, cslot, jnp.int32(0))
-            claim_val = jnp.where(want, keys, jnp.int64(EMPTY_KEY))
-            table = table.at[claim_idx].min(claim_val)
-            entry2 = table[cslot]
-            won = want & (entry2 == keys)
-        slot = jnp.where(found, fslot, slot)
-        slot = jnp.where(won, cslot, slot)
-        done = done | found | won
-        base = jnp.where(
-            done, base,
-            base + jnp.where(want, pos_empty.astype(jnp.uint32),
-                             jnp.uint32(CHUNK)))
-        return table, base, slot, done
+        def narrow_loop(T, table, base, slot, done):
+            with jax.named_scope("probe.compact"):
+                # a stable sort on `done` puts the unresolved rows first, in
+                # row order: 0.24 ms at n = 2^18 on the v5e, where cumsum +
+                # searchsorted took 3.3 and jnp.nonzero(size=T) did not fit
+                # the compiler's vmem at all
+                _, order = jax.lax.sort_key_val(
+                    done.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32))
+                src = order[:T]
+                live = jnp.arange(T, dtype=jnp.int32) < n_tail
+            table, tslot = _claim_loop(
+                table, keys[src], h0[src], mask, base[src],
+                jnp.full(T, -1, jnp.int32), ~live)
+            with jax.named_scope("probe.compact"):
+                slot = slot.at[jnp.where(live, src, n)].set(
+                    tslot, mode="drop")
+            return table, slot
 
-    def cond(state):
-        _table, base, _slot, done = state
-        return ((~done) & (base < MAX_PROBES)).any()
+        def wide_loop(table, base, slot, done):
+            return _claim_loop(table, keys, h0, mask, base, slot, done)
 
-    start_done = (jnp.zeros(n, bool) if valid is None
-                  else ~valid.astype(bool))
-    init = (table_keys, jnp.zeros(n, jnp.uint32),
-            jnp.full(n, -1, jnp.int32), start_done)
-    table, _base, slot, done = jax.lax.while_loop(cond, body, init)
-    return table, slot, done & (slot >= 0)
+        # the narrowest loop that holds the tail, else the full width
+        level = sum((n_tail > T).astype(jnp.int32) for T in widths)
+        wide = (level == len(widths)).astype(jnp.int32)
+        table, slot = jax.lax.switch(
+            level, [partial(narrow_loop, T) for T in widths] + [wide_loop],
+            table_keys, base, slot, done)
+    out = (table, slot, slot >= 0)
+    return (*out, jnp.stack([rows, n_tail, wide])) if stats else out

@@ -1586,8 +1586,10 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._coalesce_flush()
         self._drain(block=True)
         self._refresh_late(block=True)
-        if self._backend is not None and self._backend.tiering_active:
-            self._backend.prefetch_pipeline.close()
+        if self._backend is not None:
+            self._backend.note_probe_stats(block=True)
+            if self._backend.tiering_active:
+                self._backend.prefetch_pipeline.close()
 
     def _refresh_late(self, block: bool = False) -> None:
         """Sync the host cache of the device late-drop counter. Non-

@@ -259,6 +259,12 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # accumulates in a device counter checked at watermark boundaries
         self._defer = bool(defer_overflow)
         self._dropped = jnp.zeros((), jnp.int64)
+        # the probe's counters (rows, tail rows, wide batches) accumulate
+        # on the device like _dropped; note_probe_stats hands them to
+        # DEVICE_STATS once a copy taken at an earlier batch has landed
+        self._probe = jnp.zeros(3, jnp.int64)
+        self._probe_sent: Optional[jax.Array] = None
+        self._probe_noted = np.zeros(3, np.int64)
         # spill tier: device capacity is capped at the HBM budget; cold key
         # groups page out to host RAM (state/spill.py). 0 = unlimited.
         # With defer_overflow the split is computed ON DEVICE (spilled-group
@@ -1041,10 +1047,29 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             self.mark_dirty(slots)
             return slots
         dkeys = sanitize_keys_device(dkeys)
-        self.table, slots, ok = lookup_or_insert(self.table, dkeys)
+        self.table, slots, ok, probe = lookup_or_insert(
+            self.table, dkeys, stats=True)
         self._dropped = self._dropped + jnp.sum(~ok).astype(jnp.int64)
+        self._probe = self._probe + probe
+        self.note_probe_stats()
         self.mark_dirty(slots)
         return slots
+
+    def note_probe_stats(self, block: bool = False) -> None:
+        """Hand the probe's device counters to DEVICE_STATS. Per batch this
+        never waits: it reads a copy taken at an earlier batch only once
+        that has landed, then takes the next (so the gauges trail the
+        device by a batch or two); ``block=True`` (check_health, operator
+        close: places that sync anyway) reads the counters as they are."""
+        sent = self._probe if block else self._probe_sent
+        if sent is not None and (block or sent.is_ready()):
+            # lint: sync-ok the copy has landed (or the caller syncs anyway)
+            now = np.asarray(jax.device_get(sent))
+            DEVICE_STATS.note_probe(*(now - self._probe_noted))
+            self._probe_noted = now
+            self._probe_sent = None
+        if self._probe_sent is None and not block:
+            self._probe_sent = self._probe
 
     # ------------------------------------------------------------------
     # deferred-mode health (device scalars; ride along with fire programs)
@@ -1083,6 +1108,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         """Standalone (blocking) variant of apply_health."""
         d, occ = jax.device_get((self._dropped,
                                  (self.table != EMPTY_KEY).sum()))
+        self.note_probe_stats(block=True)
         self.apply_health(int(d), int(occ))
 
     def conform_ring(self, ring: int, live_panes: Iterable[int]) -> None:

@@ -1,0 +1,258 @@
+"""Property test of ``ops/hash_table.lookup_or_insert`` against a plain
+dict: the read-only first window, the compacted (narrow) loop, the
+full-width loop it falls back to, and the small-batch path, each held to
+the table's invariants and to the three counters ``stats=True`` reports."""
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+from flink_tpu.ops import hash_table as H  # noqa: E402
+from flink_tpu.ops.hash_table import (  # noqa: E402
+    CHUNK, EMPTY_KEY, MAX_PROBES, hash_keys_device, lookup,
+    lookup_or_insert, make_table,
+)
+
+CAP = 1 << 15
+N = H._COMPACT_MIN_ROWS                 # the smallest batch that compacts
+WIDTHS = H._tail_widths(N)              # of the narrow loop, ascending
+T = WIDTHS[-1]
+
+
+def _homes(keys: np.ndarray, cap: int) -> np.ndarray:
+    return np.asarray(hash_keys_device(jnp.asarray(keys, jnp.int64))
+                      ).astype(np.int64) & (cap - 1)
+
+
+def _resident(table: np.ndarray) -> dict[int, int]:
+    occ = np.flatnonzero(table != EMPTY_KEY)
+    return dict(zip(table[occ].tolist(), occ.tolist()))
+
+
+def _fill(cap: int, keys: np.ndarray, batch: int = 512) -> np.ndarray:
+    """A table holding ``keys``, built by the small-batch path (the loop
+    every earlier version ran: what a restored checkpoint looks like)."""
+    t = make_table(cap)
+    for i in range(0, len(keys), batch):
+        part = np.resize(keys[i:i + batch], batch)   # pad with repeats
+        t, _, ok = lookup_or_insert(t, jnp.asarray(part))
+        assert bool(np.asarray(ok).all())
+    return np.asarray(t)
+
+
+def _check(before: np.ndarray, keys: np.ndarray, valid, expect_wide=None):
+    """Run one batch; hold the result to the dict model. Returns
+    (table_after, slots, ok, stats)."""
+    cap = len(before)
+    n = len(keys)
+    dvalid = None if valid is None else jnp.asarray(valid)
+    table, slots, ok, stats = lookup_or_insert(
+        jnp.asarray(before), jnp.asarray(keys), dvalid, stats=True)
+    three = lookup_or_insert(jnp.asarray(before), jnp.asarray(keys), dvalid)
+    assert len(three) == 3              # callers inside programs: 3 values
+    after, slots, ok = np.asarray(table), np.asarray(slots), np.asarray(ok)
+    np.testing.assert_array_equal(after, np.asarray(three[0]))
+    np.testing.assert_array_equal(slots, np.asarray(three[1]))
+    v = np.ones(n, bool) if valid is None else np.asarray(valid, bool)
+    # rows that do not take part: never a slot, never ok, never inserted
+    assert (slots[~v] == -1).all() and not ok[~v].any()
+    assert ((slots >= 0) == ok).all()
+    # every placed row holds the slot that holds its key
+    assert (after[slots[ok]] == keys[ok]).all()
+    was = _resident(before)
+    now = _resident(after)
+    # one slot per distinct key, duplicates share it
+    for k, s in zip(keys[ok].tolist(), slots[ok].tolist()):
+        assert now[k] == s
+    # untouched entries unchanged; exactly the new keys were added
+    occupied = before != EMPTY_KEY
+    np.testing.assert_array_equal(after[occupied], before[occupied])
+    new_keys = {k for k in keys[ok].tolist() if k not in was}
+    assert set(now) - set(was) == new_keys
+    assert not (set(keys[v & ~ok].tolist()) & set(now))
+    # no key sits behind an EMPTY of its own probe sequence
+    placed = np.array(sorted(now), np.int64)
+    home = _homes(placed, cap)
+    at = np.array([now[k] for k in placed.tolist()], np.int64)
+    dist = (at - home) % cap
+    for d in range(int(dist.max(initial=0))):
+        on_path = dist > d
+        assert (after[(home[on_path] + d) % cap] != EMPTY_KEY).all()
+    # rows that gave up had nowhere to go within MAX_PROBES
+    assert (dist < MAX_PROBES + CHUNK).all()
+    # the three counters, from the dict alone
+    rows = int(v.sum())
+    widths = H._tail_widths(n)
+    if widths:
+        khome = _homes(keys, cap)
+        in_first_window = np.array(
+            [k in was and (was[k] - h) % cap < CHUNK
+             for k, h in zip(keys.tolist(), khome.tolist())])
+        tail = int((v & ~in_first_window).sum())
+        wide = int(tail > widths[-1])
+    else:
+        tail, wide = rows, 1
+    assert np.asarray(stats).tolist() == [rows, tail, wide]
+    if expect_wide is not None:
+        assert wide == expect_wide
+    return after, slots, ok, np.asarray(stats)
+
+
+@pytest.fixture(scope="module")
+def half_full():
+    """(table at load 0.5, its keys, those found in their first window)."""
+    rng = np.random.default_rng(7)
+    keys = rng.choice(1 << 40, CAP // 2, replace=False).astype(np.int64)
+    table = _fill(CAP, keys)
+    was = _resident(table)
+    home = _homes(keys, CAP)
+    near = np.array([(was[k] - h) % CAP < CHUNK
+                     for k, h in zip(keys.tolist(), home.tolist())])
+    assert (~near).sum() > 0            # some keys are displaced >= CHUNK
+    return table, keys, keys[near]
+
+
+def test_all_resident_takes_the_narrow_loop(half_full):
+    table, keys, _near = half_full
+    rng = np.random.default_rng(1)
+    batch = rng.choice(keys, N)
+    after, slots, ok, stats = _check(table, batch, None, expect_wide=0)
+    assert ok.all() and 0 < stats[1] <= T
+    np.testing.assert_array_equal(after, table)
+    np.testing.assert_array_equal(
+        slots, np.asarray(lookup(jnp.asarray(table), jnp.asarray(batch))))
+
+
+def test_all_new_takes_the_wide_loop(half_full):
+    table, _keys, _near = half_full
+    batch = np.arange(N, dtype=np.int64) + (1 << 50)
+    _after, _slots, ok, _stats = _check(table, batch, None, expect_wide=1)
+    assert ok.all()
+
+
+@pytest.mark.parametrize("unresolved", sorted(
+    {0, 1} | {w + d for w in WIDTHS for d in (-1, 0, 1)}))
+def test_tail_at_the_loop_width(half_full, unresolved):
+    """Exactly ``unresolved`` rows are left after the first window: new
+    keys, which that window can never find."""
+    table, _keys, near = half_full
+    rng = np.random.default_rng(unresolved)
+    batch = rng.choice(near, N)
+    batch[rng.choice(N, unresolved, replace=False)] = (
+        np.arange(unresolved, dtype=np.int64) + (1 << 51))
+    _after, _slots, ok, stats = _check(table, batch, None,
+                                       expect_wide=int(unresolved > T))
+    assert ok.all() and stats[1] == unresolved
+
+
+def test_hot_keys_one_of_them_displaced():
+    """Half the batch on sixteen hot keys, one of which sits CHUNK or
+    more slots from its home (its home window was filled first): its rows
+    are the tail, and the narrow loop still holds them."""
+    cand = np.arange(1, 400_000, dtype=np.int64)
+    home = _homes(cand, CAP)
+    h = int(np.bincount(home).argmax())
+    same = cand[home == h]
+    assert len(same) > CHUNK
+    rng = np.random.default_rng(3)
+    cold = rng.choice(cand[home != h], CAP // 2, replace=False)
+    table = _fill(CAP, np.concatenate([same[:CHUNK + 1], cold]))
+    displaced = int(same[CHUNK])
+    was = _resident(table)
+    assert (was[displaced] - h) % CAP >= CHUNK
+    hot = np.concatenate([[displaced], cold[:15]]).astype(np.int64)
+    batch = np.where(rng.random(N) < 0.5, rng.choice(hot, N),
+                     rng.choice(cold, N))
+    _after, slots, ok, stats = _check(table, batch, None, expect_wide=0)
+    assert ok.all() and stats[1] >= (batch == displaced).sum() > N // 64
+    assert (slots[batch == displaced] == was[displaced]).all()
+
+
+@pytest.mark.parametrize("n", [8, 100, N])
+def test_valid_mask(half_full, n):
+    table, keys, _near = half_full
+    rng = np.random.default_rng(n)
+    batch = np.where(rng.random(n) < 0.5, rng.choice(keys, n),
+                     rng.integers(1 << 52, 1 << 53, n)).astype(np.int64)
+    valid = rng.random(n) < 0.7
+    _check(table, batch, valid)
+
+
+@pytest.mark.parametrize("n", [64, N])
+def test_overflow_reports_not_ok(n):
+    """More distinct keys than a tiny table holds: the rows that run out
+    of probes report ok False and slot -1, the rest are placed."""
+    cap = 256
+    first = np.arange(400, dtype=np.int64) * 7919 + 11   # fill it to the brim
+    table = np.asarray(lookup_or_insert(
+        make_table(cap), jnp.asarray(np.resize(first, 512)))[0])
+    assert (table != EMPTY_KEY).sum() > cap - 32
+    more = np.arange(n, dtype=np.int64) * 104729 + 5
+    _after, slots, ok, _stats = _check(table, more, None)
+    assert (~ok).any() and (slots[~ok] == -1).all()
+
+
+def test_table_built_by_itself_at_load_06():
+    """Batch by batch through the function itself to load 0.6 (wide
+    batches first, mixed ones later), then read back with ``lookup``."""
+    rng = np.random.default_rng(11)
+    keys = rng.choice(1 << 40, int(0.6 * CAP), replace=False
+                      ).astype(np.int64)
+    table = np.asarray(make_table(CAP))
+    seen = 0
+    for i in range(0, len(keys), N // 2):
+        fresh = keys[i:i + N // 2]
+        old = rng.choice(keys[:max(seen, 1)], N - len(fresh))
+        table, _s, ok, _st = _check(table, np.concatenate([fresh, old]),
+                                    None)
+        assert ok.all()
+        seen += len(fresh)
+    now = _resident(table)
+    assert len(now) == len(keys)
+    got = np.asarray(lookup(jnp.asarray(table), jnp.asarray(keys)))
+    assert got.tolist() == [now[k] for k in keys.tolist()]
+    assert np.asarray(lookup(jnp.asarray(table), jnp.asarray(
+        np.arange(64, dtype=np.int64) + (1 << 55)))).tolist() == [-1] * 64
+
+
+def test_small_batches_run_the_full_width_loop(half_full):
+    table, keys, _near = half_full
+    assert H._tail_widths(N - 1) == ()
+    batch = np.concatenate([keys[:5], np.array([1 << 54, 1 << 54, 9],
+                                               np.int64)])
+    _after, slots, ok, stats = _check(table, batch, None, expect_wide=1)
+    assert ok.all() and slots[5] == slots[6] and stats.tolist() == [8, 8, 1]
+
+
+def test_backend_hands_the_counters_to_device_stats():
+    """The deferred ingest path accumulates the probe's counters on the
+    device; DEVICE_STATS gets them without a sync once a copy has landed
+    (a batch or two late) and exactly at check_health."""
+    from flink_tpu.core import KeyGroupRange
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
+
+    keys = ("probe_rows_total", "probe_tail_rows_total",
+            "probe_wide_batches_total")
+
+    def moved(since):
+        now = DEVICE_STATS.snapshot()
+        return [now[k] - since[k] for k in keys]
+
+    be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=CAP,
+                              defer_overflow=True, host_index=False)
+    start = DEVICE_STATS.snapshot()
+    resident = jnp.arange(N, dtype=jnp.int64) * 3 + 1
+    be.slots_for_batch_device(resident)                        # all new: wide
+    assert moved(start) == [0, 0, 0]            # nothing has been fetched yet
+    be._probe_sent.block_until_ready()          # the copy taken at batch one
+    be.slots_for_batch_device(resident).block_until_ready()   # all resident
+    assert moved(start) == [N, N, 1]            # the first batch's, landed
+    be.slots_for_batch_device(jnp.arange(8, dtype=jnp.int64))  # the plain loop
+    be.check_health()
+    rows, tail, wide = moved(start)
+    assert (rows, wide) == (2 * N + 8, 2) and N + 8 <= tail <= N + 8 + T
+    be.check_health()
+    assert moved(start) == [rows, tail, wide]   # a flush adds nothing twice

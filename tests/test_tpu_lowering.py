@@ -66,3 +66,44 @@ def test_mesh_step_compiles(v5e_devices, n_dev):
     jax.jit(agg.step).lower(
         state, spec((D, B), jnp.int64), {"revenue": spec((D, B), jnp.int64)},
         spec((D, B), jnp.int64), spec((D, B), jnp.bool_)).compile()
+
+
+#: the path by which the benchmark's probe_rounds_p50 finds the probe
+#: loop's claim in a device trace (benchmarks/layer_metrics/
+#: probe_rounds_p50.json), and the program it counts them under
+_CLAIM_PATH = r'op_name="[^"]*/while/body/(probe\.claim/)?scatter-min"'
+_PROBE_MODULE = "HloModule jit_lookup_or_insert"
+
+
+def _assert_probe_is_countable(hlo: str) -> None:
+    import re
+
+    assert _PROBE_MODULE in hlo
+    assert re.search(_CLAIM_PATH, hlo), "no scatter-min directly in a " \
+        "while body: probe_rounds_p50 would read 0"
+
+
+def test_hash_probe_compiles_at_the_benchmark_shape(v5e_devices):
+    """[2^24] slots x [2^18] rows, with the counters: the first window, the
+    compaction (a sort), both narrow loops and the wide one under one
+    `lax.switch`, for the v5e's compiler."""
+    from flink_tpu.ops.hash_table import lookup_or_insert
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    compiled = lookup_or_insert.lower(
+        jax.ShapeDtypeStruct((1 << 24,), jnp.int64, sharding=one),
+        jax.ShapeDtypeStruct((1 << 18,), jnp.int64, sharding=one),
+        stats=True).compile()
+    _assert_probe_is_countable(compiled.as_text())
+
+
+@pytest.mark.parametrize("rows", [64, 1 << 12])
+def test_hash_probe_claim_stays_countable_on_any_backend(rows):
+    """Small batches (the plain loop) and compacting ones alike keep the
+    claim's scatter-min directly in a while body, under the module name the
+    trace readers anchor on."""
+    from flink_tpu.ops.hash_table import lookup_or_insert, make_table
+
+    compiled = lookup_or_insert.lower(
+        make_table(1 << 14), jnp.zeros(rows, jnp.int64)).compile()
+    _assert_probe_is_countable(compiled.as_text())
