@@ -1278,12 +1278,12 @@ def _multichip_worker(n_devices: int, batch: int, steps: int) -> None:
     panes = jnp.asarray(rng.integers(0, 16, size=(D, batch)), jnp.int32)
     valid = jnp.ones((D, batch), bool)
     for _ in range(2):                                     # compile warmup
-        state, _p = agg.step(state, keys, cols, panes, valid)
+        state, _p, _r = agg.step(state, keys, cols, panes, valid)
     jax.block_until_ready(state)
     before = DEVICE_STATS.snapshot()
     t0 = time.perf_counter()
     for _ in range(steps):
-        state, _p = agg.step(state, keys, cols, panes, valid)
+        state, _p, _r = agg.step(state, keys, cols, panes, valid)
     jax.block_until_ready(state)
     wall = time.perf_counter() - t0
     after = DEVICE_STATS.snapshot()

@@ -443,10 +443,13 @@ def main(argv=None) -> int:
         n_events=1 << 22, device=False, seed=args.seed, reference=ref_22)
     _emit(report)
 
-    # 2^22 events touch ~3.4M distinct keys: 2^22 slots per device on four
-    # chips, 2^23 on one or two keep every shard under the operator's 0.6
-    # growth threshold, and the mesh step (state not donated: 3x resident)
-    # inside 16 GB
+    # sized by the operator's growth threshold alone: 2^22 events touch
+    # ~3.4M distinct keys, so 2^23 slots a device on one or two chips and
+    # 2^22 on four keep every shard under 0.6. Memory no longer decides:
+    # the mesh step donates its state (2^23 slots are 2.2 GB of table and
+    # planes, built shard by shard) and needs beside it the flat copy of
+    # each plane, 3.3 GB (PERF.md section 5); undonated it held the state
+    # three times
     report, _rows = leg_q5_mesh(
         n_keys=N_KEYS, capacity_per_device=(1 << 24) // max(n_dev, 2),
         batch=BATCH, n_events=1 << 22, seed=args.seed, reference=ref_22,

@@ -366,7 +366,10 @@ def _exempt_constants(tree: ast.Module) -> Set[int]:
                 "note_restore_fallback",
                 # program-cache scopes ("mesh.step", ...) are an open
                 # namespace keyed off the builder, not config keys
-                "instrumented_program_cache"}
+                "instrumented_program_cache",
+                # jax.named_scope labels ("mesh.exchange", ...) name
+                # regions of a device program for its trace
+                "named_scope"}
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):
             continue

@@ -92,6 +92,14 @@ class DeviceStats:
         self._probe_rows = 0
         self._probe_tail_rows = 0
         self._probe_wide_batches = 0
+        # mesh step accounting (PR 27): steps of the sharded window
+        # program and the keyBy exchange rounds they took (one for a
+        # batch spread evenly over the shards, more under skew). Read
+        # from each step's own output once its copy has landed
+        # (runtime/operators/mesh_window.py), so they trail the devices
+        # by the steps in flight
+        self._mesh_steps = 0
+        self._mesh_exchange_rounds = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -352,6 +360,17 @@ class DeviceStats:
             return (self._probe_rows, self._probe_tail_rows,
                     self._probe_wide_batches)
 
+    def note_mesh_steps(self, steps: int, rounds: int) -> None:
+        with self._lock:
+            self._mesh_steps += int(steps)
+            self._mesh_exchange_rounds += int(rounds)
+
+    @property
+    def mesh_step_counts(self) -> tuple[int, int]:
+        """(mesh steps, exchange rounds they took)."""
+        with self._lock:
+            return self._mesh_steps, self._mesh_exchange_rounds
+
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
             self._chain_dispatches += int(n)
@@ -610,6 +629,8 @@ class DeviceStats:
                 "probe_rows_total": self._probe_rows,
                 "probe_tail_rows_total": self._probe_tail_rows,
                 "probe_wide_batches_total": self._probe_wide_batches,
+                "mesh_steps_total": self._mesh_steps,
+                "mesh_exchange_rounds_total": self._mesh_exchange_rounds,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -714,6 +735,7 @@ class DeviceStats:
             self._fire_unready_polls = 0
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
+            self._mesh_steps = self._mesh_exchange_rounds = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -1130,6 +1152,10 @@ def bind_device_metrics(registry) -> None:
     g.gauge("probe_rows_total", lambda: s.probe_counts[0])
     g.gauge("probe_tail_rows_total", lambda: s.probe_counts[1])
     g.gauge("probe_wide_batches_total", lambda: s.probe_counts[2])
+    # mesh step (prometheus: flink_tpu_device_mesh_steps_total /
+    # flink_tpu_device_mesh_exchange_rounds_total)
+    g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])
+    g.gauge("mesh_exchange_rounds_total", lambda: s.mesh_step_counts[1])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

@@ -615,6 +615,9 @@ LEDGER_SITE_INVENTORY: tuple = (
      "parallel/sharded_window.py — sharded full-fire program"),
     ("mesh.fire_inc",
      "parallel/sharded_window.py — sharded incremental fire program"),
+    ("mesh.init",  # lint: key-ok ledger site, not a config key
+     "parallel/sharded_window.py — the empty sharded state, built shard "
+     "by shard"),
     ("mesh.rebuild_inc",
      "parallel/sharded_window.py — sharded incremental rebuild "
      "program"),

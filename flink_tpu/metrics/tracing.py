@@ -855,11 +855,16 @@ SPAN_INVENTORY: tuple = (
     ("window", "IngestDispatch",
      "runtime/operators/device_window.py + "
      "runtime/operators/device_session.py — host time to enqueue one "
-     "batch's ingest programs (stage span: programs)"),
+     "batch's ingest programs (stage span: programs); "
+     "runtime/operators/mesh_window.py _flush — one [D, B] block's step "
+     "and the look at the pressure probe (seq: the block's ordinal)"),
     ("window", "Upload",
      "runtime/operators/device_window.py _fold_packed / "
      "_to_device_batch — pack + the one host→device copy; device/H2D "
-     "nests under it (stage span: bytes)"),
+     "nests under it (stage span: bytes); "
+     "runtime/operators/mesh_window.py _flush — concatenating the "
+     "staged batches, cutting one [D, B] block and its host→device "
+     "copies (seq: the block's ordinal)"),
     ("window", "Watermark",
      "runtime/operators/slice_control.py "
      "SliceControlPlane.process_watermark — one watermark through the "

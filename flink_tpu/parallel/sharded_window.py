@@ -48,11 +48,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..metrics.device import instrumented_program_cache
-from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert, \
-    make_table
+from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
 from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
-    AGG_MERGES, INVERTIBLE_KINDS, make_accumulator, merge_tree_build, \
-    merge_tree_update, pow2_ceil, scatter_fold
+    AGG_MERGES, INVERTIBLE_KINDS, merge_tree_build, merge_tree_update, \
+    pow2_ceil, scatter_fold
 from .exchange import bucket_capacity, exchange_round, plan_exchange
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
     key_groups_device, shard_ranges
@@ -119,58 +118,109 @@ def _split_sig(agg_sig):
 # module-level program builders (shared across instances and meshes)
 # ----------------------------------------------------------------------
 
-@instrumented_program_cache("mesh.step")
-def _step_program(sig, max_parallelism: int, axis_name: str,
-                  rules: tuple):
-    """The sharded fold step. The returned dispatcher takes the concrete
-    Mesh as its first argument and binds the shard_map program per mesh
-    inside this one cache entry: the cache key stays local-shape-only
-    while the executable closes over the mesh shard_map needs."""
+def _make_init(sig, rules: tuple, mesh: Mesh):
+    """The jitted initialiser of the empty state on ``mesh``: its
+    ``out_shardings`` are the plan's, so every device fills only its own
+    ``[1, ...]`` shard and no device ever holds a global-sized array (a
+    [4, 16, 2^23] int64 plane tiled on one device and then cut is 4.3 GB
+    and a second copy while it is cut; the shard is 1.07 GB)."""
+    _, agg_sig, cap, ring = sig
+    aggs = _aggs_from_sig(agg_sig)
+    # lint: sync-ok mesh.devices is a host numpy array of Device objects
+    D = int(mesh.devices.size)
+    skel = {"table": 0, "accs": {a.name: 0 for a in aggs}, "dropped": 0}
+    sp = match_partition_rules(rules, skel)
+    shardings = jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec),
+        ShardedWindowState(sp["table"], sp["accs"], sp["dropped"]),
+        is_leaf=lambda x: isinstance(x, P))
+
+    def init() -> ShardedWindowState:
+        return ShardedWindowState(
+            jnp.full((D, cap), EMPTY_KEY, jnp.int64),
+            {a.name: jnp.full((D, ring, cap), AGG_INITS[a.kind](a.dtype),
+                              a.dtype) for a in aggs},
+            jnp.zeros(D, jnp.int64))
+
+    return jax.jit(init, out_shardings=shardings)
+
+
+def _per_mesh(make):
+    """``dispatch(mesh, *args)`` over programs built once per concrete
+    Mesh by ``make(mesh)``: how a builder whose cache key is local-shape-
+    only holds the executables that close over a mesh."""
+    bound: dict = {}
+
+    def dispatch(mesh: Mesh, *args):
+        prog = bound.get(mesh)
+        if prog is None:
+            prog = bound[mesh] = make(mesh)
+        return prog(*args)
+
+    return dispatch
+
+
+@instrumented_program_cache("mesh.init")
+def _init_program(sig, rules: tuple):
+    """Builds the empty state; binds per concrete Mesh inside this one
+    cache entry, like the step."""
+    return _per_mesh(lambda mesh: _make_init(sig, rules, mesh))
+
+
+def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
+               mesh: Mesh):
+    """The jitted sharded fold step on ``mesh`` (see _step_program)."""
     _, agg_sig, cap, ring = sig
     aggs = _aggs_from_sig(agg_sig)
     MP = max_parallelism
+    # lint: sync-ok mesh.devices is a host numpy array of Device objects
+    D = int(mesh.devices.size)
 
-    def bind(mesh: Mesh):
-        # lint: sync-ok mesh.devices is a host numpy array of Device objects
-        D = int(mesh.devices.size)
+    def shard_body(table, accs, dropped, keys, cols, panes, valid,
+                   base_start, base_len):
+        table, keys = table[0], keys[0]
+        accs = {k: v[0] for k, v in accs.items()}
+        cols = {k: v[0] for k, v in cols.items()}
+        panes, valid = panes[0], valid[0]
 
-        def shard_body(table, accs, dropped, keys, cols, panes, valid,
-                       base_start, base_len):
-            table, keys = table[0], keys[0]
-            accs = {k: v[0] for k, v in accs.items()}
-            cols = {k: v[0] for k, v in cols.items()}
-            panes, valid = panes[0], valid[0]
-
+        with jax.named_scope("mesh.plan"):
             kg = key_groups_device(keys, MP)
-            # ownership bounds are TRACED scalars: a rescale that re-points
-            # this mesh at a different subtask range changes only argument
-            # values, never the program
+            # ownership bounds are TRACED scalars: a rescale that
+            # re-points this mesh at a different subtask range changes
+            # only argument values, never the program
             dest = device_index_for_key_groups(kg, D, MP, base_start,
                                                base_len)
-            # rows outside this subtask's range never fold (they belong to
-            # a peer host; a correct upstream exchange never sends them)
+            # rows outside this subtask's range never fold (they
+            # belong to a peer host; a correct upstream exchange never
+            # sends them)
             valid = valid & (dest >= 0) & (dest < D)
-            payload = {"__key__": _sanitize(keys), "__pane__": panes, **cols}
+            payload = {"__key__": _sanitize(keys), "__pane__": panes,
+                       **cols}
 
             # capacity-bounded exchange: rounds of `cap_x` rows per
-            # destination keep the per-device fold width O(B) as the mesh
-            # grows (the worst-case-width keyby_exchange folds D*B rows
-            # per device — anti-scaling). The trip count is pmax-uniform
-            # across the axis so the collectives inside the loop line up;
-            # a skewed batch takes more rounds but never loses a record.
+            # destination keep the per-device fold width O(B) as the
+            # mesh grows (the worst-case-width keyby_exchange folds
+            # D*B rows per device — anti-scaling). The trip count is
+            # pmax-uniform across the axis so the collectives inside
+            # the loop line up; a skewed batch takes more rounds but
+            # never loses a record.
             B = keys.shape[0]
             cap_x = bucket_capacity(B, D)
             xplan = plan_exchange(dest, valid, D, cap_x)
             ordered = jax.tree.map(lambda c: c[xplan.order], payload)
+        with jax.named_scope("mesh.sync"):
             n_rounds = jax.lax.pmax(xplan.n_rounds, axis_name)
 
-            def fold_round(carry):
-                r, table, accs, dropped, ok_count = carry
-                accs = dict(accs)
-                routed, rvalid = exchange_round(axis_name, D, cap_x, xplan,
-                                                ordered, r)
+        def fold_round(carry):
+            r, table, accs, dropped, ok_count = carry
+            accs = dict(accs)
+            with jax.named_scope("mesh.exchange"):
+                routed, rvalid = exchange_round(axis_name, D, cap_x,
+                                                xplan, ordered, r)
+            with jax.named_scope("mesh.probe"):
                 table, slots, ok = lookup_or_insert(
                     table, routed["__key__"], rvalid)
+            with jax.named_scope("mesh.fold"):
                 n_dropped = jnp.sum(rvalid & ~ok).astype(jnp.int64)
                 ring_idx = jnp.where(ok, (routed["__pane__"] % ring),
                                      0).astype(jnp.int32)
@@ -181,49 +231,58 @@ def _step_program(sig, max_parallelism: int, axis_name: str,
                     accs[a.name] = scatter_fold(
                         a.kind, accs[a.name].reshape(-1), flat, vals,
                         ok).reshape(ring, cap)
-                return (r + 1, table, accs, dropped + n_dropped,
-                        ok_count + jnp.sum(ok).astype(jnp.int64))
+            return (r + 1, table, accs, dropped + n_dropped,
+                    ok_count + jnp.sum(ok).astype(jnp.int64))
 
-            carry = (jnp.int32(0), table, accs, dropped,
-                     jnp.zeros((), jnp.int64))
-            _, table, accs, dropped, ok_count = jax.lax.while_loop(
-                lambda c: c[0] < n_rounds, fold_round, carry)
+        carry = (jnp.int32(0), table, accs, dropped,
+                 jnp.zeros((), jnp.int64))
+        _, table, accs, dropped, ok_count = jax.lax.while_loop(
+            lambda c: c[0] < n_rounds, fold_round, carry)
+        with jax.named_scope("mesh.sync"):
             processed = jax.lax.psum(ok_count, axis_name)
-            return (table[None], {k: v[None] for k, v in accs.items()},
-                    dropped, processed)
+        return (table[None], {k: v[None] for k, v in accs.items()},
+                dropped, processed, n_rounds)
 
-        skel = {"table": 0, "accs": {a.name: 0 for a in aggs},
-                "dropped": 0, "keys": 0,
-                "cols": {a.name: 0 for a in aggs if a.kind != "count"},
-                "panes": 0, "valid": 0}
-        sp = match_partition_rules(rules, skel)
-        state_specs = (sp["table"], sp["accs"], sp["dropped"])
-        mapped = shard_map_unchecked(
-            shard_body, mesh,
-            in_specs=state_specs + (sp["keys"], sp["cols"], sp["panes"],
-                                    sp["valid"], P(), P()),
-            out_specs=state_specs + (P(),))
+    skel = {"table": 0, "accs": {a.name: 0 for a in aggs},
+            "dropped": 0, "keys": 0,
+            "cols": {a.name: 0 for a in aggs if a.kind != "count"},
+            "panes": 0, "valid": 0}
+    sp = match_partition_rules(rules, skel)
+    state_specs = (sp["table"], sp["accs"], sp["dropped"])
+    mapped = shard_map_unchecked(
+        shard_body, mesh,
+        in_specs=state_specs + (sp["keys"], sp["cols"], sp["panes"],
+                                sp["valid"], P(), P()),
+        out_specs=state_specs + (P(), P()))
 
-        @jax.jit
-        def step(state: ShardedWindowState, keys, cols, panes, valid,
-                 base_start, base_len):
-            table, accs, dropped, processed = mapped(
-                state.table, state.accs, state.dropped, keys, cols, panes,
-                valid, base_start, base_len)
-            return ShardedWindowState(table, accs, dropped), processed
+    # the state is DONATED: the loop folds into the planes in place.
+    # Without it every step allocates a second state (2.2 GB a chip
+    # at [16, 2^23] x 2 planes) and copies the planes it did not
+    # touch. Programs already enqueued on the old buffers stay valid;
+    # a Python handle on the old state does not.
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def step(state: ShardedWindowState, keys, cols, panes, valid,
+             base_start, base_len):
+        table, accs, dropped, processed, n_rounds = mapped(
+            state.table, state.accs, state.dropped, keys, cols, panes,
+            valid, base_start, base_len)
+        return (ShardedWindowState(table, accs, dropped), processed,
+                n_rounds)
 
-        return step
+    return step
 
-    bound: dict = {}
 
-    def dispatch(mesh: Mesh, state, keys, cols, panes, valid,
-                 base_start, base_len):
-        prog = bound.get(mesh)
-        if prog is None:
-            prog = bound[mesh] = bind(mesh)
-        return prog(state, keys, cols, panes, valid, base_start, base_len)
-
-    return dispatch
+@instrumented_program_cache("mesh.step")
+def _step_program(sig, max_parallelism: int, axis_name: str,
+                  rules: tuple):
+    """The sharded fold step. The returned dispatcher takes the concrete
+    Mesh as its first argument and binds the shard_map program per mesh
+    inside this one cache entry: the cache key stays local-shape-only
+    while the executable closes over the mesh shard_map needs. It returns
+    (new state, rows folded, exchange rounds taken), the two counts
+    replicated scalars; the state argument is donated."""
+    return _per_mesh(lambda mesh: _make_step(sig, max_parallelism,
+                                             axis_name, rules, mesh))
 
 
 @instrumented_program_cache("mesh.fire")
@@ -277,7 +336,9 @@ def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int]):
         occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
         dropped = state.dropped.sum()
         if topk is None:
-            return state.table, emit, out, dropped, occ
+            # a copy: an input handed back as it is would share the
+            # table's buffer, which the next step donates
+            return jnp.copy(state.table), emit, out, dropped, occ
         rank = out[rank_name]
         _vals, flat_idx, ok = global_topk(rank, emit, topk)
         keys = jnp.take(state.table.reshape(-1), flat_idx)
@@ -390,7 +451,7 @@ def _fire_inc_program(sig, rank_name: Optional[str], topk: Optional[int]):
         occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
         dropped = state.dropped.sum()
         if topk is None:
-            return state.table, emit, view, dropped, occ
+            return jnp.copy(state.table), emit, view, dropped, occ
         rank = view[rank_name]
         _vals, flat_idx, ok = global_topk(rank, emit, topk)
         keys = jnp.take(state.table.reshape(-1), flat_idx)
@@ -406,13 +467,14 @@ def _retire_program(sig):
     _, agg_sig, _cap, _ring = sig
     aggs = _aggs_from_sig(agg_sig)
 
-    @jax.jit
-    def retire(state: ShardedWindowState, row: jax.Array):
-        accs = {
-            a.name: state.accs[a.name].at[:, row].set(
-                AGG_INITS[a.kind](state.accs[a.name].dtype))
-            for a in aggs}
-        return state._replace(accs=accs)
+    # donated like the step's state, so no second copy of the planes is
+    # allocated; the program still passes over both planes to clear the
+    # one row (31 ms at [16, 2^23] on a v5e: PERF.md section 5)
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def retire(accs: dict, row: jax.Array):
+        return {a.name: accs[a.name].at[:, row].set(
+                    AGG_INITS[a.kind](accs[a.name].dtype))
+                for a in aggs}
 
     return retire
 
@@ -468,6 +530,7 @@ class ShardedWindowAgg:
                              if a.kind in INVERTIBLE_KINDS)
         self.tree_sig = tuple((a.kind, a.name) for a in self.aggs
                               if a.kind not in INVERTIBLE_KINDS)
+        self._init = _init_program(self.sig, plan.rules)
         self._step = _step_program(self.sig, max_parallelism,
                                    plan.axis_name, plan.rules)
         self._fire = _fire_program(self.sig)
@@ -488,16 +551,20 @@ class ShardedWindowAgg:
         self._base_len = np.int32(self.shard_ranges[-1].end - start + 1)
 
     # ------------------------------------------------------------------
+    def init_program(self):
+        """The jitted initialiser ``init_state`` runs (no arguments; its
+        output shardings are the plan's)."""
+        return _make_init(self.sig, self.plan.rules, self.mesh)
+
+    def step_program(self):
+        """The jitted step ``step`` dispatches, with its two ownership
+        bounds as trailing arguments."""
+        return _make_step(self.sig, self.max_parallelism,
+                          self.plan.axis_name, self.plan.rules, self.mesh)
+
     def init_state(self) -> ShardedWindowState:
-        D, cap, ring = self.n_dev, self.capacity, self.ring
-        state = ShardedWindowState(
-            jnp.tile(make_table(cap)[None], (D, 1)),
-            {a.name: jnp.tile(
-                make_accumulator(a.kind, (ring, cap), a.dtype)[None],
-                (D, 1, 1)) for a in self.aggs},
-            jnp.zeros(D, jnp.int64))
-        with self.mesh:
-            return self.plan.device_put(state)
+        """The empty state, each leaf built on the device that holds it."""
+        return self._init(self.mesh)
 
     # ------------------------------------------------------------------
     @property
@@ -509,9 +576,11 @@ class ShardedWindowAgg:
     # ------------------------------------------------------------------
     def step(self, state: ShardedWindowState, keys: jax.Array, cols: dict,
              panes: jax.Array, valid: jax.Array
-             ) -> tuple[ShardedWindowState, jax.Array]:
+             ) -> tuple[ShardedWindowState, jax.Array, jax.Array]:
         """Fold one micro-batch. keys/panes/valid: [D, B]; cols: dict of
-        [D, B] value columns (one per non-count aggregate)."""
+        [D, B] value columns (one per non-count aggregate). Returns (new
+        state, rows folded, exchange rounds taken). ``state`` is DONATED:
+        its buffers are deleted, only the returned state is live."""
         return self._step(self.mesh, state, keys, cols, panes, valid,
                           self._base_start, self._base_len)
 
@@ -572,8 +641,9 @@ class ShardedWindowAgg:
     # ------------------------------------------------------------------
     def retire_row(self, state: ShardedWindowState,
                    row: int) -> ShardedWindowState:
-        """Reset one ring row across all shards (pane retirement)."""
-        return self._retire(state, jnp.int32(row))
+        """Reset one ring row across all shards (pane retirement). The
+        planes of ``state`` are DONATED, like the step's state."""
+        return state._replace(accs=self._retire(state.accs, jnp.int32(row)))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

@@ -30,6 +30,7 @@ mesh and host subtasks agree on ownership.
 from __future__ import annotations
 
 import time
+from collections import deque
 from typing import Any, Optional, Sequence
 
 import jax
@@ -41,6 +42,7 @@ from ...core.records import RecordBatch, Schema
 from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, make_table
 from ...ops.segment_ops import AGG_INITS, make_accumulator
 from ...metrics.device import DEVICE_STATS, pytree_nbytes
+from ...metrics.tracing import TRACER
 from ...parallel.mesh import make_mesh, shard_ranges
 from ...parallel.sharded_window import (
     AggDef, ShardedWindowAgg, ShardedWindowState,
@@ -134,10 +136,19 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             self._record_fire_latency = False
         self._dropped_seen = 0
         self.stage_s: dict[str, float] = {}
-        # non-blocking pressure probe: dispatched at watermark cadence,
-        # consumed when its copy lands (never stalls the step pipeline)
+        # pressure probe (occupancy of the fullest shard + drops): an
+        # async scalar read, dispatched at watermark cadence and, between
+        # watermarks, once the blocks stepped since the last one pass a
+        # quarter of the headroom to the growth threshold; consumed when
+        # its copy has landed (_pressure_probe)
         self._probe = None
         self._blocks_since_probe = 0
+        self._occ_known = 0
+        # ordinal of the [D, B] block being stepped (seq of its stage
+        # spans) and the steps' round counts still on their way to the
+        # host (handed to DEVICE_STATS once landed, never waited for)
+        self._block_seq = 0
+        self._rounds_sent: deque = deque()
         # host-side staging buffers for [D, B] blocks
         self._buf_keys: list[np.ndarray] = []
         self._buf_panes: list[np.ndarray] = []
@@ -232,6 +243,12 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             self._mesh, defs, capacity=capacity or self._capacity,
             ring=self._ring, max_parallelism=self._max_parallelism,
             base_range=self._base_range)
+        # the state that is replaced (grow, restore, rescale) goes before
+        # the new one is built, and with it what was known of it
+        self._state = None
+        self._probe = None
+        self._blocks_since_probe = 0
+        self._occ_known = 0
         self._state = self._agg.init_state()
 
     # -- data path ---------------------------------------------------------
@@ -271,67 +288,130 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         (valid mask) drains the remainder."""
         if self._agg is None or self._buf_n == 0:
             return
-        D, B = self._n_devices, self._device_batch
-        full = D * B
-        keys = np.concatenate(self._buf_keys)
-        panes = np.concatenate(self._buf_panes)
-        cols = {n: np.concatenate(vs) for n, vs in self._buf_cols.items()}
-        pos, total = 0, len(keys)
-        while total - pos >= full:
-            self._step_block(keys[pos:pos + full], panes[pos:pos + full],
-                             {n: c[pos:pos + full] for n, c in cols.items()},
-                             n_valid=full)
-            pos += full
-        rem = total - pos
-        if pad and rem:
-            pk = np.zeros(full, np.int64)
-            pp = np.zeros(full, np.int64)
-            pk[:rem] = keys[pos:]
-            pp[:rem] = panes[pos:]
-            pc = {}
-            for n, c in cols.items():
-                buf = np.zeros(full, c.dtype)
-                buf[:rem] = c[pos:]
-                pc[n] = buf
-            self._step_block(pk, pp, pc, n_valid=rem)
-            pos = total
+        full = self._n_devices * self._device_batch
+        staged = None
+        pos, total = 0, self._buf_n
+        while total - pos >= full or (pad and total > pos):
+            n_valid = min(full, total - pos)
+            self._block_seq += 1
+            with self._upload_stage() as up:
+                if staged is None:
+                    staged = self._concat_staged()
+                block = self._upload_block(staged, pos, n_valid)
+                nbytes = pytree_nbytes(block)
+                up.set("bytes", nbytes)
+                DEVICE_STATS.note_h2d(nbytes, n_valid)
+            with self._dispatch_stage():
+                self._step_block(*block)
+            pos += n_valid
+        if pos == 0:
+            return
+        keys, panes, cols = staged
         self._buf_keys = [keys[pos:]] if pos < total else []
         self._buf_panes = [panes[pos:]] if pos < total else []
         self._buf_cols = ({n: [c[pos:]] for n, c in cols.items()}
                           if pos < total else {})
         self._buf_n = total - pos
 
-    def _step_block(self, keys: np.ndarray, panes: np.ndarray,
-                    cols: dict[str, np.ndarray], n_valid: int) -> None:
+    # -- stage spans of one block (metrics/tracing.py Stage) ---------------
+    def _upload_stage(self):
+        """window/Upload: concatenating the staged host batches, cutting
+        one [D, B] block and its host->device copies (device/H2D nests
+        under it); the caller sets ``bytes``."""
+        return TRACER.stage("window", "Upload", seq=self._block_seq,
+                            total=(self.stage_s, "ingest"))
+
+    def _dispatch_stage(self):
+        """window/IngestDispatch: the host's time to enqueue the block's
+        step (the devices run it later) and to look at the pressure
+        probe."""
+        return TRACER.stage("window", "IngestDispatch", seq=self._block_seq,
+                            total=(self.stage_s, "ingest"))
+
+    def _concat_staged(self) -> tuple:
+        return (np.concatenate(self._buf_keys),
+                np.concatenate(self._buf_panes),
+                {n: np.concatenate(vs) for n, vs in self._buf_cols.items()})
+
+    def _upload_block(self, staged: tuple, pos: int, n_valid: int) -> tuple:
+        """Rows [pos, pos + n_valid) of the staged columns as device
+        arrays of shape [D, B] (keys, cols, panes, valid), zero-padded
+        where the block is not full."""
         D, B = self._n_devices, self._device_batch
-        valid = np.zeros(D * B, bool)
+        full = D * B
+
+        def cut(col: np.ndarray) -> jax.Array:
+            if n_valid < full:
+                buf = np.zeros(full, col.dtype)
+                buf[:n_valid] = col[pos:pos + n_valid]
+            else:
+                buf = col[pos:pos + full]
+            return jnp.asarray(buf.reshape(D, B))
+
+        keys, panes, cols = staged
+        valid = np.zeros(full, bool)
         valid[:n_valid] = True
-        dkeys = jnp.asarray(keys.reshape(D, B))
-        dpanes = jnp.asarray(panes.reshape(D, B))
-        dvalid = jnp.asarray(valid.reshape(D, B))
-        dcols = {n: jnp.asarray(c.reshape(D, B)) for n, c in cols.items()}
-        self._state, _processed = self._agg.step(
+        return (cut(keys), {n: cut(c) for n, c in cols.items()},
+                cut(panes), jnp.asarray(valid.reshape(D, B)))
+
+    def _step_block(self, dkeys: jax.Array, dcols: dict, dpanes: jax.Array,
+                    dvalid: jax.Array) -> None:
+        # the old state is donated to the step: nothing may keep a handle
+        # on it (fires and probes enqueued on it earlier stay valid)
+        self._state, _processed, n_rounds = self._agg.step(
             self._state, dkeys, dcols, dpanes, dvalid)
+        n_rounds.copy_to_host_async()
+        self._rounds_sent.append(n_rounds)
+        self._note_rounds()
         self._blocks_since_probe += 1
+        self._pressure_probe(at_block=True)
+
+    def _note_rounds(self, block: bool = False) -> None:
+        """Hand the steps' exchange-round counts to DEVICE_STATS: those
+        whose copy has landed (all of them with ``block``: finish and
+        snapshot sync anyway). Never waits in the hot loop, so the
+        counters trail the devices by the steps in flight."""
+        sent = self._rounds_sent
+        steps = rounds = 0
+        while sent and (block or sent[0].is_ready()):
+            # lint: sync-ok the copy has landed (or the caller syncs anyway)
+            rounds += int(np.asarray(sent.popleft()))
+            steps += 1
+        if steps:
+            DEVICE_STATS.note_mesh_steps(steps, rounds)
 
     # -- firing (fire loop lives in SliceControlPlane) ----------------------
     def _pre_fire_flush(self) -> None:
         self._flush(pad=True)
         self._pressure_probe()
 
-    def _pressure_probe(self) -> None:
+    def _pressure_probe(self, at_block: bool = False) -> None:
         """Proactive growth WITHOUT stalling the pipeline: an async scalar
         probe (max shard occupancy + total drops) is dispatched at
         watermark cadence and consumed whenever its copy has landed; the
         growth decision adds a margin for the blocks dispatched since the
         probe, so the table grows before the load factor bites. Drops are
-        still a hard error (also checked on every fire's health scalars)."""
+        still a hard error (also checked on every fire's health scalars).
+
+        The margin counts every row stepped since the probe as a new key
+        on the fullest shard, so it must stay well inside the headroom
+        (0.6 x capacity - the last known occupancy) or a burst of blocks
+        over resident keys reads as pressure. Hence ``at_block`` (after
+        every step): a probe goes out once a quarter of the headroom has
+        been stepped since the last one, and the host waits for it once
+        half has been stepped since it went out: it may run that far
+        ahead of what it knows of the devices and no further. The wait
+        ends when the devices reach the probe, with the blocks stepped
+        since still queued behind it."""
         if self._agg is None:
             return
+        B = self._device_batch
+        headroom = max(1.0, 0.6 * self._agg.capacity - self._occ_known) // B
         if self._probe is not None:
             outs = self._probe
-            if all(leaf.is_ready()
-                   for leaf in jax.tree_util.tree_leaves(outs)):
+            wait = at_block and self._blocks_since_probe >= headroom // 2
+            if wait or all(leaf.is_ready()
+                           for leaf in jax.tree_util.tree_leaves(outs)):
                 occ, dropped = jax.device_get(outs)
                 self._probe = None
                 if int(dropped) > self._dropped_seen:
@@ -340,16 +420,17 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                         f"dropped (capacity {self._agg.capacity} per "
                         "shard); raise "
                         "state.backend.tpu.slots-per-key-group")
+                self._occ_known = int(occ)
                 # blocks dispatched AFTER the probe are invisible to its
                 # occupancy: pad the growth decision by what they could add
-                margin = self._blocks_since_probe * self._device_batch
-                need = int(occ) + margin
+                need = int(occ) + self._blocks_since_probe * B
                 if need > 0.6 * self._agg.capacity:
                     target = self._agg.capacity
                     while need > 0.6 * target:
                         target *= 2
                     self._grow(target)
-        if self._probe is None and self._blocks_since_probe:
+        if self._probe is None and self._blocks_since_probe >= (
+                max(1, headroom // 4) if at_block else 1):
             outs = _probe_program(self._state.table, self._state.dropped)
             for leaf in jax.tree_util.tree_leaves(outs):
                 leaf.copy_to_host_async()
@@ -469,6 +550,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                 keys = np.asarray(table).reshape(-1)[idx]
                 res = {n: np.asarray(v).reshape(-1)[idx]
                        for n, v in results.items()}
+            DEVICE_STATS.note_d2h(d2h_bytes, len(keys))
         if len(keys):
             with self._emit_stage(fire, len(keys)):
                 self._emit_rows(p_end, keys, res)
@@ -541,6 +623,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
     def snapshot_state(self, checkpoint_id: int) -> dict:
         self._flush(pad=True)
         self._drain(block=True)
+        self._note_rounds(block=True)
         snap = {"keyed": {"backend": self._snapshot_backend(),
                           "meta": self._control_meta()}}
         # coordinator-driven live rescale rides the aligned-barrier
@@ -748,14 +831,18 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                     acc[:, np.asarray(jax.device_get(slots))] = \
                         vals[a.name][:, sel]
                 accs[a.name][d] = acc
+        # host arrays go to their shards directly: through jnp.asarray the
+        # whole [D, ...] array would sit on one device first
         sharding = self._agg._sharding
+        self._state = None
         self._state = ShardedWindowState(
-            table=jax.device_put(jnp.asarray(tables), sharding),
-            accs={n: jax.device_put(jnp.asarray(v), sharding)
-                  for n, v in accs.items()},
-            dropped=jax.device_put(jnp.zeros(D, jnp.int64), sharding))
+            table=jax.device_put(tables, sharding),
+            accs={n: jax.device_put(v, sharding) for n, v in accs.items()},
+            dropped=jax.device_put(np.zeros(D, np.int64), sharding))
+        self._occ_known = int((tables != np.int64(EMPTY_KEY)).sum(1).max())
 
     # -- teardown ----------------------------------------------------------
     def finish(self) -> None:
         self._flush(pad=True)
         self._drain(block=True)
+        self._note_rounds(block=True)

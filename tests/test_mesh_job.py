@@ -483,3 +483,278 @@ class TestMeshHotLoop:
             mesh = max(mesh, timed(_mesh_op(
                 w, capacity=1 << 13, device_batch=256, async_fire=True)))
         assert mesh >= single / 4, (mesh, single)
+
+
+# ---------------------------------------------------------------------------
+# PR 27: Q5 with a hot set against the plain reference and the one-chip
+# operator; the donated step under grow / restore / rescale / async fires;
+# exchange rounds and transfers counted; the pressure probe's margin
+
+Q5_SCHEMA = Schema([("auction", np.int64), ("price", np.int64),
+                    ("ts", np.int64)])
+Q5 = dict(n_keys=3000, n_events=1 << 14, batch=1 << 10, pane_ms=500,
+          panes=4, topk=20)
+
+
+def _q5_columns(idx):
+    """Half the bids go to 16 hot auctions (NEXmark's hotAuctionRatio 2),
+    the rest spread over all keys; in timestamp order."""
+    h = (idx.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)) \
+        >> np.uint64(17)
+    hot = (h % np.uint64(2)) == 0
+    auction = np.where(hot, 7 + 131 * ((h >> np.uint64(1)) % np.uint64(16)),
+                       (h >> np.uint64(5)) % np.uint64(Q5["n_keys"]))
+    return {"auction": auction.astype(np.int64),
+            "price": ((h >> np.uint64(9)) % np.uint64(1 << 30)
+                      ).astype(np.int64) + 1,
+            "ts": idx.astype(np.int64) // 4}
+
+
+def _run_q5(aggregate, traces=False):
+    from flink_tpu.api import StreamExecutionEnvironment
+    from flink_tpu.core import WatermarkStrategy
+    from flink_tpu.core.config import PipelineOptions, TraceOptions
+    from flink_tpu.runtime.operators.device_window import AggSpec
+    from flink_tpu.window import SlidingEventTimeWindows
+    import chip_smoke
+
+    env = StreamExecutionEnvironment.get_execution_environment()
+    env.set_state_backend("tpu")
+    env.config.set(PipelineOptions.BATCH_SIZE, Q5["batch"])
+    env.config.set(TraceOptions.ENABLED, traces)
+    env.config.set("state.backend.tpu.host-index", False)
+    ws = WatermarkStrategy.for_monotonous_timestamps() \
+        .with_timestamp_column("ts")
+    sink = chip_smoke._collecting_sink()
+    windowed = (env.datagen(_q5_columns, Q5_SCHEMA, count=Q5["n_events"],
+                            timestamp_column="ts", watermark_strategy=ws)
+                .key_by("auction")
+                .window(SlidingEventTimeWindows.of(
+                    Q5["panes"] * Q5["pane_ms"], Q5["pane_ms"])))
+    aggregate(windowed, [AggSpec("count", out_name="bids"),
+                         AggSpec("sum", "price", out_name="revenue")]
+              ).add_sink(sink, "collect")
+    env.execute("q5-hot", timeout=300.0)
+    rows = {name: np.concatenate([b[name] for b in sink.batches])
+            for name in sink.batches[0]}
+    return rows, env.last_job
+
+
+def _check_against_reference(rows):
+    """Every emitted row equals the benchmark's plain numpy reference
+    (benchmarks/queries/q5_reference.py) and every window is a correct
+    top-k; no window missing, none besides."""
+    from benchmarks.queries.q5_reference import Q5Reference, check_window
+
+    seen = set()
+
+    def on_window(end_ms, bids, rev):
+        if not bids.any():
+            return
+        sel = rows["window_end"] == end_ms
+        assert sel.any(), f"window {end_ms} missing"
+        seen.add(end_ms)
+        v = check_window(rows["auction"][sel], rows["bids"][sel],
+                         rows["revenue"][sel], bids, rev, Q5["topk"])
+        assert (v.rows_differ, v.topk_wrong) == (0, 0), (end_ms, v.detail)
+        assert (rows["window_start"][sel]
+                == end_ms - Q5["panes"] * Q5["pane_ms"]).all()
+
+    ref = Q5Reference(Q5["n_keys"], Q5["pane_ms"], Q5["panes"], on_window)
+    cols = _q5_columns(np.arange(Q5["n_events"]))
+    ref.feed(cols["auction"], cols["price"], cols["ts"])
+    ref.close()
+    assert seen == set(np.unique(rows["window_end"]).tolist())
+
+
+@pytest.fixture(scope="module")
+def q5_one_chip_rows():
+    rows, _job = _run_q5(lambda w, aggs: w.device_aggregate(
+        aggs, capacity=1 << 13, ring_size=16, emit_window_bounds=True,
+        emit_topk=Q5["topk"], defer_overflow=True, async_fire=True))
+    return rows
+
+
+@pytest.mark.parametrize("n_devices", [4, 8])
+def test_q5_with_a_hot_set_equals_reference_and_one_chip(
+        n_devices, q5_one_chip_rows):
+    import chip_smoke
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.runtime.operators.mesh_window import \
+        MeshWindowAggOperator
+
+    before = DEVICE_STATS.snapshot()
+    rows, job = _run_q5(lambda w, aggs: w.mesh_aggregate(
+        aggs, n_devices=n_devices, capacity=1 << 11, ring_size=16,
+        device_batch=Q5["batch"] // n_devices, emit_window_bounds=True,
+        emit_topk=Q5["topk"], async_fire=True))
+    after = DEVICE_STATS.snapshot()
+    _check_against_reference(rows)
+    _check_against_reference(q5_one_chip_rows)
+    chip_smoke.check_same_answer(rows, q5_one_chip_rows)
+    op = next(o for t in job.tasks.values()
+              for o in getattr(getattr(t, "chain", None), "operators", ())
+              if isinstance(o, MeshWindowAggOperator))
+    assert op._agg.capacity == 1 << 11 and op.late_dropped == 0
+    assert len({s.device.id for s in op._state.table.addressable_shards}) \
+        == n_devices
+    # one step a batch, one exchange round a step (16 hot keys over the
+    # shards never fill a bucket of a slice's share + 25%), every
+    # uploaded block and every fire's rows counted
+    steps = after["mesh_steps_total"] - before["mesh_steps_total"]
+    assert steps == Q5["n_events"] // Q5["batch"]
+    rounds = (after["mesh_exchange_rounds_total"]
+              - before["mesh_exchange_rounds_total"])
+    assert steps <= rounds <= 2 * steps
+    assert (after["h2d_bytes"] - before["h2d_bytes"]
+            == Q5["n_events"] * (3 * 8 + 1))
+    assert after["d2h_bytes"] > before["d2h_bytes"]
+
+
+def test_mesh_blocks_have_upload_and_dispatch_stage_spans():
+    from flink_tpu.metrics.tracing import TRACER
+
+    TRACER.reset()
+    try:
+        _rows, _job = _run_q5(lambda w, aggs: w.mesh_aggregate(
+            aggs, n_devices=4, capacity=1 << 11, ring_size=16,
+            device_batch=Q5["batch"] // 4, emit_topk=Q5["topk"],
+            async_fire=True), traces=True)
+        spans = TRACER.retained_spans()
+    finally:
+        TRACER.reset()
+    named = {n: sorted((s for s in spans
+                        if (s.scope, s.name) == ("window", n)),
+                       key=lambda s: s.start_ns)
+             for n in ("Upload", "IngestDispatch")}
+    n_blocks = Q5["n_events"] // Q5["batch"]
+    assert len(named["Upload"]) == len(named["IngestDispatch"]) == n_blocks
+    for i, (up, disp) in enumerate(zip(named["Upload"],
+                                       named["IngestDispatch"])):
+        assert up.attributes["seq"] == disp.attributes["seq"] == i + 1
+        assert up.attributes["task"] == disp.attributes["task"]
+        assert up.attributes["bytes"] == Q5["batch"] * (3 * 8 + 1)
+        assert up.end_ns <= disp.start_ns and up.parent_id == disp.parent_id
+    h2d = [s for s in spans if (s.scope, s.name) == ("device", "H2D")]
+    assert {s.parent_id for s in h2d} == {u.span_id
+                                          for u in named["Upload"]}
+
+
+class TestMeshDonatedState:
+    """The step consumes its state. Every path that takes a state out of
+    the operator or puts one in must still give exact rows."""
+
+    @pytest.mark.parametrize("async_fire", [False, True])
+    def test_fires_restore_rescale_and_grow_across_steps(self, async_fire):
+        from flink_tpu.runtime import OneInputOperatorTestHarness
+        from flink_tpu.window import SlidingEventTimeWindows
+
+        w = SlidingEventTimeWindows.of(1000, 250)
+        elements, ts = _gen(31, 2400, n_keys=700, t_max=6000)
+        host = _host_window_result(elements, ts, w)
+        cuts = [400, 800, 1200, 1600, 2000, 2400]
+        kw = dict(capacity=1 << 8, device_batch=16, async_fire=async_fire)
+
+        def feed(h, lo, hi):
+            # watermarks inside a chunk leave fires pending (async) while
+            # later blocks step on, donating the state the fires read
+            for a in range(lo, hi, 100):
+                b = min(a + 100, hi)
+                h.process_elements(elements[a:b], ts[a:b])
+                h.process_watermark(ts[b - 1] - 1)
+
+        h1 = OneInputOperatorTestHarness(_mesh_op(w, 4, **kw),
+                                         schema=SCHEMA)
+        feed(h1, 0, cuts[0])
+        old = h1.operator._state
+        feed(h1, cuts[0], cuts[1])
+        assert old.table.is_deleted() and all(
+            v.is_deleted() for v in old.accs.values())
+        snap = h1.operator.snapshot_state(1)["keyed"]
+        out = [(int(k), int(v)) for k, v in h1.get_output()]
+
+        h2 = OneInputOperatorTestHarness(_mesh_op(w, 4, **kw),
+                                         schema=SCHEMA)
+        h2.open(keyed_snapshots=[snap])
+        op = h2.operator
+        feed(h2, cuts[1], cuts[2])
+        assert op.rescale_live(2)["new_devices"] == 2
+        feed(h2, cuts[2], cuts[3])
+        assert op.rescale_live(4)["new_devices"] == 4
+        feed(h2, cuts[3], cuts[4])
+        grown_from = op._agg.capacity
+        op._grow(2 * grown_from)
+        feed(h2, cuts[4], cuts[5])
+        h2.process_watermark(10**9)
+        op.finish()
+        assert op._agg.capacity >= 2 * grown_from and op._n_devices == 4
+        out += [(int(k), int(v)) for k, v in h2.get_output()]
+        assert sorted(out) == host
+
+    def test_skewed_blocks_are_counted_and_lose_nothing(self):
+        """Every key owned by ONE shard: a block of D x B rows takes
+        ceil(B / round capacity) exchange rounds, and the counters say
+        so."""
+        from flink_tpu.core.keygroups import hash_batch, \
+            key_groups_for_hash_batch
+        from flink_tpu.metrics import DEVICE_STATS
+        from flink_tpu.parallel import bucket_capacity, shard_ranges
+        from flink_tpu.runtime import OneInputOperatorTestHarness
+        from flink_tpu.window import TumblingEventTimeWindows
+
+        D, B = 4, 64
+        pool = np.arange(5000, dtype=np.int64)
+        groups = key_groups_for_hash_batch(hash_batch(pool), 128)
+        rng = shard_ranges(128, D)[2]
+        mine = pool[(groups >= rng.start) & (groups <= rng.end)][:32]
+        n = 3 * D * B
+        elements = [(int(mine[i % 32]), 1) for i in range(n)]
+        op = _mesh_op(TumblingEventTimeWindows.of(10**6), D,
+                      capacity=1 << 8, device_batch=B)
+        h = OneInputOperatorTestHarness(op, schema=SCHEMA)
+        before = DEVICE_STATS.snapshot()
+        h.process_elements(elements, list(range(n)))
+        h.process_watermark(10**9)
+        op.finish()
+        after = DEVICE_STATS.snapshot()
+        steps = after["mesh_steps_total"] - before["mesh_steps_total"]
+        rounds = (after["mesh_exchange_rounds_total"]
+                  - before["mesh_exchange_rounds_total"])
+        assert steps == 3
+        assert rounds == 3 * -(-B // bucket_capacity(B, D)) > steps
+        got = sorted((int(k), int(v)) for k, v in h.get_output())
+        assert got == sorted((int(k), n // 32) for k in mine)
+
+    def test_a_burst_over_resident_keys_does_not_grow_the_table(self):
+        """Occupancy 0.47 of a shard, then 64 blocks back to back with no
+        watermark between them, all over resident keys: the probe's margin
+        (rows stepped since the occupancy was last known) stays inside
+        the headroom to the growth threshold, because a probe goes out
+        and is waited for before it can leave it. The parent's operator
+        doubled its table here."""
+        from flink_tpu.runtime import OneInputOperatorTestHarness
+        from flink_tpu.window import TumblingEventTimeWindows
+
+        D, B, cap = 4, 64, 1 << 12
+        n_keys = int(0.47 * cap * D)
+        op = _mesh_op(TumblingEventTimeWindows.of(10**7), D, capacity=cap,
+                      device_batch=B, async_fire=True)
+        h = OneInputOperatorTestHarness(op, schema=SCHEMA)
+        keys = np.arange(n_keys)
+        for lo in range(0, n_keys, 1024):          # prefill, with probes
+            part = keys[lo:lo + 1024]
+            h.process_elements([(int(k), 1) for k in part],
+                               [lo // 1024] * len(part))
+            h.process_watermark(lo // 1024)
+        occ = int(np.asarray((op._state.table != np.iinfo(np.int64).max)
+                             .sum(axis=1).max()))
+        assert 0.4 * cap < occ < 0.6 * cap
+        burst = np.random.default_rng(5).integers(0, n_keys, 64 * D * B)
+        h.process_elements([(int(k), 1) for k in burst],
+                           [100] * len(burst))
+        h.process_watermark(10**9)
+        op.finish()
+        assert op._agg.capacity == cap
+        out = sorted((int(k), int(v)) for k, v in h.get_output())
+        want = np.bincount(burst, minlength=n_keys) + 1
+        assert out == [(k, int(want[k])) for k in range(n_keys)]

@@ -73,7 +73,7 @@ def test_sharded_step_matches_host(agg8):
         valid = rng.rand(D, B) < 0.9
         all_k.append(keys[valid]); all_v.append(vals[valid])
         all_p.append(panes[valid])
-        state, processed = agg.step(
+        state, processed, _rounds = agg.step(
             state, jnp.asarray(keys), {"price": jnp.asarray(vals)},
             jnp.asarray(panes), jnp.asarray(valid))
         assert int(processed) == int(valid.sum())
@@ -117,7 +117,7 @@ def test_fire_merges_panes_and_retire(agg8):
     vals = np.ones((D, B))
     for pane in (0, 1, 2):
         panes = np.full((D, B), pane, np.int64)
-        state, _ = agg.step(state, jnp.asarray(keys),
+        state, _, _ = agg.step(state, jnp.asarray(keys),
                             {"price": jnp.asarray(vals)},
                             jnp.asarray(panes),
                             jnp.ones((D, B), bool))
@@ -140,12 +140,138 @@ def test_overflow_reports_dropped():
     D, B = 8, 64
     rng = np.random.RandomState(1)
     keys = rng.randint(0, 10**9, (D, B)).astype(np.int64)
-    state, processed = agg.step(
+    state, processed, _rounds = agg.step(
         state, jnp.asarray(keys), {"v": jnp.ones((D, B))},
         jnp.zeros((D, B), np.int64), jnp.ones((D, B), bool))
     dropped = int(jax.device_get(state.dropped).sum())
     assert dropped > 0
     assert int(processed) + dropped == D * B
+
+
+# -- the state is built where it lives and donated through the step (PR 27) --
+
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_init_state_is_built_shard_by_shard(n_dev):
+    """Same values, shardings and pytree as a tiled state put on the mesh,
+    and no buffer beyond a device's own shards while it is built: the
+    initialiser's outputs ARE the shards and it has no temporaries."""
+    from flink_tpu.ops.hash_table import EMPTY_KEY
+    from flink_tpu.parallel.sharded_window import ShardedWindowState
+
+    cap, ring = 1 << 10, 8
+    agg = ShardedWindowAgg(
+        make_mesh(n_dev), [AggDef("bids", "count", jnp.int64),
+                           AggDef("lo", "min", jnp.int32),
+                           AggDef("hi", "max", jnp.float32)],
+        capacity=cap, ring=ring, max_parallelism=MP)
+    state = agg.init_state()
+    assert isinstance(state, ShardedWindowState)
+    assert set(state.accs) == {"bids", "lo", "hi"}
+    want = {"table": ((n_dev, cap), np.int64, EMPTY_KEY),
+            "bids": ((n_dev, ring, cap), np.int64, 0),
+            "lo": ((n_dev, ring, cap), np.int32, np.iinfo(np.int32).max),
+            "hi": ((n_dev, ring, cap), np.float32,
+                   np.finfo(np.float32).min),
+            "dropped": ((n_dev,), np.int64, 0)}
+    leaves = {"table": state.table, "dropped": state.dropped, **state.accs}
+    for name, (shape, dtype, value) in want.items():
+        leaf = leaves[name]
+        assert leaf.shape == shape and leaf.dtype == dtype, name
+        assert leaf.sharding == agg.plan.state_sharding, name
+        assert (np.asarray(jax.device_get(leaf)) == value).all(), name
+        shards = leaf.addressable_shards
+        assert sorted(s.device.id for s in shards) == sorted(
+            d.id for d in agg.mesh.devices.flat), name
+        assert all(s.data.shape == (1,) + shape[1:] for s in shards), name
+    compiled = agg.init_program().lower().compile()
+    for sharding in jax.tree.leaves(compiled.output_shardings):
+        assert sharding == agg.plan.state_sharding
+    mem = compiled.memory_analysis()
+    shard_bytes = sum(int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+                      for shape, dtype, _v in want.values())
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes <= shard_bytes + 1024
+
+
+def _one_step(agg, state, keys, panes=None):
+    D, B = keys.shape
+    return agg.step(
+        state, jnp.asarray(keys), {"price": jnp.ones((D, B))},
+        jnp.zeros((D, B), np.int64) if panes is None else panes,
+        jnp.ones((D, B), bool))
+
+
+def test_step_and_retire_donate_the_state(agg8):
+    _mesh, agg = agg8
+    keys = np.arange(8 * 32, dtype=np.int64).reshape(8, 32)
+    old = agg.init_state()
+    new, processed, rounds = _one_step(agg, old, keys)
+    assert int(processed) == keys.size and int(rounds) >= 1
+    for leaf in jax.tree.leaves(old):
+        assert leaf.is_deleted()
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(old.table)
+    # a fire enqueued on a state stays readable after that state has been
+    # stepped on (donated) and retired: programs already enqueued on the
+    # old buffers stay valid, only Python handles on them do not
+    out, emit = agg.fire(new, np.array([0], np.int32))
+    newer, _p, _r = _one_step(agg, new, keys)
+    retired = agg.retire_row(newer, 0)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(newer.accs))
+    assert not retired.table.is_deleted()
+    counts = np.asarray(jax.device_get(out["__count__"]))
+    assert counts[np.asarray(jax.device_get(emit))].sum() == keys.size
+    out, emit = agg.fire(retired, np.array([0], np.int32))
+    assert not np.asarray(jax.device_get(emit)).any()
+
+
+def test_unranked_fire_hands_back_a_table_of_its_own(agg8):
+    """Without a top-k the fused fire returns the key table; it must be a
+    copy, or the next step would donate the buffer under the pending
+    fire."""
+    _mesh, agg = agg8
+    keys = np.arange(8 * 16, dtype=np.int64).reshape(8, 16)
+    state, _p, _r = _one_step(agg, agg.init_state(), keys)
+    table, emit, _res, _dropped, _occ = agg.fire_compact(
+        state, np.array([0], np.int32), np.array([True]), None, None)
+    state, _p, _r = _one_step(agg, state, keys)
+    got = np.asarray(jax.device_get(table))[np.asarray(
+        jax.device_get(emit))]
+    assert sorted(got.tolist()) == list(range(keys.size))
+
+
+@pytest.mark.parametrize("n_dev", [4, 8])
+def test_skewed_batch_takes_more_rounds_and_loses_nothing(n_dev):
+    """Every row of a [D, B] block bound for ONE shard: the exchange takes
+    ceil(B / round capacity) rounds (the deepest bucket is a whole slice),
+    a spread block takes one, and both fold every row."""
+    from flink_tpu.parallel import bucket_capacity
+
+    B = 256
+    agg = ShardedWindowAgg(
+        make_mesh(n_dev), [AggDef("price", "sum", jnp.float64)],
+        capacity=1 << 12, ring=4, max_parallelism=MP)
+    pool = np.arange(20_000, dtype=np.int64)
+    groups = key_groups_for_hash_batch(hash_batch(pool), MP)
+    mine = pool[(groups >= agg.shard_ranges[1].start)
+                & (groups <= agg.shard_ranges[1].end)][:97]
+    skewed = np.resize(mine, (n_dev, B))
+    state, processed, rounds = _one_step(agg, agg.init_state(), skewed)
+    cap_x = bucket_capacity(B, n_dev)
+    assert int(rounds) == -(-B // cap_x) > 1
+    assert int(processed) == n_dev * B
+    assert int(jax.device_get(state.dropped).sum()) == 0
+    table = np.asarray(jax.device_get(state.table))
+    assert sorted(table[1][table[1] != np.iinfo(np.int64).max]) \
+        == sorted(mine)
+    out, emit = agg.fire(state, np.array([0], np.int32))
+    counts = np.asarray(jax.device_get(out["__count__"]))
+    emit = np.asarray(jax.device_get(emit))
+    assert counts[emit].sum() == n_dev * B and not emit[0].any()
+    # the same rows spread round-robin over their owners take one round
+    spread = pool[:n_dev * B].reshape(n_dev, B)
+    state, processed, rounds = _one_step(agg, state, spread)
+    assert int(rounds) == 1 and int(processed) == n_dev * B
 
 
 def test_global_topk():

@@ -43,29 +43,72 @@ def test_pallas_topk_compiles_under_x64(v5e_devices):
         jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one)).compile()
 
 
-@pytest.mark.parametrize("n_dev", [1, 4])
-def test_mesh_step_compiles(v5e_devices, n_dev):
+def _q5_mesh(devices, capacity: int, batch: int):
+    """Q5's sharded aggregate (COUNT + SUM, both int64, ring 16) on a mesh
+    of described devices, and the step's arguments as shapes on it."""
     from flink_tpu.parallel.sharded_window import AggDef, ShardedWindowAgg, \
         ShardedWindowState
 
-    mesh = Mesh(np.array(v5e_devices[:n_dev]), ("data",))
+    mesh = Mesh(np.array(devices), ("data",))
     agg = ShardedWindowAgg(
         mesh, [AggDef("bids", "count", jnp.int64),
                AggDef("revenue", "sum", jnp.int64)],
-        capacity=1 << 10, ring=16, max_parallelism=128)
+        capacity=capacity, ring=16, max_parallelism=128)
     sharded = NamedSharding(mesh, P("data"))
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
 
-    D, B, cap, ring = n_dev, 256, agg.capacity, agg.ring
+    D, B = len(devices), batch
     state = ShardedWindowState(
-        spec((D, cap), jnp.int64),
-        {a.name: spec((D, ring, cap), jnp.int64) for a in agg.aggs},
+        spec((D, capacity), jnp.int64),
+        {a.name: spec((D, agg.ring, capacity), jnp.int64)
+         for a in agg.aggs},
         spec((D,), jnp.int64))
-    jax.jit(agg.step).lower(
-        state, spec((D, B), jnp.int64), {"revenue": spec((D, B), jnp.int64)},
-        spec((D, B), jnp.int64), spec((D, B), jnp.bool_)).compile()
+    args = (state, spec((D, B), jnp.int64),
+            {"revenue": spec((D, B), jnp.int64)}, spec((D, B), jnp.int64),
+            spec((D, B), jnp.bool_))
+    return agg, sharded, args
+
+
+#: a device's shard of the benchmark's state (q5-16m-mesh4): a 2^23-slot
+#: table, two [16, 2^23] int64 planes, the drop counter
+_SHARD_BYTES = (1 << 23) * 8 * (1 + 2 * 16) + 8
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_mesh_step_compiles(v5e_devices, n_dev):
+    agg, _sharded, args = _q5_mesh(v5e_devices[:n_dev], 1 << 10, 256)
+    jax.jit(agg.step).lower(*args).compile()
+
+
+def test_mesh_state_is_built_shard_by_shard_at_the_benchmark_shape(
+        v5e_devices):
+    """[4, 16, 2^23] int64 x 2 planes on a v5e 2x2 (q5-16m-mesh4): the
+    initialiser writes each device's own 2.2 GB shard and nothing else.
+    Tiled on one device and then cut, the same state wanted 4.5 GB with
+    3.4 GB free and the job died building it (PR 24, chip call 8)."""
+    agg, sharded, _args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
+    compiled = agg.init_program().lower().compile()
+    assert all(s == sharded
+               for s in jax.tree.leaves(compiled.output_shardings))
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes <= _SHARD_BYTES + 4096
+    assert (mem.output_size_in_bytes + mem.temp_size_in_bytes
+            + mem.argument_size_in_bytes) < 3e9
+
+
+def test_mesh_step_donates_its_state_at_the_benchmark_shape(v5e_devices):
+    """The step at [4, 65536] rows against [4, 16, 2^23] planes, as the
+    operator dispatches it (donation included): the state is aliased into
+    the outputs (no second 2.2 GB of state a step) and the program fits a
+    16 GB chip with room."""
+    agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
+    mem = agg.step_program().lower(
+        *args, agg._base_start, agg._base_len).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= _SHARD_BYTES - 4096
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 8e9
 
 
 #: the path by which the benchmark's probe_rounds_p50 finds the probe
