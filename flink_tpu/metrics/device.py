@@ -83,6 +83,11 @@ class DeviceStats:
         # drain accounting (PR 25): non-blocking drains that found the
         # oldest queued fire's device->host copy not landed yet
         self._fire_unready_polls = 0
+        # fires taken off the async fire queue and their rows emitted, and
+        # those of them that left on the mailbox's processing-time turn
+        # (PR 29) and not on a batch's or a blocking drain
+        self._fires_drained = 0
+        self._fires_drained_timer = 0
         # hash-probe accounting (PR 26): rows probed by the deferred ingest
         # path, rows still unresolved after the probe's read-only first
         # window (the tail its claiming loop carries), and batches whose
@@ -345,6 +350,17 @@ class DeviceStats:
     def fire_unready_polls(self) -> int:
         with self._lock:
             return self._fire_unready_polls
+
+    def note_fire_drained(self, timer: bool) -> None:
+        with self._lock:
+            self._fires_drained += 1
+            self._fires_drained_timer += bool(timer)
+
+    @property
+    def fires_drained(self) -> tuple[int, int]:
+        """(fires drained, those drained on a processing-time turn)."""
+        with self._lock:
+            return self._fires_drained, self._fires_drained_timer
 
     def note_probe(self, rows: int, tail_rows: int,
                    wide_batches: int) -> None:
@@ -626,6 +642,8 @@ class DeviceStats:
                 "batches_coalesced_total": self._batches_coalesced,
                 "fire_merge_rows_read": self._fire_merge_rows,
                 "fire_unready_polls_total": self._fire_unready_polls,
+                "fires_drained_total": self._fires_drained,
+                "fires_drained_timer_total": self._fires_drained_timer,
                 "probe_rows_total": self._probe_rows,
                 "probe_tail_rows_total": self._probe_tail_rows,
                 "probe_wide_batches_total": self._probe_wide_batches,
@@ -733,6 +751,7 @@ class DeviceStats:
             self._batches_coalesced = 0
             self._fire_merge_rows = 0
             self._fire_unready_polls = 0
+            self._fires_drained = self._fires_drained_timer = 0
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
@@ -1144,8 +1163,12 @@ def bind_device_metrics(registry) -> None:
     g.gauge("batches_coalesced_total", lambda: s.batches_coalesced)
     g.gauge("fire_merge_rows_read", lambda: s.fire_merge_rows)
     # async fire drain (prometheus:
-    # flink_tpu_device_fire_unready_polls_total)
+    # flink_tpu_device_fire_unready_polls_total /
+    # flink_tpu_device_fires_drained_total /
+    # flink_tpu_device_fires_drained_timer_total)
     g.gauge("fire_unready_polls_total", lambda: s.fire_unready_polls)
+    g.gauge("fires_drained_total", lambda: s.fires_drained[0])
+    g.gauge("fires_drained_timer_total", lambda: s.fires_drained[1])
     # hash probe (prometheus: flink_tpu_device_probe_rows_total /
     # flink_tpu_device_probe_tail_rows_total /
     # flink_tpu_device_probe_wide_batches_total)

@@ -825,7 +825,8 @@ SPAN_INVENTORY: tuple = (
     ("task", "WaitInput",
      "runtime/stream_task.py OneInput/TwoInputStreamTask.invoke — first "
      "empty input poll → the next event, one span per wait (stage span: "
-     "polls); its durations are the task's idle time"),
+     "polls, busy_ms: the processing-time turns worked through inside "
+     "it); its durations less busy_ms are the task's idle time"),
     ("tier", "Evict",
      "state/tpu_backend.py _evict_cold_groups — cold key groups paged "
      "to the host-warm tier + device table rebuild"),
@@ -838,7 +839,9 @@ SPAN_INVENTORY: tuple = (
     ("window", "Drain",
      "runtime/operators/slice_control.py AsyncFireQueue._drain_stage, "
      "used by device_window / mesh_window _materialize — device_get of a "
-     "fire's outputs + host selection/sort; child of Fire (stage span)"),
+     "fire's outputs + host selection/sort; child of Fire (stage span: "
+     "turn — timer, batch or blocking: the kind of mailbox turn that took "
+     "the fire off the queue)"),
     ("window", "Emit",
      "runtime/operators/slice_control.py AsyncFireQueue._emit_stage — "
      "building the window's rows + output.emit; child of Fire (stage "
@@ -846,8 +849,9 @@ SPAN_INVENTORY: tuple = (
     ("window", "Fire",
      "runtime/operators/slice_control.py — root of one span tree per "
      "fired window: _fire entry → its rows emitted, closed from a later "
-     "mailbox turn when fires are async (stage span: window_end_ms, "
-     "rows, d2h_bytes, unready_polls)"),
+     "mailbox turn when fires are async: the first processing-time turn "
+     "after its copy has landed (stage span: window_end_ms, rows, "
+     "d2h_bytes, unready_polls)"),
     ("window", "FireDispatch",
      "runtime/operators/slice_control.py _fire_window — the host's "
      "dispatch of one fire: guarded fire program(s) + ring-row reset; "

@@ -1483,9 +1483,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 res[a.out_name] = ht.fire(a.out_name, pane_rows)[mask]
         return keys, res
 
-    def _materialize(self, item) -> None:
+    def _materialize(self, item, turn: str) -> None:
         p_end, outs, host_part, t0, fire = item
-        with self._drain_stage(fire):
+        with self._drain_stage(fire, turn):
             keys, results, d2h_bytes = self._drain_rows(outs, host_part)
         if len(keys):
             with self._emit_stage(fire, len(keys)):

@@ -531,9 +531,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             self._state = self._agg.retire_row(self._state,
                                                (p_end - W) % self._ring)
 
-    def _materialize(self, item: tuple) -> None:
+    def _materialize(self, item: tuple, turn: str) -> None:
         p_end, outs, _unused, t0, fire = item
-        with self._drain_stage(fire):
+        with self._drain_stage(fire, turn):
             host = jax.device_get(outs)   # ONE transfer for everything
             d2h_bytes = pytree_nbytes(host)
             if self._topk is not None:

@@ -118,12 +118,19 @@ class AsyncFireQueue:
     at dispatch (copy_to_host_async); emission is queued and drained once
     the copy lands, and watermarks are held behind their fires so they
     never overtake results downstream. The hot loop never blocks on a
-    fire. Subclasses implement ``_materialize(item)``; an item is a tuple
-    (pane boundary, device-output pytree, ..., the window's open
-    ``window/Fire`` stage): the stage is the root of one span tree per
-    fired window, which ``_materialize`` parents its ``window/Drain`` and
-    ``window/Emit`` on, from a later mailbox turn, and closes once the
-    window's rows are emitted."""
+    fire. The queue is looked at on every batch AND on the mailbox's
+    processing-time turn (``advance_processing_time``, at most once a
+    millisecond, on empty input polls too), so a window's rows leave on
+    the first turn after their copy has landed whether or not a further
+    batch arrives. Subclasses implement ``_materialize(item, turn)``; an
+    item is a tuple (pane boundary, device-output pytree, ..., the
+    window's open ``window/Fire`` stage): the stage is the root of one
+    span tree per fired window, which ``_materialize`` parents its
+    ``window/Drain`` and ``window/Emit`` on, from a later mailbox turn,
+    and closes once the window's rows are emitted; ``turn`` says which
+    kind of turn took the fire off the queue (``timer``, ``batch``, or
+    ``blocking``: a barrier, finish, growth, rescale or synchronous
+    fire that waits for it)."""
 
     _async: bool
 
@@ -149,11 +156,11 @@ class AsyncFireQueue:
         return TRACER.open_stage("window", "Fire", seq=end_ms,
                                  window_end_ms=end_ms)
 
-    def _drain_stage(self, fire: Stage) -> Stage:
+    def _drain_stage(self, fire: Stage, turn: str) -> Stage:
         """window/Drain: the device_get of a fire's outputs + the host
         selection / sort, a child of the window's Fire."""
         return TRACER.stage("window", "Drain", parent=fire.context,
-                            seq=fire.attrs["seq"],
+                            seq=fire.attrs["seq"], turn=turn,
                             total=(self.stage_s, "drain"))
 
     def _emit_stage(self, fire: Stage, rows: int) -> Stage:
@@ -166,9 +173,20 @@ class AsyncFireQueue:
         fire.close(rows=rows, d2h_bytes=d2h_bytes,
                    unready_polls=fire.attrs.get("unready_polls", 0))
 
-    def _drain(self, block: bool = False) -> None:
+    def advance_processing_time(self, now_ms: int) -> None:
+        """The mailbox's processing-time turn: a fire whose copy has
+        landed leaves now, not when the next batch arrives."""
+        super().advance_processing_time(now_ms)
+        if self._async and self._pending:
+            self._drain(turn="timer")
+
+    def _drain(self, block: bool = False, turn: str = "batch") -> None:
         import jax
 
+        from ...metrics.device import DEVICE_STATS
+
+        if block:
+            turn = "blocking"
         while self._pending:
             head = self._pending[0]
             if isinstance(head, Watermark):
@@ -179,11 +197,11 @@ class AsyncFireQueue:
                     leaf.is_ready()
                     for leaf in jax.tree_util.tree_leaves(head[1])):
                 head[-1].count("unready_polls")
-                from ...metrics.device import DEVICE_STATS
                 DEVICE_STATS.note_fire_unready_poll()
                 return
             self._pending.popleft()
-            self._materialize(head)
+            self._materialize(head, turn)
+            DEVICE_STATS.note_fire_drained(timer=turn == "timer")
 
     def _emit_watermark_out(self, watermark: Watermark) -> None:
         if self._async and self._pending:
@@ -195,7 +213,7 @@ class AsyncFireQueue:
         if self._async and len(self.fire_latencies_ms) < _MAX_FIRE_SAMPLES:
             self.fire_latencies_ms.append((time.perf_counter() - t0) * 1e3)
 
-    def _materialize(self, item: tuple) -> None:
+    def _materialize(self, item: tuple, turn: str) -> None:
         raise NotImplementedError
 
 

@@ -202,6 +202,7 @@ class StreamTask:
         # how many polls it has seen, and the ordinals its spans carry
         self._wait = None
         self._wait_polls = 0
+        self._wait_busy_s = 0.0  # processing-time turns inside the wait
         self._waits = 0
         self._batches = 0
         metrics = getattr(ctx, "metrics", None)
@@ -300,16 +301,20 @@ class StreamTask:
             self._wait = TRACER.open_stage("task", "WaitInput",
                                            seq=self._waits)
             self._wait_polls = 0
+            self._wait_busy_s = 0.0
         self._wait_polls += 1
 
     def _end_wait(self) -> None:
         """The next event arrived (or the input ended): close the wait;
-        its measured length is the task's idle time."""
+        its measured length, less the processing-time turns the chain
+        worked through inside it (already busy time), is the task's idle
+        time."""
         wait = self._wait
         if wait is not None:
             self._wait = None
-            wait.close(polls=self._wait_polls)
-            self.io_timers.idle_s += wait.duration_s
+            wait.close(polls=self._wait_polls,
+                       busy_ms=round(self._wait_busy_s * 1e3, 3))
+            self.io_timers.idle_s += wait.duration_s - self._wait_busy_s
 
     def _process_batch_stage(self, ev: GateEvent, gate: InputGate):
         """The task/ProcessBatch stage around one dequeued batch."""
@@ -321,11 +326,20 @@ class StreamTask:
 
     # -- helpers -----------------------------------------------------------
     def _advance_processing_time(self, chain: Optional[OperatorChain]) -> None:
+        """The mailbox's processing-time turn, at most once a millisecond.
+        What the chain does in it (timers, completed async requests, a
+        landed fire's drain and emit) is busy time, also when the turn
+        falls inside an open task/WaitInput."""
         now = self.ctx.processing_time()
         if now > self._last_proc_time:
             self._last_proc_time = now
             if chain is not None:
+                t0 = time.perf_counter()
                 chain.advance_processing_time(now)
+                spent = time.perf_counter() - t0
+                self.io_timers.busy_s += spent
+                if self._wait is not None:
+                    self._wait_busy_s += spent
 
 
 class SourceStreamTask(StreamTask):

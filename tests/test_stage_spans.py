@@ -167,6 +167,10 @@ def test_one_fire_tree_per_emitted_window(async_fire):
         drain = next(s for s in tree if s.name == "Drain")
         emit = next(s for s in tree if s.name == "Emit")
         assert drain.end_ns <= emit.start_ns
+        # which kind of mailbox turn took the fire off the queue: a
+        # synchronous fire waits for its own rows
+        assert drain.attributes["turn"] in (
+            ("timer", "batch", "blocking") if async_fire else ("blocking",))
         assert emit.attributes["rows"] == fire.attributes["rows"] > 0
         assert fire.attributes["unready_polls"] >= 0
         assert fire.attributes["d2h_bytes"] > 0
@@ -195,9 +199,14 @@ def test_wait_spans_idle_time_and_batches(traced_run):
     events = n_batches * 2 + 2
     assert 0 < len(waits) <= events + 1
     assert all(w.attributes["polls"] >= 1 for w in waits)
-    # idle time IS the waits (same timing site)
+    # idle time IS the waits (same timing site), less the processing-time
+    # turns the chain worked through inside them, which are busy time
     idle_ns = sum(w.duration_ns for w in waits)
-    assert task.io_timers.idle_s == pytest.approx(idle_ns / 1e9, abs=1e-9)
+    busy_in_waits_s = sum(w.attributes["busy_ms"] for w in waits) / 1e3
+    assert all(0 <= w.attributes["busy_ms"] <= w.duration_ns / 1e6
+               for w in waits)
+    assert task.io_timers.idle_s == pytest.approx(
+        idle_ns / 1e9 - busy_in_waits_s, abs=1e-6 * len(waits))
     # waits and batches of one task never overlap, and alternate in time
     turns = sorted(waits + batches, key=lambda s: s.start_ns)
     for a, b in zip(turns, turns[1:]):
