@@ -248,10 +248,6 @@ def run_q5(leg: str, aggregate: Callable, operator_cls, *, n_keys: int,
     env = StreamExecutionEnvironment.get_execution_environment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, batch)
-    # the chip resolves slots with the XLA probe; the native host index is
-    # the CPU backend's rung, so the tiny CPU run of this leg turns it off
-    # to take the path the chip takes
-    env.config.set("state.backend.tpu.host-index", False)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = _collecting_sink()
@@ -287,15 +283,11 @@ def run_q5(leg: str, aggregate: Callable, operator_cls, *, n_keys: int,
               "h2d_bytes": after["h2d_bytes"] - before["h2d_bytes"],
               "d2h_bytes": after["d2h_bytes"] - before["d2h_bytes"],
               "late_dropped": ops[0].late_dropped,
-              "host_index_active": bool(getattr(
-                  getattr(ops[0], "_backend", None), "host_index_active",
-                  False)),
               "peak_bytes_in_use": _memory("peak_bytes_in_use")}
     for k in FALLBACK_COUNTERS:
         report[k] = after.get(k, 0) - before.get(k, 0)
         assert report[k] == 0, (k, report[k])
     assert report["late_dropped"] == 0, report["late_dropped"]
-    assert report["host_index_active"] is False
     return report, rows, ops
 
 

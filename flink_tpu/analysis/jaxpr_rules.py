@@ -530,8 +530,9 @@ def exercise_programs(n_events: int = 4096, batch: int = 1024,
                 return True
 
         # (fire_mode, device_ingest, fused): device ingest exercises the
-        # coalesced native_fold program, host ingest the per-batch step
-        # program, and the fused run registers the certified chain programs
+        # one-dispatch step program, host ingest the packed upload with
+        # its probe and fold programs, and the fused run registers the
+        # certified chain programs
         # (chain.fused_prelude / chain.fused_step) for JX601-603.
         runs = ([(m, True, False) for m in fire_modes]
                 + [(fire_modes[0], False, False), (fire_modes[0], True, True)])

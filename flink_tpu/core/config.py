@@ -342,13 +342,6 @@ class StateOptions:
                "accumulator plane row) and applied like "
                "state.backend.tpu.hbm-budget-slots, which takes "
                "precedence when both are set. 0 = unlimited.")
-    TPU_HOST_INDEX = key("state.backend.tpu.host-index").bool_type() \
-        .default_value(
-            True, "Allow the native host hash index (CPU-fallback hot "
-                  "path) when running on the CPU backend. Disable to "
-                  "force the XLA device-resident ingest path — chaos "
-                  "tests use this to exercise the compiled-segment "
-                  "fault sites on hosts without a chip.")
     MESH_DEVICES = key("state.backend.tpu.mesh-devices").int_type() \
         .default_value(0, "Execute keyed window aggregation as one SPMD "
                           "vertex over an n-device mesh (on-device keyBy "
@@ -564,8 +557,9 @@ class FaultOptions:
         .default_value(
             True, "Persistent compile/execute failure evacuates the "
                   "operator's device state to host through the snapshot "
-                  "path and pins the operator to its CPU-fallback ingest "
-                  "path (keyed state and exactly-once semantics survive). "
+                  "path and pins the operator to its synchronous fallback "
+                  "ingest path (keyed state and exactly-once semantics "
+                  "survive). "
                   "Off = persistent device failures fail the task and "
                   "recover through the job restart strategy instead.")
 

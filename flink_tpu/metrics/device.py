@@ -230,8 +230,8 @@ class DeviceStats:
             self.d2h_fires += 1
             if self._cold_start_t0 is not None:
                 # first materialized result since the AOT-enabled deploy
-                # marked this process cold: the time-to-first-fired-window
-                # sample the coldstart bench compares warm vs cold
+                # marked this process cold: a time-to-first-fired-window
+                # sample
                 self._cold_start_ms.append(
                     (time.perf_counter() - self._cold_start_t0) * 1e3)
                 del self._cold_start_ms[:-256]

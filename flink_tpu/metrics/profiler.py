@@ -600,9 +600,6 @@ LEDGER_SITE_INVENTORY: tuple = (
     ("device_window.fire_rebuild",
      "runtime/operators/device_window.py — post-fire table rebuild "
      "program"),
-    ("device_window.native_fold",
-     "runtime/operators/device_window.py — coalesced multi-batch "
-     "device-ingest fold"),
     ("device_window.seal",
      "runtime/operators/device_window.py — pane seal program "
      "(incremental fire engine)"),

@@ -10,8 +10,8 @@ grid steps in VMEM and lane-summed by the caller.
 
 The kernel uses 8-bit digits (256 bins) so the per-lane accumulator stays
 small in VMEM ([256, 128] int32 = 128 KB); a 32-bit walk is <= 4 passes
-instead of the XLA path's <= 2 passes of 16-bit digits — the A/B
-(bench.py: topk_ab_* metrics) decides which wins on real hardware.
+instead of the XLA path's <= 2 passes of 16-bit digits; which wins on
+the chip has not been measured (ROADMAP D4).
 
 ``masked_topk_pallas`` matches ``ops.topk.masked_topk``'s contract for
 non-negative integer domains below 2^32 (the count/packed-word fires);

@@ -14,7 +14,7 @@ Two shapes of the same exchange live here:
   [n_dev, B] buffer (capacity B per destination — the whole local batch
   may hash to one shard), so ONE collective always suffices but every
   receiver folds n_dev*B rows. Per-device cost grows linearly with the
-  mesh, which is exactly the anti-scaling the multichip bench exposed.
+  mesh.
 * ``plan_exchange`` + ``exchange_round`` — the capacity-bounded form the
   sharded window step uses: buckets are cut into rounds of ``cap`` rows
   per destination and the step loops rounds until the DEEPEST bucket

@@ -72,7 +72,7 @@ one per job); unfiltered single-rule specs behave exactly as before.
 transient failures retry with exponential backoff (reusing the
 cluster/failover.py strategy math); persistent failures surface as
 ``DeviceSegmentError`` so the operator can evacuate state and degrade to
-its CPU-fallback path, and data-poison faults skip retry entirely (the
+its synchronous fallback path, and data-poison faults skip retry entirely (the
 same batch cannot stop being poisoned) so the operator quarantines the
 batch to a dead-letter output instead of folding it into state.
 """
@@ -136,7 +136,7 @@ class HangAbandoned(RuntimeError):
 class DeviceSegmentError(RuntimeError):
     """A compiled-segment call failed beyond what retries can absorb.
     ``poison`` marks a data fault (quarantine the batch); otherwise the
-    operator should degrade to its CPU-fallback path or fail over."""
+    operator should degrade to its synchronous fallback path or fail over."""
 
     def __init__(self, scope: str, cause: BaseException,
                  poison: bool = False):
@@ -526,7 +526,7 @@ class DeviceGuard:
         stall watchdog (site ``device.execute``). Retries transient
         failures AND stalls; raises DeviceSegmentError beyond that — so
         repeated stalls at one segment walk the same degradation ladder
-        as repeated failures (evacuate + CPU-fallback pin)."""
+        as repeated failures (evacuate + synchronous fallback pin)."""
         if not self.active:
             return fn()
         from ..metrics.tracing import TRACER

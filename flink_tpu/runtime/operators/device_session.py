@@ -355,13 +355,9 @@ class DeviceSessionWindowOperator(OneInputOperator):
     # -- lifecycle ---------------------------------------------------------
     def setup(self, ctx: OperatorContext, output: Output) -> None:
         super().setup(ctx, output)
-        # host_index=False: the fused session program inserts into the
-        # table with the XLA probe itself; the native dense-slot allocator
-        # must not also hand out slots for this table (a restored key
-        # would sit at a dense slot the probe never visits)
         self._backend = TpuKeyedStateBackend(
             ctx.key_group_range, ctx.max_parallelism,
-            capacity=self._capacity, host_index=False)
+            capacity=self._capacity)
         L = self._lanes
         self._backend.register_array_state("__start__", "min", jnp.int64,
                                            ring=L)

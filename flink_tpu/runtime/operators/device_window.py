@@ -188,33 +188,6 @@ def _step_program(fold_sig: tuple, ring: int, pane: int, offset: int,
         _step_body(fold_sig, ring, pane, offset, dirty_block, spill_maxp))
 
 
-@instrumented_program_cache("device_window.native_fold")
-def _native_fold_program(fold_sig: tuple, dirty_block: int):
-    """CPU-fallback companion of _step_program: slots come from the native
-    host index (backend.native_slots), so this program is only the scatter
-    folds + dirty marking, donated for in-place plane updates. Returns a
-    fresh completion token for the in-flight backpressure window."""
-    from ...ops.segment_ops import scatter_fold
-
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def fold(arrays, dirty, flat, slots, valid, vals):
-        count = arrays["__count__"]
-        out = dict(arrays)
-        out["__count__"] = scatter_fold(
-            "count", count.reshape(-1), flat,
-            jnp.ones(flat.shape[0], count.dtype), valid).reshape(count.shape)
-        for i, (kind, name, _field) in enumerate(fold_sig):
-            arr = arrays[name]
-            out[name] = scatter_fold(kind, arr.reshape(-1), flat,
-                                     vals[i].astype(arr.dtype),
-                                     valid).reshape(arr.shape)
-        dirty = dirty.at[jnp.maximum(slots, 0) // dirty_block].set(True)
-        token = jnp.sum(valid.astype(jnp.int64))
-        return out, dirty, token
-
-    return fold
-
-
 @instrumented_program_cache("device_window.fire")
 def _fire_program(agg_sig: tuple, topk: Optional[int],
                   topk_value_bits: int = 64):
@@ -542,7 +515,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._init_coalescer()
         # degradation ladder (docs/ROBUSTNESS.md): once a persistent
         # compiled-segment failure evacuates state to host, this operator
-        # is pinned to the CPU-fallback ingest path for its lifetime
+        # is pinned to the synchronous host-view ingest for its lifetime
         self._degraded = False
         self._degrade_enabled = True
         self._validate_batches = False
@@ -596,17 +569,11 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             ctx.config.get(FaultOptions.DEGRADATION))
         self._validate_batches = bool(
             ctx.config.get(FaultOptions.VALIDATE_BATCHES))
-        # fused chains insert through the XLA probe inside the composed
-        # program; mixing the native host index's slot assignment with
-        # XLA probing on one table would place a key at two slots, so a
-        # certified chain forces the device index on
-        host_index = (bool(ctx.config.get(StateOptions.TPU_HOST_INDEX))
-                      and self._fused_spec is None)
         self._backend = TpuKeyedStateBackend(
             ctx.key_group_range, ctx.max_parallelism,
             capacity=self._capacity, config=ctx.config,
             defer_overflow=self._defer,
-            hbm_budget_slots=budget, host_index=host_index)
+            hbm_budget_slots=budget)
         if self._backend.tiering_active:
             from ...state.tiering import register_residency
             register_residency(
@@ -715,30 +682,16 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 and isinstance(batch, LazyDeviceBatch)
                 and batch._realized is None
                 and not self._degraded
-                and not self._backend.host_index_active
                 and not self._spill_deferred):
             # certified fused chain: decode + fold in ONE dispatch; any
             # condition above failing lets the lazy batch realize through
             # the ordinary ladder below (graceful unfusing)
             self._ingest_chain(batch)
-        elif self._degraded and not self._backend.host_index_active:
-            # degradation ladder, last rung: state lives host-side, slot
-            # resolution through the synchronous backend path; device
-            # batches are viewed as host columns (on the CPU backend a
-            # view, not a transfer)
-            hb = self._host_view(batch)
-            keys = np.asarray(hb.column(self._key_column)).astype(
-                np.int64, copy=False)
-            self._ingest(hb, keys)
-        elif self._backend.host_index_active:
-            # CPU fallback: slot resolution through the native host index
-            # (the "device" IS the host — see TpuKeyedStateBackend
-            # .native_slots); pane bookkeeping + late filter run in the
-            # shared control plane, folds stay donated XLA programs
-            hb = self._host_view(batch)
-            keys = np.asarray(hb.column(self._key_column)).astype(
-                np.int64, copy=False)
-            self._ingest(hb, keys)
+        elif self._degraded:
+            # degradation ladder, last rung: slot resolution through the
+            # synchronous backend path; device batches are read back as
+            # host columns
+            self._ingest_degraded(batch)
         elif (isinstance(batch, DeviceRecordBatch) and self._defer
                 and batch.dtimestamps is not None):
             self._ingest_device(batch)
@@ -847,13 +800,10 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 self._late_dropped += int(jax.device_get(self._late_dev))
                 self._late_dev = None
                 self._late_cached = 0
-            from ...core.config import StateOptions
             new_backend = TpuKeyedStateBackend(
                 self.ctx.key_group_range, self.ctx.max_parallelism,
                 capacity=self._capacity, defer_overflow=False,
-                hbm_budget_slots=0,
-                host_index=bool(self.ctx.config.get(
-                    StateOptions.TPU_HOST_INDEX)))
+                hbm_budget_slots=0)
             new_backend.restore([snap])
         if self._backend.tiering_active:
             # the fallback backend is unbudgeted: retire the residency
@@ -977,10 +927,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             # degraded mid-stream: this batch re-runs through the host
             # ingest path against the evacuated state (nothing folded
             # device-side — the fault fired before dispatch)
-            hb = self._host_view(batch)
-            keys = np.asarray(hb.column(self._key_column)).astype(
-                np.int64, copy=False)
-            self._ingest(hb, keys)
+            self._ingest_degraded(batch)
             return
         self._backend.table = table
         for n, a in new_arrays.items():
@@ -1053,10 +1000,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             # degraded mid-stream: re-run through the host path (realizes
             # the batch — nothing folded device-side, the fault fired
             # before dispatch)
-            hb = self._host_view(batch)
-            keys = np.asarray(hb.column(self._key_column)).astype(
-                np.int64, copy=False)
-            self._ingest(hb, keys)
+            self._ingest_degraded(batch)
             return
         self._backend.table = table
         for n, a in new_arrays.items():
@@ -1125,10 +1069,11 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._stage["count"] = jnp.zeros((), jnp.int64)
 
     def _host_view(self, batch) -> RecordBatch:
-        """A host-column view of a batch (CPU fallback: device arrays ARE
-        host buffers, so np.asarray is a view, not a transfer)."""
+        """A batch as host columns, for the rungs that work host-side: the
+        degraded ingest, the dead-letter output and the non-finite
+        screen. A device batch is read back (one transfer per column)."""
         if isinstance(batch, DeviceRecordBatch):
-            # lint: sync-ok CPU-fallback view: np.asarray of a host-backed buffer is zero-copy
+            # lint: sync-ok off the hot path: only the degraded rung, dead letters and the non-finite screen read a device batch back
             cols = {f.name: np.asarray(batch.device_column(f.name))
                     for f in batch.schema.fields}
             ts = np.asarray(batch.dtimestamps
@@ -1137,68 +1082,18 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             return RecordBatch(batch.schema, cols, ts)
         return batch
 
-    def _fold_native(self, batch: RecordBatch, keys: np.ndarray,
-                     panes: np.ndarray) -> None:
-        """CPU-fallback fold: native host-index slot resolution + ONE
-        donated XLA fold program over all aggregates. The C++ probe beats
-        the XLA probe loop ~15x on host cores (see backend.native_slots);
-        the scatter folds stay XLA (donated, in-place)."""
-        backend = self._backend
-        slots = backend.native_slots(keys)
-        cap = backend.capacity
-        flat = (panes % self._ring).astype(np.int64) * np.int64(cap) \
-            + slots.astype(np.int64)
-        from ...ops.segment_ops import pow2_ceil
-
-        n = batch.n
-        P = pow2_ceil(n)
-
-        def _pad(a: np.ndarray, fill) -> np.ndarray:
-            if P == n:
-                return a
-            return np.concatenate([a, np.full(P - n, fill, a.dtype)])
-
-        sig = self._fold_sig()
-
-        def dispatch():
-            vals = tuple(jnp.asarray(_pad(np.asarray(batch.column(f)), 0))
-                         for _k, _n, f in sig)
-            valid = jnp.asarray(_pad(np.ones(n, bool), False))
-            DEVICE_STATS.note_h2d(
-                pytree_nbytes(vals) + valid.nbytes + flat.nbytes
-                + slots.nbytes, n)
-            arrays = {name: backend.get_array(name)
-                      for name in self._fire_array_names()}
-            prog = _native_fold_program(sig, backend.dirty_block_size)
-            return prog(
-                arrays, backend.dirty_mask, jnp.asarray(_pad(flat, 0)),
-                jnp.asarray(_pad(slots, np.int32(0))), valid, vals)
-
-        try:
-            with self._dispatch_stage(programs=1):
-                out, dirty, token = self._guard.run(
-                    dispatch, sites=("transfer.h2d", "device.execute"))
-        except DeviceSegmentError as e:
-            if e.poison:
-                self._dead_letter(self._host_view(batch))
-                return  # quarantined before folding; slots claimed but
-                # their count plane stays 0 so nothing ever emits
-            # the native fold IS already the host-fallback rung: there is
-            # no further backend to descend to — disarm injection for
-            # this operator and re-run the same fold
-            self._degraded = True
-            self._guard.active = False
-            DEVICE_STATS.note_degraded("device_window")
-            out, dirty, token = dispatch()
-        for name, a in out.items():
-            backend.set_array(name, a)
-        backend.set_dirty_mask(dirty)
-        self._admit_token(token)
+    def _ingest_degraded(self, batch) -> None:
+        """The degraded rung's ingest: the batch as host columns through
+        the shared control plane and the synchronous backend."""
+        hb = self._host_view(batch)
+        keys = np.asarray(hb.column(self._key_column)).astype(
+            np.int64, copy=False)
+        self._ingest(hb, keys)
 
     def _admit_token(self, token) -> None:
-        """Bounded in-flight window shared by the device and native ingest
-        paths: block on the (k - max_inflight)th step's completion token
-        before admitting more work, then drain any landed fires. The wait
+        """Bounded in-flight window of the one-dispatch ingest paths: block
+        on the (k - max_inflight)th step's completion token before
+        admitting more work, then drain any landed fires. The wait
         is deadline-bounded: a dispatch that never retires (wedged chip)
         raises StallError into task failover instead of blocking the
         mailbox loop forever — its state futures are unresolvable, so
@@ -1217,9 +1112,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
 
     def _fold(self, batch: RecordBatch, keys: np.ndarray,
               panes: np.ndarray) -> None:
-        if self._backend.host_index_active:
-            self._fold_native(batch, keys, panes)
-            return
         if self._defer:
             # pipelined path: host<->device calls have a fixed cost, so
             # the whole batch rides ONE upload and nothing syncs back

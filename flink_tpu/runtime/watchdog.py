@@ -18,7 +18,7 @@ Two mechanisms:
   the worker and raises a typed :class:`StallError` to the caller, which
   feeds the PR-2 degradation ladder: a stall is transient (backoff-
   retry), repeated stalls at one site are persistent (state evacuation +
-  CPU-fallback pin under ``DeviceGuard``, task failover elsewhere).
+  synchronous fallback pin under ``DeviceGuard``, task failover elsewhere).
   Exactly-once is preserved because abandoned workers never execute the
   real operation after an injected hang (the hang sleep checks the
   abandonment flag), and the non-guarded wrapped regions are idempotent

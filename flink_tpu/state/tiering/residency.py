@@ -38,7 +38,7 @@ from .policy import TieringPolicy, stage_name
 MAX_PROMOTIONS_PER_BOUNDARY = 16
 
 # Per-boundary hit-ratio samples retained per manager: enough to see a
-# whole tiny/TIERED bench run's trajectory without unbounded growth.
+# short run's trajectory without unbounded growth.
 HIT_RATIO_WINDOW = 64
 
 

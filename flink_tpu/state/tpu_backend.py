@@ -102,13 +102,6 @@ def _reset_row_program(sig: tuple):
     return reset
 
 
-@partial(jax.jit, donate_argnums=(0,))
-def _mirror_claimed(table, slots, keys):
-    """Write natively-claimed (slot, key) pairs into the device table
-    mirror; padding rows carry slot == len(table) and drop."""
-    return table.at[slots].set(keys, mode="drop")
-
-
 @jax.jit
 def _rows_set(vals, present, last_ts, slots, new_vals, now):
     B = slots.shape[0]
@@ -137,54 +130,12 @@ def _rows_get(table, vals, present, last_ts, keys, now, ttl_ms):
 
 
 @jax.jit
-def _rows_get_slots(vals, present, last_ts, slots, now, ttl_ms):
-    """_rows_get with slots already resolved (native host index)."""
-    found = slots >= 0
-    sc = jnp.maximum(slots, 0)
-    p = (present[sc] > 0) & found
-    if last_ts is not None:
-        p = p & ((now - last_ts[sc]) <= ttl_ms)
-    return vals[sc], p
-
-
-@jax.jit
-def _rows_unset_slots(present, slots):
-    cap = present.shape[0]
-    widx = jnp.where(slots >= 0, slots, cap).astype(jnp.int32)
-    return present.at[widx].set(jnp.int8(0), mode="drop"), \
-        jnp.maximum(slots, 0)
-
-
-@jax.jit
 def _rows_unset(table, present, keys):
     slots = lookup(table, keys)
     cap = present.shape[0]
     widx = jnp.where(slots >= 0, slots, cap).astype(jnp.int32)
     return present.at[widx].set(jnp.int8(0), mode="drop"), \
         jnp.maximum(slots, 0)
-
-
-@jax.jit
-def _dedup_first_slots(present, last_ts, slots, valid, ts, ttl_ms):
-    """Keep-first admission with slots ALREADY resolved (native host
-    index): same semantics as _dedup_first minus the insert."""
-    B = slots.shape[0]
-    cap = present.shape[0]
-    ok = valid.astype(bool)
-    widx = jnp.where(ok, slots, cap).astype(jnp.int32)
-    firstpos = jnp.full(cap + 1, B, jnp.int32).at[widx].min(
-        jnp.arange(B, dtype=jnp.int32))
-    is_first = jnp.arange(B, dtype=jnp.int32) == firstpos[widx]
-    sc = jnp.maximum(slots, 0)
-    was = (present[sc] > 0) & ok
-    if last_ts is not None:
-        was = was & ((ts - last_ts[sc]) <= ttl_ms)
-    fresh = ok & ~was & is_first
-    present = present.at[widx].set(jnp.int8(1), mode="drop")
-    if last_ts is not None:
-        fidx = jnp.where(fresh, slots, cap).astype(jnp.int32)
-        last_ts = last_ts.at[fidx].set(ts, mode="drop")
-    return present, last_ts, fresh, sc
 
 
 @jax.jit
@@ -243,8 +194,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def __init__(self, key_group_range: KeyGroupRange, max_parallelism: int,
                  capacity: int = 1 << 16, config=None,
                  defer_overflow: bool = False,
-                 hbm_budget_slots: int = 0,
-                 host_index: bool = True, **_kw):
+                 hbm_budget_slots: int = 0, **_kw):
         super().__init__(key_group_range, max_parallelism)
         cap = 1
         while cap < capacity:
@@ -319,86 +269,10 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # fused step) and per-group last-touch (device LRU clock)
         self._spilled_dev: Optional[jax.Array] = None
         self._touch_dev: Optional[jax.Array] = None
-        # native host index (CPU fallback hot path): when the "device" IS
-        # the host, slot resolution through the C++ open-addressing index
-        # (native/native.cpp HashIndex) beats the XLA probe loop ~15x —
-        # XLA's gathers are single-threaded general loads, while the
-        # sequential C++ probe walks cache lines. Slots are dense
-        # (first-seen order), so plane growth is a pad, never a remap; the
-        # device table stays authoritative for fires/snapshots via a
-        # per-batch mirror scatter of the claimed keys. Excluded under an
-        # HBM budget (the spill split needs device-computed groups), and
-        # opted out (host_index=False) by operators whose own fused
-        # programs insert into the table with the XLA probe — mixing the
-        # two allocators on one table would place the same key at two
-        # slots (native slots are dense, XLA slots lie on the probe
-        # sequence).
-        self._hi = None
-        if config is not None:
-            from ..core.config import StateOptions
-            host_index = host_index and bool(
-                config.get(StateOptions.TPU_HOST_INDEX))
-        if host_index and not self._budget \
-                and jax.default_backend() == "cpu":
-            try:
-                from .. import native as _native
-                if _native.NATIVE_AVAILABLE:
-                    self._hi = _native.HostHashIndex(cap)
-            except ImportError:  # pragma: no cover
-                pass
 
     # ------------------------------------------------------------------
     # hot path: batched slot resolution + scatter folds
     # ------------------------------------------------------------------
-    @property
-    def host_index_active(self) -> bool:
-        return self._hi is not None
-
-    def native_slots(self, keys: np.ndarray) -> np.ndarray:
-        """Slot resolution through the native host index (CPU fallback):
-        dense first-seen slots from the C++ open-addressing table, planes
-        grown by padding when the key count crosses capacity (dense slots
-        never remap), and the claimed keys mirrored into the device table
-        so fires/snapshots read the same state as the XLA path."""
-        keys = _sanitize_keys(np.asarray(keys))
-        slots = self._hi.upsert(keys)
-        n = len(self._hi)
-        while n > self.capacity:
-            self._grow_planes(self.capacity * 2)
-        from ..ops.segment_ops import pow2_ceil
-
-        B = len(keys)
-        P = pow2_ceil(max(B, 1))
-        if P != B:  # constant shapes: one mirror executable per bucket
-            pslots = np.full(P, self.capacity, np.int64)
-            pslots[:B] = slots
-            pkeys = np.concatenate(
-                [keys, np.zeros(P - B, np.int64)])
-        else:
-            pslots, pkeys = slots.astype(np.int64), keys
-        self.table = _mirror_claimed(self.table, jnp.asarray(pslots),
-                                     jnp.asarray(pkeys))
-        self._num_keys = n
-        return slots
-
-    def _grow_planes(self, new_capacity: int) -> None:
-        """Native-mode growth: dense slots are stable, so growing is a pad
-        of every plane (and the table mirror) — no remap, no re-probe."""
-        pad = new_capacity - self.capacity
-        self.table = jnp.concatenate(
-            [self.table, jnp.full(pad, EMPTY_KEY, jnp.int64)])
-        for st in self._array_states.values():
-            ident = AGG_INITS[st.kind](st.dtype)
-            if st.ring:
-                st.array = jnp.concatenate(
-                    [st.array, jnp.full((st.ring, pad), ident, st.dtype)],
-                    axis=1)
-            else:
-                st.array = jnp.concatenate(
-                    [st.array, jnp.full(pad, ident, st.dtype)])
-        self.capacity = new_capacity
-        self._invalidate_mirror()
-
     def slots_for_batch(self, keys: np.ndarray) -> jax.Array:
         """Lookup-or-insert a batch of int64 keys. In the default
         (synchronous) mode the table grows by rehash on overflow, at the
@@ -407,12 +281,6 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         negative slots (the fold skips them), a device drop counter
         accumulates, and ``check_health`` at the next watermark raises /
         grows. Returns device int32 slots."""
-        if self._hi is not None:
-            slots = self.native_slots(np.asarray(keys))
-            dslots = jnp.asarray(slots)
-            self._pending_host = None
-            self.mark_dirty(dslots)
-            return dslots
         keys = _sanitize_keys(np.asarray(keys))
         if self._defer:
             return self.slots_for_batch_device(jnp.asarray(keys))
@@ -1041,11 +909,6 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         if not self._defer:
             raise RuntimeError("device-resident slot resolution requires "
                                "defer_overflow mode")
-        if self._hi is not None:
-            slots = jnp.asarray(self.native_slots(
-                np.asarray(jax.device_get(dkeys))))
-            self.mark_dirty(slots)
-            return slots
         dkeys = sanitize_keys_device(dkeys)
         self.table, slots, ok, probe = lookup_or_insert(
             self.table, dkeys, stats=True)
@@ -1095,8 +958,6 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 "state.backend.tpu.slots-per-key-group or disable "
                 "deferred overflow checking")
         self._num_keys = int(occupancy)
-        if self._hi is not None:
-            return  # growth is handled inline by native_slots (pad, no remap)
         if self._num_keys > 0.6 * self.capacity:
             if not self._budget or 2 * self.capacity <= self._budget:
                 self._rehash(self.capacity * 2)
@@ -1207,29 +1068,17 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         TTL-expired keys report present=False. One lookup + one gather +
         one transfer."""
         vals, present, last, ttl = self._row_planes(name)
-        if self._hi is not None:
-            # the mirror table holds keys at DENSE slots, not probe slots:
-            # resolve through the native index (read-only lookup)
-            slots = self._hi.lookup(_sanitize_keys(np.asarray(keys)))
-            v, p = _rows_get_slots(vals, present, last, jnp.asarray(slots),
-                                   np.int64(now_ms), np.int64(ttl))
-        else:
-            v, p = _rows_get(self.table, vals, present, last,
-                             jnp.asarray(_sanitize_keys(np.asarray(keys))),
-                             np.int64(now_ms), np.int64(ttl))
+        v, p = _rows_get(self.table, vals, present, last,
+                         jnp.asarray(_sanitize_keys(np.asarray(keys))),
+                         np.int64(now_ms), np.int64(ttl))
         v, p = jax.device_get((v, p))
         return np.asarray(v), np.asarray(p)
 
     def rows_clear(self, name: str, keys: np.ndarray) -> None:
         vals, present, last, _ttl = self._row_planes(name)
-        if self._hi is not None:
-            nslots = self._hi.lookup(_sanitize_keys(np.asarray(keys)))
-            new_present, slots = _rows_unset_slots(present,
-                                                   jnp.asarray(nslots))
-        else:
-            new_present, slots = _rows_unset(
-                self.table, present,
-                jnp.asarray(_sanitize_keys(np.asarray(keys))))
+        new_present, slots = _rows_unset(
+            self.table, present,
+            jnp.asarray(_sanitize_keys(np.asarray(keys))))
         self.set_array(f"{name}.__set__", new_present)
         self.mark_dirty(slots)
 
@@ -1247,24 +1096,6 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         dvalid = (jnp.asarray(np.asarray(valid, bool)) if valid is not None
                   else jnp.ones(len(keys), bool))
         dts = jnp.asarray(np.asarray(ts, np.int64))
-        if self._hi is not None:
-            # invalid (e.g. retraction) rows must not claim slots — the
-            # XLA path threads `valid` through lookup_or_insert; here
-            # only valid rows reach the native upsert
-            valid_np = (np.asarray(valid, bool) if valid is not None
-                        else np.ones(len(keys), bool))
-            slots = np.full(len(keys), -1, np.int32)
-            if valid_np.any():
-                slots[valid_np] = self.native_slots(keys[valid_np])
-            _vals, present, last, ttl = self._row_planes(name)
-            new_present, new_last, fresh, sc = _dedup_first_slots(
-                present, last, jnp.asarray(slots), dvalid, dts,
-                np.int64(ttl))
-            self.set_array(f"{name}.__set__", new_present)
-            if new_last is not None:
-                self.set_array(f"{name}.__ts__", new_last)
-            self.mark_dirty(sc)
-            return np.asarray(jax.device_get(fresh))
         while True:
             _vals, present, last, ttl = self._row_planes(name)
             table, new_present, new_last, fresh, slots, overflow, occ = \
@@ -1400,19 +1231,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             self.capacity *= 2  # may exceed the budget; evicted back below
         self.table = make_table(self.capacity)
         self._num_keys = len(keys)
-        if self._hi is not None:
-            # fresh native index; restored keys get dense slots and the
-            # table mirror is rebuilt from them
-            from .. import native as _native
-            self._hi = _native.HostHashIndex(self.capacity)
-            if len(keys):
-                skeys = _sanitize_keys(keys)
-                nslots = self._hi.upsert(skeys)
-                slots = jnp.asarray(nslots)
-                self.table = self.table.at[slots].set(jnp.asarray(skeys))
-            else:
-                slots = jnp.zeros(0, jnp.int32)
-        elif len(keys):
+        if len(keys):
             self.table, slots, ok = lookup_or_insert(self.table,
                                                      jnp.asarray(keys))
             assert bool(jax.device_get(ok.all()))

@@ -15,11 +15,16 @@ import pytest  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def _clean_net_events():
-    """The transport-plane event log (merged into REST /exceptions) is
-    process-global; clear it per test so one test's reconnect/sever
-    events don't surface in another's exception-history assertions."""
+    """The transport-plane event log and the watchdog's stall events
+    (both merged into REST /exceptions) are process-global; clear them
+    per test so one test's reconnect/sever/stall events don't surface in
+    another's exception-history assertions (test_failover leaves an
+    unattributed coordinator stall behind, which test_webui then read
+    whenever it ran next on the same worker)."""
     from flink_tpu.cluster.transport import NET_EVENTS
+    from flink_tpu.runtime.watchdog import WATCHDOG
     NET_EVENTS.clear()
+    del WATCHDOG.events[:]
     yield
 
 

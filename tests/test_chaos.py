@@ -13,7 +13,6 @@ import pytest
 
 from flink_tpu.core.config import (
     CheckpointingOptions, Configuration, FaultOptions, PipelineOptions,
-    StateOptions,
 )
 from flink_tpu.core.device_records import DeviceRecordBatch
 from flink_tpu.core.functions import SinkFunction
@@ -44,7 +43,6 @@ def _clean_injector():
 
 def _chaos_config(spec: str, seed: int = 0) -> Configuration:
     cfg = Configuration()
-    cfg.set(StateOptions.TPU_HOST_INDEX, False)  # force the XLA path
     if spec:
         cfg.set(FaultOptions.ENABLED, True)
         cfg.set(FaultOptions.SEED, seed)
@@ -162,7 +160,7 @@ def test_chaos_counters_reach_prometheus():
 
 
 # ---------------------------------------------------------------------------
-# degradation ladder: persistent failure -> evacuate -> CPU fallback
+# degradation ladder: persistent failure -> evacuate -> synchronous fallback
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("device_batches", [True, False])
@@ -223,7 +221,6 @@ def test_validate_batches_quarantines_nonfinite_rows():
     from flink_tpu.window import TumblingEventTimeWindows
 
     cfg = Configuration()
-    cfg.set(StateOptions.TPU_HOST_INDEX, False)
     cfg.set(FaultOptions.VALIDATE_BATCHES, True)
     op = DeviceWindowAggOperator(
         TumblingEventTimeWindows.of(PANE), "k",
@@ -297,8 +294,7 @@ def test_single_hang_at_each_watchdog_site_is_absorbed(site, device_batches,
     else:
         # cold caches regardless of test order: the builder IS the site
         from flink_tpu.runtime.operators import device_window as dw
-        for builder in (dw._step_program, dw._fire_program,
-                        dw._native_fold_program):
+        for builder in (dw._step_program, dw._fire_program):
             builder.cache_clear()
     wd0 = DEVICE_STATS.watchdog_trips
     cfg = _tight_watchdog(_chaos_config(f"{site}=once@2!hang@40", seed),
@@ -379,7 +375,6 @@ def test_tiny_q5_pipeline_exactly_once_with_hang_injection(seed):
     env = StreamExecutionEnvironment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, 256)
-    env.config.set(StateOptions.TPU_HOST_INDEX, False)
     env.config.set(FaultOptions.ENABLED, True)
     env.config.set(FaultOptions.SEED, seed)
     env.config.set(FaultOptions.SPEC, spec)
@@ -423,64 +418,85 @@ class _RowSink(SinkFunction):
         return True
 
 
-@pytest.mark.parametrize("seed", [7, 11, 13])
-def test_tiny_q5_pipeline_exactly_once_under_chaos(seed):
-    """The acceptance trial: the tiny Q5-shaped pipeline (datagen ->
-    keyBy -> device tumbling aggregate -> sink) completes with
-    exactly-once results with faults armed at every named site. All
-    schedules are transient/bounded so recovery happens IN PLACE (retry
-    / injected backpressure / tolerated checkpoint-write failure), which
-    keeps the emitted stream free of restart replays."""
+_Q5_N, _Q5_KEYS = 1 << 12, 37
+_EVERY_SITE = ("device.compile=once@1,device.execute=p0.03,"
+               "transfer.h2d=p0.03,transfer.d2h=p0.03,"
+               "channel.send=once@2,channel.backpressure=every@13,"
+               "checkpoint.write=once@1,sink.invoke=once@2,"
+               "rpc.heartbeat=every@5")
+
+
+def _tiny_q5_job(name: str, spec: str, seed: int, device: bool) -> dict:
+    """One env.execute() of the tiny Q5-shaped pipeline (datagen ->
+    keyBy -> device tumbling aggregate -> sink) with ``spec`` armed;
+    returns {(key, window_end): (count, sum)}."""
     from flink_tpu.api.environment import StreamExecutionEnvironment
     from flink_tpu.core.watermarks import WatermarkStrategy
     from flink_tpu.window import TumblingEventTimeWindows
 
-    n, n_keys = 1 << 12, 37
-    spec = ("device.compile=once@1,device.execute=p0.03,"
-            "transfer.h2d=p0.03,transfer.d2h=p0.03,"
-            "channel.send=once@2,channel.backpressure=every@13,"
-            "checkpoint.write=once@1,sink.invoke=once@2,"
-            "rpc.heartbeat=every@5")
-
     def gen(idx):
-        return {"k": (idx * 7) % n_keys,
+        return {"k": (idx * 7) % _Q5_KEYS,
                 "v": (idx % 19) + 1,
-                "ts": (idx * 6 * PANE) // n}
+                "ts": (idx * 6 * PANE) // _Q5_N}
 
     schema = Schema([("k", np.int64), ("v", np.int64), ("ts", np.int64)])
     env = StreamExecutionEnvironment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, 512)
-    env.config.set(StateOptions.TPU_HOST_INDEX, False)
     env.config.set(CheckpointingOptions.INTERVAL, 0.05)
-    env.config.set(FaultOptions.ENABLED, True)
-    env.config.set(FaultOptions.SEED, seed)
-    env.config.set(FaultOptions.SPEC, spec)
+    if spec:
+        env.config.set(FaultOptions.ENABLED, True)
+        env.config.set(FaultOptions.SEED, seed)
+        env.config.set(FaultOptions.SPEC, spec)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = _RowSink()
-    (env.datagen(gen, schema, count=n, timestamp_column="ts",
-                 watermark_strategy=ws)
+    (env.datagen(gen, schema, count=_Q5_N, timestamp_column="ts",
+                 watermark_strategy=ws, device=device)
         .key_by("k")
         .window(TumblingEventTimeWindows.of(PANE))
         .device_aggregate([AggSpec("count", out_name="cnt", value_bits=31),
                            AggSpec("sum", "v", out_name="total")],
                           capacity=1 << 12, ring_size=8,
                           emit_window_bounds=True, defer_overflow=True)
-        .add_sink(sink.fn if hasattr(sink, "fn") else sink, "sink"))
-    env.execute(f"tiny-q5-chaos-{seed}", timeout=120.0)
-
-    idx = np.arange(n)
-    keys = (idx * 7) % n_keys
-    vals = (idx % 19) + 1
-    ts = (idx * 6 * PANE) // n
-    expect = _expected(keys, vals, ts)
+        .add_sink(sink, "sink"))
+    env.execute(name, timeout=120.0)
     got = {}
     for k, _ws, we, cnt, total in sink.rows:
         assert (int(k), int(we)) not in got, "duplicate window emission"
         got[(int(k), int(we))] = (int(cnt), int(total))
+    return got
+
+
+@pytest.mark.parametrize("seed,spec,device", [
+    (7, _EVERY_SITE, False), (11, _EVERY_SITE, False),
+    (13, _EVERY_SITE, False),
+    (0, "device.execute=once@2!persistent", True)],
+    ids=["7", "11", "13", "degraded"])
+def test_tiny_q5_pipeline_exactly_once_under_chaos(seed, spec, device):
+    """The acceptance trial: the tiny Q5-shaped pipeline completes with
+    exactly-once results with faults armed at every named site. Those
+    schedules are transient/bounded so recovery happens IN PLACE (retry
+    / injected backpressure / tolerated checkpoint-write failure), which
+    keeps the emitted stream free of restart replays. The ``degraded``
+    case injects ONE persistent fault into the second step of a
+    device-born job: the operator evacuates its state mid-stream and
+    finishes on the synchronous fallback (device batches read back as
+    host columns), with the rows of the job that never degraded."""
+    degraded0 = DEVICE_STATS.degraded
+    got = _tiny_q5_job(f"tiny-q5-chaos-{seed}", spec, seed, device)
+    idx = np.arange(_Q5_N)
+    expect = _expected((idx * 7) % _Q5_KEYS, (idx % 19) + 1,
+                       (idx * 6 * PANE) // _Q5_N)
     assert got == expect, f"seed {seed}: results diverged under chaos"
     assert DEVICE_STATS.injected_faults > 0
+    if "!persistent" in spec:
+        assert DEVICE_STATS.degraded == degraded0 + 1
+        faults_mod.FAULTS.reset()
+        assert got == _tiny_q5_job("tiny-q5-clean", "", 0, device)
+        assert DEVICE_STATS.degraded == degraded0 + 1
+    else:
+        assert DEVICE_STATS.degraded == degraded0
 
 
 # ---------------------------------------------------------------------------

@@ -489,7 +489,6 @@ def test_device_pipeline_exactly_once_under_corruption_chaos(
     from flink_tpu.cluster.scheduler import JobSupervisor
     from flink_tpu.core.config import (
         CheckpointingOptions, FaultOptions, PipelineOptions, RuntimeOptions,
-        StateOptions,
     )
     from flink_tpu.core.functions import SinkFunction
     from flink_tpu.core.records import Schema
@@ -501,7 +500,6 @@ def test_device_pipeline_exactly_once_under_corruption_chaos(
     env = StreamExecutionEnvironment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, 512)
-    env.config.set(StateOptions.TPU_HOST_INDEX, False)
     env.config.set(CheckpointingOptions.DIRECTORY, str(tmp_path))
     env.config.set(CheckpointingOptions.INTERVAL, 0.05)
     env.config.set(CheckpointingOptions.RETAINED, 3)

@@ -175,7 +175,6 @@ def test_device_pipeline_exactly_once_with_transfer_and_execute_faults(seed):
     env = StreamExecutionEnvironment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, 512)
-    env.config.set(StateOptions.TPU_HOST_INDEX, False)
     env.config.set(CheckpointingOptions.INTERVAL, 0.05)
     env.config.set(FaultOptions.ENABLED, True)
     env.config.set(FaultOptions.SEED, seed)

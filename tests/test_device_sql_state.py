@@ -31,11 +31,30 @@ def _cfg(backend):
 
 
 class TestTypedRowPlane:
-    def test_typed_value_roundtrip_int64(self):
-        b = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=256)
+    @pytest.mark.parametrize("grow", [False, True])
+    def test_typed_value_roundtrip_int64(self, grow):
+        """``grow``: the table starts at 8 slots and 600 more keys arrive
+        between the writes and the reads, so every plane is remapped by
+        rehash several times over and the rows move to other slots."""
+        b = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128,
+                                 capacity=8 if grow else 256)
         b.register_row_state("s", np.int64)
+        b.register_row_state("seen", np.int8)
         keys = np.array([5, 9, 5, 7], np.int64)     # duplicate: last wins
         b.rows_upsert("s", keys, np.array([10, 20, 30, 1 << 40]))
+        assert b.dedup_first_batch("seen", keys, np.zeros(4, np.int64)) \
+            .tolist() == [True, True, False, True]
+        if grow:
+            more = np.arange(100, 700, dtype=np.int64)
+            b.rows_upsert("s", more[:300], more[:300] * 2)
+            assert b.dedup_first_batch(
+                "seen", more[300:], np.zeros(300, np.int64)).all()
+            assert b.capacity >= 1024
+            vals, present = b.rows_lookup("s", more)
+            assert present.tolist() == [True] * 300 + [False] * 300
+            assert vals[:300].tolist() == (more[:300] * 2).tolist()
+        assert not b.dedup_first_batch(
+            "seen", np.array([7, 9], np.int64), np.ones(2, np.int64)).any()
         vals, present = b.rows_lookup("s", np.array([5, 7, 9, 11], np.int64))
         assert present.tolist() == [True, True, True, False]
         assert vals[:3].tolist() == [30, 1 << 40, 20]

@@ -242,7 +242,7 @@ def test_backend_hands_the_counters_to_device_stats():
         return [now[k] - since[k] for k in keys]
 
     be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=CAP,
-                              defer_overflow=True, host_index=False)
+                              defer_overflow=True)
     start = DEVICE_STATS.snapshot()
     resident = jnp.arange(N, dtype=jnp.int64) * 3 + 1
     be.slots_for_batch_device(resident)                        # all new: wide

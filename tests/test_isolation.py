@@ -14,7 +14,7 @@ import pytest
 
 from flink_tpu.core.config import (
     Configuration, FaultOptions, IsolationOptions, PipelineOptions,
-    ProfilerOptions, StateOptions, WatchdogOptions,
+    ProfilerOptions, WatchdogOptions,
 )
 from flink_tpu.core.functions import SinkFunction
 from flink_tpu.core.records import Schema
@@ -227,7 +227,6 @@ def _build_env(options, sink, n=1 << 11, n_keys=23, batch=256):
     env = StreamExecutionEnvironment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, batch)
-    env.config.set(StateOptions.TPU_HOST_INDEX, False)
     for opt, value in options:
         env.config.set(opt, value)
     ws = WatermarkStrategy.for_monotonous_timestamps() \

@@ -456,20 +456,10 @@ class TestMeshHotLoop:
             op.finish()
             return (n - 2048) / (_t.perf_counter() - t0)
 
-        # compare against the XLA single-chip path: the native host-index
-        # fast path is a CPU-fallback accelerator the SPMD mesh operator
-        # cannot use, so including it would measure the accelerator, not
-        # the mesh's exchange/sharding overhead this test bounds
-        import flink_tpu.native as _native
-        saved = _native.NATIVE_AVAILABLE
-        _native.NATIVE_AVAILABLE = False
-        try:
-            single = timed(DeviceWindowAggOperator(
-                w, "key", [AggSpec("sum", "v", out_name="result")],
-                capacity=1 << 13, emit_window_bounds=False,
-                defer_overflow=True, async_fire=True))
-        finally:
-            _native.NATIVE_AVAILABLE = saved
+        single = timed(DeviceWindowAggOperator(
+            w, "key", [AggSpec("sum", "v", out_name="result")],
+            capacity=1 << 13, emit_window_bounds=False,
+            defer_overflow=True, async_fire=True))
         mesh = timed(_mesh_op(w, capacity=1 << 13, device_batch=256,
                               async_fire=True))
         # on the virtual CPU mesh all 8 'devices' share the host's cores,
@@ -522,7 +512,6 @@ def _run_q5(aggregate, traces=False):
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, Q5["batch"])
     env.config.set(TraceOptions.ENABLED, traces)
-    env.config.set("state.backend.tpu.host-index", False)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = chip_smoke._collecting_sink()

@@ -1,5 +1,5 @@
 """Pallas radix-select histogram: correctness in interpreter mode on CPU
-(the A/B timing lives in bench.py and needs the real chip)."""
+(it has not been timed against the XLA select on the chip: ROADMAP D4)."""
 
 import numpy as np
 import pytest

@@ -91,8 +91,6 @@ def _run(async_fire=True, defer=True, traces=True, hesitant=False):
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, BATCH)
     env.config.set(TraceOptions.ENABLED, traces)
-    # the XLA probe, as on the chip (the native host index is the CPU rung)
-    env.config.set("state.backend.tpu.host-index", False)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = _Collect()

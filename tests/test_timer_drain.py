@@ -144,7 +144,6 @@ def _run(kind, async_fire=True, gated=True):
     env.config.set(PipelineOptions.BATCH_SIZE, BATCH)
     env.config.set(PipelineOptions.AUTO_WATERMARK_INTERVAL, 0.005)
     env.config.set(TraceOptions.ENABLED, True)
-    env.config.set("state.backend.tpu.host-index", False)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = _Collect()
