@@ -134,7 +134,7 @@ def scatter_rule(ctx: AnalysisContext) -> List[Finding]:
             message=f"fire-path program '{entry.scope}' lowers "
                     f"{', '.join(prims)}",
             hint="rank/permute with sort- or bisection-based selection "
-                 "(ops/topk.py masked_topk_bisect) instead of scatter; "
+                 "(ops/topk.py threshold_topk) instead of scatter; "
                  "if the scatter is provably amortized, baseline the "
                  "finding with a reason"))
     return findings

@@ -105,6 +105,15 @@ class DeviceStats:
         # by the steps in flight
         self._mesh_steps = 0
         self._mesh_exchange_rounds = 0
+        # fire select accounting (PR 31): ranked mesh fires, the
+        # compare-and-count passes their threshold selects walked (the
+        # longest shard's; the bit length of the largest rank) and how
+        # many handed a shard to the sort (a float rank, or a negative
+        # one). Read from the fire's own outputs in the copy its drain
+        # makes anyway
+        self._fire_selects = 0
+        self._fire_select_passes = 0
+        self._fire_select_sort = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -387,6 +396,20 @@ class DeviceStats:
         with self._lock:
             return self._mesh_steps, self._mesh_exchange_rounds
 
+    def note_fire_select(self, passes: int, sort: bool) -> None:
+        with self._lock:
+            self._fire_selects += 1
+            self._fire_select_passes += int(passes)
+            self._fire_select_sort += bool(sort)
+
+    @property
+    def fire_select_counts(self) -> tuple[int, int, int]:
+        """(ranked mesh fires, select passes they walked, those that
+        took the sort)."""
+        with self._lock:
+            return (self._fire_selects, self._fire_select_passes,
+                    self._fire_select_sort)
+
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
             self._chain_dispatches += int(n)
@@ -649,6 +672,9 @@ class DeviceStats:
                 "probe_wide_batches_total": self._probe_wide_batches,
                 "mesh_steps_total": self._mesh_steps,
                 "mesh_exchange_rounds_total": self._mesh_exchange_rounds,
+                "fire_selects_total": self._fire_selects,
+                "fire_select_passes_total": self._fire_select_passes,
+                "fire_select_sort_total": self._fire_select_sort,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -755,6 +781,8 @@ class DeviceStats:
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
+            self._fire_selects = self._fire_select_passes = 0
+            self._fire_select_sort = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -1179,6 +1207,12 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_mesh_exchange_rounds_total)
     g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])
     g.gauge("mesh_exchange_rounds_total", lambda: s.mesh_step_counts[1])
+    # mesh fire select (prometheus: flink_tpu_device_fire_selects_total /
+    # flink_tpu_device_fire_select_passes_total /
+    # flink_tpu_device_fire_select_sort_total)
+    g.gauge("fire_selects_total", lambda: s.fire_select_counts[0])
+    g.gauge("fire_select_passes_total", lambda: s.fire_select_counts[1])
+    g.gauge("fire_select_sort_total", lambda: s.fire_select_counts[2])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

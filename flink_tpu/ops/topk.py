@@ -1,36 +1,32 @@
-"""Exact masked top-k by radix threshold selection.
+"""Exact masked top-k without a sort over the slots.
 
 ``lax.top_k`` over a full [capacity] accumulator is the single most
-expensive op in the device window-fire path (reference fire loop:
-WindowOperator.onEventTime:437 emitting ORDER BY ... LIMIT k results) —
-measured ~480 ms for k=1000 over 2M slots on one CPU host, because XLA
-lowers it to a variant of full sort. The fire only needs the k largest
-values and their slots, so this module finds the exact k-th threshold with
-a fixed number of histogram passes (radix select) and then compacts the
-survivors with one two-ended scatter:
+expensive op a window fire can hold (reference fire loop:
+WindowOperator.onEventTime:437 emitting ORDER BY ... LIMIT k results): XLA
+lowers it to a variant of a full sort, 138 ms for k = 1000 over a v5e's
+2^23 int64 slots (PERF.md section 5, PR 27). The fire only needs the k
+largest values and their slots, so this module finds the exact k-th
+largest value T and compacts the survivors. Two walks find T:
 
-* 4 passes of 16-bit histograms walk the 64-bit key space top-down; after
-  pass p the threshold prefix is exact to 16*(p+1) bits, so 4 passes pin
-  the exact k-th largest value T. Each pass is one elementwise extract +
-  one scatter-add into 65536 bins — O(n) memory-bound work with no sort.
-* survivors split into STRICT (> T, provably fewer than k) and TIES (== T,
-  interchangeable by definition). Ties compact from the back of a [k]
-  buffer, strict from the front, strict written last so collisions resolve
-  in favor of strict — exactness without a second pass.
-
-Values map monotonically into uint64 (sign-flip for signed ints, the
-sign-magnitude trick for floats), so one implementation covers every
-accumulator dtype. Invalid slots are excluded from both the histograms and
-the final compaction.
-
-Bounded non-negative integer domains (``value_bits <= 32`` — window
-COUNTs, packed price words, everything the Q5 fire ranks on) take a
-scatter-free bitwise-bisection path instead: the exact threshold is built
-bit by bit with one vectorized compare-and-count per bit, and the winners
-compact via cumsum + searchsorted. XLA lowers scatter to a serial loop,
-so dropping the histogram scatter-adds and the two compaction scatters
-makes the select several times faster at every size measured
-(0.47 ms vs 3.6 ms at n=16k, 37 ms vs 188 ms at n=1M; k=1000).
+* ``threshold_topk``, for integers: T is built bit by bit from the top,
+  one vectorized compare-and-count over the slots per bit, starting at the
+  highest bit the largest valid value HAS (a window's COUNTs have 15 bits,
+  whatever their plane declares), on a 32-bit view wherever that maximum
+  is under 2^32; the winners (every slot above T, seats left over filled
+  from the slots equal to T in index order: ties are interchangeable by
+  definition) compact through cumsum + searchsorted. No scatter, no sort:
+  6 ms for the same 2^23 int64 slots, 15 passes (my chip run, PR 31). Both
+  window stacks fire through it (one chip: ``masked_topk``; the mesh:
+  ``parallel/sharded_window.global_topk``, per shard under shard_map).
+* the radix walk (``_masked_topk_radix``), for floats and for integers
+  with a negative valid value: 4 passes of 16-bit histograms over a
+  monotone uint64 image of the values (sign-flip for signed ints, the
+  sign-magnitude trick for floats), each one elementwise extract + one
+  scatter-add into 65536 bins; the survivors compact with a two-ended
+  scatter (ties from the back of a [k] buffer, strict from the front and
+  written last, so a collision keeps the strict element). A TPU runs a
+  scatter-add of 2^23 updates slowly, so the mesh hands these ranks to
+  ``masked_topk_sort`` instead.
 
 Contract matches lax.top_k + validity: ``(values[k], indices[k], ok[k])``
 sorted descending; ``ok[i]`` False marks padding when fewer than k valid
@@ -40,11 +36,13 @@ slots exist.
 from __future__ import annotations
 
 from functools import partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["masked_topk_radix", "masked_topk_sort", "masked_topk"]
+__all__ = ["TopK", "threshold_topk", "masked_topk_radix",
+           "masked_topk_sort", "masked_topk"]
 
 
 def _to_uint64(v: jax.Array) -> jax.Array:
@@ -69,49 +67,155 @@ def _to_uint64(v: jax.Array) -> jax.Array:
             ^ jnp.uint64(1) << jnp.uint64(63))
 
 
-def masked_topk_radix(values: jax.Array, valid: jax.Array, k: int,
-                      value_bits: int = 64):
-    """Exact top-k among valid slots via 16-bit-per-pass radix select.
+class TopK(NamedTuple):
+    """What a select hands back: the ``lax.top_k`` + validity contract
+    (sorted descending, ``ok`` False on padding) and how it got there."""
+    values: jax.Array           # [k]
+    indices: jax.Array          # [k] int32 slots
+    ok: jax.Array               # [k] bool
+    passes: jax.Array           # int32: compare-and-count passes walked
+    fell_back: jax.Array        # bool: ``otherwise`` ran, not the walk
 
-    ``value_bits``: static upper bound on the bit width of the value
-    DOMAIN (after the monotone uint64 map the top bits are constant, so
-    passes over them resolve nothing). 64 is always safe; callers that
-    know their values are non-negative and bounded (window COUNTs, packed
-    price words) pass a tighter bound to drop whole histogram passes —
-    each pass is an O(n) scatter, the dominant cost at large n.
-    """
+
+def threshold_topk(values: jax.Array, valid: jax.Array, k: int,
+                   value_bits: int = 64, otherwise=None) -> TopK:
+    """Exact top-k among the valid slots of a 1-D ``values`` by threshold
+    select: the k-th largest valid value T is built bit by bit from the
+    top, one compare-and-count pass over the slots per bit, then the
+    winners (every slot above T, seats left over filled from the slots
+    equal to T in index order) compact through cumsum + searchsorted. No
+    scatter and no sort over the slots.
+
+    The walk adapts to the data, not to a declared width: it starts at the
+    highest bit the largest valid value HAS (one max-reduce; the loop's
+    trip count is that bit length), and where that maximum is under 2^32
+    the compares run on a 32-bit view. ``value_bits`` is the caller's
+    promise that the values are non-negative and under 2^value_bits; it
+    decides only what need not be compiled: at most 32, the 64-bit view;
+    under the dtype's width, the guard. Without the promise a negative
+    valid value (the same max-reduce sees it, as the unsigned view's top
+    bit) hands the whole select to
+    ``otherwise(values, valid, k) -> (values, indices, ok)``; floats go
+    there always (their order is not their bit order). ``otherwise``
+    defaults to the radix walk; a caller whose backend lowers scatter
+    badly passes ``masked_topk_sort``.
+
+    Traceable, not jitted: it runs inside the caller's program (under x64
+    wherever a 64-bit rank may come)."""
+    if otherwise is None:
+        otherwise = _masked_topk_radix
+    dt = values.dtype
+    k = min(k, values.shape[0])
+
+    def other():
+        v, i, ok = otherwise(values, valid, k)
+        return v, i.astype(jnp.int32), ok, jnp.int32(0)
+
+    if jnp.issubdtype(dt, jnp.floating):
+        return TopK(*other(), jnp.bool_(True))
+    kk = jnp.minimum(jnp.int32(k), jnp.sum(valid, dtype=jnp.int32))
+
+    def view(unsigned):
+        return jnp.where(valid, values, 0).astype(unsigned)
+
+    def select(unsigned):
+        idx, filled = _threshold_select(view(unsigned), valid, k, kk, top)
+        return (jnp.where(filled, values[idx], _sentinel(dt)), idx, filled,
+                top)
+
+    width = 8 * dt.itemsize
+    guarded = jnp.issubdtype(dt, jnp.signedinteger) and value_bits >= width
+    wide = width > 32 and value_bits > 32
+    # a negative value of a signed dtype sets its unsigned view's top bit
+    seen = jnp.uint64 if wide else jnp.uint32
+    top = _bit_length(jnp.max(view(seen)))
+    negative = top >= (64 if wide else 32) if guarded else jnp.bool_(False)
+    branches = [lambda: select(jnp.uint32)]
+    which = jnp.int32(0)
+    if wide:
+        branches.append(lambda: select(jnp.uint64))
+        which = (top > 32).astype(jnp.int32)
+    if guarded:
+        branches.append(other)
+        which = jnp.where(negative, len(branches) - 1, which)
+    if len(branches) == 1:
+        return TopK(*branches[0](), negative)
+    return TopK(*jax.lax.switch(which, branches), negative)
+
+
+def _bit_length(m: jax.Array) -> jax.Array:
+    """Bits an unsigned scalar has (0 for 0), int32; 32-bit ops only."""
+    if m.dtype.itemsize <= 4:
+        return (32 - jax.lax.clz(m.astype(jnp.uint32))).astype(jnp.int32)
+    hi = (m >> 32).astype(jnp.uint32)
+    lo = m.astype(jnp.uint32)
+    return jnp.where(hi > 0, 64 - jax.lax.clz(hi),
+                     32 - jax.lax.clz(lo)).astype(jnp.int32)
+
+
+def _threshold_select(u: jax.Array, valid: jax.Array, k: int,
+                      kk: jax.Array, nbits: jax.Array):
+    """The walk and the compaction over an unsigned view ``u`` whose
+    invalid slots read 0: (slots [k] int32, filled [k]); unordered."""
+    n = u.shape[0]
+    one = jnp.ones((), u.dtype)
+
+    def bit(i, thr):
+        # bit b joins T iff at least kk slots sit at or above T | 1 << b;
+        # cand >= 1, so an invalid slot (0) never counts
+        cand = thr | (one << (nbits - 1 - i).astype(u.dtype))
+        cnt = jnp.sum(u >= cand, dtype=jnp.int32)
+        return jnp.where(cnt >= kk, cand, thr)
+
+    thr = jax.lax.fori_loop(0, nbits, bit, jnp.zeros((), u.dtype))
+    strict = u > thr                     # provably fewer than kk of them
+    tie = valid & (u == thr)
+    # int32 on purpose: under x64 a bare cumsum widens to int64, which a
+    # TPU emulates
+    cum_s = jnp.cumsum(strict, dtype=jnp.int32)
+    cum_t = jnp.cumsum(tie, dtype=jnp.int32)
+    n_s = cum_s[-1]
+    # seat t (1-based): the t-th strict slot while they last, then the
+    # (t - n_s)-th tie slot; searchsorted on the monotone prefix sums
+    # finds the slot that holds each rank
+    targets = jnp.arange(1, k + 1, dtype=jnp.int32)
+    pos_s = jnp.searchsorted(cum_s, targets)
+    pos_t = jnp.searchsorted(cum_t, jnp.maximum(targets - n_s, 1))
+    idx = jnp.minimum(jnp.where(targets <= n_s, pos_s, pos_t), n - 1)
+    filled = targets <= kk
+    order = jnp.lexsort((jnp.where(filled, u[idx], 0), filled))[::-1]
+    return idx[order].astype(jnp.int32), filled[order]
+
+
+def masked_topk(values: jax.Array, valid: jax.Array, k: int,
+                value_bits: int = 64):
+    """The exact masked top-k a one-chip fire calls:
+    (values [k], slots [k], ok [k]). Integers take the threshold select
+    (``threshold_topk``); floats, and integers with a negative valid
+    value, the radix walk of 16-bit histograms. ``value_bits``: the
+    caller's promise that the values are non-negative and under
+    2^value_bits; 64 is always safe. Consumers needing the sort-based
+    lowering call ``masked_topk_sort`` directly."""
     from .hash_table import ensure_x64
 
-    ensure_x64()  # uint64 radix walk needs x64 enabled
-    # tighter bound => non-negative values with the top bits constant
-    # after the sign-flip map (1 at bit 63, 0 down to value_bits): seed
-    # the prefix with those known bits and walk only the low fields.
-    # Floats always take the full walk: their monotone map packs the
-    # exponent into the HIGH bits, so a low-bits-only walk is wrong.
-    if (value_bits >= 64
-            or jnp.issubdtype(jnp.asarray(values).dtype, jnp.floating)):
-        passes = 4
-    else:
-        passes = max(1, -(-value_bits // 16))
-    if value_bits <= 32 and not jnp.issubdtype(jnp.asarray(values).dtype,
-                                               jnp.floating):
-        # non-negative integers below 2^32: bitwise threshold bisection —
-        # value_bits compare-and-count passes plus a searchsorted
-        # compaction, no scatter anywhere. XLA lowers scatter to a
-        # serial per-element loop, so the histogram walk's 65536-bin
-        # scatter-adds and the [n]->[k] compaction scatters dominate the
-        # radix path end to end (measured 3.6 ms vs 0.47 ms at n=16k and
-        # 188 ms vs 37 ms at n=1M for k=1000, value_bits=31 on one CPU
-        # host); the bisection is pure vectorized compare/reduce/gather
-        # and is also deterministic in its tie selection (index order),
-        # identically on every backend.
-        return _masked_topk_bisect(values, valid, k, value_bits)
-    return _masked_topk_radix(values, valid, k, passes)
+    ensure_x64()  # the wide view and the radix walk are uint64
+    return _masked_topk(values, valid, k, value_bits)
 
 
-@partial(jax.jit, static_argnames=("k", "passes"))
-def _masked_topk_radix(values: jax.Array, valid: jax.Array, k: int,
-                       passes: int = 4):
+#: the older name, kept for its callers
+masked_topk_radix = masked_topk
+
+
+@partial(jax.jit, static_argnames=("k", "value_bits"))
+def _masked_topk(values, valid, k: int, value_bits: int):
+    top = threshold_topk(values, valid, k, value_bits)
+    return top.values, top.indices.astype(jnp.int64), top.ok
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _masked_topk_radix(values: jax.Array, valid: jax.Array, k: int):
+    """The radix walk: four 16-bit histogram passes over the monotone
+    uint64 image pin the exact k-th largest value, any ordered dtype."""
     n = values.shape[0]
     k = min(k, n)
     u = _to_uint64(values)
@@ -119,12 +223,9 @@ def _masked_topk_radix(values: jax.Array, valid: jax.Array, k: int,
     kk = jnp.minimum(jnp.int64(k), nvalid)          # effective k
     cand = valid
     above = jnp.int64(0)                             # strictly above prefix
-    # with fewer than 4 passes the caller guarantees the skipped top bits
-    # are constant (non-negative values below 2^(16*passes)): after the
-    # sign flip that constant is exactly the sign bit
-    prefix = jnp.uint64(0) if passes >= 4 else jnp.uint64(1) << 63
+    prefix = jnp.uint64(0)
     bins = jnp.arange(65536, dtype=jnp.int64)
-    for shift in (48, 32, 16, 0)[4 - passes:]:
+    for shift in (48, 32, 16, 0):
         field = ((u >> shift) & jnp.uint64(0xFFFF)).astype(jnp.int32)
         hist = jnp.zeros(65536, jnp.int64).at[field].add(
             cand.astype(jnp.int64))
@@ -168,57 +269,6 @@ def _masked_topk_radix(values: jax.Array, valid: jax.Array, k: int,
     return buf_v[order], jnp.maximum(buf_i, 0)[order], filled[order]
 
 
-@partial(jax.jit, static_argnames=("k", "bits"))
-def _masked_topk_bisect(values: jax.Array, valid: jax.Array, k: int,
-                        bits: int = 32):
-    """Scatter-free exact top-k for non-negative integer domains below
-    2^bits: find the exact k-th largest value T by building it bit by bit
-    from the top — bit b joins the threshold iff at least kk candidates
-    still sit at or above ``T | (1 << b)`` — then compact the winners
-    with cumsum + searchsorted instead of scatters.
-
-    Every pass is one vectorized compare + masked count over [n]; the
-    compaction is two monotone-prefix binary searches of k targets. No
-    scatter appears anywhere, which on CPU (where XLA lowers scatter to
-    a serial loop) makes this several times faster than the histogram
-    radix walk at every measured size, and the arithmetic is plain
-    compare/reduce/gather that maps onto any backend identically.
-
-    Tie handling is exact and deterministic: every slot strictly above T
-    is included (provably fewer than kk of them), and remaining seats
-    fill with the lowest-index slots equal to T — ties are
-    interchangeable by definition, so this matches the radix contract."""
-    n = values.shape[0]
-    k = min(k, n)
-    u = values.astype(jnp.uint32)
-    nvalid = jnp.sum(valid, dtype=jnp.int32)
-    kk = jnp.minimum(jnp.int32(k), nvalid)
-    thr = jnp.uint32(0)
-    for b in range(bits - 1, -1, -1):
-        cand = thr | (jnp.uint32(1) << b)
-        cnt = jnp.sum(valid & (u >= cand), dtype=jnp.int32)
-        thr = jnp.where(cnt >= kk, cand, thr)
-    strict = valid & (u > thr)
-    tie = valid & (u == thr)
-    cum_s = jnp.cumsum(strict.astype(jnp.int32))
-    cum_t = jnp.cumsum(tie.astype(jnp.int32))
-    n_s = cum_s[-1]
-    # seat t (1-based): t-th strict slot while they last, then the
-    # (t - n_s)-th tie slot; searchsorted on the monotone prefix sums
-    # finds the index holding each rank without any scatter
-    targets = jnp.arange(1, k + 1, dtype=jnp.int32)
-    pos_s = jnp.searchsorted(cum_s, targets)
-    pos_t = jnp.searchsorted(cum_t, jnp.maximum(targets - n_s, 1))
-    idx = jnp.minimum(jnp.where(targets <= n_s, pos_s, pos_t), n - 1)
-    filled = targets <= kk
-    sent = _sentinel(values.dtype)
-    buf_v = jnp.where(filled, values[idx], sent)
-    order = jnp.lexsort((jnp.where(filled, buf_v.astype(jnp.uint32),
-                                   jnp.uint32(0)),
-                         filled))[::-1]
-    return (buf_v[order], idx[order].astype(jnp.int64), filled[order])
-
-
 def _sentinel(dtype):
     return (jnp.finfo(dtype).min if jnp.issubdtype(dtype, jnp.floating)
             else jnp.iinfo(dtype).min)
@@ -232,13 +282,3 @@ def masked_topk_sort(values: jax.Array, valid: jax.Array, k: int):
     kk = min(k, values.shape[0])
     vals, idx = jax.lax.top_k(masked, kk)
     return vals, idx, jnp.take(valid, idx)
-
-
-def masked_topk(values: jax.Array, valid: jax.Array, k: int,
-                value_bits: int = 64):
-    """Backend-tuned exact masked top-k: radix select everywhere by
-    default (XLA's sort-based top_k measured ~7x slower at [2M], k=1000 on
-    CPU; radix is O(n) scatter/reduce passes that also map well onto TPU
-    HBM bandwidth). Consumers needing the sort-based lowering can call
-    masked_topk_sort directly."""
-    return masked_topk_radix(values, valid, k, value_bits)

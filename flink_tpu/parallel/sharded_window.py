@@ -52,6 +52,7 @@ from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
 from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
     AGG_MERGES, INVERTIBLE_KINDS, merge_tree_build, merge_tree_update, \
     pow2_ceil, scatter_fold
+from ..ops.topk import masked_topk_sort, threshold_topk
 from .exchange import bucket_capacity, exchange_round, plan_exchange
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
     key_groups_device, shard_ranges
@@ -308,16 +309,28 @@ def _fire_program(sig):
     return fire
 
 
-@instrumented_program_cache("mesh.fire_full")
-def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int]):
-    """ONE compiled program for the whole fire (the mesh twin of
-    device_window._fire_program): pane merge for every aggregate + emit
-    mask + optional two-phase global top-k (per-shard lax.top_k, merge of
-    D*k candidates) + health scalars (max shard occupancy, total drops)
-    riding in the same outputs, so the hot loop never pays a separate sync
-    for pressure checks. Everything it returns is materialized with ONE
-    async device->host copy — never the full [D, capacity] table when a
-    top-k is requested."""
+def _top_rows(agg_sig, state: ShardedWindowState, planes: dict,
+              emit: jax.Array, rank_name: str, topk: int, axis_name: str,
+              mesh: Mesh):
+    """A ranked fire's tail: the keys and plane values of the global
+    top-k by ``planes[rank_name]``, and how the select got there (int32
+    [2]: the passes the longest shard's select walked, whether any shard
+    took the sort), to ride to the host in the fire's one copy."""
+    # a COUNT cannot be negative, whatever its width: the select then
+    # compiles no guard and no sort (global_topk's value_bits)
+    is_count = any(name == rank_name and kind == "count"
+                   for name, kind, _ in agg_sig)
+    _vals, flat_idx, ok, passes, fell_back = global_topk(
+        planes[rank_name], emit, topk, mesh, axis_name,
+        63 if is_count else 64)
+    keys = jnp.take(state.table.reshape(-1), flat_idx)
+    res = {n: jnp.take(v.reshape(-1), flat_idx) for n, v in planes.items()}
+    return keys, ok, res, jnp.stack([passes, fell_back.astype(jnp.int32)])
+
+
+def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
+                    axis_name: str, mesh: Mesh):
+    """The jitted full fire on ``mesh`` (see _fire_full_program)."""
     _, agg_sig, _cap, _ring = sig
     aggs = _aggs_from_sig(agg_sig)
     count_name = next(name for name, kind, _ in agg_sig if kind == "count")
@@ -339,14 +352,29 @@ def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int]):
             # a copy: an input handed back as it is would share the
             # table's buffer, which the next step donates
             return jnp.copy(state.table), emit, out, dropped, occ
-        rank = out[rank_name]
-        _vals, flat_idx, ok = global_topk(rank, emit, topk)
-        keys = jnp.take(state.table.reshape(-1), flat_idx)
-        res = {n: jnp.take(v.reshape(-1), flat_idx)
-               for n, v in out.items()}
-        return keys, ok, res, dropped, occ
+        keys, ok, res, select = _top_rows(agg_sig, state, out, emit,
+                                          rank_name, topk, axis_name, mesh)
+        return keys, ok, res, dropped, occ, select
 
     return fire
+
+
+@instrumented_program_cache("mesh.fire_full")
+def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int],
+                       axis_name: str = DATA_AXIS):
+    """ONE compiled program for the whole fire (the mesh twin of
+    device_window._fire_program): pane merge for every aggregate + emit
+    mask + optional two-phase global top-k (``global_topk``: a threshold
+    select on every shard, merge of D*k candidates) + health scalars (max
+    shard occupancy, total drops) and the select's pass count riding in
+    the same outputs, so the hot loop never pays a separate sync for
+    pressure checks. Everything it returns is materialized with ONE
+    async device->host copy — never the full [D, capacity] table when a
+    top-k is requested. Like the step, the returned dispatcher takes the
+    concrete Mesh as its first argument and binds per mesh inside this
+    one cache entry (the select runs under that mesh's shard_map)."""
+    return _per_mesh(lambda mesh: _make_fire_full(sig, rank_name, topk,
+                                                  axis_name, mesh))
 
 
 @instrumented_program_cache("mesh.seal_inc")
@@ -436,30 +464,32 @@ def _rebuild_inc_program(sig):
 
 
 @instrumented_program_cache("mesh.fire_inc")
-def _fire_inc_program(sig, rank_name: Optional[str], topk: Optional[int]):
+def _fire_inc_program(sig, rank_name: Optional[str], topk: Optional[int],
+                      axis_name: str = DATA_AXIS):
     """The fused fire over an incremental view: emit mask + optional
     global top-k + health scalars — identical output structure to
-    _fire_full_program, but reading [D, capacity] views instead of
-    merging W ring rows."""
+    _fire_full_program (and bound per mesh like it), but reading
+    [D, capacity] views instead of merging W ring rows."""
     _, agg_sig, _cap, _ring = sig
     count_name = next(name for name, kind, _ in agg_sig if kind == "count")
 
-    @jax.jit
-    def fire(state: ShardedWindowState, view: dict):
-        count = view[count_name]
-        emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
-        occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
-        dropped = state.dropped.sum()
-        if topk is None:
-            return jnp.copy(state.table), emit, view, dropped, occ
-        rank = view[rank_name]
-        _vals, flat_idx, ok = global_topk(rank, emit, topk)
-        keys = jnp.take(state.table.reshape(-1), flat_idx)
-        res = {n: jnp.take(v.reshape(-1), flat_idx)
-               for n, v in view.items()}
-        return keys, ok, res, dropped, occ
+    def make(mesh: Mesh):
+        @jax.jit
+        def fire(state: ShardedWindowState, view: dict):
+            count = view[count_name]
+            emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
+            occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
+            dropped = state.dropped.sum()
+            if topk is None:
+                return jnp.copy(state.table), emit, view, dropped, occ
+            keys, ok, res, select = _top_rows(agg_sig, state, view, emit,
+                                              rank_name, topk, axis_name,
+                                              mesh)
+            return keys, ok, res, dropped, occ, select
 
-    return fire
+        return fire
+
+    return _per_mesh(make)
 
 
 @instrumented_program_cache("mesh.retire")
@@ -556,6 +586,11 @@ class ShardedWindowAgg:
         output shardings are the plan's)."""
         return _make_init(self.sig, self.plan.rules, self.mesh)
 
+    def fire_program(self, rank_name: Optional[str], topk: Optional[int]):
+        """The jitted full fire ``fire_compact`` dispatches."""
+        return _make_fire_full(self.sig, rank_name, topk,
+                               self.plan.axis_name, self.mesh)
+
     def step_program(self):
         """The jitted step ``step`` dispatches, with its two ownership
         bounds as trailing arguments."""
@@ -601,7 +636,8 @@ class ShardedWindowAgg:
     # ------------------------------------------------------------------
     def _fire_full_program(self, rank_name: Optional[str],
                            topk: Optional[int]):
-        return _fire_full_program(self.sig, rank_name, topk)
+        return _fire_full_program(self.sig, rank_name, topk,
+                                  self.plan.axis_name)
 
     def fire_compact(self, state: ShardedWindowState, pane_rows: np.ndarray,
                      rows_valid: np.ndarray, rank_name: Optional[str],
@@ -609,7 +645,7 @@ class ShardedWindowAgg:
         """Dispatch the fused fire; returns device outputs (see
         _fire_full_program) without synchronizing."""
         return self._fire_full_program(rank_name, topk)(
-            state, jnp.asarray(pane_rows, jnp.int32),
+            self.mesh, state, jnp.asarray(pane_rows, jnp.int32),
             jnp.asarray(rows_valid))
 
     # -- incremental fire engine ---------------------------------------
@@ -636,7 +672,8 @@ class ShardedWindowAgg:
                  rank_name: Optional[str], topk: Optional[int]):
         """Dispatch the fused incremental fire; returns device outputs
         (same structure as fire_compact) without synchronizing."""
-        return _fire_inc_program(self.sig, rank_name, topk)(state, view)
+        return _fire_inc_program(self.sig, rank_name, topk,
+                                 self.plan.axis_name)(self.mesh, state, view)
 
     # ------------------------------------------------------------------
     def retire_row(self, state: ShardedWindowState,
@@ -646,24 +683,44 @@ class ShardedWindowAgg:
         return state._replace(accs=self._retire(state.accs, jnp.int32(row)))
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def global_topk(values: jax.Array, valid: jax.Array, k: int
-                ) -> tuple[jax.Array, jax.Array, jax.Array]:
+@functools.partial(jax.jit, static_argnames=("k", "mesh", "axis_name",
+                                             "value_bits"))
+def global_topk(values: jax.Array, valid: jax.Array, k: int,
+                mesh: Optional[Mesh] = None, axis_name: str = DATA_AXIS,
+                value_bits: int = 64
+                ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
+                           jax.Array]:
     """Two-phase global top-k over sharded [D, capacity] per-key values
-    (Nexmark Q5 hot items): per-shard top-k, then merge the D*k candidates.
+    (Nexmark Q5 hot items): each shard's exact top-k by the threshold
+    select of ``ops/topk.py`` (no sort over the slots; floats, and
+    integers with a negative valid value, take ``lax.top_k`` there), then
+    a ``lax.top_k`` over the D*k candidates. With ``mesh`` phase one runs
+    under ``shard_map`` over ``axis_name``: every device selects among its
+    own slots and only its k candidates leave it. ``value_bits`` is the
+    caller's promise of ``threshold_topk``: under the dtype's width it
+    says no value is negative, and the sort is not compiled at all.
+
     Returns (values [k], flat indices [k] into the [D*capacity] layout,
-    ok [k] bool). Entries with ok=False are padding (fewer than k valid
-    slots existed); their values/indices must be ignored — for integer
-    dtypes the sentinel is indistinguishable from a real minimum, so
-    always filter on ``ok``, not on the values."""
-    neg = (jnp.finfo(values.dtype).min
-           if jnp.issubdtype(values.dtype, jnp.floating)
-           else jnp.iinfo(values.dtype).min)
-    masked = jnp.where(valid, values, neg)
-    D, cap = masked.shape
+    ok [k] bool, the compare-and-count passes the longest shard's select
+    walked, whether any shard took the sort). Entries with ok=False are
+    padding (fewer than k valid slots existed); their values/indices must
+    be ignored — for integer dtypes the sentinel is indistinguishable
+    from a real minimum, so always filter on ``ok``, not on the values."""
+    D, cap = values.shape
     kk = min(k, cap)
-    local_v, local_i = jax.lax.top_k(masked, kk)          # [D, kk]
-    local_ok = jnp.take_along_axis(valid, local_i, axis=1)
+
+    def shards(vals, ok):
+        tops = [threshold_topk(vals[d], ok[d], kk, value_bits,
+                               otherwise=masked_topk_sort)
+                for d in range(vals.shape[0])]
+        return tuple(jnp.stack(leaf) for leaf in zip(*tops))
+
+    if mesh is not None:
+        spec = P(axis_name)
+        shards = shard_map_unchecked(shards, mesh, in_specs=(spec, spec),
+                                     out_specs=spec)
+    local_v, local_i, local_ok, passes, fell_back = shards(values, valid)
     flat_i = local_i + (jnp.arange(D, dtype=jnp.int32)[:, None] * cap)
     merged_v, sel = jax.lax.top_k(local_v.reshape(-1), min(k, D * kk))
-    return merged_v, flat_i.reshape(-1)[sel], local_ok.reshape(-1)[sel]
+    return (merged_v, flat_i.reshape(-1)[sel], local_ok.reshape(-1)[sel],
+            passes.max(), fell_back.any())

@@ -56,11 +56,13 @@ __all__ = ["DeviceWindowAggOperator", "AggSpec"]
 class AggSpec:
     """One aggregate column: kind in sum|count|min|max|avg over field.
 
-    ``value_bits``: static bound on the aggregate's RESULT domain (non-
-    negative, below 2^value_bits), used to shorten the top-k radix select
-    at fire time (ops/topk.py) — each 16 bits saved drops one O(capacity)
-    histogram pass. Defaults: 48 for count (exact up to 2.8e14 events per
-    key per window), 64 (always safe) otherwise."""
+    ``value_bits``: the promise that the aggregate's RESULT is non-
+    negative and below 2^value_bits. The fire's top-k select (ops/topk.py
+    ``threshold_topk``) walks the bits the data has whatever is declared;
+    the promise only spares it what it then need not compile: at most 32
+    bits, the 64-bit view; under the plane's width, the guard against a
+    negative rank. Defaults: 48 for count (exact up to 2.8e14 events per
+    key per window), 64 (no promise) otherwise."""
 
     def __init__(self, kind: str, field: Optional[str] = None,
                  out_name: Optional[str] = None, dtype=jnp.float32,
@@ -76,10 +78,8 @@ class AggSpec:
 
 
 from ...ops.topk import masked_topk as _masked_topk  # noqa: E402
-# exact radix-select top-k: XLA's sort-based lax.top_k over a [capacity]
-# accumulator measured ~480 ms/fire (k=1000, 2M slots, CPU) and dominated
-# the whole window-fire stage; radix select is O(capacity) histogram
-# passes (see ops/topk.py)
+# exact top-k by threshold select, not by sort: lax.top_k over a
+# [capacity] accumulator is a variant of a full sort (see ops/topk.py)
 
 
 def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
@@ -583,7 +583,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # aggregate with value_bits <= 31 promises every per-window count
         # fits int32, which halves the fold scatter + fire merge traffic
         # on the [ring, capacity] plane (the whole-capacity passes are the
-        # memory-bound cost at 10M+ keys) and feeds the uint32 radix
+        # memory-bound cost at 10M+ keys) and feeds the uint32 threshold
         # select directly
         cvb = min((a.value_bits for a in self._aggs if a.kind == "count"),
                   default=64)

@@ -537,8 +537,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             host = jax.device_get(outs)   # ONE transfer for everything
             d2h_bytes = pytree_nbytes(host)
             if self._topk is not None:
-                keys_k, ok, results, dropped, occ = host
+                keys_k, ok, results, dropped, occ, select = host
                 self._apply_health(dropped, occ)
+                DEVICE_STATS.note_fire_select(*select)
                 sel = np.asarray(ok)
                 keys = np.asarray(keys_k)[sel]
                 res = {n: np.asarray(v)[sel] for n, v in results.items()}

@@ -249,6 +249,8 @@ def test_mesh_inc_programs_match_full_merge():
     from flink_tpu.ops.segment_ops import (
         AGG_MERGES, INVERTIBLE_KINDS, make_accumulator, pow2_ceil,
     )
+    from flink_tpu.parallel.mesh import make_mesh
+    from flink_tpu.parallel.plan import MESH_RUNTIME
     from flink_tpu.parallel.sharded_window import (
         AggDef, ShardedWindowAgg, ShardedWindowState,
     )
@@ -263,6 +265,8 @@ def test_mesh_inc_programs_match_full_merge():
     agg.capacity = cap
     agg.ring = ring
     agg.n_dev = D
+    agg.mesh = make_mesh(D)          # the ranked fire selects per shard
+    agg.plan = MESH_RUNTIME.plan(agg.mesh)
     agg._fire_variants = {}
     agg.tree_size = pow2_ceil(ring)
     agg.inv_sig = tuple((a.kind, a.name) for a in aggs

@@ -500,7 +500,8 @@ def _q5_columns(idx):
             "ts": idx.astype(np.int64) // 4}
 
 
-def _run_q5(aggregate, traces=False):
+def _run_q5(aggregate, traces=False, incremental=None, columns=None,
+            schema=None):
     from flink_tpu.api import StreamExecutionEnvironment
     from flink_tpu.core import WatermarkStrategy
     from flink_tpu.core.config import PipelineOptions, TraceOptions
@@ -512,10 +513,13 @@ def _run_q5(aggregate, traces=False):
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, Q5["batch"])
     env.config.set(TraceOptions.ENABLED, traces)
+    if incremental is not None:
+        env.config.set("window.fire.incremental", incremental)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = chip_smoke._collecting_sink()
-    windowed = (env.datagen(_q5_columns, Q5_SCHEMA, count=Q5["n_events"],
+    windowed = (env.datagen(columns or _q5_columns, schema or Q5_SCHEMA,
+                            count=Q5["n_events"],
                             timestamp_column="ts", watermark_strategy=ws)
                 .key_by("auction")
                 .window(SlidingEventTimeWindows.of(
@@ -598,6 +602,81 @@ def test_q5_with_a_hot_set_equals_reference_and_one_chip(
     assert (after["h2d_bytes"] - before["h2d_bytes"]
             == Q5["n_events"] * (3 * 8 + 1))
     assert after["d2h_bytes"] > before["d2h_bytes"]
+
+
+@pytest.mark.parametrize("incremental", [False, True],
+                         ids=["fire_full", "fire_inc"])
+def test_both_mesh_fires_select_the_one_chip_operators_rows(
+        incremental, q5_one_chip_rows):
+    """The threshold select behind both mesh fire programs (PR 31), four
+    devices: the fired rows are the one-chip operator's on the same
+    input, every ranked fire is counted with the passes its longest
+    shard walked (the bit length of the window's largest count), and a
+    COUNT rank never takes the sort."""
+    import chip_smoke
+    from flink_tpu.metrics import DEVICE_STATS
+
+    before = DEVICE_STATS.snapshot()
+    rows, _job = _run_q5(lambda w, aggs: w.mesh_aggregate(
+        aggs, n_devices=4, capacity=1 << 11, ring_size=16,
+        device_batch=Q5["batch"] // 4, emit_window_bounds=True,
+        emit_topk=Q5["topk"], async_fire=True), incremental=incremental)
+    after = DEVICE_STATS.snapshot()
+    _check_against_reference(rows)
+    chip_smoke.check_same_answer(rows, q5_one_chip_rows)
+    grew = {k: after[k] - before[k] for k in (
+        "fire_selects_total", "fire_select_passes_total",
+        "fire_select_sort_total", "panes_sealed_total")}
+    ends = np.unique(rows["window_end"])
+    assert grew["fire_selects_total"] == len(ends)
+    assert grew["fire_select_sort_total"] == 0
+    assert (grew["panes_sealed_total"] > 0) == incremental
+    top = [int(rows["bids"][rows["window_end"] == e].max()) for e in ends]
+    assert grew["fire_select_passes_total"] == sum(
+        t.bit_length() for t in top)
+
+
+Q5_FLOAT_SCHEMA = Schema([("auction", np.int64), ("price", np.float32),
+                          ("ts", np.int64)])
+
+
+def test_a_float_rank_takes_the_sort_and_is_counted():
+    """Ranked on a float32 SUM the mesh fire keeps `lax.top_k` (a float's
+    order is not its bit order): every fire counts in
+    `fire_select_sort_total`, walks no pass, and the rows are the top-k
+    of the unranked job's."""
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.runtime.operators.device_window import AggSpec
+
+    def columns(idx):
+        cols = _q5_columns(idx)
+        cols["price"] = (cols["price"] % 1000).astype(np.float32)
+        return cols
+
+    def job(topk):
+        return _run_q5(lambda w, _aggs: w.mesh_aggregate(
+            [AggSpec("sum", "price", out_name="revenue"),
+             AggSpec("count", out_name="bids")],
+            n_devices=4, capacity=1 << 11, ring_size=16,
+            device_batch=Q5["batch"] // 4, emit_window_bounds=True,
+            emit_topk=topk, async_fire=True), columns=columns,
+            schema=Q5_FLOAT_SCHEMA)[0]
+
+    full = job(None)
+    before = DEVICE_STATS.snapshot()
+    rows = job(Q5["topk"])
+    after = DEVICE_STATS.snapshot()
+    ends = np.unique(full["window_end"])
+    fires = after["fire_selects_total"] - before["fire_selects_total"]
+    assert fires == len(ends) == len(np.unique(rows["window_end"]))
+    assert after["fire_select_sort_total"] \
+        - before["fire_select_sort_total"] == fires
+    assert after["fire_select_passes_total"] \
+        == before["fire_select_passes_total"]
+    for e in ends:
+        want = np.sort(full["revenue"][full["window_end"] == e])[::-1]
+        got = np.sort(rows["revenue"][rows["window_end"] == e])[::-1]
+        np.testing.assert_array_equal(got, want[:Q5["topk"]])
 
 
 def test_mesh_blocks_have_upload_and_dispatch_stage_spans():

@@ -277,7 +277,7 @@ def test_skewed_batch_takes_more_rounds_and_loses_nothing(n_dev):
 def test_global_topk():
     vals = jnp.asarray(np.arange(64, dtype=np.float32).reshape(8, 8))
     valid = jnp.ones((8, 8), bool).at[7, 7].set(False)  # mask the max
-    v, idx, ok = global_topk(vals, valid, 3)
+    v, idx, ok, _passes, _sort = global_topk(vals, valid, 3)
     np.testing.assert_array_equal(np.asarray(jax.device_get(v)),
                                   [62.0, 61.0, 60.0])
     np.testing.assert_array_equal(np.asarray(jax.device_get(idx)),
@@ -288,11 +288,123 @@ def test_global_topk():
 def test_global_topk_fewer_valid_than_k():
     vals = jnp.asarray(np.arange(16, dtype=np.int64).reshape(4, 4))
     valid = jnp.zeros((4, 4), bool).at[1, 2].set(True).at[2, 3].set(True)
-    v, idx, ok = global_topk(vals, valid, 5)
+    v, idx, ok, _passes, _sort = global_topk(vals, valid, 5)
     ok_h = np.asarray(jax.device_get(ok))
     assert ok_h.sum() == 2
     kept = np.asarray(jax.device_get(idx))[ok_h]
     np.testing.assert_array_equal(sorted(kept), [6, 11])
+
+
+# -- the per-shard threshold select behind global_topk (PR 31) ---------------
+
+def _topk_case(name, D, cap=512):
+    """([D, cap] int64 ranks, valid, k): COUNT-like data with the named
+    feature. The last shard of a mesh is the odd one out."""
+    rng = np.random.default_rng(31 + D)
+    vals = rng.integers(0, 30, (D, cap)).astype(np.int64)
+    valid = rng.random((D, cap)) < 0.7
+    k = 40
+    if name == "ties_at_kth":
+        vals[:, :5] = 900 + np.arange(5)
+        vals[:, 5:200] = 77
+        valid[:, :200] = True
+    elif name == "fewer_valid_than_k":
+        valid[:] = False
+        valid[:, 3::97] = True
+    elif name == "an_all_zero_shard":
+        vals[-1] = 0
+    elif name == "one_shard_holds_every_winner":
+        vals[:] = vals % 7
+        vals[-1, :k + 9] = 5000 + np.arange(k + 9)
+        valid[-1, :k + 9] = True
+    elif name == "max_at_2p32":
+        vals[-1, 17] = 1 << 32
+        valid[-1, 17] = True
+    elif name == "max_above_2p32":
+        vals[-1, 17:30] = (1 << 35) + np.arange(13)
+        valid[-1, 17:30] = True
+    elif name == "a_negative_valid_value":
+        vals[-1, 17] = -4
+        valid[-1, 17] = True
+    elif name == "a_float_rank":
+        vals = (vals + rng.random((D, cap))).astype(np.float32)
+    else:
+        raise ValueError(name)
+    return vals, valid, k
+
+
+TOPK_CASES = ["ties_at_kth", "fewer_valid_than_k", "an_all_zero_shard",
+              "one_shard_holds_every_winner", "max_at_2p32",
+              "max_above_2p32", "a_negative_valid_value", "a_float_rank"]
+
+
+@pytest.mark.parametrize("D", [1, 4])
+@pytest.mark.parametrize("name", TOPK_CASES)
+def test_global_topk_selects_by_threshold_on_every_shard(name, D):
+    """Against numpy, under the mesh's shard_map: the values are the k
+    largest valid ones (ties at the k-th free), each index carries its
+    value, and the passes handed back are the bit length of the largest
+    valid rank: the select walks the bits the data has, not the 64 its
+    dtype declares. Only a float rank or a negative valid value takes the
+    sort, and DEVICE_STATS counts exactly those."""
+    from flink_tpu.metrics import DEVICE_STATS
+
+    vals, valid, k = _topk_case(name, D)
+    v, idx, ok, passes, sort = jax.device_get(global_topk(
+        jnp.asarray(vals), jnp.asarray(valid), k, make_mesh(D)))
+    want = np.sort(vals[valid])[::-1][:k]
+    np.testing.assert_array_equal(v[ok], want)
+    assert ok.sum() == len(want) and ok[:len(want)].all()
+    assert len(np.unique(idx[ok])) == ok.sum()
+    np.testing.assert_array_equal(vals.reshape(-1)[idx[ok]], v[ok])
+    assert valid.reshape(-1)[idx[ok]].all()
+    takes_sort = name in ("a_negative_valid_value", "a_float_rank")
+    assert bool(sort) == takes_sort
+    if name == "a_float_rank":
+        assert passes == 0
+    else:
+        # a shard that took the sort walked nothing; the others did
+        walked = [int(vals[d][valid[d]].max()).bit_length()
+                  for d in range(D)
+                  if valid[d].any() and vals[d][valid[d]].min() >= 0]
+        assert passes == max(walked, default=0)
+    before = DEVICE_STATS.snapshot()
+    DEVICE_STATS.note_fire_select(passes, sort)
+    after = DEVICE_STATS.snapshot()
+    assert after["fire_selects_total"] - before["fire_selects_total"] == 1
+    assert after["fire_select_passes_total"] \
+        - before["fire_select_passes_total"] == passes
+    assert after["fire_select_sort_total"] \
+        - before["fire_select_sort_total"] == int(takes_sort)
+
+
+def test_global_topk_without_a_mesh_is_the_same_select():
+    vals, valid, k = _topk_case("ties_at_kth", 4)
+    with_mesh = jax.device_get(global_topk(
+        jnp.asarray(vals), jnp.asarray(valid), k, make_mesh(4)))
+    without = jax.device_get(global_topk(
+        jnp.asarray(vals), jnp.asarray(valid), k))
+    for a, b in zip(with_mesh, without):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_count_rank_compiles_no_sort_over_a_shards_slots():
+    """The promise a COUNT keeps (never negative: value_bits under the
+    dtype's width) leaves the sort out of the program; without it the
+    guard and the sort are there, for the negative value that may come."""
+    import re
+
+    D, cap, k = 4, 4096, 16
+    args = (jnp.zeros((D, cap), jnp.int64), jnp.zeros((D, cap), bool))
+
+    def widest_sort(value_bits):
+        hlo = global_topk.lower(*args, k, make_mesh(D), "data",
+                                value_bits).compile().as_text()
+        return max((int(m.group(1)) for m in re.finditer(
+            r"\[(\d+)\][^\n]* sort\(", hlo)), default=0)
+
+    assert widest_sort(63) <= D * k
+    assert widest_sort(64) >= cap
 
 
 # -- what a v5e refuses to lower (found on the chip, PR 22) ------------------

@@ -119,3 +119,128 @@ def test_sort_variant_agrees():
     sv, _si, sok = map(np.asarray, masked_topk_sort(
         jnp.asarray(vals), jnp.asarray(valid), 50))
     np.testing.assert_array_equal(rv[rok], sv[sok])
+
+
+# -- the threshold select (PR 31): the walk starts at the bit the data has --
+
+def _case(name):
+    """(values, valid, k) whose k-th largest is what the name says."""
+    rng = np.random.default_rng(31)
+    n = 2048
+    vals = rng.integers(0, 40, n).astype(np.int64)
+    valid = rng.random(n) < 0.8
+    k = 100
+    if name == "ties_at_kth":
+        vals[:60] = 1000 + np.arange(60)          # 60 strict
+        vals[60:900] = 500                        # 840 equal at the k-th
+        valid[:900] = True
+    elif name == "fewer_valid_than_k":
+        valid[:] = False
+        valid[::64] = True                        # 32 valid
+    elif name == "all_zero":
+        vals[:] = 0
+    elif name == "none_valid":
+        valid[:] = False
+    elif name == "max_at_2p32":
+        vals[5] = 1 << 32
+        valid[5] = True
+    elif name == "max_above_2p32":
+        vals[7:40] = (1 << 40) + np.arange(33) * (1 << 33)
+        valid[7:40] = True
+    elif name == "negative_valid":
+        vals[11] = -3
+        valid[11] = True
+    elif name == "negative_masked_out":
+        vals[11] = -3
+        valid[11] = False
+    elif name == "hot_counts":
+        vals[:27] = 22_500 + np.arange(27)        # 15 bits
+        valid[:27] = True
+    else:
+        raise ValueError(name)
+    return vals, valid, k
+
+
+CASES = ["ties_at_kth", "fewer_valid_than_k", "all_zero", "none_valid",
+         "max_at_2p32", "max_above_2p32", "negative_valid",
+         "negative_masked_out", "hot_counts"]
+
+
+def _bit_length_of_largest_valid(vals, valid):
+    return int(vals[valid].max()).bit_length() if valid.any() else 0
+
+
+@pytest.mark.parametrize("otherwise", ["radix", "sort"])
+@pytest.mark.parametrize("name", CASES)
+def test_threshold_select_matches_oracle_and_counts_its_passes(
+        name, otherwise):
+    from flink_tpu.ops.topk import threshold_topk
+
+    vals, valid, k = _case(name)
+    top = jax.jit(lambda v, m: threshold_topk(
+        v, m, k, otherwise=masked_topk_sort if otherwise == "sort"
+        else None))(jnp.asarray(vals), jnp.asarray(valid))
+    v, i, ok = (np.asarray(x) for x in (top.values, top.indices, top.ok))
+    exp = _oracle(vals, valid, k)
+    assert ok[:len(exp)].all() and not ok[len(exp):].any()
+    np.testing.assert_array_equal(v[:len(exp)], exp)
+    sel = i[ok]
+    assert valid[sel].all() and len(np.unique(sel)) == len(sel)
+    np.testing.assert_array_equal(vals[sel], v[ok])
+    negative = name == "negative_valid"
+    assert bool(top.fell_back) == negative
+    assert int(top.passes) == (0 if negative else
+                               _bit_length_of_largest_valid(vals, valid))
+
+
+@pytest.mark.parametrize("dtype,value_bits", [
+    (np.int32, 31), (np.int32, 64), (np.uint32, 64), (np.int64, 31),
+    (np.int64, 48), (np.int64, 63), (np.uint64, 64), (np.int16, 64)])
+def test_threshold_select_on_every_integer_width_and_promise(dtype,
+                                                             value_bits):
+    """The promise decides what is compiled (the wide view, the guard),
+    never the answer, as long as it is kept."""
+    from flink_tpu.ops.topk import threshold_topk
+
+    vals, valid, k = _case("hot_counts")
+    vals = vals.astype(dtype)
+    top = threshold_topk(jnp.asarray(vals), jnp.asarray(valid), k,
+                         value_bits)
+    exp = _oracle(vals, valid, k)
+    np.testing.assert_array_equal(np.asarray(top.values)[:len(exp)], exp)
+    assert int(top.passes) == 15 and not bool(top.fell_back)
+    assert top.values.dtype == vals.dtype
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.int16])
+def test_a_narrow_signed_rank_with_a_negative_value_falls_back(dtype):
+    from flink_tpu.ops.topk import threshold_topk
+
+    vals = np.array([5, -7, 3, 9, -1, 0], dtype)
+    top = threshold_topk(jnp.asarray(vals), jnp.ones(6, bool), 4)
+    np.testing.assert_array_equal(np.asarray(top.values), [9, 5, 3, 0])
+    assert bool(top.fell_back) and int(top.passes) == 0
+
+
+def test_a_float_rank_never_walks():
+    from flink_tpu.ops.topk import threshold_topk
+
+    vals = np.array([0.5, -2.0, 8.25, 3.0], np.float32)
+    top = threshold_topk(jnp.asarray(vals), jnp.ones(4, bool), 2)
+    np.testing.assert_array_equal(np.asarray(top.values), [8.25, 3.0])
+    assert bool(top.fell_back) and int(top.passes) == 0
+
+
+def test_a_kept_promise_compiles_neither_scatter_nor_sort_over_the_slots():
+    """Declared under the dtype's width (a COUNT), the select's program
+    holds the walk and the compaction only: no histogram scatter of the
+    radix walk, and no sort wider than the k winners."""
+    from flink_tpu.ops.topk import threshold_topk
+
+    n, k = 4096, 64
+    hlo = jax.jit(lambda v, m: threshold_topk(v, m, k, 48)).lower(
+        jnp.zeros(n, jnp.int64), jnp.zeros(n, bool)).compile().as_text()
+    import re
+    assert not re.search(r" scatter(-add)?\(", hlo)
+    for m in re.finditer(r"\[(\d+)\][^\n]* sort\(", hlo):
+        assert int(m.group(1)) <= k

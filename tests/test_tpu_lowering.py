@@ -111,6 +111,45 @@ def test_mesh_step_donates_its_state_at_the_benchmark_shape(v5e_devices):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 8e9
 
 
+def _operand_elements(hlo: str, op: str):
+    """Element counts of the array operands-or-results named on every
+    HLO line that applies ``op`` (``sort``, ``all-reduce``, ...)."""
+    import re
+
+    for line in hlo.splitlines():
+        if re.search(rf" {op}(-start)?\(", line):
+            head = line.split(f" {op}", 1)[0]
+            for dims in re.findall(r"\[([\d,]+)\]", head):
+                yield int(np.prod([int(d) for d in dims.split(",")]))
+
+
+def test_mesh_fire_selects_on_the_shard_at_the_benchmark_shape(v5e_devices):
+    """The whole mesh fire of q5-16m-mesh4 ([4, 2^23] int64 COUNT rank,
+    k = 1000) for a described v5e 2x2: phase one of the top-k is the
+    threshold select under shard_map, so the program holds no sort (and
+    no top-k custom call) over a shard's 2^23 slots (`top_k.3` was one,
+    138 of the fire's 161 ms: PERF.md section 5), and nothing wider than
+    the D x k candidates crosses the interconnect."""
+    import re
+
+    D, cap, k = 4, 1 << 23, 1000
+    agg, sharded, args = _q5_mesh(v5e_devices[:D], cap, 1 << 16)
+    rep = NamedSharding(sharded.mesh, P())
+    hlo = agg.fire_program("bids", k).lower(
+        args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)
+    ).compile().as_text()
+    assert "HloModule jit_fire" in hlo        # the name the traces anchor on
+    sorts = list(_operand_elements(hlo, "sort"))
+    assert sorts and max(sorts) <= D * k, max(sorts)
+    assert not re.search(r'custom_call_target="(TopK|ApproxTopK)', hlo)
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert max(_operand_elements(hlo, op), default=0) <= D * k, op
+    # the walk is there: a compare-and-count loop over the shard's slots
+    assert "shard_map" in hlo and re.search(r" while\(", hlo)
+
+
 #: the path by which the benchmark's probe_rounds_p50 finds the probe
 #: loop's claim in a device trace (benchmarks/layer_metrics/
 #: probe_rounds_p50.json), and the program it counts them under
