@@ -404,8 +404,8 @@ class DeviceStats:
 
     @property
     def fire_select_counts(self) -> tuple[int, int, int]:
-        """(ranked mesh fires, select passes they walked, those that
-        took the sort)."""
+        """(ranked fires of either window operator, select passes they
+        walked, those that took a fallback: a float rank)."""
         with self._lock:
             return (self._fire_selects, self._fire_select_passes,
                     self._fire_select_sort)
@@ -1207,7 +1207,8 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_mesh_exchange_rounds_total)
     g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])
     g.gauge("mesh_exchange_rounds_total", lambda: s.mesh_step_counts[1])
-    # mesh fire select (prometheus: flink_tpu_device_fire_selects_total /
+    # ranked fire select, both window operators (prometheus:
+    # flink_tpu_device_fire_selects_total /
     # flink_tpu_device_fire_select_passes_total /
     # flink_tpu_device_fire_select_sort_total)
     g.gauge("fire_selects_total", lambda: s.fire_select_counts[0])

@@ -95,12 +95,16 @@ def make_accumulator(kind: str, shape: tuple[int, ...], dtype) -> jax.Array:
 def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
                  values: jax.Array, valid: jax.Array) -> jax.Array:
     """Fold a batch into a flat accumulator: acc[flat_idx] op= values,
-    masked by ``valid`` (invalid rows fold the identity into slot 0)."""
+    masked by ``valid`` (invalid rows fold the identity into slot 0).
+    The scatter itself sits in a scope named after its kind (``fold.max``
+    under ``fold.scatter``), so a trace tells a max fold from an add fold
+    whatever program holds them."""
     with jax.named_scope("fold.scatter"):
         identity = AGG_INITS[kind](acc.dtype)
         idx = jnp.where(valid, flat_idx, 0)
         vals = jnp.where(valid, values.astype(acc.dtype), identity)
-        return AGG_FOLDS[kind](acc, idx, vals)
+        with jax.named_scope(f"fold.{kind}"):
+            return AGG_FOLDS[kind](acc, idx, vals)
 
 
 def pane_window_merge(kind: str, acc: jax.Array,

@@ -16,11 +16,12 @@ largest value T and compacts the survivors. Two walks find T:
   from the slots equal to T in index order: ties are interchangeable by
   definition) compact through cumsum + searchsorted. No scatter, no sort:
   6 ms for the same 2^23 int64 slots, 15 passes (my chip run, PR 31). Both
-  window stacks fire through it (one chip: ``masked_topk``; the mesh:
-  ``parallel/sharded_window.global_topk``, per shard under shard_map).
-* the radix walk (``_masked_topk_radix``), for floats and for integers
-  with a negative valid value: 4 passes of 16-bit histograms over a
-  monotone uint64 image of the values (sign-flip for signed ints, the
+  window stacks fire through it (one chip: ``device_window._select_topk``;
+  the mesh: ``parallel/sharded_window.global_topk``, per shard under
+  shard_map). An integer rank that may be negative (no ``value_bits``
+  promise) walks its sign-flipped view: the same loop, all of its bits.
+* the radix walk (``_masked_topk_radix``), for floats: 4 passes of 16-bit
+  histograms over a monotone uint64 image of the values (the
   sign-magnitude trick for floats), each one elementwise extract + one
   scatter-add into 65536 bins; the survivors compact with a two-ended
   scatter (ties from the back of a [k] buffer, strict from the front and
@@ -94,9 +95,13 @@ def threshold_topk(values: jax.Array, valid: jax.Array, k: int,
     decides only what need not be compiled: at most 32, the 64-bit view;
     under the dtype's width, the guard. Without the promise a negative
     valid value (the same max-reduce sees it, as the unsigned view's top
-    bit) hands the whole select to
-    ``otherwise(values, valid, k) -> (values, indices, ok)``; floats go
-    there always (their order is not their bit order). ``otherwise``
+    bit) turns the view into the values' monotone image, sign bit
+    flipped, and the same walk runs over all of the dtype's bits: one
+    algorithm, and nothing but compares and counts over the slots (the
+    radix walk that once took these ranks does not compile inside a fire
+    over 2^24 slots on a v5e: its 64-bit scans overflow vmem). Floats
+    (their order is not their bit order) go whole to
+    ``otherwise(values, valid, k) -> (values, indices, ok)``, which
     defaults to the radix walk; a caller whose backend lowers scatter
     badly passes ``masked_topk_sort``.
 
@@ -106,41 +111,45 @@ def threshold_topk(values: jax.Array, valid: jax.Array, k: int,
         otherwise = _masked_topk_radix
     dt = values.dtype
     k = min(k, values.shape[0])
-
-    def other():
-        v, i, ok = otherwise(values, valid, k)
-        return v, i.astype(jnp.int32), ok, jnp.int32(0)
-
     if jnp.issubdtype(dt, jnp.floating):
-        return TopK(*other(), jnp.bool_(True))
+        v, i, ok = otherwise(values, valid, k)
+        return TopK(v, i.astype(jnp.int32), ok, jnp.int32(0),
+                    jnp.bool_(True))
     kk = jnp.minimum(jnp.int32(k), jnp.sum(valid, dtype=jnp.int32))
-
-    def view(unsigned):
-        return jnp.where(valid, values, 0).astype(unsigned)
-
-    def select(unsigned):
-        idx, filled = _threshold_select(view(unsigned), valid, k, kk, top)
-        return (jnp.where(filled, values[idx], _sentinel(dt)), idx, filled,
-                top)
-
     width = 8 * dt.itemsize
     guarded = jnp.issubdtype(dt, jnp.signedinteger) and value_bits >= width
     wide = width > 32 and value_bits > 32
+
+    def view(unsigned, flip=None):
+        if flip is None:
+            return jnp.where(valid, values, 0).astype(unsigned)
+        return jnp.where(valid, values ^ flip, 0).astype(
+            f"uint{width}").astype(unsigned)
+
     # a negative value of a signed dtype sets its unsigned view's top bit
-    seen = jnp.uint64 if wide else jnp.uint32
-    top = _bit_length(jnp.max(view(seen)))
-    negative = top >= (64 if wide else 32) if guarded else jnp.bool_(False)
-    branches = [lambda: select(jnp.uint32)]
-    which = jnp.int32(0)
-    if wide:
-        branches.append(lambda: select(jnp.uint64))
-        which = (top > 32).astype(jnp.int32)
+    top = _bit_length(jnp.max(view(jnp.uint64 if wide else jnp.uint32)))
+    flip = None
     if guarded:
-        branches.append(other)
-        which = jnp.where(negative, len(branches) - 1, which)
-    if len(branches) == 1:
-        return TopK(*branches[0](), negative)
-    return TopK(*jax.lax.switch(which, branches), negative)
+        negative = top >= (64 if wide else 32)
+        # x ^ min is monotone from the signed order onto the unsigned one;
+        # an invalid slot still reads 0, under every valid slot but one
+        # that holds the dtype's minimum, and the ties' mask tells those
+        # apart
+        flip = jnp.where(negative, jnp.iinfo(dt).min, 0).astype(dt)
+        top = jnp.where(negative, width, top)
+
+    def select(unsigned):
+        idx, filled = _threshold_select(view(unsigned, flip), valid, k, kk,
+                                        top)
+        return (jnp.where(filled, values[idx], _sentinel(dt)), idx, filled,
+                top)
+
+    if not wide:
+        return TopK(*select(jnp.uint32), jnp.bool_(False))
+    return TopK(*jax.lax.switch(
+        (top > 32).astype(jnp.int32),
+        [lambda: select(jnp.uint32), lambda: select(jnp.uint64)]),
+        jnp.bool_(False))
 
 
 def _bit_length(m: jax.Array) -> jax.Array:

@@ -692,13 +692,13 @@ def global_topk(values: jax.Array, valid: jax.Array, k: int,
                            jax.Array]:
     """Two-phase global top-k over sharded [D, capacity] per-key values
     (Nexmark Q5 hot items): each shard's exact top-k by the threshold
-    select of ``ops/topk.py`` (no sort over the slots; floats, and
-    integers with a negative valid value, take ``lax.top_k`` there), then
-    a ``lax.top_k`` over the D*k candidates. With ``mesh`` phase one runs
-    under ``shard_map`` over ``axis_name``: every device selects among its
-    own slots and only its k candidates leave it. ``value_bits`` is the
+    select of ``ops/topk.py`` (no sort over the slots; a float rank takes
+    ``lax.top_k`` there), then a ``lax.top_k`` over the D*k candidates.
+    With ``mesh`` phase one runs under ``shard_map`` over ``axis_name``:
+    every device selects among its own slots and only its k candidates
+    leave it. ``value_bits`` is the
     caller's promise of ``threshold_topk``: under the dtype's width it
-    says no value is negative, and the sort is not compiled at all.
+    says no value is negative, and the guard is not compiled at all.
 
     Returns (values [k], flat indices [k] into the [D*capacity] layout,
     ok [k] bool, the compare-and-count passes the longest shard's select

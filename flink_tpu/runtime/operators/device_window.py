@@ -77,9 +77,18 @@ class AggSpec:
                            else 48 if kind == "count" else 64)
 
 
-from ...ops.topk import masked_topk as _masked_topk  # noqa: E402
+from ...ops.topk import threshold_topk  # noqa: E402
 # exact top-k by threshold select, not by sort: lax.top_k over a
 # [capacity] accumulator is a variant of a full sort (see ops/topk.py)
+
+
+def _select_topk(ranked, emit, topk: int, value_bits: int):
+    """The ranked fire's select: (slots [k], ok [k], int32 [passes,
+    fell_back]); the last leaf rides to the host in the fire's one copy,
+    as the mesh fire's does."""
+    top = threshold_topk(ranked, emit, topk, value_bits)
+    return (top.indices.astype(jnp.int64), top.ok,
+            jnp.stack([top.passes, top.fell_back.astype(jnp.int32)]))
 
 
 def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
@@ -241,8 +250,8 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
             else:
                 ranked = merge(rk_kind, arrays[rk_name])
             with jax.named_scope("fire.topk"):
-                _vals, idx, ok = _masked_topk(ranked, emit, topk,
-                                              value_bits=topk_value_bits)
+                idx, ok, select = _select_topk(ranked, emit, topk,
+                                               topk_value_bits)
                 keys = jnp.take(table, idx)
                 count_k = jnp.take(count, idx)
             out = {}
@@ -257,7 +266,7 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
                         s.dtype)
                 else:
                     out[out_name] = merge_at(kind, arrays[out_name], idx)
-            return keys, ok, out, dropped, occ
+            return keys, ok, out, dropped, occ, select
         results = {}
         for kind, out_name in agg_sig:
             if kind == "count":
@@ -396,8 +405,8 @@ def _fire_inc_program(agg_sig: tuple, topk: Optional[int],
             else:
                 ranked = view[rk_name]
             with jax.named_scope("fire.topk"):
-                _vals, idx, ok = _masked_topk(ranked, emit, topk,
-                                              value_bits=topk_value_bits)
+                idx, ok, select = _select_topk(ranked, emit, topk,
+                                               topk_value_bits)
                 keys = jnp.take(table, idx)
                 count_k = jnp.take(count, idx)
             out = {}
@@ -412,7 +421,7 @@ def _fire_inc_program(agg_sig: tuple, topk: Optional[int],
                         s.dtype)
                 else:
                     out[out_name] = jnp.take(view[out_name], idx)
-            return keys, ok, out, dropped, occ
+            return keys, ok, out, dropped, occ, select
         results = {}
         for kind, out_name in agg_sig:
             if kind == "count":
@@ -1377,17 +1386,19 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
 
     def _materialize(self, item, turn: str) -> None:
         p_end, outs, host_part, t0, fire = item
-        with self._drain_stage(fire, turn):
-            keys, results, d2h_bytes = self._drain_rows(outs, host_part)
+        with self._drain_stage(fire, turn) as drain:
+            keys, results, d2h_bytes = self._drain_rows(outs, host_part,
+                                                        drain)
         if len(keys):
             with self._emit_stage(fire, len(keys)):
                 self._emit_rows(p_end, keys, results)
         self._note_latency(t0)
         self._close_fire(fire, len(keys), d2h_bytes)
 
-    def _drain_rows(self, outs, host_part):
+    def _drain_rows(self, outs, host_part, drain):
         """One fire's rows on the host: the ONE device_get + selection /
-        canonical order. Returns (keys, results, d2h bytes)."""
+        canonical order. Returns (keys, results, d2h bytes); a ranked
+        fire's select is noted on ``drain``, its window/Drain stage."""
         if self._guard is None or self._guard.active:
             # ONE deadline-bounded transfer for everything (device_get is
             # idempotent: a stall-abandoned read re-runs safely)
@@ -1400,8 +1411,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             host = jax.device_get(outs)   # degraded: host buffers, a view
         d2h_bytes = pytree_nbytes(host)
         if self._topk is not None:
-            keys_k, ok, results, dropped, occ = host
+            keys_k, ok, results, dropped, occ, select = host
             self._backend.apply_health(dropped, occ)
+            self._note_fire_select(drain, select)
             sel = np.asarray(ok)
             keys = np.asarray(keys_k)[sel]
             results = {n: np.asarray(v)[sel] for n, v in results.items()}

@@ -533,13 +533,13 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
 
     def _materialize(self, item: tuple, turn: str) -> None:
         p_end, outs, _unused, t0, fire = item
-        with self._drain_stage(fire, turn):
+        with self._drain_stage(fire, turn) as drain:
             host = jax.device_get(outs)   # ONE transfer for everything
             d2h_bytes = pytree_nbytes(host)
             if self._topk is not None:
                 keys_k, ok, results, dropped, occ, select = host
                 self._apply_health(dropped, occ)
-                DEVICE_STATS.note_fire_select(*select)
+                self._note_fire_select(drain, select)
                 sel = np.asarray(ok)
                 keys = np.asarray(keys_k)[sel]
                 res = {n: np.asarray(v)[sel] for n, v in results.items()}

@@ -163,6 +163,16 @@ class AsyncFireQueue:
                             seq=fire.attrs["seq"], turn=turn,
                             total=(self.stage_s, "drain"))
 
+    @staticmethod
+    def _note_fire_select(drain: Stage, select) -> None:
+        """A ranked fire's ``[passes, fell_back]``, as it came back in the
+        drain's one copy: counted, and an attribute of its window/Drain."""
+        from ...metrics.device import DEVICE_STATS
+
+        passes, fell_back = (int(x) for x in select)
+        DEVICE_STATS.note_fire_select(passes, bool(fell_back))
+        drain.set("select_passes", passes)
+
     def _emit_stage(self, fire: Stage, rows: int) -> Stage:
         """window/Emit: building the window's rows + output.emit."""
         return TRACER.stage("window", "Emit", parent=fire.context,

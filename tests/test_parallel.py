@@ -345,7 +345,8 @@ def test_global_topk_selects_by_threshold_on_every_shard(name, D):
     largest valid ones (ties at the k-th free), each index carries its
     value, and the passes handed back are the bit length of the largest
     valid rank: the select walks the bits the data has, not the 64 its
-    dtype declares. Only a float rank or a negative valid value takes the
+    dtype declares (a shard that holds a negative valid value walks all
+    64 of its sign-flipped view, PR 33). Only a float rank takes the
     sort, and DEVICE_STATS counts exactly those."""
     from flink_tpu.metrics import DEVICE_STATS
 
@@ -358,15 +359,14 @@ def test_global_topk_selects_by_threshold_on_every_shard(name, D):
     assert len(np.unique(idx[ok])) == ok.sum()
     np.testing.assert_array_equal(vals.reshape(-1)[idx[ok]], v[ok])
     assert valid.reshape(-1)[idx[ok]].all()
-    takes_sort = name in ("a_negative_valid_value", "a_float_rank")
+    takes_sort = name == "a_float_rank"
     assert bool(sort) == takes_sort
-    if name == "a_float_rank":
+    if takes_sort:
         assert passes == 0
     else:
-        # a shard that took the sort walked nothing; the others did
-        walked = [int(vals[d][valid[d]].max()).bit_length()
-                  for d in range(D)
-                  if valid[d].any() and vals[d][valid[d]].min() >= 0]
+        walked = [64 if vals[d][valid[d]].min() < 0
+                  else int(vals[d][valid[d]].max()).bit_length()
+                  for d in range(D) if valid[d].any()]
         assert passes == max(walked, default=0)
     before = DEVICE_STATS.snapshot()
     DEVICE_STATS.note_fire_select(passes, sort)
@@ -389,12 +389,14 @@ def test_global_topk_without_a_mesh_is_the_same_select():
 
 
 def test_a_count_rank_compiles_no_sort_over_a_shards_slots():
-    """The promise a COUNT keeps (never negative: value_bits under the
-    dtype's width) leaves the sort out of the program; without it the
-    guard and the sort are there, for the negative value that may come."""
+    """An integer rank compiles no sort over the slots, with the promise
+    a COUNT keeps (never negative: value_bits under the dtype's width) or
+    without it (the guard is the same walk over the sign-flipped view,
+    PR 33)."""
     import re
 
     D, cap, k = 4, 4096, 16
+
     args = (jnp.zeros((D, cap), jnp.int64), jnp.zeros((D, cap), bool))
 
     def widest_sort(value_bits):
@@ -404,7 +406,7 @@ def test_a_count_rank_compiles_no_sort_over_a_shards_slots():
             r"\[(\d+)\][^\n]* sort\(", hlo)), default=0)
 
     assert widest_sort(63) <= D * k
-    assert widest_sort(64) >= cap
+    assert widest_sort(64) <= D * k
 
 
 # -- what a v5e refuses to lower (found on the chip, PR 22) ------------------

@@ -187,9 +187,10 @@ def test_threshold_select_matches_oracle_and_counts_its_passes(
     sel = i[ok]
     assert valid[sel].all() and len(np.unique(sel)) == len(sel)
     np.testing.assert_array_equal(vals[sel], v[ok])
-    negative = name == "negative_valid"
-    assert bool(top.fell_back) == negative
-    assert int(top.passes) == (0 if negative else
+    # a negative valid value is answered by the same walk over the
+    # sign-flipped view, all 64 bits of it (PR 33): nothing falls back
+    assert not bool(top.fell_back)
+    assert int(top.passes) == (64 if name == "negative_valid" else
                                _bit_length_of_largest_valid(vals, valid))
 
 
@@ -213,13 +214,72 @@ def test_threshold_select_on_every_integer_width_and_promise(dtype,
 
 
 @pytest.mark.parametrize("dtype", [np.int32, np.int16])
-def test_a_narrow_signed_rank_with_a_negative_value_falls_back(dtype):
+def test_a_narrow_signed_rank_with_a_negative_value_walks_its_width(dtype):
     from flink_tpu.ops.topk import threshold_topk
 
     vals = np.array([5, -7, 3, 9, -1, 0], dtype)
     top = threshold_topk(jnp.asarray(vals), jnp.ones(6, bool), 4)
     np.testing.assert_array_equal(np.asarray(top.values), [9, 5, 3, 0])
-    assert bool(top.fell_back) and int(top.passes) == 0
+    assert not bool(top.fell_back)
+    assert int(top.passes) == 8 * np.dtype(dtype).itemsize
+
+
+@pytest.mark.parametrize("value_bits", [43, 64],
+                         ids=["promised_43_bits", "no_promise"])
+@pytest.mark.parametrize("top_price", [1 << 22, (1 << 22) - 1],
+                         ids=["43_bit_word", "42_bit_word"])
+def test_a_packed_word_takes_the_wide_view_and_walks_its_bit_length(
+        value_bits, top_price):
+    """NEXmark Q7's rank, `price << 20 | bidder` with prices in
+    [1, 2^22]: the words pass 2^32, so the walk runs on the 64-bit view
+    (on the 32-bit one the words that differ only above bit 31 would
+    tie), for as many passes as the largest word has bits: 43 where a bid
+    sits at 2^22, 42 where none does. With the promise or without it."""
+    from flink_tpu.ops.topk import threshold_topk
+
+    rng = np.random.default_rng(7)
+    n, k = 4096, 5
+    price = rng.integers(1, top_price, n)
+    price[17] = top_price
+    # the runners-up share their low 32 bits: only the wide view ranks them
+    price[100:104] = top_price - 1 - np.arange(4) * 4096
+    bidder = rng.integers(0, 1_000_000, n)
+    bidder[100:104] = 77
+    word = (price.astype(np.int64) << 20) | bidder
+    valid = rng.random(n) < 0.9
+    valid[[17, 100, 101, 102, 103]] = True
+    top = jax.jit(lambda v, m: threshold_topk(v, m, k, value_bits))(
+        jnp.asarray(word), jnp.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(top.values),
+                                  np.sort(word[valid])[::-1][:k])
+    assert int(top.passes) == int(word[valid].max()).bit_length() \
+        == (43 if top_price == 1 << 22 else 42)
+    assert not bool(top.fell_back)
+
+
+@pytest.mark.parametrize("k", [1, 7, 64])
+def test_a_negative_rank_without_a_promise_is_exact_at_every_k(k):
+    """MAX planes start at the dtype's minimum and a SUM may go below 0:
+    with no promise the select still answers exactly, by the same walk
+    over the sign-flipped view (the path that replaced the radix walk's
+    branch, which did not compile inside a fire over 2^24 slots)."""
+    from flink_tpu.ops.topk import threshold_topk
+
+    rng = np.random.default_rng(k)
+    vals = rng.integers(-(1 << 62), 1 << 62, 2048)
+    vals[:3] = [np.iinfo(np.int64).min, np.iinfo(np.int64).max, -1]
+    valid = rng.random(2048) < 0.7
+    valid[:3] = True
+    for v, m in ((vals, valid), (-np.abs(vals) - 1, valid),
+                 (vals, np.arange(2048) < min(k, 3))):
+        top = threshold_topk(jnp.asarray(v), jnp.asarray(m), k)
+        exp = np.sort(v[m])[::-1][:k]       # exact: no float64 in between
+        got, ok = np.asarray(top.values), np.asarray(top.ok)
+        assert ok[:len(exp)].all() and not ok[len(exp):].any()
+        np.testing.assert_array_equal(got[:len(exp)], exp)
+        np.testing.assert_array_equal(v[np.asarray(top.indices)[ok]],
+                                      got[ok])
+        assert int(top.passes) == 64 and not bool(top.fell_back)
 
 
 def test_a_float_rank_never_walks():

@@ -150,6 +150,76 @@ def test_mesh_fire_selects_on_the_shard_at_the_benchmark_shape(v5e_devices):
     assert "shard_map" in hlo and re.search(r" while\(", hlo)
 
 
+#: q7-10m-saturated's shapes: 2^24 slots, a ring of 8, batches of 2^18
+_Q7_CAP, _Q7_RING, _Q7_BATCH = 1 << 24, 8, 1 << 18
+
+
+def test_max_fold_compiles_at_the_benchmark_shape(v5e_devices):
+    """The fold of kind `max` as `TpuKeyedStateBackend.fold_batch` runs
+    it, `plane.reshape(-1).at[idx].max(vals)` over the flat int64
+    [8 x 2^24] plane: under x64 one scatter over the plane's 32-bit
+    halves, in the scope `fold.max` by which a trace finds it."""
+    from flink_tpu.ops.segment_ops import scatter_fold
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    n = _Q7_RING * _Q7_CAP
+    compiled = jax.jit(
+        lambda acc, idx, vals, valid: scatter_fold(
+            "max", acc, idx, vals, valid)).lower(
+        jax.ShapeDtypeStruct((n,), jnp.int64, sharding=one),
+        jax.ShapeDtypeStruct((_Q7_BATCH,), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((_Q7_BATCH,), jnp.int64, sharding=one),
+        jax.ShapeDtypeStruct((_Q7_BATCH,), jnp.bool_, sharding=one)
+    ).compile()
+    hlo = compiled.as_text()
+    assert " scatter(" in hlo
+    assert "fold.scatter/fold.max" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * n * 8
+
+
+@pytest.mark.parametrize("k", [1, 1000])
+@pytest.mark.parametrize("value_bits", [43, 64],
+                         ids=["promised_43_bits", "no_promise"])
+def test_max_ranked_fire_compiles_at_the_benchmark_shape(v5e_devices,
+                                                         value_bits, k):
+    """The one-chip fire of q7-10m-saturated (a `max` rank over a
+    [8, 2^24] int64 plane beside the int64 count plane, one pane row) with
+    the 43 bits the query module promises AND with the default 64, which
+    is what `AggSpec("max", "x")` gets. The no-promise cases fail at the
+    parent of PR 33: the guard was the radix walk as a third `lax.switch`
+    branch, and its 64-bit scans do not fit vmem inside the fire
+    (`RESOURCE_EXHAUSTED: Ran out of memory in memory space vmem ...
+    %reduce-window ... (u32[4,128], u32[4,128]) ... Scoped allocation
+    with size 19.10M and limit 16.00M`). Now the guard is the same walk
+    over the sign-flipped view: no scatter over the slots, no sort wider
+    than the winners, and the select's passes ride out as an int32
+    pair."""
+    import re
+
+    from flink_tpu.runtime.operators.device_window import _fire_program
+
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    plane = spec((_Q7_RING, _Q7_CAP), jnp.int64)
+    fire = _fire_program((("max", "best"),), k, value_bits)
+    compiled = getattr(fire, "_fn", fire).lower(
+        spec((_Q7_CAP,), jnp.int64), {"__count__": plane, "best": plane},
+        spec((1,), jnp.int32), spec((1,), jnp.bool_),
+        spec((), jnp.int64)).compile()
+    hlo = compiled.as_text()
+    assert "HloModule jit_fire_fn" in hlo     # the name the traces anchor on
+    assert not re.search(r" scatter\(", hlo)
+    assert max(_operand_elements(hlo, "sort"), default=0) <= k
+    assert re.search(r" while\(", hlo)         # the compare-and-count walk
+    select = jax.tree_util.tree_leaves(compiled.out_info)[-1]
+    assert select.shape == (2,) and select.dtype == jnp.int32
+    # beside 2.28 GB of state: the merged row, the views, the scans
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 #: the path by which the benchmark's probe_rounds_p50 finds the probe
 #: loop's claim in a device trace (benchmarks/layer_metrics/
 #: probe_rounds_p50.json), and the program it counts them under
