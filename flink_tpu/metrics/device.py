@@ -114,6 +114,13 @@ class DeviceStats:
         self._fire_selects = 0
         self._fire_select_passes = 0
         self._fire_select_sort = 0
+        # ring fold accounting (PR 34): host-born batches folded on one
+        # chip and the ring rows they held a row for, which are the rows
+        # of each plane the fold program slices, scatters into and
+        # writes back (ops/segment_ops.ring_fold); counted on the host
+        # from the batch's own ring indices
+        self._fold_batches = 0
+        self._fold_ring_rows = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -410,6 +417,17 @@ class DeviceStats:
             return (self._fire_selects, self._fire_select_passes,
                     self._fire_select_sort)
 
+    def note_fold(self, ring_rows: int) -> None:
+        with self._lock:
+            self._fold_batches += 1
+            self._fold_ring_rows += int(ring_rows)
+
+    @property
+    def fold_counts(self) -> tuple[int, int]:
+        """(host-born batches folded, ring rows they touched)."""
+        with self._lock:
+            return self._fold_batches, self._fold_ring_rows
+
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
             self._chain_dispatches += int(n)
@@ -675,6 +693,8 @@ class DeviceStats:
                 "fire_selects_total": self._fire_selects,
                 "fire_select_passes_total": self._fire_select_passes,
                 "fire_select_sort_total": self._fire_select_sort,
+                "fold_batches_total": self._fold_batches,
+                "fold_ring_rows_total": self._fold_ring_rows,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -783,6 +803,7 @@ class DeviceStats:
             self._mesh_steps = self._mesh_exchange_rounds = 0
             self._fire_selects = self._fire_select_passes = 0
             self._fire_select_sort = 0
+            self._fold_batches = self._fold_ring_rows = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -1214,6 +1235,11 @@ def bind_device_metrics(registry) -> None:
     g.gauge("fire_selects_total", lambda: s.fire_select_counts[0])
     g.gauge("fire_select_passes_total", lambda: s.fire_select_counts[1])
     g.gauge("fire_select_sort_total", lambda: s.fire_select_counts[2])
+    # ring fold of the one-chip host-born ingest (prometheus:
+    # flink_tpu_device_fold_batches_total /
+    # flink_tpu_device_fold_ring_rows_total)
+    g.gauge("fold_batches_total", lambda: s.fold_counts[0])
+    g.gauge("fold_ring_rows_total", lambda: s.fold_counts[1])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

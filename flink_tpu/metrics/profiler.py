@@ -632,6 +632,9 @@ LEDGER_SITE_INVENTORY: tuple = (
      "pressure, charged to the throttled job)"),
     ("sql.device_group_agg",
      "sql/device_group_agg.py — SQL grouped-aggregation program"),
+    ("state.fold",  # lint: key-ok ledger site, not a config key
+     "state/tpu_backend.py — ring-plane fold program (one batch into "
+     "every ring plane, ring row by ring row)"),
     ("state.reset_row",
      "state/tpu_backend.py — keyed-state row reset program"),
     ("transfer.d2h",

@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["scatter_fold", "pane_window_merge", "AGG_INITS", "AGG_FOLDS",
-           "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
+__all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
+           "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
            "make_accumulator", "segment_topk", "pow2_ceil",
            "merge_tree_build", "merge_tree_update", "merge_tree_root"]
 
@@ -105,6 +105,61 @@ def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
         vals = jnp.where(valid, values.astype(acc.dtype), identity)
         with jax.named_scope(f"fold.{kind}"):
             return AGG_FOLDS[kind](acc, idx, vals)
+
+
+#: rows of a batch that ``ring_fold`` scatters at a time
+_FOLD_CHUNK = 1 << 14
+
+
+def ring_fold(kind: str, plane: jax.Array, ring_idx: jax.Array,
+              slots: jax.Array, values: jax.Array,
+              valid: jax.Array) -> jax.Array:
+    """Fold a batch into a ``[ring, capacity]`` plane, ring row by ring
+    row: plane[ring_idx, slots] op= values, masked by ``valid``. No flat
+    view of the plane is taken: the TPU keeps a 2-D plane tiled, and
+    ``plane.reshape(-1)`` around a scatter copies all of it into a flat
+    buffer and back (three quarters of the one-chip ingest step until
+    PR 34). Each ring row the batch holds a valid row for is sliced out,
+    folded by ``scatter_fold`` as the 1-D accumulator it is, and written
+    back; the rows the batch does not touch are skipped on the device.
+    A scatter costs the TPU by the update, masked or not, so a touched
+    row takes the batch ``_FOLD_CHUNK`` rows at a time and skips the
+    chunks that hold nothing for it: a batch in event-time order pays
+    for each of its rows once, plus one chunk where it crosses a pane's
+    edge; a batch shuffled over k ring rows pays k times, which is why
+    the host-born operator sorts such a batch by ring row first
+    (``DeviceWindowAggOperator._fold``). Any number of touched rows is
+    right, 0 to ``ring``."""
+    n = slots.shape[0]
+    if n == 0:
+        return plane
+    chunk = min(_FOLD_CHUNK, n)
+    ring_idx = ring_idx.astype(jnp.int32)
+    lane = jnp.arange(chunk, dtype=jnp.int32)
+
+    def fold_row(r, plane):
+        mine = valid & (ring_idx == r)
+
+        def fold_chunk(c, row):
+            at = jnp.minimum(c * chunk, n - chunk)   # the last one backs up
+            hit = jax.lax.dynamic_slice(mine, (at,), (chunk,)) \
+                & (at + lane >= c * chunk)
+            return jax.lax.cond(
+                hit.any(),
+                lambda row: scatter_fold(
+                    kind, row,
+                    jax.lax.dynamic_slice(slots, (at,), (chunk,)),
+                    jax.lax.dynamic_slice(values, (at,), (chunk,)), hit),
+                lambda row: row, row)
+
+        def fold(plane):
+            row = jax.lax.dynamic_index_in_dim(plane, r, 0, keepdims=False)
+            row = jax.lax.fori_loop(0, -(-n // chunk), fold_chunk, row)
+            return jax.lax.dynamic_update_index_in_dim(plane, row, r, 0)
+
+        return jax.lax.cond(mine.any(), fold, lambda plane: plane, plane)
+
+    return jax.lax.fori_loop(0, plane.shape[0], fold_row, plane)
 
 
 def pane_window_merge(kind: str, acc: jax.Array,

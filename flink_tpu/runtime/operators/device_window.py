@@ -99,7 +99,7 @@ def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
     fused-chain lowering (runtime/compiled.py) composes it with the
     source decode under ONE jit instead, so the certified
     source→window prefix is a single XLA dispatch."""
-    from ...ops.segment_ops import scatter_fold
+    from ...ops.segment_ops import ring_fold
 
     spill = spill_maxp > 0
 
@@ -141,22 +141,15 @@ def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
         else:
             table, slots, ok = lookup_or_insert(table, keys, fresh)
             dropped = dropped + jnp.sum(~ok & fresh).astype(jnp.int64)
+        ring_idx = (panes % ring).astype(jnp.int32)
         count = arrays["__count__"]
-        cap = count.shape[1]
-        # int64 flat index once ring*capacity could overflow int32 (tables
-        # auto-grow by doubling; shapes are static so this is trace-time)
-        idt = jnp.int64 if ring * cap > (1 << 31) - 1 else jnp.int32
-        ring_idx = (panes % ring).astype(idt)
-        flat = ring_idx * cap + jnp.maximum(slots, 0).astype(idt)
         out = dict(arrays)
-        out["__count__"] = scatter_fold(
-            "count", count.reshape(-1), flat,
-            jnp.ones(keys.shape[0], count.dtype), ok).reshape(count.shape)
+        out["__count__"] = ring_fold(
+            "count", count, ring_idx, slots,
+            jnp.ones(keys.shape[0], count.dtype), ok)
         for kind, name, field in fold_sig:
-            arr = arrays[name]
-            vals = cols[field].astype(arr.dtype)
-            out[name] = scatter_fold(kind, arr.reshape(-1), flat, vals,
-                                     ok).reshape(arr.shape)
+            out[name] = ring_fold(kind, arrays[name], ring_idx, slots,
+                                  cols[field], ok)
         # incremental-snapshot capture: mark touched dirty blocks
         dirty = dirty.at[jnp.maximum(slots, 0) // dirty_block].set(True)
         # completion token: a fresh scalar buffer that is NEVER fed back
@@ -725,13 +718,23 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         return TRACER.stage("window", "Upload", seq=self._batch_seq,
                             total=(self.stage_s, "ingest"))
 
-    def _dispatch_stage(self, programs: Optional[int] = None):
+    def _dispatch_stage(self, **attrs):
         """window/IngestDispatch: the host's time to enqueue the batch's
-        ingest programs (the device runs them later)."""
-        attrs = {} if programs is None else {"programs": programs}
+        ingest programs (the device runs them later); ``programs`` and
+        ``ring_rows`` where the caller knows them."""
         return TRACER.stage("window", "IngestDispatch",
                             seq=self._batch_seq,
                             total=(self.stage_s, "ingest"), **attrs)
+
+    def _note_fold(self, ring_idx: np.ndarray) -> int:
+        """Ring rows a host-born batch holds a row for, which are the
+        rows of each plane its fold touches: counted (DEVICE_STATS
+        ``fold_ring_rows_total`` / ``fold_batches_total``) and returned
+        for the dispatch span's ``ring_rows``."""
+        rows = int(np.count_nonzero(
+            np.bincount(ring_idx, minlength=self._ring)))
+        DEVICE_STATS.note_fold(rows)
+        return rows
 
     def _to_device_batch(self, batch: RecordBatch) -> DeviceRecordBatch:
         ts = batch.timestamps
@@ -1121,41 +1124,41 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
 
     def _fold(self, batch: RecordBatch, keys: np.ndarray,
               panes: np.ndarray) -> None:
+        ring_idx = panes % self._ring
+        ring_rows = self._note_fold(ring_idx)
+        if ring_rows > 2:
+            # out-of-order input. The fold takes a touched ring row's
+            # updates a chunk of the batch at a time and skips the chunks
+            # that hold none (ops/segment_ops.ring_fold), so a batch
+            # shuffled over k ring rows would pay k whole batches; sorted
+            # by ring row it pays one. Stable, so the updates of any one
+            # cell keep their order.
+            order = np.argsort(ring_idx, kind="stable")
+            batch, keys, ring_idx = (batch.take(order), keys[order],
+                                     ring_idx[order])
         if self._defer:
             # pipelined path: host<->device calls have a fixed cost, so
             # the whole batch rides ONE upload and nothing syncs back
-            self._fold_packed(batch, keys, panes % self._ring)
+            self._fold_packed(batch, keys, ring_idx, ring_rows)
             return
-        ring_idx = panes % self._ring
-        with self._dispatch_stage():
+        with self._dispatch_stage(ring_rows=ring_rows):
             slots = self._backend.slots_for_batch(keys)
-            valid = slots >= 0
-            self._backend.fold_batch("__count__", slots,
-                                     np.ones(batch.n, np.int64), valid,
-                                     ring_idx=ring_idx)
-            for a in self._aggs:
-                if a.kind == "count":
-                    continue
-                col = batch.column(a.field)
-                name = (f"{a.out_name}.sum" if a.kind == "avg"
-                        else a.out_name)
-                self._backend.fold_batch(name, slots, col, valid,
-                                         ring_idx=ring_idx)
+            values = {"__count__": None}
+            for _kind, name, field in self._fold_sig():
+                values[name] = batch.column(field)
+            self._backend.fold_rings(slots, ring_idx, slots >= 0, values)
 
     def _fold_packed(self, batch: RecordBatch, keys: np.ndarray,
-                     ring_idx: np.ndarray) -> None:
+                     ring_idx: np.ndarray, ring_rows: int) -> None:
         """Pack keys + ring rows + every aggregate column into one [C, B]
         int64 buffer (floats bit-cast via float64), upload once, slice on
-        device. Zero host round-trips per batch."""
+        device. Zero host round-trips per batch: the probe, then ONE fold
+        program over all of the job's planes."""
         with self._upload_stage() as up:
             rows = [keys, ring_idx]
             col_meta: list[tuple[str, bool]] = []
-            for a in self._aggs:
-                if a.kind == "count":
-                    continue
-                col = np.asarray(batch.column(a.field))
-                name = (f"{a.out_name}.sum" if a.kind == "avg"
-                        else a.out_name)
+            for _kind, name, field in self._fold_sig():
+                col = np.asarray(batch.column(field))
                 if np.issubdtype(col.dtype, np.floating):
                     rows.append(np.ascontiguousarray(
                         col.astype(np.float64)).view(np.int64))
@@ -1169,19 +1172,15 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                                 lambda: jnp.asarray(packed),  # ONE upload
                                 scope="device_window")
             DEVICE_STATS.note_h2d(buf.nbytes, batch.n)
-        with self._dispatch_stage(programs=2 + len(col_meta)):
+        with self._dispatch_stage(programs=2, ring_rows=ring_rows):
             slots = self._backend.slots_for_batch_device(buf[0])
-            dring = buf[1]
-            valid = slots >= 0
-            self._backend.fold_batch("__count__", slots,
-                                     jnp.ones(batch.n, jnp.int64), valid,
-                                     ring_idx=dring)
+            values = {"__count__": None}
             for i, (name, is_float) in enumerate(col_meta):
                 vals = buf[2 + i]
                 if is_float:
                     vals = jax.lax.bitcast_convert_type(vals, jnp.float64)
-                self._backend.fold_batch(name, slots, vals, valid,
-                                         ring_idx=dring)
+                values[name] = vals
+            self._backend.fold_rings(slots, buf[1], slots >= 0, values)
 
     # -- firing (fire loop lives in SliceControlPlane) ----------------------
     # A fire is ONE compiled program (pane merge for every aggregate +

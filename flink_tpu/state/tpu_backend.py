@@ -38,7 +38,8 @@ from ..metrics.device import DEVICE_STATS, instrumented_program_cache
 from ..ops.hash_table import (
     EMPTY_KEY, lookup, lookup_or_insert, make_table, sanitize_keys_device,
 )
-from ..ops.segment_ops import AGG_INITS, make_accumulator, scatter_fold
+from ..ops.segment_ops import AGG_INITS, make_accumulator, ring_fold, \
+    scatter_fold
 from .backend import KeyedStateBackend, State, ValueState, register_backend
 from .descriptors import StateDescriptor
 from .spill import HostTier
@@ -100,6 +101,27 @@ def _reset_row_program(sig: tuple):
         return tuple(out)
 
     return reset
+
+
+@instrumented_program_cache("state.fold")
+def _fold_program(sig: tuple):
+    """One jitted fold per ring-plane signature: a batch into EVERY ring
+    plane of a job in a single dispatch, ring row by ring row
+    (``ops/segment_ops.ring_fold``). ``sig`` = tuple of (kind, dtype_str,
+    shape), as ``_reset_row_program``'s; ``cols`` holds one value column
+    a plane, or None where every row counts one. The planes are donated:
+    the arrays passed in are deleted buffers afterwards, and only the
+    ones returned are live."""
+
+    @partial(jax.jit, donate_argnums=(0,))
+    def fold(arrays: tuple, slots, ring_idx, valid, cols: tuple):
+        return tuple(
+            ring_fold(kind, a, ring_idx, slots,
+                      jnp.ones(slots.shape, a.dtype) if c is None else c,
+                      valid)
+            for (kind, _dt, _shape), a, c in zip(sig, arrays, cols))
+
+    return fold
 
 
 @jax.jit
@@ -184,6 +206,13 @@ class _ArrayState:
         self.role = role
         shape = (ring, capacity) if ring else (capacity,)
         self.array = make_accumulator(kind, shape, dtype)
+
+
+def _plane_sig(states) -> tuple:
+    """What the plane programs (reset, fold) are cached by: (kind,
+    dtype_str, shape) of each plane they take."""
+    return tuple((st.kind, str(st.array.dtype), st.array.shape)
+                 for st in states)
 
 
 class TpuKeyedStateBackend(KeyedStateBackend):
@@ -852,33 +881,46 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         """acc[(ring_idx,) slot] op= values — one scatter per aggregate.
         ``values``/``ring_idx`` may be numpy (preferred when a spill tier
         is configured: the host-side rows of the batch fold into the host
-        mirror without a device round-trip)."""
+        mirror without a device round-trip). A ring plane goes through
+        ``fold_rings``, as a job's planes do together."""
         st = self._array_states[name]
-        dvals = values if isinstance(values, jax.Array) else \
-            jnp.asarray(values)
         if st.ring:
-            dring = (ring_idx if isinstance(ring_idx, jax.Array)
-                     else jnp.asarray(ring_idx))
-            cap = st.array.shape[1]
-            idt = (jnp.int64 if st.ring * cap > (1 << 31) - 1
-                   else jnp.int32)
-            flat = dring.astype(idt) * cap + slots.astype(idt)
-            folded = scatter_fold(st.kind, st.array.reshape(-1), flat,
-                                  dvals, valid)
-            st.array = folded.reshape(st.array.shape)
-        else:
-            st.array = scatter_fold(st.kind, st.array, slots, dvals, valid)
-        if self._pending_host is not None:
-            pos, hslots = self._pending_host
-            vals_np = (np.asarray(jax.device_get(values))
-                       if isinstance(values, jax.Array)
-                       else np.asarray(values))
-            ring_np = None
-            if st.ring is not None and ring_idx is not None:
-                ring_np = (np.asarray(jax.device_get(ring_idx))
-                           if isinstance(ring_idx, jax.Array)
-                           else np.asarray(ring_idx))[pos]
-            self._host.fold(name, hslots, vals_np[pos], ring_np)
+            self.fold_rings(slots, ring_idx, valid, {name: values})
+            return
+        st.array = scatter_fold(st.kind, st.array, slots,
+                                jnp.asarray(values), valid)
+        self._fold_host(name, values, None)
+
+    def fold_rings(self, slots: jax.Array, ring_idx, valid: jax.Array,
+                   values: dict) -> None:
+        """acc[ring_idx, slot] op= values for every ring plane named in
+        ``values`` (state name -> value column, numpy or device; None
+        counts one a row), in ONE donated program (``_fold_program``):
+        arrays taken from ``get_array`` before this call are deleted
+        buffers after it."""
+        states = [self._array_states[n] for n in values]
+        cols = tuple(None if v is None else jnp.asarray(v)
+                     for v in values.values())
+        outs = _fold_program(_plane_sig(states))(
+            tuple(st.array for st in states), slots, jnp.asarray(ring_idx),
+            valid, cols)
+        for st, arr in zip(states, outs):
+            st.array = arr
+        for name, vals in values.items():
+            self._fold_host(name, vals, ring_idx)
+
+    def _fold_host(self, name: str, values, ring_idx) -> None:
+        """The spill tier's leg of a fold: the batch's rows of spilled
+        key groups (``slots_for_batch`` left their positions) fold into
+        the host tier's plane ``name``."""
+        if self._pending_host is None:
+            return
+        pos, hslots = self._pending_host
+        vals = (np.ones(len(pos), np.int64) if values is None
+                else np.asarray(jax.device_get(values))[pos])
+        ring = (None if ring_idx is None
+                else np.asarray(jax.device_get(ring_idx))[pos])
+        self._host.fold(name, hslots, vals, ring)
 
     def reset_ring_row(self, row: int) -> None:
         """Zero one ring row of every ring-shaped array state back to its
@@ -892,9 +934,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         ring_states = [st for st in self._array_states.values()
                        if st.ring and st.role != "window"]
         if ring_states:
-            sig = tuple((st.kind, str(st.array.dtype), st.array.shape)
-                        for st in ring_states)
-            outs = _reset_row_program(sig)(
+            outs = _reset_row_program(_plane_sig(ring_states))(
                 tuple(st.array for st in ring_states), np.int32(row))
             for st, arr in zip(ring_states, outs):
                 st.array = arr

@@ -59,37 +59,43 @@ def test_baseline_entries_carry_reviewed_reasons():
             f"baseline entry without a reviewed reason: {e}")
 
 
+def _lint_in_a_fresh_process(*args):
+    """`python -m flink_tpu.cli lint` in a process of its own. The
+    program audit is process-wide, so in this one it also holds whatever
+    the test files that ran earlier on this worker built (two mesh
+    aggregates under different builder keys, a float64 job), and which
+    files those are changes with the suite's order; the gate is about
+    the programs of the tiny Q5 the lint itself exercises."""
+    import os
+    import subprocess
+    import sys
+
+    return subprocess.run(
+        [sys.executable, "-m", "flink_tpu.cli", "lint", *args],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        capture_output=True, text=True, timeout=600)
+
+
 def test_tier_b_clean_on_tiny_q5():
     """Exercise a tiny Q5-shaped pipeline and audit every compiled
     program it registered: scatter on the fire path, f64 leaks, missing
     donation, and value-derived cache keys must all be absent (or
     baselined)."""
-    jax = pytest.importorskip("jax")
-    del jax
-    from flink_tpu.metrics.device import PROGRAM_AUDIT
-    from flink_tpu.analysis.jaxpr_rules import exercise_programs
-    # an earlier pipeline test may have part-populated the audit (window
-    # programs only); every Tier-B rule needs its scope present or it
-    # skips, so exercise whenever the mesh/chain sentinels are missing
-    scopes = {e.scope for e in PROGRAM_AUDIT}
-    if (not {"chain.fused_prelude", "chain.fused_step"} <= scopes
-            or not any(s.startswith("mesh.") for s in scopes)):
-        exercise_programs()
-    skipped: list = []
-    findings = run_rules(AnalysisContext(), TIER_B, skipped)
-    assert not skipped, f"tier-B rules skipped: {skipped}"
-    new, _stale = diff_against_baseline(findings)
-    assert not new, f"unbaselined program findings:\n{_fmt(new)}"
+    pytest.importorskip("jax")
+    done = _lint_in_a_fresh_process("--rules", ",".join(TIER_B), "--json")
+    report = json.loads(done.stdout[done.stdout.index("{"):])
+    assert not report["skipped"], f"tier-B rules skipped: {report['skipped']}"
+    new = [f for f in report["findings"]
+           if f["fingerprint"] in report["new"]]
+    assert not new, f"unbaselined program findings:\n{new}"
 
 
-def test_cli_lint_exits_zero_on_committed_tree(capsys):
+def test_cli_lint_exits_zero_on_committed_tree():
     """Acceptance: `python -m flink_tpu.cli lint` (all rules) exits 0."""
     pytest.importorskip("jax")
-    from flink_tpu.cli import main
-    rc = main(["lint"])
-    out = capsys.readouterr().out
-    assert rc == 0, f"cli lint failed:\n{out}"
-    assert "0 new" in out
+    done = _lint_in_a_fresh_process()
+    assert done.returncode == 0, f"cli lint failed:\n{done.stdout}"
+    assert "0 new" in done.stdout
 
 
 def test_cli_lint_unknown_rule_is_usage_error(capsys):

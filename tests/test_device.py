@@ -2,20 +2,35 @@
 operator parity with the host WindowOperator (runs on the virtual CPU
 platform; same code path compiles for TPU)."""
 
+import glob
+import os
+import time
+
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from flink_tpu.core import KeyGroupRange, Schema  # noqa: E402
+from flink_tpu.api import StreamExecutionEnvironment  # noqa: E402
+from flink_tpu.core import (  # noqa: E402
+    KeyGroupRange, Schema, WatermarkStrategy,
+)
+from flink_tpu.core.config import (  # noqa: E402
+    CheckpointingOptions, PipelineOptions, RuntimeOptions,
+)
+from flink_tpu.core.functions import MapFunction, SinkFunction  # noqa: E402
+from flink_tpu.metrics import DEVICE_STATS  # noqa: E402
 from flink_tpu.ops.hash_table import (  # noqa: E402
-    EMPTY_KEY, lookup, lookup_or_insert, make_table,
+    EMPTY_KEY, ensure_x64, lookup, lookup_or_insert, make_table,
 )
 from flink_tpu.ops.segment_ops import (  # noqa: E402
-    make_accumulator, pane_window_merge, scatter_fold, segment_topk,
+    AGG_FOLDS, make_accumulator, pane_window_merge, ring_fold, scatter_fold,
+    segment_topk,
 )
+from flink_tpu.runtime.operators.device_window import AggSpec  # noqa: E402
 from flink_tpu.state.tpu_backend import TpuKeyedStateBackend  # noqa: E402
+from flink_tpu.window import SlidingEventTimeWindows  # noqa: E402
 
 
 class TestHashTable:
@@ -76,6 +91,66 @@ class TestSegmentOps:
             "min", accm, jnp.array([0, 0], jnp.int32),
             jnp.array([7, 3], jnp.int64), jnp.array([True, True])))
         assert out[0] == 3
+
+    _RING, _CAP, _ROWS = 4, 32, 100
+
+    @pytest.mark.parametrize("masked", [False, True],
+                             ids=["all_valid", "invalid_and_padding"])
+    @pytest.mark.parametrize("touched", [0, 1, 2, 4],
+                             ids=["no_row", "one_row", "two_rows",
+                                  "every_row"])
+    @pytest.mark.parametrize("dtype", [jnp.int32, jnp.int64, jnp.float32],
+                             ids=["int32", "int64", "float32"])
+    @pytest.mark.parametrize("kind", ["count", "sum", "min", "max"])
+    def test_ring_fold_is_the_flat_fold(self, kind, dtype, touched, masked,
+                                        monkeypatch):
+        """`ring_fold` over a [ring, capacity] plane against the flat
+        scatter it replaces, `plane.reshape(-1).at[ring_idx * cap +
+        slot].<op>(v)`, bit for bit, over two consecutive batches: for
+        0, 1, 2 and all ring rows touched, with every row valid and with
+        invalid rows (a failed insert: a live slot, `valid` false) and
+        padding rows (slot -1) among them. A ring row's run of the batch
+        is folded a chunk at a time: here 32 updates, so that a run takes
+        several chunks and the last one backs up."""
+        ensure_x64()   # the regime every job runs in
+        monkeypatch.setattr("flink_tpu.ops.segment_ops._FOLD_CHUNK", 32)
+        ring, cap, n = self._RING, self._CAP, self._ROWS
+        rng = np.random.default_rng(
+            [touched, masked, ["count", "sum", "min", "max"].index(kind)])
+        fold = jax.jit(lambda *batch: ring_fold(kind, *batch))
+        plane = flat = make_accumulator(kind, (ring, cap), dtype)
+        for _batch in range(2):
+            first = int(rng.integers(ring))
+            ring_idx = (first + rng.integers(max(touched, 1), size=n)) % ring
+            slots = rng.integers(cap, size=n).astype(np.int32)
+            values = rng.integers(-50, 50, size=n).astype(np.dtype(dtype))
+            valid = np.full(n, touched > 0)
+            if masked:
+                valid &= rng.random(n) < 0.7
+                slots[-8:], valid[-8:] = -1, False
+            if touched:    # the batch does hold a row for each of them
+                ring_idx[:touched] = (first + np.arange(touched)) % ring
+                valid[:touched], slots[:touched] = True, 0
+            plane = fold(plane, jnp.asarray(ring_idx),
+                         jnp.asarray(slots), jnp.asarray(values),
+                         jnp.asarray(valid))
+            idx = np.where(valid, ring_idx * cap + slots, ring * cap)
+            flat = AGG_FOLDS[kind](
+                flat.reshape(-1), jnp.asarray(idx),
+                jnp.asarray(values)).reshape(ring, cap)
+            assert plane.dtype == flat.dtype and plane.shape == flat.shape
+            assert np.asarray(plane).tobytes() == np.asarray(flat).tobytes()
+        untouched = np.asarray(plane) == np.asarray(
+            make_accumulator(kind, (ring, cap), dtype))
+        assert untouched.all() == (touched == 0)
+
+    def test_ring_fold_of_an_empty_batch_is_the_plane(self):
+        ensure_x64()
+        plane = make_accumulator("sum", (self._RING, self._CAP), jnp.int64)
+        none = jnp.zeros(0, jnp.int32)
+        out = ring_fold("sum", plane, none, none, jnp.zeros(0, jnp.int64),
+                        jnp.zeros(0, bool))
+        assert np.asarray(out).tobytes() == np.asarray(plane).tobytes()
 
     def test_pane_window_merge(self):
         acc = jnp.asarray(np.arange(12, dtype=np.float32).reshape(3, 4))
@@ -334,3 +409,165 @@ class TestDeviceWindowRegressions:
             op, schema=Schema([("k", np.float64), ("v", np.int64)]))
         with pytest.raises(TypeError, match="integer key column"):
             h.process_elements([(2.3, 1)], [10])
+
+
+# ---------------------------------------------------------------------------
+# The one-chip host-born fold through `env.execute()`: one donated program
+# folds a batch into every ring plane, ring row by ring row
+# (`ops/segment_ops.ring_fold`, `TpuKeyedStateBackend.fold_rings`), and
+# skips the rows a batch does not touch. The two jobs in which a skipped
+# ring row or a plane reference held across a fold would show: batches
+# whose event time is shuffled over more ring rows than two, and a
+# checkpoint taken between two batches and restored. Both against a
+# per-record reference.
+# ---------------------------------------------------------------------------
+
+FOLD_SCHEMA = Schema([("k", np.int64), ("v", np.int64), ("ts", np.int64)])
+N, KEYS, PANE, PANES, WINDOW = 1 << 13, 61, 1000, 16, 4
+#: the whole stream is 16 + 4 panes and a window 4: no watermark cadence,
+#: however slow, lets two open panes share a ring row
+RING = 32
+#: how far a record's event time is shuffled ahead of its place in the
+#: stream, and the out-of-orderness the watermark allows: four panes
+JITTER = 4 * PANE
+
+#: Q5's shape (a count that fits 32 bits beside an int64 sum) and Q7's (an
+#: int64 max over the hidden int64 count)
+AGGS = {
+    "count32_sum64": [AggSpec("count", out_name="n", value_bits=31),
+                      AggSpec("sum", "v", out_name="total")],
+    "max64": [AggSpec("max", "v", out_name="best")],
+}
+
+
+def _shuffled_gen(idx):
+    u = idx.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+    return {"k": ((u >> np.uint64(7)) % np.uint64(KEYS)).astype(np.int64),
+            "v": ((u >> np.uint64(23)) % np.uint64(1 << 40)).astype(
+                np.int64) + 1,
+            "ts": (idx * PANES * PANE) // N
+            + ((u >> np.uint64(13)) % np.uint64(JITTER)).astype(np.int64)}
+
+
+def _fold_reference(names):
+    """Per record: every window of HOP 4 s / 1 s that holds it."""
+    cols = _shuffled_gen(np.arange(N))
+    want = {}
+    for k, v, ts in zip(*(cols[c].tolist() for c in ("k", "v", "ts"))):
+        first_end = (ts // PANE + 1) * PANE
+        for end in range(first_end, first_end + WINDOW * PANE, PANE):
+            n, total, best = want.get((k, end), (0, 0, 0))
+            want[(k, end)] = (n + 1, total + v, max(best, v))
+    pick = [("n", "total", "best").index(c) for c in names]
+    return {key: tuple(row[i] for i in pick) for key, row in want.items()}
+
+
+class _Rows(SinkFunction):
+    """Collects (key, window_end) -> aggregates; a replayed window has to
+    repeat what it said."""
+
+    def __init__(self, names, crash_once_checkpointed_in=None):
+        self.names = names
+        self.got = {}
+        self.batches = 0
+        self._dir = crash_once_checkpointed_in
+        self.crashed = False
+
+    def invoke_batch(self, batch):
+        cols = [batch.column(c).tolist()
+                for c in ("k", "window_end", *self.names)]
+        for k, end, *row in zip(*cols):
+            assert self.got.setdefault((k, end), tuple(row)) == tuple(row)
+        self.batches += 1
+        if self._dir is not None and not self.crashed:
+            # leave the checkpoint coordinator its turns, and fail once a
+            # checkpoint is complete: the restart restores it
+            time.sleep(0.01)
+            if self.batches >= 3 and glob.glob(
+                    os.path.join(self._dir, "chk-*", "_metadata")):
+                self.crashed = True
+                raise RuntimeError("injected sink failure")
+        return True
+
+
+class _Pace(MapFunction):
+    """Holds every batch back a little, so that a run of sixteen batches
+    lasts long enough for checkpoints to fall between them."""
+
+    def map_batch(self, batch):
+        time.sleep(0.03)
+        return batch
+
+
+def _fold_job(env, aggs, defer, sink, paced=False):
+    env.set_state_backend("tpu")
+    env.config.set(PipelineOptions.BATCH_SIZE, 512)
+    ws = WatermarkStrategy.for_bounded_out_of_orderness(JITTER) \
+        .with_timestamp_column("ts")
+    stream = env.datagen(_shuffled_gen, FOLD_SCHEMA, count=N, timestamp_column="ts",
+                         watermark_strategy=ws)
+    if paced:
+        stream = stream.map(_Pace(), name="Pace", out_schema=FOLD_SCHEMA)
+    (stream.key_by("k")
+        .window(SlidingEventTimeWindows.of(WINDOW * PANE, PANE))
+        .device_aggregate(aggs, capacity=1 << 10, ring_size=RING,
+                          defer_overflow=defer, async_fire=defer)
+        .add_sink(sink, "rows"))
+
+
+@pytest.mark.parametrize("defer", [True, False],
+                         ids=["deferred", "synchronous"])
+@pytest.mark.parametrize("aggs", list(AGGS))
+def test_batches_shuffled_over_many_ring_rows(aggs, defer, monkeypatch):
+    """A batch of 512 rows is one pane of the stream shuffled over the
+    four panes ahead of it, so its fold touches five ring rows, not one
+    or two; the fold's own counter says so. The fold pays a batch per
+    ring row whose updates lie scattered over it, so the operator hands
+    such a batch over sorted by ring row."""
+    names = [a.out_name for a in AGGS[aggs]]
+    sink = _Rows(names)
+    env = StreamExecutionEnvironment.get_execution_environment()
+    _fold_job(env, AGGS[aggs], defer, sink)
+    handed_over = []
+    fold_rings = TpuKeyedStateBackend.fold_rings
+
+    def spy(self, slots, ring_idx, valid, values):
+        handed_over.append(np.asarray(ring_idx))
+        return fold_rings(self, slots, ring_idx, valid, values)
+
+    monkeypatch.setattr(TpuKeyedStateBackend, "fold_rings", spy)
+    before = DEVICE_STATS.snapshot()
+    env.execute(f"shuffled-{aggs}", timeout=300.0)
+    after = DEVICE_STATS.snapshot()
+    assert sink.got == _fold_reference(names)
+    assert len(handed_over) == N // 512
+    assert all((np.diff(r) >= 0).all() for r in handed_over
+               if len(np.unique(r)) > 2)
+    batches = after["fold_batches_total"] - before["fold_batches_total"]
+    rows = after["fold_ring_rows_total"] - before["fold_ring_rows_total"]
+    assert batches == N // 512
+    assert rows > 4 * batches
+
+
+@pytest.mark.parametrize("defer", [True, False],
+                         ids=["deferred", "synchronous"])
+def test_checkpoint_between_two_batches_restores_the_planes(tmp_path,
+                                                            defer):
+    """The sink fails once a checkpoint is complete; the job restarts
+    from it and every window, replayed or new, is the reference's."""
+    aggs = AGGS["count32_sum64"] + AGGS["max64"]
+    names = [a.out_name for a in aggs]
+    sink = _Rows(names, crash_once_checkpointed_in=str(tmp_path))
+    env = StreamExecutionEnvironment()
+    env.config.set(CheckpointingOptions.DIRECTORY, str(tmp_path))
+    env.config.set(CheckpointingOptions.INTERVAL, 0.02)
+    env.config.set(RuntimeOptions.RESTART_STRATEGY, "fixed-delay")
+    env.config.set(RuntimeOptions.RESTART_ATTEMPTS, 3)
+    env.config.set(RuntimeOptions.RESTART_DELAY, 0.02)
+    _fold_job(env, aggs, defer, sink, paced=True)
+    job = env.execute("checkpointed", timeout=300.0, recover=True)
+    assert sink.crashed
+    restart, = (h for h in job.supervisor.failure_history
+                if h["kind"] == "restart")
+    assert restart["restored_checkpoint"] is not None
+    assert sink.got == _fold_reference(names)

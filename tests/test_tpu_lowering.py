@@ -155,14 +155,15 @@ _Q7_CAP, _Q7_RING, _Q7_BATCH = 1 << 24, 8, 1 << 18
 
 
 def test_max_fold_compiles_at_the_benchmark_shape(v5e_devices):
-    """The fold of kind `max` as `TpuKeyedStateBackend.fold_batch` runs
-    it, `plane.reshape(-1).at[idx].max(vals)` over the flat int64
-    [8 x 2^24] plane: under x64 one scatter over the plane's 32-bit
-    halves, in the scope `fold.max` by which a trace finds it."""
+    """The fold of kind `max` as `ring_fold` hands it to `scatter_fold`:
+    `row.at[slots].max(vals)` over ONE int64 ring row of 2^24 slots (the
+    plane is [8, 2^24]; a batch touches one or two of its rows): under
+    x64 one scatter over the row's 32-bit halves, in the scope
+    `fold.max` by which a trace finds it."""
     from flink_tpu.ops.segment_ops import scatter_fold
 
     one = SingleDeviceSharding(v5e_devices[0])
-    n = _Q7_RING * _Q7_CAP
+    n = _Q7_CAP
     compiled = jax.jit(
         lambda acc, idx, vals, valid: scatter_fold(
             "max", acc, idx, vals, valid)).lower(
@@ -175,6 +176,69 @@ def test_max_fold_compiles_at_the_benchmark_shape(v5e_devices):
     assert " scatter(" in hlo
     assert "fold.scatter/fold.max" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * n * 8
+
+
+#: the ring planes of the two one-chip configurations as the backend
+#: signs them (kind, dtype, shape), 2^24 slots each: Q5's int32 count
+#: beside its int64 sum on a ring of 16, Q7's hidden int64 count beside
+#: its int64 max on a ring of 8
+_FOLD_SIGS = {
+    "q5": (("count", "int32", (16, 1 << 24)), ("sum", "int64", (16, 1 << 24))),
+    "q7": (("count", "int64", (8, 1 << 24)), ("max", "int64", (8, 1 << 24))),
+}
+
+
+@pytest.mark.parametrize("query", list(_FOLD_SIGS))
+def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
+        v5e_devices, query):
+    """The backend's fold program (one a batch, every ring plane of the
+    job donated) at 2^24 slots and 2^18 rows: no `copy` of a whole plane
+    and no loop over one but the fold's own walk over the ring rows. `plane.reshape(-1)` around the scatter was
+    both: a `[ring, 2^24]` plane is tiled `T(8,128)` and its flat view
+    `T(1024)`, so XLA copied all of it out and back in a `while` of
+    `dynamic-update-slice`s (232 of the step's 279 ms until PR 34). What
+    is left beside the planes is the 32-bit halves the int64 ones are
+    split into, and a ring row of each."""
+    import re
+
+    from flink_tpu.state.tpu_backend import _fold_program
+
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    sig = _FOLD_SIGS[query]
+    rows = 1 << 18
+    fold = _fold_program(sig)
+    compiled = getattr(fold, "_fn", fold).lower(
+        tuple(spec(shape, dt) for _k, dt, shape in sig),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int64),
+        spec((rows,), jnp.bool_),
+        (None, spec((rows,), jnp.int64))).compile()
+    hlo = compiled.as_text()
+    assert "HloModule jit_fold" in hlo
+    ring, cap = sig[0][2]
+    # a whole plane, tiled or flat; the one loop that may carry it is the
+    # fold's own walk over the ring, which updates the plane in place
+    whole = re.compile(rf"\[(1,)?{ring},{cap}\]|\[{ring * cap}\]")
+    ring_walks = 0
+    for line in hlo.splitlines():
+        if re.search(r" (copy|while)\(", line) and whole.search(line):
+            assert " while(" in line and f"[{ring * cap}]" not in line \
+                and f"[1,{ring},{cap}]" not in line \
+                and 'op_name="jit(fold)/while"' in line, line[:300]
+            ring_walks += 1
+    assert ring_walks <= len(sig)
+    assert "fold.scatter/fold.count" in hlo
+    assert f"fold.scatter/fold.{sig[1][0]}" in hlo
+    mem = compiled.memory_analysis()
+    planes = sum(np.dtype(dt).itemsize * ring * cap for _k, dt, _s in sig)
+    assert mem.alias_size_in_bytes >= planes     # every plane is donated
+    # beside the planes: the int64 ones' 32-bit halves, and the ring row
+    # of each plane that is out being folded
+    wide = sum(8 * ring * cap for _k, dt, _s in sig if dt == "int64")
+    assert mem.temp_size_in_bytes < 1.01 * (wide + planes // ring)
 
 
 @pytest.mark.parametrize("k", [1, 1000])
