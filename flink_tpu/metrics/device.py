@@ -121,6 +121,13 @@ class DeviceStats:
         # from the batch's own ring indices
         self._fold_batches = 0
         self._fold_ring_rows = 0
+        # state reclaim accounting (PR 35): sweeps of the one-chip
+        # backend's reclaim (state/tpu_backend.py reclaim: the table
+        # rebuilt at its own capacity from the keys that still hold data
+        # in a ring row) and the keys they kept and freed
+        self._reclaim_sweeps = 0
+        self._reclaim_kept = 0
+        self._reclaim_freed = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -428,6 +435,19 @@ class DeviceStats:
         with self._lock:
             return self._fold_batches, self._fold_ring_rows
 
+    def note_reclaim(self, kept: int, freed: int) -> None:
+        with self._lock:
+            self._reclaim_sweeps += 1
+            self._reclaim_kept += int(kept)
+            self._reclaim_freed += int(freed)
+
+    @property
+    def reclaim_counts(self) -> tuple[int, int, int]:
+        """(reclaim sweeps, keys they kept, keys they freed)."""
+        with self._lock:
+            return (self._reclaim_sweeps, self._reclaim_kept,
+                    self._reclaim_freed)
+
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
             self._chain_dispatches += int(n)
@@ -695,6 +715,9 @@ class DeviceStats:
                 "fire_select_sort_total": self._fire_select_sort,
                 "fold_batches_total": self._fold_batches,
                 "fold_ring_rows_total": self._fold_ring_rows,
+                "state_reclaim_sweeps_total": self._reclaim_sweeps,
+                "state_reclaim_keys_kept_total": self._reclaim_kept,
+                "state_reclaim_keys_freed_total": self._reclaim_freed,
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -804,6 +827,8 @@ class DeviceStats:
             self._fire_selects = self._fire_select_passes = 0
             self._fire_select_sort = 0
             self._fold_batches = self._fold_ring_rows = 0
+            self._reclaim_sweeps = 0
+            self._reclaim_kept = self._reclaim_freed = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -913,10 +938,17 @@ class _TimedProgram:
     explicitly-compiled executable per call signature: a warm-loaded one
     (no compile at all) or a live ``lower().compile()`` whose result is
     persisted for the next cold process. Any failure on that path falls
-    back to the plain jit call — the cache never fails a dispatch."""
+    back to the plain jit call — the cache never fails a dispatch.
+
+    ``prepare`` compiles the program for its arguments' shapes AHEAD of
+    its first dispatch (``lower().compile()``, timed as the compile), for
+    a program whose first dispatch falls where nothing may compile: the
+    state backend's reclaim, built when two health readings in a row
+    show the table heading for the load that triggers it. Dispatches
+    then run that executable."""
 
     __slots__ = ("_fn", "_scope", "_compiled", "_build_key",
-                 "_build_counted", "_aot_execs", "_aot_bad")
+                 "_build_counted", "_aot_execs", "_aot_bad", "_prepared")
 
     def __init__(self, fn, scope: str, build_key: str = "",
                  build_counted: bool = True):
@@ -927,19 +959,34 @@ class _TimedProgram:
         self._build_counted = build_counted
         self._aot_execs = None  # call_sig -> compiled executable
         self._aot_bad = None    # call_sigs pinned to the plain jit path
+        self._prepared = None   # the executable prepare() compiled
 
     def __call__(self, *args, **kwargs):
         from ..runtime.aot import AOT
-        if AOT.dispatch_active():
+        if self._prepared is None and AOT.dispatch_active():
             return self._call_aot(AOT, args, kwargs)
         return self._call_plain(args, kwargs)
 
+    def prepare(self, *args, **kwargs) -> None:
+        """Compile now for ``args`` (arrays or ``ShapeDtypeStruct``s of
+        the one shape the program will be called with); a no-op once the
+        program has compiled either way."""
+        if self._compiled:
+            return
+        from .tracing import now_ms
+        start_ms = now_ms()
+        t0 = time.perf_counter()
+        self._prepared = self._fn.lower(*args, **kwargs).compile()
+        self._note_live_compile((time.perf_counter() - t0) * 1e3,
+                                start_ms, args, kwargs)
+
     def _call_plain(self, args, kwargs):
         if self._compiled:
+            fn = self._prepared or self._fn
             if not DEVICE_LEDGER.enabled:
-                return self._fn(*args, **kwargs)
+                return fn(*args, **kwargs)
             t0 = time.perf_counter()
-            out = self._fn(*args, **kwargs)
+            out = fn(*args, **kwargs)
             DEVICE_LEDGER.record(self._scope,
                                  (time.perf_counter() - t0) * 1e3,
                                  shape_sig=self._build_key)
@@ -1240,6 +1287,13 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_fold_ring_rows_total)
     g.gauge("fold_batches_total", lambda: s.fold_counts[0])
     g.gauge("fold_ring_rows_total", lambda: s.fold_counts[1])
+    # state reclaim of the one-chip backend (prometheus:
+    # flink_tpu_device_state_reclaim_sweeps_total /
+    # flink_tpu_device_state_reclaim_keys_kept_total /
+    # flink_tpu_device_state_reclaim_keys_freed_total)
+    g.gauge("state_reclaim_sweeps_total", lambda: s.reclaim_counts[0])
+    g.gauge("state_reclaim_keys_kept_total", lambda: s.reclaim_counts[1])
+    g.gauge("state_reclaim_keys_freed_total", lambda: s.reclaim_counts[2])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

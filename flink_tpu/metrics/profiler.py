@@ -635,6 +635,9 @@ LEDGER_SITE_INVENTORY: tuple = (
     ("state.fold",  # lint: key-ok ledger site, not a config key
      "state/tpu_backend.py — ring-plane fold program (one batch into "
      "every ring plane, ring row by ring row)"),
+    ("state.reclaim",  # lint: key-ok ledger site, not a config key
+     "state/tpu_backend.py — reclaim program (the table rebuilt at its "
+     "own capacity from the live keys, every plane re-seated)"),
     ("state.reset_row",
      "state/tpu_backend.py — keyed-state row reset program"),
     ("transfer.d2h",

@@ -862,6 +862,15 @@ SPAN_INVENTORY: tuple = (
      "batch's ingest programs (stage span: programs); "
      "runtime/operators/mesh_window.py _flush — one [D, B] block's step "
      "and the look at the pressure probe (seq: the block's ordinal)"),
+    ("window", "Reclaim",
+     "runtime/operators/device_window.py _apply_health — the backend's "
+     "reclaim (state/tpu_backend.py reclaim: the table rebuilt at its own "
+     "capacity from the keys that still hold data in a ring row, every "
+     "plane re-seated, ONE device program), from its dispatch in the "
+     "mailbox turn that found the table past load 0.6 until its two "
+     "counts have landed (the mailbox does not wait for it); child of "
+     "the Drain whose health reading found it (stage span: kept, freed, "
+     "capacity; seq: that window's)"),
     ("window", "Upload",
      "runtime/operators/device_window.py _fold_packed / "
      "_to_device_batch — pack + the one host→device copy; device/H2D "

@@ -15,10 +15,14 @@ resume from the contested slot. Claims only target slots read as EMPTY in
 the same round, so occupied slots are never corrupted; duplicate keys
 follow identical probe sequences and claim the same first-empty slot (the
 loser sees its own key and resolves). The insert-only invariant (empties
-never reappear) guarantees a present key can never sit behind an empty
-slot in its probe sequence, so first-match-before-first-empty decides
-containment. Bounded probe count returns an ``ok`` mask instead of looping
-forever (host rehashes on overflow).
+never reappear IN A TABLE: a slot is freed only by building a new table
+from the keys that stay, at twice the capacity or, since the state
+backend reclaims, at the same one; state/tpu_backend.py ``reclaim``)
+guarantees a present key can never sit behind an empty slot in its probe
+sequence, so first-match-before-first-empty decides containment, from a
+table's first key to its replacement. Bounded probe count returns an
+``ok`` mask instead of looping forever (the host reclaims or rehashes
+before the load bites, and fails the job on a dropped insert).
 
 What a round costs on the chip (TPU v5e, 2^24 slots, PERF.md sections 5
 and 6): per row carried, about 110 ns for the window's two gathers (the
@@ -29,7 +33,14 @@ resident keys sit within CHUNK slots of their home. So ``lookup_or_insert``
 carries a row only while it is unresolved: one read-only window at full
 width, then the rounds over the compacted tail (1-2% of a batch of
 resident keys), at full width only when the tail does not fit (mostly new
-keys: cold start, prefill, growth). CHUNK = 8 is the window at which those
+keys: cold start, prefill, growth, and, where keys come and go, every
+batch: a key whose slot was reclaimed and that is seen again is an insert,
+and a key that is new and hot has ALL its rows unresolved, so a job over
+NEXmark's advancing auction ids leaves 58% of a 2^18-row batch to the
+claiming rounds: PERF.md section 5, q5-inflight-saturated). A caller that
+sees such batches one after another asks for the program that runs the
+full-width rounds only until what is left fits the narrow loop
+(``handover``). CHUNK = 8 is the window at which those
 shares were measured; it was first sized for a CPU cache line (2.3x over
 one-slot probing at 50% load on the CPU), which is no argument here: on
 the chip a window costs what its CHUNK gathered elements cost.
@@ -61,7 +72,7 @@ def ensure_x64() -> None:
 
 __all__ = ["EMPTY_KEY", "make_table", "lookup", "lookup_or_insert",
            "hash_keys_device", "sanitize_keys_device", "ensure_x64",
-           "MAX_PROBES"]
+           "compacts", "MAX_PROBES"]
 
 EMPTY_KEY = np.int64(np.iinfo(np.int64).max)
 MAX_PROBES = 128
@@ -183,11 +194,20 @@ def _advance(base: jax.Array, done: jax.Array,
 
 def _claim_loop(table: jax.Array, keys: jax.Array, h0: jax.Array,
                 mask: jax.Array, base: jax.Array, slot: jax.Array,
-                done: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Probe rounds until every row is resolved or out of probes, at the
-    width of ``keys``: read a window, take a match, else claim the window's
-    first EMPTY with one ``scatter-min`` (smallest key wins; losers resume
-    from the contested slot). Returns (table, slot)."""
+                done: jax.Array, until: int = 0):
+    """Probe rounds at the width of ``keys``, until every row is resolved
+    or out of probes, or, with ``until``, until no more than that many
+    rows are unresolved (for a narrower loop to take them over): read a
+    window, take a match, else claim the window's first EMPTY with one
+    ``scatter-min`` (smallest key wins; losers resume from the contested
+    slot). Returns (table, base, slot, done)."""
+
+    def unfinished(state):
+        _table, base, _slot, done = state
+        more = _unfinished(base, done)
+        if until:
+            more = more & (jnp.sum(~done, dtype=jnp.int32) > until)
+        return more
 
     def body(state):
         table, base, slot, done = state
@@ -206,9 +226,14 @@ def _claim_loop(table: jax.Array, keys: jax.Array, h0: jax.Array,
         done = done | found | won
         return table, _advance(base, done, pos_empty), slot, done
 
-    table, _base, slot, _done = jax.lax.while_loop(
-        lambda s: _unfinished(s[1], s[3]), body, (table, base, slot, done))
-    return table, slot
+    return jax.lax.while_loop(unfinished, body, (table, base, slot, done))
+
+
+def compacts(n: int) -> bool:
+    """Whether a batch of ``n`` rows is wide enough for the probe to
+    compact its unresolved rows (else every round runs at full width and
+    ``handover`` changes nothing)."""
+    return n >= _COMPACT_MIN_ROWS
 
 
 def _tail_widths(n: int) -> tuple[int, ...]:
@@ -220,9 +245,10 @@ def _tail_widths(n: int) -> tuple[int, ...]:
     return tuple(n >> s for s in _TAIL_SHIFTS)
 
 
-@partial(jax.jit, static_argnames=("stats",))
+@partial(jax.jit, static_argnames=("stats", "handover"))
 def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
-                     valid: jax.Array | None = None, stats: bool = False):
+                     valid: jax.Array | None = None, stats: bool = False,
+                     handover: bool = False):
     """Find-or-claim slots for a batch of keys.
 
     Returns (new_table_keys, slots int32, ok bool). Records that exhaust
@@ -235,17 +261,38 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     there and nobody wants a slot), then compacts the rows still
     unresolved into the narrowest of ``_tail_widths(n)`` that holds them
     and runs the probe rounds over those lanes alone; with more unresolved
-    rows than the widest (cold start, prefill, growth) the rounds run at
-    full width from where the first window left off. One program either
-    way (a ``lax.switch`` on a device scalar, nothing for the host to
-    sync on); smaller batches run the rounds at full width from the
-    start, as every batch did before. The result is a pure function of
-    (table, keys, valid) on every path.
+    rows than the widest (cold start, prefill, growth, the re-homing of
+    the live keys inside a reclaim, and any batch of a job whose keys
+    come and go: a reclaimed key seen again is an insert, and every row
+    of a brand-new hot key is unresolved) the rounds run at full width
+    from where the first window left off. One program either way (a
+    ``lax.switch`` on a device scalar, nothing for the host to sync on);
+    smaller batches run the rounds at full width from the start, as every
+    batch did before. The result is a pure function of (table, keys,
+    valid) on every path.
+
+    ``handover=True`` (static: a second program) is for a caller that
+    expects such batches, one after another: the full-width rounds then
+    run only until the rows left fit the widest narrow loop, which takes
+    them over. A round costs what its lanes cost, and after one or two
+    rounds of a batch of new keys all but a few per cent hold their slot:
+    6 to 10 full-width rounds of 83 ms at 2^18 rows and load 0.6 become
+    one or two and a few narrow ones (PERF.md section 6, PR 35). The
+    rounds are the same rounds over the same rows at either width, so the
+    result is the same. It is a program of its own, and not the only one,
+    because the v5e's compiler keeps both 32-bit halves of a 2^24-slot
+    table in its fast memory space for the program below and only one of
+    them for every form of the hand-over tried (the first window's two
+    gathers then take longer; measured on the chip with this program
+    pinned on a job of 10M resident keys: 15.4 ms a batch, 8.8% of its
+    events a second: PERF.md section 6, PRs 26 and 35), so a job whose
+    batches resolve in their first window keeps the program it had.
+    ``state/tpu_backend.py`` picks by the probe's own counters.
 
     ``stats=True`` (static) appends an int32[3]: rows probed, rows that
     entered a claiming loop (unresolved after the read-only window; every
     probed row of a batch below the compaction width), and 1 if that loop
-    ran at full width else 0.
+    started at full width else 0.
     """
     mask = jnp.uint32(table_keys.shape[0] - 1)
     h0 = hash_keys_device(keys) & mask
@@ -257,8 +304,8 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     rows = jnp.sum(~done, dtype=jnp.int32)
     if not widths:
         n_tail, wide = rows, jnp.int32(1)
-        table, slot = _claim_loop(table_keys, keys, h0, mask, base, slot,
-                                  done)
+        table, _base, slot, _done = _claim_loop(table_keys, keys, h0, mask,
+                                                base, slot, done)
     else:
         hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base, mask)
         slot = jnp.where((~done) & hit, fslot, slot)
@@ -266,7 +313,7 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
         base = _advance(base, done, pos_empty)
         n_tail = jnp.sum(~done, dtype=jnp.int32)
 
-        def narrow_loop(T, table, base, slot, done):
+        def narrow_loop(T, n_left, table, base, slot, done):
             with jax.named_scope("probe.compact"):
                 # a stable sort on `done` puts the unresolved rows first, in
                 # row order: 0.24 ms at n = 2^18 on the v5e, where cumsum +
@@ -275,8 +322,8 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
                 _, order = jax.lax.sort_key_val(
                     done.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32))
                 src = order[:T]
-                live = jnp.arange(T, dtype=jnp.int32) < n_tail
-            table, tslot = _claim_loop(
+                live = jnp.arange(T, dtype=jnp.int32) < n_left
+            table, _tbase, tslot, _tdone = _claim_loop(
                 table, keys[src], h0[src], mask, base[src],
                 jnp.full(T, -1, jnp.int32), ~live)
             with jax.named_scope("probe.compact"):
@@ -285,13 +332,20 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
             return table, slot
 
         def wide_loop(table, base, slot, done):
-            return _claim_loop(table, keys, h0, mask, base, slot, done)
+            if not handover:
+                table, _base, slot, _done = _claim_loop(
+                    table, keys, h0, mask, base, slot, done)
+                return table, slot
+            table, base, slot, done = _claim_loop(
+                table, keys, h0, mask, base, slot, done, until=widths[-1])
+            return narrow_loop(widths[-1], jnp.sum(~done, dtype=jnp.int32),
+                               table, base, slot, done)
 
         # the narrowest loop that holds the tail, else the full width
         level = sum((n_tail > T).astype(jnp.int32) for T in widths)
         wide = (level == len(widths)).astype(jnp.int32)
         table, slot = jax.lax.switch(
-            level, [partial(narrow_loop, T) for T in widths] + [wide_loop],
-            table_keys, base, slot, done)
+            level, [partial(narrow_loop, T, n_tail) for T in widths]
+            + [wide_loop], table_keys, base, slot, done)
     out = (table, slot, slot >= 0)
     return (*out, jnp.stack([rows, n_tail, wide])) if stats else out

@@ -1229,7 +1229,8 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # NOW (before this fire retires the pane row below)
         host_part = (self._host_fire_part(np.array(rows, np.int32))
                      if self._backend.spill_active else None)
-        self._enqueue_fire((p_end, outs, host_part, time.perf_counter()))
+        self._enqueue_fire((p_end, outs, host_part, time.perf_counter(),
+                            self._backend.table_generation))
         # retire the oldest pane of this window: no future window needs it
         # (skip panes below min_seen — their ring rows belong to live panes)
         if p_end - W >= self._min_seen_pane:
@@ -1348,7 +1349,8 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # retire below (same ordering as the full-merge path)
         host_part = (self._host_fire_part(np.array(rows, np.int32))
                      if self._backend.spill_active else None)
-        self._enqueue_fire((p_end, outs, host_part, time.perf_counter()))
+        self._enqueue_fire((p_end, outs, host_part, time.perf_counter(),
+                            self._backend.table_generation))
         if p_end - W >= self._min_seen_pane:
             self._backend.reset_ring_row((p_end - W) % self._ring)
         self._refresh_late(block=True)
@@ -1384,20 +1386,22 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         return keys, res
 
     def _materialize(self, item, turn: str) -> None:
-        p_end, outs, host_part, t0, fire = item
+        p_end, outs, host_part, t0, generation, fire = item
         with self._drain_stage(fire, turn) as drain:
-            keys, results, d2h_bytes = self._drain_rows(outs, host_part,
-                                                        drain)
+            keys, results, d2h_bytes = self._drain_rows(
+                outs, host_part, drain, generation)
         if len(keys):
             with self._emit_stage(fire, len(keys)):
                 self._emit_rows(p_end, keys, results)
         self._note_latency(t0)
         self._close_fire(fire, len(keys), d2h_bytes)
 
-    def _drain_rows(self, outs, host_part, drain):
+    def _drain_rows(self, outs, host_part, drain, generation):
         """One fire's rows on the host: the ONE device_get + selection /
         canonical order. Returns (keys, results, d2h bytes); a ranked
-        fire's select is noted on ``drain``, its window/Drain stage."""
+        fire's select is noted on ``drain``, its window/Drain stage.
+        ``generation``: the backend's table generation at the fire's
+        dispatch, which its health reading is of."""
         if self._guard is None or self._guard.active:
             # ONE deadline-bounded transfer for everything (device_get is
             # idempotent: a stall-abandoned read re-runs safely)
@@ -1411,14 +1415,14 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         d2h_bytes = pytree_nbytes(host)
         if self._topk is not None:
             keys_k, ok, results, dropped, occ, select = host
-            self._backend.apply_health(dropped, occ)
+            self._apply_health(dropped, occ, generation, drain)
             self._note_fire_select(drain, select)
             sel = np.asarray(ok)
             keys = np.asarray(keys_k)[sel]
             results = {n: np.asarray(v)[sel] for n, v in results.items()}
         else:
             table, emit, results, dropped, occ = host
-            self._backend.apply_health(dropped, occ)
+            self._apply_health(dropped, occ, generation, drain)
             mask = np.asarray(emit)
             idx = np.flatnonzero(mask)
             keys = np.asarray(table)[idx]
@@ -1446,6 +1450,18 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             results = {n: v[order] for n, v in results.items()}
         DEVICE_STATS.note_d2h(d2h_bytes, len(keys))
         return keys, results, d2h_bytes
+
+    def _apply_health(self, dropped, occ, generation, drain) -> None:
+        """A drained fire's health scalars to the backend, which frees
+        the slots of keys that hold no data any more (window/Reclaim:
+        opened inside this window/Drain turn, which dispatches the
+        reclaim, and closed by the backend when its counts have landed)
+        or grows when the table fills. The backend passes over a reading
+        of a table it has rebuilt since the fire's dispatch."""
+        at = {"parent": drain.context, "seq": drain.attrs["seq"]}
+        self._backend.apply_health(
+            dropped, occ, generation,
+            stage=lambda: TRACER.open_stage("window", "Reclaim", **at))
 
     def _emit_rows(self, p_end: int, keys: np.ndarray,
                    results: dict[str, np.ndarray]) -> None:

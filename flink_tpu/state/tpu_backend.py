@@ -10,7 +10,13 @@ indexed by a device hash table (ops/hash_table.py). Registered under name
 Two access planes:
 * **array states** — the hot path: named [capacity] or [ring, capacity]
   accumulator arrays updated by whole-batch scatter folds; used by the device
-  window/aggregate operators. Rehash (growth) remaps every array on device.
+  window/aggregate operators. Rehash (growth) remaps every array on device;
+  so does the reclaim, which comes first: when the table passes load 0.6
+  the slots of keys that hold no data in any ring row any more (all their
+  windows fired and retired) are freed at the SAME capacity, in one
+  device program, and the table grows only if the live keys alone fill it
+  (``reclaim``: a job's state is what its live windows hold, as in
+  Flink's WindowOperator.clearAllState).
 * **row states** — API-compatibility plane (ValueState etc.) with host-side
   gather/scatter per access; correct but slow, for small/irregular state.
 
@@ -36,7 +42,8 @@ from ..core.keygroups import KeyGroupRange, hash_batch, \
     key_groups_for_hash_batch
 from ..metrics.device import DEVICE_STATS, instrumented_program_cache
 from ..ops.hash_table import (
-    EMPTY_KEY, lookup, lookup_or_insert, make_table, sanitize_keys_device,
+    EMPTY_KEY, compacts, hash_keys_device, lookup, lookup_or_insert,
+    make_table, sanitize_keys_device,
 )
 from ..ops.segment_ops import AGG_INITS, make_accumulator, ring_fold, \
     scatter_fold
@@ -122,6 +129,154 @@ def _fold_program(sig: tuple):
             for (kind, _dt, _shape), a, c in zip(sig, arrays, cols))
 
     return fold
+
+
+#: health readings (one a fired window) the reclaim's program is built
+#: ahead of the one that would find the table past its load limit, at the
+#: pace the last two readings showed: the build must have ended by then,
+#: and on a cold compile cache it takes half a minute at 2^23 slots
+_RECLAIM_LOOKAHEAD = 8
+
+#: live keys the reclaim re-homes at a time (one ``lookup_or_insert`` a
+#: chunk, inside the program; the last chunk costs what a full one does,
+#: so a quarter of a Q5 batch, which keeps a reclaim's time in step with
+#: the keys it moves)
+_RECLAIM_CHUNK = 1 << 16
+
+
+def _sorted_slots(rank: jax.Array, ranks: int) -> jax.Array:
+    """The slots ``0..C-1`` ordered by ``rank`` (an int32 in
+    ``[0, ranks)``) and, within a rank, by slot: one single-operand sort
+    of the distinct words ``rank * C + slot`` (so it need not be stable:
+    on the v5e a stable sort of 2^23 words takes three times as long to
+    compile as an unstable one)."""
+    C = rank.shape[0]
+    word = jnp.int32 if ranks * C <= 1 << 31 else jnp.int64
+    keyed = jax.lax.sort(rank.astype(word) * C
+                         + jnp.arange(C, dtype=word), is_stable=False)
+    return (keyed % C).astype(jnp.int32)
+
+
+def _permute(where: jax.Array, values: jax.Array) -> jax.Array:
+    """``out[where[i]] = values[i]`` for a permutation ``where`` of
+    ``0..C-1``, as a sort by it (distinct keys: no stability needed)."""
+    return jax.lax.sort((where, values), num_keys=1, is_stable=False)[1]
+
+
+@instrumented_program_cache("state.reclaim")
+def _reclaim_program(sig: tuple, live_planes: tuple):
+    """One jitted reclaim per plane signature: the table rebuilt AT ITS
+    OWN CAPACITY from the keys that still hold data, every plane re-seated
+    onto the new slots, in a single fixed-shape dispatch. ``sig`` = tuple
+    of (kind, dtype_str, shape) over ALL of the backend's array states,
+    as ``_reset_row_program``'s; ``live_planes`` indexes the ones that
+    decide what lives (the pane-role ring planes).
+
+    * ``reclaim.live``: a slot lives iff it is occupied and some ring row
+      of some ``live_planes`` plane differs from its aggregate's identity
+      there (a count plane alone would do: every fold counts). One sort
+      of the slots puts first the live keys that must move (they sit
+      past their home slot, and a freed slot before them would hide them
+      from the probe), then the live keys AT their home slot, then the
+      rest.
+    * ``reclaim.rehome``: the new table starts from the live keys at
+      home, where they are; the live keys that must move go through
+      ``lookup_or_insert`` into it, ``_RECLAIM_CHUNK`` at a time, only as
+      many chunks as hold one. A freed slot is never set to EMPTY under a
+      key that stays behind it (``lookup_or_insert`` decides containment
+      by first match before first empty): every key of the new table has
+      no EMPTY between its home and its slot, and the table is
+      insert-only from there on. The slots no key landed in take the
+      freed and empty old slots, in order, so that old slot -> new slot
+      (``dest``) is a permutation.
+    * ``reclaim.remap``: every plane, ring and window role, row by row in
+      place: the row sorted by ``dest`` IS the row re-seated (a sort moves
+      a ring row of 2^23 cells in tens of milliseconds where a gather by
+      index costs half a second a 32-bit word on the v5e), identity where
+      no key landed; a row that holds nothing but identities (a retired
+      pane) is left as it is.
+
+    Returns (table, planes, dropped, [kept, freed]); ``dropped`` is the
+    backend's counter passed through, plus the live keys that found no
+    slot within MAX_PROBES (0 short of a pathological key set; the next
+    health check then fails the job as for any dropped insert). The
+    planes are donated; the old table is not (a fire still in the drain
+    queue may hold it as an output)."""
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def reclaim(table, arrays: tuple, dropped):
+        C = table.shape[0]
+        B = min(C, _RECLAIM_CHUNK)
+        slot = jnp.arange(C, dtype=jnp.int32)
+        lane = jnp.arange(B, dtype=jnp.int32)
+        empty = jnp.int64(EMPTY_KEY)
+        with jax.named_scope("reclaim.live"):
+            occupied = table != empty
+            holds = jnp.zeros(C, bool)
+            for i in live_planes:
+                a = arrays[i]
+                holds = holds | (a != AGG_INITS[sig[i][0]](a.dtype)).any(
+                    axis=0)
+            live = occupied & holds
+            home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
+                jnp.int32) == slot
+            moves = live & ~home
+            kept = jnp.sum(live, dtype=jnp.int32)
+            n_moves = jnp.sum(moves, dtype=jnp.int32)
+            freed = jnp.sum(occupied, dtype=jnp.int32) - kept
+            order = _sorted_slots(
+                jnp.where(moves, 0, jnp.where(live, 1, 2)), 3)
+
+        with jax.named_scope("reclaim.rehome"):
+            def rehome(i, carry):
+                new_table, new_of, lost = carry
+                src = jax.lax.dynamic_slice(order, (i * B,), (B,))
+                valid = i * B + lane < n_moves
+                new_table, slots, ok = lookup_or_insert(
+                    new_table, table[src], valid, handover=compacts(B))
+                new_of = jax.lax.dynamic_update_slice(
+                    new_of, jnp.where(ok, slots, src), (i * B,))
+                return (new_table, new_of,
+                        lost + jnp.sum(valid & ~ok, dtype=jnp.int32))
+
+            # new_of[p]: where the p-th slot of ``order`` goes; a key at
+            # home stays where it is
+            new_table, new_of, lost = jax.lax.fori_loop(
+                0, (n_moves + B - 1) // B, rehome,
+                (jnp.where(live & home, table, empty), order,
+                 jnp.int32(0)))
+            landed = new_table != empty
+            free = _sorted_slots(landed.astype(jnp.int32), 2)
+            new_of = jnp.where(slot < kept, new_of, jnp.roll(free, kept))
+            dest = _permute(order, new_of)
+
+        with jax.named_scope("reclaim.remap"):
+            out = []
+            for (kind, _dt, _shape), a in zip(sig, arrays):
+                ident = AGG_INITS[kind](a.dtype)
+
+                def reseat(row, ident=ident):
+                    return jax.lax.cond(
+                        (row != ident).any(),
+                        lambda r: jnp.where(landed, _permute(dest, r),
+                                            ident),
+                        lambda r: r, row)
+
+                if a.ndim == 1:
+                    out.append(reseat(a))
+                    continue
+
+                def body(r, plane, reseat=reseat):
+                    row = jax.lax.dynamic_index_in_dim(plane, r, 0,
+                                                       keepdims=False)
+                    return jax.lax.dynamic_update_index_in_dim(
+                        plane, reseat(row), r, 0)
+
+                out.append(jax.lax.fori_loop(0, a.shape[0], body, a))
+        return (new_table, tuple(out), dropped + lost.astype(dropped.dtype),
+                jnp.stack([kept, freed]))
+
+    return reclaim
 
 
 @jax.jit
@@ -233,7 +388,10 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self._array_states: dict[str, _ArrayState] = {}
         self._row_states: dict[str, State] = {}
         self._row_meta: dict[str, int] = {}  # row-plane name -> ttl_ms
-        self._num_keys = 0  # host-tracked occupancy (exact: insert-only table)
+        # host-tracked occupancy, as of the last health reading, sync-mode
+        # batch or rebuild (the table is insert-only BETWEEN two rebuilds:
+        # growth, eviction, reclaim)
+        self._num_keys = 0
         # deferred mode: the hot path never syncs with the host; overflow
         # accumulates in a device counter checked at watermark boundaries
         self._defer = bool(defer_overflow)
@@ -244,6 +402,29 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self._probe = jnp.zeros(3, jnp.int64)
         self._probe_sent: Optional[jax.Array] = None
         self._probe_noted = np.zeros(3, np.int64)
+        # probes dispatched, and how many of them the counters last sent
+        # and last noted had seen (note_probe_stats)
+        self._probe_calls = self._probe_sent_calls = 0
+        self._probe_noted_calls = 0
+        # whether EVERY batch last noted left more rows unresolved after
+        # its first window than the probe's narrow loops hold: the next
+        # batch then takes the program that hands the full-width rounds
+        # over to the narrow loop (ops/hash_table.lookup_or_insert)
+        self._probe_wide = False
+        # the table's generation: every rebuild (growth, eviction,
+        # reclaim, restore) moves the slots and starts a new one; a
+        # health reading taken under an older one says nothing of this
+        # table (table_generation, apply_health)
+        self._generation = 0
+        # the last reading of this generation and the growth it showed
+        # over the one before (None: not two readings yet): the trend
+        # that builds the reclaim's program ahead (apply_health)
+        self._trend: tuple = (None, None)
+        # plane signature the reclaim's program was last built ahead for
+        self._reclaim_built: Optional[tuple] = None
+        # a reclaim dispatched whose counts have not landed yet:
+        # (device [kept, freed], the caller's open stage span)
+        self._reclaiming: Optional[tuple] = None
         # spill tier: device capacity is capped at the HBM budget; cold key
         # groups page out to host RAM (state/spill.py). 0 = unlimited.
         # With defer_overflow the split is computed ON DEVICE (spilled-group
@@ -478,6 +659,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         """Re-key the device table to ``keep_keys`` only (rehash growth or
         post-eviction shrink of the resident set), remapping every array
         state's rows on device."""
+        self._finish_reclaim(block=True)   # its counts are of the old table
         old_arrays = {n: st.array for n, st in self._array_states.items()}
         new_table = make_table(new_capacity)
         if len(keep_keys):
@@ -501,6 +683,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                         old_arrays[name][jnp.asarray(old_slots)])
             st.array = new_arr
         self._invalidate_mirror()
+        self._new_generation()
 
     # ------------------------------------------------------------------
     # spill tier (HBM budget; state/spill.py)
@@ -949,9 +1132,13 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         if not self._defer:
             raise RuntimeError("device-resident slot resolution requires "
                                "defer_overflow mode")
+        if self._reclaiming is not None:
+            self._finish_reclaim()       # before the probe: it may grow
         dkeys = sanitize_keys_device(dkeys)
+        self._probe_calls += 1
         self.table, slots, ok, probe = lookup_or_insert(
-            self.table, dkeys, stats=True)
+            self.table, dkeys, stats=True,
+            handover=self._probe_wide and compacts(dkeys.shape[0]))
         self._dropped = self._dropped + jnp.sum(~ok).astype(jnp.int64)
         self._probe = self._probe + probe
         self.note_probe_stats()
@@ -963,16 +1150,32 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         never waits: it reads a copy taken at an earlier batch only once
         that has landed, then takes the next (so the gauges trail the
         device by a batch or two); ``block=True`` (check_health, operator
-        close: places that sync anyway) reads the counters as they are."""
+        close: places that sync anyway) reads the counters as they are.
+        The same reading picks the next batch's probe program: where
+        every batch noted left more rows unresolved than the narrow loops
+        hold (a prefill, or a stream whose keys come and go), the one
+        that hands its full-width rounds over to the narrow loop; else
+        the one every resident-key job has always run. Both give the
+        same slots, so a late or wrong pick costs time only."""
+        if block:
+            self._finish_reclaim(block=True)
         sent = self._probe if block else self._probe_sent
         if sent is not None and (block or sent.is_ready()):
             # lint: sync-ok the copy has landed (or the caller syncs anyway)
             now = np.asarray(jax.device_get(sent))
-            DEVICE_STATS.note_probe(*(now - self._probe_noted))
-            self._probe_noted = now
+            calls = self._probe_calls if block else self._probe_sent_calls
+            rows, tail, wide = now - self._probe_noted
+            DEVICE_STATS.note_probe(rows, tail, wide)
+            if calls > self._probe_noted_calls:
+                # every probe noted started its claiming rounds at full
+                # width
+                self._probe_wide = bool(
+                    wide == calls - self._probe_noted_calls)
+            self._probe_noted, self._probe_noted_calls = now, calls
             self._probe_sent = None
         if self._probe_sent is None and not block:
             self._probe_sent = self._probe
+            self._probe_sent_calls = self._probe_calls
 
     # ------------------------------------------------------------------
     # deferred-mode health (device scalars; ride along with fire programs)
@@ -981,11 +1184,34 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def dropped_device(self) -> jax.Array:
         return self._dropped
 
-    def apply_health(self, dropped: int, occupancy: int) -> None:
+    @property
+    def table_generation(self) -> int:
+        """Which table a health reading is of: a caller that takes a
+        reading now and hands it to ``apply_health`` later (a fire's
+        ``occ``, drained turns after its dispatch) hands this back with
+        it, and a reading of a table since rebuilt is passed over."""
+        return self._generation
+
+    def _new_generation(self) -> None:
+        self._generation += 1
+        self._trend = (None, None)
+
+    def apply_health(self, dropped: int, occupancy: int,
+                     generation: Optional[int] = None, stage=None) -> None:
         """Consume host-materialized health scalars (fetched in the same
-        device_get as a fire's results): hard-error on any dropped insert,
-        grow the table before the load factor bites — or, under an HBM
-        budget, page cold key groups to the host tier instead."""
+        device_get as a fire's results): hard-error on any dropped insert;
+        before the load factor bites, RECLAIM (free every slot whose key
+        holds no data in any ring row, at the same capacity) and grow only
+        if that is not enough — or, under an HBM budget, page cold key
+        groups to the host tier instead. ``generation``: the
+        ``table_generation`` the reading was taken under; the occupancy of
+        a table that has been rebuilt since says nothing of this one and
+        is passed over (None: the reading is of the table as it is).
+        ``stage`` opens the caller's span around a reclaim (``reclaim``).
+
+        Two readings in a row that show the table growing, at a pace that
+        would take it past the load limit within ``_RECLAIM_LOOKAHEAD``
+        more, build the reclaim's program (``_prepare_reclaim``)."""
         if int(dropped) > 0:
             if self._budget:
                 raise RuntimeError(
@@ -994,16 +1220,124 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                     "interval; raise spill_staging_slots or the HBM budget")
             raise RuntimeError(
                 f"device hash table overflow: {int(dropped)} records "
-                f"dropped (capacity {self.capacity}); raise "
+                f"dropped (capacity {self.capacity}: neither a reclaim nor "
+                "growth came in time); raise "
                 "state.backend.tpu.slots-per-key-group or disable "
                 "deferred overflow checking")
+        self._finish_reclaim()
+        if self._reclaiming is not None or (
+                generation is not None and generation != self._generation):
+            return
         self._num_keys = int(occupancy)
-        if self._num_keys > 0.6 * self.capacity:
-            if not self._budget or 2 * self.capacity <= self._budget:
-                self._rehash(self.capacity * 2)
-            else:
-                self._sync_touch_from_device()
-                self._evict_cold_groups()
+        last, before = self._trend
+        growth = None if last is None else self._num_keys - last
+        self._trend = (self._num_keys, growth)
+        threshold = 0.6 * self.capacity
+        if self._num_keys <= threshold:
+            # the slower of the last two steps, if both were growth
+            pace = min(before, growth) if before is not None else 0
+            if pace > 0 and self._num_keys + _RECLAIM_LOOKAHEAD * pace \
+                    > threshold:
+                self._prepare_reclaim()
+        elif self._reclaimable():
+            self.reclaim(stage, wait=False)
+        else:
+            self._grow()
+
+    def _grow(self) -> None:
+        if not self._budget or 2 * self.capacity <= self._budget:
+            self._rehash(self.capacity * 2)
+        else:
+            self._sync_touch_from_device()
+            self._evict_cold_groups()
+
+    def _reclaimable(self) -> bool:
+        """Whether a reclaim can tell what lives: no HBM budget (cold
+        groups page out instead), and every pane-role state a ring plane
+        (a row-state plane has no pane that retires)."""
+        panes = [st for st in self._array_states.values()
+                 if st.role != "window"]
+        return (not self._budget and bool(panes)
+                and all(st.ring for st in panes))
+
+    def _reclaim_call(self) -> tuple:
+        """(the reclaim program of the current planes, its arguments)."""
+        states = list(self._array_states.values())
+        live = tuple(i for i, st in enumerate(states)
+                     if st.ring and st.role != "window")
+        return (_reclaim_program(_plane_sig(states), live),
+                (self.table, tuple(st.array for st in states),
+                 self._dropped))
+
+    def _prepare_reclaim(self) -> None:
+        """Build the reclaim's program for the planes as they are now,
+        ahead of the reading that will ask for it, so that the reclaim
+        itself compiles nothing (a job may promise to build nothing once
+        it is warm). The build blocks the turn it runs in, as every
+        program's first use does and as each growth's did: a job that
+        compiled it on another thread would go on firing windows, but a
+        build that ENDS once the job has promised to build nothing breaks
+        that promise from any thread, and only a job that stops taking
+        input makes its source wait (PERF.md section 7)."""
+        sig = _plane_sig(self._array_states.values())
+        if sig == self._reclaim_built or not self._reclaimable():
+            return
+        program, args = self._reclaim_call()
+        program.prepare(*jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
+        self._reclaim_built = sig
+
+    def reclaim(self, stage=None, wait: bool = True):
+        """Free every slot whose key holds no data in any ring row of any
+        pane-role plane, keeping the capacity: what Flink's window
+        operator does at a window's cleanup time (``clearAllState``), done
+        for all keys at once when the table fills. ONE device program
+        (``_reclaim_program``), dispatched here and not waited for: the
+        batches that follow queue behind it on the device and probe the
+        new table, and the mailbox does not stop. Only the two counts
+        cross to the host, when they have landed (``_finish_reclaim``: at
+        a later batch or health reading); then the table grows after all
+        if fewer than a quarter of the occupied slots came free (the
+        job's live set really is that large). Every slot moves, so a
+        new table generation begins, the snapshot mirror is invalidated
+        and the planes taken from ``get_array`` before this call are
+        deleted buffers. ``stage`` is
+        a zero-argument factory of the caller's OPEN stage span
+        (``window/Reclaim``), closed with the attributes ``kept``,
+        ``freed`` and ``capacity`` when the counts are in. ``wait=True``
+        waits for them and returns (keys kept, keys freed)."""
+        program, args = self._reclaim_call()
+        span = stage() if stage is not None else None
+        self.table, outs, self._dropped, counts = program(*args)
+        for st, arr in zip(self._array_states.values(), outs):
+            st.array = arr
+        counts.copy_to_host_async()
+        self._invalidate_mirror()
+        self._new_generation()
+        self._reclaiming = (counts, span)
+        return self._finish_reclaim(block=True) if wait else None
+
+    def _finish_reclaim(self, block: bool = False):
+        """Take in a dispatched reclaim's counts once their copy has
+        landed (``block``: wait for it): the stage span, the counters,
+        ``num_keys``, and the growth that a reclaim which freed too
+        little still calls for. Returns (kept, freed), or None while the
+        counts are not in."""
+        if self._reclaiming is None:
+            return None
+        counts, span = self._reclaiming
+        if not (block or counts.is_ready()):
+            return None
+        self._reclaiming = None
+        # lint: sync-ok the reclaim's two counts, landed (or the caller syncs anyway)
+        kept, freed = (int(x) for x in jax.device_get(counts))
+        self._num_keys = kept
+        DEVICE_STATS.note_reclaim(kept, freed)
+        if span is not None:
+            span.close(kept=kept, freed=freed, capacity=self.capacity)
+        if 4 * freed < kept + freed and kept + freed > 0.6 * self.capacity:
+            self._grow()
+        return kept, freed
 
     def check_health(self) -> None:
         """Standalone (blocking) variant of apply_health."""
@@ -1011,6 +1345,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                                  (self.table != EMPTY_KEY).sum()))
         self.note_probe_stats(block=True)
         self.apply_health(int(d), int(occ))
+        self._finish_reclaim(block=True)
 
     def conform_ring(self, ring: int, live_panes: Iterable[int]) -> None:
         """Re-seat ring-shaped array states restored under a DIFFERENT ring
@@ -1196,6 +1531,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def snapshot(self, checkpoint_id: int) -> dict:
         # delta capture: only dirty blocks cross the device boundary; the
         # snapshot itself is assembled from the host mirror
+        self._finish_reclaim(block=True)   # num_keys is of this table
         self._sync_mirror()
         t = self._mirror["table"]
         occupied = t != EMPTY_KEY
@@ -1244,6 +1580,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             # gathered against pre-restore state — cancel, never apply
             self._prefetch.cancel()
         snapshots = list(snapshots)
+        self._finish_reclaim(block=True)   # before the state is replaced
         WATCHDOG.run("transfer.h2d",
                      lambda: self._restore_inner(snapshots),
                      scope="tpu_backend.restore",
@@ -1295,6 +1632,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self._spilled_dev = None
         self._touch_dev = None
         self._invalidate_mirror()
+        self._new_generation()
         if self._budget and self.capacity > self._budget:
             self._evict_cold_groups(rebuild_capacity=self._budget)
 

@@ -68,3 +68,42 @@ def eight_device_mesh():
     devs = np.array(jax.devices("cpu")[:8])
     with Mesh(devs, ("data",)) as m:
         yield m
+
+
+@pytest.fixture
+def host_born_upload():
+    """Runs a tiny HOST-born windowed job (its batches are numpy and the
+    operator uploads them) and returns the ``h2d_bytes`` it added to the
+    process's cumulative DEVICE_STATS: a test that reads that series has
+    made its own upload, whichever tests ran before it in the process."""
+    import numpy as np
+
+    from flink_tpu.api import StreamExecutionEnvironment
+    from flink_tpu.core import WatermarkStrategy
+    from flink_tpu.core.config import PipelineOptions
+    from flink_tpu.core.records import Schema
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.runtime.operators.device_window import AggSpec
+    from flink_tpu.window import TumblingEventTimeWindows
+
+    def run() -> int:
+        before = DEVICE_STATS.snapshot()["h2d_bytes"]
+        env = StreamExecutionEnvironment.get_execution_environment()
+        env.set_state_backend("tpu")
+        env.config.set(PipelineOptions.BATCH_SIZE, 256)
+        ws = WatermarkStrategy.for_monotonous_timestamps() \
+            .with_timestamp_column("ts")
+        rows = (env.datagen(lambda i: {"k": i % 7, "ts": i * 4},
+                            Schema([("k", np.int64), ("ts", np.int64)]),
+                            count=1024, timestamp_column="ts",
+                            watermark_strategy=ws)
+                .key_by("k")
+                .window(TumblingEventTimeWindows.of(2000))
+                .device_aggregate([AggSpec("count", out_name="n")],
+                                  capacity=1 << 8, ring_size=4,
+                                  defer_overflow=True)
+                .execute_and_collect("host-born-upload"))
+        assert rows
+        return DEVICE_STATS.snapshot()["h2d_bytes"] - before
+
+    return run

@@ -256,3 +256,57 @@ def test_backend_hands_the_counters_to_device_stats():
     assert (rows, wide) == (2 * N + 8, 2) and N + 8 <= tail <= N + 8 + T
     be.check_health()
     assert moved(start) == [rows, tail, wide]   # a flush adds nothing twice
+
+
+@pytest.mark.parametrize("load", [0.0, 0.3, 0.58])
+def test_the_handover_program_places_every_row_where_the_plain_one_does(load):
+    """``handover=True``: the full-width rounds stop once the rows left
+    fit the widest narrow loop, which takes them over; the same rounds
+    over the same rows at another width, so the same table and slots, on
+    a batch of new keys, on one half of whose rows are a few new hot keys
+    (NEXmark's moving hot auction), with a valid mask, and on a batch
+    that never goes wide."""
+    rng = np.random.default_rng(int(load * 100))
+    table = _fill(CAP, rng.choice(1 << 40, int(load * CAP),
+                                  replace=False).astype(np.int64))
+    fresh = rng.choice(1 << 41, N, replace=False).astype(np.int64) + (1 << 41)
+    hot = fresh[:40][rng.integers(0, 40, N)]
+    resident = table[table != EMPTY_KEY]
+    batches = [fresh, np.where(rng.random(N) < 0.5, hot, fresh)]
+    if len(resident):
+        batches.append(rng.choice(resident, N))
+    for keys in batches:
+        for valid in (None, jnp.asarray(rng.random(N) < 0.9)):
+            plain = lookup_or_insert(jnp.asarray(table), jnp.asarray(keys),
+                                     valid, stats=True)
+            handed = lookup_or_insert(jnp.asarray(table), jnp.asarray(keys),
+                                      valid, stats=True, handover=True)
+            for a, b in zip(plain, handed):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _check(table, batches[1], None, expect_wide=1)
+
+
+def test_backend_picks_the_handover_program_while_batches_start_wide():
+    """The backend reads the probe's counters a batch or two late and
+    picks the next batch's program by them: new keys batch after batch
+    take the hand-over, resident keys the plain program again."""
+    from flink_tpu.core.keygroups import KeyGroupRange
+    from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
+
+    be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=CAP,
+                              defer_overflow=True)
+    rng = np.random.default_rng(5)
+    keys = rng.choice(1 << 40, 6 * N, replace=False).astype(np.int64)
+    assert not be._probe_wide
+    for i in range(6):                                  # all new: wide
+        be.slots_for_batch_device(jnp.asarray(keys[i * N:(i + 1) * N]))
+    be.note_probe_stats(block=True)
+    assert be._probe_wide
+    for _ in range(3):                                  # all resident
+        slots = be.slots_for_batch_device(jnp.asarray(keys[:N]))
+        assert (np.asarray(slots) >= 0).all()
+    be.note_probe_stats(block=True)
+    assert not be._probe_wide
+    # a small batch runs at full width whatever is picked
+    be.slots_for_batch_device(jnp.asarray(keys[:64] + 1))
+    be.note_probe_stats(block=True)

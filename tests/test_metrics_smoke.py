@@ -31,9 +31,12 @@ def _scrape(port: int) -> dict:
     return out
 
 
-def test_prometheus_scrape_of_tiny_q5():
+def test_prometheus_scrape_of_tiny_q5(host_born_upload):
     import bench
 
+    # the tiny Q5 is device-born and uploads nothing: the cumulative
+    # h2d series is this test's own host-born job's doing
+    uploaded = host_born_upload()
     reg = MetricRegistry()
     rep = PrometheusReporter(port=0)
     rep.open(reg)
@@ -49,7 +52,7 @@ def test_prometheus_scrape_of_tiny_q5():
     assert vals.get("flink_tpu_device_compiles", 0) > 0
     assert vals.get("flink_tpu_device_compile_cache_hits", 0) > 0
     # transfer accounting: host->device ingest and device->host fires
-    assert vals.get("flink_tpu_device_h2d_bytes", 0) > 0
+    assert vals.get("flink_tpu_device_h2d_bytes", 0) >= uploaded > 0
     assert vals.get("flink_tpu_device_d2h_bytes", 0) > 0
     # per-subtask mailbox busy time: at least one task reported progress
     busy = [v for k, v in vals.items()

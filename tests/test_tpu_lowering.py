@@ -241,6 +241,54 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     assert mem.temp_size_in_bytes < 1.01 * (wide + planes // ring)
 
 
+def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
+    """The backend's reclaim (PR 35) at the in-flight cell's shapes, 2^23
+    slots under Q5's two ring planes: ONE program `jit_reclaim` with the
+    three scopes a trace finds its parts by, every plane donated and
+    re-seated in place by a sort a ring row (no second copy of a plane:
+    beside them only the int64 plane's 32-bit halves and per-slot
+    vectors; no gather over the slots outside the probe), and nothing
+    but the table, the planes, the dropped counter and two counts coming
+    back."""
+    import re
+
+    from flink_tpu.state.tpu_backend import _reclaim_program
+
+    one = SingleDeviceSharding(v5e_devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cap, ring = 1 << 23, 16
+    sig = (("count", "int32", (ring, cap)), ("sum", "int64", (ring, cap)))
+    reclaim = _reclaim_program(sig, (0, 1))
+    args = (spec((cap,), jnp.int64),
+            tuple(spec(shape, dt) for _k, dt, shape in sig),
+            spec((), jnp.int64))
+    compiled = getattr(reclaim, "_fn", reclaim).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "HloModule jit_reclaim" in hlo
+    for scope in ("reclaim.live", "reclaim.rehome", "reclaim.remap"):
+        assert f"jit(reclaim)/{scope}/" in hlo, scope
+    # the live keys go through the probe the ingest step uses
+    assert "reclaim.rehome/while/body/" in hlo and "probe.claim" in hlo
+    # a ring row that holds nothing is skipped; one that does is sorted
+    assert re.search(r"reclaim\.remap/while/body/.*cond/branch_1_fun/sort", hlo)
+    assert len(re.findall(r" sort\(", hlo)) >= 5
+    for line in hlo.splitlines():
+        if " copy(" in line:
+            assert not re.search(rf"\[{ring},{cap}\]", line), line[:300]
+    table, planes_out, dropped, counts = jax.eval_shape(
+        getattr(reclaim, "_fn", reclaim), *args)
+    assert (table.shape, dropped.shape, counts.shape) == ((cap,), (), (2,))
+    assert [(str(a.dtype), a.shape) for a in planes_out] \
+        == [(dt, shape) for _k, dt, shape in sig]
+    mem = compiled.memory_analysis()
+    planes = sum(np.dtype(dt).itemsize * ring * cap for _k, dt, _s in sig)
+    assert mem.alias_size_in_bytes >= planes     # every plane is donated
+    assert mem.temp_size_in_bytes < 8 * ring * cap + 64 * cap
+
+
 @pytest.mark.parametrize("k", [1, 1000])
 @pytest.mark.parametrize("value_bits", [43, 64],
                          ids=["promised_43_bits", "no_promise"])
