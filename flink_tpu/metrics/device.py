@@ -114,11 +114,11 @@ class DeviceStats:
         self._fire_selects = 0
         self._fire_select_passes = 0
         self._fire_select_sort = 0
-        # ring fold accounting (PR 34): host-born batches folded on one
-        # chip and the ring rows they held a row for, which are the rows
-        # of each plane the fold program slices, scatters into and
-        # writes back (ops/segment_ops.ring_fold); counted on the host
-        # from the batch's own ring indices
+        # ring fold accounting (PR 34; the mesh operator's blocks since
+        # PR 36): host-born batches folded and the ring rows they held a
+        # row for, which are the rows of each plane the fold slices,
+        # scatters into and writes back (ops/segment_ops.ring_fold);
+        # counted on the host from the batch's own ring indices
         self._fold_batches = 0
         self._fold_ring_rows = 0
         # state reclaim accounting (PR 35): sweeps of the one-chip
@@ -431,7 +431,8 @@ class DeviceStats:
 
     @property
     def fold_counts(self) -> tuple[int, int]:
-        """(host-born batches folded, ring rows they touched)."""
+        """(host-born batches or mesh blocks folded, ring rows they
+        touched)."""
         with self._lock:
             return self._fold_batches, self._fold_ring_rows
 
@@ -1282,7 +1283,7 @@ def bind_device_metrics(registry) -> None:
     g.gauge("fire_selects_total", lambda: s.fire_select_counts[0])
     g.gauge("fire_select_passes_total", lambda: s.fire_select_counts[1])
     g.gauge("fire_select_sort_total", lambda: s.fire_select_counts[2])
-    # ring fold of the one-chip host-born ingest (prometheus:
+    # ring fold of the host-born ingest, one chip or mesh (prometheus:
     # flink_tpu_device_fold_batches_total /
     # flink_tpu_device_fold_ring_rows_total)
     g.gauge("fold_batches_total", lambda: s.fold_counts[0])

@@ -119,7 +119,11 @@ def ring_fold(kind: str, plane: jax.Array, ring_idx: jax.Array,
     view of the plane is taken: the TPU keeps a 2-D plane tiled, and
     ``plane.reshape(-1)`` around a scatter copies all of it into a flat
     buffer and back (three quarters of the one-chip ingest step until
-    PR 34). Each ring row the batch holds a valid row for is sliced out,
+    PR 34, 100 of the mesh step's 162 ms until PR 36). Every fold of a
+    batch into ring planes goes through here: the backend's ``jit_fold``,
+    the device-born step, and the mesh step on each shard's own plane
+    under ``shard_map``, inside its exchange rounds' ``while_loop``.
+    Each ring row the batch holds a valid row for is sliced out,
     folded by ``scatter_fold`` as the 1-D accumulator it is, and written
     back; the rows the batch does not touch are skipped on the device.
     A scatter costs the TPU by the update, masked or not, so a touched

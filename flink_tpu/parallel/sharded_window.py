@@ -8,7 +8,9 @@ contiguous key-group range (mesh.shard_ranges); a step is
     capacity-bounded `all_to_all` keyBy exchange over ICI
     (one round for a uniform batch; skew adds rounds)  ->
     device hash-table lookup-or-insert per shard      ->
-    one scatter-fold per aggregate into [ring, cap] pane accumulators
+    one fold per aggregate into the shard's [ring, cap] pane accumulators,
+    ring row by ring row (ops/segment_ops.ring_fold: the plane stays
+    tiled, no flat view of it is taken)
 
 which replaces the reference's per-record WindowOperator.processElement:278 /
 KeyGroupStreamPartitioner / Netty channel pipeline. Window fire is one pane
@@ -51,7 +53,7 @@ from ..metrics.device import instrumented_program_cache
 from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
 from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
     AGG_MERGES, INVERTIBLE_KINDS, merge_tree_build, merge_tree_update, \
-    pow2_ceil, scatter_fold
+    pow2_ceil, ring_fold
 from ..ops.topk import masked_topk_sort, threshold_topk
 from .exchange import bucket_capacity, exchange_round, plan_exchange
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
@@ -171,7 +173,7 @@ def _init_program(sig, rules: tuple):
 def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
                mesh: Mesh):
     """The jitted sharded fold step on ``mesh`` (see _step_program)."""
-    _, agg_sig, cap, ring = sig
+    _, agg_sig, _cap, ring = sig
     aggs = _aggs_from_sig(agg_sig)
     MP = max_parallelism
     # lint: sync-ok mesh.devices is a host numpy array of Device objects
@@ -223,15 +225,18 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
                     table, routed["__key__"], rvalid)
             with jax.named_scope("mesh.fold"):
                 n_dropped = jnp.sum(rvalid & ~ok).astype(jnp.int64)
-                ring_idx = jnp.where(ok, (routed["__pane__"] % ring),
-                                     0).astype(jnp.int32)
-                flat = ring_idx * cap + jnp.maximum(slots, 0)
+                # rows that found no slot are masked by `ok` in the fold.
+                # The routed rows are D segments in the order of their
+                # source slices, and the operator cuts a block into
+                # slices of consecutive rows: input in event-time order
+                # reaches the fold in event-time order, so a second ring
+                # row costs it one more chunk (ring_fold), not D.
+                ring_idx = (routed["__pane__"] % ring).astype(jnp.int32)
                 for a in aggs:
-                    vals = (jnp.ones(flat.shape[0], a.dtype)
+                    vals = (jnp.ones(slots.shape[0], a.dtype)
                             if a.kind == "count" else routed[a.name])
-                    accs[a.name] = scatter_fold(
-                        a.kind, accs[a.name].reshape(-1), flat, vals,
-                        ok).reshape(ring, cap)
+                    accs[a.name] = ring_fold(
+                        a.kind, accs[a.name], ring_idx, slots, vals, ok)
             return (r + 1, table, accs, dropped + n_dropped,
                     ok_count + jnp.sum(ok).astype(jnp.int64))
 
@@ -498,8 +503,11 @@ def _retire_program(sig):
     aggs = _aggs_from_sig(agg_sig)
 
     # donated like the step's state, so no second copy of the planes is
-    # allocated; the program still passes over both planes to clear the
-    # one row (31 ms at [16, 2^23] on a v5e: PERF.md section 5)
+    # allocated. It still costs a pass over both planes (31 ms at
+    # [16, 2^23] on a v5e), and the row write is not why: every program
+    # that takes an int64 plane splits it into 32-bit halves and joins
+    # them again, and a one-row dynamic_update_slice in this place reads
+    # 27.9 ms for 29.2 (PERF.md section 7, PR 36; ROADMAP S5c, S10)
     @functools.partial(jax.jit, donate_argnums=(0,))
     def retire(accs: dict, row: jax.Array):
         return {a.name: accs[a.name].at[:, row].set(

@@ -726,16 +726,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                             seq=self._batch_seq,
                             total=(self.stage_s, "ingest"), **attrs)
 
-    def _note_fold(self, ring_idx: np.ndarray) -> int:
-        """Ring rows a host-born batch holds a row for, which are the
-        rows of each plane its fold touches: counted (DEVICE_STATS
-        ``fold_ring_rows_total`` / ``fold_batches_total``) and returned
-        for the dispatch span's ``ring_rows``."""
-        rows = int(np.count_nonzero(
-            np.bincount(ring_idx, minlength=self._ring)))
-        DEVICE_STATS.note_fold(rows)
-        return rows
-
     def _to_device_batch(self, batch: RecordBatch) -> DeviceRecordBatch:
         ts = batch.timestamps
 

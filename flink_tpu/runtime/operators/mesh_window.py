@@ -289,19 +289,31 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         if self._agg is None or self._buf_n == 0:
             return
         full = self._n_devices * self._device_batch
+        # a block's ring rows are counted before its window/Upload
+        # starts, as the one-chip operator counts a batch's before its
+        # stages: the span stays the concatenation, the cut and the copies
+        ring_idx = np.concatenate(self._buf_panes) % self._ring
         staged = None
         pos, total = 0, self._buf_n
         while total - pos >= full or (pad and total > pos):
             n_valid = min(full, total - pos)
             self._block_seq += 1
+            ring_rows = self._note_fold(ring_idx[pos:pos + n_valid])
             with self._upload_stage() as up:
                 if staged is None:
                     staged = self._concat_staged()
-                block = self._upload_block(staged, pos, n_valid)
+                rows = slice(pos, pos + n_valid)
+                if ring_rows > 2:
+                    # out-of-order input goes up sorted by ring row, as
+                    # on one chip (DeviceWindowAggOperator._fold): the
+                    # routed rows keep a slice's order, so each ring
+                    # row's updates then lie together on every shard
+                    rows = pos + np.argsort(ring_idx[rows], kind="stable")
+                block = self._upload_block(staged, rows, n_valid)
                 nbytes = pytree_nbytes(block)
                 up.set("bytes", nbytes)
                 DEVICE_STATS.note_h2d(nbytes, n_valid)
-            with self._dispatch_stage():
+            with self._dispatch_stage(ring_rows=ring_rows):
                 self._step_block(*block)
             pos += n_valid
         if pos == 0:
@@ -321,31 +333,33 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         return TRACER.stage("window", "Upload", seq=self._block_seq,
                             total=(self.stage_s, "ingest"))
 
-    def _dispatch_stage(self):
+    def _dispatch_stage(self, **attrs):
         """window/IngestDispatch: the host's time to enqueue the block's
         step (the devices run it later) and to look at the pressure
-        probe."""
+        probe; ``ring_rows`` from the caller."""
         return TRACER.stage("window", "IngestDispatch", seq=self._block_seq,
-                            total=(self.stage_s, "ingest"))
+                            total=(self.stage_s, "ingest"), **attrs)
 
     def _concat_staged(self) -> tuple:
         return (np.concatenate(self._buf_keys),
                 np.concatenate(self._buf_panes),
                 {n: np.concatenate(vs) for n, vs in self._buf_cols.items()})
 
-    def _upload_block(self, staged: tuple, pos: int, n_valid: int) -> tuple:
-        """Rows [pos, pos + n_valid) of the staged columns as device
-        arrays of shape [D, B] (keys, cols, panes, valid), zero-padded
-        where the block is not full."""
+    def _upload_block(self, staged: tuple, rows, n_valid: int) -> tuple:
+        """The ``n_valid`` ``rows`` of the staged columns (a slice, or
+        the indices of an out-of-order block in the order it goes up in)
+        as device arrays of shape [D, B] (keys, cols, panes, valid),
+        zero-padded where the block is not full."""
         D, B = self._n_devices, self._device_batch
         full = D * B
 
         def cut(col: np.ndarray) -> jax.Array:
+            picked = col[rows]
             if n_valid < full:
                 buf = np.zeros(full, col.dtype)
-                buf[:n_valid] = col[pos:pos + n_valid]
+                buf[:n_valid] = picked
             else:
-                buf = col[pos:pos + full]
+                buf = picked
             return jnp.asarray(buf.reshape(D, B))
 
         keys, panes, cols = staged

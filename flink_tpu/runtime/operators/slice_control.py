@@ -399,6 +399,21 @@ class SliceControlPlane:
     def _fire(self, p_end: int) -> None:
         raise NotImplementedError
 
+    def _note_fold(self, ring_idx: np.ndarray) -> int:
+        """Ring rows a host-born batch (a mesh block's valid rows) holds
+        a row for, which are the rows of each plane its fold slices,
+        scatters into and writes back (ops/segment_ops.ring_fold):
+        counted (DEVICE_STATS ``fold_ring_rows_total`` /
+        ``fold_batches_total``, from the batch's own ring indices, no
+        device sync) and returned for the dispatch span's
+        ``ring_rows``."""
+        from ...metrics.device import DEVICE_STATS
+
+        rows = int(np.count_nonzero(
+            np.bincount(ring_idx, minlength=self._ring)))
+        DEVICE_STATS.note_fold(rows)
+        return rows
+
     @property
     def late_dropped(self) -> int:
         return self._late_dropped

@@ -708,6 +708,55 @@ def test_mesh_blocks_have_upload_and_dispatch_stage_spans():
                                           for u in named["Upload"]}
 
 
+@pytest.mark.parametrize("case, ts, want", [
+    # a [4, 16] block is 64 rows; panes are 1,000 ms, the ring 8
+    ("inside_one_pane", list(range(3000, 3064)), [1]),
+    ("across_a_panes_edge", list(range(3968, 4032)), [2]),
+    # 64 + 20 rows: the padded final block counts its 20 valid rows'
+    # pane only (the padding is pane 0, ring row 0; theirs is ring row 5)
+    ("padded_final_block", list(range(3000, 3064)) + [5500] * 20, [1, 1]),
+    ("padded_across_an_edge",
+     list(range(3000, 3064)) + [5990] * 10 + [6010] * 10, [1, 2]),
+    # out of order over five panes: counted as it arrives (the operator
+    # sorts such a block by ring row before it cuts it into slices)
+    ("shuffled_over_five_panes",
+     [1000 * (1 + (7 * i) % 5) + i for i in range(64)], [5]),
+])
+def test_mesh_blocks_count_the_ring_rows_their_fold_touches(case, ts, want):
+    """The mesh operator feeds the fold's two counters and the dispatch
+    span's `ring_rows` as the one-chip operator does: per block, the ring
+    rows its VALID rows fall in (what `ring_fold` slices and writes back
+    on every shard), from the staged panes."""
+    from flink_tpu.metrics.device import DEVICE_STATS
+    from flink_tpu.metrics.tracing import TRACER
+    from flink_tpu.runtime import OneInputOperatorTestHarness
+    from flink_tpu.window import TumblingEventTimeWindows
+
+    elements = [(i % 37, 1) for i in range(len(ts))]
+    TRACER.reset()
+    try:
+        h = OneInputOperatorTestHarness(
+            _mesh_op(TumblingEventTimeWindows.of(1000), 4, device_batch=16,
+                     ring_size=8), schema=SCHEMA)
+        before = DEVICE_STATS.snapshot()
+        h.process_elements(elements, ts)
+        h.process_watermark(10**9)
+        h.operator.finish()
+        after = DEVICE_STATS.snapshot()
+        spans = TRACER.retained_spans()
+    finally:
+        TRACER.reset()
+    assert sum(int(v) for _k, v in h.get_output()) == len(ts)
+    assert after["fold_batches_total"] - before["fold_batches_total"] \
+        == len(want)
+    assert after["fold_ring_rows_total"] - before["fold_ring_rows_total"] \
+        == sum(want)
+    dispatches = sorted((s for s in spans if (s.scope, s.name)
+                         == ("window", "IngestDispatch")),
+                        key=lambda s: s.attributes["seq"])
+    assert [d.attributes["ring_rows"] for d in dispatches] == want
+
+
 class TestMeshDonatedState:
     """The step consumes its state. Every path that takes a state out of
     the operator or puts one in must still give exact rows."""

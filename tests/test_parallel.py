@@ -445,3 +445,114 @@ def test_mesh_step_has_no_64bit_nonsum_collective(n_dev):
     wide = [(e.primitive.name, v.aval.dtype.name) for e in collectives
             for v in e.invars if v.aval.dtype.itemsize > 4]
     assert not wide, wide
+
+
+#: the ring of the fold's parity test
+_FOLD_RING = 4
+
+
+def _fold_block(name, rng, D, B, hot_shard_keys):
+    """(keys, panes, valid) of the [D, B] block the parity test calls
+    ``name``; ``hot_shard_keys`` are keys one shard owns."""
+    keys = rng.randint(0, 300, (D, B)).astype(np.int64)
+    panes = np.full((D, B), 9, np.int64)
+    valid = np.ones((D, B), bool)
+    if name == "no_ring_row":
+        valid[:] = False
+    elif name == "two_ring_rows":
+        # event-time order: the later pane at the tail of every slice
+        panes[:, B - B // 3:] = 10
+    elif name == "every_ring_row":
+        panes = rng.randint(8, 8 + 2 * _FOLD_RING, (D, B)).astype(np.int64)
+    elif name == "padded_tail":
+        panes[:, B // 2:] = 10
+        valid.reshape(-1)[D * B - (D * B) // 3:] = False
+    elif name == "one_key":
+        keys[:] = 77
+        panes = rng.randint(8, 8 + _FOLD_RING, (D, B)).astype(np.int64)
+    elif name == "hot_shard":
+        keys = np.resize(hot_shard_keys, (D, B))
+        panes[:, B // 2:] = 10
+    else:
+        assert name == "one_ring_row"
+    return keys, panes, valid
+
+
+_FOLD_KINDS = {"total": "sum", "n": "count", "low": "min", "high": "max"}
+
+
+def _fold_per_record(folded: dict, keys, panes, vals, valid) -> None:
+    """The reference: one record at a time into {(key, ring row): [sum,
+    count, min, max]}."""
+    for k, p, v, ok in zip(keys.ravel(), panes.ravel(), vals.ravel(),
+                           valid.ravel()):
+        if not ok:
+            continue
+        cell = folded.setdefault((int(k), int(p) % _FOLD_RING),
+                                 [0, 0, int(v), int(v)])
+        cell[0] += int(v)
+        cell[1] += 1
+        cell[2] = min(cell[2], int(v))
+        cell[3] = max(cell[3], int(v))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("block", [
+    "no_ring_row", "one_ring_row", "two_ring_rows", "every_ring_row",
+    "padded_tail", "one_key", "hot_shard"])
+def test_mesh_step_folds_a_block_like_a_per_record_fold(block, n_dev):
+    """The step's fold (`ring_fold` on every shard's [ring, capacity]
+    planes, inside the exchange rounds' `while_loop`) against a per-record
+    numpy fold, exact, for sum, count, min and max at once: a block that
+    touches 0, 1, 2 and all ring rows, a padded tail, every row on one
+    key, and a hot shard whose rows need a second exchange round, so that
+    the fold runs again on the planes round one left. Each case folds
+    into planes an earlier block has already written."""
+    from flink_tpu.parallel import bucket_capacity
+
+    D, B, cap = n_dev, 64, 1 << 10
+    agg = ShardedWindowAgg(
+        make_mesh(n_dev),
+        [AggDef(name, kind, jnp.int64) for name, kind in _FOLD_KINDS.items()],
+        capacity=cap, ring=_FOLD_RING, max_parallelism=MP)
+    pool = np.arange(20_000, dtype=np.int64)
+    groups = key_groups_for_hash_batch(hash_batch(pool), MP)
+    owner = agg.shard_ranges[-1]
+    hot_shard_keys = pool[(groups >= owner.start)
+                          & (groups <= owner.end)][:41]
+    rng = np.random.RandomState(36)
+    folded: dict = {}
+    state = agg.init_state()
+    for name in ("every_ring_row", block):
+        keys, panes, valid = _fold_block(name, rng, D, B, hot_shard_keys)
+        vals = rng.randint(-1000, 1000, (D, B)).astype(np.int64)
+        _fold_per_record(folded, keys, panes, vals, valid)
+        cols = {n: jnp.asarray(vals) for n, kind in _FOLD_KINDS.items()
+                if kind != "count"}
+        state, processed, rounds = agg.step(
+            state, jnp.asarray(keys), cols, jnp.asarray(panes),
+            jnp.asarray(valid))
+        assert int(processed) == int(valid.sum())
+    if block in ("hot_shard", "one_key") and n_dev > 1:
+        # every slice's rows are bound for one shard
+        assert int(rounds) == -(-B // bucket_capacity(B, n_dev)) > 1
+    else:
+        assert int(rounds) == (block != "no_ring_row")
+    assert int(jax.device_get(state.dropped).sum()) == 0
+
+    table = np.asarray(jax.device_get(state.table))
+    planes = {n: np.asarray(jax.device_get(state.accs[n]))
+              for n in _FOLD_KINDS}
+    got = {}
+    for d, s in zip(*np.nonzero(table != np.iinfo(np.int64).max)):
+        for r in range(_FOLD_RING):
+            if planes["n"][d, r, s]:
+                got[(int(table[d, s]), r)] = [
+                    int(planes[n][d, r, s]) for n in _FOLD_KINDS]
+    assert got == folded
+    # every cell no record fell in still holds its identity
+    assert planes["n"].sum() == sum(c[1] for c in folded.values())
+    untouched = planes["n"] == 0
+    assert (planes["total"][untouched] == 0).all()
+    assert (planes["low"][untouched] == np.iinfo(np.int64).max).all()
+    assert (planes["high"][untouched] == np.iinfo(np.int64).min).all()

@@ -111,6 +111,50 @@ def test_mesh_step_donates_its_state_at_the_benchmark_shape(v5e_devices):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 8e9
 
 
+def test_mesh_step_compiles_without_a_plane_copy_at_the_benchmark_shape(
+        v5e_devices):
+    """The mesh step of q5-16m-mesh4 ([4, 65536] rows against two
+    [4, 16, 2^23] int64 planes), lowered as the operator dispatches it,
+    donation included: every aggregate folds through `ring_fold` on the
+    shard's own tiled plane, so no `copy` holds a whole shard plane and
+    the only loops that carry one are the exchange rounds' `while_loop`
+    (the planes are its carry) and, inside it, the fold's one walk over
+    the ring an aggregate. `plane.reshape(-1)` around the scatter was four
+    more: relayout `while`s of `dynamic-update-slice` between `T(8,128)`
+    and the flat `T(1024)`, out and back for each plane (100 of the
+    step's 162 ms until PR 36)."""
+    import re
+
+    agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
+    compiled = agg.step_program().lower(
+        *args, agg._base_start, agg._base_len).compile()
+    hlo = compiled.as_text()
+    assert "HloModule jit_step" in hlo        # the name the traces anchor on
+    ring, cap = agg.ring, agg.capacity
+    whole = re.compile(rf"\[(1,)?{ring},{cap}\]|\[{ring * cap}\]")
+    rounds = 'op_name="jit(step)/shard_map/while"'
+    ring_walk = 'op_name="jit(step)/shard_map/while/body/mesh.fold/while"'
+    loops = []
+    for line in hlo.splitlines():
+        if re.search(r" (copy|while)\(", line) and whole.search(line):
+            assert " while(" in line and f"[{ring * cap}]" not in line, \
+                line[:300]
+            loops.append(next((name for name in (rounds, ring_walk)
+                               if name in line), line[:300]))
+    assert sorted(loops) == sorted([rounds] + [ring_walk] * len(agg.aggs))
+    for kind in ("sum", "count"):
+        assert re.search(
+            rf"shard_map/while/body/mesh\.fold/[^\"]*fold\.scatter/fold\.{kind}/",
+            hlo), kind
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _SHARD_BYTES - 4096
+    # beside the shard's state: the int64 planes' 32-bit halves (split
+    # once at the program's entry, joined once at its exit), the table's,
+    # and the ring row of a plane that is out being folded
+    assert mem.temp_size_in_bytes < 1.01 * (
+        _SHARD_BYTES + 2 * 8 * cap)
+
+
 def _operand_elements(hlo: str, op: str):
     """Element counts of the array operands-or-results named on every
     HLO line that applies ``op`` (``sort``, ``all-reduce``, ...)."""
