@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import re
 import threading
 import time
 from typing import Any, Callable, Optional
@@ -29,7 +30,9 @@ from .profiler import DEVICE_LEDGER
 
 __all__ = ["DeviceStats", "DEVICE_STATS", "instrumented_program_cache",
            "bind_device_metrics", "set_compile_tracer", "pytree_nbytes",
-           "PROGRAM_AUDIT", "ProgramAuditEntry", "clear_program_audit"]
+           "PROGRAM_AUDIT", "ProgramAuditEntry", "clear_program_audit",
+           "REGION_SCOPES", "PATH_SCOPES", "UNNAMED", "classify_hlo",
+           "program_regions"]
 
 
 class DeviceStats:
@@ -918,7 +921,13 @@ def _record_program_audit(scope, fn, args, kwargs, build_key) -> None:
             shape = getattr(x, "shape", None)
             dtype = getattr(x, "dtype", None)
             if shape is not None and dtype is not None:
-                return jax.ShapeDtypeStruct(tuple(shape), dtype)
+                # with the sharding of an array that is committed to its
+                # devices, or a program over a mesh lowers again to
+                # another module than the one that ran
+                return jax.ShapeDtypeStruct(
+                    tuple(shape), dtype,
+                    sharding=(x.sharding if getattr(x, "committed", False)
+                              else None))
             return x
 
         PROGRAM_AUDIT.append(ProgramAuditEntry(
@@ -928,6 +937,288 @@ def _record_program_audit(scope, fn, args, kwargs, build_key) -> None:
             build_key, _program_source(fn)))
     except Exception:
         pass
+
+
+# --------------------------------------------------------------------------
+# Named regions of the device programs.
+#
+# Every ``jax.named_scope`` in flink_tpu/ is a name of this vocabulary. A
+# REGION is what a device operation's time is booked under: the innermost
+# region scope on the operation's name path (HLO ``op_name``), whatever
+# program holds it and whatever number the compiler gave its fusion. The
+# int64 planes' split into 32-bit halves and their join carry no path (the
+# x64 rewriter makes them) and go by their custom-call target.
+
+#: scope -> the region it names
+REGION_SCOPES = {
+    "probe.window0": "probe.window0", "probe.tail": "probe.tail",
+    "fold.count": "fold.count", "fold.sum": "fold.sum",
+    "fold.max": "fold.max", "fold.min": "fold.min", "fold.row": "fold.row",
+    "exchange.pack": "exchange.pack",
+    # the all-to-alls sit directly under it; what else does is packing
+    "mesh.exchange": "exchange.collective",  # lint: key-ok a region scope
+    "mesh.plan": "mesh.plan",  # lint: key-ok a region scope
+    "mesh.sync": "mesh.sync",  # lint: key-ok a region scope
+    "fire.merge": "fire.merge", "fire.topk": "fire.topk",
+    "fire.global": "fire.global", "fire.reset": "fire.reset",
+    "fire.retire": "fire.retire",
+    "reclaim.live": "reclaim.live", "reclaim.rehome": "reclaim.rehome",
+    "reclaim.remap": "reclaim.remap",
+}
+#: scopes that name no region of their own: they lie around or beneath
+#: the region scopes, for the path patterns that count the probe's rounds
+#: (``probe.claim``) and time the mesh step's shared part (``mesh.probe``,
+#: ``mesh.fold``), and for the lowering tests
+PATH_SCOPES = frozenset({
+    "probe.gather", "probe.claim", "probe.compact", "fold.scatter",
+    "mesh.probe", "mesh.fold"})  # lint: key-ok scopes, not config keys
+UNNAMED = "unnamed"
+
+_X64_TARGETS = {"X64SplitLow": "x64.split", "X64SplitHigh": "x64.split",
+                "X64Combine": "x64.join"}
+_X64_REGIONS = frozenset(_X64_TARGETS.values())
+#: what the compiler adds to move an operand between memory spaces
+_MOVES = frozenset({"copy", "copy-start", "copy-done"})
+#: instructions that only hold other computations: their bodies'
+#: instructions take regions, they take none
+_WRAPPERS = frozenset({"while", "conditional", "call"})
+_COLLECTIVES = ("all-to-all", "all-reduce", "all-gather",
+                "collective-permute", "reduce-scatter")
+
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*)$")
+_HLO_OPCODE = re.compile(r"\s*([\w\-]+)\(")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_TARGET = re.compile(r'custom_call_target="([^"]*)"')
+_HLO_METADATA = re.compile(r"metadata=\{[^}]*\}")
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+#: instructions that run nothing
+_SILENT = frozenset({"parameter", "constant", "tuple", "get-tuple-element",
+                     "bitcast"})
+
+
+def _closing(text: str, start: int) -> int:
+    """Index just past the parenthesis that closes the one at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        c = text[i]
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def _scope_region(op_name: str, opcode: str) -> Optional[str]:
+    """The region of the innermost region scope on a name path."""
+    for part in reversed(op_name.split("/")):
+        region = REGION_SCOPES.get(part)
+        if region is None:
+            continue
+        if region == "exchange.collective" \
+                and not opcode.startswith(_COLLECTIVES):
+            return "exchange.pack"
+        return region
+    return None
+
+
+def classify_hlo(text: str) -> dict[str, str]:
+    """``{instruction name: region}`` of one compiled program, from its
+    HLO text (``compiled.as_text()``). Pure: text in, map out. The map
+    holds the instructions the device runs as operations of their own:
+    those of the entry computation and of the loop bodies, conditions and
+    branches under it, not the insides of a fusion.
+
+    1. A custom-call the x64 rewriter made (``X64SplitLow`` /
+       ``X64SplitHigh`` / ``X64Combine``) is ``x64.split`` / ``x64.join``.
+    2. An instruction whose ``op_name`` holds a region scope takes the
+       innermost one's region.
+    3. A fusion without one takes the region the instructions of its
+       fused computation agree on.
+    4. An instruction without a name PATH (no ``op_name``, or the bare
+       primitive the x64 rewriter leaves on the halves of a 64-bit
+       scatter, and what the compiler adds to move an operand between
+       memory spaces) takes the region of the instructions its result
+       reaches, when they are of one region, else of the instructions
+       that feed it, when they are, else of the loop or branch that
+       holds it. A split's or a join's region goes on to such moves
+       only: what computes between a split and a join (the retire's row
+       writes) takes the scope's region beside it.
+    5. Everything else is ``unnamed``.
+
+    ``while`` / ``conditional`` / ``call`` hold other computations and get
+    no entry: their bodies' instructions do. Parameters, constants and
+    the tuple plumbing run nothing and get none either."""
+    comps: dict[str, list[tuple]] = {}
+    entry = current = None
+    for line in text.splitlines():
+        if not line or line[0] == "}":
+            continue
+        if not line[0].isspace():
+            m = _HLO_COMPUTATION.match(line)
+            current = comps.setdefault(m.group(1), []) if m else None
+            if m and line.startswith("ENTRY"):
+                entry = m.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None or current is None:
+            continue
+        name, rest = m.groups()
+        # the result type (a tuple type holds spaces), then the opcode
+        at = _closing(rest, 0) if rest.startswith("(") else rest.find(" ")
+        op = _HLO_OPCODE.match(rest, at)
+        if op is None:
+            continue
+        end = _closing(rest, op.end() - 1)
+        attrs = rest[end:]
+        path = _HLO_OP_NAME.search(attrs)
+        target = _HLO_TARGET.search(attrs)
+        current.append((name, op.group(1),
+                        _HLO_REF.findall(rest, op.end(), end),
+                        path.group(1) if path else "",
+                        target.group(1) if target else "",
+                        _HLO_REF.findall(_HLO_METADATA.sub("", attrs))))
+
+    def own(opcode, path, target) -> Optional[str]:
+        return _X64_TARGETS.get(target) or _scope_region(path, opcode)
+
+    def agreed(comp: str, seen: frozenset) -> Optional[str]:
+        """What the instructions of a fused computation agree on."""
+        found = set()
+        for _name, opcode, _refs, path, target, called in comps.get(comp, ()):
+            r = own(opcode, path, target)
+            if r is None:
+                for c in called:
+                    if c in comps and c not in seen:
+                        r = r or agreed(c, seen | {c})
+            if r is not None:
+                found.add(r)
+        return found.pop() if len(found) == 1 else None
+
+    regions: dict[str, str] = {}
+    # a loop or a branch has no entry, but what feeds it is for it
+    wrappers: dict[str, str] = {}
+    held_by: dict[str, str] = {entry: UNNAMED}    # computation -> region
+    pathless: dict[str, list[str]] = {}           # computation -> names
+    todo = [entry]
+    while todo:
+        comp = todo.pop()
+        for name, opcode, _refs, path, target, called in comps.get(comp, ()):
+            if opcode in _WRAPPERS:
+                wrappers[name] = _scope_region(path, opcode) or held_by[comp]
+                for c in called:
+                    if c not in held_by:
+                        held_by[c] = wrappers[name]
+                        todo.append(c)
+                continue
+            if opcode in _SILENT:
+                continue
+            r = own(opcode, path, target)
+            if r is None and opcode == "fusion":
+                r = agreed(called[0], frozenset(called)) if called else None
+            if r is None and "/" not in path:
+                pathless.setdefault(comp, []).append(name)
+            regions[name] = r or UNNAMED
+
+    # rule 4, over the data flow of each computation that holds a
+    # pathless instruction: through other pathless instructions and the
+    # tuple plumbing, to the first instructions that have a region
+    for comp, names in pathless.items():
+        operands = {i[0]: i[2] for i in comps[comp]}
+        users: dict[str, list[str]] = {}
+        for name, refs in operands.items():
+            for ref in refs:
+                users.setdefault(ref, []).append(name)
+        through = set(names) | {i[0] for i in comps[comp]
+                                if i[1] in _SILENT}
+        # a split's or a join's region goes on to the moves between
+        # memory spaces beside it and to nothing that computes: the
+        # retire's row writes sit between a split and a join and are
+        # neither
+        moves = {i[0] for i in comps[comp] if i[1] in _MOVES}
+
+        def reach(start: str, edges) -> set:
+            found, seen, todo = set(), {start}, list(edges.get(start, ()))
+            while todo:
+                n = todo.pop()
+                if n in seen:
+                    continue
+                seen.add(n)
+                r = regions.get(n) or wrappers.get(n, UNNAMED)
+                if r in _X64_REGIONS and start not in moves:
+                    continue
+                if r != UNNAMED:
+                    found.add(r)
+                elif n in through:
+                    todo.extend(edges.get(n, ()))
+            return found
+
+        for name in names:
+            for found in (reach(name, users), reach(name, operands),
+                          {held_by[comp]}):
+                if len(found) == 1:     # none, or two that disagree: on
+                    r = found.pop()
+                    # what feeds a collective is not the collective
+                    regions[name] = ("exchange.pack"
+                                     if r == "exchange.collective" else r)
+                    break
+    return regions
+
+
+def _executable_fingerprint(compiled) -> str:
+    """What the runtime calls the executable's fingerprint, as text (32
+    bytes in hex on a TPU, a decimal number on the CPU); "" where it gives
+    none. It tells two programs of one module name apart; it is NOT the
+    number a device trace prints after a module's name
+    (``jit_step(8252...)``), which no Python API yields."""
+    try:
+        fp = compiled.runtime_executable().fingerprint
+    except Exception:  # noqa: BLE001 - an observer never fails its caller
+        return ""
+    if isinstance(fp, bytes):
+        return fp.decode("ascii") if fp.isdigit() else fp.hex()
+    return str(fp or "")
+
+
+def program_regions() -> dict[str, dict[str, str]]:
+    """``{"<hlo module name>(<fingerprint>)": {instruction: region}}`` of
+    every program in ``PROGRAM_AUDIT``: from an xprof op name (the head
+    of an ``XLA Ops`` event's name, ``custom-call.15``) to the region its
+    time belongs to. The module name is the one a device trace shows;
+    the fingerprint is the executable's own (``_executable_fingerprint``)
+    and keeps apart the programs that share a module name (the probe
+    with and without its hand-over, a fire and its incremental twin).
+
+    Computed WHEN ASKED (the end of a traced run, a test, an operator's
+    shell), never in a dispatch: each audited program is lowered again
+    for the abstract arguments of its first dispatch and compiled, which
+    the persistent compile cache serves in a process that has run it,
+    and its HLO text goes through ``classify_hlo``. A map is valid only
+    for the executable it was made from: a reader of a trace takes, of
+    the maps of a module name, the one that holds every operation the
+    trace shows of that program (``benchmarks/harness/region_map.pair``).
+    A program that cannot be lowered this way (a plain Python builder,
+    buffers closed over) has no map."""
+    out: dict[str, dict[str, str]] = {}
+    for entry in list(PROGRAM_AUDIT):
+        lower = getattr(entry.fn, "lower", None)
+        if lower is None:
+            continue
+        try:
+            compiled = lower(*entry.abstract_args,
+                             **entry.abstract_kwargs).compile()
+            text = compiled.as_text()
+        except Exception:  # noqa: BLE001 - an observer never fails
+            continue
+        module = re.match(r"HloModule\s+([\w.\-]+)", text)
+        if module is None:
+            continue
+        key = f"{module.group(1)}({_executable_fingerprint(compiled)})"
+        if key not in out:
+            out[key] = classify_hlo(text)
+    return out
 
 
 class _TimedProgram:

@@ -294,24 +294,31 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     probed row of a batch below the compaction width), and 1 if that loop
     started at full width else 0.
     """
-    mask = jnp.uint32(table_keys.shape[0] - 1)
-    h0 = hash_keys_device(keys) & mask
     n = keys.shape[0]
     widths = _tail_widths(n)
-    done = (jnp.zeros(n, bool) if valid is None else ~valid.astype(bool))
-    base = jnp.zeros(n, jnp.uint32)
-    slot = jnp.full(n, -1, jnp.int32)
-    rows = jnp.sum(~done, dtype=jnp.int32)
+    # the hashes and the rows' start state belong to whatever reads the
+    # first window: the read-only one, or the claiming loop's
+    with jax.named_scope("probe.window0" if widths else "probe.tail"):
+        mask = jnp.uint32(table_keys.shape[0] - 1)
+        h0 = hash_keys_device(keys) & mask
+        done = (jnp.zeros(n, bool) if valid is None
+                else ~valid.astype(bool))
+        base = jnp.zeros(n, jnp.uint32)
+        slot = jnp.full(n, -1, jnp.int32)
+        rows = jnp.sum(~done, dtype=jnp.int32)
     if not widths:
         n_tail, wide = rows, jnp.int32(1)
-        table, _base, slot, _done = _claim_loop(table_keys, keys, h0, mask,
-                                                base, slot, done)
+        with jax.named_scope("probe.tail"):
+            table, _base, slot, _done = _claim_loop(
+                table_keys, keys, h0, mask, base, slot, done)
     else:
-        hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base, mask)
-        slot = jnp.where((~done) & hit, fslot, slot)
-        done = done | hit
-        base = _advance(base, done, pos_empty)
-        n_tail = jnp.sum(~done, dtype=jnp.int32)
+        with jax.named_scope("probe.window0"):
+            hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base,
+                                               mask)
+            slot = jnp.where((~done) & hit, fslot, slot)
+            done = done | hit
+            base = _advance(base, done, pos_empty)
+            n_tail = jnp.sum(~done, dtype=jnp.int32)
 
         def narrow_loop(T, n_left, table, base, slot, done):
             with jax.named_scope("probe.compact"):
@@ -342,10 +349,11 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
                                table, base, slot, done)
 
         # the narrowest loop that holds the tail, else the full width
-        level = sum((n_tail > T).astype(jnp.int32) for T in widths)
-        wide = (level == len(widths)).astype(jnp.int32)
-        table, slot = jax.lax.switch(
-            level, [partial(narrow_loop, T, n_tail) for T in widths]
-            + [wide_loop], table_keys, base, slot, done)
+        with jax.named_scope("probe.tail"):
+            level = sum((n_tail > T).astype(jnp.int32) for T in widths)
+            wide = (level == len(widths)).astype(jnp.int32)
+            table, slot = jax.lax.switch(
+                level, [partial(narrow_loop, T, n_tail) for T in widths]
+                + [wide_loop], table_keys, base, slot, done)
     out = (table, slot, slot >= 0)
     return (*out, jnp.stack([rows, n_tail, wide])) if stats else out

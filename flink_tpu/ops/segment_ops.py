@@ -96,15 +96,14 @@ def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
                  values: jax.Array, valid: jax.Array) -> jax.Array:
     """Fold a batch into a flat accumulator: acc[flat_idx] op= values,
     masked by ``valid`` (invalid rows fold the identity into slot 0).
-    The scatter itself sits in a scope named after its kind (``fold.max``
-    under ``fold.scatter``), so a trace tells a max fold from an add fold
-    whatever program holds them."""
-    with jax.named_scope("fold.scatter"):
+    The scatter and its masking sit in a scope named after the kind
+    (``fold.max`` under ``fold.scatter``), so a trace tells a max fold
+    from an add fold whatever program holds them."""
+    with jax.named_scope("fold.scatter"), jax.named_scope(f"fold.{kind}"):
         identity = AGG_INITS[kind](acc.dtype)
         idx = jnp.where(valid, flat_idx, 0)
         vals = jnp.where(valid, values.astype(acc.dtype), identity)
-        with jax.named_scope(f"fold.{kind}"):
-            return AGG_FOLDS[kind](acc, idx, vals)
+        return AGG_FOLDS[kind](acc, idx, vals)
 
 
 #: rows of a batch that ``ring_fold`` scatters at a time
@@ -133,14 +132,18 @@ def ring_fold(kind: str, plane: jax.Array, ring_idx: jax.Array,
     edge; a batch shuffled over k ring rows pays k times, which is why
     the host-born operator sorts such a batch by ring row first
     (``DeviceWindowAggOperator._fold``). Any number of touched rows is
-    right, 0 to ``ring``."""
+    right, 0 to ``ring``. What a ring row costs beside its scatters (the
+    mask, the slice, the chunk walk, the write-back) is the region
+    ``fold.row``."""
     n = slots.shape[0]
     if n == 0:
         return plane
     chunk = min(_FOLD_CHUNK, n)
-    ring_idx = ring_idx.astype(jnp.int32)
-    lane = jnp.arange(chunk, dtype=jnp.int32)
+    with jax.named_scope("fold.row"):
+        ring_idx = ring_idx.astype(jnp.int32)
+        lane = jnp.arange(chunk, dtype=jnp.int32)
 
+    @jax.named_scope("fold.row")
     def fold_row(r, plane):
         mine = valid & (ring_idx == r)
 

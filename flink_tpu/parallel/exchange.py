@@ -140,20 +140,24 @@ def exchange_round(axis_name: str, n_dev: int, cap: int, plan: ExchangePlan,
     by `plan.order`. Returns ([n_dev*cap, ...] routed pytree, [n_dev*cap]
     valid mask). Safe inside lax.while_loop with a pmax-uniform trip count.
     """
-    sub = plan.rank - r * jnp.int32(cap)
-    in_round = (sub >= 0) & (sub < cap) & (plan.sd < n_dev)
-    # Out-of-round rows get an out-of-bounds slot so mode="drop" discards
-    # them (negative indices would wrap under the default mode).
-    slot = jnp.where(in_round, sub, jnp.int32(cap))
+    # the send buffers are the region exchange.pack; the all-to-alls stay
+    # directly under the caller's scope
+    with jax.named_scope("exchange.pack"):
+        sub = plan.rank - r * jnp.int32(cap)
+        in_round = (sub >= 0) & (sub < cap) & (plan.sd < n_dev)
+        # Out-of-round rows get an out-of-bounds slot so mode="drop"
+        # discards them (negative indices would wrap under the default
+        # mode).
+        slot = jnp.where(in_round, sub, jnp.int32(cap))
 
-    send_valid = jnp.zeros((n_dev, cap), bool).at[plan.sd, slot].set(
-        in_round, mode="drop")
+        send_valid = jnp.zeros((n_dev, cap), bool).at[plan.sd, slot].set(
+            in_round, mode="drop")
 
-    def scatter(col):
-        buf = jnp.zeros((n_dev, cap) + col.shape[1:], col.dtype)
-        return buf.at[plan.sd, slot].set(col, mode="drop")
+        def scatter(col):
+            buf = jnp.zeros((n_dev, cap) + col.shape[1:], col.dtype)
+            return buf.at[plan.sd, slot].set(col, mode="drop")
 
-    send = jax.tree.map(scatter, ordered_payload)
+        send = jax.tree.map(scatter, ordered_payload)
     if n_dev == 1:
         recv, recv_valid = send, send_valid
     else:

@@ -154,12 +154,17 @@ def _per_mesh(make):
     only holds the executables that close over a mesh."""
     bound: dict = {}
 
-    def dispatch(mesh: Mesh, *args):
+    def program(mesh: Mesh):
         prog = bound.get(mesh)
         if prog is None:
             prog = bound[mesh] = make(mesh)
-        return prog(*args)
+        return prog
 
+    def dispatch(mesh: Mesh, *args):
+        return program(mesh)(*args)
+
+    # what `metrics/device.program_regions` asks of an audited program
+    dispatch.lower = lambda mesh, *args: program(mesh).lower(*args)
     return dispatch
 
 
@@ -306,9 +311,10 @@ def _fire_program(sig):
             sub = jnp.where(rows_valid[None, :, None], sub, ident)
             return AGG_MERGES[kind](sub, axis=1)
 
-        out = {a.name: merge(a.kind, state.accs[a.name]) for a in aggs}
-        count = out[count_name]
-        emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
+        with jax.named_scope("fire.merge"):
+            out = {a.name: merge(a.kind, state.accs[a.name]) for a in aggs}
+            count = out[count_name]
+            emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
         return out, emit
 
     return fire
@@ -325,12 +331,15 @@ def _top_rows(agg_sig, state: ShardedWindowState, planes: dict,
     # compiles no guard and no sort (global_topk's value_bits)
     is_count = any(name == rank_name and kind == "count"
                    for name, kind, _ in agg_sig)
-    _vals, flat_idx, ok, passes, fell_back = global_topk(
-        planes[rank_name], emit, topk, mesh, axis_name,
-        63 if is_count else 64)
-    keys = jnp.take(state.table.reshape(-1), flat_idx)
-    res = {n: jnp.take(v.reshape(-1), flat_idx) for n, v in planes.items()}
-    return keys, ok, res, jnp.stack([passes, fell_back.astype(jnp.int32)])
+    with jax.named_scope("fire.global"):
+        _vals, flat_idx, ok, passes, fell_back = global_topk(
+            planes[rank_name], emit, topk, mesh, axis_name,
+            63 if is_count else 64)
+        keys = jnp.take(state.table.reshape(-1), flat_idx)
+        res = {n: jnp.take(v.reshape(-1), flat_idx)
+               for n, v in planes.items()}
+        select = jnp.stack([passes, fell_back.astype(jnp.int32)])
+    return keys, ok, res, select
 
 
 def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
@@ -348,11 +357,14 @@ def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
             sub = jnp.where(rows_valid[None, :, None], sub, ident)
             return AGG_MERGES[kind](sub, axis=1)
 
-        out = {a.name: merge(a.kind, state.accs[a.name]) for a in aggs}
-        count = out[count_name]
-        emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
-        occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
-        dropped = state.dropped.sum()
+        # the pane merge with the emit mask and the health scalars it
+        # feeds is the region fire.merge; the select, fire.global
+        with jax.named_scope("fire.merge"):
+            out = {a.name: merge(a.kind, state.accs[a.name]) for a in aggs}
+            count = out[count_name]
+            emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
+            occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
+            dropped = state.dropped.sum()
         if topk is None:
             # a copy: an input handed back as it is would share the
             # table's buffer, which the next step donates
@@ -510,9 +522,10 @@ def _retire_program(sig):
     # 27.9 ms for 29.2 (PERF.md section 7, PR 36; ROADMAP S5c, S10)
     @functools.partial(jax.jit, donate_argnums=(0,))
     def retire(accs: dict, row: jax.Array):
-        return {a.name: accs[a.name].at[:, row].set(
-                    AGG_INITS[a.kind](accs[a.name].dtype))
-                for a in aggs}
+        with jax.named_scope("fire.retire"):
+            return {a.name: accs[a.name].at[:, row].set(
+                        AGG_INITS[a.kind](accs[a.name].dtype))
+                    for a in aggs}
 
     return retire
 

@@ -229,8 +229,9 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
                 return AGG_MERGES[kind](sub, axis=0)
 
         count = merge("count", arrays["__count__"])
-        emit = (table != jnp.int64(EMPTY_KEY)) & (count > 0)
-        occ = (table != jnp.int64(EMPTY_KEY)).sum()
+        with jax.named_scope("fire.merge"):
+            emit = (table != jnp.int64(EMPTY_KEY)) & (count > 0)
+            occ = (table != jnp.int64(EMPTY_KEY)).sum()
         if topk is not None:
             # rank on the FIRST aggregate; everything else gathers at the
             # k winners only
@@ -242,23 +243,26 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
                 ranked = s / jnp.maximum(count, 1).astype(s.dtype)
             else:
                 ranked = merge(rk_kind, arrays[rk_name])
+            # the select and the winners' rows (merge_at's gathers stay
+            # fire.merge: the innermost scope names the region)
             with jax.named_scope("fire.topk"):
                 idx, ok, select = _select_topk(ranked, emit, topk,
                                                topk_value_bits)
                 keys = jnp.take(table, idx)
                 count_k = jnp.take(count, idx)
-            out = {}
-            for kind, out_name in agg_sig:
-                if out_name == rk_name:
-                    out[out_name] = jnp.take(ranked, idx)
-                elif kind == "count":
-                    out[out_name] = count_k
-                elif kind == "avg":
-                    s = merge_at("sum", arrays[f"{out_name}.sum"], idx)
-                    out[out_name] = s / jnp.maximum(count_k, 1).astype(
-                        s.dtype)
-                else:
-                    out[out_name] = merge_at(kind, arrays[out_name], idx)
+                out = {}
+                for kind, out_name in agg_sig:
+                    if out_name == rk_name:
+                        out[out_name] = jnp.take(ranked, idx)
+                    elif kind == "count":
+                        out[out_name] = count_k
+                    elif kind == "avg":
+                        s = merge_at("sum", arrays[f"{out_name}.sum"], idx)
+                        out[out_name] = s / jnp.maximum(
+                            count_k, 1).astype(s.dtype)
+                    else:
+                        out[out_name] = merge_at(kind, arrays[out_name],
+                                                 idx)
             return keys, ok, out, dropped, occ, select
         results = {}
         for kind, out_name in agg_sig:

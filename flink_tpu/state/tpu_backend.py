@@ -40,7 +40,8 @@ import numpy as np
 
 from ..core.keygroups import KeyGroupRange, hash_batch, \
     key_groups_for_hash_batch
-from ..metrics.device import DEVICE_STATS, instrumented_program_cache
+from ..metrics.device import DEVICE_STATS, _record_program_audit, \
+    instrumented_program_cache
 from ..ops.hash_table import (
     EMPTY_KEY, compacts, hash_keys_device, lookup, lookup_or_insert,
     make_table, sanitize_keys_device,
@@ -411,6 +412,9 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # batch then takes the program that hands the full-width rounds
         # over to the narrow loop (ops/hash_table.lookup_or_insert)
         self._probe_wide = False
+        # the probe programs (by ``handover``) this backend has put in
+        # the program audit
+        self._probe_audited: set[bool] = set()
         # the table's generation: every rebuild (growth, eviction,
         # reclaim, restore) moves the slots and starts a new one; a
         # health reading taken under an older one says nothing of this
@@ -1136,9 +1140,18 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             self._finish_reclaim()       # before the probe: it may grow
         dkeys = sanitize_keys_device(dkeys)
         self._probe_calls += 1
+        handover = self._probe_wide and compacts(dkeys.shape[0])
+        if handover not in self._probe_audited:
+            # a module-level jit that no program cache instruments: its
+            # first dispatch here puts it in the audit, as
+            # _TimedProgram._note_live_compile does for the others
+            self._probe_audited.add(handover)
+            _record_program_audit(
+                "state.probe",  # lint: key-ok audit scope, not a config key
+                lookup_or_insert, (self.table, dkeys),
+                {"stats": True, "handover": handover}, repr(handover))
         self.table, slots, ok, probe = lookup_or_insert(
-            self.table, dkeys, stats=True,
-            handover=self._probe_wide and compacts(dkeys.shape[0]))
+            self.table, dkeys, stats=True, handover=handover)
         self._dropped = self._dropped + jnp.sum(~ok).astype(jnp.int64)
         self._probe = self._probe + probe
         self.note_probe_stats()

@@ -76,6 +76,25 @@ def _q5_mesh(devices, capacity: int, batch: int):
 _SHARD_BYTES = (1 << 23) * 8 * (1 + 2 * 16) + 8
 
 
+#: the benchmark-shape programs compiled so far, by name: several tests
+#: look at one program, and each compile takes a quarter of a minute
+_COMPILED: dict = {}
+
+
+def _compiled(name: str, build):
+    if name not in _COMPILED:
+        _COMPILED[name] = build()
+    return _COMPILED[name]
+
+
+def _mesh_step(devices):
+    """(agg, the step of q5-16m-mesh4 as the operator dispatches it:
+    [4, 65536] rows against [4, 16, 2^23] planes, donation included)."""
+    agg, _sharded, args = _q5_mesh(devices[:4], 1 << 23, 1 << 16)
+    return agg, _compiled("mesh.step", lambda: agg.step_program().lower(
+        *args, agg._base_start, agg._base_len).compile())
+
+
 @pytest.mark.parametrize("n_dev", [1, 4])
 def test_mesh_step_compiles(v5e_devices, n_dev):
     agg, _sharded, args = _q5_mesh(v5e_devices[:n_dev], 1 << 10, 256)
@@ -103,9 +122,8 @@ def test_mesh_step_donates_its_state_at_the_benchmark_shape(v5e_devices):
     operator dispatches it (donation included): the state is aliased into
     the outputs (no second 2.2 GB of state a step) and the program fits a
     16 GB chip with room."""
-    agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
-    mem = agg.step_program().lower(
-        *args, agg._base_start, agg._base_len).compile().memory_analysis()
+    _agg, compiled = _mesh_step(v5e_devices)
+    mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _SHARD_BYTES - 4096
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 8e9
@@ -125,9 +143,7 @@ def test_mesh_step_compiles_without_a_plane_copy_at_the_benchmark_shape(
     step's 162 ms until PR 36)."""
     import re
 
-    agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
-    compiled = agg.step_program().lower(
-        *args, agg._base_start, agg._base_len).compile()
+    agg, compiled = _mesh_step(v5e_devices)
     hlo = compiled.as_text()
     assert "HloModule jit_step" in hlo        # the name the traces anchor on
     ring, cap = agg.ring, agg.capacity
@@ -155,6 +171,19 @@ def test_mesh_step_compiles_without_a_plane_copy_at_the_benchmark_shape(
         _SHARD_BYTES + 2 * 8 * cap)
 
 
+def _mesh_fire(devices):
+    """The whole ranked fire of q5-16m-mesh4 ([4, 2^23] int64 COUNT rank,
+    k = 1000, five pane rows) on a described v5e 2x2."""
+    def build():
+        agg, sharded, args = _q5_mesh(devices[:4], 1 << 23, 1 << 16)
+        rep = NamedSharding(sharded.mesh, P())
+        return agg.fire_program("bids", 1000).lower(
+            args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)).compile()
+
+    return _compiled("mesh.fire", build)
+
+
 def _operand_elements(hlo: str, op: str):
     """Element counts of the array operands-or-results named on every
     HLO line that applies ``op`` (``sort``, ``all-reduce``, ...)."""
@@ -176,13 +205,8 @@ def test_mesh_fire_selects_on_the_shard_at_the_benchmark_shape(v5e_devices):
     the D x k candidates crosses the interconnect."""
     import re
 
-    D, cap, k = 4, 1 << 23, 1000
-    agg, sharded, args = _q5_mesh(v5e_devices[:D], cap, 1 << 16)
-    rep = NamedSharding(sharded.mesh, P())
-    hlo = agg.fire_program("bids", k).lower(
-        args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
-        jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)
-    ).compile().as_text()
+    D, k = 4, 1000
+    hlo = _mesh_fire(v5e_devices).as_text()
     assert "HloModule jit_fire" in hlo        # the name the traces anchor on
     sorts = list(_operand_elements(hlo, "sort"))
     assert sorts and max(sorts) <= D * k, max(sorts)
@@ -232,6 +256,25 @@ _FOLD_SIGS = {
 }
 
 
+def _host_born_fold(devices, query: str):
+    """The backend's fold program of a one-chip cell at 2^24 slots and
+    2^18 rows, every ring plane donated."""
+    from flink_tpu.state.tpu_backend import _fold_program
+
+    one = SingleDeviceSharding(devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    sig, rows = _FOLD_SIGS[query], 1 << 18
+    fold = _fold_program(sig)
+    return _compiled(f"fold.{query}", lambda: getattr(fold, "_fn", fold).lower(
+        tuple(spec(shape, dt) for _k, dt, shape in sig),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int64),
+        spec((rows,), jnp.bool_),
+        (None, spec((rows,), jnp.int64))).compile())
+
+
 @pytest.mark.parametrize("query", list(_FOLD_SIGS))
 def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
         v5e_devices, query):
@@ -245,21 +288,8 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     split into, and a ring row of each."""
     import re
 
-    from flink_tpu.state.tpu_backend import _fold_program
-
-    one = SingleDeviceSharding(v5e_devices[0])
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
     sig = _FOLD_SIGS[query]
-    rows = 1 << 18
-    fold = _fold_program(sig)
-    compiled = getattr(fold, "_fn", fold).lower(
-        tuple(spec(shape, dt) for _k, dt, shape in sig),
-        spec((rows,), jnp.int32), spec((rows,), jnp.int64),
-        spec((rows,), jnp.bool_),
-        (None, spec((rows,), jnp.int64))).compile()
+    compiled = _host_born_fold(v5e_devices, query)
     hlo = compiled.as_text()
     assert "HloModule jit_fold" in hlo
     ring, cap = sig[0][2]
@@ -285,6 +315,28 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     assert mem.temp_size_in_bytes < 1.01 * (wide + planes // ring)
 
 
+def _reclaim(devices):
+    """(program, plane signature, abstract arguments, executable) of the
+    backend's reclaim at the in-flight cell's shapes: 2^23 slots under
+    Q5's two ring planes."""
+    from flink_tpu.state.tpu_backend import _reclaim_program
+
+    one = SingleDeviceSharding(devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cap, ring = 1 << 23, 16
+    sig = (("count", "int32", (ring, cap)), ("sum", "int64", (ring, cap)))
+    reclaim = _reclaim_program(sig, (0, 1))
+    args = (spec((cap,), jnp.int64),
+            tuple(spec(shape, dt) for _k, dt, shape in sig),
+            spec((), jnp.int64))
+    return reclaim, sig, args, _compiled(
+        "reclaim",
+        lambda: getattr(reclaim, "_fn", reclaim).lower(*args).compile())
+
+
 def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     """The backend's reclaim (PR 35) at the in-flight cell's shapes, 2^23
     slots under Q5's two ring planes: ONE program `jit_reclaim` with the
@@ -296,20 +348,8 @@ def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     back."""
     import re
 
-    from flink_tpu.state.tpu_backend import _reclaim_program
-
-    one = SingleDeviceSharding(v5e_devices[0])
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    cap, ring = 1 << 23, 16
-    sig = (("count", "int32", (ring, cap)), ("sum", "int64", (ring, cap)))
-    reclaim = _reclaim_program(sig, (0, 1))
-    args = (spec((cap,), jnp.int64),
-            tuple(spec(shape, dt) for _k, dt, shape in sig),
-            spec((), jnp.int64))
-    compiled = getattr(reclaim, "_fn", reclaim).lower(*args).compile()
+    reclaim, sig, args, compiled = _reclaim(v5e_devices)
+    (ring, cap) = sig[0][2]
     hlo = compiled.as_text()
     assert "HloModule jit_reclaim" in hlo
     for scope in ("reclaim.live", "reclaim.rehome", "reclaim.remap"):
@@ -333,6 +373,30 @@ def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     assert mem.temp_size_in_bytes < 8 * ring * cap + 64 * cap
 
 
+def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
+                   panes: int):
+    from flink_tpu.runtime.operators.device_window import _fire_program
+
+    one = SingleDeviceSharding(devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    fire = _fire_program(agg_sig, k, value_bits)
+    return _compiled(name, lambda: getattr(fire, "_fn", fire).lower(
+        spec((cap,), jnp.int64),
+        {n: spec(shape, dt) for n, (shape, dt) in arrays.items()},
+        spec((panes,), jnp.int32), spec((panes,), jnp.bool_),
+        spec((), jnp.int64)).compile())
+
+
+def _q7_fire(devices, value_bits: int, k: int):
+    plane = ((_Q7_RING, _Q7_CAP), jnp.int64)
+    return _one_chip_fire(
+        devices, f"fire.q7.{value_bits}.{k}", (("max", "best"),), k,
+        value_bits, _Q7_CAP, {"__count__": plane, "best": plane}, 1)
+
+
 @pytest.mark.parametrize("k", [1, 1000])
 @pytest.mark.parametrize("value_bits", [43, 64],
                          ids=["promised_43_bits", "no_promise"])
@@ -352,19 +416,7 @@ def test_max_ranked_fire_compiles_at_the_benchmark_shape(v5e_devices,
     pair."""
     import re
 
-    from flink_tpu.runtime.operators.device_window import _fire_program
-
-    one = SingleDeviceSharding(v5e_devices[0])
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    plane = spec((_Q7_RING, _Q7_CAP), jnp.int64)
-    fire = _fire_program((("max", "best"),), k, value_bits)
-    compiled = getattr(fire, "_fn", fire).lower(
-        spec((_Q7_CAP,), jnp.int64), {"__count__": plane, "best": plane},
-        spec((1,), jnp.int32), spec((1,), jnp.bool_),
-        spec((), jnp.int64)).compile()
+    compiled = _q7_fire(v5e_devices, value_bits, k)
     hlo = compiled.as_text()
     assert "HloModule jit_fire_fn" in hlo     # the name the traces anchor on
     assert not re.search(r" scatter\(", hlo)
@@ -391,18 +443,21 @@ def _assert_probe_is_countable(hlo: str) -> None:
         "while body: probe_rounds_p50 would read 0"
 
 
+def _hash_probe(devices):
+    from flink_tpu.ops.hash_table import lookup_or_insert
+
+    one = SingleDeviceSharding(devices[0])
+    return _compiled("probe", lambda: lookup_or_insert.lower(
+        jax.ShapeDtypeStruct((1 << 24,), jnp.int64, sharding=one),
+        jax.ShapeDtypeStruct((1 << 18,), jnp.int64, sharding=one),
+        stats=True).compile())
+
+
 def test_hash_probe_compiles_at_the_benchmark_shape(v5e_devices):
     """[2^24] slots x [2^18] rows, with the counters: the first window, the
     compaction (a sort), both narrow loops and the wide one under one
     `lax.switch`, for the v5e's compiler."""
-    from flink_tpu.ops.hash_table import lookup_or_insert
-
-    one = SingleDeviceSharding(v5e_devices[0])
-    compiled = lookup_or_insert.lower(
-        jax.ShapeDtypeStruct((1 << 24,), jnp.int64, sharding=one),
-        jax.ShapeDtypeStruct((1 << 18,), jnp.int64, sharding=one),
-        stats=True).compile()
-    _assert_probe_is_countable(compiled.as_text())
+    _assert_probe_is_countable(_hash_probe(v5e_devices).as_text())
 
 
 @pytest.mark.parametrize("rows", [64, 1 << 12])
@@ -415,3 +470,161 @@ def test_hash_probe_claim_stays_countable_on_any_backend(rows):
     compiled = lookup_or_insert.lower(
         make_table(1 << 14), jnp.zeros(rows, jnp.int64)).compile()
     _assert_probe_is_countable(compiled.as_text())
+
+
+# ---------------------------------------------------------------------------
+# the region map of every program a benchmark cell runs, at its shapes
+
+
+def _big_instructions(hlo: str, floor: int = 1 << 20) -> dict:
+    """{instruction: opcode} of the instructions whose result, or whose
+    operands together, hold ``floor`` bytes or more."""
+    import re
+
+    from flink_tpu.metrics.device import _closing
+
+    item = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+            "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+            "f64": 8}
+
+    def nbytes(type_text: str) -> int:
+        return sum(item.get(dt, 0) * int(np.prod(
+            [int(d) for d in dims.split(",") if d], dtype=np.int64))
+            for dt, dims in re.findall(r"\b(pred|[a-z]+\d+)\[([\d,]*)\]",
+                                       type_text))
+
+    wrote, parsed = {}, []
+    for line in hlo.splitlines():
+        m = re.match(r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+(.*)$", line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        at = _closing(rest, 0) if rest.startswith("(") else rest.find(" ")
+        op = re.match(r"\s*([\w\-]+)\(", rest[at:])
+        if op is None:
+            continue
+        wrote[name] = nbytes(rest[:at])
+        end = _closing(rest, at + op.end() - 1)
+        parsed.append((name, op.group(1), re.findall(
+            r"%([\w.\-]+)", rest[at + op.end():end])))
+    return {name: opcode for name, opcode, refs in parsed
+            if max(wrote[name], sum(wrote.get(r, 0) for r in refs)) >= floor}
+
+
+def _region_programs(devices) -> dict:
+    """name -> executable of every program of the benchmark's cells, at
+    the cells' shapes (those the tests above compiled are not compiled
+    again)."""
+    from flink_tpu.parallel.sharded_window import _retire_program
+    from flink_tpu.state.tpu_backend import _reset_row_program
+
+    one = SingleDeviceSharding(devices[0])
+    q5 = _FOLD_SIGS["q5"]
+    reset = _reset_row_program(q5)
+    agg, step = _mesh_step(devices)
+    _a, sharded, args = _q5_mesh(devices[:4], 1 << 23, 1 << 16)
+    retire = _retire_program(agg.sig)
+    cap = 1 << 24
+    return {
+        "jit_lookup_or_insert": _hash_probe(devices),
+        "jit_fold.q5": _host_born_fold(devices, "q5"),
+        "jit_fold.q7": _host_born_fold(devices, "q7"),
+        "jit_fire_fn.q5": _one_chip_fire(
+            devices, "fire.q5", (("count", "bids"), ("sum", "revenue")),
+            1000, 48, cap, {"__count__": ((16, cap), jnp.int32),
+                            "revenue": ((16, cap), jnp.int64)}, 5),
+        "jit_fire_fn.q7": _q7_fire(devices, 43, 1),
+        "jit_reset": _compiled("reset", lambda: getattr(
+            reset, "_fn", reset).lower(
+            tuple(jax.ShapeDtypeStruct(shape, dt, sharding=one)
+                  for _k, dt, shape in q5),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile()),
+        "jit_step": step,
+        "jit_fire": _mesh_fire(devices),
+        "jit_retire": _compiled("mesh.retire", lambda: getattr(
+            retire, "_fn", retire).lower(
+            args[0].accs, jax.ShapeDtypeStruct(
+                (), jnp.int32,
+                sharding=NamedSharding(sharded.mesh, P()))).compile()),
+        "jit_reclaim": _reclaim(devices)[3],
+    }
+
+
+#: the regions each program must hold, beside the split and the join of
+#: its int64 arguments
+_PROGRAM_REGIONS = {
+    "jit_lookup_or_insert": {"probe.window0", "probe.tail"},
+    "jit_fold.q5": {"fold.row", "fold.count", "fold.sum"},
+    "jit_fold.q7": {"fold.row", "fold.count", "fold.max"},
+    "jit_fire_fn.q5": {"fire.merge", "fire.topk"},
+    "jit_fire_fn.q7": {"fire.merge", "fire.topk"},
+    "jit_reset": {"fire.reset"},
+    "jit_step": {"mesh.plan", "mesh.sync", "exchange.pack",
+                 "exchange.collective", "probe.window0", "probe.tail",
+                 "fold.row", "fold.count", "fold.sum"},
+    "jit_fire": {"fire.merge", "fire.global"},
+    "jit_retire": {"fire.retire"},
+    "jit_reclaim": {"reclaim.live", "reclaim.rehome", "reclaim.remap",
+                    "probe.window0", "probe.tail"},
+}
+
+
+@pytest.mark.parametrize("program", list(_PROGRAM_REGIONS))
+def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
+    """The map a program gives of itself (`metrics/device.classify_hlo`,
+    what `program_regions` serves the benchmark's partition from), for
+    every program of the benchmark's cells compiled FOR the v5e at the
+    cells' shapes: every instruction the device runs that reads or writes
+    1 MiB or more lies in a named region, the x64 rewriter's split and
+    join among them (no `named_scope` can reach those: they go by their
+    custom-call target), and the mesh step's three int64 column scatters
+    (which the rewriter makes anew, without a name path) are the
+    exchange's packing by what they feed."""
+    import re
+
+    from flink_tpu.metrics.device import UNNAMED, classify_hlo
+
+    hlo = _region_programs(v5e_devices)[program].as_text()
+    assert f"HloModule {program.split('.')[0]}" in hlo
+    regions = classify_hlo(hlo)
+    big = _big_instructions(hlo)
+    assert len(big) > 4
+    unnamed = sorted(name for name in big if regions.get(name) == UNNAMED)
+    assert not unnamed, unnamed
+    found = set(regions.values())
+    assert _PROGRAM_REGIONS[program] <= found, \
+        _PROGRAM_REGIONS[program] - found
+    x64 = {"x64.split": 0, "x64.join": 0}
+    for line in hlo.splitlines():
+        m = re.match(r'\s+(?:ROOT )?%([\w.\-]+) = .*custom_call_target='
+                     r'"X64(Split|Combine)', line)
+        if m:
+            kind = "x64.split" if m.group(2) == "Split" else "x64.join"
+            assert regions[m.group(1)] == kind, line[:200]
+            x64[kind] += 1
+    assert x64["x64.split"] >= 2 and x64["x64.join"] >= 1, x64
+    if program == "jit_retire":
+        # the row writes sit between the planes' split and their join and
+        # are neither: the rewriter left them a bare name
+        writes = re.findall(r"%([\w.\-]+) = [^=]* dynamic-update-slice\(",
+                            hlo)
+        assert len(writes) >= 4
+        assert {regions[name] for name in writes} == {"fire.retire"}
+    if program == "jit_step":
+        # the send buffers' scatters (the probe's compaction scatters
+        # into as many rows on the receiving side): the flags', and one
+        # of each int64 column over both its halves, which the x64
+        # rewriter makes anew without a name path
+        packs = [line for line in hlo.splitlines()
+                 if re.search(r" fusion\(.*kind=kCustom", line)
+                 and re.search(r"\[81920\]", line.split(" fusion(")[0])
+                 and "mesh.probe" not in line]
+        columns = flags = 0
+        for line in packs:
+            name = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+            if name in regions:         # not one inside another fusion
+                assert regions[name] == "exchange.pack", line[:200]
+                columns += " = (u32[81920]" in line \
+                    and "op_name=" not in line
+                flags += " = pred[81920]" in line
+        assert (columns, flags) == (3, 1)
