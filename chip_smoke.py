@@ -16,6 +16,10 @@ generator and seed. Legs:
   q5-mesh        the same query through the mesh vertex over ALL visible
                  devices (D=1 on one chip, D=4 on four); shards must sit
                  on D distinct devices and rows must equal q5-10M-host's
+  q5-mesh-inflight  a short run of the mesh vertex over auction ids that
+                 ADVANCE (24 panes, 13 x the ids in flight at once): the
+                 shards reclaim the slots of dead ids several times and
+                 every one ends at the capacity it began with
   pallas-topk    masked_topk_pallas compiled (not interpreted) under x64,
                  equal to ops.topk.masked_topk and to numpy
 
@@ -125,6 +129,25 @@ def _n_panes(n_events: int, batch: int) -> int:
     return max(4, min(RING - WINDOW_PANES - 2, n_events // batch))
 
 
+def _make_inflight_gen(in_flight: int, n_events: int, span_ms: int,
+                       seed: int):
+    """Bids whose auction ids advance: event ``idx`` sees the newest id
+    ``in_flight + idx * 12 * in_flight // n_events`` (half the ids in
+    flight are new every pane of a 24-pane run), bids on it one time in
+    four and otherwise on one of the ``in_flight`` ids before it; no id
+    reaches ``13 * in_flight + 1``."""
+
+    def gen(idx):
+        u = ((idx + seed).astype(np.uint64) * np.uint64(MULT))
+        newest = in_flight + (idx * (12 * in_flight)) // n_events
+        cold = newest - 1 - (u % np.uint64(in_flight)).astype(np.int64)
+        return {"auction": np.where(idx % 4 == 0, newest, cold),
+                "price": (idx % 997) + 1,
+                "ts": (idx * span_ms) // n_events}
+
+    return gen
+
+
 def _make_gen(n_keys: int, n_events: int, span_ms: int, seed: int):
     """Bid generator: numpy on the host, traced under jit on the device —
     identical values either way (uint64 wrap-around is the same)."""
@@ -142,13 +165,21 @@ def _make_gen(n_keys: int, n_events: int, span_ms: int, seed: int):
 # plain numpy reference
 # ----------------------------------------------------------------------
 
-def q5_reference(n_keys: int, n_events: int, batch: int, seed: int) -> dict:
-    """window_end_ms -> (bids[n_keys], revenue[n_keys]) for every window
+def q5_reference(n_keys: int, n_events: int, batch: int, seed: int,
+                 make_gen: Callable = _make_gen,
+                 n_panes: Optional[int] = None,
+                 id_space: Optional[int] = None) -> dict:
+    """window_end_ms -> (bids[ids], revenue[ids]) for every window
     holding data: np.bincount of auction and of price per (pane, key),
-    summed over each window's W panes. Independent of the code under test."""
-    n_panes = _n_panes(n_events, batch)
-    cols = _make_gen(n_keys, n_events, n_panes * PANE_MS, seed)(
+    summed over each window's W panes. Independent of the code under test.
+    ``make_gen`` / ``n_panes``: another generator of this module and its
+    span; ``id_space``: the ids it makes stay under this (``n_keys``
+    where every key exists from the start)."""
+    if n_panes is None:
+        n_panes = _n_panes(n_events, batch)
+    cols = make_gen(n_keys, n_events, n_panes * PANE_MS, seed)(
         np.arange(n_events, dtype=np.int64))
+    n_keys = id_space or n_keys
     cell = (cols["ts"] // PANE_MS) * n_keys + cols["auction"]
     bids = np.bincount(cell, minlength=n_panes * n_keys) \
         .reshape(n_panes, n_keys)
@@ -228,11 +259,16 @@ def _collecting_sink():
 
 def run_q5(leg: str, aggregate: Callable, operator_cls, *, n_keys: int,
            n_events: int, batch: int, device: bool, seed: int,
-           reference: dict, topk: int) -> tuple[dict, dict, list]:
+           reference: dict, topk: int, make_gen: Callable = _make_gen,
+           n_panes: Optional[int] = None,
+           watermark_interval_s: Optional[float] = None
+           ) -> tuple[dict, dict, list]:
     """One env.execute() of Q5. ``aggregate(windowed_stream, aggs)``
     picks the vertex (device_aggregate / mesh_aggregate). Returns (report,
     rows, the job's window operators) after checking the rows against the
-    reference and the fallback counters against zero."""
+    reference and the fallback counters against zero. ``make_gen`` /
+    ``n_panes`` as ``q5_reference`` takes them; ``watermark_interval_s``
+    sets ``pipeline.auto-watermark-interval`` (0: a watermark a batch)."""
     from flink_tpu.api import StreamExecutionEnvironment
     from flink_tpu.core import WatermarkStrategy
     from flink_tpu.core.config import PipelineOptions
@@ -244,14 +280,17 @@ def run_q5(leg: str, aggregate: Callable, operator_cls, *, n_keys: int,
     _watch_compiles()
     schema = Schema([("auction", np.int64), ("price", np.int64),
                      ("ts", np.int64)])
-    span_ms = _n_panes(n_events, batch) * PANE_MS
+    span_ms = (n_panes or _n_panes(n_events, batch)) * PANE_MS
     env = StreamExecutionEnvironment.get_execution_environment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, batch)
+    if watermark_interval_s is not None:
+        env.config.set(PipelineOptions.AUTO_WATERMARK_INTERVAL,
+                       watermark_interval_s)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = _collecting_sink()
-    windowed = (env.datagen(_make_gen(n_keys, n_events, span_ms, seed),
+    windowed = (env.datagen(make_gen(n_keys, n_events, span_ms, seed),
                             schema, count=n_events, timestamp_column="ts",
                             watermark_strategy=ws, device=device)
                 .key_by("auction")
@@ -348,6 +387,49 @@ def leg_q5_mesh(*, n_keys: int, capacity_per_device: int, batch: int,
     # ran a rebuild it did not size for
     assert ops[0]._agg.capacity == capacity_per_device, ops[0]._agg.capacity
     return report, rows
+
+
+def leg_q5_mesh_inflight(*, capacity_per_device: int, batch: int, seed: int,
+                         topk: int = TOPK, n_panes: int = 24) -> dict:
+    """The mesh vertex over auction ids that advance, one batch a pane:
+    a tenth of all slots in flight at once and thirteen times that many
+    ids in the run, so the tables fill to their load limit again and
+    again and only the reclaim of dead ids' slots keeps them at their
+    capacity. Rows equal numpy's; every shard ends as large as it began
+    and has been swept."""
+    import jax
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.runtime.operators.mesh_window import \
+        MeshWindowAggOperator
+
+    n_dev = len(jax.devices())
+    in_flight = n_dev * capacity_per_device // 10
+    shape = dict(n_keys=in_flight, n_events=n_panes * batch, batch=batch,
+                 seed=seed, make_gen=_make_inflight_gen, n_panes=n_panes)
+
+    def aggregate(windowed, aggs):
+        return windowed.mesh_aggregate(
+            aggs, n_devices=n_dev, capacity=capacity_per_device,
+            ring_size=RING, device_batch=batch // n_dev,
+            emit_window_bounds=True, emit_topk=topk, async_fire=True)
+
+    before = DEVICE_STATS.snapshot()
+    # a watermark a batch: the panes retire as the ids advance, however
+    # fast the host runs ahead of the 0.2 s the default leaves between two
+    report, _rows, ops = run_q5(
+        "q5-mesh-inflight", aggregate, MeshWindowAggOperator, device=False,
+        reference=q5_reference(id_space=14 * in_flight, **shape),
+        topk=topk, watermark_interval_s=0.0, **shape)
+    after = DEVICE_STATS.snapshot()
+    report.update(
+        capacity_per_device=ops[0]._agg.capacity, in_flight=in_flight,
+        **{k: after[k] - before[k] for k in (
+            "state_reclaim_sweeps_total", "state_reclaim_keys_kept_total",
+            "state_reclaim_keys_freed_total")})
+    assert ops[0]._agg.capacity == capacity_per_device, ops[0]._agg.capacity
+    assert report["state_reclaim_sweeps_total"] >= 2, report
+    assert report["state_reclaim_keys_freed_total"] > in_flight, report
+    return report
 
 
 def leg_pallas_topk(sizes=(1 << 21, 1 << 24), k: int = TOPK,
@@ -448,6 +530,11 @@ def main(argv=None) -> int:
         single_chip_rows=host_rows)
     del ref_22, host_rows, _rows
     _emit(report)
+
+    # short: 24 batches of 2^18 bids (2.5 a pane for each id in flight,
+    # so that nearly every id is bid on) over 2^20 slots in all
+    _emit(leg_q5_mesh_inflight(capacity_per_device=(1 << 20) // n_dev,
+                               batch=1 << 18, seed=args.seed))
 
     _emit(leg_pallas_topk(seed=args.seed))
 

@@ -108,6 +108,13 @@ class DeviceStats:
         # by the steps in flight
         self._mesh_steps = 0
         self._mesh_exchange_rounds = 0
+        # mesh insert accounting (PR 41): rows of the mesh step that
+        # claimed a new slot (all shards), over the rows stepped between
+        # the two readings of the shards' occupied slots that say so
+        # (the pressure probe's, or a reclaim's kept keys): how
+        # insert-heavy the blocks were, read and not inferred
+        self._mesh_inserted_rows = 0
+        self._mesh_stepped_rows = 0
         # fire select accounting (PR 31): ranked mesh fires, the
         # compare-and-count passes their threshold selects walked (the
         # longest shard's; the bit length of the largest rank) and how
@@ -124,10 +131,11 @@ class DeviceStats:
         # counted on the host from the batch's own ring indices
         self._fold_batches = 0
         self._fold_ring_rows = 0
-        # state reclaim accounting (PR 35): sweeps of the one-chip
-        # backend's reclaim (state/tpu_backend.py reclaim: the table
-        # rebuilt at its own capacity from the keys that still hold data
-        # in a ring row) and the keys they kept and freed
+        # state reclaim accounting (PR 35; the mesh operator's since
+        # PR 41, one sweep a dispatch, keys summed over the shards):
+        # sweeps of the reclaim (state/tpu_backend.py reclaim_shard: a
+        # table rebuilt at its own capacity from the keys that still hold
+        # data in a ring row) and the keys they kept and freed
         self._reclaim_sweeps = 0
         self._reclaim_kept = 0
         self._reclaim_freed = 0
@@ -412,6 +420,18 @@ class DeviceStats:
         """(mesh steps, exchange rounds they took)."""
         with self._lock:
             return self._mesh_steps, self._mesh_exchange_rounds
+
+    def note_mesh_inserts(self, inserted: int, stepped: int) -> None:
+        with self._lock:
+            self._mesh_inserted_rows += int(inserted)
+            self._mesh_stepped_rows += int(stepped)
+
+    @property
+    def mesh_insert_counts(self) -> tuple[int, int]:
+        """(rows that claimed a new slot, rows stepped between the
+        readings that say so), all shards of the mesh step."""
+        with self._lock:
+            return self._mesh_inserted_rows, self._mesh_stepped_rows
 
     def note_fire_select(self, passes: int, sort: bool) -> None:
         with self._lock:
@@ -714,6 +734,8 @@ class DeviceStats:
                 "probe_wide_batches_total": self._probe_wide_batches,
                 "mesh_steps_total": self._mesh_steps,
                 "mesh_exchange_rounds_total": self._mesh_exchange_rounds,
+                "mesh_inserted_rows_total": self._mesh_inserted_rows,
+                "mesh_stepped_rows_total": self._mesh_stepped_rows,
                 "fire_selects_total": self._fire_selects,
                 "fire_select_passes_total": self._fire_select_passes,
                 "fire_select_sort_total": self._fire_select_sort,
@@ -828,6 +850,7 @@ class DeviceStats:
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
+            self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
             self._fire_select_sort = 0
             self._fold_batches = self._fold_ring_rows = 0
@@ -1262,15 +1285,24 @@ class _TimedProgram:
     def prepare(self, *args, **kwargs) -> None:
         """Compile now for ``args`` (arrays or ``ShapeDtypeStruct``s of
         the one shape the program will be called with); a no-op once the
-        program has compiled either way."""
-        if self._compiled:
+        program has compiled either way (a second mesh of a per-mesh
+        program compiles uncounted, as its first dispatch would)."""
+        # a program that keeps its executables itself (one a mesh:
+        # parallel/sharded_window._per_mesh) is asked every time: it
+        # knows whether THIS mesh's is built
+        own = getattr(self._fn, "prepare", None)
+        if self._compiled and own is None:
             return
         from .tracing import now_ms
         start_ms = now_ms()
         t0 = time.perf_counter()
-        self._prepared = self._fn.lower(*args, **kwargs).compile()
-        self._note_live_compile((time.perf_counter() - t0) * 1e3,
-                                start_ms, args, kwargs)
+        if own is not None:
+            own(*args, **kwargs)
+        else:
+            self._prepared = self._fn.lower(*args, **kwargs).compile()
+        if not self._compiled:
+            self._note_live_compile((time.perf_counter() - t0) * 1e3,
+                                    start_ms, args, kwargs)
 
     def _call_plain(self, args, kwargs):
         if self._compiled:
@@ -1567,6 +1599,12 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_mesh_exchange_rounds_total)
     g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])
     g.gauge("mesh_exchange_rounds_total", lambda: s.mesh_step_counts[1])
+    # rows of the mesh step that claimed a new slot, over the rows
+    # stepped between the readings that say so (prometheus:
+    # flink_tpu_device_mesh_inserted_rows_total /
+    # flink_tpu_device_mesh_stepped_rows_total)
+    g.gauge("mesh_inserted_rows_total", lambda: s.mesh_insert_counts[0])
+    g.gauge("mesh_stepped_rows_total", lambda: s.mesh_insert_counts[1])
     # ranked fire select, both window operators (prometheus:
     # flink_tpu_device_fire_selects_total /
     # flink_tpu_device_fire_select_passes_total /
@@ -1579,7 +1617,7 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_fold_ring_rows_total)
     g.gauge("fold_batches_total", lambda: s.fold_counts[0])
     g.gauge("fold_ring_rows_total", lambda: s.fold_counts[1])
-    # state reclaim of the one-chip backend (prometheus:
+    # state reclaim, one chip or mesh (prometheus:
     # flink_tpu_device_state_reclaim_sweeps_total /
     # flink_tpu_device_state_reclaim_keys_kept_total /
     # flink_tpu_device_state_reclaim_keys_freed_total)

@@ -618,6 +618,10 @@ LEDGER_SITE_INVENTORY: tuple = (
     ("mesh.rebuild_inc",
      "parallel/sharded_window.py — sharded incremental rebuild "
      "program"),
+    ("mesh.reclaim",  # lint: key-ok ledger site, not a config key
+     "parallel/sharded_window.py — sharded reclaim program (every "
+     "shard's table rebuilt at its own capacity from its live keys, its "
+     "planes re-seated; built with the state, before any input)"),
     ("mesh.retire",  # lint: key-ok ledger site, not a config key
      "parallel/sharded_window.py — retired-pane cleanup program"),
     ("mesh.seal_inc",

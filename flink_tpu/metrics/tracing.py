@@ -870,7 +870,12 @@ SPAN_INVENTORY: tuple = (
      "mailbox turn that found the table past load 0.6 until its two "
      "counts have landed (the mailbox does not wait for it); child of "
      "the Drain whose health reading found it (stage span: kept, freed, "
-     "capacity; seq: that window's)"),
+     "capacity; seq: that window's); runtime/operators/mesh_window.py "
+     "_reclaim — the sharded reclaim (all shards, one dispatch), until "
+     "its [D, 2] counts have landed: kept and freed summed over the "
+     "shards, capacity a shard's; child of the Drain whose reading found "
+     "the fullest shard past the limit or, found by the pressure probe, "
+     "a root whose seq is the operator's watermark"),
     ("window", "Upload",
      "runtime/operators/device_window.py _fold_packed / "
      "_to_device_batch — pack + the one host→device copy; device/H2D "

@@ -55,6 +55,7 @@ from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
     AGG_MERGES, INVERTIBLE_KINDS, merge_tree_build, merge_tree_update, \
     pow2_ceil, ring_fold
 from ..ops.topk import masked_topk_sort, threshold_topk
+from ..state.tpu_backend import reclaim_shard
 from .exchange import bucket_capacity, exchange_round, plan_exchange
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
     key_groups_device, shard_ranges
@@ -153,6 +154,7 @@ def _per_mesh(make):
     Mesh by ``make(mesh)``: how a builder whose cache key is local-shape-
     only holds the executables that close over a mesh."""
     bound: dict = {}
+    prepared: dict = {}
 
     def program(mesh: Mesh):
         prog = bound.get(mesh)
@@ -161,10 +163,20 @@ def _per_mesh(make):
         return prog
 
     def dispatch(mesh: Mesh, *args):
-        return program(mesh)(*args)
+        return (prepared.get(mesh) or program(mesh))(*args)
+
+    def prepare(mesh: Mesh, *args) -> None:
+        """Compile ``mesh``'s program now for ``args`` (the arrays it
+        will be called with, or their ``ShapeDtypeStruct``s with the
+        shardings); its dispatches then run that executable. What
+        ``metrics/device._TimedProgram.prepare`` asks of a program that
+        keeps its executables itself."""
+        if mesh not in prepared:
+            prepared[mesh] = program(mesh).lower(*args).compile()
 
     # what `metrics/device.program_regions` asks of an audited program
     dispatch.lower = lambda mesh, *args: program(mesh).lower(*args)
+    dispatch.prepare = prepare
     return dispatch
 
 
@@ -530,6 +542,57 @@ def _retire_program(sig):
     return retire
 
 
+def _make_reclaim(sig, axis_name: str, rules: tuple, mesh: Mesh):
+    """The jitted reclaim of the sharded state on ``mesh`` (see
+    _reclaim_program)."""
+    _, agg_sig, cap, ring = sig
+    names = [name for name, _kind, _dt in agg_sig]
+    # every plane of the state is a pane-role ring plane: each one says
+    # what lives, as the one-chip backend's pane planes do
+    plane_sig = tuple((kind, dt, (ring, cap)) for _n, kind, dt in agg_sig)
+    live = tuple(range(len(plane_sig)))
+
+    def shard_body(table, accs, dropped):
+        table, planes, dropped, counts = reclaim_shard(
+            plane_sig, live, table[0], tuple(accs[n][0] for n in names),
+            dropped[0])
+        return (table[None], {n: p[None] for n, p in zip(names, planes)},
+                dropped[None], counts[None])
+
+    skel = {"table": 0, "accs": dict.fromkeys(names, 0), "dropped": 0}
+    sp = match_partition_rules(rules, skel)
+    state_specs = (sp["table"], sp["accs"], sp["dropped"])
+    mapped = shard_map_unchecked(shard_body, mesh, in_specs=state_specs,
+                                 out_specs=state_specs + (P(axis_name),))
+
+    # donated like the step's state: the planes are re-seated in place.
+    # Named as the one-chip backend's program is (``jit_reclaim`` in a
+    # trace): a reader that anchors on a reclaim, or leaves it out of a
+    # step, finds either; a job runs one of the two, never both
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def reclaim(state: ShardedWindowState):
+        table, accs, dropped, counts = mapped(state.table, state.accs,
+                                              state.dropped)
+        return ShardedWindowState(table, accs, dropped), counts
+
+    return reclaim
+
+
+@instrumented_program_cache("mesh.reclaim")
+def _reclaim_program(sig, axis_name: str, rules: tuple):
+    """The reclaim of the sharded state: ONE program (``jit_reclaim`` in a
+    trace, over a mesh) in which every shard frees, at its own capacity, the
+    slots of the keys that hold no data in any ring row of its planes,
+    re-homes its live keys and re-seats its planes. The body is the
+    one-chip backend's (``state/tpu_backend.reclaim_shard``: the regions
+    ``reclaim.live`` / ``reclaim.rehome`` / ``reclaim.remap``) under
+    ``shard_map``; no shard waits for another (no collective). Bound per
+    mesh like the step; the state is donated. Returns (new state, int32
+    [D, 2]: the keys each shard kept and freed)."""
+    return _per_mesh(lambda mesh: _make_reclaim(sig, axis_name, rules,
+                                                mesh))
+
+
 class ShardedWindowAgg:
     """Facade over the cached sharded programs for one (mesh, schema).
 
@@ -586,6 +649,8 @@ class ShardedWindowAgg:
                                    plan.axis_name, plan.rules)
         self._fire = _fire_program(self.sig)
         self._retire = _retire_program(self.sig)
+        self._reclaim = _reclaim_program(self.sig, plan.axis_name,
+                                         plan.rules)
         self.set_base_range(base_range)
 
     # ------------------------------------------------------------------
@@ -618,6 +683,11 @@ class ShardedWindowAgg:
         return _make_step(self.sig, self.max_parallelism,
                           self.plan.axis_name, self.plan.rules, self.mesh)
 
+    def reclaim_program(self):
+        """The jitted reclaim ``reclaim`` dispatches."""
+        return _make_reclaim(self.sig, self.plan.axis_name, self.plan.rules,
+                             self.mesh)
+
     def init_state(self) -> ShardedWindowState:
         """The empty state, each leaf built on the device that holds it."""
         return self._init(self.mesh)
@@ -639,6 +709,24 @@ class ShardedWindowAgg:
         its buffers are deleted, only the returned state is live."""
         return self._step(self.mesh, state, keys, cols, panes, valid,
                           self._base_start, self._base_len)
+
+    # ------------------------------------------------------------------
+    def reclaim(self, state: ShardedWindowState
+                ) -> tuple[ShardedWindowState, jax.Array]:
+        """Free, on every shard and at its own capacity, the slots of
+        the keys that hold no data in any ring row (all their windows
+        fired and retired): one dispatch, nothing waited for. Returns
+        (new state, int32 [D, 2] keys kept and freed a shard). ``state``
+        is DONATED, like the step's; every slot may move, so what was
+        derived from the old slots (the incremental fire's planes) is
+        void."""
+        return self._reclaim(self.mesh, state)
+
+    def prepare_reclaim(self, state: ShardedWindowState) -> None:
+        """Compile the reclaim for ``state``'s shapes and shardings now
+        (``state`` is not consumed), so that the reclaim itself builds
+        nothing where a job has promised to build nothing."""
+        self._reclaim.prepare(self.mesh, state)
 
     # ------------------------------------------------------------------
     def fire(self, state: ShardedWindowState, pane_rows: np.ndarray,

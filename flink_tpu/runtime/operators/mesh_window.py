@@ -55,11 +55,24 @@ from .slice_control import AsyncFireQueue, SliceControlPlane
 __all__ = ["MeshWindowAggOperator"]
 
 
+#: load of the fullest shard past which the operator acts: it reclaims,
+#: and grows if that frees too little (the one-chip backend's threshold)
+_LOAD_LIMIT = 0.6
+#: load the fullest shard must not pass unseen: the host steps no
+#: further beyond its last reading than HALF the blocks that would take
+#: the shard there at the assumed pace (``_pace``). A reclaim needs no
+#: lead (one dispatch, the steps queue behind it), so the limit itself
+#: is no place to wait at; past 0.65 the probe's 128-slot bound starts
+#: to bite (a linear-probing cluster over 128 slots: about e^-10 a key)
+_LOAD_CEILING = 0.65
+
+
 @jax.jit
 def _probe_program(table: jax.Array, dropped: jax.Array):
-    """Pressure scalars: (max per-shard occupancy, total drops)."""
-    return ((table != jnp.int64(EMPTY_KEY)).sum(axis=1).max(),
-            dropped.sum())
+    """Pressure scalars: (max per-shard occupancy, total drops, occupied
+    slots of all shards)."""
+    occ = (table != jnp.int64(EMPTY_KEY)).sum(axis=1)
+    return occ.max(), dropped.sum(), occ.sum()
 
 
 class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
@@ -139,11 +152,33 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         # pressure probe (occupancy of the fullest shard + drops): an
         # async scalar read, dispatched at watermark cadence and, between
         # watermarks, once the blocks stepped since the last one pass a
-        # quarter of the headroom to the growth threshold; consumed when
-        # its copy has landed (_pressure_probe)
-        self._probe = None
-        self._blocks_since_probe = 0
+        # quarter of the headroom to the load ceiling; consumed when
+        # its copy has landed (_take_readings): (outputs, table
+        # generation, block ordinal and rows stepped at its dispatch)
+        self._probe: Optional[tuple] = None
+        self._probed_at = 0
         self._occ_known = 0
+        # the table's generation: a reclaim moves every slot and a
+        # rebuild (grow, restore, rescale) replaces the table; a reading
+        # taken under an older one says nothing of this table
+        self._generation = 0
+        # a reclaim dispatched whose counts have not landed yet: (device
+        # [D, 2] kept / freed, its open window/Reclaim stage, block
+        # ordinal and rows stepped at its dispatch)
+        self._reclaiming: Optional[tuple] = None
+        # the last reading of this table (block ordinal it was taken at,
+        # occupancy of the fullest shard) and the slots a block the
+        # fullest shard is ASSUMED to gain: every row of its slice a new
+        # key until two readings of one table say otherwise, then twice
+        # what they showed, never under an eighth of a slice (_reading)
+        self._last_reading: Optional[tuple] = None
+        self._pace = float(device_batch)
+        # valid rows stepped, and the last reading of all shards'
+        # occupied slots (generation, rows stepped by then, slots): what
+        # a table gained between two readings are the rows that claimed
+        # a new slot (mesh_inserted_rows_total)
+        self._rows_stepped = 0
+        self._occ_total: Optional[tuple] = None
         # ordinal of the [D, B] block being stepped (seq of its stage
         # spans) and the steps' round counts still on their way to the
         # host (handed to DEVICE_STATS once landed, never waited for)
@@ -239,6 +274,8 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
 
     def _build(self, defs: list[AggDef], capacity: Optional[int] = None
                ) -> None:
+        # a reclaim in flight is of the state that goes: settle it first
+        self._finish_reclaim(block=True, grow=False)
         self._agg = ShardedWindowAgg(
             self._mesh, defs, capacity=capacity or self._capacity,
             ring=self._ring, max_parallelism=self._max_parallelism,
@@ -246,10 +283,20 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         # the state that is replaced (grow, restore, rescale) goes before
         # the new one is built, and with it what was known of it
         self._state = None
-        self._probe = None
-        self._blocks_since_probe = 0
+        self._new_generation()
         self._occ_known = 0
+        self._pace = float(self._device_batch)
         self._state = self._agg.init_state()
+        # with the step's and the fire's programs, before any input: a
+        # reclaim then compiles nothing, wherever in the job it falls (a
+        # job may have promised to build nothing once it is warm)
+        self._agg.prepare_reclaim(self._state)
+
+    def _new_generation(self) -> None:
+        self._generation += 1
+        self._probe = None
+        self._last_reading = None
+        self._occ_total = None
 
     # -- data path ---------------------------------------------------------
     def process_batch(self, batch: RecordBatch) -> None:
@@ -298,6 +345,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         while total - pos >= full or (pad and total > pos):
             n_valid = min(full, total - pos)
             self._block_seq += 1
+            self._rows_stepped += n_valid
             ring_rows = self._note_fold(ring_idx[pos:pos + n_valid])
             with self._upload_stage() as up:
                 if staged is None:
@@ -370,6 +418,10 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
 
     def _step_block(self, dkeys: jax.Array, dcols: dict, dpanes: jax.Array,
                     dvalid: jax.Array) -> None:
+        # what is due of the readings sent out is taken in BEFORE the
+        # step (a reclaim, a growth come before the block's inserts),
+        # the next probe goes out right behind it
+        self._take_readings(at_block=True)
         # the old state is donated to the step: nothing may keep a handle
         # on it (fires and probes enqueued on it earlier stay valid)
         self._state, _processed, n_rounds = self._agg.step(
@@ -377,8 +429,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         n_rounds.copy_to_host_async()
         self._rounds_sent.append(n_rounds)
         self._note_rounds()
-        self._blocks_since_probe += 1
-        self._pressure_probe(at_block=True)
+        self._send_probe(at_block=True)
 
     def _note_rounds(self, block: bool = False) -> None:
         """Hand the steps' exchange-round counts to DEVICE_STATS: those
@@ -397,74 +448,186 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
     # -- firing (fire loop lives in SliceControlPlane) ----------------------
     def _pre_fire_flush(self) -> None:
         self._flush(pad=True)
-        self._pressure_probe()
+        self._take_readings()
+        self._send_probe()
 
-    def _pressure_probe(self, at_block: bool = False) -> None:
-        """Proactive growth WITHOUT stalling the pipeline: an async scalar
-        probe (max shard occupancy + total drops) is dispatched at
-        watermark cadence and consumed whenever its copy has landed; the
-        growth decision adds a margin for the blocks dispatched since the
-        probe, so the table grows before the load factor bites. Drops are
-        still a hard error (also checked on every fire's health scalars).
+    def _headroom(self) -> int:
+        """Blocks that would take the fullest shard from its last known
+        occupancy to the load ceiling at the assumed pace."""
+        gap = _LOAD_CEILING * self._agg.capacity - self._occ_known
+        return int(max(0.0, gap) // self._pace)
 
-        The margin counts every row stepped since the probe as a new key
-        on the fullest shard, so it must stay well inside the headroom
-        (0.6 x capacity - the last known occupancy) or a burst of blocks
-        over resident keys reads as pressure. Hence ``at_block`` (after
-        every step): a probe goes out once a quarter of the headroom has
-        been stepped since the last one, and the host waits for it once
-        half has been stepped since it went out: it may run that far
-        ahead of what it knows of the devices and no further. The wait
-        ends when the devices reach the probe, with the blocks stepped
-        since still queued behind it."""
+    def _take_readings(self, at_block: bool = False) -> None:
+        """Pressure handling WITHOUT stalling the pipeline: an async
+        scalar probe (max shard occupancy, total drops, occupied slots)
+        goes out at watermark cadence and behind the steps
+        (``_send_probe``) and is consumed here whenever its copy has
+        landed, as one more reading of the table (``_reading``); so are
+        the counts of a reclaim in flight. Drops are a hard error (also
+        checked on every fire's health scalars).
+
+        With ``at_block`` (before every step) the host also bounds how far
+        it runs ahead of what it knows of the devices: it WAITS for a
+        probe, or for a reclaim's counts, once half the headroom
+        (``_headroom``: blocks to the load ceiling at the assumed pace)
+        has been stepped since it went out, this block counted. The wait
+        ends when the devices reach it, with the blocks stepped since
+        still queued behind it; what it calls for, a reclaim and then a
+        growth, comes before this block's inserts. The headroom is to the
+        CEILING, not to the limit the operator acts at: near the limit a
+        reading is due every few dozen blocks, not a wait at every
+        block."""
         if self._agg is None:
             return
-        B = self._device_batch
-        headroom = max(1.0, 0.6 * self._agg.capacity - self._occ_known) // B
+        self._settle_reclaim(at_block)
         if self._probe is not None:
-            outs = self._probe
-            wait = at_block and self._blocks_since_probe >= headroom // 2
+            outs, generation, at, rows = self._probe
+            wait = at_block and (self._block_seq - at
+                                 >= self._headroom() // 2)
             if wait or all(leaf.is_ready()
                            for leaf in jax.tree_util.tree_leaves(outs)):
-                occ, dropped = jax.device_get(outs)
+                occ, dropped, occupied = jax.device_get(outs)
                 self._probe = None
-                if int(dropped) > self._dropped_seen:
-                    raise RuntimeError(
-                        f"mesh hash table overflow: {int(dropped)} records "
-                        f"dropped (capacity {self._agg.capacity} per "
-                        "shard); raise "
-                        "state.backend.tpu.slots-per-key-group")
-                self._occ_known = int(occ)
-                # blocks dispatched AFTER the probe are invisible to its
-                # occupancy: pad the growth decision by what they could add
-                need = int(occ) + self._blocks_since_probe * B
-                if need > 0.6 * self._agg.capacity:
-                    target = self._agg.capacity
-                    while need > 0.6 * target:
-                        target *= 2
-                    self._grow(target)
-        if self._probe is None and self._blocks_since_probe >= (
-                max(1, headroom // 4) if at_block else 1):
+                self._note_inserts(generation, rows, int(occupied))
+                self._reading(dropped, occ, (generation, at))
+                self._settle_reclaim(at_block)   # one it has just sent
+
+    def _settle_reclaim(self, at_block: bool) -> None:
+        """Take in the counts of a reclaim in flight if they have landed;
+        before a step, wait for them by the rule of ``_take_readings``."""
+        if self._reclaiming is not None:
+            self._finish_reclaim(block=at_block and (
+                self._block_seq - self._reclaiming[2]
+                >= self._headroom() // 2))
+
+    def _send_probe(self, at_block: bool = False) -> None:
+        """Dispatch the pressure probe if none is out: behind a step once
+        a quarter of the headroom has been stepped since the last one
+        went out, at a watermark once any block has."""
+        if self._agg is None or self._probe is not None:
+            return
+        due = max(1, self._headroom() // 4) if at_block else 1
+        if self._block_seq - self._probed_at >= due:
             outs = _probe_program(self._state.table, self._state.dropped)
             for leaf in jax.tree_util.tree_leaves(outs):
                 leaf.copy_to_host_async()
-            self._probe = outs
-            self._blocks_since_probe = 0
+            self._probe = (outs, self._generation, self._block_seq,
+                           self._rows_stepped)
+            self._probed_at = self._block_seq
 
-    def _apply_health(self, dropped: int, occ_max: int) -> None:
-        """Pressure handling from scalars that rode a fire's outputs —
-        the hot loop itself never syncs (matches the single-chip
-        apply_health model)."""
+    def _note_inserts(self, generation: int, rows: int,
+                      occupied: int) -> None:
+        """One reading of all shards' occupied slots, taken when ``rows``
+        rows had been stepped: what one table gained since the reading
+        before are the rows in between that claimed a new slot."""
+        last = self._occ_total
+        if last is not None and last[0] == generation and rows > last[1]:
+            DEVICE_STATS.note_mesh_inserts(max(0, occupied - last[2]),
+                                           rows - last[1])
+        self._occ_total = (generation, rows, occupied)
+
+    def _reading(self, dropped: int, occ_max: int, taken: tuple,
+                 drain=None) -> None:
+        """One reading of the table's health: scalars that rode a fire's
+        outputs or a landed probe's, so the hot loop itself never syncs.
+        ``taken``: (table generation, block ordinal) at the dispatch of
+        what read them; a reading of a table since reclaimed or rebuilt
+        says nothing of this one and is passed over, as is any reading
+        while a reclaim is in flight.
+
+        Past the load limit on the fullest shard the operator RECLAIMS
+        (all shards, one dispatch, at the same capacity) and goes on
+        stepping behind it; it grows only if that frees too little
+        (``_finish_reclaim``). The reading is taken as it is, as the
+        one-chip backend takes a fire's: a reclaim needs no lead, so
+        nothing is added for the blocks stepped since (counting every row
+        of them as a new key on the fullest shard makes a job near the
+        limit wait for the devices at every block). How far the host may
+        run ahead of its readings is ``_take_readings``', by the pace
+        two readings of one table show: the slots a block the fullest
+        shard gained, doubled, at least an eighth of a slice and at most
+        a whole one (every row a new key there: what is assumed while two
+        readings of this table have not shown otherwise, a prefill)."""
+        self._check_dropped(dropped)
+        self._finish_reclaim()
+        generation, at = taken
+        if self._reclaiming is not None or generation != self._generation:
+            return
+        occ, B = int(occ_max), self._device_batch
+        last = self._last_reading
+        if last is None or at >= last[0]:
+            if last is not None and at > last[0]:
+                gained = max(0, occ - last[1]) / (at - last[0])
+                self._pace = min(float(B), max(2.0 * gained, B / 8))
+            self._last_reading = (at, occ)
+            self._occ_known = occ
+        if occ > _LOAD_LIMIT * self._agg.capacity:
+            self._reclaim(drain)
+
+    def _check_dropped(self, dropped: int) -> None:
         if int(dropped) > self._dropped_seen:
             raise RuntimeError(
                 f"mesh hash table overflow: {int(dropped)} records dropped "
-                f"(capacity {self._agg.capacity} per shard); raise "
+                f"(capacity {self._agg.capacity} per shard: neither a "
+                "reclaim nor growth came in time); raise "
                 "state.backend.tpu.slots-per-key-group")
-        if int(occ_max) > 0.6 * self._agg.capacity:
-            self._grow(self._agg.capacity * 2)
+
+    def _reclaim(self, drain=None) -> None:
+        """Dispatch the reclaim of every shard (``ShardedWindowAgg.
+        reclaim``: one donated program, not waited for). window/Reclaim
+        opens here, under the window/Drain whose reading found the
+        pressure (``seq``: that window's end) or, found by a probe, as a
+        root (``seq``: the operator's watermark), and closes when the
+        counts have landed. Every slot may move: a new table generation
+        begins and the incremental fire's planes are rebuilt at the next
+        fire."""
+        at = ({"parent": drain.context, "seq": drain.attrs["seq"]}
+              if drain is not None else {"seq": self.current_watermark})
+        span = TRACER.open_stage("window", "Reclaim", **at)
+        self._state, counts = self._agg.reclaim(self._state)
+        counts.copy_to_host_async()
+        self._new_generation()
+        self._mark_inc_dirty()
+        self._reclaiming = (counts, span, self._block_seq,
+                            self._rows_stepped)
+
+    def _finish_reclaim(self, block: bool = False,
+                        grow: bool = True) -> None:
+        """Take in a dispatched reclaim's counts once their copy has
+        landed (``block``: wait for it): the stage span, the counters
+        (summed over the shards), the fullest shard's occupancy, and the
+        growth that a reclaim which freed too little still calls for, by
+        the backend's rule read per shard: fewer than a quarter of the
+        slots a shard held past the load limit came free (the job's live
+        set really is that large)."""
+        if self._reclaiming is None:
+            return
+        counts, span, at, rows = self._reclaiming
+        if not (block or counts.is_ready()):
+            return
+        self._reclaiming = None
+        # lint: sync-ok the reclaim's counts, landed (or the caller syncs anyway)
+        kept, freed = np.asarray(jax.device_get(counts)).T.astype(np.int64)
+        cap = self._agg.capacity
+        DEVICE_STATS.note_reclaim(int(kept.sum()), int(freed.sum()))
+        span.close(kept=int(kept.sum()), freed=int(freed.sum()),
+                   capacity=cap)
+        self._occ_known = int(kept.max())
+        self._last_reading = (at, self._occ_known)
+        self._occ_total = (self._generation, rows, int(kept.sum()))
+        held = kept + freed
+        if grow and ((4 * freed < held) & (held > _LOAD_LIMIT * cap)).any():
+            target = 2 * cap
+            while kept.max() > _LOAD_LIMIT * target:
+                target *= 2
+            self._grow(target)
 
     def _grow(self, new_capacity: int) -> None:
         self._drain(block=True)  # pending fires read the pre-grow state
+        # the new state's drop counters start at zero: a row lost before
+        # the growth came is still the hard error it must be
+        self._check_dropped(np.asarray(
+            jax.device_get(self._state.dropped)).sum())
         snap = self._snapshot_backend()
         defs = list(self._agg.aggs)
         self._build(defs, capacity=new_capacity)
@@ -497,11 +660,17 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         rows_valid[:len(rows)] = True
         outs = self._agg.fire_compact(self._state, pane_rows, rows_valid,
                                       self._rank_name(), self._topk)
-        self._enqueue_fire((p_end, outs, None, time.perf_counter()))
+        self._enqueue_fire((p_end, outs, self._taken(),
+                            time.perf_counter()))
         # retire the oldest pane of this window: no future window needs it
         if p_end - W >= self._min_seen_pane:
             self._state = self._agg.retire_row(self._state,
                                                (p_end - W) % self._ring)
+
+    def _taken(self) -> tuple:
+        """(table generation, block ordinal) a fire's health scalars are
+        read under: carried from its dispatch to its drain."""
+        return self._generation, self._block_seq
 
     def _fire_incremental(self, p_end: int, first: int) -> None:
         """O(capacity) fire: consume the running window view kept by the
@@ -540,26 +709,27 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         DEVICE_STATS.note_fire_merge_rows(rows_read)
         self._inc_dirty = False
         self._inc_next = p_end + 1
-        self._enqueue_fire((p_end, outs, None, time.perf_counter()))
+        self._enqueue_fire((p_end, outs, self._taken(),
+                            time.perf_counter()))
         if p_end - W >= self._min_seen_pane:
             self._state = self._agg.retire_row(self._state,
                                                (p_end - W) % self._ring)
 
     def _materialize(self, item: tuple, turn: str) -> None:
-        p_end, outs, _unused, t0, fire = item
+        p_end, outs, taken, t0, fire = item
         with self._drain_stage(fire, turn) as drain:
             host = jax.device_get(outs)   # ONE transfer for everything
             d2h_bytes = pytree_nbytes(host)
             if self._topk is not None:
                 keys_k, ok, results, dropped, occ, select = host
-                self._apply_health(dropped, occ)
+                self._reading(dropped, occ, taken, drain)
                 self._note_fire_select(drain, select)
                 sel = np.asarray(ok)
                 keys = np.asarray(keys_k)[sel]
                 res = {n: np.asarray(v)[sel] for n, v in results.items()}
             else:
                 table, emit, results, dropped, occ = host
-                self._apply_health(dropped, occ)
+                self._reading(dropped, occ, taken, drain)
                 mask = np.asarray(emit).reshape(-1)
                 idx = np.flatnonzero(mask)
                 keys = np.asarray(table).reshape(-1)[idx]
@@ -605,6 +775,10 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         if self._agg is None:
             return {"kind": "tpu", "keys": np.empty(0, np.int64),
                     "key_groups": np.empty(0, np.int32), "states": {}}
+        # a reclaim in flight is settled first (its stage and counts; the
+        # state read below is the reclaimed one either way): a snapshot
+        # taken for a growth is of the table as it is now
+        self._finish_reclaim(block=True, grow=False)
         table = np.asarray(jax.device_get(self._state.table))  # [D, cap]
         host_accs = {n: np.asarray(jax.device_get(v))
                      for n, v in self._state.accs.items()}  # [D, ring, cap]
@@ -861,3 +1035,4 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         self._flush(pad=True)
         self._drain(block=True)
         self._note_rounds(block=True)
+        self._finish_reclaim(block=True, grow=False)

@@ -53,7 +53,7 @@ from .descriptors import StateDescriptor
 from .spill import HostTier
 from .tiering import PrefetchPipeline, ResidencyManager
 
-__all__ = ["TpuKeyedStateBackend"]
+__all__ = ["TpuKeyedStateBackend", "reclaim_shard"]
 
 
 def _sanitize_keys(keys: np.ndarray) -> np.ndarray:
@@ -164,14 +164,17 @@ def _permute(where: jax.Array, values: jax.Array) -> jax.Array:
     return jax.lax.sort((where, values), num_keys=1, is_stable=False)[1]
 
 
-@instrumented_program_cache("state.reclaim")
-def _reclaim_program(sig: tuple, live_planes: tuple):
-    """One jitted reclaim per plane signature: the table rebuilt AT ITS
-    OWN CAPACITY from the keys that still hold data, every plane re-seated
-    onto the new slots, in a single fixed-shape dispatch. ``sig`` = tuple
-    of (kind, dtype_str, shape) over ALL of the backend's array states,
-    as ``_reset_row_program``'s; ``live_planes`` indexes the ones that
-    decide what lives (the pane-role ring planes).
+def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
+                  dropped):
+    """The reclaim of ONE table and its planes, traceable and not jitted:
+    the one-chip backend jits it as it is (``_reclaim_program``) and the
+    mesh runs it on every shard under ``shard_map`` (``parallel/
+    sharded_window._make_reclaim``), so the three steps exist once. The
+    table rebuilt AT ITS OWN CAPACITY from the keys that still hold data,
+    every plane re-seated onto the new slots, at fixed shapes. ``sig`` =
+    tuple of (kind, dtype_str, shape) over ALL of the table's array
+    states, as ``_reset_row_program``'s; ``live_planes`` indexes the ones
+    that decide what lives (the pane-role ring planes).
 
     * ``reclaim.live``: a slot lives iff it is occupied and some ring row
       of some ``live_planes`` plane differs from its aggregate's identity
@@ -198,84 +201,92 @@ def _reclaim_program(sig: tuple, live_planes: tuple):
       pane) is left as it is.
 
     Returns (table, planes, dropped, [kept, freed]); ``dropped`` is the
-    backend's counter passed through, plus the live keys that found no
+    caller's counter passed through, plus the live keys that found no
     slot within MAX_PROBES (0 short of a pathological key set; the next
-    health check then fails the job as for any dropped insert). The
-    planes are donated; the old table is not (a fire still in the drain
-    queue may hold it as an output)."""
+    health check then fails the job as for any dropped insert). What is
+    donated is the caller's."""
+    C = table.shape[0]
+    B = min(C, _RECLAIM_CHUNK)
+    slot = jnp.arange(C, dtype=jnp.int32)
+    lane = jnp.arange(B, dtype=jnp.int32)
+    empty = jnp.int64(EMPTY_KEY)
+    with jax.named_scope("reclaim.live"):
+        occupied = table != empty
+        holds = jnp.zeros(C, bool)
+        for i in live_planes:
+            a = arrays[i]
+            holds = holds | (a != AGG_INITS[sig[i][0]](a.dtype)).any(
+                axis=0)
+        live = occupied & holds
+        home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
+            jnp.int32) == slot
+        moves = live & ~home
+        kept = jnp.sum(live, dtype=jnp.int32)
+        n_moves = jnp.sum(moves, dtype=jnp.int32)
+        freed = jnp.sum(occupied, dtype=jnp.int32) - kept
+        order = _sorted_slots(
+            jnp.where(moves, 0, jnp.where(live, 1, 2)), 3)
+
+    with jax.named_scope("reclaim.rehome"):
+        def rehome(i, carry):
+            new_table, new_of, lost = carry
+            src = jax.lax.dynamic_slice(order, (i * B,), (B,))
+            valid = i * B + lane < n_moves
+            new_table, slots, ok = lookup_or_insert(
+                new_table, table[src], valid, handover=compacts(B))
+            new_of = jax.lax.dynamic_update_slice(
+                new_of, jnp.where(ok, slots, src), (i * B,))
+            return (new_table, new_of,
+                    lost + jnp.sum(valid & ~ok, dtype=jnp.int32))
+
+        # new_of[p]: where the p-th slot of ``order`` goes; a key at
+        # home stays where it is
+        new_table, new_of, lost = jax.lax.fori_loop(
+            0, (n_moves + B - 1) // B, rehome,
+            (jnp.where(live & home, table, empty), order,
+             jnp.int32(0)))
+        landed = new_table != empty
+        free = _sorted_slots(landed.astype(jnp.int32), 2)
+        new_of = jnp.where(slot < kept, new_of, jnp.roll(free, kept))
+        dest = _permute(order, new_of)
+
+    with jax.named_scope("reclaim.remap"):
+        out = []
+        for (kind, _dt, _shape), a in zip(sig, arrays):
+            ident = AGG_INITS[kind](a.dtype)
+
+            def reseat(row, ident=ident):
+                return jax.lax.cond(
+                    (row != ident).any(),
+                    lambda r: jnp.where(landed, _permute(dest, r),
+                                        ident),
+                    lambda r: r, row)
+
+            if a.ndim == 1:
+                out.append(reseat(a))
+                continue
+
+            def body(r, plane, reseat=reseat):
+                row = jax.lax.dynamic_index_in_dim(plane, r, 0,
+                                                   keepdims=False)
+                return jax.lax.dynamic_update_index_in_dim(
+                    plane, reseat(row), r, 0)
+
+            out.append(jax.lax.fori_loop(0, a.shape[0], body, a))
+    return (new_table, tuple(out), dropped + lost.astype(dropped.dtype),
+            jnp.stack([kept, freed]))
+
+
+@instrumented_program_cache("state.reclaim")
+def _reclaim_program(sig: tuple, live_planes: tuple):
+    """One jitted reclaim per plane signature: ``reclaim_shard`` over the
+    backend's table and ALL of its array states in a single fixed-shape
+    dispatch. The planes are donated; the old table is not (a fire still
+    in the drain queue may hold it as an output)."""
 
     @partial(jax.jit, donate_argnums=(1,))
     def reclaim(table, arrays: tuple, dropped):
-        C = table.shape[0]
-        B = min(C, _RECLAIM_CHUNK)
-        slot = jnp.arange(C, dtype=jnp.int32)
-        lane = jnp.arange(B, dtype=jnp.int32)
-        empty = jnp.int64(EMPTY_KEY)
-        with jax.named_scope("reclaim.live"):
-            occupied = table != empty
-            holds = jnp.zeros(C, bool)
-            for i in live_planes:
-                a = arrays[i]
-                holds = holds | (a != AGG_INITS[sig[i][0]](a.dtype)).any(
-                    axis=0)
-            live = occupied & holds
-            home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
-                jnp.int32) == slot
-            moves = live & ~home
-            kept = jnp.sum(live, dtype=jnp.int32)
-            n_moves = jnp.sum(moves, dtype=jnp.int32)
-            freed = jnp.sum(occupied, dtype=jnp.int32) - kept
-            order = _sorted_slots(
-                jnp.where(moves, 0, jnp.where(live, 1, 2)), 3)
-
-        with jax.named_scope("reclaim.rehome"):
-            def rehome(i, carry):
-                new_table, new_of, lost = carry
-                src = jax.lax.dynamic_slice(order, (i * B,), (B,))
-                valid = i * B + lane < n_moves
-                new_table, slots, ok = lookup_or_insert(
-                    new_table, table[src], valid, handover=compacts(B))
-                new_of = jax.lax.dynamic_update_slice(
-                    new_of, jnp.where(ok, slots, src), (i * B,))
-                return (new_table, new_of,
-                        lost + jnp.sum(valid & ~ok, dtype=jnp.int32))
-
-            # new_of[p]: where the p-th slot of ``order`` goes; a key at
-            # home stays where it is
-            new_table, new_of, lost = jax.lax.fori_loop(
-                0, (n_moves + B - 1) // B, rehome,
-                (jnp.where(live & home, table, empty), order,
-                 jnp.int32(0)))
-            landed = new_table != empty
-            free = _sorted_slots(landed.astype(jnp.int32), 2)
-            new_of = jnp.where(slot < kept, new_of, jnp.roll(free, kept))
-            dest = _permute(order, new_of)
-
-        with jax.named_scope("reclaim.remap"):
-            out = []
-            for (kind, _dt, _shape), a in zip(sig, arrays):
-                ident = AGG_INITS[kind](a.dtype)
-
-                def reseat(row, ident=ident):
-                    return jax.lax.cond(
-                        (row != ident).any(),
-                        lambda r: jnp.where(landed, _permute(dest, r),
-                                            ident),
-                        lambda r: r, row)
-
-                if a.ndim == 1:
-                    out.append(reseat(a))
-                    continue
-
-                def body(r, plane, reseat=reseat):
-                    row = jax.lax.dynamic_index_in_dim(plane, r, 0,
-                                                       keepdims=False)
-                    return jax.lax.dynamic_update_index_in_dim(
-                        plane, reseat(row), r, 0)
-
-                out.append(jax.lax.fori_loop(0, a.shape[0], body, a))
-        return (new_table, tuple(out), dropped + lost.astype(dropped.dtype),
-                jnp.stack([kept, freed]))
+        return reclaim_shard(sig, live_planes, table, arrays, dropped)
 
     return reclaim
 

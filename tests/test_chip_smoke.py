@@ -56,6 +56,22 @@ def test_mesh_leg_equals_single_chip_on_distinct_devices(host_leg,
     assert len(set(report["state_device_ids"])) == len(jax.devices()) > 1
 
 
+def test_mesh_leg_over_advancing_ids_reclaims_and_keeps_its_capacity():
+    """The short in-flight leg (PR 41): thirteen times the ids that are
+    in flight at once over tables that hold six times as many under
+    their load limit, rows equal to numpy's (asserted by the leg), every
+    shard swept at least twice and as large at the end as it began."""
+    import jax
+    n_dev = len(jax.devices())
+    report = chip_smoke.leg_q5_mesh_inflight(
+        capacity_per_device=(1 << 13) // n_dev, batch=1 << 11, seed=3,
+        topk=50)
+    assert report["capacity_per_device"] * n_dev == 1 << 13
+    assert report["state_reclaim_sweeps_total"] >= 2
+    assert report["windows"] == 24 + chip_smoke.WINDOW_PANES - 1
+    assert all(report[k] == 0 for k in chip_smoke.FALLBACK_COUNTERS)
+
+
 def test_check_rows_rejects_a_wrong_answer(host_leg, reference):
     _report, rows = host_leg
     wrong = dict(rows, bids=rows["bids"] + (np.arange(len(rows["bids"]))
@@ -81,8 +97,8 @@ def test_main_refuses_to_run_without_a_tpu(monkeypatch, capsys):
     def no_leg(*_a, **_kw):
         raise AssertionError("a leg ran on a CPU backend")
 
-    for name in ("leg_q5_single", "leg_q5_mesh", "leg_pallas_topk",
-                 "q5_reference"):
+    for name in ("leg_q5_single", "leg_q5_mesh", "leg_q5_mesh_inflight",
+                 "leg_pallas_topk", "q5_reference"):
         monkeypatch.setattr(chip_smoke, name, no_leg)
     assert chip_smoke.main([]) != 0
     assert capsys.readouterr().out == ""

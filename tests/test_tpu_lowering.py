@@ -373,6 +373,47 @@ def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     assert mem.temp_size_in_bytes < 8 * ring * cap + 64 * cap
 
 
+def _mesh_reclaim(devices):
+    """The reclaim of the sharded state at the four-chip in-flight cell's
+    shapes (q5-inflight-mesh4: [4, 16, 2^23] int64 planes), as the
+    operator prepares it (donation included)."""
+    agg, _sharded, args = _q5_mesh(devices[:4], 1 << 23, 1 << 16)
+    return _compiled("mesh.reclaim", lambda: agg.reclaim_program().lower(
+        args[0]).compile())
+
+
+def test_mesh_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
+    """ONE program `jit_reclaim` for a described v5e 2x2 in which every
+    shard runs the backend's three steps over its own [16, 2^23] int64
+    planes (PR 41): the state donated and re-seated in place, no shard
+    waiting for another (no collective at all), nothing but the state and
+    each shard's two counts coming back, and it fits a 16 GB chip beside
+    what a step leaves."""
+    import re
+
+    compiled = _mesh_reclaim(v5e_devices)
+    hlo = compiled.as_text()
+    assert "HloModule jit_reclaim" in hlo and "shard_map" in hlo
+    for scope in ("reclaim.live", "reclaim.rehome", "reclaim.remap"):
+        assert re.search(rf"jit\(reclaim\)/.*{scope}/", hlo), scope
+    assert "reclaim.rehome/while/body/" in hlo and "probe.claim" in hlo
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert not list(_operand_elements(hlo, op)), op
+    for line in hlo.splitlines():
+        if " copy(" in line:
+            assert not re.search(r"\[(1,)?16,8388608\]", line), line[:300]
+    agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
+    state, counts = jax.eval_shape(agg.reclaim_program(), args[0])
+    assert (counts.shape, str(counts.dtype)) == ((4, 2), "int32")
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), state) \
+        == jax.tree.map(lambda a: (a.shape, a.dtype), args[0])
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _SHARD_BYTES - (1 << 23) * 8 - 4096
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 8e9
+
+
 def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
                    panes: int):
     from flink_tpu.runtime.operators.device_window import _fire_program
@@ -547,6 +588,7 @@ def _region_programs(devices) -> dict:
                 (), jnp.int32,
                 sharding=NamedSharding(sharded.mesh, P()))).compile()),
         "jit_reclaim": _reclaim(devices)[3],
+        "jit_reclaim.mesh": _mesh_reclaim(devices),
     }
 
 
@@ -566,6 +608,8 @@ _PROGRAM_REGIONS = {
     "jit_retire": {"fire.retire"},
     "jit_reclaim": {"reclaim.live", "reclaim.rehome", "reclaim.remap",
                     "probe.window0", "probe.tail"},
+    "jit_reclaim.mesh": {"reclaim.live", "reclaim.rehome", "reclaim.remap",
+                         "probe.window0", "probe.tail"},
 }
 
 
