@@ -21,7 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
-           "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
+           "Halves", "plane_map", "plane_take", "plane_row", "planes_joined",
+           "planes_stored_like", "stores_halves", "identity_words",
+           "make_plane", "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
            "make_accumulator", "segment_topk", "pow2_ceil",
            "merge_tree_build", "merge_tree_update", "merge_tree_root"]
 
@@ -92,6 +94,155 @@ def make_accumulator(kind: str, shape: tuple[int, ...], dtype) -> jax.Array:
     return jnp.full(shape, AGG_INITS[kind](dtype), dtype=dtype)
 
 
+@jax.tree_util.register_pytree_node_class
+class Halves:
+    """A 64-bit integer plane STORED as its two 32-bit words: ``hi`` and
+    ``lo``, two ``uint32`` arrays of the plane's shape. The one-chip
+    backend keeps its pane-role ring planes so (``state/tpu_backend``):
+    the TPU has no 64-bit registers, so a program whose parameter or
+    result is an ``s64`` array splits ALL of it into words at its entry
+    and joins ALL of it at its exit, whatever it touches (a quarter of the
+    one-chip step and two thirds of its fire until PR 42). Handed the
+    words, a program slices or gathers what it needs of each, ``join``s
+    that, computes in 64 bits as before (the compiler keeps an ``s64`` as
+    its pair of words anyway, so the join of a sliced row is no work) and
+    ``split``s what it writes back.
+
+    A pytree of its two words (``dtype``, the plane's own, rides as static
+    data), so it passes through ``jit``, donation, ``cond``, loops and
+    ``device_get`` as an array does; ``shape`` / ``dtype`` / ``ndim`` /
+    ``nbytes`` read as the plane's. ``split`` and ``join`` work alike on
+    device and on numpy words; ``np.asarray`` of one joins on the host."""
+
+    __slots__ = ("hi", "lo", "dtype")
+
+    def __init__(self, hi, lo, dtype):
+        self.hi, self.lo, self.dtype = hi, lo, dtype
+
+    def tree_flatten(self):
+        return (self.hi, self.lo), self.dtype
+
+    @classmethod
+    def tree_unflatten(cls, dtype, words):
+        return cls(*words, dtype)
+
+    @classmethod
+    def split(cls, values) -> "Halves":
+        """The words of a 64-bit integer array (device or numpy)."""
+        dtype = values.dtype
+        return cls((values >> dtype.type(32)).astype(np.uint32),
+                   values.astype(np.uint32), dtype)
+
+    def join(self):
+        """The 64-bit array of the words (device or numpy)."""
+        wide = self.dtype.type
+        if isinstance(self.hi, np.ndarray):
+            # the snapshot mirror joins whole planes: one new array,
+            # shifted and filled in place
+            out = self.hi.astype(wide)
+            out <<= wide(32)
+            out |= self.lo
+            return out
+        return (self.hi.astype(wide) << wide(32)) | self.lo.astype(wide)
+
+    def map(self, fn, *more: "Halves") -> "Halves":
+        """``fn`` over the high words, then over the low words, of this
+        plane and of ``more`` in step: a slice, a gather, a row write."""
+        return Halves(fn(self.hi, *(m.hi for m in more)),
+                      fn(self.lo, *(m.lo for m in more)), self.dtype)
+
+    @property
+    def shape(self) -> tuple:
+        return self.hi.shape
+
+    @property
+    def ndim(self) -> int:
+        return self.hi.ndim
+
+    @property
+    def nbytes(self) -> int:
+        return self.hi.nbytes + self.lo.nbytes
+
+    def __array__(self, dtype=None, copy=None):
+        host = Halves(np.asarray(self.hi), np.asarray(self.lo),
+                      self.dtype).join()
+        return host if dtype is None else host.astype(dtype)
+
+    def __repr__(self) -> str:
+        shape = getattr(self.hi, "shape", None)   # a mapped tree's words
+        at = list(shape) if shape is not None else f"hi={self.hi!r}, " \
+            f"lo={self.lo!r}"
+        return f"Halves({self.dtype}{at})"
+
+
+def plane_map(fn, plane, *more):
+    """``fn(plane, *more)`` for whole arrays, word by word for ``Halves``
+    (``more`` in the plane's own layout): what moves cells without
+    reading them needs no 64-bit value."""
+    if isinstance(plane, Halves):
+        return plane.map(fn, *more)
+    return fn(plane, *more)
+
+
+def plane_take(plane, take):
+    """``take(plane)`` (a row slice, a gather of rows or of cells) as
+    values of the plane's own dtype: the words of a ``Halves`` plane are
+    each taken FIRST and joined after, so only what was taken is ever
+    64 bits wide."""
+    if isinstance(plane, Halves):
+        return plane.map(take).join()
+    return take(plane)
+
+
+def plane_row(plane, row):
+    """Ring row ``row`` (a traced scalar) of a ``[ring, capacity]`` plane
+    as a 1-D array of the plane's own dtype."""
+    return plane_take(plane, lambda a: jax.lax.dynamic_index_in_dim(
+        a, row, 0, keepdims=False))
+
+
+def planes_joined(planes: dict) -> dict:
+    """``planes`` with every ``Halves`` one joined: for a program that
+    reads and rewrites WHOLE planes (the session operator's scans), at
+    its entry. Inside a program the join is no work (the compiler keeps
+    a 64-bit array as its pair of words), where a 64-bit PARAMETER is a
+    pass over the plane."""
+    return {name: plane.join() if isinstance(plane, Halves) else plane
+            for name, plane in planes.items()}
+
+
+def planes_stored_like(stored: dict, planes: dict) -> dict:
+    """``planes`` back in the layouts of ``stored``: at such a program's
+    exit, the twin of ``planes_joined``."""
+    return {name: Halves.split(plane) if isinstance(stored[name], Halves)
+            else plane for name, plane in planes.items()}
+
+
+def stores_halves(dtype, ring, role: str = "pane") -> bool:
+    """Whether the one-chip backend stores a plane as ``Halves``: a
+    pane-role ring plane of a 64-bit integer."""
+    dtype = np.dtype(dtype)
+    return bool(ring) and role == "pane" and dtype.kind in "iu" \
+        and dtype.itemsize == 8
+
+
+def identity_words(kind: str, dtype) -> Halves:
+    """The two words of a 64-bit integer aggregate's identity, as numpy
+    scalars (a constant in a trace)."""
+    dtype = np.dtype(dtype)
+    info = np.iinfo(dtype)
+    ident = {"min": info.max, "max": info.min}.get(kind, 0)
+    return Halves.split(np.asarray(ident, dtype))
+
+
+def make_plane(kind: str, shape: tuple[int, ...], dtype, halves: bool):
+    """An accumulator of identities in its stored layout."""
+    if not halves:
+        return make_accumulator(kind, shape, dtype)
+    return identity_words(kind, dtype).map(
+        lambda word: jnp.full(shape, word, jnp.uint32))
+
+
 def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
                  values: jax.Array, valid: jax.Array) -> jax.Array:
     """Fold a batch into a flat accumulator: acc[flat_idx] op= values,
@@ -110,11 +261,13 @@ def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
 _FOLD_CHUNK = 1 << 14
 
 
-def ring_fold(kind: str, plane: jax.Array, ring_idx: jax.Array,
+def ring_fold(kind: str, plane, ring_idx: jax.Array,
               slots: jax.Array, values: jax.Array,
-              valid: jax.Array) -> jax.Array:
+              valid: jax.Array):
     """Fold a batch into a ``[ring, capacity]`` plane, ring row by ring
-    row: plane[ring_idx, slots] op= values, masked by ``valid``. No flat
+    row: plane[ring_idx, slots] op= values, masked by ``valid``. The
+    plane is one array (the mesh's) or the ``Halves`` of a 64-bit one
+    (the one-chip backend's), and comes back as it came. No flat
     view of the plane is taken: the TPU keeps a 2-D plane tiled, and
     ``plane.reshape(-1)`` around a scatter copies all of it into a flat
     buffer and back (three quarters of the one-chip ingest step until
@@ -160,9 +313,16 @@ def ring_fold(kind: str, plane: jax.Array, ring_idx: jax.Array,
                 lambda row: row, row)
 
         def fold(plane):
-            row = jax.lax.dynamic_index_in_dim(plane, r, 0, keepdims=False)
-            row = jax.lax.fori_loop(0, -(-n // chunk), fold_chunk, row)
-            return jax.lax.dynamic_update_index_in_dim(plane, row, r, 0)
+            # of a ``Halves`` plane: the words of ring row r joined,
+            # folded as the 64-bit row they are, and split for the
+            # write-back; no other row of the plane is read
+            row = jax.lax.fori_loop(0, -(-n // chunk), fold_chunk,
+                                    plane_row(plane, r))
+            if isinstance(plane, Halves):
+                row = Halves.split(row)
+            return plane_map(
+                lambda words, new: jax.lax.dynamic_update_index_in_dim(
+                    words, new, r, 0), plane, row)
 
         return jax.lax.cond(mine.any(), fold, lambda plane: plane, plane)
 

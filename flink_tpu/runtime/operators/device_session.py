@@ -76,7 +76,8 @@ _POS = np.int64(1 << 62)
 @instrumented_program_cache("device_session.step", maxsize=64)
 def _sess_step(fold_sig: tuple, lanes: int, gap: int, dirty_block: int):
     """One fused program per batch. ``fold_sig``: (kind, name, field)."""
-    from ...ops.segment_ops import scatter_fold
+    from ...ops.segment_ops import planes_joined, planes_stored_like, \
+        scatter_fold
 
     L = lanes
     donate = (0, 1, 2, 3, 4, 5)
@@ -84,6 +85,10 @@ def _sess_step(fold_sig: tuple, lanes: int, gap: int, dirty_block: int):
     @partial(jax.jit, donate_argnums=donate)
     def step(table, planes, cur_lane, dropped, late, dirty, keys, ts, cols,
              n_valid, fired_boundary):
+        # the backend stores a 64-bit lanes plane as its two 32-bit words
+        # (ops/segment_ops.Halves); this program reads and rewrites whole
+        # planes, so it joins them here and splits them at its exit
+        stored, planes = planes, planes_joined(planes)
         B = keys.shape[0]
         cap = cur_lane.shape[0]
         in_batch = jnp.arange(B) < n_valid
@@ -237,8 +242,8 @@ def _sess_step(fold_sig: tuple, lanes: int, gap: int, dirty_block: int):
         ecount = jnp.zeros(B, jnp.int64).at[tgt].set(scount, mode="drop")
         evals = {name: jnp.zeros(B, svals[name].dtype).at[tgt].set(
             svals[name], mode="drop") for name in svals}
-        return (table, out, cur_lane, dropped, late, dirty,
-                n_emit, ekey, estart, eend, ecount, evals)
+        return (table, planes_stored_like(stored, out), cur_lane, dropped,
+                late, dirty, n_emit, ekey, estart, eend, ecount, evals)
 
     return step
 
@@ -260,8 +265,11 @@ def _sess_fire(agg_sig: tuple, lanes: int, gap: int):
     planes, the fired count, and an overflow count (fired sessions beyond
     the buffer stay open for the next scan — the host loops)."""
 
+    from ...ops.segment_ops import planes_joined, planes_stored_like
+
     @jax.jit
     def fire(table, planes, boundary):
+        stored, planes = planes, planes_joined(planes)   # as in the step
         L, cap = planes["__open__"].shape
         end = planes["__end__"]
         fire_mask = ((planes["__open__"] > 0)
@@ -320,7 +328,8 @@ def _sess_fire(agg_sig: tuple, lanes: int, gap: int):
                 ident = 0
             new[plane] = jnp.where(rs, jnp.asarray(ident, arr.dtype), arr)
         fired = jnp.minimum(n_fired, jnp.int64(cap))
-        return new, out_keys, out_start, out_end, outs, fired, overflow
+        return (planes_stored_like(stored, new), out_keys, out_start,
+                out_end, outs, fired, overflow)
 
     return fire
 

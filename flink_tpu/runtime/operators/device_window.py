@@ -201,17 +201,20 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
     (a compile costs seconds to a minute on the chip). ``pane_rows`` is
     therefore PADDED to the window width with a
     validity mask instead of varying in shape."""
-    from ...ops.segment_ops import AGG_INITS, AGG_MERGES
+    from ...ops.segment_ops import AGG_INITS, AGG_MERGES, plane_take
 
     @jax.jit
     def fire_fn(table, arrays, pane_rows, rows_valid, dropped):
         # named regions (module names stay, the benchmark matches them):
         # an op's name path (HLO op_name; tf_op in a TPU trace's op
         # metadata) says fire.merge / fire.topk, whatever fusion number
-        # the compiler gave it
+        # the compiler gave it. A plane stored as its 32-bit words
+        # (ops/segment_ops.Halves) has the window's rows, or the
+        # winners' cells, gathered of each word and joined after the
+        # gather: the fire reads W ring rows of a plane, never all of it
         def merge(kind, arr):
             with jax.named_scope("fire.merge"):
-                sub = arr[pane_rows]                    # [W, cap]
+                sub = plane_take(arr, lambda a: a[pane_rows])  # [W, cap]
                 ident = AGG_INITS[kind](arr.dtype)
                 sub = jnp.where(rows_valid[:, None], sub, ident)
                 return AGG_MERGES[kind](sub, axis=0)
@@ -223,7 +226,8 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
             # full-capacity read. (NOT arr[pane_rows][:, idx]: the
             # chained form materializes the [W, cap] intermediate.)
             with jax.named_scope("fire.merge"):
-                sub = arr[pane_rows[:, None], idx[None, :]]
+                sub = plane_take(
+                    arr, lambda a: a[pane_rows[:, None], idx[None, :]])
                 ident = AGG_INITS[kind](arr.dtype)
                 sub = jnp.where(rows_valid[:, None], sub, ident)
                 return AGG_MERGES[kind](sub, axis=0)
@@ -301,7 +305,7 @@ def _seal_program(inv_sig: tuple, tree_sig: tuple):
     is sized by the ring), so seal programs are shared across window
     configurations."""
     from ...ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
-        merge_tree_update
+        merge_tree_update, plane_row
 
     @partial(jax.jit, donate_argnums=(1, 2))
     def seal_fn(arrays, wins, trees, new_row, sub_row, sub_valid,
@@ -309,11 +313,9 @@ def _seal_program(inv_sig: tuple, tree_sig: tuple):
         view, new_wins, new_trees = {}, {}, {}
         for kind, name in inv_sig:
             arr = arrays[name]
-            new_pane = jax.lax.dynamic_index_in_dim(arr, new_row, 0,
-                                                    keepdims=False)
+            new_pane = plane_row(arr, new_row)
             fire_v = AGG_COMBINE2[kind](wins[name], new_pane)
-            sub_pane = jax.lax.dynamic_index_in_dim(arr, sub_row, 0,
-                                                    keepdims=False)
+            sub_pane = plane_row(arr, sub_row)
             retire = jnp.where(sub_valid, sub_pane,
                                AGG_INITS[kind](arr.dtype))
             view[name] = fire_v
@@ -325,9 +327,8 @@ def _seal_program(inv_sig: tuple, tree_sig: tuple):
             # clear the retiring pane's leaf FIRST: its position can never
             # be a live pane's (any two live panes differ by < tree size)
             tree = merge_tree_update(kind, trees[name], old_leaf, ident)
-            new_pane = jax.lax.dynamic_index_in_dim(arr, new_row, 0,
-                                                    keepdims=False)
-            tree = merge_tree_update(kind, tree, new_leaf, new_pane)
+            tree = merge_tree_update(kind, tree, new_leaf,
+                                     plane_row(arr, new_row))
             view[name] = tree[1]
             new_trees[name] = tree
         return view, new_wins, new_trees
@@ -345,7 +346,7 @@ def _rebuild_program(inv_sig: tuple, tree_sig: tuple, tree_size: int):
     stays W-independent) and returns this fire's view plus consistent
     next-state accumulators/trees."""
     from ...ops.segment_ops import AGG_INITS, AGG_INVERT, AGG_MERGES, \
-        merge_tree_build
+        merge_tree_build, plane_row, plane_take
 
     L = tree_size
 
@@ -356,17 +357,18 @@ def _rebuild_program(inv_sig: tuple, tree_sig: tuple, tree_size: int):
         for kind, name in inv_sig:
             arr = arrays[name]
             ident = AGG_INITS[kind](arr.dtype)
-            sub = jnp.where(rows_valid[:, None], arr[pane_rows], ident)
+            sub = jnp.where(rows_valid[:, None],
+                            plane_take(arr, lambda a: a[pane_rows]), ident)
             fire_v = AGG_MERGES[kind](sub, axis=0)
             view[name] = fire_v
-            sub_pane = jax.lax.dynamic_index_in_dim(arr, sub_row, 0,
-                                                    keepdims=False)
+            sub_pane = plane_row(arr, sub_row)
             retire = jnp.where(sub_valid, sub_pane, ident)
             new_wins[name] = AGG_INVERT[kind](fire_v, retire)
         for kind, name in tree_sig:
             arr = arrays[name]
             ident = AGG_INITS[kind](arr.dtype)
-            rows = jnp.where(rows_valid[:, None], arr[pane_rows], ident)
+            rows = jnp.where(rows_valid[:, None],
+                             plane_take(arr, lambda a: a[pane_rows]), ident)
             leaves = jnp.full((L,) + arr.shape[1:], ident, arr.dtype)
             lidx = jnp.where(rows_valid, pane_leaves, L)
             leaves = leaves.at[lidx].set(rows, mode="drop")
@@ -628,6 +630,13 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 self._backend.register_array_state(
                     a.out_name, a.kind, a.dtype, ring=self._ring)
         self._registered = True
+        # every plane of the job exists now (the incremental engine's
+        # derived ones too: a reclaim re-seats them) and no input has
+        # been taken: the reclaim of these planes is built here, not
+        # when a reading finds the table full (ROADMAP D14)
+        if self._inc_enabled:
+            self._ensure_inc_planes(*self._inc_sigs())
+        self._backend.prepare_reclaim()
 
     def initialize_state(self, keyed_snapshots: list, operator_snapshot) -> None:
         if keyed_snapshots:
@@ -1268,6 +1277,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                     tn, kind, self._backend.get_array(name).dtype,
                     ring=2 * self._tree_size, role="window")
                 self._inc_dirty = True
+        self._backend.prepare_reclaim()   # nothing, unless a plane is new
 
     def _fire_incremental(self, p_end: int, first: int) -> None:
         """O(capacity) fire: seal the newest pane into the running window

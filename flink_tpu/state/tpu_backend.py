@@ -46,8 +46,8 @@ from ..ops.hash_table import (
     EMPTY_KEY, compacts, hash_keys_device, lookup, lookup_or_insert,
     make_table, sanitize_keys_device,
 )
-from ..ops.segment_ops import AGG_INITS, make_accumulator, ring_fold, \
-    scatter_fold
+from ..ops.segment_ops import AGG_INITS, Halves, identity_words, \
+    make_plane, plane_map, ring_fold, scatter_fold, stores_halves
 from .backend import KeyedStateBackend, State, ValueState, register_backend
 from .descriptors import StateDescriptor
 from .spill import HostTier
@@ -102,10 +102,13 @@ def _reset_row_program(sig: tuple):
         out = []
         with jax.named_scope("fire.reset"):
             for (kind, _dt, _shape), a in zip(sig, arrays):
-                fill = jnp.full((1,) + a.shape[1:],
-                                AGG_INITS[kind](a.dtype), a.dtype)
-                out.append(
-                    jax.lax.dynamic_update_slice_in_dim(a, fill, row, 0))
+                # the identity (its two words, for halves) into ONE row
+                # of a donated buffer: no pass over the plane
+                fill = make_plane(kind, (1,) + a.shape[1:], a.dtype,
+                                  isinstance(a, Halves))
+                out.append(plane_map(
+                    lambda words, ident: jax.lax.dynamic_update_slice_in_dim(
+                        words, ident, row, 0), a, fill))
         return tuple(out)
 
     return reset
@@ -132,12 +135,6 @@ def _fold_program(sig: tuple):
     return fold
 
 
-#: health readings (one a fired window) the reclaim's program is built
-#: ahead of the one that would find the table past its load limit, at the
-#: pace the last two readings showed: the build must have ended by then,
-#: and on a cold compile cache it takes half a minute at 2^23 slots
-_RECLAIM_LOOKAHEAD = 8
-
 #: live keys the reclaim re-homes at a time (one ``lookup_or_insert`` a
 #: chunk, inside the program; the last chunk costs what a full one does,
 #: so a quarter of a Q5 batch, which keeps a reclaim's time in step with
@@ -158,10 +155,29 @@ def _sorted_slots(rank: jax.Array, ranks: int) -> jax.Array:
     return (keyed % C).astype(jnp.int32)
 
 
-def _permute(where: jax.Array, values: jax.Array) -> jax.Array:
+def _permute(where: jax.Array, values):
     """``out[where[i]] = values[i]`` for a permutation ``where`` of
-    ``0..C-1``, as a sort by it (distinct keys: no stability needed)."""
-    return jax.lax.sort((where, values), num_keys=1, is_stable=False)[1]
+    ``0..C-1``, as a sort by it (distinct keys: no stability needed);
+    the two words of a ``Halves`` row ride ONE sort as its two payloads
+    (what the compiler makes of a 64-bit payload anyway)."""
+    words, row = jax.tree_util.tree_flatten(values)
+    return row.unflatten(jax.lax.sort((where, *words), num_keys=1,
+                                      is_stable=False)[1:])
+
+
+def _identity(kind: str, plane):
+    """The aggregate's identity in ``plane``'s layout: the two words for
+    a ``Halves`` plane."""
+    return (identity_words(kind, plane.dtype) if isinstance(plane, Halves)
+            else AGG_INITS[kind](plane.dtype))
+
+
+def _differs(plane, ident) -> jax.Array:
+    """Where a plane (or a row of one) is not ``ident`` (``_identity``):
+    a ``Halves`` plane is tested word against word, never joined."""
+    if isinstance(plane, Halves):
+        return (plane.hi != ident.hi) | (plane.lo != ident.lo)
+    return plane != ident
 
 
 def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
@@ -174,7 +190,11 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     every plane re-seated onto the new slots, at fixed shapes. ``sig`` =
     tuple of (kind, dtype_str, shape) over ALL of the table's array
     states, as ``_reset_row_program``'s; ``live_planes`` indexes the ones
-    that decide what lives (the pane-role ring planes).
+    that decide what lives (the pane-role ring planes). A plane is one
+    array (the mesh's, and the one-chip backend's narrow and window-role
+    planes) or the ``Halves`` of a 64-bit one (the one-chip backend's
+    pane-role ring planes): told apart by what is handed in, tested and
+    moved word by word, and handed back as it came.
 
     * ``reclaim.live``: a slot lives iff it is occupied and some ring row
       of some ``live_planes`` plane differs from its aggregate's identity
@@ -214,9 +234,8 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
         occupied = table != empty
         holds = jnp.zeros(C, bool)
         for i in live_planes:
-            a = arrays[i]
-            holds = holds | (a != AGG_INITS[sig[i][0]](a.dtype)).any(
-                axis=0)
+            holds = holds | _differs(
+                arrays[i], _identity(sig[i][0], arrays[i])).any(axis=0)
         live = occupied & holds
         home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
             jnp.int32) == slot
@@ -253,13 +272,14 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     with jax.named_scope("reclaim.remap"):
         out = []
         for (kind, _dt, _shape), a in zip(sig, arrays):
-            ident = AGG_INITS[kind](a.dtype)
+            ident = _identity(kind, a)
 
             def reseat(row, ident=ident):
                 return jax.lax.cond(
-                    (row != ident).any(),
-                    lambda r: jnp.where(landed, _permute(dest, r),
-                                        ident),
+                    _differs(row, ident).any(),
+                    lambda r: plane_map(
+                        lambda moved, i: jnp.where(landed, moved, i),
+                        _permute(dest, r), ident),
                     lambda r: r, row)
 
             if a.ndim == 1:
@@ -267,10 +287,12 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
                 continue
 
             def body(r, plane, reseat=reseat):
-                row = jax.lax.dynamic_index_in_dim(plane, r, 0,
-                                                   keepdims=False)
-                return jax.lax.dynamic_update_index_in_dim(
-                    plane, reseat(row), r, 0)
+                row = plane_map(
+                    lambda words: jax.lax.dynamic_index_in_dim(
+                        words, r, 0, keepdims=False), plane)
+                return plane_map(
+                    lambda words, new: jax.lax.dynamic_update_index_in_dim(
+                        words, new, r, 0), plane, reseat(row))
 
             out.append(jax.lax.fori_loop(0, a.shape[0], body, a))
     return (new_table, tuple(out), dropped + lost.astype(dropped.dtype),
@@ -371,15 +393,42 @@ class _ArrayState:
         # retirement and conform_ring — a restore simply rebuilds them
         # from the pane planes.
         self.role = role
+        # a pane-role ring plane of a 64-bit integer is STORED as its two
+        # 32-bit words (ops/segment_ops.Halves), on every platform: every
+        # program takes and returns the words, and 64-bit values exist
+        # only inside a program, of the rows or cells it has sliced
         shape = (ring, capacity) if ring else (capacity,)
-        self.array = make_accumulator(kind, shape, dtype)
+        self.array = make_plane(kind, shape, dtype,
+                                stores_halves(dtype, ring, role))
 
 
 def _plane_sig(states) -> tuple:
-    """What the plane programs (reset, fold) are cached by: (kind,
-    dtype_str, shape) of each plane they take."""
-    return tuple((st.kind, str(st.array.dtype), st.array.shape)
+    """What the plane programs (reset, fold, reclaim) are cached by:
+    (kind, dtype_str, shape) of each plane they take; a plane stored as
+    ``Halves`` says so in its dtype_str (``halves:int64``)."""
+    return tuple((st.kind,
+                  ("halves:" if isinstance(st.array, Halves) else "")
+                  + str(st.array.dtype), st.array.shape)
                  for st in states)
+
+
+def _to_host(plane) -> np.ndarray:
+    """A plane (or a part of one) on the host, in its own dtype and
+    writable: a ``Halves`` joined with numpy."""
+    # lint: sync-ok the snapshot, spill and rebuild paths' one transfer a plane
+    host = jax.device_get(plane)
+    return host.join() if isinstance(host, Halves) else np.array(host)
+
+
+def _in_layout(plane, values):
+    """``values`` (the plane's own dtype, numpy or device) in ``plane``'s
+    stored layout: split into words for a ``Halves`` plane."""
+    if not isinstance(plane, Halves):
+        return jnp.asarray(values)
+    if isinstance(values, np.ndarray):
+        return Halves.split(values.astype(plane.dtype, copy=False)).map(
+            jnp.asarray)
+    return Halves.split(jnp.asarray(values, plane.dtype))
 
 
 class TpuKeyedStateBackend(KeyedStateBackend):
@@ -431,11 +480,8 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # health reading taken under an older one says nothing of this
         # table (table_generation, apply_health)
         self._generation = 0
-        # the last reading of this generation and the growth it showed
-        # over the one before (None: not two readings yet): the trend
-        # that builds the reclaim's program ahead (apply_health)
-        self._trend: tuple = (None, None)
         # plane signature the reclaim's program was last built ahead for
+        # (prepare_reclaim)
         self._reclaim_built: Optional[tuple] = None
         # a reclaim dispatched whose counts have not landed yet:
         # (device [kept, freed], the caller's open stage span)
@@ -616,8 +662,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         if self._mirror is None:
             # writable copies: device_get may return read-only views
             t = np.array(jax.device_get(self.table))
-            arrs = {n: np.array(jax.device_get(st.array))
-                    for n, st in snap_states}
+            arrs = {n: _to_host(st.array) for n, st in snap_states}
             self._mirror = {"table": t, "arrays": arrs}
             self.last_snapshot_dma_bytes = t.nbytes + sum(
                 a.nbytes for a in arrs.values())
@@ -625,7 +670,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             arrs = self._mirror["arrays"]
             for n, st in snap_states:
                 if n not in arrs:
-                    a = np.array(jax.device_get(st.array))
+                    a = _to_host(st.array)
                     arrs[n] = a
                     self.last_snapshot_dma_bytes += a.nbytes
             # ① replay ring-row retirements host-side (no DMA)
@@ -633,7 +678,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 for n, st in snap_states:
                     if st.ring:
                         arrs[n][row, :] = np.asarray(
-                            AGG_INITS[st.kind](st.array.dtype))
+                            AGG_INITS[st.kind](st.dtype))
             # ② patch dirty blocks: gather on device, ONE transfer
             d = np.asarray(jax.device_get(self._dirty))
             self.last_snapshot_dma_bytes += d.nbytes
@@ -642,14 +687,14 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 bidx = jnp.asarray(blocks)
                 parts = {"__table__": self.table.reshape(nb, bs)[bidx]}
                 for n, st in snap_states:
-                    if st.ring:
-                        parts[n] = st.array.reshape(
-                            st.array.shape[0], nb, bs)[:, bidx]
-                    else:
-                        parts[n] = st.array.reshape(nb, bs)[bidx]
+                    # the blocks of each word of a halves plane: the same
+                    # bytes cross, and the host joins them
+                    parts[n] = plane_map(
+                        lambda a: a.reshape(a.shape[:-1] + (nb, bs))[
+                            ..., bidx, :], st.array)
                 host = jax.device_get(parts)
                 self.last_snapshot_dma_bytes += sum(
-                    np.asarray(v).nbytes for v in host.values())
+                    v.nbytes for v in host.values())
                 self._mirror["table"].reshape(nb, bs)[blocks] = \
                     np.asarray(host["__table__"])
                 for n, st in snap_states:
@@ -688,14 +733,14 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self._num_keys = len(keep_keys)
         for name, st in self._array_states.items():
             shape = ((st.ring, new_capacity) if st.ring else (new_capacity,))
-            new_arr = make_accumulator(st.kind, shape, st.dtype)
+            new_arr = make_plane(st.kind, shape, st.dtype,
+                                 isinstance(st.array, Halves))
             if len(keep_keys):
-                if st.ring:
-                    new_arr = new_arr.at[:, new_slots].set(
-                        old_arrays[name][:, jnp.asarray(old_slots)])
-                else:
-                    new_arr = new_arr.at[new_slots].set(
-                        old_arrays[name][jnp.asarray(old_slots)])
+                # cells move as they are: word by word for a halves plane
+                new_arr = plane_map(
+                    lambda new, old: new.at[..., new_slots].set(
+                        old[..., jnp.asarray(old_slots)]),
+                    new_arr, old_arrays[name])
             st.array = new_arr
         self._invalidate_mirror()
         self._new_generation()
@@ -853,9 +898,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         if sel.any():
             values = {}
             for name, st in self._snapshot_states():
-                arr = np.asarray(jax.device_get(st.array))
-                values[name] = (arr[:, slots_dev[sel]] if st.ring
-                                else arr[slots_dev[sel]])
+                values[name] = _to_host(st.array)[..., slots_dev[sel]]
             host.absorb(keys_dev[sel], values)
         host.spilled_mask[np.asarray(groups, np.int64)] = True
         if sel.any() or cap != self.capacity:
@@ -998,7 +1041,9 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             if pad:
                 v = np.concatenate(
                     [v, np.zeros(v.shape[:-1] + (pad,), v.dtype)], axis=-1)
-            dvals[name] = jnp.asarray(v)
+            # staged in the plane's stored layout (the words of a halves
+            # plane are split here, with numpy, off the mailbox thread)
+            dvals[name] = _in_layout(self._array_states[name].array, v)
         return {"groups": groups, "version": version, "n": n,
                 "dkeys": jnp.asarray(pkeys), "valid": jnp.asarray(valid),
                 "values": dvals}
@@ -1033,11 +1078,9 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self._num_keys += n
         widx = jnp.where(payload["valid"], slots, self.capacity)
         for name, st in self._snapshot_states():
-            dv = payload["values"][name]
-            if st.ring:
-                st.array = st.array.at[:, widx].set(dv, mode="drop")
-            else:
-                st.array = st.array.at[widx].set(dv, mode="drop")
+            st.array = plane_map(
+                lambda a, v: a.at[..., widx].set(v, mode="drop"),
+                st.array, payload["values"][name])
         host.drop_groups(groups)
         self._sync_spilled_dev()
         self.mark_dirty(slots)
@@ -1067,10 +1110,16 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         return [(n, st) for n, st in self._array_states.items()
                 if st.role != "window"]
 
-    def get_array(self, name: str) -> jax.Array:
+    def get_array(self, name: str):
+        """The plane as it is stored: one array, or the ``Halves`` (two
+        ``uint32`` arrays, high and low words) of a pane-role ring plane
+        of a 64-bit integer. ``shape`` and ``dtype`` read as the plane's
+        either way; ``np.asarray`` of a ``Halves`` joins on the host.
+        A program takes what this hands out and ``set_array`` takes back
+        what the program returns."""
         return self._array_states[name].array
 
-    def set_array(self, name: str, array: jax.Array) -> None:
+    def set_array(self, name: str, array) -> None:
         self._array_states[name].array = array
 
     def fold_batch(self, name: str, slots: jax.Array, values,
@@ -1218,7 +1267,6 @@ class TpuKeyedStateBackend(KeyedStateBackend):
 
     def _new_generation(self) -> None:
         self._generation += 1
-        self._trend = (None, None)
 
     def apply_health(self, dropped: int, occupancy: int,
                      generation: Optional[int] = None, stage=None) -> None:
@@ -1232,10 +1280,8 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         a table that has been rebuilt since says nothing of this one and
         is passed over (None: the reading is of the table as it is).
         ``stage`` opens the caller's span around a reclaim (``reclaim``).
-
-        Two readings in a row that show the table growing, at a pace that
-        would take it past the load limit within ``_RECLAIM_LOOKAHEAD``
-        more, build the reclaim's program (``_prepare_reclaim``)."""
+        The reclaim's program was built before the job's first input and
+        after each growth (``prepare_reclaim``): no reading builds it."""
         if int(dropped) > 0:
             if self._budget:
                 raise RuntimeError(
@@ -1253,17 +1299,9 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 generation is not None and generation != self._generation):
             return
         self._num_keys = int(occupancy)
-        last, before = self._trend
-        growth = None if last is None else self._num_keys - last
-        self._trend = (self._num_keys, growth)
-        threshold = 0.6 * self.capacity
-        if self._num_keys <= threshold:
-            # the slower of the last two steps, if both were growth
-            pace = min(before, growth) if before is not None else 0
-            if pace > 0 and self._num_keys + _RECLAIM_LOOKAHEAD * pace \
-                    > threshold:
-                self._prepare_reclaim()
-        elif self._reclaimable():
+        if self._num_keys <= 0.6 * self.capacity:
+            return
+        if self._reclaimable():
             self.reclaim(stage, wait=False)
         else:
             self._grow()
@@ -1271,6 +1309,9 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def _grow(self) -> None:
         if not self._budget or 2 * self.capacity <= self._budget:
             self._rehash(self.capacity * 2)
+            # the planes have a new shape: their reclaim is built now,
+            # in the turn that grew, before the next input
+            self.prepare_reclaim()
         else:
             self._sync_touch_from_device()
             self._evict_cold_groups()
@@ -1293,18 +1334,24 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 (self.table, tuple(st.array for st in states),
                  self._dropped))
 
-    def _prepare_reclaim(self) -> None:
-        """Build the reclaim's program for the planes as they are now,
-        ahead of the reading that will ask for it, so that the reclaim
-        itself compiles nothing (a job may promise to build nothing once
-        it is warm). The build blocks the turn it runs in, as every
-        program's first use does and as each growth's did: a job that
-        compiled it on another thread would go on firing windows, but a
-        build that ENDS once the job has promised to build nothing breaks
-        that promise from any thread, and only a job that stops taking
-        input makes its source wait (PERF.md section 7)."""
+    def prepare_reclaim(self) -> None:
+        """Build the reclaim's program for the planes as they are now, so
+        that the reclaim itself compiles nothing (a job may promise to
+        build nothing once it is warm): called by the operator when its
+        planes are registered, before its first input, and by ``_grow``
+        after each growth, as the mesh operator prepares its own in
+        ``_build``. WHEN a table will first pass its load limit no
+        reading can tell in time (two windows that fire back to back read
+        one occupancy; a build started at the prefill's last fire ends in
+        the timed phase: ROADMAP D14), so it is not guessed. Only a
+        backend that decides by deferred health readings and can tell
+        what lives builds one; the build blocks the turn it runs in, as
+        every program's first use does: a build that ENDS once the job
+        has promised to build nothing breaks that promise from any
+        thread (PERF.md section 7)."""
         sig = _plane_sig(self._array_states.values())
-        if sig == self._reclaim_built or not self._reclaimable():
+        if sig == self._reclaim_built or not self._defer \
+                or not self._reclaimable():
             return
         program, args = self._reclaim_call()
         program.prepare(*jax.tree_util.tree_map(
@@ -1385,9 +1432,12 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                     f"cannot conform ring {st.ring} -> {ring}: "
                     f"{len(live)} panes are live; increase ring_size")
             old = st.array
-            new = make_accumulator(st.kind, (ring, self.capacity), st.dtype)
+            new = make_plane(st.kind, (ring, self.capacity), st.dtype,
+                             isinstance(old, Halves))
             for p in live:
-                new = new.at[p % ring].set(old[p % st.ring])
+                new = plane_map(
+                    lambda new, old, p=p: new.at[p % ring].set(
+                        old[p % st.ring]), new, old)
             st.array = new
             st.ring = ring
             self._invalidate_mirror()
@@ -1644,11 +1694,13 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             st = _ArrayState(name, meta["kind"], dtype, meta["ring"],
                              self.capacity)
             if len(keys):
-                vals = (np.concatenate(per_state_vals[name], axis=-1))
-                if meta["ring"]:
-                    st.array = st.array.at[:, slots].set(jnp.asarray(vals))
-                else:
-                    st.array = st.array.at[slots].set(jnp.asarray(vals))
+                # the snapshot's int64 values into the stored layout (the
+                # words split with numpy: a snapshot's bytes are the same
+                # whatever layout wrote or reads it)
+                st.array = plane_map(
+                    lambda a, v: a.at[..., slots].set(v), st.array,
+                    _in_layout(st.array, np.concatenate(
+                        per_state_vals[name], axis=-1)))
             self._array_states[name] = st
         # restored state may exceed the HBM budget: page the overflow out
         # immediately (fresh LRU; group order decides coldness)

@@ -114,3 +114,118 @@ class TestCommittedFixtureMigration:
         ga = cp.task_snapshots["v2#0"]["chain"]["sum"]["keyed"]["backend"]
         entry = ga["group-agg"][5][(1, "x")]
         np.testing.assert_array_equal(entry, np.array([2.0, 9.0]))
+
+
+# -- a 64-bit ring plane's stored layout is not a snapshot's format ---------
+
+INT64_PLANES = os.path.join(os.path.dirname(__file__), "fixtures",
+                            "int64_planes")
+
+
+def _int64_planes_job():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "int64_planes_make", os.path.join(INT64_PLANES, "make.py"))
+    make = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(make)
+    return make
+
+
+@pytest.fixture(scope="module")
+def parent_snapshots():
+    """What the parent of PR 42 wrote, whose backend kept a 64-bit ring
+    plane as ONE int64 array: {flat name: array}, two snapshots."""
+    with np.load(os.path.join(INT64_PLANES, "snapshots.npz")) as z:
+        return {name: z[name] for name in z.files}
+
+
+class TestInt64PlanesSnapshotCompatibility:
+    """Since PR 42 the one-chip backend stores a pane-role ring plane of
+    a 64-bit integer as its two 32-bit words (`ops/segment_ops.Halves`).
+    That is a layout on the device, not a format: a snapshot holds int64
+    arrays as it always did, byte for byte."""
+
+    @pytest.mark.parametrize("between", ["nothing", "a_reclaim"])
+    def test_todays_snapshots_are_byte_equal_to_the_parents(
+            self, parent_snapshots, between):
+        """The same job, run on the backend as it is now: a full snapshot
+        and an incremental one (dirty blocks, a retired ring row replayed
+        on the host) hold the parent's bytes, names, dtypes and shapes; a
+        reclaim between the two moves every slot and none of the bytes."""
+        make = _int64_planes_job()
+
+        def reclaim(be):
+            be.reclaim()
+
+        snaps = make.run_job(TpuKeyedStateBackend,
+                             reclaim if between == "a_reclaim" else None)
+        for snap in snaps:
+            for name, _kind, dtype in make.PLANES:
+                st = snap["states"][name]
+                assert (st["dtype"], st["ring"]) == (np.dtype(dtype).name,
+                                                     make.RING)
+        mine = make.flatten(snaps)
+        assert list(mine) == list(parent_snapshots)
+        for name, theirs in parent_snapshots.items():
+            assert mine[name].dtype == theirs.dtype, name
+            assert mine[name].shape == theirs.shape, name
+            assert mine[name].tobytes() == theirs.tobytes(), name
+
+    @pytest.mark.parametrize("which", [0, 1], ids=["full", "incremental"])
+    def test_a_parents_snapshot_restores_into_halves(self, parent_snapshots,
+                                                     which):
+        """int64 arrays on the wire, two uint32 words a plane on the
+        device: every key's every cell as the parent wrote it, and the
+        snapshot taken straight back is the one that went in."""
+        from flink_tpu.ops.hash_table import lookup
+        from flink_tpu.ops.segment_ops import Halves
+
+        make = _int64_planes_job()
+        snap = {"kind": "tpu", "max_parallelism": 128,
+                "keys": parent_snapshots[f"{which}/keys"],
+                "key_groups": parent_snapshots[f"{which}/key_groups"],
+                "states": {name: {
+                    "kind": kind, "dtype": np.dtype(dtype).name,
+                    "ring": make.RING,
+                    "values": parent_snapshots[f"{which}/states/{name}"]}
+                    for name, kind, dtype in make.PLANES}}
+        b = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=512)
+        b.restore([snap])
+        slots = np.asarray(lookup(b.table, jax.numpy.asarray(snap["keys"])))
+        assert (slots >= 0).all()
+        for name, _kind, dtype in make.PLANES:
+            plane = b.get_array(name)
+            wide = np.dtype(dtype).itemsize == 8
+            assert isinstance(plane, Halves) == wide, name
+            if wide:
+                assert plane.hi.dtype == plane.lo.dtype == np.uint32
+                assert plane.hi.shape == plane.lo.shape == plane.shape
+            assert (plane.dtype, plane.shape) == (np.dtype(dtype),
+                                                  (make.RING, b.capacity))
+            np.testing.assert_array_equal(
+                np.asarray(plane)[:, slots], snap["states"][name]["values"])
+        again = b.snapshot(3)
+        assert again["keys"].tobytes() == snap["keys"].tobytes()
+        for name, st in snap["states"].items():
+            got = again["states"][name]
+            assert got["values"].dtype == st["values"].dtype
+            assert got["values"].tobytes() == st["values"].tobytes(), name
+
+    def test_a_snapshot_of_halves_restores_into_a_smaller_ring(
+            self, parent_snapshots):
+        """`conform_ring` re-seats the live panes of a restored plane
+        word by word."""
+        make = _int64_planes_job()
+        b = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128)
+        snap = make.run_job(TpuKeyedStateBackend)[1]
+        b.restore([snap])
+        b.conform_ring(3, [2, 3])
+        plane = np.asarray(b.get_array("revenue"))
+        assert plane.shape == (3, b.capacity)
+        from flink_tpu.ops.hash_table import lookup
+        slots = np.asarray(lookup(b.table, jax.numpy.asarray(snap["keys"])))
+        vals = snap["states"]["revenue"]["values"]
+        np.testing.assert_array_equal(plane[2 % 3, slots], vals[2])
+        np.testing.assert_array_equal(plane[3 % 3, slots], vals[3])
+        assert (plane[1] == 0).all()

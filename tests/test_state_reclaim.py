@@ -38,10 +38,19 @@ PLANES = (("__count__", "count", jnp.int32), ("revenue", "sum", jnp.int64),
 
 # -- (a) the program against a dict-based model ----------------------------
 
-def _seeded_backend(capacity: int, seed: int):
+def _planes(wide):
+    """PLANES with the two value planes at ``wide``: int64 planes are
+    stored as their 32-bit words (``ops/segment_ops.Halves``), int32 ones
+    as the arrays they are; the reclaim is one algorithm for both."""
+    return tuple((name, kind, dtype if name == "__count__" else wide)
+                 for name, kind, dtype in PLANES)
+
+
+def _seeded_backend(capacity: int, seed: int, wide=jnp.int64):
     """A backend at load 0.62 whose keys hold data in random ring rows,
     most of them only in rows that have since been retired; and the
     model: key -> {plane: the key's ring column}."""
+    PLANES = _planes(wide)
     rng = np.random.default_rng(seed)
     be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=capacity,
                               defer_overflow=True)
@@ -69,7 +78,8 @@ def _seeded_backend(capacity: int, seed: int):
             sel = np.ones(batch, bool) if rows[0] == 0 \
                 else np.array([p in late for p in pos])
             ring = rng.integers(*rows, size=batch)
-            price = rng.integers(1, 1 << 40, size=batch)
+            price = rng.integers(
+                1, 1 << (40 if wide == jnp.int64 else 20), size=batch)
             slots = be.slots_for_batch_device(jnp.asarray(k))
             be.fold_rings(slots, ring, (slots >= 0) & jnp.asarray(sel),
                           {"__count__": None, "revenue": price,
@@ -94,9 +104,17 @@ def _seeded_backend(capacity: int, seed: int):
     return be, model, win_of
 
 
+@pytest.mark.parametrize("wide", [jnp.int64, jnp.int32],
+                         ids=["halves", "arrays"])
 @pytest.mark.parametrize("capacity", [1 << 10, 1 << 11, 1 << 12])
-def test_reclaim_keeps_every_live_key_bit_equal_and_no_dead_one(capacity):
-    be, model, win_of = _seeded_backend(capacity, seed=capacity)
+def test_reclaim_keeps_every_live_key_bit_equal_and_no_dead_one(capacity,
+                                                                wide):
+    from flink_tpu.ops.segment_ops import Halves
+
+    PLANES = _planes(wide)
+    be, model, win_of = _seeded_backend(capacity, seed=capacity, wide=wide)
+    assert [isinstance(be.get_array(name), Halves)
+            for name, _k, _d in PLANES] == [False] + 2 * [wide == jnp.int64]
     live = {k for k, m in model.items() if m["__count__"].any()}
     assert 0 < len(live) < 0.45 * capacity < len(model)
     before = DEVICE_STATS.snapshot()
@@ -186,45 +204,145 @@ def _counting_backend(capacity: int, ring: int = 3):
     return be, bid
 
 
-def test_the_reclaim_program_is_built_when_the_table_heads_for_its_limit():
-    """Two readings in a row that show the table growing, at a pace that
-    takes it past load 0.6 within the look-ahead, build the program
-    (ahead of need, so that the reclaim compiles nothing where a job has
-    promised to build nothing); the reclaim then runs that executable."""
+def test_the_reclaim_program_is_built_when_asked_and_by_no_reading():
+    """`prepare_reclaim` builds the program for the planes as they are
+    (the operator asks once its planes are registered, before its first
+    input; `_grow` after each growth), so that the reclaim compiles
+    nothing where a job has promised to build nothing. No health reading
+    builds it, whatever trend the readings show: the readings of two
+    windows that fire back to back are one reading (ROADMAP D14)."""
     be, bid = _counting_backend(1 << 10, ring=5)
     program = be._reclaim_call()[0]
-    for n in (30, 60):                       # one reading is no trend
-        bid(n - 30, n)
-        be.check_health()
-        assert program._prepared is None and not program._compiled
-    bid(60, 90)
-    be.check_health()                        # 90 + 8 x 30 < 614: no hurry
-    assert program._prepared is None
-    bid(90, 160)
-    be.check_health()                        # 160 + 8 x 30 < 614 (slower pace)
-    assert program._prepared is None
-    bid(160, 230)
-    be.check_health()                        # 230 + 8 x 70 > 614
+    assert program._prepared is None and not program._compiled
+    be.prepare_reclaim()
     assert program._prepared is not None and program._compiled
-    be.reset_ring_row(0)
-    before = DEVICE_STATS.snapshot()
-    assert be.reclaim() == (0, 230)
+    prepared = program._prepared
+    bid(0, 30)                               # the probe's and the fold's
+    before = DEVICE_STATS.snapshot()         # programs are built
+    for lo, hi in ((30, 60), (60, 60), (60, 160), (160, 230)):
+        if hi > lo:
+            bid(lo, hi)
+        be.check_health()                    # (60, 60): the same reading
+    be.prepare_reclaim()                     # the same planes: nothing
+    assert be.reclaim() == (230, 0)
     after = DEVICE_STATS.snapshot()
     assert after["compiles"] == before["compiles"]
-    assert int((np.asarray(be.table) != EMPTY_KEY).sum()) == 0
+    assert program._prepared is prepared
+    assert int((np.asarray(be.table) != EMPTY_KEY).sum()) == 230
 
 
-def test_a_table_that_stops_growing_short_of_its_limit_builds_nothing():
-    """The fixed-key shape at a small size: the keys arrive, the table
-    settles at load 0.3, and no reading after that builds anything."""
+def test_a_backend_that_was_not_asked_builds_nothing_from_its_readings():
+    """Growing readings that head straight for the load limit (what the
+    trend rule of PRs 35-41 built the program from) build nothing; a
+    backend that cannot reclaim, or that does not decide by deferred
+    health readings, builds nothing even when asked."""
     be, bid = _counting_backend(1 << 10, ring=6)
     program = be._reclaim_call()[0]
-    bid(0, 300)
-    be.check_health()
-    for _ in range(4):
-        bid(0, 300)
+    for n in (100, 200, 300, 400, 500):
+        bid(n - 100, n)
         be.check_health()
     assert program._prepared is None and not program._compiled
+    sync = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=1 << 10)
+    sync.register_array_state("__count__", "count", jnp.int32, ring=7)
+    sync.prepare_reclaim()
+    tiered = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128,
+                                  capacity=1 << 10, defer_overflow=True,
+                                  hbm_budget_slots=1 << 10)
+    tiered.register_array_state("__count__", "count", jnp.int32, ring=7)
+    tiered.prepare_reclaim()
+    for other in (sync, tiered):
+        assert other._reclaim_built is None
+        built = other._reclaim_call()[0]
+        assert built._prepared is None and not built._compiled
+
+
+def test_a_growth_builds_the_reclaim_of_the_grown_planes():
+    """A table whose live keys alone fill it grows, and the turn that
+    grew it builds the reclaim of the new shape before the next input."""
+    be, bid = _counting_backend(1 << 10, ring=9)
+    be.prepare_reclaim()
+    bid(0, 700)
+    be.check_health()                        # all live: reclaims, grows
+    assert be.capacity == 1 << 11
+    program = be._reclaim_call()[0]
+    assert program._prepared is not None and program._compiled
+    before = DEVICE_STATS.snapshot()["compiles"]
+    assert be.reclaim() == (700, 0)
+    assert DEVICE_STATS.snapshot()["compiles"] == before
+
+
+def test_a_job_builds_nothing_once_its_first_windows_have_fired():
+    """ROADMAP D14's guard, in the idiom of `tests/test_mesh_reclaim.py::
+    test_nothing_is_compiled_once_the_first_reclaim_has_been_prepared`:
+    the operator builds the reclaim of its planes before its first input,
+    so a job whose first two windows fire back to back and hand
+    `apply_health` ONE occupancy twice (no trend to read), and whose next
+    reading is already past the load limit, reclaims without a single
+    compile. Until PR 42 that reclaim compiled where it ran: on an empty
+    compile cache at 2^24 slots a 20 s build, ending in q7-10m-saturated's
+    timed phase and voiding the run (PR 39's verdict in the ledger)."""
+    from jax._src import monitoring
+
+    from flink_tpu.core import Schema
+    from flink_tpu.core.records import RecordBatch
+    from flink_tpu.runtime.harness import OneInputOperatorTestHarness
+    from flink_tpu.runtime.operators.device_window import (
+        AggSpec, DeviceWindowAggOperator)
+    from flink_tpu.window import TumblingEventTimeWindows
+
+    schema = Schema([("k", np.int64), ("v", np.int64)])
+    op = DeviceWindowAggOperator(
+        TumblingEventTimeWindows.of(1000), "k",
+        [AggSpec("count", out_name="n", value_bits=31),
+         AggSpec("sum", "v", out_name="s")],
+        capacity=1 << 10, ring_size=11, defer_overflow=True)
+    h = OneInputOperatorTestHarness(op, schema)
+    readings = []
+    apply_health = TpuKeyedStateBackend.apply_health
+
+    def feed(pane: int) -> None:
+        keys = np.arange(250 * pane, 250 * (pane + 1), dtype=np.int64)
+        h.process_batch(RecordBatch(
+            schema, {"k": keys, "v": keys + (1 << 33)},
+            np.full(250, 1000 * pane + 5, np.int64)))
+
+    def spy(self, dropped, occupancy, *a, **kw):
+        readings.append(int(occupancy))
+        return apply_health(self, dropped, occupancy, *a, **kw)
+
+    builds = []
+
+    def on_duration(event, _seconds, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            builds.append(event)
+
+    sweeps = DEVICE_STATS.snapshot()["state_reclaim_sweeps_total"]
+    TpuKeyedStateBackend.apply_health = spy
+    try:
+        feed(0)
+        program = op._backend._reclaim_call()[0]
+        assert program._compiled             # built before the first input
+        feed(1)
+        h.process_watermark(1999)            # two windows, back to back
+        assert readings == [500, 500]
+        monitoring.register_event_duration_secs_listener(on_duration)
+        try:
+            feed(2)
+            h.process_watermark(2999)        # reads 750 of 1024: reclaims
+            feed(3)
+            h.process_watermark(3999)
+        finally:
+            monitoring.unregister_event_duration_listener(on_duration)
+    finally:
+        TpuKeyedStateBackend.apply_health = apply_health
+    assert readings[2] == 750 and op._backend.capacity == 1 << 10
+    h.process_watermark(1 << 40)
+    h.close()
+    assert DEVICE_STATS.snapshot()["state_reclaim_sweeps_total"] > sweeps
+    assert builds == []
+    rows = sorted((int(k), int(n), int(s))
+                  for k, _start, _end, n, s in h.get_output())
+    assert rows == [(k, 1, k + (1 << 33)) for k in range(1000)]
 
 
 def test_a_reading_of_a_table_rebuilt_since_is_passed_over():

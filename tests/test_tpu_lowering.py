@@ -249,11 +249,33 @@ def test_max_fold_compiles_at_the_benchmark_shape(v5e_devices):
 #: the ring planes of the two one-chip configurations as the backend
 #: signs them (kind, dtype, shape), 2^24 slots each: Q5's int32 count
 #: beside its int64 sum on a ring of 16, Q7's hidden int64 count beside
-#: its int64 max on a ring of 8
+#: its int64 max on a ring of 8. The int64 ones are STORED as their two
+#: 32-bit words since PR 42 (`ops/segment_ops.Halves`), and signed so
 _FOLD_SIGS = {
-    "q5": (("count", "int32", (16, 1 << 24)), ("sum", "int64", (16, 1 << 24))),
-    "q7": (("count", "int64", (8, 1 << 24)), ("max", "int64", (8, 1 << 24))),
+    "q5": (("count", "int32", (16, 1 << 24)),
+           ("sum", "halves:int64", (16, 1 << 24))),
+    "q7": (("count", "halves:int64", (8, 1 << 24)),
+           ("max", "halves:int64", (8, 1 << 24))),
 }
+
+
+def _plane_spec(dt: str, shape, sharding):
+    """A plane of a backend signature as shapes: one array, or the two
+    `uint32` words of a `halves:` plane."""
+    from flink_tpu.ops.segment_ops import Halves
+
+    def spec(dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    if dt.startswith("halves:"):
+        return Halves(spec(jnp.uint32), spec(jnp.uint32),
+                      np.dtype(dt.split(":")[1]))
+    return spec(dt)
+
+
+def _plane_bytes(sig) -> int:
+    return sum(np.dtype(dt.split(":")[-1]).itemsize * int(np.prod(shape))
+               for _k, dt, shape in sig)
 
 
 def _host_born_fold(devices, query: str):
@@ -269,7 +291,7 @@ def _host_born_fold(devices, query: str):
     sig, rows = _FOLD_SIGS[query], 1 << 18
     fold = _fold_program(sig)
     return _compiled(f"fold.{query}", lambda: getattr(fold, "_fn", fold).lower(
-        tuple(spec(shape, dt) for _k, dt, shape in sig),
+        tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
         spec((rows,), jnp.int32), spec((rows,), jnp.int64),
         spec((rows,), jnp.bool_),
         (None, spec((rows,), jnp.int64))).compile())
@@ -284,8 +306,10 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     both: a `[ring, 2^24]` plane is tiled `T(8,128)` and its flat view
     `T(1024)`, so XLA copied all of it out and back in a `while` of
     `dynamic-update-slice`s (232 of the step's 279 ms until PR 34). What
-    is left beside the planes is the 32-bit halves the int64 ones are
-    split into, and a ring row of each."""
+    is left beside the planes is a ring row of each: the int64 planes
+    come as their 32-bit words (PR 42), so no second copy of one is made
+    at the program's entry (2.1 GB of temporaries at Q5's shapes until
+    then)."""
     import re
 
     sig = _FOLD_SIGS[query]
@@ -307,12 +331,11 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     assert "fold.scatter/fold.count" in hlo
     assert f"fold.scatter/fold.{sig[1][0]}" in hlo
     mem = compiled.memory_analysis()
-    planes = sum(np.dtype(dt).itemsize * ring * cap for _k, dt, _s in sig)
+    planes = _plane_bytes(sig)
     assert mem.alias_size_in_bytes >= planes     # every plane is donated
-    # beside the planes: the int64 ones' 32-bit halves, and the ring row
-    # of each plane that is out being folded
-    wide = sum(8 * ring * cap for _k, dt, _s in sig if dt == "int64")
-    assert mem.temp_size_in_bytes < 1.01 * (wide + planes // ring)
+    # beside the planes: the ring row of each plane that is out being
+    # folded, and nothing the size of a plane
+    assert mem.temp_size_in_bytes < 1.01 * planes // ring
 
 
 def _reclaim(devices):
@@ -327,10 +350,11 @@ def _reclaim(devices):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     cap, ring = 1 << 23, 16
-    sig = (("count", "int32", (ring, cap)), ("sum", "int64", (ring, cap)))
+    sig = (("count", "int32", (ring, cap)),
+           ("sum", "halves:int64", (ring, cap)))
     reclaim = _reclaim_program(sig, (0, 1))
     args = (spec((cap,), jnp.int64),
-            tuple(spec(shape, dt) for _k, dt, shape in sig),
+            tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
             spec((), jnp.int64))
     return reclaim, sig, args, _compiled(
         "reclaim",
@@ -342,8 +366,9 @@ def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     slots under Q5's two ring planes: ONE program `jit_reclaim` with the
     three scopes a trace finds its parts by, every plane donated and
     re-seated in place by a sort a ring row (no second copy of a plane:
-    beside them only the int64 plane's 32-bit halves and per-slot
-    vectors; no gather over the slots outside the probe), and nothing
+    the int64 plane comes and goes as its 32-bit words, so beside the
+    planes there are only per-slot vectors; no gather over the slots
+    outside the probe), and nothing
     but the table, the planes, the dropped counter and two counts coming
     back."""
     import re
@@ -365,12 +390,14 @@ def test_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     table, planes_out, dropped, counts = jax.eval_shape(
         getattr(reclaim, "_fn", reclaim), *args)
     assert (table.shape, dropped.shape, counts.shape) == ((cap,), (), (2,))
-    assert [(str(a.dtype), a.shape) for a in planes_out] \
-        == [(dt, shape) for _k, dt, shape in sig]
+    # every plane goes out in the layout it came in
+    assert jax.tree.structure(planes_out) == jax.tree.structure(args[1])
+    assert [(a.shape, a.dtype) for a in jax.tree.leaves(planes_out)] \
+        == [(a.shape, a.dtype) for a in jax.tree.leaves(args[1])]
     mem = compiled.memory_analysis()
-    planes = sum(np.dtype(dt).itemsize * ring * cap for _k, dt, _s in sig)
+    planes = _plane_bytes(sig)
     assert mem.alias_size_in_bytes >= planes     # every plane is donated
-    assert mem.temp_size_in_bytes < 8 * ring * cap + 64 * cap
+    assert mem.temp_size_in_bytes < 64 * cap
 
 
 def _mesh_reclaim(devices):
@@ -426,7 +453,10 @@ def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
     fire = _fire_program(agg_sig, k, value_bits)
     return _compiled(name, lambda: getattr(fire, "_fn", fire).lower(
         spec((cap,), jnp.int64),
-        {n: spec(shape, dt) for n, (shape, dt) in arrays.items()},
+        # an int64 ring plane is handed over as the backend stores it
+        {n: _plane_spec(
+            "halves:int64" if np.dtype(dt) == np.int64 else np.dtype(dt).name,
+            shape, one) for n, (shape, dt) in arrays.items()},
         spec((panes,), jnp.int32), spec((panes,), jnp.bool_),
         spec((), jnp.int64)).compile())
 
@@ -577,8 +607,7 @@ def _region_programs(devices) -> dict:
         "jit_fire_fn.q7": _q7_fire(devices, 43, 1),
         "jit_reset": _compiled("reset", lambda: getattr(
             reset, "_fn", reset).lower(
-            tuple(jax.ShapeDtypeStruct(shape, dt, sharding=one)
-                  for _k, dt, shape in q5),
+            tuple(_plane_spec(dt, shape, one) for _k, dt, shape in q5),
             jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile()),
         "jit_step": step,
         "jit_fire": _mesh_fire(devices),
@@ -591,6 +620,9 @@ def _region_programs(devices) -> dict:
         "jit_reclaim.mesh": _mesh_reclaim(devices),
     }
 
+
+#: the programs of the mesh stack, whose planes are int64 arrays
+_MESH_PROGRAMS = {"jit_step", "jit_fire", "jit_retire", "jit_reclaim.mesh"}
 
 #: the regions each program must hold, beside the split and the join of
 #: its int64 arguments
@@ -646,7 +678,10 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
             kind = "x64.split" if m.group(2) == "Split" else "x64.join"
             assert regions[m.group(1)] == kind, line[:200]
             x64[kind] += 1
-    assert x64["x64.split"] >= 2 and x64["x64.join"] >= 1, x64
+    if program in _MESH_PROGRAMS or program == "jit_lookup_or_insert":
+        # the mesh stack's planes are int64 arrays (ROADMAP S10b), and
+        # the table's keys are everywhere (S1)
+        assert x64["x64.split"] >= 2 and x64["x64.join"] >= 1, x64
     if program == "jit_retire":
         # the row writes sit between the planes' split and their join and
         # are neither: the rewriter left them a bare name
@@ -672,3 +707,156 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
                     and "op_name=" not in line
                 flags += " = pred[81920]" in line
         assert (columns, flags) == (3, 1)
+
+
+# ---------------------------------------------------------------------------
+# PR 42: a 64-bit ring plane of the one-chip backend is stored as its two
+# 32-bit words, so no program splits or joins a whole plane
+
+
+def _q7_reclaim(devices):
+    """The backend's reclaim at q7-10m-saturated's shapes: 2^24 slots
+    under two `[8, 2^24]` planes, both stored as words."""
+    from flink_tpu.state.tpu_backend import _reclaim_program
+
+    one = SingleDeviceSharding(devices[0])
+    sig = _FOLD_SIGS["q7"]
+    reclaim = _reclaim_program(sig, (0, 1))
+    return _compiled("reclaim.q7", lambda: getattr(
+        reclaim, "_fn", reclaim).lower(
+        jax.ShapeDtypeStruct((_Q7_CAP,), jnp.int64, sharding=one),
+        tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
+        jax.ShapeDtypeStruct((), jnp.int64, sharding=one)).compile())
+
+
+def _q7_reset(devices):
+    from flink_tpu.state.tpu_backend import _reset_row_program
+
+    one = SingleDeviceSharding(devices[0])
+    sig = _FOLD_SIGS["q7"]
+    reset = _reset_row_program(sig)
+    return _compiled("reset.q7", lambda: getattr(reset, "_fn", reset).lower(
+        tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile())
+
+
+#: program -> (the slots of its table, whether a table-sized split or
+#: join is the table's own and so allowed: S1's, not this layout's)
+_ONE_CHIP_PLANE_PROGRAMS = {
+    "jit_fold.q5": (1 << 24, False), "jit_fold.q7": (1 << 24, False),
+    "jit_reset": (1 << 24, False), "jit_reset.q7": (1 << 24, False),
+    "jit_fire_fn.q5": (1 << 24, True), "jit_fire_fn.q7": (1 << 24, True),
+    "jit_reclaim": (1 << 23, True), "jit_reclaim.q7": (1 << 24, True),
+}
+
+
+@pytest.mark.parametrize("program", list(_ONE_CHIP_PLANE_PROGRAMS))
+def test_no_one_chip_program_splits_or_joins_a_plane(v5e_devices, program):
+    """Every program that takes a ring plane of the one-chip backend, FOR
+    the v5e at X's shapes (`[16, 2^24]` int64 SUM beside the int32 COUNT)
+    and Q's (two `[8, 2^24]` int64 planes), read through the map the
+    program gives of itself (`metrics/device.classify_hlo`, what
+    `program_regions` serves and `step_x64_ms` / `fire_x64_ms` read): the
+    regions `x64.split` / `x64.join` hold no instruction over a plane.
+    The fold and the reset hold none over as much as a ring ROW (the
+    fold's are its batch's int64 columns, 2^18 rows; the reset has none
+    at all); the fire and the reclaim hold the table's own split (and the
+    reclaim the new table's join), which are the hash table's int64 keys
+    and ROADMAP S1's to remove. Until PR 42 each of these programs split
+    every int64 plane at its entry (`X64SplitLow` / `X64SplitHigh`) and
+    the donating ones joined it at their exit (`X64Combine`), whatever
+    they touched of it: 25 of the step's 121 ms and 37 of the fire's
+    56 ms in q5-10m-saturated (ledger, PR 41)."""
+    import re
+
+    from flink_tpu.metrics.device import classify_hlo
+
+    cap, table_allowed = _ONE_CHIP_PLANE_PROGRAMS[program]
+    compiled = {**_region_programs(v5e_devices),
+                "jit_reclaim.q7": _q7_reclaim(v5e_devices),
+                "jit_reset.q7": _q7_reset(v5e_devices)}[program]
+    hlo = compiled.as_text()
+    assert f"HloModule {program.split('.')[0]}" in hlo
+    regions = classify_hlo(hlo)
+    sized = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = [us]\d+\[([\d,]*)\]\S* "
+                     r"custom-call\(([^)]*)\)", line)
+        if m and regions.get(m.group(1)) in ("x64.split", "x64.join"):
+            sized[m.group(1)] = (
+                regions[m.group(1)],
+                int(np.prod([int(d) for d in m.group(2).split(",") if d],
+                            dtype=np.int64)), m.group(3))
+    assert set(sized) == {n for n, r in regions.items()
+                          if r in ("x64.split", "x64.join")}
+    over_a_row = {n: v for n, v in sized.items() if v[1] >= cap}
+    assert not any(v[1] > cap for v in over_a_row.values()), over_a_row
+    if not table_allowed:
+        assert not over_a_row, over_a_row
+        if program.startswith("jit_reset"):
+            assert not sized, sized
+        return
+    splits = [v for v in over_a_row.values() if v[0] == "x64.split"]
+    joins = [v for v in over_a_row.values() if v[0] == "x64.join"]
+    assert len(splits) == 2 and all("%table" in v[2] for v in splits), splits
+    # the reclaim hands back a new table; the fire hands back none
+    assert len(joins) == (1 if program.startswith("jit_reclaim") else 0)
+    # and no second copy of a plane is made inside the program: what it
+    # needs beside its arguments is less than ONE ring row of each plane
+    # (the fire: the merged rows and the select's views)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 48 * cap
+
+
+#: sha256 of `lower().as_text()` (StableHLO, no locations) of the mesh
+#: stack's four programs on a described v5e 2x2 at `_q5_mesh(devices, 2^10,
+#: 256)`, AT THE PARENT OF PR 42 (commit 1f66f4f). `ring_fold` and
+#: `reclaim_shard` are shared with the one-chip stack and tell the two
+#: layouts apart by what they are handed: handed int64 arrays they trace
+#: what they traced. A later change that MEANS to alter a mesh program
+#: (ROADMAP S10b) writes its own digests here (`_mesh_program_digests`).
+_MESH_DIGESTS_AT_1F66F4F = {
+    "jit_step": 
+        "42805652eb567edb68d234a7eeea194298b5098ee65978d228a5a38ee908bf9b",
+    "jit_fire": 
+        "1addce37b3e89072476c3860ff35c361037d49b2daacd9ec79c6621fa7fd2b14",
+    "jit_retire": 
+        "e11a013a2b95e474c2faf9630c675ed3b5934603702a4c155a869ce2d0ee1b32",
+    "jit_reclaim": 
+        "c2a80841e59bdc8486d81555470bf190a3408e63c1f61c59b5f61257f8ea4e51",
+}
+
+
+def _mesh_program_digests(devices) -> dict:
+    import hashlib
+
+    from flink_tpu.parallel.sharded_window import _retire_program
+
+    agg, sharded, args = _q5_mesh(devices[:4], 1 << 10, 256)
+    rep = NamedSharding(sharded.mesh, P())
+    retire = _retire_program(agg.sig)
+    lowered = {
+        "jit_step": agg.step_program().lower(
+            *args, np.int32(0), np.int32(128)),
+        "jit_fire": agg.fire_program("revenue", 1000).lower(
+            args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)),
+        "jit_retire": getattr(retire, "_fn", retire).lower(
+            args[0].accs,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)),
+        "jit_reclaim": agg.reclaim_program().lower(args[0]),
+    }
+    return {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+            for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", list(_MESH_DIGESTS_AT_1F66F4F))
+def test_the_mesh_programs_lower_to_what_they_lowered_to(v5e_devices,
+                                                         program):
+    """M and F run another stack's programs, and PR 42 does not touch
+    them: `jit_step`, `jit_fire`, `jit_retire` and the mesh `jit_reclaim`
+    lower, letter for letter, to the text they lowered to at the parent
+    commit (int64 planes in, int64 planes out)."""
+    got = _compiled("mesh.digests",
+                    lambda: _mesh_program_digests(v5e_devices))
+    assert got[program] == _MESH_DIGESTS_AT_1F66F4F[program]
