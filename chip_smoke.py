@@ -432,6 +432,103 @@ def leg_q5_mesh_inflight(*, capacity_per_device: int, batch: int, seed: int,
     return report
 
 
+def q11_reference(gen: Callable, n_events: int, gap_ms: int) -> set:
+    """Per-bidder sessions of the whole stream, in plain numpy: sort by
+    (bidder, ts), cut where a bidder changes or two of its bids lie the
+    gap or more apart; a session is (bidder, first, last + gap, bids)."""
+    cols = gen(np.arange(n_events, dtype=np.int64))
+    order = np.lexsort((cols["ts"], cols["bidder"]))
+    b, t = cols["bidder"][order], cols["ts"][order]
+    cut = np.flatnonzero(np.r_[True, (b[1:] != b[:-1])
+                               | (t[1:] - t[:-1] >= gap_ms)])
+    end = np.r_[cut[1:], n_events] - 1
+    return set(zip(b[cut].tolist(), t[cut].tolist(),
+                   (t[end] + gap_ms).tolist(), (end - cut + 1).tolist()))
+
+
+def leg_q11_sessions(*, n_keys: int, capacity: int, batch: int,
+                     n_events: int, seed: int, gap_ms: int = 2000,
+                     span_ms: int = 16000) -> dict:
+    """NEXmark Q11 in small: per-bidder SESSION(gap) COUNT(*) through
+    ``env.execute()`` and ``DeviceSessionWindowOperator`` with
+    ``async_fire``, three bids in four on a hot bidder that moves every
+    batch, the rest uniform over ``n_keys``; every session equals
+    numpy's, the fires ran at their cadence, and no lane overflowed."""
+    from flink_tpu.api import StreamExecutionEnvironment
+    from flink_tpu.core import WatermarkStrategy
+    from flink_tpu.core.config import PipelineOptions
+    from flink_tpu.core.records import Schema
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.runtime.operators.device_session import \
+        DeviceSessionWindowOperator
+    from flink_tpu.runtime.operators.device_window import AggSpec
+    from flink_tpu.window import EventTimeSessionWindows
+
+    def gen(idx):
+        u = ((idx + seed).astype(np.uint64) * np.uint64(MULT))
+        cold = (u % np.uint64(n_keys)).astype(np.int64)
+        return {"bidder": np.where(idx % 4 == 0, cold,
+                                   n_keys + idx // batch),
+                "ts": (idx * span_ms) // n_events}
+
+    _watch_compiles()
+    leg = "q11-sessions"
+    schema = Schema([("bidder", np.int64), ("ts", np.int64)])
+    env = StreamExecutionEnvironment.get_execution_environment()
+    env.set_state_backend("tpu")
+    env.config.set(PipelineOptions.BATCH_SIZE, batch)
+    # a watermark a batch: a bidder's sessions close as the stream moves
+    # on, however fast the host runs ahead of the default's 0.2 s (four
+    # lanes hold the sessions of one bidder that have not fired yet)
+    env.config.set(PipelineOptions.AUTO_WATERMARK_INTERVAL, 0.0)
+    ws = WatermarkStrategy.for_monotonous_timestamps() \
+        .with_timestamp_column("ts")
+    sink = _collecting_sink()
+    env.datagen(gen, schema, count=n_events, timestamp_column="ts",
+                watermark_strategy=ws, device=False) \
+        .key_by("bidder") \
+        .window(EventTimeSessionWindows.with_gap(gap_ms)) \
+        .device_aggregate([AggSpec("count", out_name="bid_count")],
+                          capacity=capacity, ring_size=4,
+                          emit_window_bounds=True, async_fire=True) \
+        .add_sink(sink, "collect")
+    before = DEVICE_STATS.snapshot()
+    compile_before = dict(_COMPILE)
+    t0 = time.perf_counter()
+    env.execute(leg, timeout=1100.0)
+    wall = time.perf_counter() - t0
+    after = DEVICE_STATS.snapshot()
+    rows = {name: np.concatenate([b[name] for b in sink.batches])
+            for name in sink.batches[0]}
+    got = list(zip(rows["bidder"].tolist(), rows["window_start"].tolist(),
+                   rows["window_end"].tolist(), rows["bid_count"].tolist()))
+    want = q11_reference(gen, n_events, gap_ms)
+    assert len(got) == len(set(got)), "a session was emitted twice"
+    assert set(got) == want, (len(got), len(want),
+                              sorted(set(got) ^ want)[:5])
+    ops = [o for task in env.last_job.tasks.values()
+           for o in getattr(getattr(task, "chain", None), "operators", ())
+           if isinstance(o, DeviceSessionWindowOperator)]
+    assert len(ops) == 1, ops
+    report = {**_leg_header(leg), "n_keys": n_keys, "batch": batch,
+              "events": n_events, "sessions": len(got),
+              "wall_s": round(wall, 3), **_compile_since(compile_before),
+              "late_dropped": ops[0].late_dropped,
+              "capacity": int(ops[0]._backend.capacity),
+              "peak_bytes_in_use": _memory("peak_bytes_in_use"),
+              **{k: after[k] - before[k] for k in after
+                 if k.startswith("session_")}}
+    for k in FALLBACK_COUNTERS:
+        report[k] = after.get(k, 0) - before.get(k, 0)
+        assert report[k] == 0, (k, report[k])
+    assert report["late_dropped"] == 0, report["late_dropped"]
+    assert report["capacity"] == capacity, report["capacity"]
+    assert report["session_fired_total"] == len(got), report
+    assert report["session_lane_overflow_total"] == 0, report
+    assert report["session_fires_total"] >= 2, report
+    return report
+
+
 def leg_pallas_topk(sizes=(1 << 21, 1 << 24), k: int = TOPK,
                     value_bits: int = 31, interpret: bool = False,
                     seed: int = 0) -> dict:
@@ -535,6 +632,12 @@ def main(argv=None) -> int:
     # so that nearly every id is bid on) over 2^20 slots in all
     _emit(leg_q5_mesh_inflight(capacity_per_device=(1 << 20) // n_dev,
                                batch=1 << 18, seed=args.seed))
+
+    # sessions: 2^20 bidders bid over 16 s, gap 2 s: about 3M sessions
+    # through the lanes of a 2^22-slot table, fired every 0.4 s of event
+    # time in rounds of 2^18
+    _emit(leg_q11_sessions(n_keys=1 << 20, capacity=1 << 22, batch=BATCH,
+                           n_events=1 << 22, seed=args.seed))
 
     _emit(leg_pallas_topk(seed=args.seed))
 

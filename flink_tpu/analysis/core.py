@@ -327,14 +327,18 @@ def save_baseline(findings: Sequence[Finding],
 def diff_against_baseline(
         findings: Sequence[Finding],
         baseline: Optional[List[dict]] = None,
+        rules: Optional[Sequence[str]] = None,
 ) -> Tuple[List[Finding], List[dict]]:
     """Split findings into (unbaselined, stale_baseline_entries).  Stale
     entries — baselined findings that no longer occur — are reported so
-    the baseline shrinks as fixes land instead of rotting."""
+    the baseline shrinks as fixes land instead of rotting.  ``rules``:
+    the rule ids that RAN, where that is not all of them: an entry of a
+    rule that did not run cannot have recurred and is not stale."""
     if baseline is None:
         baseline = load_baseline()
     known = {e["fingerprint"] for e in baseline}
     seen = {f.fingerprint for f in findings}
     new = [f for f in findings if f.fingerprint not in known]
-    stale = [e for e in baseline if e["fingerprint"] not in seen]
+    stale = [e for e in baseline if e["fingerprint"] not in seen
+             and (rules is None or e["rule"] in rules)]
     return new, stale

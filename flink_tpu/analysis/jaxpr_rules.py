@@ -557,6 +557,26 @@ def exercise_programs(n_events: int = 4096, batch: int = 1024,
                 .add_sink(_DiscardSink(), "audit-sink"))
             env.execute(f"tpu-lint-audit-{fire_mode}", timeout=600.0)
 
+        # the session operator's two programs (device_session.step /
+        # device_session.fire): the same bids keyed into per-auction
+        # SESSION windows of one pane's gap (a session a key: the host
+        # runs ahead of the periodic watermark, and a key has eight lanes)
+        from flink_tpu.window import EventTimeSessionWindows
+
+        env = StreamExecutionEnvironment.get_execution_environment()
+        env.set_state_backend("tpu")
+        env.config.set(PipelineOptions.BATCH_SIZE, batch)
+        ws = WatermarkStrategy.for_monotonous_timestamps() \
+            .with_timestamp_column("ts")
+        (env.datagen(gen, schema, count=n_events, timestamp_column="ts",
+                     watermark_strategy=ws, device=False)
+            .key_by("auction")
+            .window(EventTimeSessionWindows.with_gap(pane_ms))
+            .device_aggregate([AggSpec("count", out_name="bids")],
+                              capacity=capacity, ring_size=8)
+            .add_sink(_DiscardSink(), "audit-sink"))
+        env.execute("tpu-lint-audit-sessions", timeout=600.0)
+
         # sharded (mesh.*) programs: one direct step + fused fire on a tiny
         # ShardedWindowAgg so the JX505 local-key audit has entries to lint
         import jax

@@ -701,11 +701,14 @@ class WindowedStream:
             if emit_topk is not None:
                 raise ValueError(
                     "emit_topk is not supported for session windows")
-            if defer_overflow or async_fire or hbm_budget_slots:
+            if hbm_budget_slots:
                 raise ValueError(
-                    "defer_overflow/async_fire/hbm_budget_slots are not "
-                    "supported by the session operator yet; drop them or "
-                    "use the host WindowOperator path")
+                    "hbm_budget_slots is not supported by the session "
+                    "operator yet; drop it or use the host WindowOperator "
+                    "path")
+            # defer_overflow is how the session operator always works: a
+            # table or lane overflow is counted on the device and raises
+            # at the next fire's drain
             from ..runtime.operators.device_session import (
                 DeviceSessionWindowOperator,
             )
@@ -715,7 +718,8 @@ class WindowedStream:
                 return DeviceSessionWindowOperator(
                     gap, key_col, aggs, capacity=capacity,
                     lanes=max(4, min(ring_size, 16)),
-                    emit_window_bounds=emit_window_bounds, name=name)
+                    emit_window_bounds=emit_window_bounds,
+                    async_fire=async_fire, name=name)
 
             par = 1 if self._all else None
             return self.keyed._one_input(

@@ -647,7 +647,7 @@ def _cmd_lint(args) -> int:
 
     ctx = AnalysisContext()
     findings = run_rules(ctx, selected, skipped)
-    new, stale = diff_against_baseline(findings)
+    new, stale = diff_against_baseline(findings, rules=selected)
 
     if args.update_baseline:
         save_baseline(findings, default_reason=args.reason or None)

@@ -139,6 +139,21 @@ class DeviceStats:
         self._reclaim_sweeps = 0
         self._reclaim_kept = 0
         self._reclaim_freed = 0
+        # session operator accounting (PR 43): fires (one boundary each),
+        # the rounds they took (a round compacts at most the operator's
+        # ``fire_rows`` ripe sessions), the sessions they emitted and
+        # the rows their drains handed on; lanes the steps allocated,
+        # segments that found no free lane, segments settled inside a
+        # batch (they bypass the lanes). The step's three are device
+        # counters that ride back with a fire round's copy, so they
+        # trail the device by the batches since the last drained round
+        self._session_fires = 0
+        self._session_fire_rounds = 0
+        self._session_fired = 0
+        self._session_rows_drained = 0
+        self._session_lanes_allocated = 0
+        self._session_lane_overflow = 0
+        self._session_settled = 0
         # whole-chain fusion accounting (PR 11): micro-batches ingested
         # through a certified fused chain program — ONE dispatch covering
         # source-decode + window step (graph/fusion.py certificate)
@@ -472,6 +487,44 @@ class DeviceStats:
             return (self._reclaim_sweeps, self._reclaim_kept,
                     self._reclaim_freed)
 
+    def note_session_round(self, fired: int, last: bool) -> None:
+        """One drained round of a session fire: the sessions it took off
+        the lanes, and whether it ended its fire."""
+        with self._lock:
+            self._session_fire_rounds += 1
+            self._session_fired += int(fired)
+            self._session_fires += bool(last)
+
+    def note_session_rows(self, rows: int) -> None:
+        """Session rows handed on: a round's, or settled ones' that were
+        ripe at a watermark."""
+        with self._lock:
+            self._session_rows_drained += int(rows)
+
+    def note_session_steps(self, lanes_allocated: int, lane_overflow: int,
+                           settled: int) -> None:
+        """What the session steps counted since the last reading."""
+        with self._lock:
+            self._session_lanes_allocated += int(lanes_allocated)
+            self._session_lane_overflow += int(lane_overflow)
+            self._session_settled += int(settled)
+
+    def _session_counts(self) -> dict[str, int]:
+        return {
+            "session_fires_total": self._session_fires,
+            "session_fire_rounds_total": self._session_fire_rounds,
+            "session_fired_total": self._session_fired,
+            "session_rows_drained_total": self._session_rows_drained,
+            "session_lanes_allocated_total": self._session_lanes_allocated,
+            "session_lane_overflow_total": self._session_lane_overflow,
+            "session_settled_in_batch_total": self._session_settled}
+
+    @property
+    def session_counts(self) -> dict[str, int]:
+        """The session operator's counters, under their snapshot names."""
+        with self._lock:
+            return self._session_counts()
+
     def note_chain_dispatch(self, n: int = 1) -> None:
         with self._lock:
             self._chain_dispatches += int(n)
@@ -744,6 +797,7 @@ class DeviceStats:
                 "state_reclaim_sweeps_total": self._reclaim_sweeps,
                 "state_reclaim_keys_kept_total": self._reclaim_kept,
                 "state_reclaim_keys_freed_total": self._reclaim_freed,
+                **self._session_counts(),
                 "chain_fused_dispatches_total": self._chain_dispatches,
                 "rescales_total": self._rescales,
                 "keygroups_migrated_total": self._keygroups_migrated,
@@ -856,6 +910,10 @@ class DeviceStats:
             self._fold_batches = self._fold_ring_rows = 0
             self._reclaim_sweeps = 0
             self._reclaim_kept = self._reclaim_freed = 0
+            self._session_fires = self._session_fire_rounds = 0
+            self._session_fired = self._session_rows_drained = 0
+            self._session_lanes_allocated = 0
+            self._session_lane_overflow = self._session_settled = 0
             self._chain_dispatches = 0
             self._rescales = 0
             self._keygroups_migrated = 0
@@ -987,6 +1045,14 @@ REGION_SCOPES = {
     "fire.retire": "fire.retire",
     "reclaim.live": "reclaim.live", "reclaim.rehome": "reclaim.rehome",
     "reclaim.remap": "reclaim.remap",
+    # the session operator's two programs (runtime/operators/
+    # device_session.py); its probe's own windows stay probe.*
+    "session.probe": "session.probe", "session.segment": "session.segment",
+    "session.lanes": "session.lanes", "session.fold": "session.fold",
+    "session.emit": "session.emit",
+    "session.fire.scan": "session.fire.scan",
+    "session.fire.compact": "session.fire.compact",
+    "session.fire.reset": "session.fire.reset",
 }
 #: scopes that name no region of their own: they lie around or beneath
 #: the region scopes, for the path patterns that count the probe's rounds
@@ -1068,7 +1134,10 @@ def classify_hlo(text: str) -> dict[str, str]:
        that feed it, when they are, else of the loop or branch that
        holds it. A split's or a join's region goes on to such moves
        only: what computes between a split and a join (the retire's row
-       writes) takes the scope's region beside it.
+       writes) takes the scope's region beside it. A LOOP without a name
+       path (the compiler re-tiling a plane around a scatter, row by
+       row) is placed the same way, before the rest, and what it holds
+       goes with it.
     5. Everything else is ``unnamed``.
 
     ``while`` / ``conditional`` / ``call`` hold other computations and get
@@ -1125,16 +1194,21 @@ def classify_hlo(text: str) -> dict[str, str]:
     wrappers: dict[str, str] = {}
     held_by: dict[str, str] = {entry: UNNAMED}    # computation -> region
     pathless: dict[str, list[str]] = {}           # computation -> names
+    # loops the compiler made itself (no name path: a plane re-tiled
+    # around a scatter, row by row), with the computations they hold
+    bare_loops: list[tuple[str, str, list[str]]] = []
     todo = [entry]
     while todo:
         comp = todo.pop()
         for name, opcode, _refs, path, target, called in comps.get(comp, ()):
             if opcode in _WRAPPERS:
                 wrappers[name] = _scope_region(path, opcode) or held_by[comp]
-                for c in called:
-                    if c not in held_by:
-                        held_by[c] = wrappers[name]
-                        todo.append(c)
+                mine = [c for c in called if c not in held_by]
+                if "/" not in path and wrappers[name] == UNNAMED:
+                    bare_loops.append((comp, name, mine))
+                for c in mine:
+                    held_by[c] = wrappers[name]
+                    todo.append(c)
                 continue
             if opcode in _SILENT:
                 continue
@@ -1148,7 +1222,7 @@ def classify_hlo(text: str) -> dict[str, str]:
     # rule 4, over the data flow of each computation that holds a
     # pathless instruction: through other pathless instructions and the
     # tuple plumbing, to the first instructions that have a region
-    for comp, names in pathless.items():
+    def flows(comp: str, names) -> tuple:
         operands = {i[0]: i[2] for i in comps[comp]}
         users: dict[str, list[str]] = {}
         for name, refs in operands.items():
@@ -1161,6 +1235,46 @@ def classify_hlo(text: str) -> dict[str, str]:
         # retire's row writes sit between a split and a join and are
         # neither
         moves = {i[0] for i in comps[comp] if i[1] in _MOVES}
+        return operands, users, through, moves
+
+    # rule 4 for a loop without a name path, first (what lies beside it
+    # then sees its region): the loop, and through it every computation
+    # it holds, takes the region its result reaches, else the one that
+    # feeds it
+    for comp, loop, held in bare_loops:
+        operands, users, through, _moves = flows(
+            comp, pathless.get(comp, ()))
+
+        def near(edges) -> set:
+            found, seen, todo = set(), {loop}, list(edges.get(loop, ()))
+            while todo:
+                n = todo.pop()
+                if n in seen:
+                    continue
+                seen.add(n)
+                r = regions.get(n) or wrappers.get(n, UNNAMED)
+                if r in _X64_REGIONS:
+                    continue
+                if r != UNNAMED:
+                    found.add(r)
+                elif n in through:
+                    todo.extend(edges.get(n, ()))
+            return found
+
+        for found in (near(users), near(operands)):
+            if len(found) == 1:
+                wrappers[loop] = found.pop()
+                todo = list(held)
+                while todo:
+                    c = todo.pop()
+                    held_by[c] = wrappers[loop]
+                    todo.extend(k for k, r in held_by.items()
+                                if r == UNNAMED and any(
+                                    k in i[5] for i in comps.get(c, ())))
+                break
+
+    for comp, names in pathless.items():
+        operands, users, through, moves = flows(comp, names)
 
         def reach(start: str, edges) -> set:
             found, seen, todo = set(), {start}, list(edges.get(start, ()))
@@ -1624,6 +1738,9 @@ def bind_device_metrics(registry) -> None:
     g.gauge("state_reclaim_sweeps_total", lambda: s.reclaim_counts[0])
     g.gauge("state_reclaim_keys_kept_total", lambda: s.reclaim_counts[1])
     g.gauge("state_reclaim_keys_freed_total", lambda: s.reclaim_counts[2])
+    # session operator (prometheus: flink_tpu_device_session_*_total)
+    for name in s.session_counts:
+        g.gauge(name, lambda name=name: s.session_counts[name])
     # whole-chain fusion (prometheus:
     # flink_tpu_device_chain_fused_dispatches_total)
     g.gauge("chain_fused_dispatches_total", lambda: s.chain_dispatches)

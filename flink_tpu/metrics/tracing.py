@@ -841,21 +841,40 @@ SPAN_INVENTORY: tuple = (
      "used by device_window / mesh_window _materialize — device_get of a "
      "fire's outputs + host selection/sort; child of Fire (stage span: "
      "turn — timer, batch or blocking: the kind of mailbox turn that took "
-     "the fire off the queue)"),
+     "the fire off the queue); "
+     "runtime/operators/device_session.py _materialize — device_get of "
+     "one fire ROUND's counters and rows, copied since the round's "
+     "dispatch (stage span: round, turn, fired, left: the ripe sessions "
+     "the round left on their lanes)"),
     ("window", "Emit",
      "runtime/operators/slice_control.py AsyncFireQueue._emit_stage — "
      "building the window's rows + output.emit; child of Fire (stage "
-     "span: rows)"),
+     "span: rows); runtime/operators/device_session.py _materialize — "
+     "a fire round's session rows built and emitted"),
     ("window", "Fire",
      "runtime/operators/slice_control.py — root of one span tree per "
      "fired window: _fire entry → its rows emitted, closed from a later "
      "mailbox turn when fires are async: the first processing-time turn "
      "after its copy has landed (stage span: window_end_ms, rows, "
-     "d2h_bytes, unready_polls)"),
+     "d2h_bytes, unready_polls); "
+     "runtime/operators/device_session.py _maybe_fire — root of one "
+     "span tree per session fire (one boundary, at the operator's "
+     "cadence): its first round's dispatch → its last round's rows "
+     "emitted and its watermark forwarded (stage span: boundary_ms, "
+     "rounds, unready_polls; seq: the boundary)"),
     ("window", "FireDispatch",
      "runtime/operators/slice_control.py _fire_window — the host's "
      "dispatch of one fire: guarded fire program(s) + ring-row reset; "
-     "child of Fire (stage span)"),
+     "child of Fire (stage span); "
+     "runtime/operators/device_session.py _dispatch_round — the "
+     "dispatch of one round of a session fire and the start of its "
+     "device→host copy (stage span: round)"),
+    ("window", "HostSort",
+     "runtime/operators/device_session.py _ingest — the host's sort of "
+     "one batch by (key, ts) (a stable argsort by key where the "
+     "timestamps are in order, else a lexsort) and the padding of its "
+     "columns, on the task's thread before the upload (stage span: "
+     "rows; seq: the batch's ordinal)"),
     ("window", "IngestDispatch",
      "runtime/operators/device_window.py + "
      "runtime/operators/device_session.py — host time to enqueue one "
@@ -882,9 +901,15 @@ SPAN_INVENTORY: tuple = (
      "nests under it (stage span: bytes); "
      "runtime/operators/mesh_window.py _flush — concatenating the "
      "staged batches, cutting one [D, B] block and its host→device "
-     "copies (seq: the block's ordinal)"),
+     "copies (seq: the block's ordinal); "
+     "runtime/operators/device_session.py _ingest — the sorted batch's "
+     "key, timestamp and aggregate columns to the device (stage span: "
+     "rows)"),
     ("window", "Watermark",
      "runtime/operators/slice_control.py "
      "SliceControlPlane.process_watermark — one watermark through the "
-     "window operator (stage span: watermark_ms, fires, since_batch_ms)"),
+     "window operator (stage span: watermark_ms, fires, since_batch_ms); "
+     "runtime/operators/device_session.py process_watermark — the "
+     "settled segments' flush, a look at the round in flight and, at "
+     "the cadence, a fire's first dispatch"),
 )

@@ -46,7 +46,7 @@ def test_tier_a_clean_against_baseline():
     """Any unbaselined Tier-A finding (host-sync, wiring, inventory
     drift, lock discipline, determinism) fails tier-1 right here."""
     findings = run_rules(AnalysisContext(), TIER_A)
-    new, stale = diff_against_baseline(findings)
+    new, stale = diff_against_baseline(findings, rules=TIER_A)
     assert not new, f"unbaselined findings:\n{_fmt(new)}"
     assert not stale, f"stale baseline entries (fixed? shrink the " \
                       f"baseline): {stale}"
@@ -575,6 +575,13 @@ def test_baseline_diff_reports_new_and_stale():
     ]
     new, stale = diff_against_baseline([f_known, f_new], baseline)
     assert [f.symbol for f in new] == ["s2"]
+    assert [e["symbol"] for e in stale] == ["dead"]
+    # an entry of a rule that did not run has not gone stale
+    _new, stale = diff_against_baseline([f_known, f_new], baseline,
+                                        rules=("OTHER",))
+    assert stale == []
+    _new, stale = diff_against_baseline([f_known, f_new], baseline,
+                                        rules=("R",))
     assert [e["symbol"] for e in stale] == ["dead"]
 
 

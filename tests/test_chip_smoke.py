@@ -72,6 +72,32 @@ def test_mesh_leg_over_advancing_ids_reclaims_and_keeps_its_capacity():
     assert all(report[k] == 0 for k in chip_smoke.FALLBACK_COUNTERS)
 
 
+def test_sessions_leg_equals_numpy_and_fires_at_its_cadence():
+    """The Q11 leg (PR 43): every session of the stream equals the numpy
+    sessionization (asserted by the leg), through `env.execute()` with
+    `async_fire`; the hot bidder of each batch is one session of three
+    quarters of the batch."""
+    report = chip_smoke.leg_q11_sessions(
+        n_keys=500, capacity=1 << 12, batch=1 << 9, n_events=1 << 13,
+        seed=3, gap_ms=500, span_ms=8000)
+    assert report["sessions"] == report["session_fired_total"] > 500
+    assert report["session_fires_total"] >= 2
+    assert report["session_fire_rounds_total"] \
+        >= report["session_fires_total"]
+    assert report["capacity"] == 1 << 12
+    assert all(report[k] == 0 for k in chip_smoke.FALLBACK_COUNTERS)
+
+
+def test_the_sessions_reference_cuts_at_the_gap():
+    def gen(idx):
+        return {"bidder": np.array([1, 1, 1, 2, 1])[idx],
+                "ts": np.array([0, 99, 199, 250, 400])[idx]}
+
+    assert chip_smoke.q11_reference(gen, 5, 100) == {
+        (1, 0, 199, 2), (1, 199, 299, 1), (2, 250, 350, 1),
+        (1, 400, 500, 1)}
+
+
 def test_check_rows_rejects_a_wrong_answer(host_leg, reference):
     _report, rows = host_leg
     wrong = dict(rows, bids=rows["bids"] + (np.arange(len(rows["bids"]))

@@ -860,3 +860,104 @@ def test_the_mesh_programs_lower_to_what_they_lowered_to(v5e_devices,
     got = _compiled("mesh.digests",
                     lambda: _mesh_program_digests(v5e_devices))
     assert got[program] == _MESH_DIGESTS_AT_1F66F4F[program]
+
+
+# ---------------------------------------------------------------------------
+# PR 43: the session operator's two programs at the Q11 cell's shapes
+
+
+_SESSION_SHAPE = dict(lanes=4, cap=1 << 24, rows=1 << 18, gap=10_000,
+                      fire_rows=1 << 18, dirty_block=512)
+
+
+def _session_program(devices, which: str):
+    """`jit_step` / `jit_fire` of `runtime/operators/device_session.py` at
+    `q11-sessions-saturated`'s shapes: 2^24 slots, 4 lanes, the three
+    int64 lanes planes as the backend stores them (two 32-bit words),
+    `__open__` int8, COUNT(*) alone, a batch of 2^18 bids, a round of
+    2^18 sessions."""
+    from flink_tpu.runtime.operators.device_session import _sess_fire, \
+        _sess_step
+
+    one = SingleDeviceSharding(devices[0])
+    s = _SESSION_SHAPE
+    L, cap, B = s["lanes"], s["cap"], s["rows"]
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    planes = {name: _plane_spec(dt, (L, cap), one) for name, dt in (
+        ("__start__", "halves:int64"), ("__end__", "halves:int64"),
+        ("__open__", "int8"), ("__count__", "halves:int64"))}
+    scalar, stats = spec((), jnp.int64), spec((3,), jnp.int64)
+    dirty = spec((cap // s["dirty_block"],), jnp.bool_)
+    if which == "step":
+        step = _sess_step((), L, s["gap"], s["dirty_block"])
+        return _compiled("session.step", lambda: getattr(
+            step, "_fn", step).lower(
+            spec((cap,), jnp.int64), planes, spec((cap,), jnp.int32),
+            scalar, scalar, stats, dirty, spec((B,), jnp.int64),
+            spec((B,), jnp.int64), {}, scalar, scalar).compile())
+    fire = _sess_fire((), s["gap"], s["fire_rows"], s["dirty_block"])
+    return _compiled("session.fire", lambda: getattr(
+        fire, "_fn", fire).lower(
+        spec((cap,), jnp.int64), planes, scalar, scalar, stats, dirty,
+        scalar).compile())
+
+
+#: the regions each session program must hold
+_SESSION_REGIONS = {
+    "step": {"session.probe", "session.segment", "session.lanes",
+             "session.fold", "session.emit", "probe.window0", "probe.tail"},
+    "fire": {"session.fire.scan", "session.fire.compact",
+             "session.fire.reset"},
+}
+
+
+@pytest.mark.parametrize("which", ["step", "fire"])
+def test_session_programs_compile_at_the_benchmark_shape(v5e_devices, which):
+    """Both programs of the session operator compile for a described v5e
+    at `capacity` 2^24, 4 lanes and 2^18 rows (PR 43), their planes
+    donated and rewritten in place, and fit a 16 GB chip many times over
+    (the live bytes are printed: a later PR that makes them not fit is
+    caught here, without a chip). Every instruction that reads or writes
+    1 MiB or more lies in a named region (the compiler's own re-tiling
+    of a plane around its scatter among them, by what it feeds), and no
+    whole 64-bit plane is an argument or a result: the planes go in and
+    come out as their 32-bit words."""
+    import re
+
+    from flink_tpu.metrics.device import UNNAMED, classify_hlo
+
+    compiled = _session_program(v5e_devices, which)
+    hlo = compiled.as_text()
+    assert f"HloModule jit_{which}" in hlo
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"\nsession {which} at 2^24 x 4 lanes: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, aliased "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB, live {live / 1e9:.3f} GB")
+    # the table (134 MB) and six words + __open__ (1.68 GB) + cur_lane
+    state = _SESSION_SHAPE["cap"] * (6 * 4 + 1) * _SESSION_SHAPE["lanes"]
+    assert mem.alias_size_in_bytes >= state
+    assert live < 4e9
+    entry = next(line for line in hlo.splitlines()
+                 if line.startswith("ENTRY"))
+    assert "s64[4,16777216]" not in entry
+    regions = classify_hlo(hlo)
+    found = set(regions.values())
+    assert _SESSION_REGIONS[which] <= found, _SESSION_REGIONS[which] - found
+    big = _big_instructions(hlo)
+    assert len(big) > 20
+    unnamed = sorted(name for name in big if regions.get(name) == UNNAMED)
+    # what is left unnamed is small: the pieces of a cumulative sum over
+    # the batch that the compiler cuts loose from their scope
+    sizes = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT\s+)?%([\w.\-]+)\s+=\s+(.*)$", line)
+        if m and m.group(1) in unnamed:
+            sizes[m.group(1)] = m.group(2)[:60]
+    assert not [n for n, text in sizes.items()
+                if "16777216" in text or "67108864" in text], sizes
