@@ -304,7 +304,10 @@ _LOCAL_KEY_PREFIX = "((('local',"
 @rule("JX505", "sharded program keyed by non-local shapes", "B",
       "every 'mesh.*' program builder must be keyed by the local-shard "
       "signature (parallel/sharded_window.local_signature: schema + "
-      "per-device dims) and NEVER by the device count or a global "
+      "per-device dims; the local leaves it determines are table "
+      "[1, capacity] int64, accs [1, ring, capacity] per dtype, a 64-bit "
+      "integer one as two uint32 words, dropped [1] int64) and NEVER by "
+      "the device count or a global "
       "[D, ...] shape — a global-keyed builder compiles a different "
       "program per mesh size, so a live rescale that preserves local "
       "shard shapes pays a recompile instead of a cache hit "

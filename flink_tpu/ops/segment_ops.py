@@ -23,7 +23,7 @@ import numpy as np
 __all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
            "Halves", "plane_map", "plane_take", "plane_row", "planes_joined",
            "planes_stored_like", "stores_halves", "identity_words",
-           "make_plane", "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
+           "plane_identity", "make_plane", "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
            "make_accumulator", "segment_topk", "pow2_ceil",
            "merge_tree_build", "merge_tree_update", "merge_tree_root"]
 
@@ -97,12 +97,14 @@ def make_accumulator(kind: str, shape: tuple[int, ...], dtype) -> jax.Array:
 @jax.tree_util.register_pytree_node_class
 class Halves:
     """A 64-bit integer plane STORED as its two 32-bit words: ``hi`` and
-    ``lo``, two ``uint32`` arrays of the plane's shape. The one-chip
-    backend keeps its pane-role ring planes so (``state/tpu_backend``):
-    the TPU has no 64-bit registers, so a program whose parameter or
-    result is an ``s64`` array splits ALL of it into words at its entry
-    and joins ALL of it at its exit, whatever it touches (a quarter of the
-    one-chip step and two thirds of its fire until PR 42). Handed the
+    ``lo``, two ``uint32`` arrays of the plane's shape. Both stacks keep
+    their pane-role ring planes so (``state/tpu_backend``; the mesh's
+    ``ShardedWindowState.accs``, ``[D, ring, capacity]`` a word): the
+    TPU has no 64-bit registers, so a program whose parameter or result
+    is an ``s64`` array splits ALL of it into words at its entry and
+    joins ALL of it at its exit, whatever it touches (a quarter of the
+    one-chip step and two thirds of its fire until PR 42; 25 of the mesh
+    step's 93 ms and 37 of its fire's 58 until PR 44). Handed the
     words, a program slices or gathers what it needs of each, ``join``s
     that, computes in 64 bits as before (the compiler keeps an ``s64`` as
     its pair of words anyway, so the join of a sliced row is no work) and
@@ -219,8 +221,9 @@ def planes_stored_like(stored: dict, planes: dict) -> dict:
 
 
 def stores_halves(dtype, ring, role: str = "pane") -> bool:
-    """Whether the one-chip backend stores a plane as ``Halves``: a
-    pane-role ring plane of a 64-bit integer."""
+    """Whether a plane is stored as ``Halves`` (the one-chip backend's
+    and the mesh state's one layout rule): a pane-role ring plane of a
+    64-bit integer."""
     dtype = np.dtype(dtype)
     return bool(ring) and role == "pane" and dtype.kind in "iu" \
         and dtype.itemsize == 8
@@ -233,6 +236,13 @@ def identity_words(kind: str, dtype) -> Halves:
     info = np.iinfo(dtype)
     ident = {"min": info.max, "max": info.min}.get(kind, 0)
     return Halves.split(np.asarray(ident, dtype))
+
+
+def plane_identity(kind: str, plane):
+    """The aggregate's identity in ``plane``'s layout: the two words for
+    a ``Halves`` plane."""
+    return (identity_words(kind, plane.dtype) if isinstance(plane, Halves)
+            else AGG_INITS[kind](plane.dtype))
 
 
 def make_plane(kind: str, shape: tuple[int, ...], dtype, halves: bool):
@@ -266,8 +276,9 @@ def ring_fold(kind: str, plane, ring_idx: jax.Array,
               valid: jax.Array):
     """Fold a batch into a ``[ring, capacity]`` plane, ring row by ring
     row: plane[ring_idx, slots] op= values, masked by ``valid``. The
-    plane is one array (the mesh's) or the ``Halves`` of a 64-bit one
-    (the one-chip backend's), and comes back as it came. No flat
+    plane is the ``Halves`` of a 64-bit integer one or one array (a
+    float or 32-bit plane), on one chip and on a mesh's shard alike, and
+    comes back as it came. No flat
     view of the plane is taken: the TPU keeps a 2-D plane tiled, and
     ``plane.reshape(-1)`` around a scatter copies all of it into a flat
     buffer and back (three quarters of the one-chip ingest step until

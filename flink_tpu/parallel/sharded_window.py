@@ -52,8 +52,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..metrics.device import instrumented_program_cache
 from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
 from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
-    AGG_MERGES, INVERTIBLE_KINDS, merge_tree_build, merge_tree_update, \
-    pow2_ceil, ring_fold
+    AGG_MERGES, INVERTIBLE_KINDS, make_plane, merge_tree_build, \
+    merge_tree_update, plane_identity, plane_map, plane_take, pow2_ceil, \
+    ring_fold, stores_halves
 from ..ops.topk import masked_topk_sort, threshold_topk
 from ..state.tpu_backend import reclaim_shard
 from .exchange import bucket_capacity, exchange_round, plan_exchange
@@ -81,7 +82,9 @@ class AggDef(NamedTuple):
 class ShardedWindowState(NamedTuple):
     """Pytree of device arrays; leading axis = mesh position ("data")."""
     table: jax.Array            # [D, capacity] int64 key table
-    accs: dict                  # name -> [D, ring, capacity]
+    accs: dict                  # name -> [D, ring, capacity]; a 64-bit
+    #                             integer plane as its two uint32 words
+    #                             (ops/segment_ops.Halves)
     dropped: jax.Array          # [D] int64 records lost to table overflow
 
 
@@ -98,9 +101,11 @@ def local_signature(aggs: Sequence[AggDef], capacity: int, ring: int
                     ) -> tuple:
     """The canonical program-cache key: aggregate schema + per-device
     shard dims. Fully determines every local leaf — table [1, capacity]
-    int64, accs [1, ring, capacity] per dtype, dropped [1] int64 — and is
-    invariant under device count and mesh identity, which is what lets a
-    rescale hit every cached program (JX505 pins this contract)."""
+    int64, accs [1, ring, capacity] per dtype, a 64-bit integer one as
+    two uint32 words (``stores_halves``: decided by the dtype, so the key
+    needs no word for it), dropped [1] int64 — and is invariant under
+    device count and mesh identity, which is what lets a rescale hit
+    every cached program (JX505 pins this contract)."""
     return ("local",
             tuple((a.name, a.kind, np.dtype(a.dtype).name) for a in aggs),
             int(capacity), int(ring))
@@ -127,7 +132,10 @@ def _make_init(sig, rules: tuple, mesh: Mesh):
     ``out_shardings`` are the plan's, so every device fills only its own
     ``[1, ...]`` shard and no device ever holds a global-sized array (a
     [4, 16, 2^23] int64 plane tiled on one device and then cut is 4.3 GB
-    and a second copy while it is cut; the shard is 1.07 GB)."""
+    and a second copy while it is cut; the shard is 1.07 GB). A 64-bit
+    integer plane is built as its two ``uint32`` words (a sharding per
+    plane is a prefix of its two leaves): no device ever holds an int64
+    plane."""
     _, agg_sig, cap, ring = sig
     aggs = _aggs_from_sig(agg_sig)
     # lint: sync-ok mesh.devices is a host numpy array of Device objects
@@ -142,11 +150,24 @@ def _make_init(sig, rules: tuple, mesh: Mesh):
     def init() -> ShardedWindowState:
         return ShardedWindowState(
             jnp.full((D, cap), EMPTY_KEY, jnp.int64),
-            {a.name: jnp.full((D, ring, cap), AGG_INITS[a.kind](a.dtype),
-                              a.dtype) for a in aggs},
+            {a.name: make_plane(a.kind, (D, ring, cap), a.dtype,
+                                stores_halves(a.dtype, ring))
+             for a in aggs},
             jnp.zeros(D, jnp.int64))
 
     return jax.jit(init, out_shardings=shardings)
+
+
+def _shard_plane(block):
+    """A shard's own ``[ring, capacity]`` plane out of the ``[1, ring,
+    capacity]`` block ``shard_map`` hands its body: each word of a
+    ``Halves`` plane, which stays the two words it is stored as."""
+    return plane_map(lambda words: words[0], block)
+
+
+def _mesh_plane(plane):
+    """``_shard_plane`` undone, for the body's result."""
+    return plane_map(lambda words: words[None], plane)
 
 
 def _per_mesh(make):
@@ -199,7 +220,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
     def shard_body(table, accs, dropped, keys, cols, panes, valid,
                    base_start, base_len):
         table, keys = table[0], keys[0]
-        accs = {k: v[0] for k, v in accs.items()}
+        accs = {k: _shard_plane(v) for k, v in accs.items()}
         cols = {k: v[0] for k, v in cols.items()}
         panes, valid = panes[0], valid[0]
 
@@ -263,7 +284,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
             lambda c: c[0] < n_rounds, fold_round, carry)
         with jax.named_scope("mesh.sync"):
             processed = jax.lax.psum(ok_count, axis_name)
-        return (table[None], {k: v[None] for k, v in accs.items()},
+        return (table[None], {k: _mesh_plane(v) for k, v in accs.items()},
                 dropped, processed, n_rounds)
 
     skel = {"table": 0, "accs": {a.name: 0 for a in aggs},
@@ -282,7 +303,9 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
     # Without it every step allocates a second state (2.2 GB a chip
     # at [16, 2^23] x 2 planes) and copies the planes it did not
     # touch. Programs already enqueued on the old buffers stay valid;
-    # a Python handle on the old state does not.
+    # a Python handle on the old state does not. A 64-bit plane rides
+    # the rounds' loop as its two uint32 words, and ``ring_fold`` joins
+    # only the ring row it folds into.
     @functools.partial(jax.jit, donate_argnums=(0,))
     def step(state: ShardedWindowState, keys, cols, panes, valid,
              base_start, base_len):
@@ -308,6 +331,20 @@ def _step_program(sig, max_parallelism: int, axis_name: str,
                                              axis_name, rules, mesh))
 
 
+def _ring_rows(plane, rows: jax.Array) -> jax.Array:
+    """Ring rows ``rows`` ([W] int32) of every shard's plane as values of
+    the plane's own dtype, [D, W, cap]: the words of a ``Halves`` plane
+    are gathered first and joined after (``plane_take``), so only what a
+    fire reads is ever 64 bits wide."""
+    return plane_take(plane, lambda a: a[:, rows, :])
+
+
+def _ring_row(plane, row: jax.Array) -> jax.Array:
+    """Ring row ``row`` (a traced scalar) of every shard's plane,
+    [D, cap], taken as ``_ring_rows`` takes."""
+    return plane_take(plane, lambda a: jnp.take(a, row, axis=1))
+
+
 @instrumented_program_cache("mesh.fire")
 def _fire_program(sig):
     _, agg_sig, _cap, _ring = sig
@@ -318,7 +355,7 @@ def _fire_program(sig):
     def fire(state: ShardedWindowState, pane_rows: jax.Array,
              rows_valid: jax.Array):
         def merge(kind, arr):
-            sub = arr[:, pane_rows, :]              # [D, W, cap]
+            sub = _ring_rows(arr, pane_rows)        # [D, W, cap]
             ident = AGG_INITS[kind](arr.dtype)
             sub = jnp.where(rows_valid[None, :, None], sub, ident)
             return AGG_MERGES[kind](sub, axis=1)
@@ -364,7 +401,7 @@ def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
     @jax.jit
     def fire(state: ShardedWindowState, pane_rows, rows_valid):
         def merge(kind, arr):
-            sub = arr[:, pane_rows, :]              # [D, W, cap]
+            sub = _ring_rows(arr, pane_rows)        # [D, W, cap]
             ident = AGG_INITS[kind](arr.dtype)
             sub = jnp.where(rows_valid[None, :, None], sub, ident)
             return AGG_MERGES[kind](sub, axis=1)
@@ -423,11 +460,10 @@ def _seal_inc_program(sig):
         view, new_wins, new_trees = {}, {}, {}
         for kind, name in inv_sig:
             arr = state.accs[name]                  # [D, ring, cap]
-            sealed = jnp.take(arr, new_row, axis=1)  # [D, cap]
+            sealed = _ring_row(arr, new_row)         # [D, cap]
             fire_v = AGG_COMBINE2[kind](wins[name], sealed)
             ident = AGG_INITS[kind](arr.dtype)
-            retire = jnp.where(sub_valid,
-                               jnp.take(arr, sub_row, axis=1), ident)
+            retire = jnp.where(sub_valid, _ring_row(arr, sub_row), ident)
             new_wins[name] = AGG_INVERT[kind](fire_v, retire)
             view[name] = fire_v
         for kind, name in tree_sig:
@@ -441,7 +477,7 @@ def _seal_inc_program(sig):
             )(trees[name], ident)
             tree = jax.vmap(
                 lambda t, v: merge_tree_update(kind, t, new_leaf, v)
-            )(tree, jnp.take(arr, new_row, axis=1))
+            )(tree, _ring_row(arr, new_row))
             new_trees[name] = tree
             view[name] = tree[:, 1]
         return view, new_wins, new_trees
@@ -468,17 +504,16 @@ def _rebuild_inc_program(sig):
             arr = state.accs[name]
             ident = AGG_INITS[kind](arr.dtype)
             sub = jnp.where(rows_valid[None, :, None],
-                            arr[:, pane_rows, :], ident)
+                            _ring_rows(arr, pane_rows), ident)
             fire_v = AGG_MERGES[kind](sub, axis=1)   # [D, cap]
-            retire = jnp.where(sub_valid,
-                               jnp.take(arr, sub_row, axis=1), ident)
+            retire = jnp.where(sub_valid, _ring_row(arr, sub_row), ident)
             new_wins[name] = AGG_INVERT[kind](fire_v, retire)
             view[name] = fire_v
         for kind, name in tree_sig:
             arr = state.accs[name]
             ident = AGG_INITS[kind](arr.dtype)
             rows = jnp.where(rows_valid[None, :, None],
-                             arr[:, pane_rows, :], ident)
+                             _ring_rows(arr, pane_rows), ident)
             leaves = jnp.full((arr.shape[0], L, arr.shape[2]), ident,
                               arr.dtype)
             idx = jnp.where(rows_valid, pane_leaves, L)
@@ -527,16 +562,17 @@ def _retire_program(sig):
     aggs = _aggs_from_sig(agg_sig)
 
     # donated like the step's state, so no second copy of the planes is
-    # allocated. It still costs a pass over both planes (31 ms at
-    # [16, 2^23] on a v5e), and the row write is not why: every program
-    # that takes an int64 plane splits it into 32-bit halves and joins
-    # them again, and a one-row dynamic_update_slice in this place reads
-    # 27.9 ms for 29.2 (PERF.md section 7, PR 36; ROADMAP S5c, S10)
+    # allocated, and nothing but the row is written: the identity (its
+    # two words, for a 64-bit plane) into ONE ring row of each buffer.
+    # While the planes were int64 arrays this was a pass over both
+    # (31 ms at [16, 2^23] on a v5e, 26 of them the split into 32-bit
+    # halves and the join: PERF.md section 6, PR 36; ROADMAP S5c)
     @functools.partial(jax.jit, donate_argnums=(0,))
     def retire(accs: dict, row: jax.Array):
         with jax.named_scope("fire.retire"):
-            return {a.name: accs[a.name].at[:, row].set(
-                        AGG_INITS[a.kind](accs[a.name].dtype))
+            return {a.name: plane_map(
+                        lambda words, ident: words.at[:, row].set(ident),
+                        accs[a.name], plane_identity(a.kind, accs[a.name]))
                     for a in aggs}
 
     return retire
@@ -553,10 +589,13 @@ def _make_reclaim(sig, axis_name: str, rules: tuple, mesh: Mesh):
     live = tuple(range(len(plane_sig)))
 
     def shard_body(table, accs, dropped):
+        # a shard's plane in the layout the state keeps it in: the words
+        # of a 64-bit one, which the reclaim tests and moves as words
         table, planes, dropped, counts = reclaim_shard(
-            plane_sig, live, table[0], tuple(accs[n][0] for n in names),
-            dropped[0])
-        return (table[None], {n: p[None] for n, p in zip(names, planes)},
+            plane_sig, live, table[0],
+            tuple(_shard_plane(accs[n]) for n in names), dropped[0])
+        return (table[None],
+                {n: _mesh_plane(p) for n, p in zip(names, planes)},
                 dropped[None], counts[None])
 
     skel = {"table": 0, "accs": dict.fromkeys(names, 0), "dropped": 0}

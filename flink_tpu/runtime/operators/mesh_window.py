@@ -40,7 +40,7 @@ import numpy as np
 from ...core.keygroups import hash_batch, key_groups_for_hash_batch
 from ...core.records import RecordBatch, Schema
 from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, make_table
-from ...ops.segment_ops import AGG_INITS, make_accumulator
+from ...ops.segment_ops import AGG_INITS, Halves, plane_map, stores_halves
 from ...metrics.device import DEVICE_STATS, pytree_nbytes
 from ...metrics.tracing import TRACER
 from ...parallel.mesh import make_mesh, shard_ranges
@@ -65,6 +65,12 @@ _LOAD_LIMIT = 0.6
 #: is no place to wait at; past 0.65 the probe's 128-slot bound starts
 #: to bite (a linear-probing cluster over 128 slots: about e^-10 a key)
 _LOAD_CEILING = 0.65
+
+
+def _identity(kind: str, dtype) -> np.ndarray:
+    """An aggregate's identity as a host scalar of ``dtype``."""
+    # lint: sync-ok a scalar constant, at restore, growth and rescale only
+    return np.asarray(jax.device_get(AGG_INITS[kind](jnp.dtype(dtype))))
 
 
 @jax.jit
@@ -780,6 +786,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         # taken for a growth is of the table as it is now
         self._finish_reclaim(block=True, grow=False)
         table = np.asarray(jax.device_get(self._state.table))  # [D, cap]
+        # a plane kept as its two words is joined HERE, on the host (numpy:
+        # `np.asarray` of a `Halves`): the snapshot holds the values, and
+        # its bytes are what they were when the planes were int64 arrays
         host_accs = {n: np.asarray(jax.device_get(v))
                      for n, v in self._state.accs.items()}  # [D, ring, cap]
         keys_parts, group_parts = [], []
@@ -947,9 +956,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             raise RuntimeError(
                 f"cannot restore onto ring {self._ring}: {len(span)} panes "
                 "are live; increase ring_size")
-        identity = np.asarray(jax.device_get(AGG_INITS[kind](
-            jnp.dtype(dtype))))
-        out = np.full((self._ring, vals.shape[1]), identity,
+        out = np.full((self._ring, vals.shape[1]), _identity(kind, dtype),
                       dtype=vals.dtype)
         for p in span:
             out[p % self._ring] = vals[p % old_ring]
@@ -999,8 +1006,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                             else np.empty((self._ring, 0)))
         D, cap, ring = self._n_devices, self._agg.capacity, self._ring
         tables = np.empty((D, cap), np.int64)
-        accs = {a.name: np.empty((D, ring, cap),
-                                 np.dtype(jnp.dtype(a.dtype).name))
+        accs = {a.name: np.full((D, ring, cap), _identity(a.kind, a.dtype))
                 for a in self._agg.aggs}
         for d, rng in enumerate(self._agg.shard_ranges):
             sel = (all_groups >= rng.start) & (all_groups <= rng.end)
@@ -1013,21 +1019,26 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                     raise RuntimeError(
                         "mesh restore overflow: raise capacity")
             tables[d] = np.asarray(jax.device_get(table_d))
-            for a in self._agg.aggs:
-                acc = np.array(jax.device_get(make_accumulator(
-                    a.kind, (ring, cap), a.dtype)))
-                if len(keys_d):
-                    acc[:, np.asarray(jax.device_get(slots))] = \
-                        vals[a.name][:, sel]
-                accs[a.name][d] = acc
+            if len(keys_d):
+                at = np.asarray(jax.device_get(slots))
+                for a in self._agg.aggs:
+                    accs[a.name][d][:, at] = vals[a.name][:, sel]
         # host arrays go to their shards directly: through jnp.asarray the
-        # whole [D, ...] array would sit on one device first
+        # whole [D, ...] array would sit on one device first. A plane the
+        # state keeps as its two words is split HERE, with numpy, and the
+        # words go up: no device splits or joins a plane, here or anywhere
         sharding = self._agg._sharding
+
+        def put(host: np.ndarray):
+            return jax.device_put(host, sharding)
+
         self._state = None
         self._state = ShardedWindowState(
-            table=jax.device_put(tables, sharding),
-            accs={n: jax.device_put(v, sharding) for n, v in accs.items()},
-            dropped=jax.device_put(np.zeros(D, np.int64), sharding))
+            table=put(tables),
+            accs={n: plane_map(put, Halves.split(v)
+                               if stores_halves(v.dtype, ring) else v)
+                  for n, v in accs.items()},
+            dropped=put(np.zeros(D, np.int64)))
         self._occ_known = int((tables != np.int64(EMPTY_KEY)).sum(1).max())
 
     # -- teardown ----------------------------------------------------------

@@ -46,8 +46,8 @@ from ..ops.hash_table import (
     EMPTY_KEY, compacts, hash_keys_device, lookup, lookup_or_insert,
     make_table, sanitize_keys_device,
 )
-from ..ops.segment_ops import AGG_INITS, Halves, identity_words, \
-    make_plane, plane_map, ring_fold, scatter_fold, stores_halves
+from ..ops.segment_ops import AGG_INITS, Halves, make_plane, \
+    plane_identity, plane_map, ring_fold, scatter_fold, stores_halves
 from .backend import KeyedStateBackend, State, ValueState, register_backend
 from .descriptors import StateDescriptor
 from .spill import HostTier
@@ -165,15 +165,8 @@ def _permute(where: jax.Array, values):
                                       is_stable=False)[1:])
 
 
-def _identity(kind: str, plane):
-    """The aggregate's identity in ``plane``'s layout: the two words for
-    a ``Halves`` plane."""
-    return (identity_words(kind, plane.dtype) if isinstance(plane, Halves)
-            else AGG_INITS[kind](plane.dtype))
-
-
 def _differs(plane, ident) -> jax.Array:
-    """Where a plane (or a row of one) is not ``ident`` (``_identity``):
+    """Where a plane (or a row of one) is not ``ident`` (``plane_identity``):
     a ``Halves`` plane is tested word against word, never joined."""
     if isinstance(plane, Halves):
         return (plane.hi != ident.hi) | (plane.lo != ident.lo)
@@ -190,11 +183,12 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     every plane re-seated onto the new slots, at fixed shapes. ``sig`` =
     tuple of (kind, dtype_str, shape) over ALL of the table's array
     states, as ``_reset_row_program``'s; ``live_planes`` indexes the ones
-    that decide what lives (the pane-role ring planes). A plane is one
-    array (the mesh's, and the one-chip backend's narrow and window-role
-    planes) or the ``Halves`` of a 64-bit one (the one-chip backend's
-    pane-role ring planes): told apart by what is handed in, tested and
-    moved word by word, and handed back as it came.
+    that decide what lives (the pane-role ring planes). A plane is the
+    ``Halves`` of a 64-bit integer one (the pane-role ring planes of
+    both stacks) or one array (a float or 32-bit plane of either, and
+    the one-chip backend's window-role planes): told apart by what is
+    handed in, tested and moved word by word, and handed back as it
+    came.
 
     * ``reclaim.live``: a slot lives iff it is occupied and some ring row
       of some ``live_planes`` plane differs from its aggregate's identity
@@ -235,7 +229,7 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
         holds = jnp.zeros(C, bool)
         for i in live_planes:
             holds = holds | _differs(
-                arrays[i], _identity(sig[i][0], arrays[i])).any(axis=0)
+                arrays[i], plane_identity(sig[i][0], arrays[i])).any(axis=0)
         live = occupied & holds
         home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
             jnp.int32) == slot
@@ -272,7 +266,7 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     with jax.named_scope("reclaim.remap"):
         out = []
         for (kind, _dt, _shape), a in zip(sig, arrays):
-            ident = _identity(kind, a)
+            ident = plane_identity(kind, a)
 
             def reseat(row, ident=ident):
                 return jax.lax.cond(

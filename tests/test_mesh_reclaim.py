@@ -26,7 +26,7 @@ from flink_tpu.core.records import RecordBatch, Schema
 from flink_tpu.metrics import DEVICE_STATS
 from flink_tpu.metrics.tracing import TRACER
 from flink_tpu.ops.hash_table import EMPTY_KEY, lookup
-from flink_tpu.ops.segment_ops import AGG_INITS
+from flink_tpu.ops.segment_ops import AGG_INITS, Halves
 from flink_tpu.parallel.mesh import make_mesh
 from flink_tpu.parallel.sharded_window import AggDef, ShardedWindowAgg
 from flink_tpu.runtime import OneInputOperatorTestHarness
@@ -75,22 +75,35 @@ def _seeded_state(capacity: int, seed: int):
 @pytest.fixture(scope="module", params=[1 << 9, 1 << 11])
 def reclaimed(request):
     agg, state = _seeded_state(request.param, seed=request.param)
-    before = jax.device_get(state)
+    # every plane here is a 64-bit integer's: the state keeps its words
+    assert all(isinstance(p, Halves) for p in state.accs.values())
+    before = _on_host(state)
     new, counts = agg.reclaim(state)        # ``state`` is donated
-    return agg, before, jax.device_get(new), np.asarray(counts)
+    assert all(isinstance(p, Halves) for p in new.accs.values())
+    return agg, before, _on_host(new), np.asarray(counts)
+
+
+def _on_host(state):
+    """``state`` as numpy, each plane joined to the int64 values it holds."""
+    host = jax.device_get(state)
+    return host._replace(accs={n: np.asarray(p)
+                               for n, p in host.accs.items()})
 
 
 def test_the_sharded_reclaim_equals_the_one_chip_reclaim_on_each_shard(
         reclaimed):
     agg, before, after, counts = reclaimed
-    sig = tuple((a.kind, np.dtype(a.dtype).name, (RING, agg.capacity))
-                for a in AGGS)
+    # the one-chip backend's own program and layout: a 64-bit plane as
+    # its two words (signed ``halves:int64``)
+    sig = tuple((a.kind, "halves:" + np.dtype(a.dtype).name,
+                 (RING, agg.capacity)) for a in AGGS)
     one_chip = _reclaim_program(sig, tuple(range(len(AGGS))))
     assert counts.shape == (D, 2) and counts.dtype == np.int32
     for d in range(D):
         table, planes, dropped, kept_freed = one_chip(
             jnp.asarray(before.table[d]),
-            tuple(jnp.asarray(before.accs[a.name][d]) for a in AGGS),
+            tuple(Halves.split(jnp.asarray(before.accs[a.name][d]))
+                  for a in AGGS),
             jnp.asarray(before.dropped[d]))
         assert (np.asarray(table) == after.table[d]).all(), d
         for a, plane in zip(AGGS, planes):

@@ -15,6 +15,7 @@ from flink_tpu.parallel import (AggDef, ShardedWindowAgg, global_topk,
 from flink_tpu.parallel.mesh import device_index_for_key_groups
 
 from flink_tpu.ops.hash_table import ensure_x64
+from flink_tpu.ops.segment_ops import Halves
 
 ensure_x64()  # int64 keys on device (flipped before any test array exists)
 
@@ -174,15 +175,23 @@ def test_init_state_is_built_shard_by_shard(n_dev):
                    np.finfo(np.float32).min),
             "dropped": ((n_dev,), np.int64, 0)}
     leaves = {"table": state.table, "dropped": state.dropped, **state.accs}
+    # a 64-bit integer plane is kept as its two uint32 words; a narrower
+    # or a float plane stays one array
+    assert {n: isinstance(p, Halves) for n, p in state.accs.items()} \
+        == {"bids": True, "lo": False, "hi": False}
     for name, (shape, dtype, value) in want.items():
         leaf = leaves[name]
         assert leaf.shape == shape and leaf.dtype == dtype, name
-        assert leaf.sharding == agg.plan.state_sharding, name
         assert (np.asarray(jax.device_get(leaf)) == value).all(), name
-        shards = leaf.addressable_shards
-        assert sorted(s.device.id for s in shards) == sorted(
-            d.id for d in agg.mesh.devices.flat), name
-        assert all(s.data.shape == (1,) + shape[1:] for s in shards), name
+        for word in jax.tree.leaves(leaf):
+            assert word.dtype == (np.uint32 if isinstance(leaf, Halves)
+                                  else dtype), name
+            assert word.sharding == agg.plan.state_sharding, name
+            shards = word.addressable_shards
+            assert sorted(s.device.id for s in shards) == sorted(
+                d.id for d in agg.mesh.devices.flat), name
+            assert all(s.data.shape == (1,) + shape[1:]
+                       for s in shards), name
     compiled = agg.init_program().lower().compile()
     for sharding in jax.tree.leaves(compiled.output_shardings):
         assert sharding == agg.plan.state_sharding

@@ -60,10 +60,12 @@ def _q5_mesh(devices, capacity: int, batch: int):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
 
     D, B = len(devices), batch
+    # an int64 ring plane is handed over as the state keeps it: its two
+    # uint32 words, sharded as the plane is
     state = ShardedWindowState(
         spec((D, capacity), jnp.int64),
-        {a.name: spec((D, agg.ring, capacity), jnp.int64)
-         for a in agg.aggs},
+        {a.name: _plane_spec("halves:int64", (D, agg.ring, capacity),
+                             sharded) for a in agg.aggs},
         spec((D,), jnp.int64))
     args = (state, spec((D, B), jnp.int64),
             {"revenue": spec((D, B), jnp.int64)}, spec((D, B), jnp.int64),
@@ -72,7 +74,8 @@ def _q5_mesh(devices, capacity: int, batch: int):
 
 
 #: a device's shard of the benchmark's state (q5-16m-mesh4): a 2^23-slot
-#: table, two [16, 2^23] int64 planes, the drop counter
+#: table, two [16, 2^23] int64 planes (each its two uint32 words: the
+#: same bytes), the drop counter
 _SHARD_BYTES = (1 << 23) * 8 * (1 + 2 * 16) + 8
 
 
@@ -164,11 +167,10 @@ def test_mesh_step_compiles_without_a_plane_copy_at_the_benchmark_shape(
             hlo), kind
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _SHARD_BYTES - 4096
-    # beside the shard's state: the int64 planes' 32-bit halves (split
-    # once at the program's entry, joined once at its exit), the table's,
-    # and the ring row of a plane that is out being folded
-    assert mem.temp_size_in_bytes < 1.01 * (
-        _SHARD_BYTES + 2 * 8 * cap)
+    # beside the shard's state: the table's 32-bit halves, the ring row
+    # of a plane that is out being folded, the send buffers; no second
+    # copy of a plane (an int64 plane PARAMETER was one: its halves)
+    assert mem.temp_size_in_bytes < 32 * cap
 
 
 def _mesh_fire(devices):
@@ -412,7 +414,8 @@ def _mesh_reclaim(devices):
 def test_mesh_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     """ONE program `jit_reclaim` for a described v5e 2x2 in which every
     shard runs the backend's three steps over its own [16, 2^23] int64
-    planes (PR 41): the state donated and re-seated in place, no shard
+    planes, kept as their words (PR 41, PR 44): the state donated and
+    re-seated in place, no shard
     waiting for another (no collective at all), nothing but the state and
     each shard's two counts coming back, and it fits a 16 GB chip beside
     what a step leaves."""
@@ -433,8 +436,10 @@ def test_mesh_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
     agg, _sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
     state, counts = jax.eval_shape(agg.reclaim_program(), args[0])
     assert (counts.shape, str(counts.dtype)) == ((4, 2), "int32")
-    assert jax.tree.map(lambda a: (a.shape, a.dtype), state) \
-        == jax.tree.map(lambda a: (a.shape, a.dtype), args[0])
+    # every plane goes out in the layout it came in (its two words)
+    assert jax.tree.structure(state) == jax.tree.structure(args[0])
+    assert [(a.shape, a.dtype) for a in jax.tree.leaves(state)] \
+        == [(a.shape, a.dtype) for a in jax.tree.leaves(args[0])]
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= _SHARD_BYTES - (1 << 23) * 8 - 4096
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -621,8 +626,8 @@ def _region_programs(devices) -> dict:
     }
 
 
-#: the programs of the mesh stack, whose planes are int64 arrays
-_MESH_PROGRAMS = {"jit_step", "jit_fire", "jit_retire", "jit_reclaim.mesh"}
+#: the programs of the mesh stack that take the sharded table
+_MESH_TABLE_PROGRAMS = {"jit_step", "jit_fire", "jit_reclaim.mesh"}
 
 #: the regions each program must hold, beside the split and the join of
 #: its int64 arguments
@@ -678,16 +683,15 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
             kind = "x64.split" if m.group(2) == "Split" else "x64.join"
             assert regions[m.group(1)] == kind, line[:200]
             x64[kind] += 1
-    if program in _MESH_PROGRAMS or program == "jit_lookup_or_insert":
-        # the mesh stack's planes are int64 arrays (ROADMAP S10b), and
-        # the table's keys are everywhere (S1)
+    if program in _MESH_TABLE_PROGRAMS or program == "jit_lookup_or_insert":
+        # the table's int64 keys are everywhere (ROADMAP S1)
         assert x64["x64.split"] >= 2 and x64["x64.join"] >= 1, x64
     if program == "jit_retire":
-        # the row writes sit between the planes' split and their join and
-        # are neither: the rewriter left them a bare name
+        # the identity's word into one ring row of each plane's two
+        # words: four row writes and no split or join (PR 44)
         writes = re.findall(r"%([\w.\-]+) = [^=]* dynamic-update-slice\(",
                             hlo)
-        assert len(writes) >= 4
+        assert len(writes) == 4 and x64 == {"x64.split": 0, "x64.join": 0}
         assert {regions[name] for name in writes} == {"fire.retire"}
     if program == "jit_step":
         # the send buffers' scatters (the probe's compaction scatters
@@ -740,6 +744,32 @@ def _q7_reset(devices):
         jax.ShapeDtypeStruct((), jnp.int32, sharding=one)).compile())
 
 
+def _x64_instructions(hlo: str) -> dict:
+    """{instruction: (region, opcode, the most elements any array of its
+    result holds, its operands' text)} of everything the program's own map
+    (`metrics/device.classify_hlo`) books under `x64.split` / `x64.join`:
+    the custom calls and the moves between memory spaces only they feed."""
+    import re
+
+    from flink_tpu.metrics.device import classify_hlo
+
+    regions = classify_hlo(hlo)
+    sized = {}
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(([^)]*)",
+                     line)
+        if m and regions.get(m.group(1)) in ("x64.split", "x64.join"):
+            sized[m.group(1)] = (
+                regions[m.group(1)], m.group(3),
+                max(int(np.prod([int(d) for d in dims.split(",") if d],
+                                dtype=np.int64))
+                    for dims in re.findall(r"\[([\d,]*)\]", m.group(2))),
+                m.group(4))
+    assert set(sized) == {n for n, r in regions.items()
+                          if r in ("x64.split", "x64.join")}
+    return sized
+
+
 #: program -> (the slots of its table, whether a table-sized split or
 #: join is the table's own and so allowed: S1's, not this layout's)
 _ONE_CHIP_PLANE_PROGRAMS = {
@@ -767,30 +797,16 @@ def test_no_one_chip_program_splits_or_joins_a_plane(v5e_devices, program):
     the donating ones joined it at their exit (`X64Combine`), whatever
     they touched of it: 25 of the step's 121 ms and 37 of the fire's
     56 ms in q5-10m-saturated (ledger, PR 41)."""
-    import re
-
-    from flink_tpu.metrics.device import classify_hlo
-
     cap, table_allowed = _ONE_CHIP_PLANE_PROGRAMS[program]
     compiled = {**_region_programs(v5e_devices),
                 "jit_reclaim.q7": _q7_reclaim(v5e_devices),
                 "jit_reset.q7": _q7_reset(v5e_devices)}[program]
     hlo = compiled.as_text()
     assert f"HloModule {program.split('.')[0]}" in hlo
-    regions = classify_hlo(hlo)
-    sized = {}
-    for line in hlo.splitlines():
-        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = [us]\d+\[([\d,]*)\]\S* "
-                     r"custom-call\(([^)]*)\)", line)
-        if m and regions.get(m.group(1)) in ("x64.split", "x64.join"):
-            sized[m.group(1)] = (
-                regions[m.group(1)],
-                int(np.prod([int(d) for d in m.group(2).split(",") if d],
-                            dtype=np.int64)), m.group(3))
-    assert set(sized) == {n for n, r in regions.items()
-                          if r in ("x64.split", "x64.join")}
-    over_a_row = {n: v for n, v in sized.items() if v[1] >= cap}
-    assert not any(v[1] > cap for v in over_a_row.values()), over_a_row
+    sized = _x64_instructions(hlo)
+    over_a_row = {n: v for n, v in sized.items()
+                  if v[1] == "custom-call" and v[2] >= cap}
+    assert not any(v[2] > cap for v in sized.values()), sized
     if not table_allowed:
         assert not over_a_row, over_a_row
         if program.startswith("jit_reset"):
@@ -798,7 +814,7 @@ def test_no_one_chip_program_splits_or_joins_a_plane(v5e_devices, program):
         return
     splits = [v for v in over_a_row.values() if v[0] == "x64.split"]
     joins = [v for v in over_a_row.values() if v[0] == "x64.join"]
-    assert len(splits) == 2 and all("%table" in v[2] for v in splits), splits
+    assert len(splits) == 2 and all("%table" in v[3] for v in splits), splits
     # the reclaim hands back a new table; the fire hands back none
     assert len(joins) == (1 if program.startswith("jit_reclaim") else 0)
     # and no second copy of a plane is made inside the program: what it
@@ -808,58 +824,151 @@ def test_no_one_chip_program_splits_or_joins_a_plane(v5e_devices, program):
     assert mem.temp_size_in_bytes < 48 * cap
 
 
-#: sha256 of `lower().as_text()` (StableHLO, no locations) of the mesh
-#: stack's four programs on a described v5e 2x2 at `_q5_mesh(devices, 2^10,
-#: 256)`, AT THE PARENT OF PR 42 (commit 1f66f4f). `ring_fold` and
-#: `reclaim_shard` are shared with the one-chip stack and tell the two
-#: layouts apart by what they are handed: handed int64 arrays they trace
-#: what they traced. A later change that MEANS to alter a mesh program
-#: (ROADMAP S10b) writes its own digests here (`_mesh_program_digests`).
-_MESH_DIGESTS_AT_1F66F4F = {
-    "jit_step": 
-        "42805652eb567edb68d234a7eeea194298b5098ee65978d228a5a38ee908bf9b",
-    "jit_fire": 
-        "1addce37b3e89072476c3860ff35c361037d49b2daacd9ec79c6621fa7fd2b14",
-    "jit_retire": 
-        "e11a013a2b95e474c2faf9630c675ed3b5934603702a4c155a869ce2d0ee1b32",
-    "jit_reclaim": 
-        "c2a80841e59bdc8486d81555470bf190a3408e63c1f61c59b5f61257f8ea4e51",
+#: the mesh stack's four programs (ISSUE 44): each takes the sharded state
+#: of M / F, a 2^23-slot table a chip under two `[4, 16, 2^23]` int64
+#: planes kept as their words; the retire takes the planes alone
+_MESH_PLANE_PROGRAMS = ("jit_step", "jit_fire", "jit_retire",
+                        "jit_reclaim.mesh")
+
+
+@pytest.mark.parametrize("program", _MESH_PLANE_PROGRAMS)
+def test_no_mesh_program_splits_or_joins_a_plane(v5e_devices, program):
+    """The mesh twin of the test above, on the described v5e 2x2 at M's
+    shapes (`[4, 16, 2^23]`, COUNT + SUM int64): `jit_step`, `jit_fire`,
+    `jit_retire` and the mesh `jit_reclaim` take the planes as their two
+    `uint32` words (`ShardedWindowState.accs`: `Halves`), so the regions
+    `x64.split` / `x64.join` of the program's own map hold no instruction
+    over as much as a ring ROW of a plane but the table's own (the hash
+    table's int64 keys, ROADMAP S1's: the step and the reclaim split it
+    and join the new one, the fire splits it, the retire never sees it).
+    Until PR 44 each split both planes of a shard at its entry and the
+    donating ones joined them at their exit, whatever they touched: 25.2
+    of the step's 93.5 ms and 37.0 of the fire's 58.2 ms in
+    q5-16m-mesh4-saturated (ledger, PR 43). The state stays donated, no
+    parameter or result of a program is an `s64` plane, and beside the
+    state a program holds less than one ring row of each plane's words
+    twice over (the fire: the window's rows, merged, and the select's
+    views)."""
+    cap, ring, planes = 1 << 23, 16, 2
+    compiled = _region_programs(v5e_devices)[program]
+    hlo = compiled.as_text()
+    assert f"HloModule {program.split('.')[0]}" in hlo
+    entry = next(line for line in hlo.splitlines()
+                 if line.startswith("ENTRY"))
+    assert f"s64[1,{ring},{cap}]" not in entry
+    assert entry.split("->")[0].count(f"u32[1,{ring},{cap}]") == 2 * planes
+    sized = _x64_instructions(hlo)
+    assert not any(v[2] > cap for v in sized.values()), sized
+    calls = [v[0] for v in sized.values()
+             if v[1] == "custom-call" and v[2] >= cap]
+    splits, joins = calls.count("x64.split"), calls.count("x64.join")
+    mem = compiled.memory_analysis()
+    plane_bytes = planes * ring * cap * 8
+    if program == "jit_retire":
+        # two one-row writes a plane, in place, and nothing else
+        assert not sized, sized
+        assert mem.alias_size_in_bytes >= plane_bytes
+        assert mem.temp_size_in_bytes < 1 << 20
+        return
+    # the table's two words at the entry; the step and the reclaim hand
+    # back a new table, the fire hands back none
+    assert splits == 2 and joins == (0 if program == "jit_fire" else 1), \
+        sized
+    if program != "jit_fire":
+        assert mem.alias_size_in_bytes >= _SHARD_BYTES - cap * 8 - 4096
+    # no second copy of a plane (1.07 GB a shard): the step's send
+    # buffers and the probe's, the reclaim's per-slot vectors, the fire's
+    # [W, cap] rows and views
+    assert mem.temp_size_in_bytes < 128 * cap, mem.temp_size_in_bytes
+
+
+#: sha256 of `lower().as_text()` (StableHLO, no locations) of the ONE-CHIP
+#: stack's plane programs on a described v5e at `_one_chip_digest_programs`'
+#: shapes, AT THE PARENT OF PR 44 (commit 4081619). PR 44 changes the mesh
+#: stack's stored layout and must not touch this stack: `ring_fold` and
+#: `reclaim_shard` are shared, and handed what the one-chip backend hands
+#: them they trace what they traced. A later change that MEANS to alter a
+#: one-chip program writes its own digests here.
+_ONE_CHIP_DIGESTS_AT_4081619 = {
+    "jit_fold.q5":
+        "fb346eaa0dfce0cfbe1a75ae84dd78c96d885bbcab2fcd0c8c19b2ce129e5d59",
+    "jit_fold.q7":
+        "1427d30fcd0fb5ebd9b8bbe805b9f72da387dbee377b6d84a72509fcef0433dc",
+    "jit_fire_fn.q5":
+        "9da6a9579af24094a0730af2551359ea6214dc479836b3ed6d68c9c05bd0ca3f",
+    "jit_fire_fn.q7":
+        "3380eca9de098280a35143cd7d86438d2c717b73c1b0aa205eb0afb42c3141ca",
+    "jit_reset":
+        "e8e4d0355a1a73cec64b87a924a87ab6ad0261ad0c4ec239394270940560f1f9",
+    "jit_reclaim.q5":
+        "ff1098ef55b64a7a2ef6b53fc5cef6690cd9be715ab677d150311275abd4a21c",
+    "jit_reclaim.q7":
+        "18c9051f3d5cfbfbc683f74a4f478d02fa105a7e40f1591925c8f07a5cfadb74",
 }
 
 
-def _mesh_program_digests(devices) -> dict:
+def _one_chip_digest_programs(devices) -> dict:
+    """name -> sha256 of the StableHLO of the one-chip programs that take
+    ring planes, at 2^10 slots and 256 rows in the backend's own layouts
+    (Q5: int32 COUNT beside the int64 SUM's words; Q7: two int64 planes'
+    words)."""
     import hashlib
 
-    from flink_tpu.parallel.sharded_window import _retire_program
+    from flink_tpu.runtime.operators.device_window import _fire_program
+    from flink_tpu.state.tpu_backend import _fold_program, \
+        _reclaim_program, _reset_row_program
 
-    agg, sharded, args = _q5_mesh(devices[:4], 1 << 10, 256)
-    rep = NamedSharding(sharded.mesh, P())
-    retire = _retire_program(agg.sig)
-    lowered = {
-        "jit_step": agg.step_program().lower(
-            *args, np.int32(0), np.int32(128)),
-        "jit_fire": agg.fire_program("revenue", 1000).lower(
-            args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
-            jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)),
-        "jit_retire": getattr(retire, "_fn", retire).lower(
-            args[0].accs,
-            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)),
-        "jit_reclaim": agg.reclaim_program().lower(args[0]),
-    }
+    one = SingleDeviceSharding(devices[0])
+    cap, rows = 1 << 10, 256
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(program):
+        return getattr(program, "_fn", program)
+
+    sigs = {q: tuple((kind, dt, (ring, cap)) for kind, dt, (ring, _c) in sig)
+            for q, sig in _FOLD_SIGS.items()}
+
+    def planes(sig):
+        return tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig)
+
+    lowered = {}
+    for q, sig in sigs.items():
+        lowered[f"jit_fold.{q}"] = fn(_fold_program(sig)).lower(
+            planes(sig), spec((rows,), jnp.int32), spec((rows,), jnp.int64),
+            spec((rows,), jnp.bool_), (None, spec((rows,), jnp.int64)))
+    q5 = sigs["q5"]
+    for q, agg_sig, names, k, bits, panes in (
+            ("q5", (("count", "bids"), ("sum", "revenue")),
+             ("__count__", "revenue"), 1000, 48, 5),
+            ("q7", (("max", "best"),), ("__count__", "best"), 1, 43, 1)):
+        lowered[f"jit_fire_fn.{q}"] = fn(_fire_program(agg_sig, k, bits)).lower(
+            spec((cap,), jnp.int64),
+            {n: _plane_spec(dt, shape, one)
+             for n, (_k, dt, shape) in zip(names, sigs[q])},
+            spec((panes,), jnp.int32), spec((panes,), jnp.bool_),
+            spec((), jnp.int64))
+    lowered["jit_reset"] = fn(_reset_row_program(q5)).lower(
+        planes(q5), spec((), jnp.int32))
+    for q, sig in sigs.items():
+        lowered[f"jit_reclaim.{q}"] = fn(_reclaim_program(sig, (0, 1))).lower(
+            spec((cap,), jnp.int64), planes(sig), spec((), jnp.int64))
     return {name: hashlib.sha256(low.as_text().encode()).hexdigest()
             for name, low in lowered.items()}
 
 
-@pytest.mark.parametrize("program", list(_MESH_DIGESTS_AT_1F66F4F))
-def test_the_mesh_programs_lower_to_what_they_lowered_to(v5e_devices,
-                                                         program):
-    """M and F run another stack's programs, and PR 42 does not touch
-    them: `jit_step`, `jit_fire`, `jit_retire` and the mesh `jit_reclaim`
-    lower, letter for letter, to the text they lowered to at the parent
-    commit (int64 planes in, int64 planes out)."""
-    got = _compiled("mesh.digests",
-                    lambda: _mesh_program_digests(v5e_devices))
-    assert got[program] == _MESH_DIGESTS_AT_1F66F4F[program]
+@pytest.mark.parametrize("program", list(_ONE_CHIP_DIGESTS_AT_4081619))
+def test_the_one_chip_programs_lower_to_what_they_lowered_to(v5e_devices,
+                                                             program):
+    """X, S, U, Q, I and K run another stack's programs, and PR 44 does
+    not touch them: the backend's `jit_fold` (Q5's and Q7's signatures),
+    `jit_fire_fn`, `jit_reset` and `jit_reclaim` lower, letter for letter,
+    to the text they lowered to at the parent commit."""
+    got = _compiled("one_chip.digests",
+                    lambda: _one_chip_digest_programs(v5e_devices))
+    assert set(got) == set(_ONE_CHIP_DIGESTS_AT_4081619)
+    assert got[program] == _ONE_CHIP_DIGESTS_AT_4081619[program]
 
 
 # ---------------------------------------------------------------------------
