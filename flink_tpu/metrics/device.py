@@ -100,6 +100,11 @@ class DeviceStats:
         self._probe_rows = 0
         self._probe_tail_rows = 0
         self._probe_wide_batches = 0
+        # of the wide batches, those whose unresolved rows elected one
+        # lane a distinct key for the rounds, and the rows that stood
+        # behind a representative (the same device vector's other half)
+        self._probe_elected_rows = 0
+        self._probe_elected_batches = 0
         # mesh step accounting (PR 27): steps of the sharded window
         # program and the keyBy exchange rounds they took (one for a
         # batch spread evenly over the shards, more under skew). Read
@@ -411,19 +416,23 @@ class DeviceStats:
         with self._lock:
             return self._fires_drained, self._fires_drained_timer
 
-    def note_probe(self, rows: int, tail_rows: int,
-                   wide_batches: int) -> None:
+    def note_probe(self, rows: int, tail_rows: int, wide_batches: int,
+                   elected_rows: int = 0, elected_batches: int = 0) -> None:
         with self._lock:
             self._probe_rows += int(rows)
             self._probe_tail_rows += int(tail_rows)
             self._probe_wide_batches += int(wide_batches)
+            self._probe_elected_rows += int(elected_rows)
+            self._probe_elected_batches += int(elected_batches)
 
     @property
-    def probe_counts(self) -> tuple[int, int, int]:
-        """(rows probed, tail rows, wide batches)."""
+    def probe_counts(self) -> tuple[int, int, int, int, int]:
+        """(rows probed, tail rows, wide batches, rows that stood behind
+        an elected representative, batches that elected)."""
         with self._lock:
             return (self._probe_rows, self._probe_tail_rows,
-                    self._probe_wide_batches)
+                    self._probe_wide_batches, self._probe_elected_rows,
+                    self._probe_elected_batches)
 
     def note_mesh_steps(self, steps: int, rounds: int) -> None:
         with self._lock:
@@ -785,6 +794,8 @@ class DeviceStats:
                 "probe_rows_total": self._probe_rows,
                 "probe_tail_rows_total": self._probe_tail_rows,
                 "probe_wide_batches_total": self._probe_wide_batches,
+                "probe_elected_rows_total": self._probe_elected_rows,
+                "probe_elected_batches_total": self._probe_elected_batches,
                 "mesh_steps_total": self._mesh_steps,
                 "mesh_exchange_rounds_total": self._mesh_exchange_rounds,
                 "mesh_inserted_rows_total": self._mesh_inserted_rows,
@@ -903,6 +914,7 @@ class DeviceStats:
             self._fires_drained = self._fires_drained_timer = 0
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
+            self._probe_elected_rows = self._probe_elected_batches = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
             self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
@@ -1059,7 +1071,8 @@ REGION_SCOPES = {
 #: (``probe.claim``) and time the mesh step's shared part (``mesh.probe``,
 #: ``mesh.fold``), and for the lowering tests
 PATH_SCOPES = frozenset({
-    "probe.gather", "probe.claim", "probe.compact", "fold.scatter",
+    "probe.gather", "probe.claim", "probe.compact", "probe.elect",
+    "fold.scatter",
     "mesh.probe", "mesh.fold"})  # lint: key-ok scopes, not config keys
 UNNAMED = "unnamed"
 
@@ -1705,10 +1718,14 @@ def bind_device_metrics(registry) -> None:
     g.gauge("fires_drained_timer_total", lambda: s.fires_drained[1])
     # hash probe (prometheus: flink_tpu_device_probe_rows_total /
     # flink_tpu_device_probe_tail_rows_total /
-    # flink_tpu_device_probe_wide_batches_total)
+    # flink_tpu_device_probe_wide_batches_total /
+    # flink_tpu_device_probe_elected_rows_total /
+    # flink_tpu_device_probe_elected_batches_total)
     g.gauge("probe_rows_total", lambda: s.probe_counts[0])
     g.gauge("probe_tail_rows_total", lambda: s.probe_counts[1])
     g.gauge("probe_wide_batches_total", lambda: s.probe_counts[2])
+    g.gauge("probe_elected_rows_total", lambda: s.probe_counts[3])
+    g.gauge("probe_elected_batches_total", lambda: s.probe_counts[4])
     # mesh step (prometheus: flink_tpu_device_mesh_steps_total /
     # flink_tpu_device_mesh_exchange_rounds_total)
     g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])

@@ -40,10 +40,12 @@ NEXmark's advancing auction ids leaves 58% of a 2^18-row batch to the
 claiming rounds: PERF.md section 5, q5-inflight-saturated). A caller that
 sees such batches one after another asks for the program that runs the
 full-width rounds only until what is left fits the narrow loop
-(``handover``). CHUNK = 8 is the window at which those
-shares were measured; it was first sized for a CPU cache line (2.3x over
-one-slot probing at 50% load on the CPU), which is no argument here: on
-the chip a window costs what its CHUNK gathered elements cost.
+(``handover``), and in which, unless the caller's keys cannot repeat, a
+key's rows send ONE lane into the rounds (``_elect``). CHUNK = 8 is the
+window at which those shares were measured; it was first sized for a CPU
+cache line (2.3x over one-slot probing at 50% load on the CPU), which is
+no argument here: on the chip a window costs what its CHUNK gathered
+elements cost.
 
 Keys are int64 with EMPTY = int64 max as the sentinel (a real key equal to
 the sentinel is remapped by the caller — see state/tpu_backend.py).
@@ -89,6 +91,20 @@ _COMPACT_MIN_ROWS = 1 << 12
 # widths because a round costs what its lanes cost: 48.5 ms a 2^18-row
 # batch with both against 73.0 with n / 16 alone (508 before).
 _TAIL_SHIFTS = (6, 4)
+# The loops of a wide batch whose rows elected one lane a key (``_elect``),
+# narrowest first: what is left after the election is distinct keys,
+# mostly cold inserts. n / 4 too because a job whose keys come and go
+# inserts up to a quarter of a batch for some tens of batches after a
+# reclaim (PERF.md section 5, q5-inflight-saturated); more than that
+# starts at full width, as before. A round resolves all but a few per cent
+# of the new keys it carries, so every loop but the narrowest runs only
+# until the narrowest holds what is left.
+_ELECT_SHIFTS = (6, 4, 2)
+# cells of the election's scratch a lane of the batch: a key that shares
+# its cell with another key of a smaller lane stays unelected (every row
+# of it its own representative: correct, only wider), and with 4 cells a
+# lane a hot key among n / 8 distinct keys loses one time in 64
+_ELECT_CELLS = 4
 
 
 def sanitize_keys_device(keys: jax.Array) -> jax.Array:
@@ -229,6 +245,42 @@ def _claim_loop(table: jax.Array, keys: jax.Array, h0: jax.Array,
     return jax.lax.while_loop(unfinished, body, (table, base, slot, done))
 
 
+def _elect_cells(keys: jax.Array) -> tuple[jax.Array, int]:
+    """(the cell of the election's scratch each key hashes to, cells):
+    ``_ELECT_CELLS`` cells a lane, rounded up to a power of two, by the
+    top bits of a second mix of the probe's hash."""
+    bits = (_ELECT_CELLS * keys.shape[0] - 1).bit_length()
+    cell = (hash_keys_device(keys) * jnp.uint32(0x9E3779B1)) >> jnp.uint32(
+        32 - bits)
+    return cell.astype(jnp.int32), 1 << bits
+
+
+def _elect(keys: jax.Array, done: jax.Array):
+    """One representative lane for every distinct key among the rows not
+    ``done``: (rep, follows). The unresolved lanes ``scatter-min`` their
+    lane number into an int32 scratch at a second hash of their key and
+    read their cell back; a lane FOLLOWS the lane it finds there when
+    that lane's key is its own, compared as 64-bit keys (``rep`` is then
+    that lane, which follows nobody: it found itself). A lane whose cell
+    went to another key follows nobody either. Rows of one key read the
+    same windows, claim the same slot with the same value and read back
+    the same winner, so the followers take their representative's slot
+    when the rounds are over and the table, every slot and every ``ok``
+    are what they are with every row in the rounds. An int32 scatter and
+    gather, a 64-bit gather for the compare and (the caller's) an int32
+    gather of the slots: the price of sparing a key's other rows the
+    rounds, where one new hot key owns hundreds of a batch's rows."""
+    n = keys.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    cell, cells = _elect_cells(keys)
+    # (a resolved lane writes nothing: the identity into cell 0, as the
+    # claim does)
+    first = jnp.full(cells, n, jnp.int32).at[
+        jnp.where(done, 0, cell)].min(jnp.where(done, n, lane))
+    rep = jnp.where(done, lane, first[cell])
+    return rep, (rep != lane) & (keys[rep] == keys)
+
+
 def compacts(n: int) -> bool:
     """Whether a batch of ``n`` rows is wide enough for the probe to
     compact its unresolved rows (else every round runs at full width and
@@ -245,10 +297,10 @@ def _tail_widths(n: int) -> tuple[int, ...]:
     return tuple(n >> s for s in _TAIL_SHIFTS)
 
 
-@partial(jax.jit, static_argnames=("stats", "handover"))
+@partial(jax.jit, static_argnames=("stats", "handover", "distinct"))
 def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
                      valid: jax.Array | None = None, stats: bool = False,
-                     handover: bool = False):
+                     handover: bool = False, distinct: bool = False):
     """Find-or-claim slots for a batch of keys.
 
     Returns (new_table_keys, slots int32, ok bool). Records that exhaust
@@ -289,13 +341,35 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     batches resolve in their first window keeps the program it had.
     ``state/tpu_backend.py`` picks by the probe's own counters.
 
+    Such a caller's wide batch also ELECTS: where the tail does not fit
+    the widest narrow loop, and only there, every distinct unresolved key
+    sends one representative lane into the rounds (``_elect``), the
+    representatives go to the narrowest of the election's loops that
+    holds them, and every other lane takes its representative's slot
+    when the rounds are over. With NEXmark's advancing ids 131 thousand
+    of a batch's 150 thousand unresolved rows are the rows of 171 new hot
+    ids, and all 767 rows of one read the same window, write the same
+    key to the same slot and read back the same winner (PERF.md section
+    6, PR 46). The claim lets the smallest KEY win a slot, so with one
+    writer a key the table, every slot and every ``ok`` are what they are
+    with all of them. ``distinct=True`` (static) is a caller's word that
+    no key comes twice among the rows it sends (keys taken from a table:
+    the reclaim's re-homing; one lane a key run: the session step): it
+    gets the hand-over program without an election, and nothing of the
+    above is traced for it. Without ``handover`` it means nothing.
+
     ``stats=True`` (static) appends an int32[3]: rows probed, rows that
     entered a claiming loop (unresolved after the read-only window; every
     probed row of a batch below the compaction width), and 1 if that loop
-    started at full width else 0.
+    started at full width else 0; all three BEFORE any election, so that
+    a caller who picks its program by them keeps its pick. The electing
+    program (``handover`` without ``distinct``) appends an int32[2]
+    behind it: rows that stood behind a representative, and 1 if the
+    batch elected else 0.
     """
     n = keys.shape[0]
     widths = _tail_widths(n)
+    elects = handover and not distinct
     # the hashes and the rows' start state belong to whatever reads the
     # first window: the read-only one, or the claiming loop's
     with jax.named_scope("probe.window0" if widths else "probe.tail"):
@@ -320,21 +394,33 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
             base = _advance(base, done, pos_empty)
             n_tail = jnp.sum(~done, dtype=jnp.int32)
 
-        def narrow_loop(T, n_left, table, base, slot, done):
+        def narrow_loop(T, n_left, table, base, slot, done, cols=(keys, h0),
+                        then=0):
+            """The rounds over the unresolved rows of ``cols``, compacted
+            into ``T`` lanes; with ``then``, only until no more than that
+            many are left, which a loop of ``then`` lanes, compacted
+            again, finishes."""
+            ckeys, ch0 = cols
+            m = ckeys.shape[0]
             with jax.named_scope("probe.compact"):
                 # a stable sort on `done` puts the unresolved rows first, in
                 # row order: 0.24 ms at n = 2^18 on the v5e, where cumsum +
                 # searchsorted took 3.3 and jnp.nonzero(size=T) did not fit
                 # the compiler's vmem at all
                 _, order = jax.lax.sort_key_val(
-                    done.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32))
+                    done.astype(jnp.int32), jnp.arange(m, dtype=jnp.int32))
                 src = order[:T]
                 live = jnp.arange(T, dtype=jnp.int32) < n_left
-            table, _tbase, tslot, _tdone = _claim_loop(
-                table, keys[src], h0[src], mask, base[src],
-                jnp.full(T, -1, jnp.int32), ~live)
+            tkeys, th0 = ckeys[src], ch0[src]
+            table, tbase, tslot, tdone = _claim_loop(
+                table, tkeys, th0, mask, base[src],
+                jnp.full(T, -1, jnp.int32), ~live, until=then)
+            if then:
+                table, tslot = narrow_loop(
+                    then, jnp.sum(~tdone, dtype=jnp.int32), table, tbase,
+                    tslot, tdone, cols=(tkeys, th0))
             with jax.named_scope("probe.compact"):
-                slot = slot.at[jnp.where(live, src, n)].set(
+                slot = slot.at[jnp.where(live, src, m)].set(
                     tslot, mode="drop")
             return table, slot
 
@@ -348,12 +434,59 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
             return narrow_loop(widths[-1], jnp.sum(~done, dtype=jnp.int32),
                                table, base, slot, done)
 
+        def elected_loop(table, base, slot, done):
+            """A wide batch of keys that may repeat: one lane a distinct
+            key goes through the rounds (``_elect``), in the narrowest of
+            the election's loops that holds the representatives (at full
+            width until the widest does), each handing over to the
+            narrowest once that holds what is left; the other lanes take
+            their representative's slot. The same rounds over the same
+            keys, so the same table, slots and ``ok``."""
+            with jax.named_scope("probe.elect"):
+                rep, follows = _elect(keys, done)
+                done = done | follows
+                n_rep = jnp.sum(~done, dtype=jnp.int32)
+            ewidths = tuple(n >> s for s in _ELECT_SHIFTS)
+            last = ewidths[0]
+
+            def full_width(table, base, slot, done):
+                table, base, slot, done = _claim_loop(
+                    table, keys, h0, mask, base, slot, done,
+                    until=ewidths[-1])
+                return narrow_loop(
+                    ewidths[-1], jnp.sum(~done, dtype=jnp.int32), table,
+                    base, slot, done, then=last)
+
+            table, slot = jax.lax.switch(
+                sum((n_rep > T).astype(jnp.int32) for T in ewidths),
+                [partial(narrow_loop, T, n_rep, then=last if T > last else 0)
+                 for T in ewidths] + [full_width], table, base, slot, done)
+            with jax.named_scope("probe.elect"):
+                slot = jnp.where(follows, slot[rep], slot)
+                elected = jnp.stack([jnp.sum(follows, dtype=jnp.int32),
+                                     jnp.int32(1)])
+            return table, slot, elected
+
         # the narrowest loop that holds the tail, else the full width
         with jax.named_scope("probe.tail"):
             level = sum((n_tail > T).astype(jnp.int32) for T in widths)
             wide = (level == len(widths)).astype(jnp.int32)
-            table, slot = jax.lax.switch(
-                level, [partial(narrow_loop, T, n_tail) for T in widths]
-                + [wide_loop], table_keys, base, slot, done)
+            if elects:
+                def unelected(T, *state):
+                    return (*narrow_loop(T, n_tail, *state),
+                            jnp.zeros(2, jnp.int32))
+
+                table, slot, elected = jax.lax.switch(
+                    level, [partial(unelected, T) for T in widths]
+                    + [elected_loop], table_keys, base, slot, done)
+            else:
+                table, slot = jax.lax.switch(
+                    level, [partial(narrow_loop, T, n_tail) for T in widths]
+                    + [wide_loop], table_keys, base, slot, done)
     out = (table, slot, slot >= 0)
-    return (*out, jnp.stack([rows, n_tail, wide])) if stats else out
+    if not stats:
+        return out
+    out = (*out, jnp.stack([rows, n_tail, wide]))
+    if elects:
+        out = (*out, elected if widths else jnp.zeros(2, jnp.int32))
+    return out

@@ -152,7 +152,8 @@ def _sess_step(fold_sig: tuple, lanes: int, gap: int, dirty_block: int):
                 [jnp.zeros(1, bool), keys[1:] == keys[:-1]])
             run_start = in_batch & ~same_key
             table, fslot, fok = lookup_or_insert(table, keys, run_start,
-                                                 handover=True)
+                                                 handover=True,
+                                                 distinct=True)
             head = jax.lax.cummax(jnp.where(run_start, idx, 0))
             kslot, ok = fslot[head], fok[head]
         valid = ok & in_batch

@@ -614,7 +614,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._fused_spec = (source, int(subtask), int(parallelism))
         return True
 
-    def _register_aggs(self, schema: Schema) -> None:
+    def _register_aggs(self, schema: Schema, batch_rows: int) -> None:
         """Accumulator dtypes follow the input columns (sum over int64
         accumulates int64, matching the host operator's Python arithmetic);
         avg always accumulates float."""
@@ -633,10 +633,11 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # every plane of the job exists now (the incremental engine's
         # derived ones too: a reclaim re-seats them) and no input has
         # been taken: the reclaim of these planes is built here, not
-        # when a reading finds the table full (ROADMAP D14)
+        # when a reading finds the table full (ROADMAP D14), and beside
+        # it the probe program a new table's wide batches will run
         if self._inc_enabled:
             self._ensure_inc_planes(*self._inc_sigs())
-        self._backend.prepare_reclaim()
+        self._backend.prepare(batch_rows)
 
     def initialize_state(self, keyed_snapshots: list, operator_snapshot) -> None:
         if keyed_snapshots:
@@ -686,7 +687,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                     f"device window aggregation needs an integer key column; "
                     f"{self._key_column!r} is {key_dtype} — use the hashmap "
                     "state backend for float/string keys")
-            self._register_aggs(batch.schema)
+            self._register_aggs(batch.schema, batch.n)
         if self._validate_batches:
             batch = self._screen_nonfinite(batch)
             if batch.n == 0:

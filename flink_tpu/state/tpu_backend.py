@@ -31,6 +31,7 @@ the host backend — the graph planner routes accordingly.
 from __future__ import annotations
 
 import time
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Any, Iterable, Optional
 
@@ -246,7 +247,8 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
             src = jax.lax.dynamic_slice(order, (i * B,), (B,))
             valid = i * B + lane < n_moves
             new_table, slots, ok = lookup_or_insert(
-                new_table, table[src], valid, handover=compacts(B))
+                new_table, table[src], valid, handover=compacts(B),
+                distinct=True)     # keys of a table
             new_of = jax.lax.dynamic_update_slice(
                 new_of, jnp.where(ok, slots, src), (i * B,))
             return (new_table, new_of,
@@ -455,20 +457,28 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # on the device like _dropped; note_probe_stats hands them to
         # DEVICE_STATS once a copy taken at an earlier batch has landed
         self._probe = jnp.zeros(3, jnp.int64)
-        self._probe_sent: Optional[jax.Array] = None
-        self._probe_noted = np.zeros(3, np.int64)
+        # beside them what the wide-batch program's election counts (rows
+        # that stood behind a representative, batches that elected); only
+        # a batch through that program adds to it
+        self._elected = jnp.zeros(2, jnp.int64)
+        self._probe_sent: Optional[tuple[jax.Array, jax.Array]] = None
+        self._probe_noted = np.zeros(5, np.int64)
         # probes dispatched, and how many of them the counters last sent
         # and last noted had seen (note_probe_stats)
         self._probe_calls = self._probe_sent_calls = 0
         self._probe_noted_calls = 0
         # whether EVERY batch last noted left more rows unresolved after
         # its first window than the probe's narrow loops hold: the next
-        # batch then takes the program that hands the full-width rounds
-        # over to the narrow loop (ops/hash_table.lookup_or_insert)
+        # batch then takes the program in which one lane a distinct key
+        # enters the rounds and the full-width rounds hand over to the
+        # narrow loops (ops/hash_table.lookup_or_insert)
         self._probe_wide = False
         # the probe programs (by ``handover``) this backend has put in
         # the program audit
         self._probe_audited: set[bool] = set()
+        # the wide-batch program built ahead for a new table:
+        # (table shape, batch shape, executable) (prepare)
+        self._wide_probe: Optional[tuple] = None
         # the table's generation: every rebuild (growth, eviction,
         # reclaim, restore) moves the slots and starts a new one; a
         # health reading taken under an older one says nothing of this
@@ -1204,13 +1214,24 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 "state.probe",  # lint: key-ok audit scope, not a config key
                 lookup_or_insert, (self.table, dkeys),
                 {"stats": True, "handover": handover}, repr(handover))
-        self.table, slots, ok, probe = lookup_or_insert(
-            self.table, dkeys, stats=True, handover=handover)
+        self.table, slots, ok, probe, *elected = self._probe_program(
+            dkeys, handover)(self.table, dkeys)
         self._dropped = self._dropped + jnp.sum(~ok).astype(jnp.int64)
+        if elected:
+            self._elected = self._elected + elected[0]
         self._probe = self._probe + probe
         self.note_probe_stats()
         self.mark_dirty(slots)
         return slots
+
+    def _probe_program(self, dkeys: jax.Array, handover: bool):
+        """The probe as a callable of (table, keys): the wide-batch
+        program built ahead (``prepare``) where it is of these shapes,
+        else the jitted function, which builds at its first use."""
+        if handover and self._wide_probe is not None \
+                and self._wide_probe[:2] == (self.table.shape, dkeys.shape):
+            return self._wide_probe[2]
+        return partial(lookup_or_insert, stats=True, handover=handover)
 
     def note_probe_stats(self, block: bool = False) -> None:
         """Hand the probe's device counters to DEVICE_STATS. Per batch this
@@ -1221,18 +1242,23 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         The same reading picks the next batch's probe program: where
         every batch noted left more rows unresolved than the narrow loops
         hold (a prefill, or a stream whose keys come and go), the one
-        that hands its full-width rounds over to the narrow loop; else
-        the one every resident-key job has always run. Both give the
-        same slots, so a late or wrong pick costs time only."""
+        in which one lane a distinct key enters the rounds and the
+        full-width rounds hand over to the narrow loops; else the one
+        every resident-key job has always run. Both give the same
+        slots, so a late or wrong pick costs time only; and the counters
+        the pick reads are of the rows before that election, so a job
+        whose every batch is wide does not flip between the two."""
         if block:
             self._finish_reclaim(block=True)
-        sent = self._probe if block else self._probe_sent
-        if sent is not None and (block or sent.is_ready()):
+        sent = (self._probe, self._elected) if block else self._probe_sent
+        # (the election's counters are the older of the two: a batch adds
+        # to them first)
+        if sent is not None and (block or sent[0].is_ready()):
             # lint: sync-ok the copy has landed (or the caller syncs anyway)
-            now = np.asarray(jax.device_get(sent))
+            now = np.concatenate(jax.device_get(sent))
             calls = self._probe_calls if block else self._probe_sent_calls
-            rows, tail, wide = now - self._probe_noted
-            DEVICE_STATS.note_probe(rows, tail, wide)
+            rows, tail, wide, elected, elections = now - self._probe_noted
+            DEVICE_STATS.note_probe(rows, tail, wide, elected, elections)
             if calls > self._probe_noted_calls:
                 # every probe noted started its claiming rounds at full
                 # width
@@ -1241,7 +1267,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             self._probe_noted, self._probe_noted_calls = now, calls
             self._probe_sent = None
         if self._probe_sent is None and not block:
-            self._probe_sent = self._probe
+            self._probe_sent = (self._probe, self._elected)
             self._probe_sent_calls = self._probe_calls
 
     # ------------------------------------------------------------------
@@ -1327,6 +1353,35 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         return (_reclaim_program(_plane_sig(states), live),
                 (self.table, tuple(st.array for st in states),
                  self._dropped))
+
+    def prepare(self, batch_rows: int) -> None:
+        """Build ahead, at a job's first batch and before it is taken in,
+        what the job will run for certain beside the programs that batch
+        builds by running them: the reclaim (``prepare_reclaim``) and,
+        for a NEW table fed batches wide enough to compact, the probe's
+        wide-batch program (every key of the first batches is new, so
+        they start wide and the backend picks it from the third on), on a
+        thread of its own while this one builds the reclaim, and waited
+        for: nothing outlives the call. From an empty compile cache the
+        job then builds that program in the reclaim's time (21 s beside
+        26 at 2^23 slots on the chip's host) where it built it at its
+        third batch, with the device idle and the sink silent for 16 s
+        more; a harness that takes a silent sink for a drained job then
+        timed the fire's programs, which were still to be built (PERF.md
+        section 6, PR 46: what voided PR 45's run). A table restored or
+        grown, and batches that differ from the first in size, build the
+        program at its first use, as before."""
+        if not (self._defer and self._num_keys == 0
+                and compacts(batch_rows)):
+            self.prepare_reclaim()
+            return
+        table, keys = (jax.ShapeDtypeStruct(shape, jnp.int64)
+                       for shape in ((self.capacity,), (batch_rows,)))
+        with ThreadPoolExecutor(1, "probe-build") as pool:
+            wide = pool.submit(lambda: lookup_or_insert.lower(
+                table, keys, stats=True, handover=True).compile())
+            self.prepare_reclaim()
+        self._wide_probe = (table.shape, keys.shape, wide.result())
 
     def prepare_reclaim(self) -> None:
         """Build the reclaim's program for the planes as they are now, so

@@ -247,7 +247,7 @@ def test_backend_hands_the_counters_to_device_stats():
     resident = jnp.arange(N, dtype=jnp.int64) * 3 + 1
     be.slots_for_batch_device(resident)                        # all new: wide
     assert moved(start) == [0, 0, 0]            # nothing has been fetched yet
-    be._probe_sent.block_until_ready()          # the copy taken at batch one
+    be._probe_sent[0].block_until_ready()       # the copy taken at batch one
     be.slots_for_batch_device(resident).block_until_ready()   # all resident
     assert moved(start) == [N, N, 1]            # the first batch's, landed
     be.slots_for_batch_device(jnp.arange(8, dtype=jnp.int64))  # the plain loop
@@ -286,6 +286,98 @@ def test_the_handover_program_places_every_row_where_the_plain_one_does(load):
     _check(table, batches[1], None, expect_wide=1)
 
 
+def _elected_by_the_dict(table, keys, valid) -> tuple[int, int]:
+    """(rows the election must put behind a representative, rows it could
+    at most): of the rows the first window leaves, every key that keeps
+    its scratch cell (the cell's smallest lane is one of its own) sends
+    all but that lane; a key that loses its cell sends none."""
+    cap = len(table)
+    was = _resident(table)
+    home = _homes(keys, cap)
+    left = np.array([v and not (k in was and (was[k] - h) % cap < CHUNK)
+                     for k, h, v in zip(keys.tolist(), home.tolist(),
+                                        valid.tolist())])
+    cells = np.asarray(H._elect_cells(jnp.asarray(keys))[0])
+    winner: dict[int, int] = {}
+    for lane in np.flatnonzero(left).tolist():
+        winner.setdefault(int(cells[lane]), lane)
+    rows: dict[int, int] = {}
+    for k in keys[left].tolist():
+        rows[k] = rows.get(k, 0) + 1
+    kept = {int(keys[lane]) for lane in winner.values()}
+    return (sum(rows[k] - 1 for k in kept),
+            int(left.sum()) - len(rows))
+
+
+#: name -> (new hot keys, rows of each, new distinct keys, share of valid
+#: rows); the rest of a batch are resident keys. By the representatives
+#: left: one key (the 64-lane loop), 8 + 150 (256 lanes, handing over), 8 +
+#: 600 (1,024 lanes, handing over), 8 + 3,000 (more than the widest elected
+#: loop: full width first)
+_ELECTIONS = {
+    "hot_ids_and_cold_inserts": (8, 300, 150, 1.0),
+    "hot_ids_and_many_cold_inserts": (8, 300, 600, 1.0),
+    "one_new_key": (1, N, 0, 1.0),
+    "no_duplicates": (0, 0, N // 2, 1.0),
+    "invalid_rows_among_the_duplicates": (8, 300, 150, 0.8),
+    "more_new_keys_than_the_widest_elected_loop": (4, 200, 3000, 1.0),
+}
+
+
+@pytest.mark.parametrize("load", [0.3, 0.58])
+@pytest.mark.parametrize("case", list(_ELECTIONS))
+def test_the_electing_program_places_every_row_where_the_plain_one_does(
+        case, load):
+    """``handover=True`` from a caller whose keys may repeat: a wide batch
+    sends ONE lane a distinct unresolved key through the rounds and the
+    others take its slot. Table, slots, ``ok`` and the three counters are
+    the plain program's, bit for bit, and the elected rows are those of
+    the keys that kept their scratch cell: all but one lane of each."""
+    hot, per, cold, valid_share = _ELECTIONS[case]
+    rng = np.random.default_rng(len(case) + int(load * 100))
+    table = _fill(CAP, rng.choice(1 << 40, int(load * CAP),
+                                  replace=False).astype(np.int64))
+    resident = table[table != EMPTY_KEY]
+    new = rng.choice(1 << 41, hot + cold, replace=False).astype(
+        np.int64) + (1 << 41)
+    keys = rng.choice(resident, N, replace=bool(hot))
+    at = rng.permutation(N)
+    for j in range(hot):
+        keys[at[j * per:(j + 1) * per]] = new[j]
+    keys[at[hot * per:hot * per + cold]] = new[hot:]
+    valid = rng.random(N) < valid_share
+    dvalid = None if valid.all() else jnp.asarray(valid)
+    plain = lookup_or_insert(jnp.asarray(table), jnp.asarray(keys), dvalid,
+                             stats=True)
+    *elect, elected = lookup_or_insert(
+        jnp.asarray(table), jnp.asarray(keys), dvalid, stats=True,
+        handover=True)
+    for a, b in zip(plain, elect, strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert int(plain[3][2]) == 1                # a wide batch: it elected
+    must, at_most = _elected_by_the_dict(table, keys, valid)
+    assert np.asarray(elected).tolist() == [must, 1]
+    assert must <= at_most
+    if case == "one_new_key":
+        assert must == at_most == int(valid.sum()) - 1
+    if case == "no_duplicates":
+        assert must == at_most == 0
+    if hot > 1:
+        # 4 cells a lane: a hot id loses its cell to an earlier key now
+        # and then, most keep it
+        assert must >= at_most // 2
+    # a batch whose tail fits a narrow loop elects nothing, and says so
+    out = lookup_or_insert(jnp.asarray(table),
+                           jnp.asarray(rng.choice(resident, N)),
+                           stats=True, handover=True)
+    assert np.asarray(out[3])[2] == 0 and np.asarray(out[4]).tolist() == [0, 0]
+    # keys a caller promises distinct: the program without an election
+    assert len(lookup_or_insert(
+        jnp.asarray(table), jnp.asarray(np.resize(new, N)),
+        jnp.arange(N) < len(new), stats=True, handover=True,
+        distinct=True)) == 4
+
+
 def test_backend_picks_the_handover_program_while_batches_start_wide():
     """The backend reads the probe's counters a batch or two late and
     picks the next batch's program by them: new keys batch after batch
@@ -310,3 +402,32 @@ def test_backend_picks_the_handover_program_while_batches_start_wide():
     # a small batch runs at full width whatever is picked
     be.slots_for_batch_device(jnp.asarray(keys[:64] + 1))
     be.note_probe_stats(block=True)
+    # wide batches whose keys repeat (half of every batch on eight ids
+    # that are new with it: NEXmark's hot auction of the moment): the
+    # pick holds from the second batch on, for the counters it reads are
+    # of the rows BEFORE the election, and every batch after the first
+    # sent one lane a key through the rounds
+    from flink_tpu.metrics import DEVICE_STATS
+
+    be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=CAP,
+                              defer_overflow=True)
+    start = DEVICE_STATS.snapshot()
+    more = rng.choice(1 << 40, 5 * N, replace=False).astype(np.int64) \
+        + (1 << 41)
+    picked = []
+    for i in range(5):
+        fresh = more[i * N:(i + 1) * N]
+        batch = np.where(rng.random(N) < 0.5,
+                         fresh[rng.integers(0, 8, N)], fresh)
+        slots = be.slots_for_batch_device(jnp.asarray(batch))
+        np.testing.assert_array_equal(
+            np.asarray(slots),
+            np.asarray(lookup(be.table, jnp.asarray(batch))))
+        be.note_probe_stats(block=True)
+        picked.append(be._probe_wide)
+    assert picked == [True] * 5
+    now = DEVICE_STATS.snapshot()
+    moved = {k: now[k] - start[k] for k in now if k.startswith("probe_")}
+    assert moved["probe_wide_batches_total"] == 5
+    assert moved["probe_elected_batches_total"] == 4
+    assert 4 * 0.4 * N < moved["probe_elected_rows_total"] < 4 * 0.5 * N

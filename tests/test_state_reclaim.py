@@ -22,7 +22,8 @@ from benchmarks.harness.spec import BENCH_DIR, REPO_ROOT, load_spec
 from flink_tpu.core.keygroups import KeyGroupRange
 from flink_tpu.metrics import DEVICE_STATS
 from flink_tpu.metrics.tracing import TRACER
-from flink_tpu.ops.hash_table import EMPTY_KEY, lookup
+from flink_tpu.ops.hash_table import EMPTY_KEY, compacts, lookup, \
+    lookup_or_insert
 from flink_tpu.ops.segment_ops import AGG_INITS
 from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
 
@@ -271,7 +272,11 @@ def test_a_growth_builds_the_reclaim_of_the_grown_planes():
     assert DEVICE_STATS.snapshot()["compiles"] == before
 
 
-def test_a_job_builds_nothing_once_its_first_windows_have_fired():
+@pytest.mark.parametrize("capacity, per_pane, copies", [
+    (1 << 10, 250, 1), (1 << 14, 4000, 2)],
+    ids=["narrow_batches", "wide_batches_with_duplicates"])
+def test_a_job_builds_nothing_once_its_first_windows_have_fired(
+        capacity, per_pane, copies):
     """ROADMAP D14's guard, in the idiom of `tests/test_mesh_reclaim.py::
     test_nothing_is_compiled_once_the_first_reclaim_has_been_prepared`:
     the operator builds the reclaim of its planes before its first input,
@@ -280,7 +285,16 @@ def test_a_job_builds_nothing_once_its_first_windows_have_fired():
     reading is already past the load limit, reclaims without a single
     compile. Until PR 42 that reclaim compiled where it ran: on an empty
     compile cache at 2^24 slots a 20 s build, ending in q7-10m-saturated's
-    timed phase and voiding the run (PR 39's verdict in the ledger)."""
+    timed phase and voiding the run (PR 39's verdict in the ledger).
+
+    With batches wide enough to compact whose every key comes twice
+    (PR 46): the backend picks its wide-batch program from the second
+    batch's counters and keeps it (every batch is all new keys: no
+    flipping back to the plain program, which would build nothing new
+    but pay the full-width rounds), that program is built beside the
+    plain one at the new table's first batch and nothing after the third,
+    the reclaim included, and one lane a key went through the rounds: the
+    other stood behind it."""
     from jax._src import monitoring
 
     from flink_tpu.core import Schema
@@ -295,16 +309,17 @@ def test_a_job_builds_nothing_once_its_first_windows_have_fired():
         TumblingEventTimeWindows.of(1000), "k",
         [AggSpec("count", out_name="n", value_bits=31),
          AggSpec("sum", "v", out_name="s")],
-        capacity=1 << 10, ring_size=11, defer_overflow=True)
+        capacity=capacity, ring_size=11, defer_overflow=True)
     h = OneInputOperatorTestHarness(op, schema)
     readings = []
     apply_health = TpuKeyedStateBackend.apply_health
 
     def feed(pane: int) -> None:
-        keys = np.arange(250 * pane, 250 * (pane + 1), dtype=np.int64)
+        keys = np.tile(np.arange(per_pane * pane, per_pane * (pane + 1),
+                                 dtype=np.int64), copies)
         h.process_batch(RecordBatch(
             schema, {"k": keys, "v": keys + (1 << 33)},
-            np.full(250, 1000 * pane + 5, np.int64)))
+            np.full(len(keys), 1000 * pane + 5, np.int64)))
 
     def spy(self, dropped, occupancy, *a, **kw):
         readings.append(int(occupancy))
@@ -316,33 +331,67 @@ def test_a_job_builds_nothing_once_its_first_windows_have_fired():
         if event == "/jax/core/compile/backend_compile_duration":
             builds.append(event)
 
-    sweeps = DEVICE_STATS.snapshot()["state_reclaim_sweeps_total"]
+    wide = compacts(per_pane * copies)
+    before = DEVICE_STATS.snapshot()
     TpuKeyedStateBackend.apply_health = spy
     try:
         feed(0)
-        program = op._backend._reclaim_call()[0]
+        be = op._backend
+        program = be._reclaim_call()[0]
         assert program._compiled             # built before the first input
         feed(1)
         h.process_watermark(1999)            # two windows, back to back
-        assert readings == [500, 500]
+        assert readings == [2 * per_pane, 2 * per_pane]
+        be.note_probe_stats(block=True)
+        assert be._probe_wide                # all new keys, batch on batch
+        if wide:
+            # built beside the plain program, at the first batch of a new
+            # table: the third batch, the first to run it, traces nothing
+            assert be._wide_probe is not None
+            traced = lookup_or_insert._cache_size()
+            feed(2)
+            assert be._probe_audited == {False, True}
+            assert lookup_or_insert._cache_size() == traced
         monitoring.register_event_duration_secs_listener(on_duration)
         try:
-            feed(2)
-            h.process_watermark(2999)        # reads 750 of 1024: reclaims
-            feed(3)
-            h.process_watermark(3999)
+            if not wide:
+                feed(2)
+            # reads three quarters of the table: reclaims
+            h.process_watermark(2999)
+            for pane in (3, 4):
+                feed(pane)
+                be.note_probe_stats(block=True)
+                assert be._probe_wide
+                h.process_watermark(1000 * pane + 999)
         finally:
             monitoring.unregister_event_duration_listener(on_duration)
     finally:
         TpuKeyedStateBackend.apply_health = apply_health
-    assert readings[2] == 750 and op._backend.capacity == 1 << 10
+    assert readings[2] == 3 * per_pane > 0.6 * capacity
+    assert be.capacity == capacity
+    # (a batch too narrow to compact has one program whatever is picked)
+    assert be._probe_audited == ({False, True} if wide else {False})
     h.process_watermark(1 << 40)
     h.close()
-    assert DEVICE_STATS.snapshot()["state_reclaim_sweeps_total"] > sweeps
+    moved = {k: v - before[k] for k, v in DEVICE_STATS.snapshot().items()
+             if k.startswith(("probe_", "state_reclaim_sweeps"))}
+    assert moved["state_reclaim_sweeps_total"] > 0
     assert builds == []
+    assert moved["probe_rows_total"] == 5 * per_pane * copies
+    assert moved["probe_wide_batches_total"] == 5
+    # batches 3 to 5 elected, and every key's second row stood behind
+    # its first (4 cells a lane: a key loses its cell to another's now
+    # and then, and both its rows then go through the rounds)
+    assert moved["probe_elected_batches_total"] == (3 if wide else 0)
+    if wide:
+        assert 0.8 * 3 * per_pane \
+            < moved["probe_elected_rows_total"] <= 3 * per_pane
+    else:
+        assert moved["probe_elected_rows_total"] == 0
     rows = sorted((int(k), int(n), int(s))
                   for k, _start, _end, n, s in h.get_output())
-    assert rows == [(k, 1, k + (1 << 33)) for k in range(1000)]
+    assert rows == [(k, copies, copies * (k + (1 << 33)))
+                    for k in range(5 * per_pane)]
 
 
 def test_a_reading_of_a_table_rebuilt_since_is_passed_over():

@@ -529,11 +529,48 @@ def _hash_probe(devices):
         stats=True).compile())
 
 
+def _electing_probe(devices):
+    """The wide-batch program of `q5-inflight-saturated`: [2^23] slots x
+    [2^18] rows whose keys may repeat (`handover=True`), with both
+    counter vectors."""
+    from flink_tpu.ops.hash_table import lookup_or_insert
+
+    one = SingleDeviceSharding(devices[0])
+    return _compiled("probe.elect", lambda: lookup_or_insert.lower(
+        jax.ShapeDtypeStruct((1 << 23,), jnp.int64, sharding=one),
+        jax.ShapeDtypeStruct((1 << 18,), jnp.int64, sharding=one),
+        stats=True, handover=True).compile())
+
+
 def test_hash_probe_compiles_at_the_benchmark_shape(v5e_devices):
     """[2^24] slots x [2^18] rows, with the counters: the first window, the
     compaction (a sort), both narrow loops and the wide one under one
     `lax.switch`, for the v5e's compiler."""
     _assert_probe_is_countable(_hash_probe(v5e_devices).as_text())
+
+
+def test_electing_probe_compiles_at_the_benchmark_shape(v5e_devices):
+    """The program every batch of the in-flight cell runs (PR 46): window
+    0, the two narrow loops, and the election with its three loops and
+    the full-width one. Its rounds stay countable, the election's own
+    `scatter-min` lies outside every loop, and the v5e's compiler keeps
+    BOTH 32-bit halves of the 2^23-slot table in its fast memory space
+    for window 0's gathers, as it does for the program without an
+    election (a form of the election that chained its loops through
+    `lax.cond`s lost one half: PERF.md section 6, PR 46)."""
+    import re
+
+    hlo = _electing_probe(v5e_devices).as_text()
+    _assert_probe_is_countable(hlo)
+    elects = [line for line in hlo.splitlines()
+              if re.search(r'op_name="[^"]*probe\.elect/scatter-min"', line)]
+    assert elects and not any(re.search(_CLAIM_PATH, line)
+                              for line in elects)
+    halves = [line for line in hlo.splitlines()
+              if re.search(r'custom_call_target="X64Split(Low|High)"', line)
+              and "u32[8388608]" in line.split(" custom-call(")[0]]
+    assert len(halves) == 2 and all("S(1)" in line.split(" custom-call(")[0]
+                                    for line in halves), halves
 
 
 @pytest.mark.parametrize("rows", [64, 1 << 12])
@@ -546,6 +583,29 @@ def test_hash_probe_claim_stays_countable_on_any_backend(rows):
     compiled = lookup_or_insert.lower(
         make_table(1 << 14), jnp.zeros(rows, jnp.int64)).compile()
     _assert_probe_is_countable(compiled.as_text())
+
+
+def test_the_elections_scatter_is_not_counted_as_a_round():
+    """The electing program has one `scatter-min` more than its loops
+    hold: the election's, under `probe.elect` and in no `while` body, so
+    the pattern `probe_rounds_p50` counts rounds by passes over it."""
+    import re
+
+    from flink_tpu.ops.hash_table import lookup_or_insert, make_table
+
+    lowered = lookup_or_insert.lower(
+        make_table(1 << 14), jnp.zeros(1 << 12, jnp.int64), stats=True,
+        handover=True)
+    hlo = lowered.compile().as_text()
+    _assert_probe_is_countable(hlo)
+    # (the x64 rewriter leaves the bare primitive on a 64-bit scatter's
+    # halves: no path, no match)
+    names = set(re.findall(r'op_name="([^"]*/scatter-min)"', hlo))
+    elect = [n for n in names if "/probe.elect/" in n]
+    assert elect and all("/probe.tail/" in n for n in elect)
+    assert not any(re.search(_CLAIM_PATH, f'op_name="{n}"') for n in elect)
+    rounds = [n for n in names if re.search(_CLAIM_PATH, f'op_name="{n}"')]
+    assert rounds and len(rounds) + len(elect) == len(names)
 
 
 # ---------------------------------------------------------------------------
@@ -603,6 +663,7 @@ def _region_programs(devices) -> dict:
     cap = 1 << 24
     return {
         "jit_lookup_or_insert": _hash_probe(devices),
+        "jit_lookup_or_insert.elect": _electing_probe(devices),
         "jit_fold.q5": _host_born_fold(devices, "q5"),
         "jit_fold.q7": _host_born_fold(devices, "q7"),
         "jit_fire_fn.q5": _one_chip_fire(
@@ -633,6 +694,7 @@ _MESH_TABLE_PROGRAMS = {"jit_step", "jit_fire", "jit_reclaim.mesh"}
 #: its int64 arguments
 _PROGRAM_REGIONS = {
     "jit_lookup_or_insert": {"probe.window0", "probe.tail"},
+    "jit_lookup_or_insert.elect": {"probe.window0", "probe.tail"},
     "jit_fold.q5": {"fold.row", "fold.count", "fold.sum"},
     "jit_fold.q7": {"fold.row", "fold.count", "fold.max"},
     "jit_fire_fn.q5": {"fire.merge", "fire.topk"},
@@ -683,7 +745,8 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
             kind = "x64.split" if m.group(2) == "Split" else "x64.join"
             assert regions[m.group(1)] == kind, line[:200]
             x64[kind] += 1
-    if program in _MESH_TABLE_PROGRAMS or program == "jit_lookup_or_insert":
+    if program in _MESH_TABLE_PROGRAMS \
+            or program.startswith("jit_lookup_or_insert"):
         # the table's int64 keys are everywhere (ROADMAP S1)
         assert x64["x64.split"] >= 2 and x64["x64.join"] >= 1, x64
     if program == "jit_retire":
@@ -969,6 +1032,102 @@ def test_the_one_chip_programs_lower_to_what_they_lowered_to(v5e_devices,
                     lambda: _one_chip_digest_programs(v5e_devices))
     assert set(got) == set(_ONE_CHIP_DIGESTS_AT_4081619)
     assert got[program] == _ONE_CHIP_DIGESTS_AT_4081619[program]
+
+
+#: sha256 of the StableHLO of the hash probe AS ITS CALLERS OUTSIDE THE
+#: ONE-CHIP BACKEND'S WIDE BATCHES GET IT, at the parent of PR 46 (commit
+#: f6f8d19), which gave the backend's wide batches a program that elects
+#: one lane a distinct key and must not touch the others by a letter: the
+#: plain program with its counters (X, S, U, Q past their prefill), as the
+#: mesh step calls it (a valid mask, no counters) and below the compaction
+#: width; the hand-over program for keys that cannot repeat (`distinct`:
+#: what `handover=True` was), alone, inside a reclaim whose re-homing
+#: chunk compacts (2^12 slots: the digests above are of 2^10) and inside
+#: the session step.
+_PROBE_DIGESTS_AT_F6F8D19 = {
+    "jit_lookup_or_insert.plain":
+        "6a43d1f0b91f2268da762692b29da611ec863e75069336f43185bb5598edba79",
+    "jit_lookup_or_insert.plain.mesh":
+        "7abaaff8ffee251f7b416ffd3e8af05fd82bf7d3e76e746fe36e1c4f407bd742",
+    "jit_lookup_or_insert.plain.small":
+        "9f5be740fbf7cf03c85023870abe12c6a336995a522b7465b8602c282143f584",
+    "jit_lookup_or_insert.distinct":
+        "0e0737e37b578f637a9c9150016803f942c6c539b647033ac16139d8f6c81e83",
+    "jit_reclaim.q5.handover":
+        "d945af33957392bcbaf1a871c77ab31c15edc580f2b5b5e8fd927d2844949d8a",
+    "jit_step.session":
+        "844a7be154d0c4523b3bc1b3760e7ec985db163276454b19a2daa4d8f6ee92d9",
+}
+
+
+def _probe_digest_programs(devices) -> dict:
+    """name -> sha256 of the StableHLO of the probe's unelecting forms:
+    2^14 slots x 2^12 rows (the smallest batch that compacts) and 256
+    rows; the Q5 reclaim at 2^12 slots; the session step at 2^13 slots,
+    2 lanes, 2^12 rows."""
+    import hashlib
+
+    from flink_tpu.ops.hash_table import lookup_or_insert
+    from flink_tpu.runtime.operators.device_session import _sess_step
+    from flink_tpu.state.tpu_backend import _reclaim_program
+
+    one = SingleDeviceSharding(devices[0])
+    cap, rows = 1 << 14, 1 << 12
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def fn(program):
+        return getattr(program, "_fn", program)
+
+    table, keys = spec((cap,), jnp.int64), spec((rows,), jnp.int64)
+    valid = spec((rows,), jnp.bool_)
+    lowered = {
+        "jit_lookup_or_insert.plain":
+            lookup_or_insert.lower(table, keys, stats=True),
+        "jit_lookup_or_insert.plain.mesh":
+            lookup_or_insert.lower(table, keys, valid),
+        "jit_lookup_or_insert.plain.small":
+            lookup_or_insert.lower(table, spec((256,), jnp.int64),
+                                   stats=True),
+        "jit_lookup_or_insert.distinct":
+            lookup_or_insert.lower(table, keys, valid, handover=True,
+                                   distinct=True),
+    }
+    sig = tuple((kind, dt, (ring, rows))
+                for kind, dt, (ring, _c) in _FOLD_SIGS["q5"])
+    lowered["jit_reclaim.q5.handover"] = fn(
+        _reclaim_program(sig, (0, 1))).lower(
+        spec((rows,), jnp.int64),
+        tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
+        spec((), jnp.int64))
+    L, scap, block = 2, 1 << 13, 512
+    planes = {name: _plane_spec(dt, (L, scap), one) for name, dt in (
+        ("__start__", "halves:int64"), ("__end__", "halves:int64"),
+        ("__open__", "int8"), ("__count__", "halves:int64"))}
+    scalar = spec((), jnp.int64)
+    lowered["jit_step.session"] = fn(_sess_step((), L, 10_000, block)).lower(
+        spec((scap,), jnp.int64), planes, spec((scap,), jnp.int32), scalar,
+        scalar, spec((3,), jnp.int64), spec((scap // block,), jnp.bool_),
+        keys, keys, {}, scalar, scalar)
+    return {name: hashlib.sha256(low.as_text().encode()).hexdigest()
+            for name, low in lowered.items()}
+
+
+@pytest.mark.parametrize("program", list(_PROBE_DIGESTS_AT_F6F8D19))
+def test_the_unelecting_probe_lowers_to_what_it_lowered_to(v5e_devices,
+                                                           program):
+    """PR 46 changes ONE program, the one-chip backend's wide-batch probe.
+    The plain program (X, S, U, Q; the mesh step of M and F), and the
+    hand-over program of callers whose keys cannot repeat (the reclaim's
+    re-homing on both stacks, the session step of K) lower, letter for
+    letter, to the text they lowered to at the parent commit: which fast
+    memory the v5e's compiler gives the table's halves turns on how the
+    rest of a probe program is written (ROADMAP D13)."""
+    got = _compiled("probe.digests",
+                    lambda: _probe_digest_programs(v5e_devices))
+    assert set(got) == set(_PROBE_DIGESTS_AT_F6F8D19)
+    assert got[program] == _PROBE_DIGESTS_AT_F6F8D19[program]
 
 
 # ---------------------------------------------------------------------------
