@@ -5,9 +5,8 @@ PERF.md, PERF_LEDGER.jsonl), and the correctness check on the chip is
 ``chip_smoke.py``. Every mode runs on whatever backend JAX has and prints the
 device first; a wall time or a rate in a report from the CPU is not a speed.
 
-  --tiny [--fire-mode full|incremental] [--window-panes N[,N...]] [--audit]
-                 the tiny Q5 stage report: compiles, recompiles (0 after the
-                 warmup), transfer bytes, fire / coalesce / tier counters
+  --tiny [--audit]   the tiny Q5 stage report: compiles, recompiles (0 after
+                 the warmup), transfer bytes, coalesce / tier counters
   --audit        --tiny with the tpu-lint jaxpr and certificate audit
   --fused [--audit]   the same pipeline unfused and fused: recompiles and
                  chain dispatches per micro-batch
@@ -49,6 +48,7 @@ def _start() -> None:
 
 
 RING = 16
+WINDOW_PANES = 5            # Q5's HOP 10 s / 2 s
 MULT = 0x9E3779B97F4A7C15   # odd 64-bit mixer: idx -> pseudo-uniform key
 
 
@@ -94,20 +94,8 @@ def _n_panes(n_events: int, batch: int, max_panes: int) -> int:
     window's W-1-pane tail fits inside the ring-slot accumulator ring
     with headroom: worst-case open span = n_panes + W - 1 must stay
     <= ring - 3 even if fire retirement lags ingest completely, so the
-    caller passes ring - W - 2 for its ``_ring_for()`` ring."""
+    caller passes RING - W - 2."""
     return max(4, min(max_panes, n_events // batch))
-
-
-def _ring_for(window_panes: int) -> int:
-    """Ring size for a given window width: the default RING covers the
-    default W=5; wider windows (--window-panes sweep) grow the ring to
-    2W + 6 so W + 4 data panes fit under the open-span bound
-    (n_panes + W - 1 <= ring - 3) — a fire near the end of the stream
-    genuinely merges W live rows instead of being starved. Depends ONLY
-    on the width (never the event count) so a short warmup run compiles
-    the same shapes as the timed run; at W=5 this is byte-identical to
-    the seed RING."""
-    return max(RING, 2 * window_panes + 6)
 
 
 def _collect_stages(env) -> dict:
@@ -144,9 +132,8 @@ def _collect_metrics(env, before: dict) -> dict:
     out["recompiles"] = snap["compiles"] - before.get("compiles", 0)
     # degradation-ladder + stall counters (deltas for this run): nonzero
     # only under injection or a genuinely failing/hanging device path
-    # incremental fire engine + coalesced ingest counters (deltas)
-    for k in ("panes_sealed_total", "batches_coalesced_total",
-              "fire_merge_rows_read", "chain_fused_dispatches_total"):
+    # coalesced ingest + fused chain counters (deltas)
+    for k in ("batches_coalesced_total", "chain_fused_dispatches_total"):
         out[k] = snap.get(k, 0) - before.get(k, 0)
     # tiered-state counters: eviction/prefetch deltas for this run plus
     # the hit-ratio and HBM-footprint gauges (point-in-time readings)
@@ -235,7 +222,6 @@ def _device_time_block(before: dict) -> dict:
 
 def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
                   metrics_registry=None, extra_config: dict = None,
-                  fire_mode: str = "full", window_panes: int = 5,
                   job_name: str = "nexmark-q5"):
     """One env.execute() of the tiny Q5 pipeline (device-born batches,
     2 s panes, top 1000 by bid count, 2^14 slots); returns (wall_seconds,
@@ -253,9 +239,8 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
 
     schema = Schema([("auction", np.int64), ("price", np.int64),
                      ("ts", np.int64)])
-    ring = _ring_for(window_panes)
     pane_ms = 2000
-    n_panes = _n_panes(n_events, batch, max_panes=ring - window_panes - 2)
+    n_panes = _n_panes(n_events, batch, max_panes=RING - WINDOW_PANES - 2)
     span = n_panes * pane_ms
 
     def gen(idx):
@@ -272,7 +257,6 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
     env = StreamExecutionEnvironment.get_execution_environment()
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, batch)
-    env.config.set("window.fire.incremental", fire_mode == "incremental")
     # device-time ledger on by default so every stage report carries its
     # device_time block; extra_config may still override it off (the
     # overhead A/B measures exactly that)
@@ -285,7 +269,7 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
     (env.datagen(gen, schema, count=n_events, timestamp_column="ts",
                  watermark_strategy=ws, device=True)
         .key_by("auction")
-        .window(SlidingEventTimeWindows.of(window_panes * pane_ms,
+        .window(SlidingEventTimeWindows.of(WINDOW_PANES * pane_ms,
                                            pane_ms))
         # rank hot items by bid COUNT (value_bits=31: exact to 2.1e9 events/key/window, and
         # <= 31 selects the int32 count plane + uint32 radix select) and
@@ -293,7 +277,7 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
         .device_aggregate([AggSpec("count", out_name="bids",
                                    value_bits=31),
                            AggSpec("sum", "price", out_name="revenue")],
-                          capacity=1 << 14, ring_size=ring,
+                          capacity=1 << 14, ring_size=RING,
                           emit_window_bounds=False, emit_topk=1000,
                           defer_overflow=True, async_fire=True)
         .add_sink(sink.fn, "count"))
@@ -306,8 +290,6 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
     stages = _collect_stages(env)
     stages.update(_collect_metrics(env, stats_before))
     stages["device_time"] = _device_time_block(led_before)
-    stages["fire_mode"] = fire_mode
-    stages["window_panes"] = window_panes
     stages["max_inflight"] = max((o._max_inflight for o in ops), default=0)
     return wall, lat, sink.rows, stages
 
@@ -315,7 +297,6 @@ def _tiny_q5_pass(n_keys: int, n_events: int, batch: int,
 def run_tiny_q5(n_keys: int = 1000, batch: int = 1 << 12,
                 n_batches: int = 8, metrics_registry=None,
                 chaos_seed=None, extra_config: dict = None,
-                fire_mode: str = "full", window_panes: int = 5,
                 job_name: str = "nexmark-q5") -> dict:
     """Tiny Q5 acceptance probe (tier-1 safe): warmup + timed run on
     whatever backend jax already has;
@@ -354,12 +335,10 @@ def run_tiny_q5(n_keys: int = 1000, batch: int = 1 << 12,
         ISOLATION.reset()  # per-job shed/reject counters start at zero
     _tiny_q5_pass(n_keys, 4 * batch, batch,
                   metrics_registry=metrics_registry, extra_config=warm_extra,
-                  fire_mode=fire_mode, window_panes=window_panes,
                   job_name=job_name)                        # compile warmup
     wall, lat, rows, stages = _tiny_q5_pass(
         n_keys, n_events, batch, metrics_registry=metrics_registry,
-        extra_config=extra, fire_mode=fire_mode, window_panes=window_panes,
-        job_name=job_name)
+        extra_config=extra, job_name=job_name)
     stages["wall"] = wall
     stages["events_per_sec"] = round(n_events / wall, 2)
     stages["p99_fire_latency_ms"] = round(_p99(lat), 3)
@@ -528,26 +507,19 @@ def _audit_report() -> dict:
     return report
 
 
-def tiny(fire_mode: str = "full", window_panes_list=(5,),
-         audit: bool = False) -> None:
-    """`python bench.py --tiny [--fire-mode full|incremental]
-    [--window-panes N[,N...]] [--audit]`: the acceptance probe — one
-    JSON line per window width, the tiny Q5 stage report with the
-    metrics snapshot embedded. Passing several widths sweeps them
-    (seal/fire programs are shared across widths, so only the first
-    width compiles). ``--audit`` runs the tpu-lint Tier-B jaxpr audit
-    over the programs the run compiled and embeds per-rule finding
-    counts."""
+def tiny(audit: bool = False) -> None:
+    """`python bench.py --tiny [--audit]`: the acceptance probe — one
+    JSON line, the tiny Q5 stage report with the metrics snapshot
+    embedded. ``--audit`` runs the tpu-lint Tier-B jaxpr audit over the
+    programs the run compiled and embeds per-rule finding counts."""
     _start()
-    for wp in window_panes_list:
-        stages = run_tiny_q5(extra_config=_trace_extra_config(),
-                             fire_mode=fire_mode, window_panes=wp)
-        rec = {"metric": "nexmark_q5_tiny_stage_report", "unit": "report"}
-        rec.update({k: (round(v, 3) if isinstance(v, float) else v)
-                    for k, v in stages.items()})
-        if audit:
-            rec.update(_audit_report())
-        print(json.dumps(rec))
+    stages = run_tiny_q5(extra_config=_trace_extra_config())
+    rec = {"metric": "nexmark_q5_tiny_stage_report", "unit": "report"}
+    rec.update({k: (round(v, 3) if isinstance(v, float) else v)
+                for k, v in stages.items()})
+    if audit:
+        rec.update(_audit_report())
+    print(json.dumps(rec))
     _maybe_write_trace("tiny_q5")
     _maybe_write_profile("tiny_q5")
     sys.stdout.flush()
@@ -747,26 +719,13 @@ if __name__ == "__main__":
                           if (len(sys.argv) > i + 1
                               and not sys.argv[i + 1].startswith("--"))
                           else "bench")
-    _fire_mode = "full"
-    if "--fire-mode" in sys.argv:
-        i = sys.argv.index("--fire-mode")
-        _fire_mode = sys.argv[i + 1]
-        if _fire_mode not in ("full", "incremental"):
-            raise SystemExit(f"--fire-mode must be full|incremental, "
-                             f"got {_fire_mode!r}")
-    _window_panes = (5,)
-    if "--window-panes" in sys.argv:
-        i = sys.argv.index("--window-panes")
-        _window_panes = tuple(int(w) for w in sys.argv[i + 1].split(","))
     if "--tiny" in sys.argv:
-        tiny(fire_mode=_fire_mode, window_panes_list=_window_panes,
-             audit="--audit" in sys.argv)
+        tiny(audit="--audit" in sys.argv)
     elif "--fused" in sys.argv:
         fused()
     elif "--audit" in sys.argv:
         # audit alone: the tiny acceptance probe with the jaxpr audit on
-        tiny(fire_mode=_fire_mode, window_panes_list=_window_panes,
-             audit=True)
+        tiny(audit=True)
     elif "--chaos" in sys.argv:
         i = sys.argv.index("--chaos")
         chaos(int(sys.argv[i + 1]) if len(sys.argv) > i + 1 else 0)

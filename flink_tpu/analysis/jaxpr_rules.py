@@ -486,11 +486,8 @@ def chain_cache_key_rule(ctx: AnalysisContext) -> List[Finding]:
 
 
 def exercise_programs(n_events: int = 4096, batch: int = 1024,
-                      capacity: int = 2048,
-                      fire_modes: Tuple[str, ...] = ("full",
-                                                     "incremental"),
-                      ) -> List[str]:
-    """Run a tiny Q5 sliding-window job (per fire mode) so every
+                      capacity: int = 2048) -> List[str]:
+    """Run a tiny Q5 sliding-window job (per ingest path) so every
     window-path builder registers its compiled programs in
     PROGRAM_AUDIT; returns the registered scopes.  Mirrors bench.py
     _run_q5 at toy scale — same operators, same program builders.
@@ -532,20 +529,17 @@ def exercise_programs(n_events: int = 4096, batch: int = 1024,
             def invoke_batch(self, batch):
                 return True
 
-        # (fire_mode, device_ingest, fused): device ingest exercises the
+        # (device_ingest, fused): device ingest exercises the
         # one-dispatch step program, host ingest the packed upload with
         # its probe and fold programs, and the fused run registers the
         # certified chain programs
         # (chain.fused_prelude / chain.fused_step) for JX601-603.
-        runs = ([(m, True, False) for m in fire_modes]
-                + [(fire_modes[0], False, False), (fire_modes[0], True, True)])
-        for fire_mode, device_ingest, fused in runs:
+        for device_ingest, fused in ((True, False), (False, False),
+                                     (True, True)):
             env = StreamExecutionEnvironment.get_execution_environment()
             env.set_state_backend("tpu")
             env.config.set(PipelineOptions.BATCH_SIZE, batch)
             env.config.set(PipelineOptions.FUSION, fused)
-            env.config.set("window.fire.incremental",
-                           fire_mode == "incremental")
             ws = WatermarkStrategy.for_monotonous_timestamps() \
                 .with_timestamp_column("ts")
             (env.datagen(gen, schema, count=n_events, timestamp_column="ts",
@@ -558,7 +552,7 @@ def exercise_programs(n_events: int = 4096, batch: int = 1024,
                     capacity=capacity, ring_size=16, emit_window_bounds=False,
                     emit_topk=32, defer_overflow=True)
                 .add_sink(_DiscardSink(), "audit-sink"))
-            env.execute(f"tpu-lint-audit-{fire_mode}", timeout=600.0)
+            env.execute("tpu-lint-audit", timeout=600.0)
 
         # the session operator's two programs (device_session.step /
         # device_session.fire): the same bids keyed into per-auction

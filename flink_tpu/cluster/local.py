@@ -328,8 +328,7 @@ def live_rescale(job: "LocalJob", n_devices: int,
     folded and every in-flight fire drained, so the barrier that makes
     the checkpoint consistent is the same event that makes the worker-set
     switch consistent. State moves via the checkpoint page format
-    (digest-verified; see parallel/rescale.py); derived window planes are
-    rebuilt on the new mesh, not shipped. Returns the merged migration
+    (digest-verified; see parallel/rescale.py). Returns the merged migration
     stats ({keygroups_migrated, bytes_moved, epoch, ...} summed/maxed
     over operators).
     """

@@ -427,22 +427,6 @@ class MeshOptions:
                   "old mesh keeps running.")
 
 
-class WindowOptions:
-    """Window-operator execution strategy (reference table-runtime
-    SliceSharedWindowAggProcessor knobs)."""
-
-    FIRE_INCREMENTAL = key("window.fire.incremental").bool_type() \
-        .default_value(
-            False, "Incremental sliding-window fires: keep a running "
-                   "window accumulator per invertible aggregate "
-                   "(sum/count/avg) and a binary merge tree over ring "
-                   "pane rows for min/max, updated once per pane seal, "
-                   "so each fire reads O(capacity) rows independent of "
-                   "the window width instead of re-merging all W panes. "
-                   "Emitted rows are byte-identical to the full merge "
-                   "for integer aggregates (see docs/PERFORMANCE.md).")
-
-
 class TaskOptions:
     """Per-task dispatch pipelining and ingest batching knobs."""
 

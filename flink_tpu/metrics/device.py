@@ -75,14 +75,9 @@ class DeviceStats:
         # tracing accounting (PR 7): spans evicted from the bounded
         # in-memory trace reporter (traces.max-retained)
         self._spans_dropped = 0
-        # incremental-fire / coalesced-ingest accounting (PR 8): panes
-        # folded into the running window accumulators (seals count 1,
-        # rebuilds count every live pane), upstream micro-batches merged
-        # into coalesced dispatches, and pane rows read per window fire
-        # (the O(W) vs O(1) distinction made measurable)
-        self._panes_sealed = 0
+        # coalesced-ingest accounting (PR 8): upstream micro-batches
+        # merged into coalesced dispatches
         self._batches_coalesced = 0
-        self._fire_merge_rows = 0
         # drain accounting (PR 25): non-blocking drains that found the
         # oldest queued fire's device->host copy not landed yet
         self._fire_unready_polls = 0
@@ -383,18 +378,10 @@ class DeviceStats:
             self._takeover_ms.append(float(took_ms))
             del self._takeover_ms[:-256]
 
-    # -- incremental-fire / coalescing accounting ----------------------------
-    def note_panes_sealed(self, n: int = 1) -> None:
-        with self._lock:
-            self._panes_sealed += int(n)
-
+    # -- coalescing accounting -----------------------------------------------
     def note_batches_coalesced(self, n: int) -> None:
         with self._lock:
             self._batches_coalesced += int(n)
-
-    def note_fire_merge_rows(self, n: int) -> None:
-        with self._lock:
-            self._fire_merge_rows += int(n)
 
     def note_fire_unready_poll(self) -> None:
         with self._lock:
@@ -544,19 +531,9 @@ class DeviceStats:
             return self._chain_dispatches
 
     @property
-    def panes_sealed(self) -> int:
-        with self._lock:
-            return self._panes_sealed
-
-    @property
     def batches_coalesced(self) -> int:
         with self._lock:
             return self._batches_coalesced
-
-    @property
-    def fire_merge_rows(self) -> int:
-        with self._lock:
-            return self._fire_merge_rows
 
     # -- live-rescale accounting ---------------------------------------------
     def note_rescale(self, keygroups_migrated: int, bytes_moved: int,
@@ -785,9 +762,7 @@ class DeviceStats:
                 "coordinator_failovers_total":
                     sum(self._failovers.values()),
                 "spans_dropped_total": self._spans_dropped,
-                "panes_sealed_total": self._panes_sealed,
                 "batches_coalesced_total": self._batches_coalesced,
-                "fire_merge_rows_read": self._fire_merge_rows,
                 "fire_unready_polls_total": self._fire_unready_polls,
                 "fires_drained_total": self._fires_drained,
                 "fires_drained_timer_total": self._fires_drained_timer,
@@ -907,9 +882,7 @@ class DeviceStats:
             self._cold_start_ms.clear()
             self._cold_start_t0 = None
             self._spans_dropped = 0
-            self._panes_sealed = 0
             self._batches_coalesced = 0
-            self._fire_merge_rows = 0
             self._fire_unready_polls = 0
             self._fires_drained = self._fires_drained_timer = 0
             self._probe_rows = self._probe_tail_rows = 0
@@ -1702,13 +1675,9 @@ def bind_device_metrics(registry) -> None:
     g.gauge("compile_storms_total", lambda: s.compile_storms)
     # tracing (prometheus: flink_tpu_device_spans_dropped_total)
     g.gauge("spans_dropped_total", lambda: s.spans_dropped)
-    # incremental fire engine / coalesced ingest (prometheus:
-    # flink_tpu_device_panes_sealed_total /
-    # flink_tpu_device_batches_coalesced_total /
-    # flink_tpu_device_fire_merge_rows_read)
-    g.gauge("panes_sealed_total", lambda: s.panes_sealed)
+    # coalesced ingest (prometheus:
+    # flink_tpu_device_batches_coalesced_total)
     g.gauge("batches_coalesced_total", lambda: s.batches_coalesced)
-    g.gauge("fire_merge_rows_read", lambda: s.fire_merge_rows)
     # async fire drain (prometheus:
     # flink_tpu_device_fire_unready_polls_total /
     # flink_tpu_device_fires_drained_total /

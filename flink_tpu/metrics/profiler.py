@@ -594,15 +594,6 @@ LEDGER_SITE_INVENTORY: tuple = (
      "program"),
     ("device_window.fire",
      "runtime/operators/device_window.py — full pane fire program"),
-    ("device_window.fire_inc",
-     "runtime/operators/device_window.py — incremental fire merge "
-     "program"),
-    ("device_window.fire_rebuild",
-     "runtime/operators/device_window.py — post-fire table rebuild "
-     "program"),
-    ("device_window.seal",
-     "runtime/operators/device_window.py — pane seal program "
-     "(incremental fire engine)"),
     ("device_window.step",
      "runtime/operators/device_window.py — per-batch window ingest "
      "program"),
@@ -610,22 +601,15 @@ LEDGER_SITE_INVENTORY: tuple = (
      "parallel/sharded_window.py — sharded fire (compact) program"),
     ("mesh.fire_full",
      "parallel/sharded_window.py — sharded full-fire program"),
-    ("mesh.fire_inc",
-     "parallel/sharded_window.py — sharded incremental fire program"),
     ("mesh.init",  # lint: key-ok ledger site, not a config key
      "parallel/sharded_window.py — the empty sharded state, built shard "
      "by shard"),
-    ("mesh.rebuild_inc",
-     "parallel/sharded_window.py — sharded incremental rebuild "
-     "program"),
     ("mesh.reclaim",  # lint: key-ok ledger site, not a config key
      "parallel/sharded_window.py — sharded reclaim program (every "
      "shard's table rebuilt at its own capacity from its live keys, its "
      "planes re-seated; built with the state, before any input)"),
     ("mesh.retire",  # lint: key-ok ledger site, not a config key
      "parallel/sharded_window.py — retired-pane cleanup program"),
-    ("mesh.seal_inc",
-     "parallel/sharded_window.py — sharded pane seal program"),
     ("mesh.step",  # lint: key-ok ledger site, not a config key
      "parallel/sharded_window.py — sharded per-batch ingest program"),
     ("ops.pallas_topk",

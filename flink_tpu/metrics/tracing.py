@@ -794,7 +794,7 @@ SPAN_INVENTORY: tuple = (
      "diff + digest-verified key-group transfer"),
     ("rescale", "Rebuild",
      "runtime/operators/mesh_window.py rescale_live — state install on "
-     "the new mesh + derived-plane invalidation"),
+     "the new mesh"),
     ("rescale", "Rescale",
      "cluster/local.py live_rescale + mesh_window rescale_live — root "
      "span, barrier-aligned worker-set change without restart"),

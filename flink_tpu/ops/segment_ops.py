@@ -23,9 +23,8 @@ import numpy as np
 __all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
            "Halves", "plane_map", "plane_take", "plane_row", "planes_joined",
            "planes_stored_like", "stores_halves", "identity_words",
-           "plane_identity", "make_plane", "AGG_FOLDS", "AGG_MERGES", "AGG_COMBINE2", "AGG_INVERT", "INVERTIBLE_KINDS",
-           "make_accumulator", "segment_topk", "pow2_ceil",
-           "merge_tree_build", "merge_tree_update", "merge_tree_root"]
+           "plane_identity", "make_plane", "AGG_FOLDS", "AGG_MERGES",
+           "make_accumulator", "segment_topk", "pow2_ceil"]
 
 
 def _scatter_add(acc, idx, vals):
@@ -68,27 +67,6 @@ AGG_MERGES = {
 }
 _MERGES = AGG_MERGES
 
-#: kind -> elementwise pairwise combine (a ⊕ b) — the binary form of the
-#: pane merge, used by the incremental fire engine (running window
-#: accumulators and merge-tree levels).
-AGG_COMBINE2 = {
-    "sum": jnp.add,
-    "count": jnp.add,
-    "min": jnp.minimum,
-    "max": jnp.maximum,
-}
-
-#: kind -> inverse combine (a ⊖ b), defined only for invertible aggregates.
-AGG_INVERT = {
-    "sum": jnp.subtract,
-    "count": jnp.subtract,
-}
-
-#: Aggregate kinds whose combine has an inverse: a running window
-#: accumulator can retire a pane by subtraction. min/max are not in this
-#: set and use a merge tree instead.
-INVERTIBLE_KINDS = frozenset(AGG_INVERT)
-
 
 def make_accumulator(kind: str, shape: tuple[int, ...], dtype) -> jax.Array:
     return jnp.full(shape, AGG_INITS[kind](dtype), dtype=dtype)
@@ -98,7 +76,7 @@ def make_accumulator(kind: str, shape: tuple[int, ...], dtype) -> jax.Array:
 class Halves:
     """A 64-bit integer plane STORED as its two 32-bit words: ``hi`` and
     ``lo``, two ``uint32`` arrays of the plane's shape. Both stacks keep
-    their pane-role ring planes so (``state/tpu_backend``; the mesh's
+    their ring planes so (``state/tpu_backend``; the mesh's
     ``ShardedWindowState.accs``, ``[D, ring, capacity]`` a word): the
     TPU has no 64-bit registers, so a program whose parameter or result
     is an ``s64`` array splits ALL of it into words at its entry and
@@ -220,13 +198,12 @@ def planes_stored_like(stored: dict, planes: dict) -> dict:
             else plane for name, plane in planes.items()}
 
 
-def stores_halves(dtype, ring, role: str = "pane") -> bool:
+def stores_halves(dtype, ring) -> bool:
     """Whether a plane is stored as ``Halves`` (the one-chip backend's
-    and the mesh state's one layout rule): a pane-role ring plane of a
-    64-bit integer."""
+    and the mesh state's one layout rule): a ring plane of a 64-bit
+    integer."""
     dtype = np.dtype(dtype)
-    return bool(ring) and role == "pane" and dtype.kind in "iu" \
-        and dtype.itemsize == 8
+    return bool(ring) and dtype.kind in "iu" and dtype.itemsize == 8
 
 
 def identity_words(kind: str, dtype) -> Halves:
@@ -358,47 +335,6 @@ def segment_topk(values: jax.Array, valid: jax.Array, k: int
                else jnp.iinfo(values.dtype).min)
     masked = jnp.where(valid, values, neg_inf)
     return jax.lax.top_k(masked, k)
-
-
-def merge_tree_build(kind: str, leaves: jax.Array) -> jax.Array:
-    """Build a flat binary merge tree over ``leaves`` [L, capacity] (L a
-    power of two). Returns a heap-ordered [2L, capacity] array: node 0 is
-    the identity (padding target), node 1 the root, children of node i at
-    2i and 2i+1, leaves occupying rows [L, 2L). A window fire reads the
-    root; a pane seal rewrites one leaf and its log2(L) ancestors."""
-    L = leaves.shape[0]
-    combine = AGG_COMBINE2[kind]
-    levels = [leaves]
-    cur = leaves
-    while cur.shape[0] > 1:
-        cur = combine(cur[0::2], cur[1::2])
-        levels.append(cur)
-    ident = jnp.full(leaves.shape[1:], AGG_INITS[kind](leaves.dtype),
-                     leaves.dtype)
-    return jnp.concatenate([ident[None]] + list(reversed(levels)), axis=0)
-
-
-def merge_tree_update(kind: str, tree: jax.Array, leaf_pos: jax.Array,
-                      value: jax.Array) -> jax.Array:
-    """Functionally set leaf ``leaf_pos`` (traced int in [0, L)) of a heap
-    tree [2L, capacity] to ``value`` [capacity] and recompute its ancestor
-    path — O(log L) dynamic row updates, shape-independent of which leaf
-    changed."""
-    L = tree.shape[0] // 2
-    combine = AGG_COMBINE2[kind]
-    idx = (leaf_pos + L).astype(jnp.int32)
-    tree = jax.lax.dynamic_update_slice_in_dim(tree, value[None], idx, axis=0)
-    for _ in range(max(L.bit_length() - 1, 0)):
-        idx = idx // 2
-        parent = combine(tree[2 * idx], tree[2 * idx + 1])
-        tree = jax.lax.dynamic_update_slice_in_dim(tree, parent[None], idx,
-                                                   axis=0)
-    return tree
-
-
-def merge_tree_root(tree: jax.Array) -> jax.Array:
-    """The full-tree merge: root of a heap-ordered merge tree."""
-    return tree[1]
 
 
 def pow2_ceil(n: int) -> int:

@@ -9,10 +9,7 @@ snapshot reordered by (key group, key) and cut into fixed spans of the
 max-parallelism key-group space, each page digest-verified (blake2b-128,
 the checkpoint chunk digest) before it is applied — a page that fails
 verification aborts the rescale instead of installing torn state. Only
-pages whose key groups CHANGE owner count as moved; `role="window"` planes
-(the derived incremental fire planes) are never shipped — the operator
-rebuilds them from the pane accumulators after the switch
-(`_mark_inc_dirty`), exactly as after a checkpoint restore.
+pages whose key groups CHANGE owner count as moved.
 
 This module is pure host-side planning over snapshot dicts (the
 `_snapshot_backend` format); the operator drives it and owns the device

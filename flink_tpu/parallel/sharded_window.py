@@ -51,10 +51,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..metrics.device import instrumented_program_cache
 from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
-from ..ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
-    AGG_MERGES, INVERTIBLE_KINDS, make_plane, merge_tree_build, \
-    merge_tree_update, plane_identity, plane_map, plane_take, pow2_ceil, \
-    ring_fold, stores_halves
+from ..ops.segment_ops import AGG_INITS, AGG_MERGES, make_plane, \
+    plane_identity, plane_map, plane_take, ring_fold, stores_halves
 from ..ops.topk import masked_topk_sort, threshold_topk
 from ..state.tpu_backend import reclaim_shard
 from .exchange import bucket_capacity, exchange_round, plan_exchange
@@ -113,14 +111,6 @@ def local_signature(aggs: Sequence[AggDef], capacity: int, ring: int
 
 def _aggs_from_sig(agg_sig) -> list[AggDef]:
     return [AggDef(name, kind, np.dtype(dt)) for name, kind, dt in agg_sig]
-
-
-def _split_sig(agg_sig):
-    inv = tuple((kind, name) for name, kind, _ in agg_sig
-                if kind in INVERTIBLE_KINDS)
-    tree = tuple((kind, name) for name, kind, _ in agg_sig
-                 if kind not in INVERTIBLE_KINDS)
-    return inv, tree
 
 
 # ----------------------------------------------------------------------
@@ -339,12 +329,6 @@ def _ring_rows(plane, rows: jax.Array) -> jax.Array:
     return plane_take(plane, lambda a: a[:, rows, :])
 
 
-def _ring_row(plane, row: jax.Array) -> jax.Array:
-    """Ring row ``row`` (a traced scalar) of every shard's plane,
-    [D, cap], taken as ``_ring_rows`` takes."""
-    return plane_take(plane, lambda a: jnp.take(a, row, axis=1))
-
-
 @instrumented_program_cache("mesh.fire")
 def _fire_program(sig):
     _, agg_sig, _cap, _ring = sig
@@ -443,119 +427,6 @@ def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int],
                                                   axis_name, mesh))
 
 
-@instrumented_program_cache("mesh.seal_inc")
-def _seal_inc_program(sig):
-    """ONE donated program per pane seal: for each invertible plane,
-    window' = (window ⊕ sealed pane) ⊖ retiring pane; for each merge
-    tree, clear the retiring leaf then write the sealed pane and
-    recompute both O(log L) ancestor paths. Returns the fire view
-    ([D, capacity] per plane) alongside the new planes — the fire
-    consumes the view without re-reading any ring row."""
-    _, agg_sig, _cap, _ring = sig
-    inv_sig, tree_sig = _split_sig(agg_sig)
-
-    @functools.partial(jax.jit, donate_argnums=(1, 2))
-    def seal(state: ShardedWindowState, wins: dict, trees: dict,
-             new_row, sub_row, sub_valid, new_leaf, old_leaf):
-        view, new_wins, new_trees = {}, {}, {}
-        for kind, name in inv_sig:
-            arr = state.accs[name]                  # [D, ring, cap]
-            sealed = _ring_row(arr, new_row)         # [D, cap]
-            fire_v = AGG_COMBINE2[kind](wins[name], sealed)
-            ident = AGG_INITS[kind](arr.dtype)
-            retire = jnp.where(sub_valid, _ring_row(arr, sub_row), ident)
-            new_wins[name] = AGG_INVERT[kind](fire_v, retire)
-            view[name] = fire_v
-        for kind, name in tree_sig:
-            arr = state.accs[name]
-            ident = jnp.full((arr.shape[0], arr.shape[2]),
-                             AGG_INITS[kind](arr.dtype), arr.dtype)
-            # clear the retiring leaf FIRST: it can never be the pane
-            # being sealed (any two live panes differ by < L)
-            tree = jax.vmap(
-                lambda t, v: merge_tree_update(kind, t, old_leaf, v)
-            )(trees[name], ident)
-            tree = jax.vmap(
-                lambda t, v: merge_tree_update(kind, t, new_leaf, v)
-            )(tree, _ring_row(arr, new_row))
-            new_trees[name] = tree
-            view[name] = tree[:, 1]
-        return view, new_wins, new_trees
-
-    return seal
-
-
-@instrumented_program_cache("mesh.rebuild_inc")
-def _rebuild_inc_program(sig):
-    """Re-derive the incremental planes from the pane accumulators in
-    one dispatch (restore, degrade, fire-boundary jump, or a write
-    into an already-sealed pane). ``pane_rows``/``pane_leaves`` are
-    padded to [ring] so the program shape is window-width-independent;
-    padding rows carry leaf index L and drop out of the scatter."""
-    _, agg_sig, _cap, ring = sig
-    inv_sig, tree_sig = _split_sig(agg_sig)
-    L = pow2_ceil(ring)
-
-    @jax.jit
-    def rebuild(state: ShardedWindowState, pane_rows, rows_valid,
-                pane_leaves, sub_row, sub_valid):
-        view, new_wins, new_trees = {}, {}, {}
-        for kind, name in inv_sig:
-            arr = state.accs[name]
-            ident = AGG_INITS[kind](arr.dtype)
-            sub = jnp.where(rows_valid[None, :, None],
-                            _ring_rows(arr, pane_rows), ident)
-            fire_v = AGG_MERGES[kind](sub, axis=1)   # [D, cap]
-            retire = jnp.where(sub_valid, _ring_row(arr, sub_row), ident)
-            new_wins[name] = AGG_INVERT[kind](fire_v, retire)
-            view[name] = fire_v
-        for kind, name in tree_sig:
-            arr = state.accs[name]
-            ident = AGG_INITS[kind](arr.dtype)
-            rows = jnp.where(rows_valid[None, :, None],
-                             _ring_rows(arr, pane_rows), ident)
-            leaves = jnp.full((arr.shape[0], L, arr.shape[2]), ident,
-                              arr.dtype)
-            idx = jnp.where(rows_valid, pane_leaves, L)
-            leaves = leaves.at[:, idx, :].set(rows, mode="drop")
-            tree = jax.vmap(lambda lv: merge_tree_build(kind, lv))(
-                leaves)
-            new_trees[name] = tree
-            view[name] = tree[:, 1]
-        return view, new_wins, new_trees
-
-    return rebuild
-
-
-@instrumented_program_cache("mesh.fire_inc")
-def _fire_inc_program(sig, rank_name: Optional[str], topk: Optional[int],
-                      axis_name: str = DATA_AXIS):
-    """The fused fire over an incremental view: emit mask + optional
-    global top-k + health scalars — identical output structure to
-    _fire_full_program (and bound per mesh like it), but reading
-    [D, capacity] views instead of merging W ring rows."""
-    _, agg_sig, _cap, _ring = sig
-    count_name = next(name for name, kind, _ in agg_sig if kind == "count")
-
-    def make(mesh: Mesh):
-        @jax.jit
-        def fire(state: ShardedWindowState, view: dict):
-            count = view[count_name]
-            emit = (state.table != jnp.int64(EMPTY_KEY)) & (count > 0)
-            occ = (state.table != jnp.int64(EMPTY_KEY)).sum(axis=1).max()
-            dropped = state.dropped.sum()
-            if topk is None:
-                return jnp.copy(state.table), emit, view, dropped, occ
-            keys, ok, res, select = _top_rows(agg_sig, state, view, emit,
-                                              rank_name, topk, axis_name,
-                                              mesh)
-            return keys, ok, res, dropped, occ, select
-
-        return fire
-
-    return _per_mesh(make)
-
-
 @instrumented_program_cache("mesh.retire")
 def _retire_program(sig):
     _, agg_sig, _cap, _ring = sig
@@ -583,16 +454,13 @@ def _make_reclaim(sig, axis_name: str, rules: tuple, mesh: Mesh):
     _reclaim_program)."""
     _, agg_sig, cap, ring = sig
     names = [name for name, _kind, _dt in agg_sig]
-    # every plane of the state is a pane-role ring plane: each one says
-    # what lives, as the one-chip backend's pane planes do
     plane_sig = tuple((kind, dt, (ring, cap)) for _n, kind, dt in agg_sig)
-    live = tuple(range(len(plane_sig)))
 
     def shard_body(table, accs, dropped):
         # a shard's plane in the layout the state keeps it in: the words
         # of a 64-bit one, which the reclaim tests and moves as words
         table, planes, dropped, counts = reclaim_shard(
-            plane_sig, live, table[0],
+            plane_sig, table[0],
             tuple(_shard_plane(accs[n]) for n in names), dropped[0])
         return (table[None],
                 {n: _mesh_plane(p) for n, p in zip(names, planes)},
@@ -673,16 +541,6 @@ class ShardedWindowAgg:
         self.ring = ring
         self.max_parallelism = max_parallelism
         self._sharding = plan.state_sharding
-        # incremental fire engine plane split (window.fire.incremental):
-        # invertible aggregates keep a running [D, capacity] window
-        # accumulator; min/max keep a [D, 2L, capacity] binary merge tree
-        # over ring pane rows. L tracks the RING (not the window width) so
-        # the compiled seal/rebuild shapes are independent of W.
-        self.tree_size = pow2_ceil(ring)
-        self.inv_sig = tuple((a.kind, a.name) for a in self.aggs
-                             if a.kind in INVERTIBLE_KINDS)
-        self.tree_sig = tuple((a.kind, a.name) for a in self.aggs
-                              if a.kind not in INVERTIBLE_KINDS)
         self._init = _init_program(self.sig, plan.rules)
         self._step = _step_program(self.sig, max_parallelism,
                                    plan.axis_name, plan.rules)
@@ -756,9 +614,7 @@ class ShardedWindowAgg:
         the keys that hold no data in any ring row (all their windows
         fired and retired): one dispatch, nothing waited for. Returns
         (new state, int32 [D, 2] keys kept and freed a shard). ``state``
-        is DONATED, like the step's; every slot may move, so what was
-        derived from the old slots (the incremental fire's planes) is
-        void."""
+        is DONATED, like the step's; every slot may move."""
         return self._reclaim(self.mesh, state)
 
     def prepare_reclaim(self, state: ShardedWindowState) -> None:
@@ -795,33 +651,6 @@ class ShardedWindowAgg:
         return self._fire_full_program(rank_name, topk)(
             self.mesh, state, jnp.asarray(pane_rows, jnp.int32),
             jnp.asarray(rows_valid))
-
-    # -- incremental fire engine ---------------------------------------
-    def seal_inc(self, state: ShardedWindowState, wins: dict, trees: dict,
-                 new_row: int, sub_row: int, sub_valid: bool,
-                 new_leaf: int, old_leaf: int):
-        """Seal one pane into the incremental planes (wins/trees are
-        donated) and return (fire view, new wins, new trees)."""
-        return _seal_inc_program(self.sig)(
-            state, wins, trees, jnp.int32(new_row), jnp.int32(sub_row),
-            jnp.bool_(sub_valid), jnp.int32(new_leaf), jnp.int32(old_leaf))
-
-    def rebuild_inc(self, state: ShardedWindowState, pane_rows: np.ndarray,
-                    rows_valid: np.ndarray, pane_leaves: np.ndarray,
-                    sub_row: int, sub_valid: bool):
-        """Rebuild the incremental planes from the pane accumulators;
-        same return shape as seal_inc."""
-        return _rebuild_inc_program(self.sig)(
-            state, jnp.asarray(pane_rows, jnp.int32),
-            jnp.asarray(rows_valid), jnp.asarray(pane_leaves, jnp.int32),
-            jnp.int32(sub_row), jnp.bool_(sub_valid))
-
-    def fire_inc(self, state: ShardedWindowState, view: dict,
-                 rank_name: Optional[str], topk: Optional[int]):
-        """Dispatch the fused incremental fire; returns device outputs
-        (same structure as fire_compact) without synchronizing."""
-        return _fire_inc_program(self.sig, rank_name, topk,
-                                 self.plan.axis_name)(self.mesh, state, view)
 
     # ------------------------------------------------------------------
     def retire_row(self, state: ShardedWindowState,

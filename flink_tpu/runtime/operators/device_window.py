@@ -282,159 +282,6 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
     return fire_fn
 
 
-@instrumented_program_cache("device_window.seal")
-def _seal_program(inv_sig: tuple, tree_sig: tuple):
-    """Incremental fire engine, steady-state path: ONE donated program per
-    aggregate signature that seals the newest pane into the running window
-    state and yields this fire's merged view — O(capacity) (invertible) /
-    O(capacity·log ring) (merge tree) regardless of window width.
-
-    * invertible aggregates (``inv_sig``: sum/count/avg-sum) keep a
-      [capacity] running accumulator: fire view = acc + sealed pane row;
-      the next state subtracts the retiring pane (masked when that pane
-      predates the data: its ring row may alias a live future pane);
-    * non-invertible aggregates (``tree_sig``: min/max) keep a heap-
-      ordered binary merge tree over per-pane leaf copies: clear the
-      retiring leaf, write the sealed pane's leaf, recompute both ancestor
-      paths (O(log) dynamic row updates); the fire view is the root.
-
-    The pane planes (``arrays``) are read BEFORE the caller retires the
-    oldest ring row, so the subtraction sees the retiring pane intact.
-    Leaf/row indices are traced scalars — one executable serves every
-    pane, and none of the shapes depend on the window width W (the tree
-    is sized by the ring), so seal programs are shared across window
-    configurations."""
-    from ...ops.segment_ops import AGG_COMBINE2, AGG_INITS, AGG_INVERT, \
-        merge_tree_update, plane_row
-
-    @partial(jax.jit, donate_argnums=(1, 2))
-    def seal_fn(arrays, wins, trees, new_row, sub_row, sub_valid,
-                new_leaf, old_leaf):
-        view, new_wins, new_trees = {}, {}, {}
-        for kind, name in inv_sig:
-            arr = arrays[name]
-            new_pane = plane_row(arr, new_row)
-            fire_v = AGG_COMBINE2[kind](wins[name], new_pane)
-            sub_pane = plane_row(arr, sub_row)
-            retire = jnp.where(sub_valid, sub_pane,
-                               AGG_INITS[kind](arr.dtype))
-            view[name] = fire_v
-            new_wins[name] = AGG_INVERT[kind](fire_v, retire)
-        for kind, name in tree_sig:
-            arr = arrays[name]
-            ident = jnp.full(arr.shape[1:], AGG_INITS[kind](arr.dtype),
-                             arr.dtype)
-            # clear the retiring pane's leaf FIRST: its position can never
-            # be a live pane's (any two live panes differ by < tree size)
-            tree = merge_tree_update(kind, trees[name], old_leaf, ident)
-            tree = merge_tree_update(kind, tree, new_leaf,
-                                     plane_row(arr, new_row))
-            view[name] = tree[1]
-            new_trees[name] = tree
-        return view, new_wins, new_trees
-
-    return seal_fn
-
-
-@instrumented_program_cache("device_window.fire_rebuild")
-def _rebuild_program(inv_sig: tuple, tree_sig: tuple, tree_size: int):
-    """Incremental fire engine, recovery path: rebuild the running window
-    state from the pane planes in one dispatch — after restore/degrade, a
-    fire-boundary jump, or a write into an already-sealed pane. Reads the
-    live window's pane rows exactly like the full merge (pane_rows is
-    padded to the RING length with a validity mask so the program shape
-    stays W-independent) and returns this fire's view plus consistent
-    next-state accumulators/trees."""
-    from ...ops.segment_ops import AGG_INITS, AGG_INVERT, AGG_MERGES, \
-        merge_tree_build, plane_row, plane_take
-
-    L = tree_size
-
-    @jax.jit
-    def rebuild_fn(arrays, pane_rows, rows_valid, pane_leaves, sub_row,
-                   sub_valid):
-        view, new_wins, new_trees = {}, {}, {}
-        for kind, name in inv_sig:
-            arr = arrays[name]
-            ident = AGG_INITS[kind](arr.dtype)
-            sub = jnp.where(rows_valid[:, None],
-                            plane_take(arr, lambda a: a[pane_rows]), ident)
-            fire_v = AGG_MERGES[kind](sub, axis=0)
-            view[name] = fire_v
-            sub_pane = plane_row(arr, sub_row)
-            retire = jnp.where(sub_valid, sub_pane, ident)
-            new_wins[name] = AGG_INVERT[kind](fire_v, retire)
-        for kind, name in tree_sig:
-            arr = arrays[name]
-            ident = AGG_INITS[kind](arr.dtype)
-            rows = jnp.where(rows_valid[:, None],
-                             plane_take(arr, lambda a: a[pane_rows]), ident)
-            leaves = jnp.full((L,) + arr.shape[1:], ident, arr.dtype)
-            lidx = jnp.where(rows_valid, pane_leaves, L)
-            leaves = leaves.at[lidx].set(rows, mode="drop")
-            tree = merge_tree_build(kind, leaves)
-            view[name] = tree[1]
-            new_trees[name] = tree
-        return view, new_wins, new_trees
-
-    return rebuild_fn
-
-
-@instrumented_program_cache("device_window.fire_inc")
-def _fire_inc_program(agg_sig: tuple, topk: Optional[int],
-                      topk_value_bits: int = 64):
-    """Incremental counterpart of ``_fire_program``: identical outputs
-    (emit mask / top-k, health scalars), but every aggregate's window
-    merge is a [capacity] READ of the sealed view — no [W, capacity] pane
-    gather anywhere. The signature carries no window width, so one
-    executable serves every W."""
-
-    @jax.jit
-    def fire_fn(table, view, dropped):
-        count = view["__count__"]
-        emit = (table != jnp.int64(EMPTY_KEY)) & (count > 0)
-        occ = (table != jnp.int64(EMPTY_KEY)).sum()
-        if topk is not None:
-            rk_kind, rk_name = agg_sig[0]
-            if rk_kind == "count":
-                ranked = count
-            elif rk_kind == "avg":
-                s = view[f"{rk_name}.sum"]
-                ranked = s / jnp.maximum(count, 1).astype(s.dtype)
-            else:
-                ranked = view[rk_name]
-            with jax.named_scope("fire.topk"):
-                idx, ok, select = _select_topk(ranked, emit, topk,
-                                               topk_value_bits)
-                keys = jnp.take(table, idx)
-                count_k = jnp.take(count, idx)
-            out = {}
-            for kind, out_name in agg_sig:
-                if out_name == rk_name:
-                    out[out_name] = jnp.take(ranked, idx)
-                elif kind == "count":
-                    out[out_name] = count_k
-                elif kind == "avg":
-                    s = jnp.take(view[f"{out_name}.sum"], idx)
-                    out[out_name] = s / jnp.maximum(count_k, 1).astype(
-                        s.dtype)
-                else:
-                    out[out_name] = jnp.take(view[out_name], idx)
-            return keys, ok, out, dropped, occ, select
-        results = {}
-        for kind, out_name in agg_sig:
-            if kind == "count":
-                results[out_name] = count
-            elif kind == "avg":
-                s = view[f"{out_name}.sum"]
-                results[out_name] = s / jnp.maximum(count, 1).astype(s.dtype)
-            else:
-                results[out_name] = view[out_name]
-        return table, emit, results, dropped, occ
-
-    return fire_fn
-
-
 class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                               SliceControlPlane, OneInputOperator):
     def __init__(self, assigner: WindowAssigner, key_column: str,
@@ -447,7 +294,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                  async_fire: bool = False,
                  hbm_budget_slots: int = 0,
                  spill_staging_slots: int = 1 << 16,
-                 fire_incremental: Optional[bool] = None,
                  name: str = "DeviceWindowAgg"):
         """``emit_topk``: emit only the k keys with the largest value of the
         FIRST aggregate per window (one device lax.top_k instead of a full
@@ -508,18 +354,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._late_cached = 0  # host cache of _late_dev (metrics scrapes
         # must never force a device sync; refreshed at fire/checkpoint
         # boundaries)
-        # incremental fire engine (window.fire.incremental): running
-        # window accumulator per invertible aggregate + merge tree per
-        # min/max aggregate, updated once per pane seal. _inc_next is the
-        # fire boundary the sealed state is consistent FOR; _inc_dirty
-        # forces a one-dispatch rebuild from the pane planes (restore,
-        # degrade, boundary jump, write into a sealed pane).
-        self._inc_flag = fire_incremental
-        self._inc_enabled = bool(fire_incremental)
-        self._inc_next: Optional[int] = None
-        self._inc_dirty = True
-        from ...ops.segment_ops import pow2_ceil
-        self._tree_size = pow2_ceil(self._ring)  # leaf count L (>= ring)
         self._init_coalescer()
         # degradation ladder (docs/ROBUSTNESS.md): once a persistent
         # compiled-segment failure evacuates state to host, this operator
@@ -545,8 +379,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
     # -- lifecycle ---------------------------------------------------------
     def setup(self, ctx: OperatorContext, output: Output) -> None:
         super().setup(ctx, output)
-        from ...core.config import FaultOptions, StateOptions, TaskOptions, \
-            WindowOptions
+        from ...core.config import FaultOptions, StateOptions, TaskOptions
         budget = self._hbm_budget or ctx.config.get(
             StateOptions.TPU_HBM_BUDGET)
         if not budget:
@@ -563,9 +396,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                                    if a.kind != "count")
                 slot_bytes = 8 + (self._ring or 1) * 8 * (1 + value_planes)
                 budget = max(1, budget_bytes // slot_bytes)
-        if self._inc_flag is None:
-            self._inc_enabled = bool(
-                ctx.config.get(WindowOptions.FIRE_INCREMENTAL))
         self._max_inflight = max(1, int(
             ctx.config.get(TaskOptions.MAX_INFLIGHT)))
         self._coalesce_target = int(
@@ -630,13 +460,10 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 self._backend.register_array_state(
                     a.out_name, a.kind, a.dtype, ring=self._ring)
         self._registered = True
-        # every plane of the job exists now (the incremental engine's
-        # derived ones too: a reclaim re-seats them) and no input has
-        # been taken: the reclaim of these planes is built here, not
-        # when a reading finds the table full (ROADMAP D14), and beside
-        # it the probe program a new table's wide batches will run
-        if self._inc_enabled:
-            self._ensure_inc_planes(*self._inc_sigs())
+        # every plane of the job exists now and no input has been taken:
+        # the reclaim of these planes is built here, not when a reading
+        # finds the table full (ROADMAP D14), and beside it the probe
+        # program a new table's wide batches will run
         self._backend.prepare(batch_rows)
 
     def initialize_state(self, keyed_snapshots: list, operator_snapshot) -> None:
@@ -651,11 +478,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             live = (range(first, self._max_seen_pane + 1)
                     if first is not None else range(0))
             self._backend.conform_ring(self._ring, live)
-            # snapshots never carry the derived incremental state (full-
-            # merge checkpoints restore into incremental mode and vice
-            # versa): the first fire after restore rebuilds it
-            self._inc_dirty = True
-            self._inc_next = None
 
     # -- data path ---------------------------------------------------------
     def process_batch(self, batch: RecordBatch) -> None:
@@ -833,9 +655,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._stage = None
         self._degraded = True
         self._guard.active = False
-        # the evacuated snapshot carries only pane planes (window-role
-        # state is derived): the next incremental fire rebuilds
-        self._inc_dirty = True
         DEVICE_STATS.note_degraded("device_window")
 
     def _on_segment_failure(self, err: DeviceSegmentError,
@@ -851,14 +670,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             self._degrade(err)
             return False
         raise err
-
-    def _note_open_ingest(self, min_pane: int) -> None:
-        """A write into a pane the incremental engine already sealed
-        (pane < _inc_next - 1: late-but-open records or a min-pane
-        decrease) invalidates the running window state; the next fire
-        rebuilds it from the pane planes in one dispatch."""
-        if self._inc_next is not None and min_pane < self._inc_next - 1:
-            self._inc_dirty = True
 
     # -- device-resident ingest (zero-transfer hot path) --------------------
     def _fold_sig(self) -> tuple:
@@ -888,7 +699,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                                else max(self._max_seen_pane, pane_hi))
         self._min_seen_pane = (eff_lo if self._min_seen_pane is None
                                else min(self._min_seen_pane, eff_lo))
-        self._note_open_ingest(eff_lo)
         low = (first_open if self._fired_boundary is not None
                else self._min_seen_pane)
         if pane_hi - low >= self._ring:
@@ -978,7 +788,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                                else max(self._max_seen_pane, pane_hi))
         self._min_seen_pane = (eff_lo if self._min_seen_pane is None
                                else min(self._min_seen_pane, eff_lo))
-        self._note_open_ingest(eff_lo)
         low = (first_open if self._fired_boundary is not None
                else self._min_seen_pane)
         if pane_hi - low >= self._ring:
@@ -1049,10 +858,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         self._coalesce_flush()
         self._drain_spill_stage()
         if self._backend is not None and self._backend.tiering_active:
-            if self._backend.tier_boundary():
-                # promoted keys arrive with identity window-role planes:
-                # the next incremental fire rebuilds them from the panes
-                self._inc_dirty = True
+            self._backend.tier_boundary()
 
     def _drain_spill_stage(self) -> None:
         if self._stage is None:
@@ -1200,11 +1006,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         # rows may be occupied by live FUTURE panes (row aliasing); that
         # the window has a pane at all, _fire_window has checked
         first = max(p_end - W, self._min_seen_pane)
-        if self._inc_enabled:
-            self._fire_incremental(p_end, first)
-            return
         rows = [(p % self._ring) for p in range(first, p_end)]
-        DEVICE_STATS.note_fire_merge_rows(len(rows))
         # constant [W] shape: pad + mask so every fire shares one program
         pane_rows = np.zeros(W, np.int32)
         pane_rows[:len(rows)] = rows
@@ -1237,125 +1039,6 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                             self._backend.table_generation))
         # retire the oldest pane of this window: no future window needs it
         # (skip panes below min_seen — their ring rows belong to live panes)
-        if p_end - W >= self._min_seen_pane:
-            self._backend.reset_ring_row((p_end - W) % self._ring)
-        self._refresh_late(block=True)
-
-    # -- incremental fire engine -------------------------------------------
-    def _inc_sigs(self) -> tuple[tuple, tuple]:
-        """(invertible, merge-tree) signatures over the fire planes.
-        The count plane is always invertible, so ``inv_sig`` is never
-        empty; min/max planes go through the merge tree."""
-        from ...ops.segment_ops import INVERTIBLE_KINDS
-
-        inv, tree = [("count", "__count__")], []
-        for a in self._aggs:
-            if a.kind == "count":
-                continue
-            if a.kind == "avg":
-                inv.append(("sum", f"{a.out_name}.sum"))
-            elif a.kind in INVERTIBLE_KINDS:
-                inv.append((a.kind, a.out_name))
-            else:
-                tree.append((a.kind, a.out_name))
-        return tuple(inv), tuple(tree)
-
-    def _ensure_inc_planes(self, inv_sig: tuple, tree_sig: tuple) -> None:
-        """Register the derived window-role planes on the CURRENT backend
-        (lazily: the backend is replaced on degrade and rebuilt on
-        restore, neither of which carries window-role state)."""
-        for kind, name in inv_sig:
-            wn = f"{name}.__win__"
-            if not self._backend.has_array(wn):
-                self._backend.register_array_state(
-                    wn, kind, self._backend.get_array(name).dtype,
-                    ring=None, role="window")
-                self._inc_dirty = True
-        for kind, name in tree_sig:
-            tn = f"{name}.__tree__"
-            if not self._backend.has_array(tn):
-                self._backend.register_array_state(
-                    tn, kind, self._backend.get_array(name).dtype,
-                    ring=2 * self._tree_size, role="window")
-                self._inc_dirty = True
-        self._backend.prepare_reclaim()   # nothing, unless a plane is new
-
-    def _fire_incremental(self, p_end: int, first: int) -> None:
-        """O(capacity) fire: seal the newest pane into the running window
-        state (or rebuild it from the pane planes when stale), then read
-        the merged view — outputs byte-identical to the full-merge path
-        for integer aggregates and min/max (float sums may differ in
-        rounding order; see docs/PERFORMANCE.md)."""
-        W = self._window_panes
-        rows = [(p % self._ring) for p in range(first, p_end)]
-        inv_sig, tree_sig = self._inc_sigs()
-        agg_sig = tuple((a.kind, a.out_name) for a in self._aggs)
-        vb = (self._aggs[0].value_bits
-              if self._topk is not None and self._aggs else 64)
-        L = self._tree_size
-
-        def dispatch():
-            self._ensure_inc_planes(inv_sig, tree_sig)
-            backend = self._backend
-            arrays = {n: backend.get_array(n)
-                      for n in self._fire_array_names()}
-            sub_row = np.int32((p_end - W) % self._ring)
-            sub_valid = np.bool_(p_end - W >= self._min_seen_pane)
-            if self._inc_dirty or self._inc_next != p_end:
-                pane_rows = np.zeros(self._ring, np.int32)
-                rows_valid = np.zeros(self._ring, bool)
-                pane_leaves = np.zeros(self._ring, np.int32)
-                pane_rows[:len(rows)] = rows
-                rows_valid[:len(rows)] = True
-                pane_leaves[:len(rows)] = [p % L
-                                           for p in range(first, p_end)]
-                rb = _rebuild_program(inv_sig, tree_sig, L)
-                view, new_wins, new_trees = rb(
-                    arrays, jnp.asarray(pane_rows), jnp.asarray(rows_valid),
-                    jnp.asarray(pane_leaves), sub_row, sub_valid)
-                rows_read = sealed = len(rows)
-            else:
-                seal = _seal_program(inv_sig, tree_sig)
-                wins = {n: backend.get_array(f"{n}.__win__")
-                        for _k, n in inv_sig}
-                trees = {n: backend.get_array(f"{n}.__tree__")
-                        for _k, n in tree_sig}
-                view, new_wins, new_trees = seal(
-                    arrays, wins, trees,
-                    np.int32((p_end - 1) % self._ring), sub_row, sub_valid,
-                    np.int32((p_end - 1) % L),
-                    np.int32((p_end - 1 - W) % L))
-                rows_read = 2 if bool(sub_valid) else 1
-                sealed = 1
-            fire_fn = _fire_inc_program(agg_sig, self._topk, vb)
-            outs = fire_fn(backend.table, view, backend.dropped_device)
-            return outs, new_wins, new_trees, rows_read, sealed
-
-        try:
-            outs, new_wins, new_trees, rows_read, sealed = \
-                self._guard.run(dispatch)
-        except DeviceSegmentError as e:
-            # persistent failure may degrade (state evacuates to a fresh
-            # backend) — and the seal DONATED the window-role buffers, so
-            # the retry must never re-seal: force the rebuild branch,
-            # which reads only the (restored) pane planes
-            self._on_segment_failure(e)
-            self._inc_dirty = True
-            outs, new_wins, new_trees, rows_read, sealed = dispatch()
-        for _k, n in inv_sig:
-            self._backend.set_array(f"{n}.__win__", new_wins[n])
-        for _k, n in tree_sig:
-            self._backend.set_array(f"{n}.__tree__", new_trees[n])
-        DEVICE_STATS.note_panes_sealed(sealed)
-        DEVICE_STATS.note_fire_merge_rows(rows_read)
-        self._inc_dirty = False
-        self._inc_next = p_end + 1
-        # host spill tier merges at materialization; take it BEFORE the
-        # retire below (same ordering as the full-merge path)
-        host_part = (self._host_fire_part(np.array(rows, np.int32))
-                     if self._backend.spill_active else None)
-        self._enqueue_fire((p_end, outs, host_part, time.perf_counter(),
-                            self._backend.table_generation))
         if p_end - W >= self._min_seen_pane:
             self._backend.reset_ring_row((p_end - W) % self._ring)
         self._refresh_late(block=True)

@@ -103,7 +103,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                  emit_window_bounds: bool = True,
                  emit_topk: Optional[int] = None,
                  async_fire: bool = False,
-                 fire_incremental: Optional[bool] = None,
                  name: str = "MeshWindowAgg"):
         super().__init__(name)
         pane = assigner.pane_size
@@ -129,16 +128,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         self._topk = emit_topk
         self._async = bool(async_fire)
         self._n_devices = n_devices
-        # incremental fire engine (window.fire.incremental): running
-        # window accumulators + merge trees, held OUTSIDE
-        # ShardedWindowState so snapshots never carry them (derived
-        # state; rebuilt from the pane planes after restore/grow)
-        self._inc_flag = fire_incremental
-        self._inc_enabled = bool(fire_incremental)
-        self._inc_next: Optional[int] = None
-        self._inc_dirty = True
-        self._inc_wins: dict = {}
-        self._inc_trees: dict = {}
 
         self._agg: Optional[ShardedWindowAgg] = None
         self._state: Optional[ShardedWindowState] = None
@@ -233,29 +222,12 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         else:
             devs = local[:n]
         self._mesh = make_mesh(n, devices=devs)
-        if self._inc_flag is None:
-            from ...core.config import WindowOptions
-            self._inc_enabled = bool(
-                ctx.config.get(WindowOptions.FIRE_INCREMENTAL))
 
     def initialize_state(self, keyed_snapshots: list, operator_snapshot) -> None:
         if not keyed_snapshots:
             return
         self._restore_control_meta([s["meta"] for s in keyed_snapshots])
         self._restore_backends([s["backend"] for s in keyed_snapshots])
-        # snapshots never carry the derived incremental planes; the first
-        # fire after restore rebuilds them from the pane accumulators
-        self._mark_inc_dirty()
-
-    def _mark_inc_dirty(self) -> None:
-        self._inc_dirty = True
-        self._inc_next = None
-        self._inc_wins = {}
-        self._inc_trees = {}
-
-    def _note_open_ingest(self, min_pane: int) -> None:
-        if self._inc_next is not None and min_pane < self._inc_next - 1:
-            self._inc_dirty = True
 
     # -- agg program construction ------------------------------------------
     def _aggdefs(self, schema: Schema) -> list[AggDef]:
@@ -585,15 +557,13 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         pressure (``seq``: that window's end) or, found by a probe, as a
         root (``seq``: the operator's watermark), and closes when the
         counts have landed. Every slot may move: a new table generation
-        begins and the incremental fire's planes are rebuilt at the next
-        fire."""
+        begins."""
         at = ({"parent": drain.context, "seq": drain.attrs["seq"]}
               if drain is not None else {"seq": self.current_watermark})
         span = TRACER.open_stage("window", "Reclaim", **at)
         self._state, counts = self._agg.reclaim(self._state)
         counts.copy_to_host_async()
         self._new_generation()
-        self._mark_inc_dirty()
         self._reclaiming = (counts, span, self._block_seq,
                             self._rows_stepped)
 
@@ -638,7 +608,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         defs = list(self._agg.aggs)
         self._build(defs, capacity=new_capacity)
         self._load_snapshot_into_state([snap])
-        self._mark_inc_dirty()  # plane shapes changed with capacity
 
     # -- fire/emit ---------------------------------------------------------
     def _rank_name(self) -> Optional[str]:
@@ -655,9 +624,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         # rows may be occupied by live FUTURE panes (row aliasing); that
         # the window has a pane at all, _fire_window has checked
         first = max(p_end - W, self._min_seen_pane)
-        if self._inc_enabled:
-            self._fire_incremental(p_end, first)
-            return
         rows = [(p % self._ring) for p in range(first, p_end)]
         # constant [W] shape so the fire program compiles once
         pane_rows = np.zeros(W, np.int32)
@@ -677,49 +643,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         """(table generation, block ordinal) a fire's health scalars are
         read under: carried from its dispatch to its drain."""
         return self._generation, self._block_seq
-
-    def _fire_incremental(self, p_end: int, first: int) -> None:
-        """O(capacity) fire: consume the running window view kept by the
-        pane-seal programs instead of re-merging all W ring rows. Dirty
-        state (restore, grow, boundary jump, write into a sealed pane)
-        forces a one-dispatch rebuild from the pane accumulators."""
-        from ...metrics.device import DEVICE_STATS
-
-        W, ring = self._window_panes, self._ring
-        L = self._agg.tree_size
-        rows = [(p % ring) for p in range(first, p_end)]
-        sub_row = np.int32((p_end - W) % ring)
-        sub_valid = np.bool_(p_end - W >= self._min_seen_pane)
-        if (self._inc_dirty or self._inc_next != p_end
-                or not (self._inc_wins or self._inc_trees)):
-            # padded to [ring] so the rebuild shape is W-independent
-            pane_rows = np.zeros(ring, np.int32)
-            pane_rows[:len(rows)] = rows
-            rows_valid = np.zeros(ring, bool)
-            rows_valid[:len(rows)] = True
-            pane_leaves = np.full(ring, L, np.int32)
-            pane_leaves[:len(rows)] = [p % L for p in range(first, p_end)]
-            view, self._inc_wins, self._inc_trees = self._agg.rebuild_inc(
-                self._state, pane_rows, rows_valid, pane_leaves,
-                sub_row, sub_valid)
-            rows_read = sealed = len(rows)
-        else:
-            view, self._inc_wins, self._inc_trees = self._agg.seal_inc(
-                self._state, self._inc_wins, self._inc_trees,
-                np.int32((p_end - 1) % ring), sub_row, sub_valid,
-                np.int32((p_end - 1) % L), np.int32((p_end - 1 - W) % L))
-            rows_read, sealed = (2 if bool(sub_valid) else 1), 1
-        outs = self._agg.fire_inc(self._state, view, self._rank_name(),
-                                  self._topk)
-        DEVICE_STATS.note_panes_sealed(sealed)
-        DEVICE_STATS.note_fire_merge_rows(rows_read)
-        self._inc_dirty = False
-        self._inc_next = p_end + 1
-        self._enqueue_fire((p_end, outs, self._taken(),
-                            time.perf_counter()))
-        if p_end - W >= self._min_seen_pane:
-            self._state = self._agg.retire_row(self._state,
-                                               (p_end - W) % self._ring)
 
     def _materialize(self, item: tuple, turn: str) -> None:
         p_end, outs, taken, t0, fire = item
@@ -850,10 +773,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         """Re-shard device-resident key-group state across a new mesh
         WITHOUT restarting the job: snapshot at the quiescent point, diff
         key-group ownership old->new, ship only the pages whose groups
-        change owner (checkpoint page format, digest-verified), install on
-        the new mesh, and rebuild the derived incremental planes
-        (`role="window"` — never shipped). Emits one causal trace tree
-        under the ``rescale/`` scope and feeds the migration counters.
+        change owner (checkpoint page format, digest-verified), and install
+        on the new mesh. Emits one causal trace tree under the
+        ``rescale/`` scope and feeds the migration counters.
 
         Because every sharded program is cache-keyed by local shard shape
         only (sharded_window.local_signature), a rescale that preserves
@@ -917,8 +839,6 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                 else:
                     self._build(list(self._agg.aggs),
                                 capacity=self._agg.capacity)
-                # derived incremental planes are rebuilt, never shipped
-                self._mark_inc_dirty()
                 reb.set_attribute("local_shapes_changed",
                                   self._agg.sig != old_sig)
             self._rescale_epoch += 1

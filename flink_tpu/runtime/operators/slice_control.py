@@ -305,14 +305,7 @@ class SliceControlPlane:
                 f"pane ring overflow: open span [{low},{max_pane}] exceeds "
                 f"ring {self._ring}; increase ring_size or reduce "
                 "watermark lag")
-        self._note_open_ingest(min_pane)
         self._fold(batch, keys, panes)
-
-    def _note_open_ingest(self, min_pane: int) -> None:
-        """Hook: the incremental fire engine invalidates its running
-        window accumulators when a batch writes into an already-sealed
-        pane (late-but-not-dropped records, or a min-pane decrease)."""
-        pass
 
     # -- firing ------------------------------------------------------------
     def process_watermark(self, watermark: Watermark) -> None:

@@ -174,8 +174,7 @@ def _differs(plane, ident) -> jax.Array:
     return plane != ident
 
 
-def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
-                  dropped):
+def reclaim_shard(sig: tuple, table, arrays: tuple, dropped):
     """The reclaim of ONE table and its planes, traceable and not jitted:
     the one-chip backend jits it as it is (``_reclaim_program``) and the
     mesh runs it on every shard under ``shard_map`` (``parallel/
@@ -183,17 +182,14 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     table rebuilt AT ITS OWN CAPACITY from the keys that still hold data,
     every plane re-seated onto the new slots, at fixed shapes. ``sig`` =
     tuple of (kind, dtype_str, shape) over ALL of the table's array
-    states, as ``_reset_row_program``'s; ``live_planes`` indexes the ones
-    that decide what lives (the pane-role ring planes). A plane is the
-    ``Halves`` of a 64-bit integer one (the pane-role ring planes of
-    both stacks) or one array (a float or 32-bit plane of either, and
-    the one-chip backend's window-role planes): told apart by what is
-    handed in, tested and moved word by word, and handed back as it
-    came.
+    states, as ``_reset_row_program``'s: ring planes every one, and each
+    says what lives. A plane is the ``Halves`` of a 64-bit integer one
+    or one array (a float or 32-bit plane): told apart by what is handed
+    in, tested and moved word by word, and handed back as it came.
 
     * ``reclaim.live``: a slot lives iff it is occupied and some ring row
-      of some ``live_planes`` plane differs from its aggregate's identity
-      there (a count plane alone would do: every fold counts). One sort
+      of some plane differs from its aggregate's identity there (a count
+      plane alone would do: every fold counts). One sort
       of the slots puts first the live keys that must move (they sit
       past their home slot, and a freed slot before them would hide them
       from the probe), then the live keys AT their home slot, then the
@@ -208,8 +204,8 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
       insert-only from there on. The slots no key landed in take the
       freed and empty old slots, in order, so that old slot -> new slot
       (``dest``) is a permutation.
-    * ``reclaim.remap``: every plane, ring and window role, row by row in
-      place: the row sorted by ``dest`` IS the row re-seated (a sort moves
+    * ``reclaim.remap``: every plane, row by row in place: the row
+      sorted by ``dest`` IS the row re-seated (a sort moves
       a ring row of 2^23 cells in tens of milliseconds where a gather by
       index costs half a second a 32-bit word on the v5e), identity where
       no key landed; a row that holds nothing but identities (a retired
@@ -228,9 +224,9 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
     with jax.named_scope("reclaim.live"):
         occupied = table != empty
         holds = jnp.zeros(C, bool)
-        for i in live_planes:
+        for (kind, _dt, _shape), a in zip(sig, arrays):
             holds = holds | _differs(
-                arrays[i], plane_identity(sig[i][0], arrays[i])).any(axis=0)
+                a, plane_identity(kind, a)).any(axis=0)
         live = occupied & holds
         home = (hash_keys_device(table) & jnp.uint32(C - 1)).astype(
             jnp.int32) == slot
@@ -278,10 +274,6 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
                         _permute(dest, r), ident),
                     lambda r: r, row)
 
-            if a.ndim == 1:
-                out.append(reseat(a))
-                continue
-
             def body(r, plane, reseat=reseat):
                 row = plane_map(
                     lambda words: jax.lax.dynamic_index_in_dim(
@@ -296,7 +288,7 @@ def reclaim_shard(sig: tuple, live_planes: tuple, table, arrays: tuple,
 
 
 @instrumented_program_cache("state.reclaim")
-def _reclaim_program(sig: tuple, live_planes: tuple):
+def _reclaim_program(sig: tuple):
     """One jitted reclaim per plane signature: ``reclaim_shard`` over the
     backend's table and ALL of its array states in a single fixed-shape
     dispatch. The planes are donated; the old table is not (a fire still
@@ -304,7 +296,7 @@ def _reclaim_program(sig: tuple, live_planes: tuple):
 
     @partial(jax.jit, donate_argnums=(1,))
     def reclaim(table, arrays: tuple, dropped):
-        return reclaim_shard(sig, live_planes, table, arrays, dropped)
+        return reclaim_shard(sig, table, arrays, dropped)
 
     return reclaim
 
@@ -373,29 +365,21 @@ def _dedup_first(table, present, last_ts, keys, valid, ts, ttl_ms):
 
 
 class _ArrayState:
-    __slots__ = ("name", "kind", "dtype", "ring", "array", "role")
+    __slots__ = ("name", "kind", "dtype", "ring", "array")
 
     def __init__(self, name: str, kind: str, dtype, ring: Optional[int],
-                 capacity: int, role: str = "pane"):
+                 capacity: int):
         self.name = name
         self.kind = kind
         self.dtype = dtype
         self.ring = ring
-        # role "pane" (default): source-of-truth pane accumulators — they
-        # snapshot, spill, retire and conform. role "window": DERIVED
-        # incremental-fire state (running window accumulators / merge-tree
-        # planes). Window planes follow slot remaps (rehash/growth) but are
-        # excluded from snapshots, the host spill tier, ring-row
-        # retirement and conform_ring — a restore simply rebuilds them
-        # from the pane planes.
-        self.role = role
-        # a pane-role ring plane of a 64-bit integer is STORED as its two
+        # a ring plane of a 64-bit integer is STORED as its two
         # 32-bit words (ops/segment_ops.Halves), on every platform: every
         # program takes and returns the words, and 64-bit values exist
         # only inside a program, of the rows or cells it has sliced
         shape = (ring, capacity) if ring else (capacity,)
         self.array = make_plane(kind, shape, dtype,
-                                stores_halves(dtype, ring, role))
+                                stores_halves(dtype, ring))
 
 
 def _plane_sig(states) -> tuple:
@@ -662,7 +646,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def _sync_mirror_inner(self) -> None:
         nb, bs = self._n_blocks, self._block
         self.last_snapshot_dma_bytes = 0
-        snap_states = self._snapshot_states()
+        snap_states = self._array_states.items()
         if self._mirror is None:
             # writable copies: device_get may return read-only views
             t = np.array(jax.device_get(self.table))
@@ -876,7 +860,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
     def _ensure_host_tier(self) -> HostTier:
         if self._host is None:
             self._host = HostTier(self.max_parallelism)
-        for name, st in self._snapshot_states():
+        for name, st in self._array_states.items():
             self._host.register(name, st.kind, np.dtype(jnp.dtype(st.dtype)),
                                 st.ring)
         return self._host
@@ -901,7 +885,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         host = self._ensure_host_tier()
         if sel.any():
             values = {}
-            for name, st in self._snapshot_states():
+            for name, st in self._array_states.items():
                 values[name] = _to_host(st.array)[..., slots_dev[sel]]
             host.absorb(keys_dev[sel], values)
         host.spilled_mask[np.asarray(groups, np.int64)] = True
@@ -985,18 +969,15 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             total += int(st.array.nbytes)
         return total
 
-    def tier_boundary(self) -> bool:
+    def tier_boundary(self) -> None:
         """Batch-boundary tiering step, called by the operator after the
         staged-spill drain (so nothing is in flight for any group):
         advance the decay cadence, queue promotion candidates on the
-        prefetch pipeline, and apply at most one staged payload. Returns
-        True when residency changed (a promotion landed) so the operator
-        can invalidate derived window planes."""
+        prefetch pipeline, and apply at most one staged payload."""
         if self._residency is None:
-            return False
+            return
         self._sync_touch_from_device()
         self._residency.on_boundary()
-        changed = False
         host = self._host
         if host is not None and host.active and self._prefetch is not None:
             cands = self._residency.promotion_candidates(
@@ -1006,11 +987,10 @@ class TpuKeyedStateBackend(KeyedStateBackend):
                 self._prefetch.request(cands)
             payload = self._prefetch.poll()
             if payload is not None:
-                changed = self.apply_promotion(payload)
+                self.apply_promotion(payload)
             self._residency.update_view(host.spilled_mask,
                                         host.group_counts())
         DEVICE_STATS.set_tier_hbm_bytes(self._hbm_bytes_in_use())
-        return changed
 
     def _stage_promotion(self, groups: np.ndarray) -> Optional[dict]:
         """Gather ``groups``' warm rows and upload the staged device
@@ -1081,7 +1061,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         self.table = new_table
         self._num_keys += n
         widx = jnp.where(payload["valid"], slots, self.capacity)
-        for name, st in self._snapshot_states():
+        for name, st in self._array_states.items():
             st.array = plane_map(
                 lambda a, v: a.at[..., widx].set(v, mode="drop"),
                 st.array, payload["values"][name])
@@ -1093,31 +1073,18 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         return True
 
     def register_array_state(self, name: str, kind: str, dtype,
-                             ring: Optional[int] = None,
-                             role: str = "pane") -> None:
+                             ring: Optional[int] = None) -> None:
         if name not in self._array_states:
             self._array_states[name] = _ArrayState(name, kind, dtype, ring,
-                                                   self.capacity, role)
-            if self._host is not None and role != "window":
+                                                   self.capacity)
+            if self._host is not None:
                 self._host.register(name, kind,
                                     np.dtype(jnp.dtype(dtype)), ring)
 
-    def has_array(self, name: str) -> bool:
-        return name in self._array_states
-
-    def drop_array_state(self, name: str) -> None:
-        self._array_states.pop(name, None)
-
-    def _snapshot_states(self):
-        """(name, state) pairs that participate in snapshots/mirror/spill —
-        everything except derived window-role planes."""
-        return [(n, st) for n, st in self._array_states.items()
-                if st.role != "window"]
-
     def get_array(self, name: str):
         """The plane as it is stored: one array, or the ``Halves`` (two
-        ``uint32`` arrays, high and low words) of a pane-role ring plane
-        of a 64-bit integer. ``shape`` and ``dtype`` read as the plane's
+        ``uint32`` arrays, high and low words) of a ring plane of a
+        64-bit integer. ``shape`` and ``dtype`` read as the plane's
         either way; ``np.asarray`` of a ``Halves`` joins on the host.
         A program takes what this hands out and ``set_array`` takes back
         what the program returns."""
@@ -1183,7 +1150,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         The host knows the retired row, so the snapshot mirror replays it
         without marking anything dirty on device."""
         ring_states = [st for st in self._array_states.values()
-                       if st.ring and st.role != "window"]
+                       if st.ring]
         if ring_states:
             outs = _reset_row_program(_plane_sig(ring_states))(
                 tuple(st.array for st in ring_states), np.int32(row))
@@ -1338,19 +1305,16 @@ class TpuKeyedStateBackend(KeyedStateBackend):
 
     def _reclaimable(self) -> bool:
         """Whether a reclaim can tell what lives: no HBM budget (cold
-        groups page out instead), and every pane-role state a ring plane
-        (a row-state plane has no pane that retires)."""
-        panes = [st for st in self._array_states.values()
-                 if st.role != "window"]
-        return (not self._budget and bool(panes)
-                and all(st.ring for st in panes))
+        groups page out instead), and every state a ring plane (a
+        row-state plane has no pane that retires)."""
+        states = self._array_states.values()
+        return (not self._budget and bool(states)
+                and all(st.ring for st in states))
 
     def _reclaim_call(self) -> tuple:
         """(the reclaim program of the current planes, its arguments)."""
         states = list(self._array_states.values())
-        live = tuple(i for i, st in enumerate(states)
-                     if st.ring and st.role != "window")
-        return (_reclaim_program(_plane_sig(states), live),
+        return (_reclaim_program(_plane_sig(states)),
                 (self.table, tuple(st.array for st in states),
                  self._dropped))
 
@@ -1409,7 +1373,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
 
     def reclaim(self, stage=None, wait: bool = True):
         """Free every slot whose key holds no data in any ring row of any
-        pane-role plane, keeping the capacity: what Flink's window
+        plane, keeping the capacity: what Flink's window
         operator does at a window's cleanup time (``clearAllState``), done
         for all keys at once when the table fills. ONE device program
         (``_reclaim_program``), dispatched here and not waited for: the
@@ -1474,7 +1438,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         identity (retired). No-op when sizes already match."""
         live = list(live_panes)
         for st in self._array_states.values():
-            if not st.ring or st.ring == ring or st.role == "window":
+            if not st.ring or st.ring == ring:
                 continue
             if len(live) > ring:
                 raise RuntimeError(
@@ -1677,7 +1641,7 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         keys = np.ascontiguousarray(keys[order])
         groups = np.ascontiguousarray(groups[order])
         states = {}
-        for name, st in self._snapshot_states():
+        for name, st in self._array_states.items():
             arr = self._mirror["arrays"][name]
             vals = arr[:, slots] if st.ring else arr[slots]
             if host_vals is not None:

@@ -42,7 +42,8 @@ def schema():
     return Schema(list(FIELDS))
 
 
-def make_op(n_devices: int = D, capacity: int = 1 << 8, **kw):
+def make_op(n_devices: int = D, capacity: int = 1 << 8, size: int = SIZE,
+            **kw):
     from flink_tpu.runtime.operators.device_window import AggSpec
     from flink_tpu.runtime.operators.mesh_window import \
         MeshWindowAggOperator
@@ -50,7 +51,7 @@ def make_op(n_devices: int = D, capacity: int = 1 << 8, **kw):
 
     kw.setdefault("device_batch", 32)
     return MeshWindowAggOperator(
-        SlidingEventTimeWindows.of(SIZE, PANE), "key",
+        SlidingEventTimeWindows.of(size, PANE), "key",
         [AggSpec("sum", "v", out_name="total"),
          AggSpec("max", "w", out_name="high"),
          AggSpec("min", "f", out_name="low")],

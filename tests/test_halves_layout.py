@@ -1,9 +1,9 @@
 """The STORED layout of a 64-bit ring plane of the one-chip backend (PR 42).
 
-A pane-role ring plane whose dtype is a 64-bit integer is kept on the
+A ring plane whose dtype is a 64-bit integer is kept on the
 device as two ``uint32`` arrays of the plane's shape, its high and its low
 words (``ops/segment_ops.Halves``), on every platform: the fold, the reset,
-the fire (both engines), the device-born step and the reclaim take the
+the fire, the device-born step and the reclaim take the
 words and hand them back, and a 64-bit value exists only inside a program,
 joined from the rows or cells it has sliced or gathered. Held here to
 numpy's int64 arithmetic: the words themselves, every program that takes
@@ -84,16 +84,16 @@ def test_unsigned_words_and_the_identities():
             assert (np.asarray(plane) == want).all()
 
 
-@pytest.mark.parametrize("dtype,ring,role,halves", [
-    (np.int64, 8, "pane", True), (np.uint64, 8, "pane", True),
-    (np.int32, 8, "pane", False), (np.float64, 8, "pane", False),
-    (np.int64, None, "pane", False), (np.int64, 8, "window", False)])
-def test_which_planes_are_stored_as_halves(dtype, ring, role, halves):
-    """A layout, not an option: decided by the plane's own dtype, shape
-    and role, the same on every platform."""
-    assert stores_halves(dtype, ring, role) == halves
+@pytest.mark.parametrize("dtype,ring,halves", [
+    (np.int64, 8, True), (np.uint64, 8, True),
+    (np.int32, 8, False), (np.float64, 8, False),
+    (np.int64, None, False), (np.uint32, 8, False)])
+def test_which_planes_are_stored_as_halves(dtype, ring, halves):
+    """A layout, not an option: decided by the plane's own dtype and
+    shape, the same on every platform."""
+    assert stores_halves(dtype, ring) == halves
     be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=64)
-    be.register_array_state("p", "sum", dtype, ring=ring, role=role)
+    be.register_array_state("p", "sum", dtype, ring=ring)
     plane = be.get_array("p")
     assert isinstance(plane, Halves) == halves
     assert plane.dtype == np.dtype(dtype)
@@ -193,51 +193,56 @@ def test_ring_fold_is_one_algorithm_for_both_layouts():
         np.testing.assert_array_equal(np.asarray(words), np.asarray(whole))
 
 
-PANE, WINDOW = 1000, 3
+PANE, RING = 1000, 8
 SCHEMA = Schema([("k", np.int64), ("v", np.int64)])
 
 
-def _stream(seed: int, n: int = 1024, batches: int = 8):
+def _stream(seed: int, n: int = 1024, batches: int = 8,
+            a_pane_each: bool = False):
+    """``batches`` equal cuts of 9 panes of rows in event-time order (a
+    batch then straddles a pane's edge), or one batch a pane (a window of
+    ring - 1 panes leaves the ring one open pane)."""
     rng = np.random.default_rng(seed)
     keys = rng.integers(0, 29, n).astype(np.int64) * 1_000_003 - 7
     vals = _prices(rng, n).astype(np.int64)
     ts = np.sort(rng.integers(0, 9 * PANE, n)).astype(np.int64)
-    cut = n // batches
-    return [(keys[i:i + cut], vals[i:i + cut], ts[i:i + cut])
-            for i in range(0, n, cut)]
+    edges = (np.searchsorted(ts, np.arange(10) * PANE) if a_pane_each
+             else np.arange(0, n + 1, n // batches))
+    return [(keys[i:j], vals[i:j], ts[i:j])
+            for i, j in zip(edges[:-1], edges[1:])]
 
 
-def _windows(batches) -> dict:
+def _windows(batches, window_panes: int) -> dict:
     """(key, window end) -> (count, sum, max, min), in Python integers."""
     want = {}
     for keys, vals, ts in batches:
         for k, v, t in zip(keys.tolist(), vals.tolist(), ts.tolist()):
             first = (t // PANE + 1) * PANE
-            for end in range(first, first + WINDOW * PANE, PANE):
+            for end in range(first, first + window_panes * PANE, PANE):
                 n, s, hi, lo = want.get((k, end), (0, 0, v, v))
                 want[(k, end)] = (n + 1, s + v, max(hi, v), min(lo, v))
     return want
 
 
 @pytest.mark.parametrize("born", ["host_born", "device_born"])
-@pytest.mark.parametrize("engine", ["full_merge", "incremental"])
-def test_the_operator_over_halves_equals_python_integers(engine, born):
+@pytest.mark.parametrize("window_panes", [3, RING - 1],
+                         ids=["hop3", "widest"])
+def test_the_operator_over_halves_equals_python_integers(window_panes, born):
     """COUNT (int64), SUM, MAX and MIN of an int64 column through the
-    window operator, HOP 3 s / 1 s: the full-merge fire and the
-    incremental engine (running accumulators, merge trees), batches
+    window operator, HOP 3 s / 1 s and the widest window the ring holds
+    (7 s / 1 s: the fire gathers seven rows of every word), batches
     uploaded from the host (probe, then `jit_fold`) and batches born on
     the device (one `jit_step`), row for row against Python's integers;
     SUMs pass 2^32 in both directions."""
     op = DeviceWindowAggOperator(
-        SlidingEventTimeWindows.of(WINDOW * PANE, PANE), "k",
+        SlidingEventTimeWindows.of(window_panes * PANE, PANE), "k",
         [AggSpec("count", out_name="n"), AggSpec("sum", "v", out_name="s"),
          AggSpec("max", "v", out_name="hi"),
          AggSpec("min", "v", out_name="lo")],
-        capacity=1 << 8, ring_size=8, emit_window_bounds=True,
-        defer_overflow=True, async_fire=True,
-        fire_incremental=engine == "incremental")
+        capacity=1 << 8, ring_size=RING, emit_window_bounds=True,
+        defer_overflow=True, async_fire=True)
     h = OneInputOperatorTestHarness(op, SCHEMA)
-    batches = _stream(11)
+    batches = _stream(11, a_pane_each=window_panes == RING - 1)
     for keys, vals, ts in batches:
         if born == "device_born":
             h.process_batch(DeviceRecordBatch(
@@ -252,7 +257,7 @@ def test_the_operator_over_halves_equals_python_integers(engine, born):
         assert isinstance(op._backend.get_array(name), Halves), name
     got = {(k, end): tuple(int(x) for x in row)
            for k, _start, end, *row in h.get_output()}
-    want = _windows(batches)
+    want = _windows(batches, window_panes)
     assert got == want
     assert max(abs(row[1]) for row in want.values()) > 2**32
 
@@ -297,7 +302,7 @@ def test_reclaim_of_halves_equals_the_int64_form_slot_for_slot(capacity):
     sig = tuple((kind, str(np.dtype(dtype)), (4, capacity))
                 for _n, kind, dtype in PLANES)
     want_table, want_planes, _d, want_counts = jax.jit(
-        lambda t, a, d: reclaim_shard(sig, (0, 1, 2), t, a, d))(
+        lambda t, a, d: reclaim_shard(sig, t, a, d))(
         table, whole, jnp.zeros((), jnp.int64))
     kept, freed = be.reclaim()
     assert (kept, freed) == tuple(int(x) for x in want_counts)

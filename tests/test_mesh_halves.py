@@ -4,8 +4,9 @@ as the one-chip backend's planes since PR 42), and nothing a user sees
 moves: a mesh job with an int64 SUM that carries across 2^32 in both
 directions, an int64 MAX over negative values and the hidden count,
 beside a float32 MIN whose plane stays one array, gives the rows of the
-per-record reference through step, full and incremental fire (seal and
-rebuild), retire, reclaim, grow, live rescale and snapshot -> restore;
+per-record reference through step, fire (at the fixture's width and at
+the widest window the ring holds), retire, reclaim, grow, live rescale
+and snapshot -> restore;
 its snapshots are the PARENT's byte for byte, and a snapshot the parent
 wrote restores into the words. Runs on the virtual CPU devices of
 ``conftest.py``.
@@ -21,7 +22,7 @@ import pytest
 from flink_tpu.core.records import RecordBatch
 from flink_tpu.metrics import DEVICE_STATS
 from flink_tpu.ops.hash_table import ensure_x64
-from flink_tpu.ops.segment_ops import Halves
+from flink_tpu.ops.segment_ops import AGG_INITS, Halves
 from flink_tpu.runtime import OneInputOperatorTestHarness
 
 ensure_x64()
@@ -56,17 +57,24 @@ def _rows(*harnesses) -> list:
                   for h in harnesses for k, s, e, t, hi, lo in h.get_output())
 
 
-def _reference(batches: list) -> list:
+#: the window's width in panes: the fixture's, and the widest its ring
+#: holds (the fire then gathers all but one ring row of every word)
+WIDTHS = pytest.mark.parametrize(
+    "window_panes", [make.SIZE // make.PANE, make.RING - 1],
+    ids=["hop4", "widest"])
+
+
+def _reference(batches: list, size: int = make.SIZE) -> list:
     """Record by record: every sliding window's SUM, MAX and MIN a key."""
     cols = {n: np.concatenate([c[n] for c, _ts in batches])
             for n, _dt in make.FIELDS}
     ts = np.concatenate([t for _c, t in batches])
     out = []
-    for end in range(make.PANE, int(ts.max()) + make.SIZE + 1, make.PANE):
-        sel = (ts >= end - make.SIZE) & (ts < end)
+    for end in range(make.PANE, int(ts.max()) + size + 1, make.PANE):
+        sel = (ts >= end - size) & (ts < end)
         for k in np.unique(cols["key"][sel]).tolist():
             mine = sel & (cols["key"] == k)
-            out.append((k, end - make.SIZE, end, int(cols["v"][mine].sum()),
+            out.append((k, end - size, end, int(cols["v"][mine].sum()),
                         int(cols["w"][mine].max()),
                         float(cols["f"][mine].min())))
     return sorted(out)
@@ -95,43 +103,40 @@ def _finish(*harnesses) -> None:
         h.operator.finish()
 
 
-@pytest.mark.parametrize("incremental", [False, True],
-                         ids=["fire_full", "fire_inc"])
+@WIDTHS
 @pytest.mark.parametrize("async_fire", [False, True], ids=["sync", "async"])
-def test_step_fire_retire_and_reclaim_give_the_reference_rows(incremental,
+def test_step_fire_retire_and_reclaim_give_the_reference_rows(window_panes,
                                                               async_fire):
     """40 panes of advancing keys through 4 tables of 256 slots: every
-    block steps, every pane fires (the incremental engine seals, and
-    rebuilds after each reclaim), the oldest pane retires, the tables
+    block steps, every pane fires, the oldest pane retires, the tables
     reclaim at their capacity; every window's rows are the reference's."""
     batches = make.batches(40)
+    size = window_panes * make.PANE
     before = DEVICE_STATS.snapshot()
     h = OneInputOperatorTestHarness(
-        make.make_op(async_fire=async_fire, fire_incremental=incremental),
-        schema=SCHEMA)
+        make.make_op(async_fire=async_fire, size=size), schema=SCHEMA)
     make.feed(h, batches)
     _assert_layout(h.operator)
     _finish(h)
     after = DEVICE_STATS.snapshot()
-    assert _rows(h) == _reference(batches)
+    assert _rows(h) == _reference(batches, size)
     assert h.operator._agg.capacity == 1 << 8
     assert h.operator.late_dropped == 0
     sweeps = "state_reclaim_sweeps_total"
     assert after[sweeps] - before[sweeps] >= 2
-    sealed = after["panes_sealed_total"] - before["panes_sealed_total"]
-    assert (sealed > 0) == incremental
 
 
-def test_a_late_write_into_a_sealed_pane_rebuilds_the_words_view():
-    """Out-of-order rows land in a pane the incremental engine has sealed:
-    it rebuilds its view from the planes' words, and the rows are exact."""
+def test_a_late_write_into_an_open_pane_is_in_every_window_still_open():
+    """Out-of-order rows land in a pane whose first windows have fired:
+    the fire reads the planes' words as they are, so every window still
+    open holds them, exactly."""
     batches = make.batches(12, seed=7)
     # the third batch's rows again, two panes late but inside the
     # windows still open
     cols, ts = batches[2]
     batches.insert(5, (cols, ts + 2 * make.PANE))
     h = OneInputOperatorTestHarness(
-        make.make_op(fire_incremental=True, capacity=1 << 9), schema=SCHEMA)
+        make.make_op(capacity=1 << 9), schema=SCHEMA)
     for cols, ts in batches:
         h.process_batch(RecordBatch(SCHEMA, cols, ts))
         h.process_watermark(int(ts.max()) - 3 * make.PANE)
@@ -140,14 +145,13 @@ def test_a_late_write_into_a_sealed_pane_rebuilds_the_words_view():
     assert h.operator.late_dropped == 0
 
 
-@pytest.mark.parametrize("incremental", [False, True],
-                         ids=["fire_full", "fire_inc"])
-def test_grow_rescale_and_restore_keep_every_cell(incremental):
+@WIDTHS
+def test_grow_rescale_and_restore_keep_every_cell(window_panes):
     """The host's side of the layout: a growth, a live rescale 4 -> 2 -> 4
     and a restore onto another mesh size split the planes with numpy and
     put the words on their shards; the job goes on exactly."""
     batches = make.batches(30, seed=5)
-    kw = dict(async_fire=True, fire_incremental=incremental)
+    kw = dict(async_fire=True, size=window_panes * make.PANE)
     h1 = OneInputOperatorTestHarness(make.make_op(**kw), schema=SCHEMA)
     make.feed(h1, batches[:6])
     op = h1.operator
@@ -166,25 +170,55 @@ def test_grow_rescale_and_restore_keep_every_cell(incremental):
     make.feed(h2, batches[21:], first=21)
     _finish(h2)
     h1.operator.finish()
-    assert _rows(h1, h2) == _reference(batches)
+    assert _rows(h1, h2) == _reference(batches, kw["size"])
     assert h2.operator._n_devices == 2
     assert h2.operator.late_dropped == 0
 
 
-@pytest.mark.parametrize("incremental", [False, True],
-                         ids=["fire_full", "fire_inc"])
+def _assert_holds_the_open_panes(snap: dict, fed: list,
+                                 window_panes: int) -> None:
+    """A snapshot's cells, record by record: a key's cell in the ring row
+    of a pane some window still to fire covers is that pane's SUM, MAX,
+    MIN and count of the key's rows; every other cell is the identity."""
+    meta, backend = snap["keyed"]["meta"], snap["keyed"]["backend"]
+    cols = {n: np.concatenate([c[n] for c, _ts in fed])
+            for n, _dt in make.FIELDS}
+    pane_of = np.concatenate([t for _c, t in fed]) // make.PANE
+    fold = {"total": ("v", np.sum), "high": ("w", np.max),
+            "low": ("f", np.min), "__count__": ("key", len)}
+    keys = backend["keys"]
+    first_open = meta["fired_boundary"] - window_panes
+    for name, kind, dtype in make.PLANES:
+        want = np.full((make.RING, len(keys)),
+                       np.asarray(AGG_INITS[kind](np.dtype(dtype))))
+        field, fn = fold[name]
+        for pane in range(max(first_open, 0), int(pane_of.max()) + 1):
+            for j, k in enumerate(keys.tolist()):
+                mine = cols[field][(pane_of == pane) & (cols["key"] == k)]
+                if len(mine):
+                    want[pane % make.RING, j] = fn(mine)
+        got = backend["states"][name]["values"]
+        assert got.dtype == want.dtype and (got == want).all(), name
+
+
+@WIDTHS
 def test_todays_snapshots_are_byte_equal_to_the_parents(parent_snapshots,
-                                                        incremental):
+                                                        window_panes):
     """The same job on the operator as it is now: both snapshots (the
-    second across two reclaims) hold the parent's bytes, names, dtypes
-    and shapes. The stored layout is the device's, not a format."""
-    _h, snaps = make.run_job(fire_incremental=incremental)
-    for snap in snaps:
+    second across two reclaims) hold names, dtypes and shapes as the
+    parent's did and, cell for cell, what the records say of the panes
+    still open; at the width the parent ran, the parent's bytes. The
+    stored layout is the device's, not a format."""
+    _h, snaps = make.run_job(size=window_panes * make.PANE)
+    for snap, fed in zip(snaps, make.CUTS):
         states = snap["keyed"]["backend"]["states"]
         assert {n: (st["kind"], st["dtype"], st["ring"])
                 for n, st in states.items()} \
             == {n: (kind, str(np.dtype(dt)), make.RING)
                 for n, kind, dt in make.PLANES}
+        _assert_holds_the_open_panes(snap, make.batches(fed), window_panes)
+    if window_panes * make.PANE != make.SIZE:
+        return
     mine = make.flatten(snaps)
     written = {name: theirs for name, theirs in parent_snapshots.items()
                if name[0] in "01"}

@@ -485,6 +485,7 @@ Q5_SCHEMA = Schema([("auction", np.int64), ("price", np.int64),
                     ("ts", np.int64)])
 Q5 = dict(n_keys=3000, n_events=1 << 14, batch=1 << 10, pane_ms=500,
           panes=4, topk=20)
+Q5_RING = 16
 
 
 def _q5_columns(idx):
@@ -501,7 +502,7 @@ def _q5_columns(idx):
             "ts": idx.astype(np.int64) // 4}
 
 
-def _run_q5(aggregate, traces=False, incremental=None, columns=None,
+def _run_q5(aggregate, traces=False, panes=None, columns=None,
             schema=None):
     from flink_tpu.api import StreamExecutionEnvironment
     from flink_tpu.core import WatermarkStrategy
@@ -514,8 +515,11 @@ def _run_q5(aggregate, traces=False, incremental=None, columns=None,
     env.set_state_backend("tpu")
     env.config.set(PipelineOptions.BATCH_SIZE, Q5["batch"])
     env.config.set(TraceOptions.ENABLED, traces)
-    if incremental is not None:
-        env.config.set("window.fire.incremental", incremental)
+    panes = panes or Q5["panes"]
+    if panes == Q5_RING - 1:
+        # the ring then holds ONE open pane: a watermark behind every
+        # batch (half a pane), not every 200 ms of the wall clock
+        env.config.set(PipelineOptions.AUTO_WATERMARK_INTERVAL, 0.0)
     ws = WatermarkStrategy.for_monotonous_timestamps() \
         .with_timestamp_column("ts")
     sink = chip_smoke._collecting_sink()
@@ -524,7 +528,7 @@ def _run_q5(aggregate, traces=False, incremental=None, columns=None,
                             timestamp_column="ts", watermark_strategy=ws)
                 .key_by("auction")
                 .window(SlidingEventTimeWindows.of(
-                    Q5["panes"] * Q5["pane_ms"], Q5["pane_ms"])))
+                    panes * Q5["pane_ms"], Q5["pane_ms"])))
     aggregate(windowed, [AggSpec("count", out_name="bids"),
                          AggSpec("sum", "price", out_name="revenue")]
               ).add_sink(sink, "collect")
@@ -534,13 +538,14 @@ def _run_q5(aggregate, traces=False, incremental=None, columns=None,
     return rows, env.last_job
 
 
-def _check_against_reference(rows):
+def _check_against_reference(rows, panes=None):
     """Every emitted row equals the benchmark's plain numpy reference
     (benchmarks/queries/q5_reference.py) and every window is a correct
     top-k; no window missing, none besides."""
     from benchmarks.queries.q5_reference import Q5Reference, check_window
 
     seen = set()
+    panes = panes or Q5["panes"]
 
     def on_window(end_ms, bids, rev):
         if not bids.any():
@@ -552,21 +557,26 @@ def _check_against_reference(rows):
                          rows["revenue"][sel], bids, rev, Q5["topk"])
         assert (v.rows_differ, v.topk_wrong) == (0, 0), (end_ms, v.detail)
         assert (rows["window_start"][sel]
-                == end_ms - Q5["panes"] * Q5["pane_ms"]).all()
+                == end_ms - panes * Q5["pane_ms"]).all()
 
-    ref = Q5Reference(Q5["n_keys"], Q5["pane_ms"], Q5["panes"], on_window)
+    ref = Q5Reference(Q5["n_keys"], Q5["pane_ms"], panes, on_window)
     cols = _q5_columns(np.arange(Q5["n_events"]))
     ref.feed(cols["auction"], cols["price"], cols["ts"])
     ref.close()
     assert seen == set(np.unique(rows["window_end"]).tolist())
 
 
+def _one_chip_q5(panes=None):
+    rows, _job = _run_q5(lambda w, aggs: w.device_aggregate(
+        aggs, capacity=1 << 13, ring_size=Q5_RING, emit_window_bounds=True,
+        emit_topk=Q5["topk"], defer_overflow=True, async_fire=True),
+        panes=panes)
+    return rows
+
+
 @pytest.fixture(scope="module")
 def q5_one_chip_rows():
-    rows, _job = _run_q5(lambda w, aggs: w.device_aggregate(
-        aggs, capacity=1 << 13, ring_size=16, emit_window_bounds=True,
-        emit_topk=Q5["topk"], defer_overflow=True, async_fire=True))
-    return rows
+    return _one_chip_q5()
 
 
 @pytest.mark.parametrize("n_devices", [4, 8])
@@ -605,33 +615,35 @@ def test_q5_with_a_hot_set_equals_reference_and_one_chip(
     assert after["d2h_bytes"] > before["d2h_bytes"]
 
 
-@pytest.mark.parametrize("incremental", [False, True],
-                         ids=["fire_full", "fire_inc"])
-def test_both_mesh_fires_select_the_one_chip_operators_rows(
-        incremental, q5_one_chip_rows):
-    """The threshold select behind both mesh fire programs (PR 31), four
-    devices: the fired rows are the one-chip operator's on the same
-    input, every ranked fire is counted with the passes its longest
-    shard walked (the bit length of the window's largest count), and a
-    COUNT rank never takes the sort."""
+@pytest.mark.parametrize("panes", [Q5["panes"], Q5_RING - 1],
+                         ids=["hop4", "widest"])
+def test_the_mesh_fire_selects_the_one_chip_operators_rows(
+        panes, q5_one_chip_rows):
+    """The threshold select behind the mesh fire program (PR 31), four
+    devices, at Q5's width and at the widest window the ring holds: the
+    fired rows are the one-chip operator's on the same input, every
+    ranked fire is counted with the passes its longest shard walked (the
+    bit length of the window's largest count), and a COUNT rank never
+    takes the sort."""
     import chip_smoke
     from flink_tpu.metrics import DEVICE_STATS
 
+    one_chip = (q5_one_chip_rows if panes == Q5["panes"]
+                else _one_chip_q5(panes))
     before = DEVICE_STATS.snapshot()
     rows, _job = _run_q5(lambda w, aggs: w.mesh_aggregate(
-        aggs, n_devices=4, capacity=1 << 11, ring_size=16,
+        aggs, n_devices=4, capacity=1 << 11, ring_size=Q5_RING,
         device_batch=Q5["batch"] // 4, emit_window_bounds=True,
-        emit_topk=Q5["topk"], async_fire=True), incremental=incremental)
+        emit_topk=Q5["topk"], async_fire=True), panes=panes)
     after = DEVICE_STATS.snapshot()
-    _check_against_reference(rows)
-    chip_smoke.check_same_answer(rows, q5_one_chip_rows)
+    _check_against_reference(rows, panes)
+    chip_smoke.check_same_answer(rows, one_chip)
     grew = {k: after[k] - before[k] for k in (
         "fire_selects_total", "fire_select_passes_total",
-        "fire_select_sort_total", "panes_sealed_total")}
+        "fire_select_sort_total")}
     ends = np.unique(rows["window_end"])
     assert grew["fire_selects_total"] == len(ends)
     assert grew["fire_select_sort_total"] == 0
-    assert (grew["panes_sealed_total"] > 0) == incremental
     top = [int(rows["bids"][rows["window_end"] == e].max()) for e in ends]
     assert grew["fire_select_passes_total"] == sum(
         t.bit_length() for t in top)

@@ -97,7 +97,7 @@ def test_the_sharded_reclaim_equals_the_one_chip_reclaim_on_each_shard(
     # its two words (signed ``halves:int64``)
     sig = tuple((a.kind, "halves:" + np.dtype(a.dtype).name,
                  (RING, agg.capacity)) for a in AGGS)
-    one_chip = _reclaim_program(sig, tuple(range(len(AGGS))))
+    one_chip = _reclaim_program(sig)
     assert counts.shape == (D, 2) and counts.dtype == np.int32
     for d in range(D):
         table, planes, dropped, kept_freed = one_chip(
@@ -141,7 +141,7 @@ def test_every_live_key_keeps_every_cell_and_no_dead_key_keeps_a_slot(
 # -- (b) the operator on a stream whose keys advance ------------------------
 
 SCHEMA = Schema([("key", np.int64), ("v", np.int64)])
-PANE, SIZE = 250, 1000
+PANE, SIZE, OP_RING = 250, 1000, 16
 
 
 def _advancing(n_batches: int, rows: int = 128, in_flight: int = 400,
@@ -160,12 +160,13 @@ def _advancing(n_batches: int, rows: int = 128, in_flight: int = 400,
     return out
 
 
-def _op(n_devices: int = D, capacity: int = 1 << 8, **kw):
+def _op(n_devices: int = D, capacity: int = 1 << 8, size: int = SIZE,
+        **kw):
     kw.setdefault("device_batch", 64)
     return MeshWindowAggOperator(
-        SlidingEventTimeWindows.of(SIZE, PANE), "key",
+        SlidingEventTimeWindows.of(size, PANE), "key",
         [AggSpec("sum", "v", out_name="result")], n_devices=n_devices,
-        capacity=capacity, ring_size=16, emit_window_bounds=True, **kw)
+        capacity=capacity, ring_size=OP_RING, emit_window_bounds=True, **kw)
 
 
 def _feed(h, batches, first: int = 0):
@@ -179,13 +180,13 @@ def _rows(*harnesses):
                   for h in harnesses for k, s, e, v in h.get_output())
 
 
-def _reference(batches):
+def _reference(batches, size: int = SIZE):
     keys, vals, ts = (np.concatenate(c) for c in zip(*batches))
     out = []
-    for end in range(PANE, int(ts.max()) + SIZE + 1, PANE):
-        sel = (ts >= end - SIZE) & (ts < end)
+    for end in range(PANE, int(ts.max()) + size + 1, PANE):
+        sel = (ts >= end - size) & (ts < end)
         for k in np.unique(keys[sel]).tolist():
-            out.append((k, end - SIZE, end,
+            out.append((k, end - size, end,
                         int(vals[sel & (keys == k)].sum())))
     return sorted(out)
 
@@ -194,29 +195,31 @@ def _sweeps() -> int:
     return DEVICE_STATS.snapshot()["state_reclaim_sweeps_total"]
 
 
-@pytest.mark.parametrize("incremental", [False, True],
-                         ids=["fire_full", "fire_inc"])
-def test_the_operator_reclaims_and_every_window_is_still_exact(incremental):
-    """Both fire engines: the incremental fire's derived planes are
-    indexed by slot, so it rebuilds them after every reclaim."""
-    batches = _advancing(40)
+@pytest.mark.parametrize("window_panes,capacity,n_batches", [
+    (SIZE // PANE, 1 << 8, 40), (OP_RING - 1, 1 << 9, 80)],
+    ids=["hop4", "widest"])
+def test_the_operator_reclaims_and_every_window_is_still_exact(
+        window_panes, capacity, n_batches):
+    """HOP 4 panes, and the widest window the ring holds (15 panes: a
+    key lives four times as long, so the tables are twice the size and
+    the stream twice as long)."""
+    batches = _advancing(n_batches)
+    size = window_panes * PANE
     before = DEVICE_STATS.snapshot()
     h = OneInputOperatorTestHarness(
-        _op(async_fire=True, fire_incremental=incremental), schema=SCHEMA)
+        _op(async_fire=True, size=size, capacity=capacity), schema=SCHEMA)
     _feed(h, batches)
     h.process_watermark(10**9)
     h.operator.finish()
     after = DEVICE_STATS.snapshot()
-    assert _rows(h) == _reference(batches)
+    assert _rows(h) == _reference(batches, size)
     op = h.operator
-    assert op._agg.capacity == 1 << 8 and op.late_dropped == 0
+    assert op._agg.capacity == capacity and op.late_dropped == 0
     assert after[RECLAIM[0]] - before[RECLAIM[0]] >= 2
     assert after[RECLAIM[2]] - before[RECLAIM[2]] > 0
     # more ids were bid on than the four tables hold under their limit
     assert len(np.unique(np.concatenate([b[0] for b in batches]))) \
-        > 0.6 * D * (1 << 8)
-    sealed = after["panes_sealed_total"] - before["panes_sealed_total"]
-    assert (sealed > 0) == incremental
+        > 0.6 * D * capacity
 
 
 @pytest.mark.parametrize("n_after", [4, 2])

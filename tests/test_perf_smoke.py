@@ -1,12 +1,11 @@
-"""Tier-1-safe performance contract smoke tests for the incremental fire
-engine: the timed tiny-Q5 run is recompile-free, the seal/fire program
-caches are window-width independent (one executable serves every W), and
-an incremental fire genuinely reads fewer pane rows than the full merge.
+"""Tier-1-safe performance contract smoke tests for the window fire: the
+timed tiny-Q5 run is recompile-free, the fire's program cache is window-
+width independent (one builder entry serves every W), and every fire of
+a stream at one width, partial windows included, runs ONE executable.
 
 Wall-clock ratios are NOT asserted here — they are hardware- and
-load-dependent; bench.py --fire-mode measures them (docs/PERFORMANCE.md
-records the reference numbers). These tests pin the structural facts the
-speedup rests on instead."""
+load-dependent; `python -m benchmarks.run` measures on the chip (PERF.md).
+These tests pin the structural facts the fire's cost rests on instead."""
 
 import numpy as np
 import pytest
@@ -15,26 +14,24 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from flink_tpu.core.records import Schema  # noqa: E402
-from flink_tpu.metrics import DEVICE_STATS  # noqa: E402
 from flink_tpu.runtime import OneInputOperatorTestHarness  # noqa: E402
 from flink_tpu.runtime.operators.device_window import (  # noqa: E402
-    AggSpec, DeviceWindowAggOperator, _fire_inc_program, _seal_program,
+    AggSpec, DeviceWindowAggOperator, _fire_program,
 )
 from flink_tpu.window import SlidingEventTimeWindows  # noqa: E402
 
 pytestmark = pytest.mark.perf
 
 SCHEMA = Schema([("k", np.int64), ("v", np.int64)])
+AGGS = (("sum", "sum_v"), ("min", "min_v"))
 
 
-def _drive(window_panes: int, inc: bool, steps: int = 24,
-           late: bool = True):
+def _drive(window_panes: int, steps: int = 24):
     op = DeviceWindowAggOperator(
         SlidingEventTimeWindows.of(window_panes * 1000, 1000), "k",
         [AggSpec("sum", "v", dtype=jnp.int64),
          AggSpec("min", "v", dtype=jnp.int64)],
-        capacity=128, ring_size=2 * window_panes + 6,
-        fire_incremental=inc)
+        capacity=128, ring_size=2 * window_panes + 6)
     h = OneInputOperatorTestHarness(op, schema=SCHEMA)
     rng = np.random.default_rng(11)
     t = 0
@@ -42,7 +39,7 @@ def _drive(window_panes: int, inc: bool, steps: int = 24,
         n = int(rng.integers(4, 16))
         h.process_elements(
             list(zip(rng.integers(0, 7, n), rng.integers(0, 99, n))),
-            list(rng.integers(max(0, t - 400) if late else t, t + 800, n)))
+            list(rng.integers(max(0, t - 400), t + 800, n)))
         t += 1000
         h.process_watermark(t)
     h.process_watermark(t + window_panes * 2000)
@@ -51,47 +48,37 @@ def _drive(window_panes: int, inc: bool, steps: int = 24,
     return rows
 
 
-def test_tiny_q5_incremental_recompile_free():
+def test_tiny_q5_recompile_free():
     """The acceptance invariant from ISSUE 8: after the warmup pass the
-    timed tiny-Q5 run in incremental mode compiles NOTHING — seal, fire
-    and coalesced-step dispatches all hit the program caches."""
+    timed tiny-Q5 run compiles NOTHING — step and fire dispatches all
+    hit the program caches."""
     import bench
 
-    report = bench.run_tiny_q5(n_keys=500, batch=1 << 11, n_batches=6,
-                               fire_mode="incremental")
+    report = bench.run_tiny_q5(n_keys=500, batch=1 << 11, n_batches=6)
     assert report["recompiles"] == 0
-    assert report["panes_sealed_total"] > 0
     assert report["emitted_rows"] > 0
-    assert report["fire_mode"] == "incremental"
 
 
 def test_program_cache_width_independent():
-    """Widening the window must NOT mint new seal/fire executables: the
-    program keys carry aggregate signatures and scalar traced indices,
-    never W, so the steady-state cache footprint is O(signatures)."""
-    _drive(5, inc=True)
-    seal0 = _seal_program.cache_info().currsize
-    fire0 = _fire_inc_program.cache_info().currsize
+    """Widening the window must NOT mint new fire builders: the program
+    key carries the aggregate signature and the top-k, never W, so the
+    builder cache's footprint is O(signatures)."""
+    assert _drive(5) > 0
+    fire0 = _fire_program.cache_info().currsize
     for w in (8, 12):
-        _drive(w, inc=True)
-    assert _seal_program.cache_info().currsize == seal0
-    assert _fire_inc_program.cache_info().currsize == fire0
+        assert _drive(w) > 0
+    assert _fire_program.cache_info().currsize == fire0
 
 
-def test_fire_merge_rows_read_reduced():
-    """At W=8 the full merge gathers ~W pane rows per fire while the
-    incremental engine reads the sealed view plus at most the new and
-    retiring panes — at least a 2x reduction in pane-plane traffic. The
-    stream is in-order here: a write into an already-sealed pane forces
-    a W-row rebuild by design (equivalence over late panes is covered in
-    test_incremental_fire.py)."""
-    before = DEVICE_STATS.snapshot().get("fire_merge_rows_read", 0)
-    rows_full = _drive(8, inc=False, late=False)
-    mid = DEVICE_STATS.snapshot().get("fire_merge_rows_read", 0)
-    rows_inc = _drive(8, inc=True, late=False)
-    after = DEVICE_STATS.snapshot().get("fire_merge_rows_read", 0)
-    full_read = mid - before
-    inc_read = after - mid
-    assert rows_full == rows_inc
-    assert 0 < inc_read
-    assert inc_read * 2 <= full_read
+def test_every_fire_of_a_width_runs_one_executable():
+    """A fire's pane rows are padded to [W] under a validity mask, so the
+    stream's first windows (fewer than W panes of data), its full ones
+    and its tail's all dispatch the executable the first fire compiled:
+    one entry a width in the jit's own cache, none a fire."""
+    fire_fn = _fire_program(AGGS, None, 64)._fn   # the jitted fire itself
+    _drive(6)
+    one_width = fire_fn._cache_size()
+    _drive(6, steps=40)
+    assert fire_fn._cache_size() == one_width
+    _drive(7)
+    assert fire_fn._cache_size() == one_width + 1

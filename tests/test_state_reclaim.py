@@ -57,9 +57,6 @@ def _seeded_backend(capacity: int, seed: int, wide=jnp.int64):
                               defer_overflow=True)
     for name, kind, dtype in PLANES:
         be.register_array_state(name, kind, dtype, ring=RING)
-    # a derived window-role plane, as the incremental fire keeps one
-    be.register_array_state("__count__.__win__", "count", jnp.int32,
-                            ring=None, role="window")
     n = int(0.62 * capacity)
     keys = rng.choice(1 << 40, size=n, replace=False).astype(np.int64) \
         - (1 << 39)
@@ -96,13 +93,7 @@ def _seeded_backend(capacity: int, seed: int, wide=jnp.int64):
         for m in model.values():
             for name, kind, dtype in PLANES:
                 m[name][row] = np.asarray(AGG_INITS[kind](jnp.dtype(dtype)))
-    # the window plane: any value per occupied slot, which must follow
-    win = np.where(np.asarray(be.table) != EMPTY_KEY,
-                   rng.integers(1, 1000, size=capacity), 0).astype(np.int32)
-    be.set_array("__count__.__win__", jnp.asarray(win))
-    win_of = {int(k): int(w) for k, w in zip(np.asarray(be.table), win)
-              if k != EMPTY_KEY}
-    return be, model, win_of
+    return be, model
 
 
 @pytest.mark.parametrize("wide", [jnp.int64, jnp.int32],
@@ -113,7 +104,7 @@ def test_reclaim_keeps_every_live_key_bit_equal_and_no_dead_one(capacity,
     from flink_tpu.ops.segment_ops import Halves
 
     PLANES = _planes(wide)
-    be, model, win_of = _seeded_backend(capacity, seed=capacity, wide=wide)
+    be, model = _seeded_backend(capacity, seed=capacity, wide=wide)
     assert [isinstance(be.get_array(name), Halves)
             for name, _k, _d in PLANES] == [False] + 2 * [wide == jnp.int64]
     live = {k for k, m in model.items() if m["__count__"].any()}
@@ -129,7 +120,6 @@ def test_reclaim_keeps_every_live_key_bit_equal_and_no_dead_one(capacity,
     assert set(table[table != EMPTY_KEY].tolist()) == live
     planes = {name: np.asarray(be.get_array(name))
               for name, _k, _d in PLANES}
-    win = np.asarray(be.get_array("__count__.__win__"))
     keys = np.array(sorted(model), np.int64)
     slots = np.asarray(lookup(be.table, jnp.asarray(keys)))
     for k, s in zip(keys.tolist(), slots.tolist()):
@@ -139,25 +129,22 @@ def test_reclaim_keeps_every_live_key_bit_equal_and_no_dead_one(capacity,
         assert s >= 0, k
         for name, col in model[k].items():
             assert (planes[name][:, s] == col).all(), (k, name)
-        assert win[s] == win_of[k]
-    # a freed slot holds the identity in every plane, window role too
+    # a freed slot holds the identity in every plane
     empty = table == EMPTY_KEY
     for name, kind, dtype in PLANES:
         assert (planes[name][:, empty]
                 == np.asarray(AGG_INITS[kind](jnp.dtype(dtype)))).all()
-    assert (win[empty] == 0).all()
     # a second reclaim finds nothing to free and moves nothing
     assert be.reclaim() == (len(live), 0)
     assert (np.asarray(be.table) == table).all()
     for name, arr in planes.items():
         assert (np.asarray(be.get_array(name)) == arr).all()
-    assert (np.asarray(be.get_array("__count__.__win__")) == win).all()
 
 
 def test_a_table_whose_keys_all_live_grows_as_it_did():
     """Fewer than a quarter of the occupied slots come free: the job's
     live set really is that large, and the answer is the old one."""
-    be, model, _win = _seeded_backend(1 << 10, seed=7)
+    be, model = _seeded_backend(1 << 10, seed=7)
     slots = be.slots_for_batch_device(jnp.asarray(
         np.array(sorted(model), np.int64)))
     be.fold_rings(slots, np.full(len(model), RING - 1), slots >= 0,

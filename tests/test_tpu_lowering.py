@@ -354,7 +354,7 @@ def _reclaim(devices):
     cap, ring = 1 << 23, 16
     sig = (("count", "int32", (ring, cap)),
            ("sum", "halves:int64", (ring, cap)))
-    reclaim = _reclaim_program(sig, (0, 1))
+    reclaim = _reclaim_program(sig)
     args = (spec((cap,), jnp.int64),
             tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
             spec((), jnp.int64))
@@ -788,7 +788,7 @@ def _q7_reclaim(devices):
 
     one = SingleDeviceSharding(devices[0])
     sig = _FOLD_SIGS["q7"]
-    reclaim = _reclaim_program(sig, (0, 1))
+    reclaim = _reclaim_program(sig)
     return _compiled("reclaim.q7", lambda: getattr(
         reclaim, "_fn", reclaim).lower(
         jax.ShapeDtypeStruct((_Q7_CAP,), jnp.int64, sharding=one),
@@ -1015,7 +1015,7 @@ def _one_chip_digest_programs(devices) -> dict:
     lowered["jit_reset"] = fn(_reset_row_program(q5)).lower(
         planes(q5), spec((), jnp.int32))
     for q, sig in sigs.items():
-        lowered[f"jit_reclaim.{q}"] = fn(_reclaim_program(sig, (0, 1))).lower(
+        lowered[f"jit_reclaim.{q}"] = fn(_reclaim_program(sig)).lower(
             spec((cap,), jnp.int64), planes(sig), spec((), jnp.int64))
     return {name: hashlib.sha256(low.as_text().encode()).hexdigest()
             for name, low in lowered.items()}
@@ -1097,7 +1097,7 @@ def _probe_digest_programs(devices) -> dict:
     sig = tuple((kind, dt, (ring, rows))
                 for kind, dt, (ring, _c) in _FOLD_SIGS["q5"])
     lowered["jit_reclaim.q5.handover"] = fn(
-        _reclaim_program(sig, (0, 1))).lower(
+        _reclaim_program(sig)).lower(
         spec((rows,), jnp.int64),
         tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
         spec((), jnp.int64))
