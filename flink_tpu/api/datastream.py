@@ -759,7 +759,14 @@ class WindowedStream:
         SURVEY §5.8's two-level plan. ``emit_topk``/``async_fire`` match
         device_aggregate: two-phase global top-k ranked on the first
         aggregate, fires emitting asynchronously with watermarks held
-        behind them."""
+        behind them. The rank aggregate's ``AggSpec.value_bits`` is the
+        job's promise to every shard's select, as on one chip: under the
+        plane's width (Q7's 43-bit packed word in an int64 MAX) the
+        select compiles no guard against a negative rank; a COUNT rank
+        is promised 63 bits whatever is declared (the mesh keeps COUNT
+        in int64), and a rank with no promise (64) walks guarded. It is
+        a word of the fire program's cache key, never of a shard's
+        signature; no other aggregate's ``value_bits`` is read here."""
         from ..runtime.operators.mesh_window import MeshWindowAggOperator
         if not isinstance(self.keyed.key_spec, str):
             raise ValueError("mesh aggregation needs a column key")

@@ -124,6 +124,7 @@ class DeviceStats:
         self._fire_selects = 0
         self._fire_select_passes = 0
         self._fire_select_sort = 0
+        self._fire_select_guarded = 0
         # ring fold accounting (PR 34; the mesh operator's blocks since
         # PR 36): host-born batches folded and the ring rows they held a
         # row for, which are the rows of each plane the fold slices,
@@ -444,19 +445,24 @@ class DeviceStats:
         with self._lock:
             return self._mesh_inserted_rows, self._mesh_stepped_rows
 
-    def note_fire_select(self, passes: int, sort: bool) -> None:
+    def note_fire_select(self, passes: int, sort: bool,
+                         guarded: bool = False) -> None:
         with self._lock:
             self._fire_selects += 1
             self._fire_select_passes += int(passes)
             self._fire_select_sort += bool(sort)
+            self._fire_select_guarded += bool(guarded)
 
     @property
-    def fire_select_counts(self) -> tuple[int, int, int]:
+    def fire_select_counts(self) -> tuple[int, int, int, int]:
         """(ranked fires of either window operator, select passes they
-        walked, those that took a fallback: a float rank)."""
+        walked, those that took a fallback: a float rank, those whose
+        select was compiled with the guard against a negative rank: a
+        signed integer rank with no ``value_bits`` promise under its
+        width)."""
         with self._lock:
             return (self._fire_selects, self._fire_select_passes,
-                    self._fire_select_sort)
+                    self._fire_select_sort, self._fire_select_guarded)
 
     def note_fold(self, ring_rows: int) -> None:
         with self._lock:
@@ -778,6 +784,7 @@ class DeviceStats:
                 "fire_selects_total": self._fire_selects,
                 "fire_select_passes_total": self._fire_select_passes,
                 "fire_select_sort_total": self._fire_select_sort,
+                "fire_select_guarded_total": self._fire_select_guarded,
                 "fold_batches_total": self._fold_batches,
                 "fold_ring_rows_total": self._fold_ring_rows,
                 "state_reclaim_sweeps_total": self._reclaim_sweeps,
@@ -891,7 +898,7 @@ class DeviceStats:
             self._mesh_steps = self._mesh_exchange_rounds = 0
             self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
-            self._fire_select_sort = 0
+            self._fire_select_sort = self._fire_select_guarded = 0
             self._fold_batches = self._fold_ring_rows = 0
             self._reclaim_sweeps = 0
             self._reclaim_kept = self._reclaim_freed = 0
@@ -1708,10 +1715,12 @@ def bind_device_metrics(registry) -> None:
     # ranked fire select, both window operators (prometheus:
     # flink_tpu_device_fire_selects_total /
     # flink_tpu_device_fire_select_passes_total /
-    # flink_tpu_device_fire_select_sort_total)
+    # flink_tpu_device_fire_select_sort_total /
+    # flink_tpu_device_fire_select_guarded_total)
     g.gauge("fire_selects_total", lambda: s.fire_select_counts[0])
     g.gauge("fire_select_passes_total", lambda: s.fire_select_counts[1])
     g.gauge("fire_select_sort_total", lambda: s.fire_select_counts[2])
+    g.gauge("fire_select_guarded_total", lambda: s.fire_select_counts[3])
     # ring fold of the host-born ingest, one chip or mesh (prometheus:
     # flink_tpu_device_fold_batches_total /
     # flink_tpu_device_fold_ring_rows_total)

@@ -42,7 +42,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["TopK", "threshold_topk", "masked_topk_radix",
+__all__ = ["TopK", "threshold_topk", "select_guarded", "masked_topk_radix",
            "masked_topk_sort", "masked_topk"]
 
 
@@ -66,6 +66,17 @@ def _to_uint64(v: jax.Array) -> jax.Array:
     # signed ints: flip the sign bit after widening
     return (v.astype(jnp.int64).astype(jnp.uint64)
             ^ jnp.uint64(1) << jnp.uint64(63))
+
+
+def select_guarded(dtype, value_bits: int) -> bool:
+    """Whether ``threshold_topk`` compiles its guard against a negative
+    rank for values of ``dtype`` under the promise ``value_bits``: a
+    signed integer that nothing promises to fit under its own width. The
+    host asks the same question of a fire it drains (``DEVICE_STATS.
+    fire_select_guarded_total``), so it is one definition."""
+    dt = jnp.dtype(dtype)
+    return bool(jnp.issubdtype(dt, jnp.signedinteger)
+                and value_bits >= 8 * dt.itemsize)
 
 
 class TopK(NamedTuple):
@@ -117,7 +128,7 @@ def threshold_topk(values: jax.Array, valid: jax.Array, k: int,
                     jnp.bool_(True))
     kk = jnp.minimum(jnp.int32(k), jnp.sum(valid, dtype=jnp.int32))
     width = 8 * dt.itemsize
-    guarded = jnp.issubdtype(dt, jnp.signedinteger) and value_bits >= width
+    guarded = select_guarded(dt, value_bits)
     wide = width > 32 and value_bits > 32
 
     def view(unsigned, flip=None):

@@ -353,21 +353,20 @@ def _fire_program(sig):
     return fire
 
 
-def _top_rows(agg_sig, state: ShardedWindowState, planes: dict,
-              emit: jax.Array, rank_name: str, topk: int, axis_name: str,
-              mesh: Mesh):
+def _top_rows(state: ShardedWindowState, planes: dict, emit: jax.Array,
+              rank_name: str, topk: int, axis_name: str, mesh: Mesh,
+              value_bits: int):
     """A ranked fire's tail: the keys and plane values of the global
     top-k by ``planes[rank_name]``, and how the select got there (int32
     [2]: the passes the longest shard's select walked, whether any shard
     took the sort), to ride to the host in the fire's one copy."""
-    # a COUNT cannot be negative, whatever its width: the select then
-    # compiles no guard and no sort (global_topk's value_bits)
-    is_count = any(name == rank_name and kind == "count"
-                   for name, kind, _ in agg_sig)
+    # ``value_bits`` is the rank's promise as ``ShardedWindowAgg.
+    # rank_bits`` settled it: under the plane's width every shard's
+    # select compiles no guard against a negative rank (a COUNT, 63; a
+    # job's own promise for its MAX, Q7's 43), at the width it does
     with jax.named_scope("fire.global"):
         _vals, flat_idx, ok, passes, fell_back = global_topk(
-            planes[rank_name], emit, topk, mesh, axis_name,
-            63 if is_count else 64)
+            planes[rank_name], emit, topk, mesh, axis_name, value_bits)
         keys = jnp.take(state.table.reshape(-1), flat_idx)
         res = {n: jnp.take(v.reshape(-1), flat_idx)
                for n, v in planes.items()}
@@ -376,7 +375,7 @@ def _top_rows(agg_sig, state: ShardedWindowState, planes: dict,
 
 
 def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
-                    axis_name: str, mesh: Mesh):
+                    axis_name: str, mesh: Mesh, value_bits: int = 64):
     """The jitted full fire on ``mesh`` (see _fire_full_program)."""
     _, agg_sig, _cap, _ring = sig
     aggs = _aggs_from_sig(agg_sig)
@@ -402,8 +401,8 @@ def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
             # a copy: an input handed back as it is would share the
             # table's buffer, which the next step donates
             return jnp.copy(state.table), emit, out, dropped, occ
-        keys, ok, res, select = _top_rows(agg_sig, state, out, emit,
-                                          rank_name, topk, axis_name, mesh)
+        keys, ok, res, select = _top_rows(state, out, emit, rank_name,
+                                          topk, axis_name, mesh, value_bits)
         return keys, ok, res, dropped, occ, select
 
     return fire
@@ -411,7 +410,7 @@ def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
 
 @instrumented_program_cache("mesh.fire_full")
 def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int],
-                       axis_name: str = DATA_AXIS):
+                       axis_name: str = DATA_AXIS, value_bits: int = 64):
     """ONE compiled program for the whole fire (the mesh twin of
     device_window._fire_program): pane merge for every aggregate + emit
     mask + optional two-phase global top-k (``global_topk``: a threshold
@@ -422,9 +421,13 @@ def _fire_full_program(sig, rank_name: Optional[str], topk: Optional[int],
     async device->host copy — never the full [D, capacity] table when a
     top-k is requested. Like the step, the returned dispatcher takes the
     concrete Mesh as its first argument and binds per mesh inside this
-    one cache entry (the select runs under that mesh's shard_map)."""
+    one cache entry (the select runs under that mesh's shard_map).
+    ``value_bits``, the rank's promise to the select, is a word of this
+    program's key beside the rank and k, never of ``sig``: it changes
+    what the select compiles, not a shard's shapes."""
     return _per_mesh(lambda mesh: _make_fire_full(sig, rank_name, topk,
-                                                  axis_name, mesh))
+                                                  axis_name, mesh,
+                                                  value_bits))
 
 
 @instrumented_program_cache("mesh.retire")
@@ -569,10 +572,27 @@ class ShardedWindowAgg:
         output shardings are the plan's)."""
         return _make_init(self.sig, self.plan.rules, self.mesh)
 
-    def fire_program(self, rank_name: Optional[str], topk: Optional[int]):
+    def rank_bits(self, rank_name: Optional[str],
+                  value_bits: Optional[int] = None) -> int:
+        """The promise a ranked fire makes its select (``threshold_topk``:
+        the rank is non-negative and under 2^bits). A COUNT cannot be
+        negative whatever its width: 63, whatever is declared for it (the
+        plane is int64 here; its 48-bit default is the one-chip packing's).
+        Any other rank keeps what the job declared (``AggSpec.value_bits``),
+        and 64, no promise, where it declared nothing."""
+        if rank_name is None:
+            return 64
+        kind = next(a.kind for a in self.aggs if a.name == rank_name)
+        if kind == "count":
+            return 63
+        return 64 if value_bits is None else int(value_bits)
+
+    def fire_program(self, rank_name: Optional[str], topk: Optional[int],
+                     value_bits: Optional[int] = None):
         """The jitted full fire ``fire_compact`` dispatches."""
         return _make_fire_full(self.sig, rank_name, topk,
-                               self.plan.axis_name, self.mesh)
+                               self.plan.axis_name, self.mesh,
+                               self.rank_bits(rank_name, value_bits))
 
     def step_program(self):
         """The jitted step ``step`` dispatches, with its two ownership
@@ -639,16 +659,19 @@ class ShardedWindowAgg:
 
     # ------------------------------------------------------------------
     def _fire_full_program(self, rank_name: Optional[str],
-                           topk: Optional[int]):
+                           topk: Optional[int],
+                           value_bits: Optional[int] = None):
         return _fire_full_program(self.sig, rank_name, topk,
-                                  self.plan.axis_name)
+                                  self.plan.axis_name,
+                                  self.rank_bits(rank_name, value_bits))
 
     def fire_compact(self, state: ShardedWindowState, pane_rows: np.ndarray,
                      rows_valid: np.ndarray, rank_name: Optional[str],
-                     topk: Optional[int]):
+                     topk: Optional[int], value_bits: Optional[int] = None):
         """Dispatch the fused fire; returns device outputs (see
-        _fire_full_program) without synchronizing."""
-        return self._fire_full_program(rank_name, topk)(
+        _fire_full_program) without synchronizing. ``value_bits``: what
+        the job promised of the rank aggregate (``rank_bits``)."""
+        return self._fire_full_program(rank_name, topk, value_bits)(
             self.mesh, state, jnp.asarray(pane_rows, jnp.int32),
             jnp.asarray(rows_valid))
 

@@ -1104,7 +1104,9 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         if self._topk is not None:
             keys_k, ok, results, dropped, occ, select = host
             self._apply_health(dropped, occ, generation, drain)
-            self._note_fire_select(drain, select)
+            self._note_fire_select(
+                drain, select, self._aggs[0].value_bits,
+                results[self._aggs[0].out_name].dtype)
             sel = np.asarray(ok)
             keys = np.asarray(keys_k)[sel]
             results = {n: np.asarray(v)[sel] for n, v in results.items()}

@@ -233,7 +233,11 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
     def _aggdefs(self, schema: Schema) -> list[AggDef]:
         """AggSpec -> AggDef list. Accumulator dtype follows the input
         column (sum over int64 stays int64, matching the host operator);
-        avg accumulates a float sum plane and divides by count at emit."""
+        avg accumulates a float sum plane and divides by count at emit.
+        An ``AggDef`` is a plane's shape and nothing else (it is a word of
+        ``local_signature``): the RANK aggregate's ``value_bits`` goes to
+        the fire beside the rank's name (``_fire``), and no other
+        aggregate's promise is read by anything on the mesh."""
         defs = []
         for a in self._aggs:
             if a.kind == "count":
@@ -615,6 +619,15 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             return None
         return self._plane_name(self._aggs[0])
 
+    def _rank_bits(self) -> int:
+        """The promise a ranked fire's select is compiled under: what the
+        job declared for the rank aggregate (``AggSpec.value_bits``), as
+        ``ShardedWindowAgg.rank_bits`` settles it (a COUNT rank: 63)."""
+        if self._topk is None:
+            return 64
+        return self._agg.rank_bits(self._rank_name(),
+                                   self._aggs[0].value_bits)
+
     def _window_holds_data(self, p_end: int) -> bool:
         return self._agg is not None and super()._window_holds_data(p_end)
 
@@ -631,7 +644,8 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         rows_valid = np.zeros(W, bool)
         rows_valid[:len(rows)] = True
         outs = self._agg.fire_compact(self._state, pane_rows, rows_valid,
-                                      self._rank_name(), self._topk)
+                                      self._rank_name(), self._topk,
+                                      self._rank_bits())
         self._enqueue_fire((p_end, outs, self._taken(),
                             time.perf_counter()))
         # retire the oldest pane of this window: no future window needs it
@@ -652,7 +666,8 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             if self._topk is not None:
                 keys_k, ok, results, dropped, occ, select = host
                 self._reading(dropped, occ, taken, drain)
-                self._note_fire_select(drain, select)
+                self._note_fire_select(drain, select, self._rank_bits(),
+                                       results[self._rank_name()].dtype)
                 sel = np.asarray(ok)
                 keys = np.asarray(keys_k)[sel]
                 res = {n: np.asarray(v)[sel] for n, v in results.items()}

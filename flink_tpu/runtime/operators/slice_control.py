@@ -164,14 +164,21 @@ class AsyncFireQueue:
                             total=(self.stage_s, "drain"))
 
     @staticmethod
-    def _note_fire_select(drain: Stage, select) -> None:
+    def _note_fire_select(drain: Stage, select, value_bits: int,
+                          rank_dtype) -> None:
         """A ranked fire's ``[passes, fell_back]``, as it came back in the
-        drain's one copy: counted, and an attribute of its window/Drain."""
+        drain's one copy: counted, and an attribute of its window/Drain,
+        beside the promise the fire's select was compiled under
+        (``value_bits``; whether that left the guard against a negative
+        rank of ``rank_dtype`` in the program is counted too)."""
         from ...metrics.device import DEVICE_STATS
+        from ...ops.topk import select_guarded
 
         passes, fell_back = (int(x) for x in select)
-        DEVICE_STATS.note_fire_select(passes, bool(fell_back))
+        DEVICE_STATS.note_fire_select(
+            passes, bool(fell_back), select_guarded(rank_dtype, value_bits))
         drain.set("select_passes", passes)
+        drain.set("value_bits", int(value_bits))
 
     def _emit_stage(self, fire: Stage, rows: int) -> Stage:
         """window/Emit: building the window's rows + output.emit."""

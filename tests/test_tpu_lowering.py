@@ -1229,3 +1229,165 @@ def test_session_programs_compile_at_the_benchmark_shape(v5e_devices, which):
             sizes[m.group(1)] = m.group(2)[:60]
     assert not [n for n, text in sizes.items()
                 if "16777216" in text or "67108864" in text], sizes
+
+
+# ---------------------------------------------------------------------------
+# PR 48: NEXmark Q7 on the mesh (q7-16m-mesh4-saturated): a sharded int64
+# MAX plane beside the hidden count plane, a ring of 8, one pane row a
+# window, k = 1, the rank promised 43 bits
+
+
+def _q7_mesh(devices):
+    """Q7's sharded aggregate (one int64 MAX; the state appends the hidden
+    int64 count plane) at the four-chip cell's shapes on a described v5e
+    2x2, and the step's arguments as shapes on it."""
+    from flink_tpu.parallel.sharded_window import AggDef, ShardedWindowAgg, \
+        ShardedWindowState
+
+    cap, batch, ring, D = 1 << 23, 1 << 16, 8, 4
+    mesh = Mesh(np.array(devices[:D]), ("data",))
+    agg = ShardedWindowAgg(mesh, [AggDef("best", "max", jnp.int64)],
+                           capacity=cap, ring=ring, max_parallelism=128)
+    sharded = NamedSharding(mesh, P("data"))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharded)
+
+    state = ShardedWindowState(
+        spec((D, cap), jnp.int64),
+        {a.name: _plane_spec("halves:int64", (D, ring, cap), sharded)
+         for a in agg.aggs},
+        spec((D,), jnp.int64))
+    args = (state, spec((D, batch), jnp.int64),
+            {"best": spec((D, batch), jnp.int64)},
+            spec((D, batch), jnp.int64), spec((D, batch), jnp.bool_))
+    return agg, sharded, args
+
+
+def _q7_mesh_program(devices, program: str):
+    """One program of the four-chip Q7 cell, compiled as the operator
+    dispatches it; ``jit_fire.unpromised`` is the fire of a job that
+    declared no ``value_bits``."""
+    from flink_tpu.parallel.sharded_window import _retire_program
+
+    agg, sharded, args = _q7_mesh(devices)
+    rep = NamedSharding(sharded.mesh, P())
+
+    def fire(value_bits):
+        return agg.fire_program("best", 1, value_bits).lower(
+            args[0], jax.ShapeDtypeStruct((1,), jnp.int32, sharding=rep),
+            jax.ShapeDtypeStruct((1,), jnp.bool_, sharding=rep)).compile()
+
+    def retire():
+        program = _retire_program(agg.sig)
+        return getattr(program, "_fn", program).lower(
+            args[0].accs,
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)).compile()
+
+    build = {
+        "jit_step": lambda: agg.step_program().lower(
+            *args, agg._base_start, agg._base_len).compile(),
+        "jit_fire": lambda: fire(43),
+        "jit_fire.unpromised": lambda: fire(None),
+        "jit_retire": retire,
+        "jit_reclaim": lambda: agg.reclaim_program().lower(
+            args[0]).compile(),
+    }[program]
+    return _compiled(f"q7.mesh.{program}", build)
+
+
+#: a chip's shard of the Q7 mesh state: the table and two [8, 2^23] planes
+_Q7_SHARD_BYTES = (1 << 23) * 8 * (1 + 2 * 8) + 8
+
+_Q7_MESH_REGIONS = {
+    "jit_step": {"mesh.plan", "mesh.sync", "exchange.pack",
+                 "exchange.collective", "probe.window0", "probe.tail",
+                 "fold.row", "fold.count", "fold.max"},
+    "jit_fire": {"fire.merge", "fire.global"},
+    "jit_fire.unpromised": {"fire.merge", "fire.global"},
+    "jit_retire": {"fire.retire"},
+    "jit_reclaim": {"reclaim.live", "reclaim.rehome", "reclaim.remap",
+                    "probe.window0", "probe.tail"},
+}
+
+
+@pytest.mark.parametrize("program", list(_Q7_MESH_REGIONS))
+def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
+                                                         program):
+    """Step, fire, retire and reclaim of `q7-16m-mesh4-saturated` for a
+    described v5e 2x2 ([4, 65536] rows against a 2^23-slot table and two
+    [4, 8, 2^23] int64 planes kept as their words; the fire over ONE pane
+    row, k = 1, a 64-bit rank): each compiles, fits a chip beside the
+    1.14 GB of state with room, names the regions the benchmark reads
+    (`fold.max`, `fire.merge`, `fire.global`, `fire.retire`) over every
+    instruction that moves 1 MiB or more, and takes no `s64` plane. With
+    the job's promise (43 bits) a shard's select holds no guard: the
+    compiled fire has not one `xor`; with none it xors every slot with
+    the flip word."""
+    import re
+
+    from flink_tpu.metrics.device import UNNAMED, classify_hlo
+
+    compiled = _q7_mesh_program(v5e_devices, program)
+    hlo = compiled.as_text()
+    assert f"HloModule {program.split('.')[0]}" in hlo
+    regions = classify_hlo(hlo)
+    big = _big_instructions(hlo)
+    unnamed = sorted(name for name in big if regions.get(name) == UNNAMED)
+    assert big and not unnamed, unnamed
+    assert _Q7_MESH_REGIONS[program] <= set(regions.values())
+    entry = next(line for line in hlo.splitlines()
+                 if line.startswith("ENTRY"))
+    assert "s64[1,8,8388608]" not in entry
+    assert entry.split("->")[0].count("u32[1,8,8388608]") == 4
+    mem = compiled.memory_analysis()
+    live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert live < 2e9, live
+    if program.startswith("jit_fire"):
+        guarded = program.endswith("unpromised")
+        assert bool(re.search(r" xor\(", hlo)) == guarded
+        # the walk is there, on the shard, and nothing wider than the
+        # D x k candidates crosses the interconnect or is sorted
+        assert "shard_map" in hlo and re.search(r" while\(", hlo)
+        assert max(_operand_elements(hlo, "sort"), default=0) <= 4
+        for op in ("all-reduce", "all-gather", "all-to-all",
+                   "collective-permute", "reduce-scatter"):
+            assert max(_operand_elements(hlo, op), default=0) <= 128, op
+        # the window's one row of each plane and the select's views
+        assert mem.temp_size_in_bytes < 48 * (1 << 23)
+    else:
+        assert mem.alias_size_in_bytes >= {
+            "jit_retire": _Q7_SHARD_BYTES - (1 << 23) * 8 - 4096,
+            "jit_reclaim": _Q7_SHARD_BYTES - (1 << 23) * 8 - 4096,
+            "jit_step": _Q7_SHARD_BYTES - 4096}[program]
+
+
+#: sha256 of `lower().as_text()` of the ranked mesh fire of M and F
+#: (q5-16m-mesh4 / q5-inflight-mesh4: a COUNT rank over [4, 16, 2^23]
+#: int64 planes, k = 1000, five pane rows) and of the same planes ranked
+#: by the SUM (no promise), on a described v5e 2x2, AT THE PARENT OF PR 48
+#: (commit 47c33d1). PR 48 carries the rank's `value_bits` to the fire;
+#: a COUNT rank keeps 63 and a rank with no promise 64, so these fires
+#: are the parent's text and load the parent's executables from the
+#: compile cache. A later change that MEANS to alter them writes its own
+_MESH_FIRE_DIGESTS_AT_47C33D1 = {
+    "bids":
+        "b273280c02a484594a1d418fcbe5a17727b7a2721732f331d99feee20397fac7",
+    "revenue":
+        "257da0c3fcdfd51a4c0943a523404bd48bfb8b1df370a595e495dd5b3802daf8",
+}
+
+
+@pytest.mark.parametrize("rank", list(_MESH_FIRE_DIGESTS_AT_47C33D1))
+def test_the_mesh_fire_of_m_and_f_lowers_to_what_it_lowered_to(v5e_devices,
+                                                               rank):
+    import hashlib
+
+    agg, sharded, args = _q5_mesh(v5e_devices[:4], 1 << 23, 1 << 16)
+    rep = NamedSharding(sharded.mesh, P())
+    text = agg.fire_program(rank, 1000).lower(
+        args[0], jax.ShapeDtypeStruct((5,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((5,), jnp.bool_, sharding=rep)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == _MESH_FIRE_DIGESTS_AT_47C33D1[rank]
