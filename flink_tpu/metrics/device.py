@@ -35,6 +35,19 @@ __all__ = ["DeviceStats", "DEVICE_STATS", "instrumented_program_cache",
            "program_regions"]
 
 
+#: the forms a window operator's hidden plane ``__count__`` takes: kind
+#: and width (``DeviceWindowAggOperator._count_plane``, ``ShardedWindowAgg``)
+COUNT_PLANE_FORMS = ("presence32", "count32", "count64")
+
+
+def count_plane_form(kind: str, dtype) -> str:
+    """A hidden plane's form, of ``COUNT_PLANE_FORMS``: its kind and its
+    width in bits."""
+    import numpy as np
+
+    return f"{kind}{8 * np.dtype(dtype).itemsize}"
+
+
 class DeviceStats:
     """Process-global compile + transfer counters (thread-safe)."""
 
@@ -125,6 +138,11 @@ class DeviceStats:
         self._fire_select_passes = 0
         self._fire_select_sort = 0
         self._fire_select_guarded = 0
+        # the form of a window operator's hidden plane ``__count__``,
+        # counted once an operator when its planes are built: a job that
+        # reads no count (no COUNT, no AVG) keeps a 32-bit presence
+        # plane, one that reads it a count of 32 or 64 bits
+        self._count_planes = dict.fromkeys(COUNT_PLANE_FORMS, 0)
         # ring fold accounting (PR 34; the mesh operator's blocks since
         # PR 36): host-born batches folded and the ring rows they held a
         # row for, which are the rows of each plane the fold slices,
@@ -464,6 +482,17 @@ class DeviceStats:
             return (self._fire_selects, self._fire_select_passes,
                     self._fire_select_sort, self._fire_select_guarded)
 
+    def note_count_plane(self, form: str) -> None:
+        with self._lock:
+            self._count_planes[form] += 1
+
+    @property
+    def count_plane_counts(self) -> dict[str, int]:
+        """Window operators of either stack by the form of their hidden
+        plane (``COUNT_PLANE_FORMS``)."""
+        with self._lock:
+            return dict(self._count_planes)
+
     def note_fold(self, ring_rows: int) -> None:
         with self._lock:
             self._fold_batches += 1
@@ -785,6 +814,8 @@ class DeviceStats:
                 "fire_select_passes_total": self._fire_select_passes,
                 "fire_select_sort_total": self._fire_select_sort,
                 "fire_select_guarded_total": self._fire_select_guarded,
+                **{f"count_plane_{form}_total": n
+                   for form, n in self._count_planes.items()},
                 "fold_batches_total": self._fold_batches,
                 "fold_ring_rows_total": self._fold_ring_rows,
                 "state_reclaim_sweeps_total": self._reclaim_sweeps,
@@ -899,6 +930,7 @@ class DeviceStats:
             self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
             self._fire_select_sort = self._fire_select_guarded = 0
+            self._count_planes = dict.fromkeys(COUNT_PLANE_FORMS, 0)
             self._fold_batches = self._fold_ring_rows = 0
             self._reclaim_sweeps = 0
             self._reclaim_kept = self._reclaim_freed = 0
@@ -1721,6 +1753,12 @@ def bind_device_metrics(registry) -> None:
     g.gauge("fire_select_passes_total", lambda: s.fire_select_counts[1])
     g.gauge("fire_select_sort_total", lambda: s.fire_select_counts[2])
     g.gauge("fire_select_guarded_total", lambda: s.fire_select_counts[3])
+    # the hidden plane's form, one count a window operator (prometheus:
+    # flink_tpu_device_count_plane_presence32_total / _count32_total /
+    # _count64_total)
+    for form in COUNT_PLANE_FORMS:
+        g.gauge(f"count_plane_{form}_total",
+                lambda form=form: s.count_plane_counts[form])
     # ring fold of the host-born ingest, one chip or mesh (prometheus:
     # flink_tpu_device_fold_batches_total /
     # flink_tpu_device_fold_ring_rows_total)

@@ -841,7 +841,8 @@ SPAN_INVENTORY: tuple = (
      "used by device_window / mesh_window _materialize — device_get of a "
      "fire's outputs + host selection/sort; child of Fire (stage span: "
      "turn — timer, batch or blocking: the kind of mailbox turn that took "
-     "the fire off the queue); "
+     "the fire off the queue; count_plane — presence32, count32 or "
+     "count64: the form of the operator's hidden plane); "
      "runtime/operators/device_session.py _materialize — device_get of "
      "one fire ROUND's counters and rows, copied since the round's "
      "dispatch (stage span: round, turn, fired, left: the ripe sessions "

@@ -24,6 +24,7 @@ __all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
            "Halves", "plane_map", "plane_take", "plane_row", "planes_joined",
            "planes_stored_like", "stores_halves", "identity_words",
            "plane_identity", "make_plane", "AGG_FOLDS", "AGG_MERGES",
+           "COUNT_KINDS",
            "make_accumulator", "segment_topk", "pow2_ceil"]
 
 
@@ -39,10 +40,16 @@ def _scatter_max(acc, idx, vals):
     return acc.at[idx].max(vals)
 
 
-#: kind -> (identity element factory, scatter fold, pane merge)
+#: kind -> (identity element factory, scatter fold, pane merge).
+#: ``presence`` is the hidden plane of a job that reads no count (no COUNT,
+#: no AVG): one 32-bit word a cell, 1 where a record of the key fell in
+#: the pane and 0 where none did. Every row folds a one into it as into a
+#: count, but as a saturating mark (``max``), so no number of records of
+#: one key in one pane can wrap it and hide the key's window.
 AGG_INITS = {
     "sum": lambda dtype: jnp.array(0, dtype),
     "count": lambda dtype: jnp.array(0, dtype),
+    "presence": lambda dtype: jnp.array(0, dtype),
     "min": lambda dtype: jnp.array(jnp.finfo(dtype).max
                                    if jnp.issubdtype(dtype, jnp.floating)
                                    else jnp.iinfo(dtype).max, dtype),
@@ -54,14 +61,25 @@ AGG_INITS = {
 AGG_FOLDS = {
     "sum": _scatter_add,
     "count": _scatter_add,
+    "presence": _scatter_max,
     "min": _scatter_min,
     "max": _scatter_max,
 }
+
+#: the kinds whose plane takes no input column: every row folds a one
+COUNT_KINDS = ("count", "presence")
+
+#: kind -> the region its scatter is named for where that is not the kind
+#: itself: the presence plane's fold is the step's ``fold.count`` work
+#: whatever its arithmetic (a trace must not find it under ``fold.max``,
+#: beside the job's own MAX)
+_FOLD_REGIONS = {"presence": "count"}
 
 #: kind -> pane-merge reduction (callable(x, axis=...))
 AGG_MERGES = {
     "sum": jnp.sum,
     "count": jnp.sum,
+    "presence": lambda x, axis: jnp.max(x, axis=axis),
     "min": lambda x, axis: jnp.min(x, axis=axis),
     "max": lambda x, axis: jnp.max(x, axis=axis),
 }
@@ -230,14 +248,21 @@ def make_plane(kind: str, shape: tuple[int, ...], dtype, halves: bool):
         lambda word: jnp.full(shape, word, jnp.uint32))
 
 
+def _fold_scope(kind: str):
+    """The scope a kind's scatter is named by (``_FOLD_REGIONS``)."""
+    kind = _FOLD_REGIONS.get(kind, kind)
+    return jax.named_scope(f"fold.{kind}")
+
+
 def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
                  values: jax.Array, valid: jax.Array) -> jax.Array:
     """Fold a batch into a flat accumulator: acc[flat_idx] op= values,
     masked by ``valid`` (invalid rows fold the identity into slot 0).
     The scatter and its masking sit in a scope named after the kind
-    (``fold.max`` under ``fold.scatter``), so a trace tells a max fold
-    from an add fold whatever program holds them."""
-    with jax.named_scope("fold.scatter"), jax.named_scope(f"fold.{kind}"):
+    (``fold.max`` under ``fold.scatter``; a presence plane's is
+    ``fold.count``), so a trace tells a max fold from an add fold
+    whatever program holds them."""
+    with jax.named_scope("fold.scatter"), _fold_scope(kind):
         identity = AGG_INITS[kind](acc.dtype)
         idx = jnp.where(valid, flat_idx, 0)
         vals = jnp.where(valid, values.astype(acc.dtype), identity)

@@ -51,8 +51,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..metrics.device import instrumented_program_cache
 from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
-from ..ops.segment_ops import AGG_INITS, AGG_MERGES, make_plane, \
-    plane_identity, plane_map, plane_take, ring_fold, stores_halves
+from ..ops.segment_ops import AGG_INITS, AGG_MERGES, COUNT_KINDS, \
+    make_plane, plane_identity, plane_map, plane_take, ring_fold, \
+    stores_halves
 from ..ops.topk import masked_topk_sort, threshold_topk
 from ..state.tpu_backend import reclaim_shard
 from .exchange import bucket_capacity, exchange_round, plan_exchange
@@ -66,11 +67,13 @@ __all__ = ["AggDef", "ShardedWindowState", "ShardedWindowAgg",
 
 
 class AggDef(NamedTuple):
-    """One aggregate accumulator: kind in sum|count|min|max.
+    """One aggregate accumulator: kind in sum|count|presence|min|max.
 
-    ``count`` needs no input column; others fold the column named ``name``
-    from the step's value dict. (avg = sum + count at fire, like the
-    reference's AggregateFunction.getResult — AggregateFunction.java:114.)
+    ``count`` and ``presence`` (``ops/segment_ops.COUNT_KINDS``: every row
+    folds a one) need no input column; others fold the column named
+    ``name`` from the step's value dict. (avg = sum + count at fire, like
+    the reference's AggregateFunction.getResult —
+    AggregateFunction.java:114.)
     """
     name: str
     kind: str
@@ -111,6 +114,13 @@ def local_signature(aggs: Sequence[AggDef], capacity: int, ring: int
 
 def _aggs_from_sig(agg_sig) -> list[AggDef]:
     return [AggDef(name, kind, np.dtype(dt)) for name, kind, dt in agg_sig]
+
+
+def _count_name(agg_sig) -> str:
+    """The plane a fire's emit mask reads (a key emits iff its merge is
+    positive): the first count, or the presence plane that stands in
+    where the caller declared none."""
+    return next(name for name, kind, _ in agg_sig if kind in COUNT_KINDS)
 
 
 # ----------------------------------------------------------------------
@@ -262,7 +272,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
                 ring_idx = (routed["__pane__"] % ring).astype(jnp.int32)
                 for a in aggs:
                     vals = (jnp.ones(slots.shape[0], a.dtype)
-                            if a.kind == "count" else routed[a.name])
+                            if a.kind in COUNT_KINDS else routed[a.name])
                     accs[a.name] = ring_fold(
                         a.kind, accs[a.name], ring_idx, slots, vals, ok)
             return (r + 1, table, accs, dropped + n_dropped,
@@ -279,7 +289,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
 
     skel = {"table": 0, "accs": {a.name: 0 for a in aggs},
             "dropped": 0, "keys": 0,
-            "cols": {a.name: 0 for a in aggs if a.kind != "count"},
+            "cols": {a.name: 0 for a in aggs if a.kind not in COUNT_KINDS},
             "panes": 0, "valid": 0}
     sp = match_partition_rules(rules, skel)
     state_specs = (sp["table"], sp["accs"], sp["dropped"])
@@ -333,7 +343,7 @@ def _ring_rows(plane, rows: jax.Array) -> jax.Array:
 def _fire_program(sig):
     _, agg_sig, _cap, _ring = sig
     aggs = _aggs_from_sig(agg_sig)
-    count_name = next(name for name, kind, _ in agg_sig if kind == "count")
+    count_name = _count_name(agg_sig)
 
     @jax.jit
     def fire(state: ShardedWindowState, pane_rows: jax.Array,
@@ -379,7 +389,7 @@ def _make_fire_full(sig, rank_name: Optional[str], topk: Optional[int],
     """The jitted full fire on ``mesh`` (see _fire_full_program)."""
     _, agg_sig, _cap, _ring = sig
     aggs = _aggs_from_sig(agg_sig)
-    count_name = next(name for name, kind, _ in agg_sig if kind == "count")
+    count_name = _count_name(agg_sig)
 
     @jax.jit
     def fire(state: ShardedWindowState, pane_rows, rows_valid):
@@ -535,8 +545,12 @@ class ShardedWindowAgg:
         if max_parallelism < self.n_dev:
             raise ValueError("max_parallelism must be >= mesh size")
         self.aggs = list(aggs)
-        if not any(a.kind == "count" for a in self.aggs):
-            self.aggs.append(AggDef("__count__", "count", jnp.int64))
+        if not any(a.kind in COUNT_KINDS for a in self.aggs):
+            # nothing declared reads a count (who reads one declares it:
+            # a COUNT, or the count an AVG divides by), so all the fire
+            # needs is whether a record of the key fell in the window: a
+            # 32-bit presence plane, folded as a saturating mark
+            self.aggs.append(AggDef("__count__", "presence", jnp.int32))
         names = [a.name for a in self.aggs]
         if len(set(names)) != len(names):
             raise ValueError(f"duplicate aggregate names: {names}")
@@ -576,8 +590,9 @@ class ShardedWindowAgg:
                   value_bits: Optional[int] = None) -> int:
         """The promise a ranked fire makes its select (``threshold_topk``:
         the rank is non-negative and under 2^bits). A COUNT cannot be
-        negative whatever its width: 63, whatever is declared for it (the
-        plane is int64 here; its 48-bit default is the one-chip packing's).
+        negative whatever its width: 63, whatever is declared for it (a
+        declared COUNT's plane is int64 here; its 48-bit default is the
+        one-chip packing's).
         Any other rank keeps what the job declared (``AggSpec.value_bits``),
         and 64, no promise, where it declared nothing."""
         if rank_name is None:
@@ -621,7 +636,8 @@ class ShardedWindowAgg:
              panes: jax.Array, valid: jax.Array
              ) -> tuple[ShardedWindowState, jax.Array, jax.Array]:
         """Fold one micro-batch. keys/panes/valid: [D, B]; cols: dict of
-        [D, B] value columns (one per non-count aggregate). Returns (new
+        [D, B] value columns (one per aggregate that takes a column: not a
+        count, not the presence plane). Returns (new
         state, rows folded, exchange rounds taken). ``state`` is DONATED:
         its buffers are deleted, only the returned state is live."""
         return self._step(self.mesh, state, keys, cols, panes, valid,

@@ -110,7 +110,7 @@ class FusedChain:
 
     def __init__(self, source, subtask: int, parallelism: int,
                  key_column: str, fold_sig: tuple, ring: int, pane: int,
-                 offset: int, dirty_block: int):
+                 offset: int, dirty_block: int, count_kind: str = "count"):
         self._src = source
         self._subtask = int(subtask)
         self._parallelism = int(parallelism)
@@ -120,12 +120,13 @@ class FusedChain:
         self._pane = int(pane)
         self._offset = int(offset)
         self._dirty_block = int(dirty_block)
+        self._count_kind = count_kind
         src = self._src
         self._cache_key = (
             src._gen, tuple((f.name, str(f.dtype)) for f in src.schema.fields),
             src._ts_col, self._subtask, self._parallelism, key_column,
             self._sig, self._ring, self._pane, self._offset,
-            self._dirty_block)
+            self._dirty_block, count_kind)
 
     # -- program construction ---------------------------------------------
     def _build(self, n: int) -> dict[str, Any]:
@@ -143,7 +144,7 @@ class FusedChain:
         sig = self._sig
         key_col = self._key_column
         step = _step_body(sig, self._ring, self._pane, self._offset,
-                          self._dirty_block, 0)
+                          self._dirty_block, 0, self._count_kind)
 
         def decode(iota, start, prev_last):
             # identical integer math to _DeviceDataGenReader._program —

@@ -36,8 +36,8 @@ import numpy as np
 from ...core.device_records import DeviceRecordBatch
 from ...core.elements import Watermark
 from ...core.records import MIN_TIMESTAMP, RecordBatch, Schema
-from ...metrics.device import DEVICE_STATS, instrumented_program_cache, \
-    pytree_nbytes
+from ...metrics.device import DEVICE_STATS, count_plane_form, \
+    instrumented_program_cache, pytree_nbytes
 from ...metrics.tracing import TRACER
 from ..faults import DeviceGuard, DeviceSegmentError, FAULTS, \
     fire_with_retries
@@ -92,13 +92,15 @@ def _select_topk(ranked, emit, topk: int, value_bits: int):
 
 
 def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
-               dirty_block: int, spill_maxp: int = 0):
+               dirty_block: int, spill_maxp: int = 0,
+               count_kind: str = "count"):
     """The UNJITTED ingest-step body — pane assignment + late masking +
     hash-table lookup-or-insert + every scatter-fold. ``_step_program``
     wraps it in a donated jit (the standalone per-batch dispatch); the
     fused-chain lowering (runtime/compiled.py) composes it with the
     source decode under ONE jit instead, so the certified
-    source→window prefix is a single XLA dispatch."""
+    source→window prefix is a single XLA dispatch. ``count_kind``: what
+    the hidden plane folds by (``_count_plane``)."""
     from ...ops.segment_ops import ring_fold
 
     spill = spill_maxp > 0
@@ -145,7 +147,7 @@ def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
         count = arrays["__count__"]
         out = dict(arrays)
         out["__count__"] = ring_fold(
-            "count", count, ring_idx, slots,
+            count_kind, count, ring_idx, slots,
             jnp.ones(keys.shape[0], count.dtype), ok)
         for kind, name, field in fold_sig:
             out[name] = ring_fold(kind, arrays[name], ring_idx, slots,
@@ -164,7 +166,8 @@ def _step_body(fold_sig: tuple, ring: int, pane: int, offset: int,
 
 @instrumented_program_cache("device_window.step")
 def _step_program(fold_sig: tuple, ring: int, pane: int, offset: int,
-                  dirty_block: int, spill_maxp: int = 0):
+                  dirty_block: int, spill_maxp: int = 0,
+                  count_kind: str = "count"):
     """ONE compiled program per batch for the device-resident ingest path
     (see ``_step_body`` for what runs inside), over columns that are
     ALREADY in HBM (DeviceRecordBatch). This is the whole per-batch hot
@@ -174,8 +177,8 @@ def _step_program(fold_sig: tuple, ring: int, pane: int, offset: int,
     State buffers are donated so XLA updates them in place instead of
     copying [ring, capacity] arrays every batch.
 
-    ``fold_sig`` is a tuple of (fold_kind, state_name, field). The count
-    plane ("__count__") folds implicitly.
+    ``fold_sig`` is a tuple of (fold_kind, state_name, field). The hidden
+    plane ("__count__") folds implicitly, a one a row, by ``count_kind``.
 
     ``spill_maxp`` > 0 enables the deferred-spill split (HBM budget +
     defer_overflow): records of spilled key groups — and failed inserts —
@@ -187,12 +190,13 @@ def _step_program(fold_sig: tuple, ring: int, pane: int, offset: int,
     """
     donate = (0, 1, 2, 3, 4, 5, 6) if spill_maxp > 0 else (0, 1, 2, 3, 4)
     return partial(jax.jit, donate_argnums=donate)(
-        _step_body(fold_sig, ring, pane, offset, dirty_block, spill_maxp))
+        _step_body(fold_sig, ring, pane, offset, dirty_block, spill_maxp,
+                   count_kind))
 
 
 @instrumented_program_cache("device_window.fire")
 def _fire_program(agg_sig: tuple, topk: Optional[int],
-                  topk_value_bits: int = 64):
+                  topk_value_bits: int = 64, count_kind: str = "count"):
     """ONE compiled program per (aggregate signature, top-k) covering the
     whole fire: masked pane-row merge for every aggregate + emit mask +
     optional device top-k + health scalars. Module-level and cached so
@@ -200,7 +204,9 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
     fire programs must never recompile per instance or per pane count
     (a compile costs seconds to a minute on the chip). ``pane_rows`` is
     therefore PADDED to the window width with a
-    validity mask instead of varying in shape."""
+    validity mask instead of varying in shape. ``count_kind``: what the
+    hidden plane's W rows merge by (``_count_plane``); whatever it is, a
+    key emits iff the merge is positive."""
     from ...ops.segment_ops import AGG_INITS, AGG_MERGES, plane_take
 
     @jax.jit
@@ -232,7 +238,7 @@ def _fire_program(agg_sig: tuple, topk: Optional[int],
                 sub = jnp.where(rows_valid[:, None], sub, ident)
                 return AGG_MERGES[kind](sub, axis=0)
 
-        count = merge("count", arrays["__count__"])
+        count = merge(count_kind, arrays["__count__"])
         with jax.named_scope("fire.merge"):
             emit = (table != jnp.int64(EMPTY_KEY)) & (count > 0)
             occ = (table != jnp.int64(EMPTY_KEY)).sum()
@@ -385,16 +391,19 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
         if not budget:
             # byte-denominated budget: convert to slots from the per-slot
             # footprint this operator allocates — the 8-byte table key
-            # plus every [ring, capacity] accumulator plane row (count
-            # plane + one value plane per non-count aggregate, avg's sum
-            # plane included), at 8 bytes per cell (the widest dtype the
-            # planes use; narrower dtypes just land under budget)
+            # plus every [ring, capacity] accumulator plane row: the
+            # hidden plane at its own width, and one value plane per
+            # non-count aggregate (avg's sum plane included) at 8 bytes
+            # per cell (the widest dtype the value planes use; narrower
+            # dtypes just land under budget)
             budget_bytes = int(ctx.config.get(
                 StateOptions.TPU_HBM_BUDGET_BYTES) or 0)
             if budget_bytes:
                 value_planes = sum(1 for a in self._aggs
                                    if a.kind != "count")
-                slot_bytes = 8 + (self._ring or 1) * 8 * (1 + value_planes)
+                slot_bytes = 8 + (self._ring or 1) * (
+                    np.dtype(self._count_plane()[1]).itemsize
+                    + 8 * value_planes)
                 budget = max(1, budget_bytes // slot_bytes)
         self._max_inflight = max(1, int(
             ctx.config.get(TaskOptions.MAX_INFLIGHT)))
@@ -417,18 +426,50 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             register_residency(
                 f"{ctx.task_name}/{ctx.subtask_index}",
                 self._backend.residency)
-        # count-plane width follows the declared result bound: a COUNT
-        # aggregate with value_bits <= 31 promises every per-window count
-        # fits int32, which halves the fold scatter + fire merge traffic
-        # on the [ring, capacity] plane (the whole-capacity passes are the
-        # memory-bound cost at 10M+ keys) and feeds the uint32 threshold
-        # select directly
+        self._backend.register_array_state(
+            "__count__", *self._count_plane(), ring=self._ring)
+        self._registered = False
+
+    def _count_plane(self) -> tuple:
+        """(kind, dtype) the hidden plane ``__count__`` is registered
+        with: what the job needs of it, decided by its aggregate kinds
+        alone. A job that READS the count (a COUNT emits it, an AVG
+        divides by it) keeps a count, and its width follows the declared
+        result bound: a COUNT with value_bits <= 31 promises every
+        per-window count fits int32, which halves the fold scatter + fire
+        merge traffic on the [ring, capacity] plane and feeds the uint32
+        threshold select directly; no such promise, int64. A job that
+        reads no count needs the plane for one thing, the fire's "did a
+        record of this key fall in this window": a 32-bit presence plane
+        (``ops/segment_ops.AGG_INITS``), whose fold is a saturating mark
+        and cannot wrap where an int32 count of 2^31 records would. (A
+        restored backend keeps the plane its snapshot holds, whatever
+        this says: ``_count_form``.)"""
+        if not any(a.kind in ("count", "avg") for a in self._aggs):
+            return "presence", jnp.int32
         cvb = min((a.value_bits for a in self._aggs if a.kind == "count"),
                   default=64)
-        count_dtype = jnp.int32 if cvb <= 31 else jnp.int64
-        self._backend.register_array_state("__count__", "count", count_dtype,
-                                           ring=self._ring)
-        self._registered = False
+        return "count", jnp.int32 if cvb <= 31 else jnp.int64
+
+    def _count_kind(self) -> str:
+        """What the hidden plane folds and merges by, as the backend
+        holds it (a savepoint written with an int64 count restores into
+        a job that would register a presence plane, and runs on)."""
+        return self._backend.array_kind("__count__")
+
+    def _count_args(self) -> tuple:
+        """The trailing ``count_kind`` argument of the step's and the
+        fire's builders: none for a count, so a job that reads the count
+        keeps the program keys (in memory and in the persistent AOT
+        cache) it had before the presence plane existed."""
+        kind = self._count_kind()
+        return () if kind == "count" else (kind,)
+
+    def _count_form(self) -> str:
+        """The hidden plane's form, for the window/Drain span and the
+        operator's gauge: ``presence32`` | ``count32`` | ``count64``."""
+        return count_plane_form(
+            self._count_kind(), self._backend.get_array("__count__").dtype)
 
     def enable_fused_chain(self, source, subtask: int,
                            parallelism: int) -> bool:
@@ -460,6 +501,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
                 self._backend.register_array_state(
                     a.out_name, a.kind, a.dtype, ring=self._ring)
         self._registered = True
+        DEVICE_STATS.note_count_plane(self._count_form())
         # every plane of the job exists now and no input has been taken:
         # the reclaim of these planes is built here, not when a reading
         # finds the table full (ROADMAP D14), and beside it the probe
@@ -718,7 +760,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             step = _step_program(sig, self._ring, self._pane, self._offset,
                                  self._backend.dirty_block_size,
                                  self._backend.max_parallelism if spill
-                                 else 0)
+                                 else 0, *self._count_args())
             arrays = {n: self._backend.get_array(n)
                       for n in self._fire_array_names()}
             from ...ops.segment_ops import pow2_ceil
@@ -803,7 +845,7 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             self._fused_chain = FusedChain(
                 source, subtask, parallelism, self._key_column,
                 self._fold_sig(), self._ring, self._pane, self._offset,
-                self._backend.dirty_block_size)
+                self._backend.dirty_block_size, *self._count_args())
         chain = self._fused_chain
         fo = np.int64(first_open if first_open is not None else MIN_TIMESTAMP)
 
@@ -1016,7 +1058,8 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
             fire_fn = _fire_program(
                 tuple((a.kind, a.out_name) for a in self._aggs), self._topk,
                 self._aggs[0].value_bits
-                if self._topk is not None and self._aggs else 64)
+                if self._topk is not None and self._aggs else 64,
+                *self._count_args())
             arrays = {n: self._backend.get_array(n)
                       for n in self._fire_array_names()}
             return fire_fn(self._backend.table, arrays,

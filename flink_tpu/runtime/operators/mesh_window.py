@@ -40,8 +40,10 @@ import numpy as np
 from ...core.keygroups import hash_batch, key_groups_for_hash_batch
 from ...core.records import RecordBatch, Schema
 from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, make_table
-from ...ops.segment_ops import AGG_INITS, Halves, plane_map, stores_halves
-from ...metrics.device import DEVICE_STATS, pytree_nbytes
+from ...ops.segment_ops import AGG_INITS, COUNT_KINDS, Halves, plane_map, \
+    stores_halves
+from ...metrics.device import DEVICE_STATS, count_plane_form, \
+    pytree_nbytes
 from ...metrics.tracing import TRACER
 from ...parallel.mesh import make_mesh, shard_ranges
 from ...parallel.sharded_window import (
@@ -248,6 +250,12 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                 dt = (jnp.dtype(np.dtype(schema.field(a.field).dtype))
                       if a.field in schema else jnp.dtype(a.dtype))
                 defs.append(AggDef(a.out_name, a.kind, dt))
+        kinds = {a.kind for a in self._aggs}
+        if "avg" in kinds and "count" not in kinds:
+            # an AVG divides by the count: who reads one declares it (a
+            # job that reads none gets ``ShardedWindowAgg``'s 32-bit
+            # presence plane in its place)
+            defs.append(AggDef("__count__", "count", jnp.int64))
         return defs
 
     @staticmethod
@@ -258,6 +266,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                ) -> None:
         # a reclaim in flight is of the state that goes: settle it first
         self._finish_reclaim(block=True, grow=False)
+        first = self._agg is None    # of this operator: not a growth
         self._agg = ShardedWindowAgg(
             self._mesh, defs, capacity=capacity or self._capacity,
             ring=self._ring, max_parallelism=self._max_parallelism,
@@ -269,10 +278,16 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         self._occ_known = 0
         self._pace = float(self._device_batch)
         self._state = self._agg.init_state()
+        if first:
+            DEVICE_STATS.note_count_plane(self._count_form())
         # with the step's and the fire's programs, before any input: a
         # reclaim then compiles nothing, wherever in the job it falls (a
         # job may have promised to build nothing once it is warm)
         self._agg.prepare_reclaim(self._state)
+
+    def _count_form(self) -> str:
+        plane = next(a for a in self._agg.aggs if a.kind in COUNT_KINDS)
+        return count_plane_form(plane.kind, plane.dtype)
 
     def _new_generation(self) -> None:
         self._generation += 1
@@ -687,8 +702,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         self._close_fire(fire, len(keys), d2h_bytes)
 
     def _emit_rows(self, p_end: int, keys: np.ndarray, host: dict) -> None:
-        count_name = next(a.name for a in self._agg.aggs
-                          if a.kind == "count")
+        # the count an AVG divides by (a job that reads none has none)
+        count_name = next((d.name for d in self._agg.aggs
+                           if d.kind == "count"), None)
         n = len(keys)
         start = (p_end - self._window_panes) * self._pane + self._offset
         end = p_end * self._pane + self._offset

@@ -161,7 +161,15 @@ class AsyncFireQueue:
         selection / sort, a child of the window's Fire."""
         return TRACER.stage("window", "Drain", parent=fire.context,
                             seq=fire.attrs["seq"], turn=turn,
+                            count_plane=self._count_form(),
                             total=(self.stage_s, "drain"))
+
+    def _count_form(self) -> str:
+        """The form of the operator's hidden plane ``__count__``
+        (``metrics/device.COUNT_PLANE_FORMS``): an attribute of every
+        window/Drain, so a trace says whether the job's fires read a
+        presence plane or a count."""
+        raise NotImplementedError
 
     @staticmethod
     def _note_fire_select(drain: Stage, select, value_bits: int,

@@ -32,7 +32,7 @@ __all__ = ["HostTier", "HOST_IDENT"]
 
 
 def _ident(kind: str, dtype: np.dtype):
-    if kind in ("sum", "count"):
+    if kind in ("sum", "count", "presence"):
         return dtype.type(0)
     if kind == "min":
         return (np.finfo(dtype).max if np.issubdtype(dtype, np.floating)
@@ -46,6 +46,9 @@ HOST_IDENT = _ident
 _FOLDS = {
     "sum": np.add.at,
     "count": np.add.at,
+    # the mark of a job that reads no count (ops/segment_ops.AGG_FOLDS):
+    # saturating here as on the device
+    "presence": np.maximum.at,
     "min": np.minimum.at,
     "max": np.maximum.at,
 }
@@ -53,6 +56,7 @@ _FOLDS = {
 _MERGES = {
     "sum": lambda v: v.sum(axis=0),
     "count": lambda v: v.sum(axis=0),
+    "presence": lambda v: v.max(axis=0),
     "min": lambda v: v.min(axis=0),
     "max": lambda v: v.max(axis=0),
 }

@@ -188,8 +188,9 @@ def reclaim_shard(sig: tuple, table, arrays: tuple, dropped):
     in, tested and moved word by word, and handed back as it came.
 
     * ``reclaim.live``: a slot lives iff it is occupied and some ring row
-      of some plane differs from its aggregate's identity there (a count
-      plane alone would do: every fold counts). One sort
+      of some plane differs from its aggregate's identity there (the
+      hidden plane alone would do: every fold counts into it, or marks
+      it where it is a presence plane). One sort
       of the slots puts first the live keys that must move (they sit
       past their home slot, and a freed slot before them would hide them
       from the probe), then the live keys AT their home slot, then the
@@ -1092,6 +1093,12 @@ class TpuKeyedStateBackend(KeyedStateBackend):
 
     def set_array(self, name: str, array) -> None:
         self._array_states[name].array = array
+
+    def array_kind(self, name: str) -> str:
+        """The aggregate kind plane ``name`` folds, merges and retires
+        by: what it was registered with, or after a restore what the
+        snapshot says it was written with."""
+        return self._array_states[name].kind
 
     def fold_batch(self, name: str, slots: jax.Array, values,
                    valid: jax.Array,

@@ -42,6 +42,13 @@ def _load_make():
 make = _load_make()
 SCHEMA = make.schema()
 
+#: the job's planes as the operator builds them NOW: the job declares no
+#: COUNT and no AVG, so since PR 49 its hidden plane is a 32-bit presence
+#: plane (1 where the parent's int64 count was positive), one array. The
+#: parent's snapshots (``make.PLANES``) still restore, and keep the int64
+#: count they were written with
+PLANES_NOW = make.PLANES[:-1] + (("__count__", "presence", np.int32),)
+
 
 @pytest.fixture(scope="module")
 def parent_snapshots():
@@ -80,13 +87,15 @@ def _reference(batches: list, size: int = make.SIZE) -> list:
     return sorted(out)
 
 
-def _assert_layout(op) -> None:
-    """Three planes of 64-bit integers as their words, sharded as a plane
-    is; the float plane one array."""
+def _assert_layout(op, planes=PLANES_NOW) -> None:
+    """The planes of 64-bit integers as their words, sharded as a plane
+    is; the float plane and the 32-bit presence plane one array each."""
     accs = op._state.accs
-    assert set(accs) == {name for name, _k, _dt in make.PLANES}
+    assert set(accs) == {name for name, _k, _dt in planes}
+    assert {a.name: a.kind for a in op._agg.aggs} \
+        == {name: kind for name, kind, _dt in planes}
     shape = (op._n_devices, make.RING, op._agg.capacity)
-    for name, _kind, dtype in make.PLANES:
+    for name, _kind, dtype in planes:
         plane = accs[name]
         wide = np.dtype(dtype).itemsize == 8
         assert isinstance(plane, Halves) == wide, name
@@ -185,10 +194,10 @@ def _assert_holds_the_open_panes(snap: dict, fed: list,
             for n, _dt in make.FIELDS}
     pane_of = np.concatenate([t for _c, t in fed]) // make.PANE
     fold = {"total": ("v", np.sum), "high": ("w", np.max),
-            "low": ("f", np.min), "__count__": ("key", len)}
+            "low": ("f", np.min), "__count__": ("key", lambda mine: 1)}
     keys = backend["keys"]
     first_open = meta["fired_boundary"] - window_panes
-    for name, kind, dtype in make.PLANES:
+    for name, kind, dtype in PLANES_NOW:
         want = np.full((make.RING, len(keys)),
                        np.asarray(AGG_INITS[kind](np.dtype(dtype))))
         field, fn = fold[name]
@@ -208,14 +217,17 @@ def test_todays_snapshots_are_byte_equal_to_the_parents(parent_snapshots,
     second across two reclaims) hold names, dtypes and shapes as the
     parent's did and, cell for cell, what the records say of the panes
     still open; at the width the parent ran, the parent's bytes. The
-    stored layout is the device's, not a format."""
+    stored layout is the device's, not a format. One plane differs, and
+    says so in the snapshot's own ``kind`` / ``dtype``: the hidden plane
+    of this COUNT-less job is a presence plane since PR 49, 1 in int32
+    exactly where the parent's int64 count is positive."""
     _h, snaps = make.run_job(size=window_panes * make.PANE)
     for snap, fed in zip(snaps, make.CUTS):
         states = snap["keyed"]["backend"]["states"]
         assert {n: (st["kind"], st["dtype"], st["ring"])
                 for n, st in states.items()} \
             == {n: (kind, str(np.dtype(dt)), make.RING)
-                for n, kind, dt in make.PLANES}
+                for n, kind, dt in PLANES_NOW}
         _assert_holds_the_open_panes(snap, make.batches(fed), window_panes)
     if window_panes * make.PANE != make.SIZE:
         return
@@ -224,6 +236,9 @@ def test_todays_snapshots_are_byte_equal_to_the_parents(parent_snapshots,
                if name[0] in "01"}
     assert list(mine) == list(written)
     for name, theirs in written.items():
+        if name.endswith("states/__count__"):
+            assert theirs.dtype == np.int64 and (theirs >= 0).all()
+            theirs = (theirs > 0).astype(np.int32)
         assert mine[name].dtype == theirs.dtype, name
         assert mine[name].shape == theirs.shape, name
         assert mine[name].tobytes() == theirs.tobytes(), name
@@ -243,7 +258,8 @@ def test_a_parents_snapshot_restores_into_the_words(parent_snapshots, which,
     snap = make.unflatten(parent_snapshots, which)
     h, again = make.restored_snapshot(
         snap, n_devices, capacity=1 << (8 if n_devices == 4 else 9))
-    _assert_layout(h.operator)
+    # the planes the snapshot holds, its int64 count among them
+    _assert_layout(h.operator, make.PLANES)
     assert again["keyed"]["meta"] == snap["keyed"]["meta"]
     theirs, mine = snap["keyed"]["backend"], again["keyed"]["backend"]
     order = np.argsort(theirs["keys"], kind="stable")

@@ -271,8 +271,11 @@ def test_the_operator_hands_the_ranks_promise_to_its_fire(value_bits,
                for d, k in zip(drains, (99, 199)))
     # the promise is no word of the shard's signature (JX505)
     assert "43" not in repr(op._agg.sig)
-    assert [(a.name, a.kind) for a in op._agg.aggs] \
-        == [("best", "max"), ("__count__", "count")]
+    # a MAX-only job reads no count: the hidden plane is a 32-bit presence
+    # plane, and every window/Drain says so
+    assert [(a.name, a.kind, np.dtype(a.dtype).name) for a in op._agg.aggs] \
+        == [("best", "max", "int64"), ("__count__", "presence", "int32")]
+    assert {d.attributes["count_plane"] for d in drains} == {"presence32"}
 
 
 # -- the cell's rehearsal, where the driver's run sees it ------------------

@@ -56,8 +56,11 @@ def _host_window_sums(keys, vals, panes):
 @pytest.fixture
 def agg8():
     mesh = make_mesh(8)
+    # these tests read the count, so they declare it (a caller that
+    # declares none gets a 32-bit presence plane: test_presence_plane.py)
     return mesh, ShardedWindowAgg(
-        mesh, [AggDef("price", "sum", jnp.float64)],
+        mesh, [AggDef("price", "sum", jnp.float64),
+               AggDef("__count__", "count", jnp.int64)],
         capacity=1 << 12, ring=8, max_parallelism=MP)
 
 
@@ -258,7 +261,8 @@ def test_skewed_batch_takes_more_rounds_and_loses_nothing(n_dev):
 
     B = 256
     agg = ShardedWindowAgg(
-        make_mesh(n_dev), [AggDef("price", "sum", jnp.float64)],
+        make_mesh(n_dev), [AggDef("price", "sum", jnp.float64),
+                           AggDef("__count__", "count", jnp.int64)],
         capacity=1 << 12, ring=4, max_parallelism=MP)
     pool = np.arange(20_000, dtype=np.int64)
     groups = key_groups_for_hash_batch(hash_batch(pool), MP)

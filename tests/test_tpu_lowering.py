@@ -250,13 +250,15 @@ def test_max_fold_compiles_at_the_benchmark_shape(v5e_devices):
 
 #: the ring planes of the two one-chip configurations as the backend
 #: signs them (kind, dtype, shape), 2^24 slots each: Q5's int32 count
-#: beside its int64 sum on a ring of 16, Q7's hidden int64 count beside
-#: its int64 max on a ring of 8. The int64 ones are STORED as their two
+#: beside its int64 sum on a ring of 16, Q7's hidden plane beside its
+#: int64 max on a ring of 8: the job reads no count, so since PR 49 that
+#: plane is a 32-bit presence plane (an int64 count until then). The int64
+#: ones are STORED as their two
 #: 32-bit words since PR 42 (`ops/segment_ops.Halves`), and signed so
 _FOLD_SIGS = {
     "q5": (("count", "int32", (16, 1 << 24)),
            ("sum", "halves:int64", (16, 1 << 24))),
-    "q7": (("count", "halves:int64", (8, 1 << 24)),
+    "q7": (("presence", "int32", (8, 1 << 24)),
            ("max", "halves:int64", (8, 1 << 24))),
 }
 
@@ -447,7 +449,7 @@ def test_mesh_reclaim_compiles_in_place_at_the_benchmark_shape(v5e_devices):
 
 
 def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
-                   panes: int):
+                   panes: int, count_kind: str = "count"):
     from flink_tpu.runtime.operators.device_window import _fire_program
 
     one = SingleDeviceSharding(devices[0])
@@ -455,7 +457,7 @@ def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
-    fire = _fire_program(agg_sig, k, value_bits)
+    fire = _fire_program(agg_sig, k, value_bits, count_kind)
     return _compiled(name, lambda: getattr(fire, "_fn", fire).lower(
         spec((cap,), jnp.int64),
         # an int64 ring plane is handed over as the backend stores it
@@ -467,10 +469,11 @@ def _one_chip_fire(devices, name: str, agg_sig, k, value_bits, cap, arrays,
 
 
 def _q7_fire(devices, value_bits: int, k: int):
-    plane = ((_Q7_RING, _Q7_CAP), jnp.int64)
+    shape = (_Q7_RING, _Q7_CAP)
     return _one_chip_fire(
         devices, f"fire.q7.{value_bits}.{k}", (("max", "best"),), k,
-        value_bits, _Q7_CAP, {"__count__": plane, "best": plane}, 1)
+        value_bits, _Q7_CAP, {"__count__": (shape, jnp.int32),
+                              "best": (shape, jnp.int64)}, 1, "presence")
 
 
 @pytest.mark.parametrize("k", [1, 1000])
@@ -479,7 +482,8 @@ def _q7_fire(devices, value_bits: int, k: int):
 def test_max_ranked_fire_compiles_at_the_benchmark_shape(v5e_devices,
                                                          value_bits, k):
     """The one-chip fire of q7-10m-saturated (a `max` rank over a
-    [8, 2^24] int64 plane beside the int64 count plane, one pane row) with
+    [8, 2^24] int64 plane beside the 32-bit presence plane, one pane row)
+    with
     the 43 bits the query module promises AND with the default 64, which
     is what `AggSpec("max", "x")` gets. The no-promise cases fail at the
     parent of PR 33: the guard was the radix walk as a third `lax.switch`
@@ -500,7 +504,7 @@ def test_max_ranked_fire_compiles_at_the_benchmark_shape(v5e_devices,
     assert re.search(r" while\(", hlo)         # the compare-and-count walk
     select = jax.tree_util.tree_leaves(compiled.out_info)[-1]
     assert select.shape == (2,) and select.dtype == jnp.int32
-    # beside 2.28 GB of state: the merged row, the views, the scans
+    # beside 1.74 GB of state: the merged row, the views, the scans
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -783,7 +787,8 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
 
 def _q7_reclaim(devices):
     """The backend's reclaim at q7-10m-saturated's shapes: 2^24 slots
-    under two `[8, 2^24]` planes, both stored as words."""
+    under two `[8, 2^24]` planes, the MAX stored as words beside the
+    32-bit presence plane."""
     from flink_tpu.state.tpu_backend import _reclaim_program
 
     one = SingleDeviceSharding(devices[0])
@@ -847,7 +852,8 @@ _ONE_CHIP_PLANE_PROGRAMS = {
 def test_no_one_chip_program_splits_or_joins_a_plane(v5e_devices, program):
     """Every program that takes a ring plane of the one-chip backend, FOR
     the v5e at X's shapes (`[16, 2^24]` int64 SUM beside the int32 COUNT)
-    and Q's (two `[8, 2^24]` int64 planes), read through the map the
+    and Q's (an `[8, 2^24]` int64 MAX beside the int32 presence plane),
+    read through the map the
     program gives of itself (`metrics/device.classify_hlo`, what
     `program_regions` serves and `step_x64_ms` / `fire_x64_ms` read): the
     regions `x64.split` / `x64.join` hold no instruction over a plane.
@@ -951,30 +957,33 @@ def test_no_mesh_program_splits_or_joins_a_plane(v5e_devices, program):
 #: stack's stored layout and must not touch this stack: `ring_fold` and
 #: `reclaim_shard` are shared, and handed what the one-chip backend hands
 #: them they trace what they traced. A later change that MEANS to alter a
-#: one-chip program writes its own digests here.
+#: one-chip program writes its own digests here: PR 49 did for the three
+#: `.q7` programs (the hidden plane of the COUNT-less Q7 job is a 32-bit
+#: presence plane folded by a scatter-max, where it was an int64 count);
+#: the Q5 ones are still the text of 4081619.
 _ONE_CHIP_DIGESTS_AT_4081619 = {
     "jit_fold.q5":
         "fb346eaa0dfce0cfbe1a75ae84dd78c96d885bbcab2fcd0c8c19b2ce129e5d59",
     "jit_fold.q7":
-        "1427d30fcd0fb5ebd9b8bbe805b9f72da387dbee377b6d84a72509fcef0433dc",
+        "63c4c2cc153cc81669f8f51e18ee57e3f8d324a2aff7c4f2453724725dc6ca40",
     "jit_fire_fn.q5":
         "9da6a9579af24094a0730af2551359ea6214dc479836b3ed6d68c9c05bd0ca3f",
     "jit_fire_fn.q7":
-        "3380eca9de098280a35143cd7d86438d2c717b73c1b0aa205eb0afb42c3141ca",
+        "2722b805f84a17a72eb051a26894b4ca1fab966cb29eadb99c412b3a032978c4",
     "jit_reset":
         "e8e4d0355a1a73cec64b87a924a87ab6ad0261ad0c4ec239394270940560f1f9",
     "jit_reclaim.q5":
         "ff1098ef55b64a7a2ef6b53fc5cef6690cd9be715ab677d150311275abd4a21c",
     "jit_reclaim.q7":
-        "18c9051f3d5cfbfbc683f74a4f478d02fa105a7e40f1591925c8f07a5cfadb74",
+        "4cb23bc7fc21c2f81f6c72a0d1d056e9b406b1855964eb9695e3e06aef3ec8d5",
 }
 
 
 def _one_chip_digest_programs(devices) -> dict:
     """name -> sha256 of the StableHLO of the one-chip programs that take
     ring planes, at 2^10 slots and 256 rows in the backend's own layouts
-    (Q5: int32 COUNT beside the int64 SUM's words; Q7: two int64 planes'
-    words)."""
+    (Q5: int32 COUNT beside the int64 SUM's words; Q7: the int32 presence
+    plane beside the int64 MAX's words)."""
     import hashlib
 
     from flink_tpu.runtime.operators.device_window import _fire_program
@@ -1006,7 +1015,8 @@ def _one_chip_digest_programs(devices) -> dict:
             ("q5", (("count", "bids"), ("sum", "revenue")),
              ("__count__", "revenue"), 1000, 48, 5),
             ("q7", (("max", "best"),), ("__count__", "best"), 1, 43, 1)):
-        lowered[f"jit_fire_fn.{q}"] = fn(_fire_program(agg_sig, k, bits)).lower(
+        lowered[f"jit_fire_fn.{q}"] = fn(_fire_program(
+            agg_sig, k, bits, sigs[q][0][0])).lower(
             spec((cap,), jnp.int64),
             {n: _plane_spec(dt, shape, one)
              for n, (_k, dt, shape) in zip(names, sigs[q])},
@@ -1233,14 +1243,15 @@ def test_session_programs_compile_at_the_benchmark_shape(v5e_devices, which):
 
 # ---------------------------------------------------------------------------
 # PR 48: NEXmark Q7 on the mesh (q7-16m-mesh4-saturated): a sharded int64
-# MAX plane beside the hidden count plane, a ring of 8, one pane row a
-# window, k = 1, the rank promised 43 bits
+# MAX plane beside the hidden plane (a 32-bit presence plane since PR 49:
+# the job reads no count), a ring of 8, one pane row a window, k = 1, the
+# rank promised 43 bits
 
 
 def _q7_mesh(devices):
     """Q7's sharded aggregate (one int64 MAX; the state appends the hidden
-    int64 count plane) at the four-chip cell's shapes on a described v5e
-    2x2, and the step's arguments as shapes on it."""
+    plane, 32 bits of presence) at the four-chip cell's shapes on a
+    described v5e 2x2, and the step's arguments as shapes on it."""
     from flink_tpu.parallel.sharded_window import AggDef, ShardedWindowAgg, \
         ShardedWindowState
 
@@ -1255,7 +1266,11 @@ def _q7_mesh(devices):
 
     state = ShardedWindowState(
         spec((D, cap), jnp.int64),
-        {a.name: _plane_spec("halves:int64", (D, ring, cap), sharded)
+        # each plane as the state stores it: the int64 MAX as its words,
+        # the int32 presence plane as the one array it is
+        {a.name: _plane_spec(
+            ("halves:" if np.dtype(a.dtype).itemsize == 8 else "")
+            + np.dtype(a.dtype).name, (D, ring, cap), sharded)
          for a in agg.aggs},
         spec((D,), jnp.int64))
     args = (state, spec((D, batch), jnp.int64),
@@ -1296,8 +1311,9 @@ def _q7_mesh_program(devices, program: str):
     return _compiled(f"q7.mesh.{program}", build)
 
 
-#: a chip's shard of the Q7 mesh state: the table and two [8, 2^23] planes
-_Q7_SHARD_BYTES = (1 << 23) * 8 * (1 + 2 * 8) + 8
+#: a chip's shard of the Q7 mesh state: the table, the [8, 2^23] int64 MAX
+#: and the [8, 2^23] int32 presence plane
+_Q7_SHARD_BYTES = (1 << 23) * (8 + 8 * 8 + 8 * 4) + 8
 
 _Q7_MESH_REGIONS = {
     "jit_step": {"mesh.plan", "mesh.sync", "exchange.pack",
@@ -1315,10 +1331,11 @@ _Q7_MESH_REGIONS = {
 def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
                                                          program):
     """Step, fire, retire and reclaim of `q7-16m-mesh4-saturated` for a
-    described v5e 2x2 ([4, 65536] rows against a 2^23-slot table and two
-    [4, 8, 2^23] int64 planes kept as their words; the fire over ONE pane
-    row, k = 1, a 64-bit rank): each compiles, fits a chip beside the
-    1.14 GB of state with room, names the regions the benchmark reads
+    described v5e 2x2 ([4, 65536] rows against a 2^23-slot table, a
+    [4, 8, 2^23] int64 MAX kept as its words and the int32 presence plane
+    of a job that reads no count; the fire over ONE pane row, k = 1, a
+    64-bit rank): each compiles, fits a chip beside the 0.87 GB of state
+    with room, names the regions the benchmark reads
     (`fold.max`, `fire.merge`, `fire.global`, `fire.retire`) over every
     instruction that moves 1 MiB or more, and takes no `s64` plane. With
     the job's promise (43 bits) a shard's select holds no guard: the
@@ -1339,7 +1356,9 @@ def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
     entry = next(line for line in hlo.splitlines()
                  if line.startswith("ENTRY"))
     assert "s64[1,8,8388608]" not in entry
-    assert entry.split("->")[0].count("u32[1,8,8388608]") == 4
+    # the MAX's two words and the hidden plane as ONE 32-bit operand
+    assert entry.split("->")[0].count("u32[1,8,8388608]") == 2
+    assert entry.split("->")[0].count("s32[1,8,8388608]") == 1
     mem = compiled.memory_analysis()
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -1361,6 +1380,41 @@ def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
             "jit_retire": _Q7_SHARD_BYTES - (1 << 23) * 8 - 4096,
             "jit_reclaim": _Q7_SHARD_BYTES - (1 << 23) * 8 - 4096,
             "jit_step": _Q7_SHARD_BYTES - 4096}[program]
+
+
+def _count_scatters(hlo: str) -> list:
+    """The result types of the scatters that lie under ``fold.count``."""
+    import re
+
+    return [m.group(1) for line in hlo.splitlines()
+            if "fold.scatter/fold.count" in line
+            for m in [re.search(r"= (\(.*?\)|\S+) scatter\(", line)] if m]
+
+
+@pytest.mark.parametrize("program", ["jit_fold.q7", "jit_step.q7_mesh"])
+def test_the_presence_plane_folds_as_one_32_bit_scatter(v5e_devices,
+                                                        program):
+    """Q's fold and Z's step at the cells' shapes: the hidden plane of the
+    COUNT-less Q7 job is a 32-bit operand, and what folds it, named
+    `fold.count` whatever its arithmetic, is a scatter over ONE `s32` row:
+    no two-word (variadic `u32`, `u32`) scatter of an int64 count lies
+    under `fold.count` any more, and the job's MAX keeps its own under
+    `fold.max`."""
+    import re
+
+    compiled = (_host_born_fold(v5e_devices, "q7") if program == "jit_fold.q7"
+                else _q7_mesh_program(v5e_devices, "jit_step"))
+    hlo = compiled.as_text()
+    rows = 1 << (24 if program == "jit_fold.q7" else 23)
+    scatters = _count_scatters(hlo)
+    assert scatters and all(t.startswith(f"s32[{rows}]{{")
+                            for t in scatters), scatters
+    # the folds' two-word scatters (the mesh step has others: its send
+    # buffers', the probe's claim of int64 keys): the MAX's, nobody else's
+    wide = [line for line in hlo.splitlines()
+            if "/fold.scatter/" in line and re.search(
+                r"= \(u32\[\d+\]\S*, u32\[\d+\]\S*\) scatter\(", line)]
+    assert wide and all("fold.scatter/fold.max" in line for line in wide)
 
 
 #: sha256 of `lower().as_text()` of the ranked mesh fire of M and F
