@@ -15,15 +15,21 @@ Two shapes of the same exchange live here:
   may hash to one shard), so ONE collective always suffices but every
   receiver folds n_dev*B rows. Per-device cost grows linearly with the
   mesh.
-* ``plan_exchange`` + ``exchange_round`` — the capacity-bounded form the
-  sharded window step uses: buckets are cut into rounds of ``cap`` rows
-  per destination and the step loops rounds until the DEEPEST bucket
-  across the mesh is drained (`lax.pmax` of the local round counts, so
-  every device runs the same trip count and the collectives stay
-  uniform). A uniform batch takes one round of ~B/n_dev-deep buckets —
-  per-device fold width stays O(B) as the mesh grows; a fully skewed
-  batch degrades to ceil(B/cap) rounds, the old worst case, but never
-  drops a record.
+* ``plan_exchange`` + ``order_payload`` + ``exchange_round`` — the
+  capacity-bounded form the sharded window step uses: buckets are cut
+  into rounds of ``cap`` rows per destination and the step loops rounds
+  until the DEEPEST bucket across the mesh is drained (`lax.pmax` of the
+  local round counts, so every device runs the same trip count and the
+  collectives stay uniform). A uniform batch takes one round of
+  ~B/n_dev-deep buckets — per-device fold width stays O(B) as the mesh
+  grows; a fully skewed batch degrades to ceil(B/cap) rounds, the old
+  worst case, but never drops a record. A round is packed BY POSITION,
+  with no scatter: the plan's stable sort leaves bucket ``d`` as the
+  contiguous run ``ordered[offsets[d] : offsets[d] + counts[d]]``, so
+  lane ``l`` of destination ``d`` in round ``r`` is row
+  ``offsets[d] + r*cap + l`` of the ordered column — one ``cap``-row
+  slice a destination a column — and is valid iff
+  ``l < counts[d] - r*cap``; the lanes past a bucket's end are zeroed.
 
 Invalid (padding) rows are routed to a virtual overflow destination and
 vanish in both forms.
@@ -36,8 +42,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["keyby_exchange", "plan_exchange", "exchange_round",
-           "ExchangePlan"]
+__all__ = ["keyby_exchange", "plan_exchange", "exchange_round", "ExchangePlan"]
 
 
 def keyby_exchange(axis_name: str, n_dev: int, dest: jax.Array,
@@ -85,14 +90,14 @@ class ExchangePlan(NamedTuple):
     """Routing plan for the capacity-bounded exchange (see module doc).
 
     order:    [B] int32 — stable sort permutation grouping rows by dest
-    sd:       [B] int32 — destination of each ordered row (n_dev = padding)
-    rank:     [B] int32 — position of each ordered row within its bucket
+    counts:   [n_dev] int32 — rows of the local batch bound for each dest
+    offsets:  [n_dev] int32 — where each dest's run starts in the order
     n_rounds: []  int32 — LOCAL round count; `lax.pmax` it across the
               axis before looping so every device runs the same trips
     """
     order: jax.Array
-    sd: jax.Array
-    rank: jax.Array
+    counts: jax.Array
+    offsets: jax.Array
     n_rounds: jax.Array
 
 
@@ -114,50 +119,60 @@ def plan_exchange(dest: jax.Array, valid: jax.Array, n_dev: int,
     Call INSIDE shard_map. `cap` must be a static int (shapes depend on
     it); `bucket_capacity` picks a good default.
     """
-    B = dest.shape[0]
     d = jnp.where(valid, dest, jnp.int32(n_dev))
     # Every reduction names int32: under x64 (which the state path turns
     # on) argsort/sum/cumsum widen to int64, and the caller's pmax of
     # n_rounds would then be an s64 max all-reduce, which the TPU refuses
     # ("Supported lowering only of Sum all reduce").
     order = jnp.argsort(d, stable=True).astype(jnp.int32)
-    sd = d[order]
     counts = jnp.sum(jax.nn.one_hot(d, n_dev + 1, dtype=jnp.int32), axis=0,
-                     dtype=jnp.int32)
-    offsets = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32),
-         jnp.cumsum(counts, dtype=jnp.int32)[:-1]])
-    rank = jnp.arange(B, dtype=jnp.int32) - offsets[sd]
-    deepest = jnp.max(counts[:n_dev])
-    n_rounds = (deepest + jnp.int32(cap - 1)) // jnp.int32(cap)
-    return ExchangePlan(order, sd, rank, n_rounds)
+                     dtype=jnp.int32)[:n_dev]
+    offsets = jnp.cumsum(counts, dtype=jnp.int32) - counts
+    n_rounds = (jnp.max(counts) + jnp.int32(cap - 1)) // jnp.int32(cap)
+    return ExchangePlan(order, counts, offsets, n_rounds)
+
+
+def order_payload(plan: ExchangePlan, payload: Any, cap: int) -> Any:
+    """The columns `exchange_round` packs from: each [B, ...] column of
+    `payload` permuted by `plan.order`, with `cap` rows of zeros behind it
+    so that a round's `cap`-row slice which starts inside the batch never
+    clamps back into another bucket. Once a step, outside the rounds'
+    loop."""
+    def ordered(col):
+        pad = jnp.zeros((cap,) + col.shape[1:], col.dtype)
+        return jnp.concatenate([col[plan.order], pad])
+
+    return jax.tree.map(ordered, payload)
 
 
 def exchange_round(axis_name: str, n_dev: int, cap: int, plan: ExchangePlan,
                    ordered_payload: Any, r: jax.Array) -> tuple[Any, jax.Array]:
     """Route round `r` of a planned exchange: rows with bucket rank in
-    [r*cap, (r+1)*cap). `ordered_payload` columns must already be permuted
-    by `plan.order`. Returns ([n_dev*cap, ...] routed pytree, [n_dev*cap]
-    valid mask). Safe inside lax.while_loop with a pmax-uniform trip count.
+    [r*cap, (r+1)*cap). `ordered_payload` is `order_payload`'s. Returns
+    ([n_dev*cap, ...] routed pytree, [n_dev*cap] valid mask). Safe inside
+    lax.while_loop with a pmax-uniform trip count.
     """
     # the send buffers are the region exchange.pack; the all-to-alls stay
     # directly under the caller's scope
     with jax.named_scope("exchange.pack"):
-        sub = plan.rank - r * jnp.int32(cap)
-        in_round = (sub >= 0) & (sub < cap) & (plan.sd < n_dev)
-        # Out-of-round rows get an out-of-bounds slot so mode="drop"
-        # discards them (negative indices would wrap under the default
-        # mode).
-        slot = jnp.where(in_round, sub, jnp.int32(cap))
+        done = r * jnp.int32(cap)
+        left = jnp.clip(plan.counts - done, 0, cap)
+        send_valid = jnp.arange(cap, dtype=jnp.int32)[None, :] < left[:, None]
+        # A drained bucket's start may lie past the batch: dynamic_slice
+        # clamps it onto the padding, and no lane of it is valid.
+        starts = plan.offsets + done
 
-        send_valid = jnp.zeros((n_dev, cap), bool).at[plan.sd, slot].set(
-            in_round, mode="drop")
+        def pack(col):
+            # shapes are static: a `cap` other than `order_payload`'s would
+            # let a slice clamp back into another bucket
+            assert col.shape[0] == plan.order.shape[0] + cap, (col.shape, cap)
+            rows = jnp.stack([
+                jax.lax.dynamic_slice_in_dim(col, starts[d], cap)
+                for d in range(n_dev)])
+            keep = send_valid.reshape(send_valid.shape + (1,) * (col.ndim - 1))
+            return jnp.where(keep, rows, jnp.zeros((), col.dtype))
 
-        def scatter(col):
-            buf = jnp.zeros((n_dev, cap) + col.shape[1:], col.dtype)
-            return buf.at[plan.sd, slot].set(col, mode="drop")
-
-        send = jax.tree.map(scatter, ordered_payload)
+        send = jax.tree.map(pack, ordered_payload)
     if n_dev == 1:
         recv, recv_valid = send, send_valid
     else:

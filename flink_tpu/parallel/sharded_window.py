@@ -56,7 +56,8 @@ from ..ops.segment_ops import AGG_INITS, AGG_MERGES, COUNT_KINDS, \
     stores_halves
 from ..ops.topk import masked_topk_sort, threshold_topk
 from ..state.tpu_backend import reclaim_shard
-from .exchange import bucket_capacity, exchange_round, plan_exchange
+from .exchange import (bucket_capacity, exchange_round, order_payload,
+                       plan_exchange)
 from .mesh import DATA_AXIS, device_index_for_key_groups, \
     key_groups_device, shard_ranges
 from .plan import ShardingPlan, match_partition_rules, \
@@ -248,7 +249,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
             B = keys.shape[0]
             cap_x = bucket_capacity(B, D)
             xplan = plan_exchange(dest, valid, D, cap_x)
-            ordered = jax.tree.map(lambda c: c[xplan.order], payload)
+            ordered = order_payload(xplan, payload, cap_x)
         with jax.named_scope("mesh.sync"):
             n_rounds = jax.lax.pmax(xplan.n_rounds, axis_name)
 

@@ -434,9 +434,11 @@ def test_plan_exchange_is_int32_under_x64():
     dest = jnp.arange(64, dtype=jnp.int32) % 3
     plan = plan_exchange(dest, jnp.arange(64) % 7 != 0, 3, 16)
     assert {f: getattr(plan, f).dtype.name for f in plan._fields} == {
-        "order": "int32", "sd": "int32", "rank": "int32",
+        "order": "int32", "counts": "int32", "offsets": "int32",
         "n_rounds": "int32"}
-    assert int(plan.n_rounds) == 2          # ceil(19 valid of dest 0 / 16)
+    assert plan.counts.tolist() == [18, 18, 18]    # the valid rows a shard
+    assert plan.offsets.tolist() == [0, 18, 36]
+    assert int(plan.n_rounds) == 2          # ceil(18 / 16)
 
 
 @pytest.mark.parametrize("n_dev", [1, 2])

@@ -18,7 +18,8 @@ from flink_tpu.core.keygroups import (KeyGroupRange, assign_to_key_group,
 from flink_tpu.core.records import Schema
 from flink_tpu.ops.hash_table import ensure_x64
 from flink_tpu.parallel.exchange import (bucket_capacity, exchange_round,
-                                         keyby_exchange, plan_exchange)
+                                         keyby_exchange, order_payload,
+                                         plan_exchange)
 from flink_tpu.parallel.mesh import (DATA_AXIS, device_index_for_key_groups,
                                      make_mesh, shard_ranges)
 from flink_tpu.parallel.plan import shard_map_unchecked
@@ -104,7 +105,7 @@ def _exchange_hists(D, dest, keys, valid, cap=None):
                 jnp.where(rvalid, 1, 0), mode="drop")
             return hist[None], jnp.ones(1, jnp.int32)
         plan = plan_exchange(d, v, D, cap)
-        ordered = {"k": k[plan.order]}
+        ordered = order_payload(plan, {"k": k}, cap)
         n_rounds = jax.lax.pmax(plan.n_rounds, DATA_AXIS)
 
         def rnd(carry):
@@ -162,6 +163,106 @@ def test_bounded_exchange_skew_takes_extra_rounds_losslessly():
     assert hist[1:].sum() == 0  # only shard 0 received anything
     np.testing.assert_array_equal(
         hist[0], np.bincount(keys[valid], minlength=K))
+
+
+def _route_numpy(D, cap, dest, valid, cols, r):
+    """Round `r` as a plain loop: what device j holds after the exchange
+    is, source by source, the r-th `cap` rows of the source's valid rows
+    bound for j in their batch order, zeros behind them."""
+    flags = np.zeros((D, D * cap), bool)
+    out = {n: np.zeros((D, D * cap) + c.shape[2:], c.dtype)
+           for n, c in cols.items()}
+    for src in range(D):
+        for dst in range(D):
+            rows = np.flatnonzero(valid[src] & (dest[src] == dst))[
+                r * cap:(r + 1) * cap]
+            at = slice(src * cap, src * cap + len(rows))
+            flags[dst, at] = True
+            for n, c in cols.items():
+                out[n][dst, at] = c[src, rows]
+    return out, flags
+
+
+def _packing_case(case, D, B, cap, rng):
+    """(dest, valid) [D, B] for one shape of batch; every source slice of
+    a case has the same shape, with its own rows."""
+    dest = rng.integers(0, D, (D, B)).astype(np.int32)
+    valid = np.ones((D, B), bool)
+    if case == "two_rounds":        # dest 0 holds cap + 1 rows of a slice
+        dest[:, :cap + 1] = 0
+        dest[:, cap + 1:] = np.maximum(dest[:, cap + 1:], min(1, D - 1))
+    elif case == "full_skew":       # ceil(B / cap) rounds
+        dest[:] = D - 1
+    elif case == "partly_invalid":
+        valid = rng.random((D, B)) < 0.6
+    elif case == "wholly_invalid":
+        valid[:] = False
+    elif case == "ends_at_B":       # the last run is short and ends at B:
+        dest = np.sort(dest, axis=1)   # its slice overhangs the batch
+        dest[:, -3:] = D - 1
+        dest[:, :-3] = np.minimum(dest[:, :-3], max(D - 2, 0))
+    else:
+        assert case == "uniform"
+        dest = np.tile(np.arange(B, dtype=np.int32) % D, (D, 1))
+    return dest, valid
+
+
+@pytest.mark.parametrize("case", ["uniform", "two_rounds", "full_skew",
+                                  "partly_invalid", "wholly_invalid",
+                                  "ends_at_B"])
+@pytest.mark.parametrize("D", [1, 2, 4, 8])
+def test_exchange_round_packs_what_a_plain_routing_packs(D, case):
+    """Every round's buffers and flags, byte for byte, through the
+    collective: the rounds a batch needs and the one past the last, which
+    is all-invalid and all-zero."""
+    B = 64
+    cap = B if D == 1 else B // D + 4
+    rng = np.random.default_rng(D * 100 + len(case))
+    dest, valid = _packing_case(case, D, B, cap, rng)
+    cols = {"k": rng.integers(1, 1 << 62, (D, B)).astype(np.int64),
+            "v": rng.integers(1, 1 << 30, (D, B, 2)).astype(np.int32)}
+    deepest = max(int(np.sum(valid[s] & (dest[s] == d)))
+                  for s in range(D) for d in range(D))
+    want_rounds = -(-deepest // cap)
+    assert want_rounds == {"uniform": 1, "two_rounds": 1 if D == 1 else 2,
+                           "full_skew": -(-B // cap),
+                           "wholly_invalid": 0}.get(case, want_rounds)
+    R = want_rounds + 1
+
+    def body(dest, valid, k, v):
+        plan = plan_exchange(dest[0], valid[0], D, cap)
+        ordered = order_payload(plan, {"k": k[0], "v": v[0]}, cap)
+        rounds = [exchange_round(DATA_AXIS, D, cap, plan, ordered,
+                                 jnp.int32(r)) for r in range(R)]
+        return (jnp.stack([o["k"] for o, _ in rounds])[None],
+                jnp.stack([o["v"] for o, _ in rounds])[None],
+                jnp.stack([f for _, f in rounds])[None],
+                jax.lax.pmax(plan.n_rounds, DATA_AXIS)[None])
+
+    fn = shard_map_unchecked(
+        body, make_mesh(D), in_specs=(P(DATA_AXIS),) * 4,
+        out_specs=(P(DATA_AXIS),) * 4)
+    k, v, flags, n_rounds = jax.device_get(jax.jit(fn)(
+        jnp.asarray(dest), jnp.asarray(valid), jnp.asarray(cols["k"]),
+        jnp.asarray(cols["v"])))
+    assert n_rounds.tolist() == [want_rounds] * D
+    assert k.dtype == np.int64 and v.dtype == np.int32
+    for r in range(R):
+        want, want_flags = _route_numpy(D, cap, dest, valid, cols, r)
+        np.testing.assert_array_equal(flags[:, r], want_flags)
+        np.testing.assert_array_equal(k[:, r], want["k"])
+        np.testing.assert_array_equal(v[:, r], want["v"])
+    assert not flags[:, -1].any() and not k[:, -1].any()
+
+
+def test_exchange_round_refuses_columns_padded_for_another_cap():
+    """The three calls share one `cap`: columns padded for another would
+    let a round's slice clamp back into a neighbour's bucket."""
+    dest = jnp.arange(64, dtype=jnp.int32) % 1
+    plan = plan_exchange(dest, jnp.ones(64, bool), 1, 16)
+    ordered = order_payload(plan, {"k": jnp.arange(64)}, 8)
+    with pytest.raises(AssertionError):
+        exchange_round(DATA_AXIS, 1, 16, plan, ordered, jnp.int32(0))
 
 
 def test_bucket_capacity_bounds():

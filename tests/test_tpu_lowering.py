@@ -612,6 +612,28 @@ def test_the_elections_scatter_is_not_counted_as_a_round():
     assert rounds and len(rounds) + len(elect) == len(names)
 
 
+def _assert_the_exchange_packs_without_a_scatter(hlo: str,
+                                                 regions: dict) -> None:
+    """A compiled mesh step at the benchmark shape ([4, 65536] rows a
+    block, rounds of 4 x 20480): a round's send buffers are slices of the
+    destination-ordered columns (PR 50), so no scatter lies under
+    `mesh.exchange`, none is pathless (the x64 rewriter made the int64
+    columns' scatters anew, without a name path), and the slices' fusions
+    are the region `exchange.pack`."""
+    import re
+
+    lines = hlo.splitlines()
+    scatters = [line for line in lines if re.search(r" scatter\(", line)]
+    assert scatters
+    assert not [line[:300] for line in scatters
+                if "mesh.exchange" in line or 'op_name="' not in line]
+    slices = {m.group(1) for line in lines if "exchange.pack/dynamic_slice"
+              in line and " fusion(" in line
+              for m in [re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line)]}
+    assert slices and {regions[name] for name in slices
+                       if name in regions} == {"exchange.pack"}
+
+
 # ---------------------------------------------------------------------------
 # the region map of every program a benchmark cell runs, at its shapes
 
@@ -724,9 +746,8 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
     cells' shapes: every instruction the device runs that reads or writes
     1 MiB or more lies in a named region, the x64 rewriter's split and
     join among them (no `named_scope` can reach those: they go by their
-    custom-call target), and the mesh step's three int64 column scatters
-    (which the rewriter makes anew, without a name path) are the
-    exchange's packing by what they feed."""
+    custom-call target), and the mesh step's send buffers are slices
+    under `exchange.pack`, with no scatter."""
     import re
 
     from flink_tpu.metrics.device import UNNAMED, classify_hlo
@@ -761,23 +782,7 @@ def test_every_big_instruction_lies_in_a_named_region(v5e_devices, program):
         assert len(writes) == 4 and x64 == {"x64.split": 0, "x64.join": 0}
         assert {regions[name] for name in writes} == {"fire.retire"}
     if program == "jit_step":
-        # the send buffers' scatters (the probe's compaction scatters
-        # into as many rows on the receiving side): the flags', and one
-        # of each int64 column over both its halves, which the x64
-        # rewriter makes anew without a name path
-        packs = [line for line in hlo.splitlines()
-                 if re.search(r" fusion\(.*kind=kCustom", line)
-                 and re.search(r"\[81920\]", line.split(" fusion(")[0])
-                 and "mesh.probe" not in line]
-        columns = flags = 0
-        for line in packs:
-            name = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line).group(1)
-            if name in regions:         # not one inside another fusion
-                assert regions[name] == "exchange.pack", line[:200]
-                columns += " = (u32[81920]" in line \
-                    and "op_name=" not in line
-                flags += " = pred[81920]" in line
-        assert (columns, flags) == (3, 1)
+        _assert_the_exchange_packs_without_a_scatter(hlo, regions)
 
 
 # ---------------------------------------------------------------------------
@@ -1363,6 +1368,8 @@ def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
     live = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert live < 2e9, live
+    if program == "jit_step":
+        _assert_the_exchange_packs_without_a_scatter(hlo, regions)
     if program.startswith("jit_fire"):
         guarded = program.endswith("unpromised")
         assert bool(re.search(r" xor\(", hlo)) == guarded
@@ -1409,8 +1416,8 @@ def test_the_presence_plane_folds_as_one_32_bit_scatter(v5e_devices,
     scatters = _count_scatters(hlo)
     assert scatters and all(t.startswith(f"s32[{rows}]{{")
                             for t in scatters), scatters
-    # the folds' two-word scatters (the mesh step has others: its send
-    # buffers', the probe's claim of int64 keys): the MAX's, nobody else's
+    # the folds' two-word scatters (the mesh step has others: the probe's
+    # claim of int64 keys): the MAX's, nobody else's
     wide = [line for line in hlo.splitlines()
             if "/fold.scatter/" in line and re.search(
                 r"= \(u32\[\d+\]\S*, u32\[\d+\]\S*\) scatter\(", line)]
