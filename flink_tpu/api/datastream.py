@@ -320,28 +320,11 @@ class DataStream:
     def assign_timestamps_and_watermarks(self, ws) -> "DataStream":
         """Mid-stream watermark assignment (reference
         assignTimestampsAndWatermarks)."""
-        from ..runtime.operators.simple import BatchFnOperator
-        from ..core.elements import Watermark
-        from ..runtime.operators.base import OneInputOperator
-
-        class _WmOperator(OneInputOperator):
-            def __init__(self):
-                super().__init__("TimestampsWatermarks")
-                self._gen = ws.create_generator()
-
-            def process_batch(self, batch):
-                batch = ws.assign_timestamps(batch)
-                self._gen.on_batch(batch)
-                self.output.emit(batch)
-                wm = self._gen.current_watermark()
-                if wm > self.current_watermark:
-                    self.current_watermark = wm
-                    self.output.emit_watermark(Watermark(wm))
-
-            def process_watermark(self, watermark):
-                pass  # replaced by generated watermarks
-
-        return self._one_input("TimestampsWatermarks", _WmOperator)
+        from ..runtime.operators.simple import \
+            TimestampsAndWatermarksOperator
+        return self._one_input(
+            "TimestampsWatermarks",
+            lambda: TimestampsAndWatermarksOperator(ws))
 
 
 class IterativeStream(DataStream):

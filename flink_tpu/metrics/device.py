@@ -150,6 +150,12 @@ class DeviceStats:
         # counted on the host from the batch's own ring indices
         self._fold_batches = 0
         self._fold_ring_rows = 0
+        # out-of-order input (PR 51): the batches (mesh blocks) that held
+        # rows of more than two ring rows and so went up sorted by ring
+        # row, and the rows whose pane lay under the newest pane the
+        # operator had seen before their batch (0 for a stream in order)
+        self._fold_sorted_batches = 0
+        self._fold_back_rows = 0
         # state reclaim accounting (PR 35; the mesh operator's since
         # PR 41, one sweep a dispatch, keys summed over the shards):
         # sweeps of the reclaim (state/tpu_backend.py reclaim_shard: a
@@ -493,17 +499,25 @@ class DeviceStats:
         with self._lock:
             return dict(self._count_planes)
 
-    def note_fold(self, ring_rows: int) -> None:
+    def note_fold(self, ring_rows: int, sorted_: bool = False) -> None:
         with self._lock:
             self._fold_batches += 1
             self._fold_ring_rows += int(ring_rows)
+            self._fold_sorted_batches += bool(sorted_)
+
+    def note_fold_back(self, rows: int) -> None:
+        with self._lock:
+            self._fold_back_rows += int(rows)
 
     @property
-    def fold_counts(self) -> tuple[int, int]:
+    def fold_counts(self) -> tuple[int, int, int, int]:
         """(host-born batches or mesh blocks folded, ring rows they
-        touched)."""
+        touched, those of them that went up sorted by ring row, rows
+        whose pane was older than the newest pane seen before their
+        batch)."""
         with self._lock:
-            return self._fold_batches, self._fold_ring_rows
+            return (self._fold_batches, self._fold_ring_rows,
+                    self._fold_sorted_batches, self._fold_back_rows)
 
     def note_reclaim(self, kept: int, freed: int) -> None:
         with self._lock:
@@ -818,6 +832,8 @@ class DeviceStats:
                    for form, n in self._count_planes.items()},
                 "fold_batches_total": self._fold_batches,
                 "fold_ring_rows_total": self._fold_ring_rows,
+                "fold_sorted_batches_total": self._fold_sorted_batches,
+                "fold_back_rows_total": self._fold_back_rows,
                 "state_reclaim_sweeps_total": self._reclaim_sweeps,
                 "state_reclaim_keys_kept_total": self._reclaim_kept,
                 "state_reclaim_keys_freed_total": self._reclaim_freed,
@@ -932,6 +948,7 @@ class DeviceStats:
             self._fire_select_sort = self._fire_select_guarded = 0
             self._count_planes = dict.fromkeys(COUNT_PLANE_FORMS, 0)
             self._fold_batches = self._fold_ring_rows = 0
+            self._fold_sorted_batches = self._fold_back_rows = 0
             self._reclaim_sweeps = 0
             self._reclaim_kept = self._reclaim_freed = 0
             self._session_fires = self._session_fire_rounds = 0
@@ -1761,9 +1778,13 @@ def bind_device_metrics(registry) -> None:
                 lambda form=form: s.count_plane_counts[form])
     # ring fold of the host-born ingest, one chip or mesh (prometheus:
     # flink_tpu_device_fold_batches_total /
-    # flink_tpu_device_fold_ring_rows_total)
+    # flink_tpu_device_fold_ring_rows_total /
+    # flink_tpu_device_fold_sorted_batches_total /
+    # flink_tpu_device_fold_back_rows_total)
     g.gauge("fold_batches_total", lambda: s.fold_counts[0])
     g.gauge("fold_ring_rows_total", lambda: s.fold_counts[1])
+    g.gauge("fold_sorted_batches_total", lambda: s.fold_counts[2])
+    g.gauge("fold_back_rows_total", lambda: s.fold_counts[3])
     # state reclaim, one chip or mesh (prometheus:
     # flink_tpu_device_state_reclaim_sweeps_total /
     # flink_tpu_device_state_reclaim_keys_kept_total /

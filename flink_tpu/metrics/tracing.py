@@ -896,6 +896,13 @@ SPAN_INVENTORY: tuple = (
      "shards, capacity a shard's; child of the Drain whose reading found "
      "the fullest shard past the limit or, found by the pressure probe, "
      "a root whose seq is the operator's watermark"),
+    ("window", "RingSort",
+     "runtime/operators/device_window.py _fold — out-of-order input: a "
+     "host-born batch that holds rows of more than two ring rows is "
+     "put in ring-row order (a stable argsort of its ring indices and "
+     "a take of every column) on the task's thread, before its Upload "
+     "(stage span: rows, ring_rows; seq: the batch's ordinal); an "
+     "in-order batch opens none"),
     ("window", "Upload",
      "runtime/operators/device_window.py _fold_packed / "
      "_to_device_batch — pack + the one host→device copy; device/H2D "

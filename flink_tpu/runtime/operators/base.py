@@ -123,6 +123,7 @@ class StreamOperator:
         self.output: Output = None  # type: ignore[assignment]
         self.current_watermark: int = -(1 << 62)
         self._latency_hist = None
+        self._metric_group = None    # the operator's scope, once set up
         self.latency_markers_seen = 0
         self._ledger_job = ""
         self._ledger_ident = self.name
@@ -139,7 +140,8 @@ class StreamOperator:
         if metrics is not None and hasattr(metrics, "operator_group"):
             # per-operator scope (reference AbstractStreamOperator's
             # WatermarkGauge + latency histogram under the operator group)
-            g = metrics.operator_group(getattr(self, "_op_key", self.name))
+            g = self._metric_group = metrics.operator_group(
+                getattr(self, "_op_key", self.name))
             g.gauge("currentInputWatermark", lambda: self.current_watermark)
             g.gauge("watermarkLag", self._watermark_lag_ms)
             self._latency_hist = g.histogram("latency")

@@ -47,8 +47,8 @@ from ...ops.hash_table import EMPTY_KEY, lookup_or_insert, \
 from ...state.tpu_backend import TpuKeyedStateBackend
 from ...window.assigners import WindowAssigner
 from .base import OneInputOperator, OperatorContext, Output
-from .slice_control import AsyncFireQueue, CoalescingIngest, \
-    SliceControlPlane
+from .slice_control import IN_ORDER_RING_ROWS, AsyncFireQueue, \
+    CoalescingIngest, SliceControlPlane
 
 __all__ = ["DeviceWindowAggOperator", "AggSpec"]
 
@@ -978,16 +978,19 @@ class DeviceWindowAggOperator(AsyncFireQueue, CoalescingIngest,
               panes: np.ndarray) -> None:
         ring_idx = panes % self._ring
         ring_rows = self._note_fold(ring_idx)
-        if ring_rows > 2:
+        if ring_rows > IN_ORDER_RING_ROWS:
             # out-of-order input. The fold takes a touched ring row's
             # updates a chunk of the batch at a time and skips the chunks
             # that hold none (ops/segment_ops.ring_fold), so a batch
             # shuffled over k ring rows would pay k whole batches; sorted
             # by ring row it pays one. Stable, so the updates of any one
             # cell keep their order.
-            order = np.argsort(ring_idx, kind="stable")
-            batch, keys, ring_idx = (batch.take(order), keys[order],
-                                     ring_idx[order])
+            with TRACER.stage("window", "RingSort", seq=self._batch_seq,
+                              total=(self.stage_s, "ingest"),
+                              rows=batch.n, ring_rows=ring_rows):
+                order = np.argsort(ring_idx, kind="stable")
+                batch, keys, ring_idx = (batch.take(order), keys[order],
+                                         ring_idx[order])
         if self._defer:
             # pipelined path: host<->device calls have a fixed cost, so
             # the whole batch rides ONE upload and nothing syncs back

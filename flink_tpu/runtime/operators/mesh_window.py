@@ -52,7 +52,8 @@ from ...parallel.sharded_window import (
 from ...window.assigners import WindowAssigner
 from .base import OneInputOperator, OperatorContext, Output
 from .device_window import AggSpec
-from .slice_control import AsyncFireQueue, SliceControlPlane
+from .slice_control import IN_ORDER_RING_ROWS, AsyncFireQueue, \
+    SliceControlPlane
 
 __all__ = ["MeshWindowAggOperator"]
 
@@ -348,7 +349,7 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                 if staged is None:
                     staged = self._concat_staged()
                 rows = slice(pos, pos + n_valid)
-                if ring_rows > 2:
+                if ring_rows > IN_ORDER_RING_ROWS:
                     # out-of-order input goes up sorted by ring row, as
                     # on one chip (DeviceWindowAggOperator._fold): the
                     # routed rows keep a slice's order, so each ring

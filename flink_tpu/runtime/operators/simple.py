@@ -18,12 +18,14 @@ from ...core.functions import (
     Collector, FilterFunction, FlatMapFunction, MapFunction, ProcessFunction,
     RuntimeContext,
 )
-from ...core.records import MIN_TIMESTAMP, RecordBatch, Schema
+from ...core.records import MAX_TIMESTAMP, MIN_TIMESTAMP, RecordBatch, \
+    Schema
 from ..timers import InternalTimerService, Timer
 from .base import OneInputOperator, OperatorContext, Output
 
 __all__ = ["MapOperator", "FilterOperator", "FlatMapOperator",
-           "KeyedProcessOperator", "BatchFnOperator", "KeyExtractor"]
+           "KeyedProcessOperator", "BatchFnOperator", "KeyExtractor",
+           "TimestampsAndWatermarksOperator"]
 
 # KeyExtractor: RecordBatch -> np.ndarray of keys (one per row)
 KeyExtractor = Callable[[RecordBatch], np.ndarray]
@@ -131,6 +133,53 @@ class BatchFnOperator(OneInputOperator):
         out = self._fn(batch)
         if out is not None and out.n:
             self.output.emit(out)
+
+
+class TimestampsAndWatermarksOperator(OneInputOperator):
+    """Mid-stream timestamp and watermark assignment (reference
+    TimestampsAndWatermarksOperator): every batch gets the strategy's
+    timestamps and is followed by the generator's watermark where that
+    moved. Watermarks from upstream are replaced by the generated ones,
+    all but the end-of-input one, which is forwarded as the reference
+    forwards ``Long.MAX_VALUE``: without it a bounded job's last windows,
+    those the generator's holdback keeps open, would never fire.
+
+    ``numRecordsOutOfOrder`` (operator scope of the job's registry)
+    counts the rows whose timestamp lies under the largest timestamp of
+    the batches BEFORE theirs: 0 for a stream in order. A device batch
+    is not counted (its timestamps are not on the host)."""
+
+    def __init__(self, strategy, name: str = "TimestampsWatermarks"):
+        super().__init__(name)
+        self._strategy = strategy
+        self._gen = strategy.create_generator()
+        self._max_ts = MIN_TIMESTAMP
+        self.records_out_of_order = 0
+
+    def setup(self, ctx: OperatorContext, output: Output) -> None:
+        super().setup(ctx, output)
+        if self._metric_group is not None:
+            self._metric_group.gauge("numRecordsOutOfOrder",
+                                     lambda: self.records_out_of_order)
+
+    def process_batch(self, batch: RecordBatch) -> None:
+        batch = self._strategy.assign_timestamps(batch)
+        if batch.n and not getattr(batch, "is_device", False):
+            ts = batch.timestamps
+            self.records_out_of_order += int(
+                np.count_nonzero(ts < self._max_ts))
+            self._max_ts = max(self._max_ts, int(ts.max()))
+        self._gen.on_batch(batch)
+        self.output.emit(batch)
+        wm = self._gen.current_watermark()
+        if wm > self.current_watermark:
+            self.current_watermark = wm
+            self.output.emit_watermark(Watermark(wm))
+
+    def process_watermark(self, watermark: Watermark) -> None:
+        if watermark.timestamp >= MAX_TIMESTAMP:
+            self.current_watermark = watermark.timestamp
+            self.output.emit_watermark(watermark)
 
 
 class KeyedProcessOperator(OneInputOperator):

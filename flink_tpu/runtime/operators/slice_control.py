@@ -29,9 +29,14 @@ from ...core.elements import Watermark
 from ...core.records import RecordBatch
 from ...metrics.tracing import TRACER, Stage
 
-__all__ = ["SliceControlPlane", "AsyncFireQueue", "CoalescingIngest"]
+__all__ = ["SliceControlPlane", "AsyncFireQueue", "CoalescingIngest",
+           "IN_ORDER_RING_ROWS"]
 
 _MAX_FIRE_SAMPLES = 65536
+#: ring rows a batch in event-time order can hold a row for (two where
+#: it straddles a pane's edge); a batch that holds more is out of order
+#: and goes up sorted by ring row (``SliceControlPlane._note_fold``)
+IN_ORDER_RING_ROWS = 2
 
 
 class CoalescingIngest:
@@ -308,6 +313,15 @@ class SliceControlPlane:
                     return
         max_pane = int(panes.max())
         min_pane = int(panes.min())
+        if (self._max_seen_pane is not None
+                and min_pane < self._max_seen_pane):
+            # rows that reach back behind the newest pane seen BEFORE
+            # this batch (none where the stream is in order, so an
+            # in-order batch pays the comparison above and no pass)
+            from ...metrics.device import DEVICE_STATS
+
+            DEVICE_STATS.note_fold_back(
+                int(np.count_nonzero(panes < self._max_seen_pane)))
         self._max_seen_pane = (max_pane if self._max_seen_pane is None
                                else max(self._max_seen_pane, max_pane))
         self._min_seen_pane = (min_pane if self._min_seen_pane is None
@@ -414,12 +428,14 @@ class SliceControlPlane:
         counted (DEVICE_STATS ``fold_ring_rows_total`` /
         ``fold_batches_total``, from the batch's own ring indices, no
         device sync) and returned for the dispatch span's
-        ``ring_rows``."""
+        ``ring_rows``. Past ``IN_ORDER_RING_ROWS`` the input is out of
+        order and the caller sends the batch up sorted by ring row
+        (``fold_sorted_batches_total``)."""
         from ...metrics.device import DEVICE_STATS
 
         rows = int(np.count_nonzero(
             np.bincount(ring_idx, minlength=self._ring)))
-        DEVICE_STATS.note_fold(rows)
+        DEVICE_STATS.note_fold(rows, rows > IN_ORDER_RING_ROWS)
         return rows
 
     @property

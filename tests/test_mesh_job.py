@@ -764,6 +764,12 @@ def test_mesh_blocks_count_the_ring_rows_their_fold_touches(case, ts, want):
         == len(want)
     assert after["fold_ring_rows_total"] - before["fold_ring_rows_total"] \
         == sum(want)
+    # a block over more than two ring rows went up sorted, and the mesh
+    # operator opens no window/RingSort for it (it sorts in its Upload)
+    assert after["fold_sorted_batches_total"] \
+        - before["fold_sorted_batches_total"] == sum(n > 2 for n in want)
+    assert not [s for s in spans if (s.scope, s.name)
+                == ("window", "RingSort")]
     dispatches = sorted((s for s in spans if (s.scope, s.name)
                          == ("window", "IngestDispatch")),
                         key=lambda s: s.attributes["seq"])
