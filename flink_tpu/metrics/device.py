@@ -113,6 +113,10 @@ class DeviceStats:
         # behind a representative (the same device vector's other half)
         self._probe_elected_rows = 0
         self._probe_elected_batches = 0
+        # rows whose first window, read from the slots' low 32-bit words
+        # (PR 52), could not say what it holds: the one candidate's high
+        # word was neither the key's nor EMPTY's. 0 for keys under 2^32
+        self._probe_undecided_rows = 0
         # mesh step accounting (PR 27): steps of the sharded window
         # program and the keyBy exchange rounds they took (one for a
         # batch spread evenly over the shards, more under skew). Read
@@ -429,22 +433,25 @@ class DeviceStats:
             return self._fires_drained, self._fires_drained_timer
 
     def note_probe(self, rows: int, tail_rows: int, wide_batches: int,
-                   elected_rows: int = 0, elected_batches: int = 0) -> None:
+                   elected_rows: int = 0, elected_batches: int = 0,
+                   undecided_rows: int = 0) -> None:
         with self._lock:
             self._probe_rows += int(rows)
             self._probe_tail_rows += int(tail_rows)
             self._probe_wide_batches += int(wide_batches)
             self._probe_elected_rows += int(elected_rows)
             self._probe_elected_batches += int(elected_batches)
+            self._probe_undecided_rows += int(undecided_rows)
 
     @property
-    def probe_counts(self) -> tuple[int, int, int, int, int]:
+    def probe_counts(self) -> tuple[int, int, int, int, int, int]:
         """(rows probed, tail rows, wide batches, rows that stood behind
-        an elected representative, batches that elected)."""
+        an elected representative, batches that elected, rows the first
+        window left undecided)."""
         with self._lock:
             return (self._probe_rows, self._probe_tail_rows,
                     self._probe_wide_batches, self._probe_elected_rows,
-                    self._probe_elected_batches)
+                    self._probe_elected_batches, self._probe_undecided_rows)
 
     def note_mesh_steps(self, steps: int, rounds: int) -> None:
         with self._lock:
@@ -817,6 +824,7 @@ class DeviceStats:
                 "fires_drained_timer_total": self._fires_drained_timer,
                 "probe_rows_total": self._probe_rows,
                 "probe_tail_rows_total": self._probe_tail_rows,
+                "probe_undecided_rows_total": self._probe_undecided_rows,
                 "probe_wide_batches_total": self._probe_wide_batches,
                 "probe_elected_rows_total": self._probe_elected_rows,
                 "probe_elected_batches_total": self._probe_elected_batches,
@@ -942,6 +950,7 @@ class DeviceStats:
             self._probe_rows = self._probe_tail_rows = 0
             self._probe_wide_batches = 0
             self._probe_elected_rows = self._probe_elected_batches = 0
+            self._probe_undecided_rows = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
             self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
@@ -1743,11 +1752,13 @@ def bind_device_metrics(registry) -> None:
     g.gauge("fires_drained_timer_total", lambda: s.fires_drained[1])
     # hash probe (prometheus: flink_tpu_device_probe_rows_total /
     # flink_tpu_device_probe_tail_rows_total /
+    # flink_tpu_device_probe_undecided_rows_total /
     # flink_tpu_device_probe_wide_batches_total /
     # flink_tpu_device_probe_elected_rows_total /
     # flink_tpu_device_probe_elected_batches_total)
     g.gauge("probe_rows_total", lambda: s.probe_counts[0])
     g.gauge("probe_tail_rows_total", lambda: s.probe_counts[1])
+    g.gauge("probe_undecided_rows_total", lambda: s.probe_counts[5])
     g.gauge("probe_wide_batches_total", lambda: s.probe_counts[2])
     g.gauge("probe_elected_rows_total", lambda: s.probe_counts[3])
     g.gauge("probe_elected_batches_total", lambda: s.probe_counts[4])

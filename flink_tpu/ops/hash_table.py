@@ -25,27 +25,37 @@ table's first key to its replacement. Bounded probe count returns an
 before the load bites, and fails the job on a dropped insert).
 
 What a round costs on the chip (TPU v5e, 2^24 slots, PERF.md sections 5
-and 6): per row carried, about 110 ns for the window's two gathers (the
-int64 table is two 32-bit halves) and about 100 ns for the claim, whatever
-the width, and the row with the longest probe chain sets the round count
-for every row beside it. At load 0.6 that is 5 to 9 rounds while 98.6% of
-resident keys sit within CHUNK slots of their home. So ``lookup_or_insert``
-carries a row only while it is unresolved: one read-only window at full
-width, then the rounds over the compacted tail (1-2% of a batch of
-resident keys), at full width only when the tail does not fit (mostly new
-keys: cold start, prefill, growth, and, where keys come and go, every
-batch: a key whose slot was reclaimed and that is seen again is an insert,
-and a key that is new and hot has ALL its rows unresolved, so a job over
-NEXmark's advancing auction ids leaves 58% of a 2^18-row batch to the
-claiming rounds: PERF.md section 5, q5-inflight-saturated). A caller that
-sees such batches one after another asks for the program that runs the
-full-width rounds only until what is left fits the narrow loop
-(``handover``), and in which, unless the caller's keys cannot repeat, a
-key's rows send ONE lane into the rounds (``_elect``). CHUNK = 8 is the
-window at which those shares were measured; it was first sized for a CPU
-cache line (2.3x over one-slot probing at 50% load on the CPU), which is
-no argument here: on the chip a window costs what its CHUNK gathered
-elements cost.
+and 6): the int64 table is two 32-bit words a slot (the compiler keeps
+it as two ``uint32`` halves), a gather costs by the element it fetches
+and not by the byte (about 6.3 ns), so per row carried a claiming round
+pays about 110 ns for its window's two gathers of CHUNK words and about
+100 ns for the claim, whatever the width, and the row with the longest
+probe chain sets the round count for every row beside it. At load 0.6
+that is 5 to 9 rounds while 98.6% of resident keys sit within CHUNK
+slots of their home. So ``lookup_or_insert`` carries a row only while it
+is unresolved: one read-only window at full width, then the rounds over
+the compacted tail (1-2% of a batch of resident keys), at full width
+only when the tail does not fit (mostly new keys: cold start, prefill,
+growth, and, where keys come and go, every batch: a key whose slot was
+reclaimed and that is seen again is an insert, and a key that is new and
+hot has ALL its rows unresolved, so a job over NEXmark's advancing
+auction ids leaves 58% of a 2^18-row batch to the claiming rounds:
+PERF.md section 5, q5-inflight-saturated). That read-only window is the
+one every row pays every batch, and it reads ONE word a slot
+(``_window0``, PR 52): the eight LOW words say where the key or the
+first EMPTY can be, one HIGH word at that slot says which it is: one
+``[n, CHUNK]`` gather (13.2 ms at 2^18 rows) and one ``[n]`` gather (3.2)
+where whole keys take two ``[n, CHUNK]`` gathers, and the window with
+its hashes and bookkeeping reads 22.9 ms a batch where it read 34.5
+(PERF.md section 6, PR 52); the few rows that leaves undecided (none for
+keys under 2^32) go with the tail. A caller that sees wide batches one after another asks
+for the program that runs the full-width rounds only until what is left
+fits the narrow loop (``handover``), and in which, unless the caller's
+keys cannot repeat, a key's rows send ONE lane into the rounds
+(``_elect``). CHUNK = 8 is the window at which those shares were
+measured; it was first sized for a CPU cache line (2.3x over one-slot
+probing at 50% load on the CPU), which is no argument here: on the chip
+a window costs what its CHUNK gathered elements cost.
 
 Keys are int64 with EMPTY = int64 max as the sentinel (a real key equal to
 the sentinel is remapped by the caller — see state/tpu_backend.py).
@@ -145,11 +155,14 @@ def _window(table: jax.Array, keys: jax.Array, h0: jax.Array,
     """One CHUNK-slot window of every row's probe sequence, read and matched:
     (hit, fslot, pos_empty, eslot). ``hit``: the row's key sits in the window
     before its first EMPTY (``fslot`` is where); ``pos_empty``: offset of the
-    window's first EMPTY (CHUNK if none) and ``eslot`` its slot. The ONE
-    window read that ``lookup`` and every round of ``lookup_or_insert``
-    share; its ops carry probe.gather in their name path (HLO op_name; the
-    tf_op stat of an op's metadata in a TPU trace), whatever fusion numbers
-    the compiler assigns."""
+    window's first EMPTY (CHUNK if none) and ``eslot`` its slot. The
+    full-width read (both 32-bit words of every slot) that serves
+    ``lookup`` and every claiming round of ``lookup_or_insert``, which
+    must see whole keys to claim; the read-only first window of a wide
+    batch reads less (``_window0``) and falls back on this one. Its ops
+    carry probe.gather in their name path (HLO op_name; the tf_op stat of
+    an op's metadata in a TPU trace), whatever fusion numbers the compiler
+    assigns."""
     offs = jnp.arange(CHUNK, dtype=jnp.uint32)
     rng = jnp.arange(CHUNK, dtype=jnp.int32)
     C = jnp.int32(CHUNK)
@@ -171,6 +184,54 @@ def _window(table: jax.Array, keys: jax.Array, h0: jax.Array,
         eslot = jnp.take_along_axis(
             idx, jnp.minimum(pos_empty, C - 1)[:, None], axis=1)[:, 0]
     return pos_found < pos_empty, fslot, pos_empty, eslot
+
+
+def _window0(table: jax.Array, keys: jax.Array, h0: jax.Array,
+             mask: jax.Array, done: jax.Array):
+    """The read-only first window of a wide batch, from ONE 32-bit word a
+    slot: (hit, fslot, pos_empty, undecided), the first three as
+    ``_window`` gives them at ``base`` 0 for every row it DECIDES.
+
+    The int64 table is two 32-bit words a slot on the chip and a gather
+    costs by the element it fetches, so the window's eight LOW words find
+    where the row's key or the first EMPTY can be, and ONE high word, at
+    the first such candidate, says which it is. Decided, and exactly as
+    ``_window`` decides it: the candidate is the key (a hit), it is an
+    EMPTY (the row resumes there to claim), or the window holds no
+    candidate at all (a whole match implies a low-word match, so it holds
+    neither). UNDECIDED: the candidate failed its high-word check
+    (another key with the key's low word, or a key whose low word is
+    EMPTY's, all ones). Such a row reads as not hit with ``pos_empty`` 0:
+    it resumes where it stands, and whoever reads next reads the same
+    slots in full. Keys under 2^32 against keys under 2^32 (every NEXmark
+    id) leave none. ``undecided`` counts such rows among those not
+    ``done``."""
+    offs = jnp.arange(CHUNK, dtype=jnp.uint32)
+    rng = jnp.arange(CHUNK, dtype=jnp.int32)
+    C = jnp.int32(CHUNK)
+    empty_lo = jnp.uint32(np.uint64(EMPTY_KEY) & np.uint64(0xFFFFFFFF))
+    empty_hi = jnp.int32(EMPTY_KEY >> 32)
+    with jax.named_scope("probe.gather"):
+        idx = ((h0[:, None] + offs[None, :]) & mask).astype(jnp.int32)
+        # (the compiler's own low half of the table, X64SplitLow: no copy)
+        low = table.astype(jnp.uint32)[idx]                  # [n, CHUNK]
+        c_key = jnp.min(jnp.where(low == keys.astype(jnp.uint32)[:, None],
+                                  rng[None], C), axis=1)
+        c_emp = jnp.min(jnp.where(low == empty_lo, rng[None], C), axis=1)
+        first = jnp.minimum(c_key, c_emp)
+        # (by take_along_axis, as in _window and for its reason)
+        cslot = jnp.take_along_axis(
+            idx, jnp.minimum(first, C - 1)[:, None], axis=1)[:, 0]
+        high = (table >> 32).astype(jnp.int32)[cslot]        # [n]
+        cand = first < C
+        # (an EMPTY before a match, as _window's pos_found < pos_empty)
+        empty = cand & (c_emp == first) & (high == empty_hi)
+        hit = cand & (c_key == first) & ~empty \
+            & (high == (keys >> 32).astype(jnp.int32))
+        failed = cand & ~hit & ~empty
+        pos_empty = jnp.where(empty, first, jnp.where(failed, 0, C))
+        undecided = jnp.sum(failed & ~done, dtype=jnp.int32)
+    return hit, cslot, pos_empty, undecided
 
 
 def _unfinished(base: jax.Array, done: jax.Array) -> jax.Array:
@@ -310,9 +371,12 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
 
     A batch of ``_COMPACT_MIN_ROWS`` or more reads its first window at full
     width WITHOUT claiming (in steady state nearly every key is resident
-    there and nobody wants a slot), then compacts the rows still
-    unresolved into the narrowest of ``_tail_widths(n)`` that holds them
-    and runs the probe rounds over those lanes alone; with more unresolved
+    there and nobody wants a slot) and from the slots' low 32-bit words
+    alone, with one high word a row to tell a key from its low word's
+    namesakes (``_window0``: one ``[n, CHUNK]`` gather where whole keys
+    take two), then compacts the rows still unresolved into the narrowest
+    of ``_tail_widths(n)`` that holds them and runs the probe rounds over
+    those lanes alone; with more unresolved
     rows than the widest (cold start, prefill, growth, the re-homing of
     the live keys inside a reclaim, and any batch of a job whose keys
     come and go: a reclaimed key seen again is an insert, and every row
@@ -322,6 +386,24 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     smaller batches run the rounds at full width from the start, as every
     batch did before. The result is a pure function of (table, keys,
     valid) on every path.
+
+    A row the half-width window cannot decide (its candidate slot holds
+    another key with the same low word, or a key whose low word is all
+    ones) counts as unresolved and the rounds' first window reads its
+    slots in full: it claims, where it must, one round later than a row
+    that window decided, so among NEW keys that contend for a slot the
+    one each gets may differ from what whole-key reads would give; the
+    table's invariants do not. The worst case is bounded by what the
+    program sees: a batch with more undecided rows than its narrowest
+    loop holds (``n >> 6``: keys that share few low words, ``x << 32``, a
+    composite ``a << 32 | b`` with a handful of ``b``) reads the window
+    once more in full (``_window`` under a ``lax.cond`` on that device
+    count; no option, no host decision) and goes on exactly as if it had
+    read it so in the first place: it pays both reads (73 ms a 2^18-row
+    batch of resident keys ``x << 32`` against 2^24 slots where whole-key
+    reads took 49 and ids under 2^32 now take 37; the ``cond`` itself
+    costs 0.2 ms: PERF.md section 6, PR 52), not full-width claiming
+    rounds.
 
     ``handover=True`` (static: a second program) is for a caller that
     expects such batches, one after another: the full-width rounds then
@@ -358,11 +440,13 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     gets the hand-over program without an election, and nothing of the
     above is traced for it. Without ``handover`` it means nothing.
 
-    ``stats=True`` (static) appends an int32[3]: rows probed, rows that
+    ``stats=True`` (static) appends an int32[4]: rows probed, rows that
     entered a claiming loop (unresolved after the read-only window; every
-    probed row of a batch below the compaction width), and 1 if that loop
-    started at full width else 0; all three BEFORE any election, so that
-    a caller who picks its program by them keeps its pick. The electing
+    probed row of a batch below the compaction width), 1 if that loop
+    started at full width else 0, and rows the half-width window left
+    undecided (counted before any re-read; 0 below the compaction
+    width); all BEFORE any election, so that a caller who picks its
+    program by them keeps its pick. The electing
     program (``handover`` without ``distinct``) appends an int32[2]
     behind it: rows that stood behind a representative, and 1 if the
     batch elected else 0.
@@ -381,14 +465,25 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
         slot = jnp.full(n, -1, jnp.int32)
         rows = jnp.sum(~done, dtype=jnp.int32)
     if not widths:
-        n_tail, wide = rows, jnp.int32(1)
+        n_tail, wide, undecided = rows, jnp.int32(1), jnp.int32(0)
         with jax.named_scope("probe.tail"):
             table, _base, slot, _done = _claim_loop(
                 table_keys, keys, h0, mask, base, slot, done)
     else:
         with jax.named_scope("probe.window0"):
-            hit, fslot, pos_empty, _ = _window(table_keys, keys, h0, base,
-                                               mask)
+            hit, fslot, pos_empty, undecided = _window0(
+                table_keys, keys, h0, mask, done)
+        # the bounded worst case: more undecided rows than the narrowest
+        # loop holds, and the window is read once more, in full. By the
+        # tail's name: it is what window 0 could not do, and in the mesh
+        # step the compiler feeds this branch and the tail's loops from
+        # ONE copy of a table half, which a trace gives to one region
+        with jax.named_scope("probe.tail"):
+            hit, fslot, pos_empty = jax.lax.cond(
+                undecided > widths[0],
+                lambda: _window(table_keys, keys, h0, base, mask)[:3],
+                lambda: (hit, fslot, pos_empty))
+        with jax.named_scope("probe.window0"):
             slot = jnp.where((~done) & hit, fslot, slot)
             done = done | hit
             base = _advance(base, done, pos_empty)
@@ -486,7 +581,7 @@ def lookup_or_insert(table_keys: jax.Array, keys: jax.Array,
     out = (table, slot, slot >= 0)
     if not stats:
         return out
-    out = (*out, jnp.stack([rows, n_tail, wide]))
+    out = (*out, jnp.stack([rows, n_tail, wide, undecided]))
     if elects:
         out = (*out, elected if widths else jnp.zeros(2, jnp.int32))
     return out

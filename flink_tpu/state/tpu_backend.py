@@ -438,16 +438,17 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # accumulates in a device counter checked at watermark boundaries
         self._defer = bool(defer_overflow)
         self._dropped = jnp.zeros((), jnp.int64)
-        # the probe's counters (rows, tail rows, wide batches) accumulate
-        # on the device like _dropped; note_probe_stats hands them to
+        # the probe's counters (rows, tail rows, wide batches, rows its
+        # half-width first window left undecided) accumulate on the
+        # device like _dropped; note_probe_stats hands them to
         # DEVICE_STATS once a copy taken at an earlier batch has landed
-        self._probe = jnp.zeros(3, jnp.int64)
+        self._probe = jnp.zeros(4, jnp.int64)
         # beside them what the wide-batch program's election counts (rows
         # that stood behind a representative, batches that elected); only
         # a batch through that program adds to it
         self._elected = jnp.zeros(2, jnp.int64)
         self._probe_sent: Optional[tuple[jax.Array, jax.Array]] = None
-        self._probe_noted = np.zeros(5, np.int64)
+        self._probe_noted = np.zeros(6, np.int64)
         # probes dispatched, and how many of them the counters last sent
         # and last noted had seen (note_probe_stats)
         self._probe_calls = self._probe_sent_calls = 0
@@ -1231,8 +1232,10 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             # lint: sync-ok the copy has landed (or the caller syncs anyway)
             now = np.concatenate(jax.device_get(sent))
             calls = self._probe_calls if block else self._probe_sent_calls
-            rows, tail, wide, elected, elections = now - self._probe_noted
-            DEVICE_STATS.note_probe(rows, tail, wide, elected, elections)
+            rows, tail, wide, undecided, elected, elections = \
+                now - self._probe_noted
+            DEVICE_STATS.note_probe(rows, tail, wide, elected, elections,
+                                    undecided)
             if calls > self._probe_noted_calls:
                 # every probe noted started its claiming rounds at full
                 # width

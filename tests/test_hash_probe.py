@@ -1,7 +1,9 @@
 """Property test of ``ops/hash_table.lookup_or_insert`` against a plain
 dict: the read-only first window, the compacted (narrow) loop, the
 full-width loop it falls back to, and the small-batch path, each held to
-the table's invariants and to the three counters ``stats=True`` reports."""
+the table's invariants and to the four counters ``stats=True`` reports;
+and the half-width first window (PR 52: one 32-bit word a slot) against a
+reference that reads that window in full."""
 
 import numpy as np
 import pytest
@@ -29,6 +31,23 @@ def _homes(keys: np.ndarray, cap: int) -> np.ndarray:
 def _resident(table: np.ndarray) -> dict[int, int]:
     occ = np.flatnonzero(table != EMPTY_KEY)
     return dict(zip(table[occ].tolist(), occ.tolist()))
+
+
+def _failed_candidates(table: np.ndarray, keys: np.ndarray) -> np.ndarray:
+    """Per row, whether the half-width first window cannot decide it: its
+    first slot whose LOW word is the key's or EMPTY's holds a high word
+    that is neither the key's nor EMPTY's (another key with that low
+    word). NumPy on the table alone."""
+    cap = len(table)
+    idx = (_homes(keys, cap)[:, None] + np.arange(CHUNK)) % cap
+    lo, hi = table[idx] & 0xFFFFFFFF, table[idx] >> 32
+    cand = (lo == (keys & 0xFFFFFFFF)[:, None]) | (lo == 0xFFFFFFFF)
+    first = cand.argmax(axis=1)
+    at = np.arange(len(keys))
+    chi = hi[at, first]
+    is_key = (lo[at, first] == keys & 0xFFFFFFFF) & (chi == keys >> 32)
+    is_empty = (lo[at, first] == 0xFFFFFFFF) & (chi == EMPTY_KEY >> 32)
+    return cand.any(axis=1) & ~is_key & ~is_empty
 
 
 def _fill(cap: int, keys: np.ndarray, batch: int = 512) -> np.ndarray:
@@ -82,7 +101,10 @@ def _check(before: np.ndarray, keys: np.ndarray, valid, expect_wide=None):
         assert (after[(home[on_path] + d) % cap] != EMPTY_KEY).all()
     # rows that gave up had nowhere to go within MAX_PROBES
     assert (dist < MAX_PROBES + CHUNK).all()
-    # the three counters, from the dict alone
+    # the four counters, from the dict and the table alone: a row leaves
+    # the first window resolved if its key sits there, unless that window,
+    # read from the slots' low words, left it undecided (and the batch had
+    # too few such rows to read the window again in full)
     rows = int(v.sum())
     widths = H._tail_widths(n)
     if widths:
@@ -90,11 +112,15 @@ def _check(before: np.ndarray, keys: np.ndarray, valid, expect_wide=None):
         in_first_window = np.array(
             [k in was and (was[k] - h) % cap < CHUNK
              for k, h in zip(keys.tolist(), khome.tolist())])
+        failed = _failed_candidates(before, keys) & v
+        undecided = int(failed.sum())
+        if undecided <= widths[0]:
+            in_first_window &= ~failed
         tail = int((v & ~in_first_window).sum())
         wide = int(tail > widths[-1])
     else:
-        tail, wide = rows, 1
-    assert np.asarray(stats).tolist() == [rows, tail, wide]
+        tail, wide, undecided = rows, 1, 0
+    assert np.asarray(stats).tolist() == [rows, tail, wide, undecided]
     if expect_wide is not None:
         assert wide == expect_wide
     return after, slots, ok, np.asarray(stats)
@@ -223,7 +249,8 @@ def test_small_batches_run_the_full_width_loop(half_full):
     batch = np.concatenate([keys[:5], np.array([1 << 54, 1 << 54, 9],
                                                np.int64)])
     _after, slots, ok, stats = _check(table, batch, None, expect_wide=1)
-    assert ok.all() and slots[5] == slots[6] and stats.tolist() == [8, 8, 1]
+    assert ok.all() and slots[5] == slots[6]
+    assert stats.tolist() == [8, 8, 1, 0]
 
 
 def test_backend_hands_the_counters_to_device_stats():
@@ -330,7 +357,7 @@ def test_the_electing_program_places_every_row_where_the_plain_one_does(
         case, load):
     """``handover=True`` from a caller whose keys may repeat: a wide batch
     sends ONE lane a distinct unresolved key through the rounds and the
-    others take its slot. Table, slots, ``ok`` and the three counters are
+    others take its slot. Table, slots, ``ok`` and the four counters are
     the plain program's, bit for bit, and the elected rows are those of
     the keys that kept their scratch cell: all but one lane of each."""
     hot, per, cold, valid_share = _ELECTIONS[case]
@@ -431,3 +458,178 @@ def test_backend_picks_the_handover_program_while_batches_start_wide():
     assert moved["probe_wide_batches_total"] == 5
     assert moved["probe_elected_batches_total"] == 4
     assert 4 * 0.4 * N < moved["probe_elected_rows_total"] < 4 * 0.5 * N
+
+
+# ---------------------------------------------------------------------------
+# PR 52: the first window reads ONE 32-bit word a slot
+# ---------------------------------------------------------------------------
+
+_FORMS = {"plain": {}, "electing": {"handover": True},
+          "distinct": {"handover": True, "distinct": True}}
+_REFERENCES: dict = {}
+
+
+def _window0_in_full(table, keys, h0, mask, done):
+    """What ``_window0`` stands in for: the first window read in full."""
+    hit, fslot, pos_empty, _ = H._window(table, keys, h0,
+                                         jnp.zeros_like(h0), mask)
+    return hit, fslot, pos_empty, jnp.int32(0)
+
+
+def _reference(form: str, *args):
+    """``lookup_or_insert(stats=True)`` in ``form`` with its first window
+    read by ``_window``: the program as it was before the half-width
+    window, traced from the same source with that one function swapped."""
+    from unittest import mock
+
+    if form not in _REFERENCES:
+        fn = lookup_or_insert.__wrapped__
+        _REFERENCES[form] = jax.jit(
+            lambda *a: fn(*a, stats=True, **_FORMS[form]))
+    with mock.patch.object(H, "_window0", _window0_in_full):
+        return _REFERENCES[form](*args)
+
+
+def _colliding_with_residents(table: np.ndarray, want: int, rng):
+    """``want`` new keys ``a << 32 | low`` whose HOME slot holds a resident
+    key with the same low word (and another high word): the half-width
+    window's candidate is that slot, at offset 0, and fails."""
+    cap = len(table)
+    out = []
+    highs = np.arange(1, 1 << 17, dtype=np.int64)
+    for slot in rng.permutation(np.flatnonzero(table != EMPTY_KEY)):
+        cand = (highs << 32) | (int(table[slot]) & 0xFFFFFFFF)
+        at_home = cand[(_homes(cand, cap) == slot) & (cand != table[slot])]
+        out.extend(at_home[:1].tolist())
+        if len(out) == want:
+            return np.array(out, np.int64)
+    raise AssertionError("no colliding keys found")
+
+
+def _under_2_32(rng, _table):
+    return rng.integers(0, 1 << 32, N).astype(np.int64)
+
+
+def _dense_ids(rng, _table):
+    """Resident from the start (the test fills them in): batches of these
+    leave a tail that the narrow loops hold."""
+    return rng.integers(0, 2 * N, N).astype(np.int64)
+
+
+def _shifted_left_32(rng, _table):
+    return rng.integers(0, 3 * N, N).astype(np.int64) << 32
+
+
+def _composite_of_few_low_words(rng, _table):
+    return (rng.integers(0, 1 << 20, N).astype(np.int64) << 32
+            | rng.integers(0, 4, N))
+
+
+def _low_words_all_ones(rng, _table):
+    return (rng.integers(0, 3 * N, N).astype(np.int64) << 32) | 0xFFFFFFFF
+
+
+def _few_shared_low_words(rng, table):
+    """Ids under 2^32, and on 40 rows 20 keys that share a resident
+    key's low word at their home slot."""
+    keys = _dense_ids(rng, table)
+    if (table != EMPTY_KEY).any():
+        keys[rng.choice(N, 40, replace=False)] = np.repeat(
+            _colliding_with_residents(table, 20, rng), 2)
+    return keys
+
+
+def _few_low_words_all_ones(rng, table):
+    """Resident ids, a quarter new ids under 2^32, and 256 keys whose low
+    word is EMPTY's: a row whose window meets one of those before its own
+    key or its first EMPTY takes it for a candidate EMPTY, and the high
+    word says no."""
+    keys = np.where(rng.random(N) < 0.25, _under_2_32(rng, table),
+                    _dense_ids(rng, table))
+    keys[:256] = (np.arange(256, dtype=np.int64) << 32) | 0xFFFFFFFF
+    return keys
+
+
+#: name -> (a batch's keys from (rng, the table so far), what the batches'
+#: undecided counts must show: none / some batch with a few, read once /
+#: some batch with more than N >> 6, read again in full)
+_KEY_FAMILIES = {
+    "ids_under_2_32": (_under_2_32, "none"),
+    "dense_ids": (_dense_ids, "none"),
+    "shifted_left_32": (_shifted_left_32, "reread"),
+    "composite_of_few_low_words": (_composite_of_few_low_words, "reread"),
+    "low_words_all_ones": (_low_words_all_ones, "reread"),
+    "few_shared_low_words": (_few_shared_low_words, "few"),
+    "few_low_words_all_ones": (_few_low_words_all_ones, "few"),
+}
+
+
+@pytest.mark.parametrize("form", list(_FORMS))
+@pytest.mark.parametrize("family", list(_KEY_FAMILIES))
+def test_half_width_first_window(family, form):
+    """Three batches a table, with a valid mask, through every program
+    form. Every batch: the table's invariants and the four counters
+    (``_check``: the undecided count is NumPy's count of failed
+    candidates), and the form's table, slots, ``ok`` and counters are the
+    plain program's. A batch with no undecided row, or with more than
+    ``N >> 6`` (which reads the window again in full), is the reference's
+    to the bit, its tail count too; a batch with a few may place a new
+    key elsewhere than the reference does, invariants intact."""
+    gen, shows = _KEY_FAMILIES[family]
+    rng = np.random.default_rng(len(family))
+    # (keys under 2^32 at load 0.45, the dense ids among them)
+    table = _fill(CAP, np.unique(np.concatenate([
+        np.arange(2 * N), rng.choice(1 << 32, int(0.2 * CAP))])
+        ).astype(np.int64))
+    seen = []
+    for _ in range(3):
+        keys = gen(rng, table)
+        valid = rng.random(N) < 0.9
+        if form == "distinct":
+            uniq = np.unique(keys[valid])
+            keys = np.resize(uniq, N)
+            valid = np.arange(N) < len(uniq)
+        after, slots, ok, stats = _check(table, keys, valid)
+        args = (jnp.asarray(table), jnp.asarray(keys), jnp.asarray(valid))
+        out = [np.asarray(x) for x in lookup_or_insert(
+            *args, stats=True, **_FORMS[form])]
+        for a, b in zip((after, slots, ok, stats), out):
+            np.testing.assert_array_equal(a, b)
+        undecided = int(stats[3])
+        if undecided == 0 or undecided > N >> 6:
+            ref = [np.asarray(x) for x in _reference(form, *args)]
+            assert len(ref) == len(out)
+            for a, b in zip(out[:3], ref[:3]):
+                np.testing.assert_array_equal(a, b)
+            assert out[3][:3].tolist() == ref[3][:3].tolist()
+        seen.append(undecided)
+        table = after
+    if shows == "none":
+        assert seen == [0, 0, 0]
+    elif shows == "few":
+        assert any(0 < u <= N >> 6 for u in seen), seen
+    else:
+        assert any(u > N >> 6 for u in seen), seen
+
+
+def test_backend_counts_the_rows_the_first_window_left_undecided():
+    """``probe_undecided_rows_total``: 0 for ids under 2^32, and what
+    NumPy counts on the table for keys ``x << 32`` (one low word)."""
+    from flink_tpu.core import KeyGroupRange
+    from flink_tpu.metrics import DEVICE_STATS
+    from flink_tpu.state.tpu_backend import TpuKeyedStateBackend
+
+    def undecided_moved(keys) -> int:
+        before = DEVICE_STATS.snapshot()["probe_undecided_rows_total"]
+        be.slots_for_batch_device(jnp.asarray(keys))
+        be.check_health()
+        return DEVICE_STATS.snapshot()["probe_undecided_rows_total"] - before
+
+    be = TpuKeyedStateBackend(KeyGroupRange(0, 127), 128, capacity=CAP,
+                              defer_overflow=True)
+    ids = np.arange(N, dtype=np.int64) * 5 + 1
+    assert undecided_moved(ids) == 0 and undecided_moved(ids) == 0
+    shifted = ids << 32
+    assert undecided_moved(shifted) == 0        # nothing shares a low word 0
+    expect = int(_failed_candidates(np.asarray(be.table), shifted).sum())
+    assert undecided_moved(shifted) == expect > N >> 6

@@ -523,6 +523,32 @@ def _assert_probe_is_countable(hlo: str) -> None:
         "while body: probe_rounds_p50 would read 0"
 
 
+def _assert_window0_reads_one_word_a_slot(hlo: str, slots: int) -> None:
+    """PR 52: the first window (outside the tail's `cond` that re-reads a
+    batch of colliding low words in full) holds ONE `[2^18, 8]` gather (the
+    slots' low words; the high word's is `[2^18]`), and the v5e's
+    compiler keeps BOTH 32-bit halves of the table in its fast memory
+    space `S(1)`: a gather from a half left outside takes twice as long
+    (PERF.md section 6, PRs 26 and 52)."""
+    import re
+
+    window0 = [line for line in hlo.splitlines()
+               if re.search(r"= \S+ gather\(", line)
+               and re.search(r'op_name="[^"]*/probe\.window0/probe\.gather/',
+                             line)]
+    shapes = sorted(line.split("= ")[1].split("{")[0] for line in window0)
+    assert shapes == ["s32[262144]", "u32[262144,8]"], window0
+    # (the re-read, `_window` in full, is the tail's: two more, in a branch)
+    reread = [line for line in hlo.splitlines()
+              if re.search(r"= u32\[262144,8\]\S* gather\(", line)
+              and "/probe.tail/cond/branch_1_fun/probe.gather/" in line]
+    assert len(reread) == 2, reread
+    halves = [line.split(" custom-call(")[0] for line in hlo.splitlines()
+              if re.search(r'custom_call_target="X64Split(Low|High)"', line)
+              and f"u32[{slots}]" in line.split(" custom-call(")[0]]
+    assert len(halves) == 2 and all("S(1)" in h for h in halves), halves
+
+
 def _hash_probe(devices):
     from flink_tpu.ops.hash_table import lookup_or_insert
 
@@ -549,8 +575,12 @@ def _electing_probe(devices):
 def test_hash_probe_compiles_at_the_benchmark_shape(v5e_devices):
     """[2^24] slots x [2^18] rows, with the counters: the first window, the
     compaction (a sort), both narrow loops and the wide one under one
-    `lax.switch`, for the v5e's compiler."""
-    _assert_probe_is_countable(_hash_probe(v5e_devices).as_text())
+    `lax.switch`, for the v5e's compiler; the first window reads one
+    32-bit word a slot from a table whose halves both sit in fast
+    memory."""
+    hlo = _hash_probe(v5e_devices).as_text()
+    _assert_probe_is_countable(hlo)
+    _assert_window0_reads_one_word_a_slot(hlo, 1 << 24)
 
 
 def test_electing_probe_compiles_at_the_benchmark_shape(v5e_devices):
@@ -559,7 +589,8 @@ def test_electing_probe_compiles_at_the_benchmark_shape(v5e_devices):
     the full-width one. Its rounds stay countable, the election's own
     `scatter-min` lies outside every loop, and the v5e's compiler keeps
     BOTH 32-bit halves of the 2^23-slot table in its fast memory space
-    for window 0's gathers, as it does for the program without an
+    for window 0's gathers (one `[2^18, 8]` of low words and one `[2^18]`
+    of high words since PR 52), as it does for the program without an
     election (a form of the election that chained its loops through
     `lax.cond`s lost one half: PERF.md section 6, PR 46)."""
     import re
@@ -570,11 +601,7 @@ def test_electing_probe_compiles_at_the_benchmark_shape(v5e_devices):
               if re.search(r'op_name="[^"]*probe\.elect/scatter-min"', line)]
     assert elects and not any(re.search(_CLAIM_PATH, line)
                               for line in elects)
-    halves = [line for line in hlo.splitlines()
-              if re.search(r'custom_call_target="X64Split(Low|High)"', line)
-              and "u32[8388608]" in line.split(" custom-call(")[0]]
-    assert len(halves) == 2 and all("S(1)" in line.split(" custom-call(")[0]
-                                    for line in halves), halves
+    _assert_window0_reads_one_word_a_slot(hlo, 1 << 23)
 
 
 @pytest.mark.parametrize("rows", [64, 1 << 12])
@@ -1049,29 +1076,33 @@ def test_the_one_chip_programs_lower_to_what_they_lowered_to(v5e_devices,
     assert got[program] == _ONE_CHIP_DIGESTS_AT_4081619[program]
 
 
-#: sha256 of the StableHLO of the hash probe AS ITS CALLERS OUTSIDE THE
-#: ONE-CHIP BACKEND'S WIDE BATCHES GET IT, at the parent of PR 46 (commit
-#: f6f8d19), which gave the backend's wide batches a program that elects
-#: one lane a distinct key and must not touch the others by a letter: the
-#: plain program with its counters (X, S, U, Q past their prefill), as the
-#: mesh step calls it (a valid mask, no counters) and below the compaction
-#: width; the hand-over program for keys that cannot repeat (`distinct`:
-#: what `handover=True` was), alone, inside a reclaim whose re-homing
-#: chunk compacts (2^12 slots: the digests above are of 2^10) and inside
-#: the session step.
-_PROBE_DIGESTS_AT_F6F8D19 = {
+#: sha256 of the StableHLO of the hash probe as its callers get it, AT PR
+#: 52, which MEANT to change all six: a batch that compacts reads its
+#: first window from the slots' low 32-bit words (`_window0`), and the
+#: counters are four (the fourth: rows that window left undecided), which
+#: is all that moved the program below the compaction width. The plain
+#: program with its counters (X, S, U, Q, D past their prefill), as the
+#: mesh step calls it (a valid mask, no counters) and below the
+#: compaction width; the hand-over program for keys that cannot repeat
+#: (`distinct`), alone, inside a reclaim whose re-homing chunk compacts
+#: (2^12 slots: the digests above are of 2^10, with no first window, and
+#: did not move) and inside the session step. They were PR 46's control
+#: (`_PROBE_DIGESTS_AT_F6F8D19`: the parent of the election) until this
+#: PR; a PR that does not mean to change the probe leaves them as they
+#: are.
+_PROBE_DIGESTS_AT_PR_52 = {
     "jit_lookup_or_insert.plain":
-        "6a43d1f0b91f2268da762692b29da611ec863e75069336f43185bb5598edba79",
+        "2275c2415d8bd083a709ffca524c47faa9a3866f75f173f7a7ccabd940dc9404",
     "jit_lookup_or_insert.plain.mesh":
-        "7abaaff8ffee251f7b416ffd3e8af05fd82bf7d3e76e746fe36e1c4f407bd742",
+        "33bb1c7f53016cf4823585a85773b8f258703da53a77bd743db3587ef50f026f",
     "jit_lookup_or_insert.plain.small":
-        "9f5be740fbf7cf03c85023870abe12c6a336995a522b7465b8602c282143f584",
+        "246356872ce026ac84b5e0dcd46696bd1a3fd476bd1c5dd3c9327f4b9de2d197",
     "jit_lookup_or_insert.distinct":
-        "0e0737e37b578f637a9c9150016803f942c6c539b647033ac16139d8f6c81e83",
+        "0f7925ce669381e684aeaaf9b3c012477685f591a4bd7b1ec440f4f05c37a942",
     "jit_reclaim.q5.handover":
-        "d945af33957392bcbaf1a871c77ab31c15edc580f2b5b5e8fd927d2844949d8a",
+        "ee642c6962662b969b1e150dd85d6cf754466bc455c6186196cd9343b172be32",
     "jit_step.session":
-        "844a7be154d0c4523b3bc1b3760e7ec985db163276454b19a2daa4d8f6ee92d9",
+        "a3410c871a854defd1530ff7b375da9589713f0417997497bdeee8523809544c",
 }
 
 
@@ -1129,20 +1160,21 @@ def _probe_digest_programs(devices) -> dict:
             for name, low in lowered.items()}
 
 
-@pytest.mark.parametrize("program", list(_PROBE_DIGESTS_AT_F6F8D19))
+@pytest.mark.parametrize("program", list(_PROBE_DIGESTS_AT_PR_52))
 def test_the_unelecting_probe_lowers_to_what_it_lowered_to(v5e_devices,
                                                            program):
-    """PR 46 changes ONE program, the one-chip backend's wide-batch probe.
-    The plain program (X, S, U, Q; the mesh step of M and F), and the
-    hand-over program of callers whose keys cannot repeat (the reclaim's
-    re-homing on both stacks, the session step of K) lower, letter for
-    letter, to the text they lowered to at the parent commit: which fast
-    memory the v5e's compiler gives the table's halves turns on how the
-    rest of a probe program is written (ROADMAP D13)."""
+    """The probe's forms outside the one-chip backend's wide batches
+    (the plain program of X, S, U, Q, D and of the mesh step of M, Z and
+    F; the hand-over program of callers whose keys cannot repeat: the
+    reclaim's re-homing on both stacks, the session step of K) lower,
+    letter for letter, to the text PR 52 left: which fast memory the
+    v5e's compiler gives the table's halves turns on how the rest of a
+    probe program is written (ROADMAP D13), so a PR that touches one form
+    shows here that it left the others alone."""
     got = _compiled("probe.digests",
                     lambda: _probe_digest_programs(v5e_devices))
-    assert set(got) == set(_PROBE_DIGESTS_AT_F6F8D19)
-    assert got[program] == _PROBE_DIGESTS_AT_F6F8D19[program]
+    assert set(got) == set(_PROBE_DIGESTS_AT_PR_52)
+    assert got[program] == _PROBE_DIGESTS_AT_PR_52[program]
 
 
 # ---------------------------------------------------------------------------
