@@ -220,7 +220,7 @@ class TaskMetrics:
         self.io_timers = None
 
     def bind_io_timers(self, timers) -> None:
-        """Expose a task's busy/idle/backpressured accounting as gauges
+        """Expose a task's busy/idle/backpressured/cpu accounting as gauges
         (reference TaskIOMetricGroup busyTimeMsPerSecond family). The
         timers object outlives the task thread, so reporters keep a
         stable terminal reading after the job finishes."""
@@ -231,6 +231,10 @@ class TaskMetrics:
         g.gauge("backPressuredTimeMsPerSecond",
                 lambda: timers.backpressured_ms_per_s)
         g.gauge("busyTimeRatio", lambda: timers.busy_ratio)
+        # what the mailbox thread computed; busy - cpu is the time it
+        # stood still inside a turn (device, GIL, machine)
+        g.gauge("cpuTimeMsPerSecond", lambda: timers.cpu_ms_per_s)
+        g.gauge("cpuTimeRatio", lambda: timers.cpu_ratio)
 
     def bind_input_gates(self, gates) -> None:
         """Expose, per input gate, how long the element polled last sat

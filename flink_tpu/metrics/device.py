@@ -125,6 +125,13 @@ class DeviceStats:
         # by the steps in flight
         self._mesh_steps = 0
         self._mesh_exchange_rounds = 0
+        # the mesh operator's one host wait (PR 53): times its task's
+        # thread WAITED for a reading that had not landed (a pressure
+        # probe, a reclaim's counts: half the headroom stepped since it
+        # went out, or a snapshot / rebuild / finish settling a reclaim)
+        # and the microseconds it stood there
+        self._mesh_reading_waits = 0
+        self._mesh_reading_wait_us = 0.0
         # mesh insert accounting (PR 41): rows of the mesh step that
         # claimed a new slot (all shards), over the rows stepped between
         # the two readings of the shards' occupied slots that say so
@@ -463,6 +470,17 @@ class DeviceStats:
         """(mesh steps, exchange rounds they took)."""
         with self._lock:
             return self._mesh_steps, self._mesh_exchange_rounds
+
+    def note_mesh_reading_wait(self, us: float) -> None:
+        with self._lock:
+            self._mesh_reading_waits += 1
+            self._mesh_reading_wait_us += float(us)
+
+    @property
+    def mesh_reading_wait_counts(self) -> tuple[int, float]:
+        """(host waits for a mesh reading, microseconds waited)."""
+        with self._lock:
+            return self._mesh_reading_waits, self._mesh_reading_wait_us
 
     def note_mesh_inserts(self, inserted: int, stepped: int) -> None:
         with self._lock:
@@ -830,6 +848,9 @@ class DeviceStats:
                 "probe_elected_batches_total": self._probe_elected_batches,
                 "mesh_steps_total": self._mesh_steps,
                 "mesh_exchange_rounds_total": self._mesh_exchange_rounds,
+                "mesh_reading_waits_total": self._mesh_reading_waits,
+                "mesh_reading_wait_us_total": round(
+                    self._mesh_reading_wait_us, 1),
                 "mesh_inserted_rows_total": self._mesh_inserted_rows,
                 "mesh_stepped_rows_total": self._mesh_stepped_rows,
                 "fire_selects_total": self._fire_selects,
@@ -952,6 +973,7 @@ class DeviceStats:
             self._probe_elected_rows = self._probe_elected_batches = 0
             self._probe_undecided_rows = 0
             self._mesh_steps = self._mesh_exchange_rounds = 0
+            self._mesh_reading_waits, self._mesh_reading_wait_us = 0, 0.0
             self._mesh_inserted_rows = self._mesh_stepped_rows = 0
             self._fire_selects = self._fire_select_passes = 0
             self._fire_select_sort = self._fire_select_guarded = 0
@@ -1766,6 +1788,13 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_mesh_exchange_rounds_total)
     g.gauge("mesh_steps_total", lambda: s.mesh_step_counts[0])
     g.gauge("mesh_exchange_rounds_total", lambda: s.mesh_step_counts[1])
+    # host waits for a mesh reading and the microseconds waited
+    # (prometheus: flink_tpu_device_mesh_reading_waits_total /
+    # flink_tpu_device_mesh_reading_wait_us_total)
+    g.gauge("mesh_reading_waits_total",
+            lambda: s.mesh_reading_wait_counts[0])
+    g.gauge("mesh_reading_wait_us_total",
+            lambda: s.mesh_reading_wait_counts[1])
     # rows of the mesh step that claimed a new slot, over the rows
     # stepped between the readings that say so (prometheus:
     # flink_tpu_device_mesh_inserted_rows_total /

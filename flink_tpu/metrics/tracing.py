@@ -60,7 +60,8 @@ __all__ = [
     "Span", "SpanBuilder", "TraceContext", "TraceReporter",
     "InMemoryTraceReporter", "FlightRecorder", "Tracer",
     "TRACER", "FLIGHT_RECORDER", "chrome_trace_events",
-    "current_context", "use_context", "now_ms", "now_ns", "Stage",
+    "current_context", "use_context", "now_ms", "now_ns", "thread_cpu_ns",
+    "Stage",
     "record_flight_event", "dump_flight_recorder", "SPAN_INVENTORY",
 ]
 
@@ -79,6 +80,12 @@ def now_ns() -> int:
 def now_ms() -> int:
     """Epoch milliseconds measured on the monotonic clock."""
     return now_ns() // _NS_PER_MS
+
+
+#: CPU nanoseconds the CALLING thread has run (user + system). Beside the
+#: wall clock it tells a thread that computes from one that stands still:
+#: waiting for the device, for the GIL, or for the machine.
+thread_cpu_ns = time.thread_time_ns
 
 
 def _new_id() -> str:
@@ -298,6 +305,16 @@ class Stage:
     seconds is added to on close, so a stage total and its spans come
     from one timing site.
 
+    From the same two sites the stage reads the opening thread's CPU
+    clock (``thread_cpu_ns``), inside the wall stamps, and closes with
+    ``cpu_ms``: the part of its duration the thread was computing. The
+    rest it stood still (a device wait, the GIL in another thread's
+    hands, a machine that did not run it). A stage closed by another
+    thread than opened it (a reclaim's) has no ``cpu_ms``. The clock is
+    as fine as the kernel keeps it: nanoseconds on a plain Linux host; a
+    kernel that samples CPU time by ticks (10 ms on some sandboxed hosts)
+    gives multiples of a tick, good only added up over many stages.
+
     Every stage carries ``task`` (the mailbox thread's name, which is the
     task id) and the caller's ``seq``; ``(scope.Name, task, seq)``
     identifies the interval in both records. Stages run per batch, per
@@ -305,22 +322,26 @@ class Stage:
     that is opened is reported: a caller that cannot know beforehand
     whether an attempt is an interval at all (a source read that may
     return nothing) stamps ``now_ns()`` before it and opens the stage
-    afterwards with ``start_ns=`` that stamp. The span then starts at the
-    stamp; the annotation, which cannot be backdated, starts where the
-    stage was opened (a source's ``read_ms`` later)."""
+    afterwards with ``start_ns=`` that stamp (and ``start_cpu_ns=`` the
+    CPU stamp beside it). The span then starts at the stamp; the
+    annotation, which cannot be backdated, starts where the stage was
+    opened (a source's ``read_ms`` later)."""
 
     __slots__ = ("_tracer", "scope", "name", "attrs", "_parent", "_total",
                  "_ann", "_late", "_ctx", "_ctx_cm", "_open", "start_ns",
-                 "end_ns")
+                 "end_ns", "_thread", "_cpu_ns")
 
     def __init__(self, tracer: "Tracer", scope: str, name: str,
                  parent: Optional[TraceContext], total: Optional[tuple],
-                 attrs: dict, start_ns: Optional[int] = None):
+                 attrs: dict, start_ns: Optional[int] = None,
+                 start_cpu_ns: Optional[int] = None):
         self._tracer = tracer
         self.scope = scope
         self.name = name
-        attrs.setdefault("task", threading.current_thread().name)
+        thread = threading.current_thread()
+        attrs.setdefault("task", thread.name)
         self.attrs = attrs
+        self._thread = thread.ident
         self._parent = parent
         self._total = total
         self._ctx: Optional[TraceContext] = None
@@ -335,6 +356,8 @@ class Stage:
         else:
             self._ann = self._late = None
         self.start_ns = now_ns() if start_ns is None else start_ns
+        self._cpu_ns = (thread_cpu_ns() if start_cpu_ns is None
+                        else start_cpu_ns)
 
     @property
     def context(self) -> Optional[TraceContext]:
@@ -371,12 +394,17 @@ class Stage:
     def duration_ms(self) -> float:
         return self.duration_ns / 1e6
 
-    def close(self, **attrs: Any) -> None:
+    def close(self, end_ns: Optional[int] = None, **attrs: Any) -> None:
+        """``end_ns``: a stamp the caller took to compute an attribute
+        from the stage's own duration (a source's ``emit_ms``)."""
         if not self._open:
             return
         for k, v in attrs.items():
             self.set(k, v)
-        self.end_ns = now_ns()
+        if self._thread == threading.get_ident():
+            self.set("cpu_ms",
+                     round((thread_cpu_ns() - self._cpu_ns) / 1e6, 3))
+        self.end_ns = now_ns() if end_ns is None else end_ns
         self._open = False
         if self._ann is not None:
             if self._late:
@@ -604,10 +632,11 @@ class Tracer:
     def stage(self, scope: str, name: str,
               parent: Optional[TraceContext] = None,
               total: Optional[tuple] = None,
-              start_ns: Optional[int] = None, **attrs: Any) -> Stage:
+              start_ns: Optional[int] = None,
+              start_cpu_ns: Optional[int] = None, **attrs: Any) -> Stage:
         """A stage interval as a context manager (see :class:`Stage`)."""
         return Stage(self, scope, name, parent or current_context(), total,
-                     attrs, start_ns)
+                     attrs, start_ns, start_cpu_ns)
 
     def open_stage(self, scope: str, name: str,
                    parent: Optional[TraceContext] = None,
@@ -821,7 +850,8 @@ SPAN_INVENTORY: tuple = (
      "queued_ms, queue_depth)"),
     ("task", "SourceBatch",
      "runtime/stream_task.py — one source read→emit mailbox cycle "
-     "(stage span)"),
+     "(stage span: records, read_ms, emit_ms, blocked_ms: the part of "
+     "emit_ms its writers stood in a full channel)"),
     ("task", "WaitInput",
      "runtime/stream_task.py OneInput/TwoInputStreamTask.invoke — first "
      "empty input poll → the next event, one span per wait (stage span: "
@@ -881,7 +911,8 @@ SPAN_INVENTORY: tuple = (
      "runtime/operators/device_session.py — host time to enqueue one "
      "batch's ingest programs (stage span: programs); "
      "runtime/operators/mesh_window.py _flush — one [D, B] block's step "
-     "and the look at the pressure probe (seq: the block's ordinal)"),
+     "and the look at the pressure probe (seq: the block's ordinal; "
+     "reading_wait_ms on a block that waited for a reading)"),
     ("window", "Reclaim",
      "runtime/operators/device_window.py _apply_health — the backend's "
      "reclaim (state/tpu_backend.py reclaim: the table rebuilt at its own "

@@ -182,6 +182,9 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         # host (handed to DEVICE_STATS once landed, never waited for)
         self._block_seq = 0
         self._rounds_sent: deque = deque()
+        # ns the task's thread has WAITED for a reading that had not
+        # landed (_await_reading), all of this operator's
+        self._reading_wait_ns = 0
         # host-side staging buffers for [D, B] blocks
         self._buf_keys: list[np.ndarray] = []
         self._buf_panes: list[np.ndarray] = []
@@ -359,8 +362,12 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
                 nbytes = pytree_nbytes(block)
                 up.set("bytes", nbytes)
                 DEVICE_STATS.note_h2d(nbytes, n_valid)
-            with self._dispatch_stage(ring_rows=ring_rows):
+            with self._dispatch_stage(ring_rows=ring_rows) as disp:
+                waited = self._reading_wait_ns
                 self._step_block(*block)
+                if self._reading_wait_ns > waited:
+                    disp.set("reading_wait_ms", round(
+                        (self._reading_wait_ns - waited) / 1e6, 3))
             pos += n_valid
         if pos == 0:
             return
@@ -382,7 +389,8 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
     def _dispatch_stage(self, **attrs):
         """window/IngestDispatch: the host's time to enqueue the block's
         step (the devices run it later) and to look at the pressure
-        probe; ``ring_rows`` from the caller."""
+        probe; ``ring_rows`` from the caller, ``reading_wait_ms`` on a
+        block that waited for a reading (``_await_reading``)."""
         return TRACER.stage("window", "IngestDispatch", seq=self._block_seq,
                             total=(self.stage_s, "ingest"), **attrs)
 
@@ -482,13 +490,31 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
             outs, generation, at, rows = self._probe
             wait = at_block and (self._block_seq - at
                                  >= self._headroom() // 2)
-            if wait or all(leaf.is_ready()
-                           for leaf in jax.tree_util.tree_leaves(outs)):
-                occ, dropped, occupied = jax.device_get(outs)
+            landed = all(leaf.is_ready()
+                         for leaf in jax.tree_util.tree_leaves(outs))
+            if wait or landed:
+                occ, dropped, occupied = self._await_reading(outs, landed)
                 self._probe = None
                 self._note_inserts(generation, rows, int(occupied))
                 self._reading(dropped, occ, (generation, at))
                 self._settle_reclaim(at_block)   # one it has just sent
+
+    def _await_reading(self, outs, landed: bool):
+        """``jax.device_get`` of a reading. One that has ``landed`` costs
+        a copy already made; for any other the task's thread WAITS until
+        the devices reach it, and the wait is counted
+        (``mesh_reading_waits_total`` / ``mesh_reading_wait_us_total``;
+        ``reading_wait_ms`` on the block's window/IngestDispatch)."""
+        if landed:
+            # lint: sync-ok the reading's copy has landed
+            return jax.device_get(outs)
+        t0 = time.perf_counter_ns()
+        # lint: sync-ok the one wait of the hot loop, by _take_readings' rule
+        host = jax.device_get(outs)
+        waited = time.perf_counter_ns() - t0
+        self._reading_wait_ns += waited
+        DEVICE_STATS.note_mesh_reading_wait(waited / 1e3)
+        return host
 
     def _settle_reclaim(self, at_block: bool) -> None:
         """Take in the counts of a reclaim in flight if they have landed;
@@ -599,11 +625,12 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         if self._reclaiming is None:
             return
         counts, span, at, rows = self._reclaiming
-        if not (block or counts.is_ready()):
+        landed = counts.is_ready()
+        if not (block or landed):
             return
         self._reclaiming = None
-        # lint: sync-ok the reclaim's counts, landed (or the caller syncs anyway)
-        kept, freed = np.asarray(jax.device_get(counts)).T.astype(np.int64)
+        kept, freed = np.asarray(
+            self._await_reading(counts, landed)).T.astype(np.int64)
         cap = self._agg.capacity
         DEVICE_STATS.note_reclaim(int(kept.sum()), int(freed.sum()))
         span.close(kept=int(kept.sum()), freed=int(freed.sum()),

@@ -33,7 +33,9 @@ from ..core.elements import (
 from ..core.records import MIN_TIMESTAMP, RecordBatch
 from ..core.watermarks import WatermarkStrategy
 from ..connectors.core import SinkWriter, Source, SourceReader
-from ..metrics.tracing import TRACER, TraceContext, now_ms, now_ns
+from ..metrics.tracing import (
+    TRACER, TraceContext, now_ms, now_ns, thread_cpu_ns,
+)
 from ..state.backend import OperatorStateBackend
 from .channels import GateEvent, InputGate
 from .operators.base import OperatorChain, OperatorContext, Output
@@ -50,10 +52,16 @@ class TaskIOTimers:
     cumulative here instead of last-second-windowed). ``busy_s`` is raw
     processing time and INCLUDES time blocked inside emits; the writer
     accounts that blocked time into ``backpressured_s`` separately, so
-    the derived ratios subtract it — busy means 'making progress'."""
+    the derived ratios subtract it — busy means 'making progress'.
+
+    ``cpu_s`` is what the mailbox thread COMPUTED: its CPU clock, which
+    any thread may read and which costs the task nothing per batch. The
+    three together: busy = inside a turn; cpu = computing; busy - cpu =
+    standing still inside a turn (a device the thread waits for, the GIL
+    in another thread's hands, a machine that does not run it)."""
 
     __slots__ = ("busy_s", "idle_s", "backpressured_s",
-                 "_started_at", "_ended_at")
+                 "_started_at", "_ended_at", "_cpu_clock", "_cpu_s")
 
     def __init__(self):
         self.busy_s = 0.0
@@ -61,14 +69,23 @@ class TaskIOTimers:
         self.backpressured_s = 0.0
         self._started_at: Optional[float] = None
         self._ended_at: Optional[float] = None
+        self._cpu_clock: Optional[int] = None   # of the thread that started
+        self._cpu_s: Optional[float] = None     # frozen at stop()
 
     def start(self) -> None:
+        """Called by the mailbox thread itself: its CPU clock is the one
+        ``cpu_s`` reads."""
         if self._started_at is None:
             self._started_at = time.time()
+            if hasattr(time, "pthread_getcpuclockid"):
+                self._cpu_clock = time.pthread_getcpuclockid(
+                    threading.get_ident())
 
     def stop(self) -> None:
         # freeze elapsed at task exit so post-run gauge reads are stable
+        # (and the CPU clock while its thread still is)
         if self._ended_at is None:
+            self._cpu_s, self._cpu_clock = self.cpu_s, None
             self._ended_at = time.time()
 
     @property
@@ -79,6 +96,19 @@ class TaskIOTimers:
                    1e-9)
 
     @property
+    def cpu_s(self) -> Optional[float]:
+        """CPU seconds of the mailbox thread since it started, readable
+        from any thread; None where the platform has no per-thread CPU
+        clock (or the task never started)."""
+        clock = self._cpu_clock
+        if clock is not None:
+            try:
+                return time.clock_gettime(clock)
+            except OSError:     # the thread went between the two lines
+                pass
+        return self._cpu_s
+
+    @property
     def busy_ratio(self) -> float:
         return min(1.0, max(0.0, self.busy_s - self.backpressured_s)
                    / self.elapsed_s)
@@ -86,6 +116,16 @@ class TaskIOTimers:
     @property
     def busy_ms_per_s(self) -> float:
         return self.busy_ratio * 1000.0
+
+    @property
+    def cpu_ratio(self) -> Optional[float]:
+        cpu = self.cpu_s
+        return None if cpu is None else min(1.0, cpu / self.elapsed_s)
+
+    @property
+    def cpu_ms_per_s(self) -> Optional[float]:
+        ratio = self.cpu_ratio
+        return None if ratio is None else ratio * 1000.0
 
     @property
     def idle_ms_per_s(self) -> float:
@@ -564,7 +604,7 @@ class SourceStreamTask(StreamTask):
             # a read is a task/SourceBatch stage only once it returns rows:
             # an unbounded or paced source is asked about 1 kHz while it
             # has nothing, and stages never run per poll
-            read_ns = now_ns()
+            read_ns, read_cpu_ns = now_ns(), thread_cpu_ns()
             batch = self.reader.read_batch(self.current_batch_size)
             read_dt = (now_ns() - read_ns) / 1e9
             self.stage_s["read"] += read_dt
@@ -574,8 +614,12 @@ class SourceStreamTask(StreamTask):
             if batch.n:
                 cycle = TRACER.stage("task", "SourceBatch",
                                      start_ns=read_ns,
+                                     start_cpu_ns=read_cpu_ns,
                                      seq=self._batches + 1, records=batch.n,
                                      read_ms=round(read_dt * 1e3, 3))
+                # what the writers stand in a full channel they account
+                # themselves (RecordWriter._put_blocking)
+                blocked_s = self.io_timers.backpressured_s
                 if self.ctx.metrics is not None:
                     self.ctx.metrics.records_in.inc(batch.n)
                 batch = self.ws.assign_timestamps(batch)
@@ -589,8 +633,12 @@ class SourceStreamTask(StreamTask):
                 else:
                     out.emit(batch)
                 self._batches += 1
-                emit_dt = cycle.duration_s - read_dt
-                cycle.close(emit_ms=round(emit_dt * 1e3, 3))
+                # read and emit are the span's own two stamps
+                end_ns = now_ns()
+                emit_dt = (end_ns - read_ns) / 1e9 - read_dt
+                blocked_s = self.io_timers.backpressured_s - blocked_s
+                cycle.close(end_ns, emit_ms=round(emit_dt * 1e3, 3),
+                            blocked_ms=round(blocked_s * 1e3, 3))
                 self.stage_s["emit"] += emit_dt
                 self.io_timers.busy_s += emit_dt
                 self.progress.bump()

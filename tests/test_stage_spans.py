@@ -233,6 +233,13 @@ def test_stage_totals_equal_their_spans(traced_run):
     assert sum(c.attributes["records"] for c in cycles) == N
     assert src.stage_s["emit"] == pytest.approx(
         sum(c.attributes["emit_ms"] for c in cycles) / 1e3, abs=1e-3)
+    # read and emit are cut from the span's own two stamps (the reads
+    # that returned nothing are no span and take time too)
+    assert src.stage_s["read"] + src.stage_s["emit"] >= sum(
+        c.duration_ns for c in cycles) / 1e9 - 1e-9
+    assert all(c.attributes["emit_ms"] + c.attributes["read_ms"]
+               == pytest.approx(c.duration_ns / 1e6, abs=2e-3)
+               for c in cycles)
 
 
 def test_batch_and_watermark_stage_attributes(traced_run):
@@ -302,6 +309,9 @@ def test_open_stage_is_a_root_and_a_backdated_stage_keeps_its_stamp():
     assert sorted(s.name for s in spans) == ["Emit", "Fire", "Watermark"]
     root = next(s for s in spans if s.name == "Fire")
     assert root.parent_id == ""
+    # closed by the thread that opened it: the thread's CPU time between
+    cpu_ms = root.attributes.pop("cpu_ms")
+    assert cpu_ms >= 0
     assert root.attributes == {"seq": 7, "task": "MainThread",
                                "unready_polls": 1, "rows": 3}
     emit = next(s for s in spans if s.name == "Emit")
