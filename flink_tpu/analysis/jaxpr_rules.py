@@ -589,7 +589,7 @@ def exercise_programs(n_events: int = 4096, batch: int = 1024,
         state = agg.init_state()
         B = 64
         keys = (jnp.arange(D * B, dtype=jnp.int64) % 37).reshape(D, B) + 1
-        state, _, _ = agg.step(state, keys,
+        state, *_ = agg.step(state, keys,
                             {"price": jnp.ones((D, B), jnp.int64)},
                             jnp.zeros((D, B), jnp.int32),
                             jnp.ones((D, B), bool))

@@ -167,6 +167,13 @@ class DeviceStats:
         # operator had seen before their batch (0 for a stream in order)
         self._fold_sorted_batches = 0
         self._fold_back_rows = 0
+        # the limb scatters those folds ran (PR 54): an additive 64-bit
+        # plane takes a batch limb by limb, one 32-bit scatter-add pass a
+        # limb that some row of a touched ring row holds a non-zero value
+        # in (ops/segment_ops.ring_fold). Counted BY the device program,
+        # whose count rides to the host with the probe's counters (one
+        # chip) or the step's round count (mesh: the busiest shard's)
+        self._fold_limb_scatters = 0
         # state reclaim accounting (PR 35; the mesh operator's since
         # PR 41, one sweep a dispatch, keys summed over the shards):
         # sweeps of the reclaim (state/tpu_backend.py reclaim_shard: a
@@ -530,6 +537,19 @@ class DeviceStats:
             self._fold_ring_rows += int(ring_rows)
             self._fold_sorted_batches += bool(sorted_)
 
+    def note_limb_scatters(self, scatters: int) -> None:
+        with self._lock:
+            self._fold_limb_scatters += int(scatters)
+
+    @property
+    def fold_limb_scatters(self) -> int:
+        """Limb scatters the ring folds ran (``ring_fold``): over
+        ``fold_counts[1]``, the live limbs a touched ring row (Q5's
+        23-bit prices 2, its int64 COUNT on the mesh 1 more, a column
+        with negative values 5)."""
+        with self._lock:
+            return self._fold_limb_scatters
+
     def note_fold_back(self, rows: int) -> None:
         with self._lock:
             self._fold_back_rows += int(rows)
@@ -863,6 +883,7 @@ class DeviceStats:
                 "fold_ring_rows_total": self._fold_ring_rows,
                 "fold_sorted_batches_total": self._fold_sorted_batches,
                 "fold_back_rows_total": self._fold_back_rows,
+                "fold_limb_scatters_total": self._fold_limb_scatters,
                 "state_reclaim_sweeps_total": self._reclaim_sweeps,
                 "state_reclaim_keys_kept_total": self._reclaim_kept,
                 "state_reclaim_keys_freed_total": self._reclaim_freed,
@@ -980,6 +1001,7 @@ class DeviceStats:
             self._count_planes = dict.fromkeys(COUNT_PLANE_FORMS, 0)
             self._fold_batches = self._fold_ring_rows = 0
             self._fold_sorted_batches = self._fold_back_rows = 0
+            self._fold_limb_scatters = 0
             self._reclaim_sweeps = 0
             self._reclaim_kept = self._reclaim_freed = 0
             self._session_fires = self._session_fire_rounds = 0
@@ -1132,7 +1154,7 @@ REGION_SCOPES = {
 #: ``mesh.fold``), and for the lowering tests
 PATH_SCOPES = frozenset({
     "probe.gather", "probe.claim", "probe.compact", "probe.elect",
-    "fold.scatter",
+    "fold.scatter", "fold.limb", "fold.carry",
     "mesh.probe", "mesh.fold"})  # lint: key-ok scopes, not config keys
 UNNAMED = "unnamed"
 
@@ -1820,11 +1842,13 @@ def bind_device_metrics(registry) -> None:
     # flink_tpu_device_fold_batches_total /
     # flink_tpu_device_fold_ring_rows_total /
     # flink_tpu_device_fold_sorted_batches_total /
-    # flink_tpu_device_fold_back_rows_total)
+    # flink_tpu_device_fold_back_rows_total /
+    # flink_tpu_device_fold_limb_scatters_total)
     g.gauge("fold_batches_total", lambda: s.fold_counts[0])
     g.gauge("fold_ring_rows_total", lambda: s.fold_counts[1])
     g.gauge("fold_sorted_batches_total", lambda: s.fold_counts[2])
     g.gauge("fold_back_rows_total", lambda: s.fold_counts[3])
+    g.gauge("fold_limb_scatters_total", lambda: s.fold_limb_scatters)
     # state reclaim, one chip or mesh (prometheus:
     # flink_tpu_device_state_reclaim_sweeps_total /
     # flink_tpu_device_state_reclaim_keys_kept_total /

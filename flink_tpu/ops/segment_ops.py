@@ -22,7 +22,8 @@ import numpy as np
 
 __all__ = ["scatter_fold", "ring_fold", "pane_window_merge", "AGG_INITS",
            "Halves", "plane_map", "plane_take", "plane_row", "planes_joined",
-           "planes_stored_like", "stores_halves", "identity_words",
+           "planes_stored_like", "stores_halves", "folds_by_limbs",
+           "identity_words",
            "plane_identity", "make_plane", "AGG_FOLDS", "AGG_MERGES",
            "COUNT_KINDS",
            "make_accumulator", "segment_topk", "pow2_ceil"]
@@ -272,10 +273,65 @@ def scatter_fold(kind: str, acc: jax.Array, flat_idx: jax.Array,
 #: rows of a batch that ``ring_fold`` scatters at a time
 _FOLD_CHUNK = 1 << 14
 
+#: the kinds whose fold is an addition: into a ``Halves`` plane they go
+#: limb by limb (``ring_fold``)
+_ADDITIVE = ("sum", "count")
+
+
+def folds_by_limbs(kind: str, halves: bool) -> bool:
+    """Whether ``ring_fold`` adds a batch into the plane limb by limb
+    with 32-bit scatters: an additive kind into a ``Halves`` plane."""
+    return halves and kind in _ADDITIVE
+
+
+def _limb_width(n: int) -> int:
+    """The widest limb that ``n`` rows of one key cannot carry out of 32
+    bits: ``n * (2^w - 1) < 2^32``."""
+    return 32 - (n - 1).bit_length()
+
+
+def _limb(words: Halves, w: int, j: int) -> jax.Array:
+    """Bits ``[w * j, w * j + w)`` of 64-bit values given as their words:
+    32-bit shifts by constants, no 64-bit value."""
+    s, mask = w * j, np.uint32((1 << w) - 1)
+    if s + w <= 32:
+        bits = words.lo >> np.uint32(s)
+    elif s >= 32:
+        bits = words.hi >> np.uint32(s - 32)
+    else:
+        bits = (words.lo >> np.uint32(s)) | (words.hi << np.uint32(32 - s))
+    return bits & mask
+
+
+def _limb_cuts(kind: str, values: jax.Array, w: int) -> list:
+    """A batch's 64-bit values as ``uint32`` limbs of ``w`` bits, lowest
+    first: ``ceil(64 / w)`` of them, or the one limb of ones a ``count``
+    folds (``COUNT_KINDS``: every row counts one)."""
+    if kind == "count":
+        return [jnp.ones(values.shape, jnp.uint32)]
+    words = Halves.split(values)
+    return [_limb(words, w, j) for j in range(-(-64 // w))]
+
+
+def _add_shifted(row: Halves, delta: jax.Array, s: int) -> Halves:
+    """``row + (delta << s)`` modulo 2^64 over a ring row's two words,
+    ``delta`` a ``uint32`` row and ``s`` a constant under 64: dense
+    32-bit arithmetic, the carry out of ``lo`` taken from the wrap."""
+    hi, lo = row.hi, row.lo
+    if s < 32:
+        low = delta << np.uint32(s) if s else delta
+        lo = lo + low
+        hi = hi + (lo < low).astype(jnp.uint32)
+        if s:
+            hi = hi + (delta >> np.uint32(32 - s))
+    else:
+        hi = hi + (delta << np.uint32(s - 32) if s > 32 else delta)
+    return Halves(hi, lo, row.dtype)
+
 
 def ring_fold(kind: str, plane, ring_idx: jax.Array,
               slots: jax.Array, values: jax.Array,
-              valid: jax.Array):
+              valid: jax.Array, counted: bool = False):
     """Fold a batch into a ``[ring, capacity]`` plane, ring row by ring
     row: plane[ring_idx, slots] op= values, masked by ``valid``. The
     plane is the ``Halves`` of a 64-bit integer one or one array (a
@@ -289,7 +345,7 @@ def ring_fold(kind: str, plane, ring_idx: jax.Array,
     the device-born step, and the mesh step on each shard's own plane
     under ``shard_map``, inside its exchange rounds' ``while_loop``.
     Each ring row the batch holds a valid row for is sliced out,
-    folded by ``scatter_fold`` as the 1-D accumulator it is, and written
+    folded as the 1-D accumulator it is, and written
     back; the rows the batch does not touch are skipped on the device.
     A scatter costs the TPU by the update, masked or not, so a touched
     row takes the batch ``_FOLD_CHUNK`` rows at a time and skips the
@@ -300,46 +356,124 @@ def ring_fold(kind: str, plane, ring_idx: jax.Array,
     (``DeviceWindowAggOperator._fold``). Any number of touched rows is
     right, 0 to ``ring``. What a ring row costs beside its scatters (the
     mask, the slice, the chunk walk, the write-back) is the region
-    ``fold.row``."""
+    ``fold.row``.
+
+    And a scatter costs by the WIDTH of the update: a 64-bit one six
+    times a 32-bit one (PERF.md section 6, PR 34 and PR 54). An additive
+    kind (``sum``, ``count``) therefore never scatters into a ``Halves``
+    row's two words. The batch's values, read as unsigned 64-bit words,
+    are cut into limbs of ``w = 32 - ceil(log2 n)`` bits, so that no
+    number of the batch's n rows on one slot can carry out of 32 bits;
+    each limb that some valid row of the ring row holds a non-zero value
+    in (decided on the device; a ``count`` folds ones and has one) is
+    scatter-added into a zeroed ``uint32`` row, one 32-bit scatter a
+    chunk, and that row, shifted to the limb's place, is added into the
+    ring row's two words with the carry from ``lo`` into ``hi``. That is
+    the 64-bit add modulo 2^64 whatever the values are: Q5's prices run
+    two limbs, a negative value all of them, and no 64-bit value of a
+    ring row exists. All of it lies under the kind's own scope
+    (``fold.scatter/fold.sum``: the scatters beneath ``fold.limb``, the
+    dense add beneath ``fold.carry``). ``min`` / ``max`` / ``presence``
+    and every float or 32-bit plane fold with the one scatter of their
+    kind (``scatter_fold``).
+
+    ``counted``: also return the limb scatters the fold ran, one a live
+    limb a touched ring row (an int32 scalar; the integer 0 for a plane
+    that does not fold by limbs)."""
+    limbs = folds_by_limbs(kind, isinstance(plane, Halves))
     n = slots.shape[0]
     if n == 0:
-        return plane
+        return (plane, 0) if counted else plane
     chunk = min(_FOLD_CHUNK, n)
     with jax.named_scope("fold.row"):
         ring_idx = ring_idx.astype(jnp.int32)
         lane = jnp.arange(chunk, dtype=jnp.int32)
+    if limbs:
+        w = _limb_width(n)
+        with jax.named_scope("fold.scatter"), _fold_scope(kind), \
+                jax.named_scope("fold.limb"):
+            cuts = _limb_cuts(kind, values.astype(plane.dtype), w)
 
-    @jax.named_scope("fold.row")
-    def fold_row(r, plane):
-        mine = valid & (ring_idx == r)
-
+    def chunk_walk(mine, fold_hit, row):
+        """``row`` after ``fold_hit(row, at, hit)`` for every chunk of
+        the batch that holds a row of ``mine``."""
         def fold_chunk(c, row):
             at = jnp.minimum(c * chunk, n - chunk)   # the last one backs up
             hit = jax.lax.dynamic_slice(mine, (at,), (chunk,)) \
                 & (at + lane >= c * chunk)
             return jax.lax.cond(
-                hit.any(),
-                lambda row: scatter_fold(
-                    kind, row,
-                    jax.lax.dynamic_slice(slots, (at,), (chunk,)),
-                    jax.lax.dynamic_slice(values, (at,), (chunk,)), hit),
+                hit.any(), lambda row: fold_hit(row, at, hit),
                 lambda row: row, row)
 
-        def fold(plane):
-            # of a ``Halves`` plane: the words of ring row r joined,
-            # folded as the 64-bit row they are, and split for the
-            # write-back; no other row of the plane is read
-            row = jax.lax.fori_loop(0, -(-n // chunk), fold_chunk,
-                                    plane_row(plane, r))
-            if isinstance(plane, Halves):
-                row = Halves.split(row)
+        return jax.lax.fori_loop(0, -(-n // chunk), fold_chunk, row)
+
+    def fold_whole(mine, row):
+        return chunk_walk(mine, lambda row, at, hit: scatter_fold(
+            kind, row,
+            jax.lax.dynamic_slice(slots, (at,), (chunk,)),
+            jax.lax.dynamic_slice(values, (at,), (chunk,)), hit), row)
+
+    def fold_limbs(mine, row: Halves, ran):
+        for j, cut in enumerate(cuts):
+            with jax.named_scope("fold.limb"):
+                live = mine & (cut != 0) if len(cuts) > 1 else mine
+
+            def add_limb(carry, j=j, cut=cut, live=live):
+                row, ran = carry
+                with jax.named_scope("fold.limb"):
+                    # the zeroed row as the fill of a TRACED zero (the
+                    # count's sign bit): the fill of a constant the v5e's
+                    # compiler makes anew without its name path, and a
+                    # trace then books 64 MB of writes under no region
+                    zero = (ran >> 31).astype(jnp.uint32)
+                    delta = chunk_walk(
+                        live, lambda delta, at, hit: delta.at[jnp.where(
+                            hit, jax.lax.dynamic_slice(
+                                slots, (at,), (chunk,)), 0)].add(jnp.where(
+                                    hit, jax.lax.dynamic_slice(
+                                        cut, (at,), (chunk,)), 0)),
+                        jnp.full(row.shape, zero))
+                with jax.named_scope("fold.carry"):
+                    return _add_shifted(row, delta, w * j), ran + 1
+
+            if len(cuts) > 1:
+                row, ran = jax.lax.cond(live.any(), add_limb,
+                                        lambda carry: carry, (row, ran))
+            else:
+                row, ran = add_limb((row, ran))
+        return row, ran
+
+    @jax.named_scope("fold.row")
+    def fold_row(r, carry):
+        plane, ran = carry
+        mine = valid & (ring_idx == r)
+
+        def fold(carry):
+            plane, ran = carry
+            if limbs:
+                # the row's two words as they lie: no 64-bit value of it
+                row = plane.map(lambda a: jax.lax.dynamic_index_in_dim(
+                    a, r, 0, keepdims=False))
+                with jax.named_scope("fold.scatter"), _fold_scope(kind):
+                    row, ran = fold_limbs(mine, row, ran)
+            else:
+                row = fold_whole(mine, plane_row(plane, r))
+                if isinstance(plane, Halves):
+                    # a min / max: the words of ring row r joined, folded
+                    # as the 64-bit row they are, and split again
+                    row = Halves.split(row)
             return plane_map(
                 lambda words, new: jax.lax.dynamic_update_index_in_dim(
-                    words, new, r, 0), plane, row)
+                    words, new, r, 0), plane, row), ran
 
-        return jax.lax.cond(mine.any(), fold, lambda plane: plane, plane)
+        return jax.lax.cond(mine.any(), fold, lambda carry: carry, carry)
 
-    return jax.lax.fori_loop(0, plane.shape[0], fold_row, plane)
+    plane, ran = jax.lax.fori_loop(
+        0, plane.shape[0], fold_row,
+        (plane, jnp.int32(0) if limbs else ()))
+    if not counted:
+        return plane
+    return plane, ran if limbs else 0
 
 
 def pane_window_merge(kind: str, acc: jax.Array,

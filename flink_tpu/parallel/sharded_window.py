@@ -52,8 +52,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..metrics.device import instrumented_program_cache
 from ..ops.hash_table import EMPTY_KEY, ensure_x64, lookup_or_insert
 from ..ops.segment_ops import AGG_INITS, AGG_MERGES, COUNT_KINDS, \
-    make_plane, plane_identity, plane_map, plane_take, ring_fold, \
-    stores_halves
+    folds_by_limbs, make_plane, plane_identity, plane_map, plane_take, \
+    ring_fold, stores_halves
 from ..ops.topk import masked_topk_sort, threshold_topk
 from ..state.tpu_backend import reclaim_shard
 from .exchange import (bucket_capacity, exchange_round, order_payload,
@@ -217,6 +217,11 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
     MP = max_parallelism
     # lint: sync-ok mesh.devices is a host numpy array of Device objects
     D = int(mesh.devices.size)
+    # whether a plane folds limb by limb (an additive 64-bit one): each
+    # shard then counts the limb scatters it ran, through the rounds'
+    # loop, and the step hands the [D] counts back beside the rounds
+    limbed = {a.name for a in aggs
+              if folds_by_limbs(a.kind, stores_halves(a.dtype, ring))}
 
     def shard_body(table, accs, dropped, keys, cols, panes, valid,
                    base_start, base_len):
@@ -254,7 +259,7 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
             n_rounds = jax.lax.pmax(xplan.n_rounds, axis_name)
 
         def fold_round(carry):
-            r, table, accs, dropped, ok_count = carry
+            r, table, accs, dropped, ok_count, limbs = carry
             accs = dict(accs)
             with jax.named_scope("mesh.exchange"):
                 routed, rvalid = exchange_round(axis_name, D, cap_x,
@@ -274,19 +279,23 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
                 for a in aggs:
                     vals = (jnp.ones(slots.shape[0], a.dtype)
                             if a.kind in COUNT_KINDS else routed[a.name])
-                    accs[a.name] = ring_fold(
-                        a.kind, accs[a.name], ring_idx, slots, vals, ok)
+                    accs[a.name], ran = ring_fold(
+                        a.kind, accs[a.name], ring_idx, slots, vals, ok,
+                        counted=True)
+                    if a.name in limbed:
+                        limbs = limbs + ran
             return (r + 1, table, accs, dropped + n_dropped,
-                    ok_count + jnp.sum(ok).astype(jnp.int64))
+                    ok_count + jnp.sum(ok).astype(jnp.int64), limbs)
 
         carry = (jnp.int32(0), table, accs, dropped,
-                 jnp.zeros((), jnp.int64))
-        _, table, accs, dropped, ok_count = jax.lax.while_loop(
+                 jnp.zeros((), jnp.int64),
+                 jnp.zeros(1, jnp.int32) if limbed else None)
+        _, table, accs, dropped, ok_count, limbs = jax.lax.while_loop(
             lambda c: c[0] < n_rounds, fold_round, carry)
         with jax.named_scope("mesh.sync"):
             processed = jax.lax.psum(ok_count, axis_name)
         return (table[None], {k: _mesh_plane(v) for k, v in accs.items()},
-                dropped, processed, n_rounds)
+                dropped, processed, n_rounds, limbs)
 
     skel = {"table": 0, "accs": {a.name: 0 for a in aggs},
             "dropped": 0, "keys": 0,
@@ -298,7 +307,8 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
         shard_body, mesh,
         in_specs=state_specs + (sp["keys"], sp["cols"], sp["panes"],
                                 sp["valid"], P(), P()),
-        out_specs=state_specs + (P(), P()))
+        out_specs=state_specs + (P(), P(),
+                                 sp["dropped"] if limbed else None))
 
     # the state is DONATED: the loop folds into the planes in place.
     # Without it every step allocates a second state (2.2 GB a chip
@@ -310,11 +320,11 @@ def _make_step(sig, max_parallelism: int, axis_name: str, rules: tuple,
     @functools.partial(jax.jit, donate_argnums=(0,))
     def step(state: ShardedWindowState, keys, cols, panes, valid,
              base_start, base_len):
-        table, accs, dropped, processed, n_rounds = mapped(
+        table, accs, dropped, processed, n_rounds, limbs = mapped(
             state.table, state.accs, state.dropped, keys, cols, panes,
             valid, base_start, base_len)
         return (ShardedWindowState(table, accs, dropped), processed,
-                n_rounds)
+                n_rounds, limbs)
 
     return step
 
@@ -326,8 +336,10 @@ def _step_program(sig, max_parallelism: int, axis_name: str,
     Mesh as its first argument and binds the shard_map program per mesh
     inside this one cache entry: the cache key stays local-shape-only
     while the executable closes over the mesh shard_map needs. It returns
-    (new state, rows folded, exchange rounds taken), the two counts
-    replicated scalars; the state argument is donated."""
+    (new state, rows folded, exchange rounds taken, limb scatters run),
+    the first two counts replicated scalars, the last an int32 [D], one
+    a shard, or None where no plane folds by limbs
+    (``ops/segment_ops.ring_fold``); the state argument is donated."""
     return _per_mesh(lambda mesh: _make_step(sig, max_parallelism,
                                              axis_name, rules, mesh))
 
@@ -635,11 +647,14 @@ class ShardedWindowAgg:
     # ------------------------------------------------------------------
     def step(self, state: ShardedWindowState, keys: jax.Array, cols: dict,
              panes: jax.Array, valid: jax.Array
-             ) -> tuple[ShardedWindowState, jax.Array, jax.Array]:
+             ) -> tuple[ShardedWindowState, jax.Array, jax.Array,
+                        Optional[jax.Array]]:
         """Fold one micro-batch. keys/panes/valid: [D, B]; cols: dict of
         [D, B] value columns (one per aggregate that takes a column: not a
         count, not the presence plane). Returns (new
-        state, rows folded, exchange rounds taken). ``state`` is DONATED:
+        state, rows folded, exchange rounds taken, the limb scatters each
+        shard's folds ran: int32 [D], None where no plane is an additive
+        64-bit one). ``state`` is DONATED:
         its buffers are deleted, only the returned state is live."""
         return self._step(self.mesh, state, keys, cols, panes, valid,
                           self._base_start, self._base_len)

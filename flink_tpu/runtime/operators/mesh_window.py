@@ -430,26 +430,35 @@ class MeshWindowAggOperator(AsyncFireQueue, SliceControlPlane,
         self._take_readings(at_block=True)
         # the old state is donated to the step: nothing may keep a handle
         # on it (fires and probes enqueued on it earlier stay valid)
-        self._state, _processed, n_rounds = self._agg.step(
+        self._state, _processed, n_rounds, limbs = self._agg.step(
             self._state, dkeys, dcols, dpanes, dvalid)
-        n_rounds.copy_to_host_async()
-        self._rounds_sent.append(n_rounds)
+        for count in (n_rounds, limbs):
+            if count is not None:
+                count.copy_to_host_async()
+        self._rounds_sent.append((n_rounds, limbs))
         self._note_rounds()
         self._send_probe(at_block=True)
 
     def _note_rounds(self, block: bool = False) -> None:
-        """Hand the steps' exchange-round counts to DEVICE_STATS: those
-        whose copy has landed (all of them with ``block``: finish and
-        snapshot sync anyway). Never waits in the hot loop, so the
-        counters trail the devices by the steps in flight."""
+        """Hand the steps' exchange-round counts to DEVICE_STATS, and
+        with them the limb scatters the busiest shard's folds ran (a
+        step's time is its slowest shard's; None where no plane folds by
+        limbs): those whose copy has landed (all of them with ``block``:
+        finish and snapshot sync anyway). Never waits in the hot loop, so
+        the counters trail the devices by the steps in flight."""
         sent = self._rounds_sent
-        steps = rounds = 0
-        while sent and (block or sent[0].is_ready()):
+        steps = rounds = limb_scatters = 0
+        while sent and (block or sent[0][0].is_ready()):
+            n_rounds, limbs = sent.popleft()
             # lint: sync-ok the copy has landed (or the caller syncs anyway)
-            rounds += int(np.asarray(sent.popleft()))
+            rounds += int(np.asarray(n_rounds))
+            if limbs is not None:
+                # lint: sync-ok one program's outputs: landed together
+                limb_scatters += int(np.asarray(limbs).max())
             steps += 1
         if steps:
             DEVICE_STATS.note_mesh_steps(steps, rounds)
+            DEVICE_STATS.note_limb_scatters(limb_scatters)
 
     # -- firing (fire loop lives in SliceControlPlane) ----------------------
     def _pre_fire_flush(self) -> None:

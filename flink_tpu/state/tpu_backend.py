@@ -48,7 +48,8 @@ from ..ops.hash_table import (
     make_table, sanitize_keys_device,
 )
 from ..ops.segment_ops import AGG_INITS, Halves, make_plane, \
-    plane_identity, plane_map, ring_fold, scatter_fold, stores_halves
+    folds_by_limbs, plane_identity, plane_map, ring_fold, scatter_fold, \
+    stores_halves
 from .backend import KeyedStateBackend, State, ValueState, register_backend
 from .descriptors import StateDescriptor
 from .spill import HostTier
@@ -123,17 +124,36 @@ def _fold_program(sig: tuple):
     shape), as ``_reset_row_program``'s; ``cols`` holds one value column
     a plane, or None where every row counts one. The planes are donated:
     the arrays passed in are deleted buffers afterwards, and only the
-    ones returned are live."""
+    ones returned are live. Where a plane folds by limbs
+    (``_folds_by_limbs``: an additive 64-bit one) the program also takes
+    ``limb_scatters``, the backend's running count, and returns (the
+    planes, the count plus the limb scatters this fold ran): the count
+    rides through the program, so that keeping it costs no dispatch of
+    its own. Every other signature's program is what it was."""
+    limbed = _folds_by_limbs(sig)
 
     @partial(jax.jit, donate_argnums=(0,))
-    def fold(arrays: tuple, slots, ring_idx, valid, cols: tuple):
-        return tuple(
-            ring_fold(kind, a, ring_idx, slots,
-                      jnp.ones(slots.shape, a.dtype) if c is None else c,
-                      valid)
-            for (kind, _dt, _shape), a, c in zip(sig, arrays, cols))
+    def fold(arrays: tuple, slots, ring_idx, valid, cols: tuple,
+             limb_scatters=None):
+        outs = []
+        for (kind, _dt, _shape), a, c in zip(sig, arrays, cols):
+            plane, ran = ring_fold(
+                kind, a, ring_idx, slots,
+                jnp.ones(slots.shape, a.dtype) if c is None else c,
+                valid, counted=True)
+            outs.append(plane)
+            if folds_by_limbs(kind, isinstance(a, Halves)):
+                limb_scatters = limb_scatters + ran
+        return (tuple(outs), limb_scatters) if limbed else tuple(outs)
 
     return fold
+
+
+def _folds_by_limbs(sig: tuple) -> bool:
+    """Whether the fold of a plane signature runs limb scatters (and so
+    carries the backend's count of them)."""
+    return any(folds_by_limbs(kind, dt.startswith("halves:"))
+               for kind, dt, _shape in sig)
 
 
 #: live keys the reclaim re-homes at a time (one ``lookup_or_insert`` a
@@ -447,8 +467,13 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         # that stood behind a representative, batches that elected); only
         # a batch through that program adds to it
         self._elected = jnp.zeros(2, jnp.int64)
-        self._probe_sent: Optional[tuple[jax.Array, jax.Array]] = None
-        self._probe_noted = np.zeros(6, np.int64)
+        # and the limb scatters the folds ran (ops/segment_ops.ring_fold:
+        # an additive 64-bit plane takes a batch limb by limb), which
+        # ride THROUGH the fold program: it takes the count and returns
+        # it moved on
+        self._limb_scatters = jnp.zeros(1, jnp.int64)
+        self._probe_sent: Optional[tuple[jax.Array, ...]] = None
+        self._probe_noted = np.zeros(7, np.int64)
         # probes dispatched, and how many of them the counters last sent
         # and last noted had seen (note_probe_stats)
         self._probe_calls = self._probe_sent_calls = 0
@@ -1127,9 +1152,14 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         states = [self._array_states[n] for n in values]
         cols = tuple(None if v is None else jnp.asarray(v)
                      for v in values.values())
-        outs = _fold_program(_plane_sig(states))(
-            tuple(st.array for st in states), slots, jnp.asarray(ring_idx),
-            valid, cols)
+        sig = _plane_sig(states)
+        args = (tuple(st.array for st in states), slots,
+                jnp.asarray(ring_idx), valid, cols)
+        if _folds_by_limbs(sig):
+            outs, self._limb_scatters = _fold_program(sig)(
+                *args, self._limb_scatters)
+        else:
+            outs = _fold_program(sig)(*args)
         for st, arr in zip(states, outs):
             st.array = arr
         for name, vals in values.items():
@@ -1225,17 +1255,19 @@ class TpuKeyedStateBackend(KeyedStateBackend):
         whose every batch is wide does not flip between the two."""
         if block:
             self._finish_reclaim(block=True)
-        sent = (self._probe, self._elected) if block else self._probe_sent
-        # (the election's counters are the older of the two: a batch adds
-        # to them first)
+        sent = self._probe_counters() if block else self._probe_sent
+        # (the probe's own four are the youngest of them: a batch adds to
+        # the election's first, and the limb scatters are the fold's
+        # before)
         if sent is not None and (block or sent[0].is_ready()):
             # lint: sync-ok the copy has landed (or the caller syncs anyway)
             now = np.concatenate(jax.device_get(sent))
             calls = self._probe_calls if block else self._probe_sent_calls
-            rows, tail, wide, undecided, elected, elections = \
+            rows, tail, wide, undecided, elected, elections, limbs = \
                 now - self._probe_noted
             DEVICE_STATS.note_probe(rows, tail, wide, elected, elections,
                                     undecided)
+            DEVICE_STATS.note_limb_scatters(limbs)
             if calls > self._probe_noted_calls:
                 # every probe noted started its claiming rounds at full
                 # width
@@ -1244,8 +1276,12 @@ class TpuKeyedStateBackend(KeyedStateBackend):
             self._probe_noted, self._probe_noted_calls = now, calls
             self._probe_sent = None
         if self._probe_sent is None and not block:
-            self._probe_sent = (self._probe, self._elected)
+            self._probe_sent = self._probe_counters()
             self._probe_sent_calls = self._probe_calls
+
+    def _probe_counters(self) -> tuple[jax.Array, ...]:
+        """The device counters ``note_probe_stats`` reads, as they are."""
+        return self._probe, self._elected, self._limb_scatters
 
     # ------------------------------------------------------------------
     # deferred-mode health (device scalars; ride along with fire programs)

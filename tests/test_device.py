@@ -549,6 +549,70 @@ def test_batches_shuffled_over_many_ring_rows(aggs, defer, monkeypatch):
     assert rows > 4 * batches
 
 
+#: X's batch: 2^18 rows, so the additive fold cuts a value into limbs of
+#: 32 - 18 = 14 bits (`ops/segment_ops.ring_fold`)
+LIMB_ROWS = 1 << 18
+
+
+def _limb_gen(sign):
+    """Two batches of 2^18 rows in event-time order over four panes (two
+    ring rows a batch), prices under 2^22 as Q5's, of either sign."""
+    def gen(idx):
+        u = idx.astype(np.uint64) * np.uint64(0x9E3779B97F4A7C15)
+        return {"k": ((u >> np.uint64(7)) % np.uint64(KEYS)).astype(np.int64),
+                "v": sign * (((u >> np.uint64(23)) % np.uint64(1 << 22))
+                             .astype(np.int64) + 1),
+                "ts": (idx * 4 * PANE) // (2 * LIMB_ROWS)}
+    return gen
+
+
+@pytest.mark.parametrize("column, live_limbs", [("prices", 2),
+                                                ("negative", 5)])
+def test_a_q5_job_reports_the_limb_scatters_its_folds_ran(column,
+                                                          live_limbs):
+    """Q5 through `env.execute()` at X's batch size: the int64 SUM takes a
+    batch limb by limb, and `DEVICE_STATS` `fold_limb_scatters_total`,
+    counted by the fold program on the device, reads the live limbs a
+    touched ring row: 2 for 22-bit prices (limbs of 14 bits), 5 where the
+    column holds negative values (every high limb is set). The int32
+    COUNT beside it folds with its one scatter and counts nothing."""
+    gen = _limb_gen(1 if column == "prices" else -1)
+    sink = _Rows(["n", "total"])
+    env = StreamExecutionEnvironment.get_execution_environment()
+    env.set_state_backend("tpu")
+    env.config.set(PipelineOptions.BATCH_SIZE, LIMB_ROWS)
+    ws = WatermarkStrategy.for_monotonous_timestamps() \
+        .with_timestamp_column("ts")
+    (env.datagen(gen, FOLD_SCHEMA, count=2 * LIMB_ROWS,
+                 timestamp_column="ts", watermark_strategy=ws)
+        .key_by("k")
+        .window(SlidingEventTimeWindows.of(WINDOW * PANE, PANE))
+        .device_aggregate(AGGS["count32_sum64"], capacity=1 << 10,
+                          ring_size=RING, defer_overflow=True,
+                          async_fire=True)
+        .add_sink(sink, "rows"))
+    before = DEVICE_STATS.snapshot()
+    env.execute(f"limbs-{column}", timeout=300.0)
+    grew = {k: v - before[k] for k, v in DEVICE_STATS.snapshot().items()
+            if k.startswith("fold_")}
+    assert grew["fold_batches_total"] == 2
+    assert grew["fold_ring_rows_total"] == 4
+    assert grew["fold_limb_scatters_total"] \
+        == live_limbs * grew["fold_ring_rows_total"]
+    # and the windows are the per-record sums, to the unit
+    cols = gen(np.arange(2 * LIMB_ROWS))
+    pane = cols["ts"] // PANE
+    per_pane = np.zeros((2, KEYS, 4 + WINDOW), np.int64)
+    np.add.at(per_pane[0], (cols["k"], pane), 1)
+    np.add.at(per_pane[1], (cols["k"], pane), cols["v"])
+    want = {}
+    for last in range(4 + WINDOW - 1):              # window end = last + 1
+        n, total = per_pane[:, :, max(0, last - WINDOW + 1):last + 1].sum(2)
+        want.update({(k, (last + 1) * PANE): (int(n[k]), int(total[k]))
+                     for k in range(KEYS) if n[k]})
+    assert sink.got == want
+
+
 @pytest.mark.parametrize("defer", [True, False],
                          ids=["deferred", "synchronous"])
 def test_checkpoint_between_two_batches_restores_the_planes(tmp_path,

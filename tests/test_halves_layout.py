@@ -193,6 +193,112 @@ def test_ring_fold_is_one_algorithm_for_both_layouts():
         np.testing.assert_array_equal(np.asarray(words), np.asarray(whole))
 
 
+def _limb_batch(rng, n, ring=4, cap=64, touched=None, shuffled=False,
+                one_slot=False, masked=0.0):
+    """(ring rows, slots, valid) of ``n`` rows over ``touched`` ring
+    rows, in ring-row order or shuffled."""
+    touched = ring if touched is None else touched
+    rows = np.sort(rng.integers(0, touched, n)) * (ring // touched) \
+        if n else np.zeros(0, np.int64)
+    if shuffled:
+        rng.shuffle(rows)
+    slots = np.full(n, 5) if one_slot else rng.integers(0, cap, n)
+    return rows, slots.astype(np.int32), rng.random(n) >= masked
+
+
+def _full(rng, n):
+    return rng.integers(I64.min, I64.max, n, endpoint=True)
+
+
+#: name -> (kind, rows of the batch, its values, what shapes the batch)
+_LIMB_CASES = {
+    "q5_prices": ("sum", 90, lambda rng, n: rng.integers(1, 1 << 22, n), {}),
+    "negative": ("sum", 90, lambda rng, n: -rng.integers(1, 1 << 22, n), {}),
+    "either_sign_across_2_32": ("sum", 90, _prices, {}),
+    "int64_min_and_max": ("sum", 90, lambda rng, n: rng.choice(
+        np.array([I64.min, I64.max, -1, 0, 1]), n), {}),
+    "sums_that_wrap": ("sum", 300, lambda rng, n: rng.integers(
+        2**61, 2**63 - 1, n), {"cap": 8}),
+    "all_64_bits": ("sum", 300, _full, {}),
+    "masked_rows": ("sum", 300, _full, {"masked": 0.5}),
+    "count": ("count", 300, None, {}),
+    "count_masked": ("count", 300, None, {"masked": 0.5}),
+    # n rows on ONE slot with every limb at its largest: n * (2^w - 1) is
+    # the most a delta cell can be asked to hold, 2^32 - n
+    "one_slot_all_ones_256": ("sum", 256, lambda rng, n: np.full(n, -1),
+                              {"one_slot": True, "touched": 1}),
+    "one_slot_all_ones_2_chunks": ("sum", 1 << 15,
+                                   lambda rng, n: np.full(n, -1),
+                                   {"one_slot": True, "touched": 1}),
+    "count_one_slot": ("count", 1 << 15, None,
+                       {"one_slot": True, "touched": 1}),
+    "one_touched_row": ("sum", 200, _full, {"touched": 1}),
+    "two_touched_rows": ("sum", 200, _full, {"touched": 2}),
+    "two_touched_rows_shuffled": ("sum", 200, _full,
+                                  {"touched": 2, "shuffled": True}),
+    "every_row_shuffled": ("sum", 200, _full, {"shuffled": True}),
+    "not_a_multiple_of_the_chunk": ("sum", (1 << 14) + 777, _full,
+                                    {"shuffled": True}),
+    "one_row": ("sum", 1, _full, {}),
+    "two_rows": ("sum", 2, _full, {"one_slot": True, "touched": 1}),
+    "three_rows": ("sum", 3, _full, {"one_slot": True, "touched": 1}),
+    "no_row": ("sum", 0, _full, {}),
+    "the_mesh_shards_row_count": ("sum", 81920, _full, {"cap": 512}),
+    "the_mesh_shards_count": ("count", 81920, None, {"cap": 512}),
+}
+
+
+@pytest.mark.parametrize("case", list(_LIMB_CASES))
+def test_the_limb_fold_is_the_wrapping_int64_add(case):
+    """An additive kind into a ``Halves`` plane goes limb by limb through
+    32-bit scatters (``ring_fold``): whatever the values, the plane is what
+    numpy's wrapping int64 add makes of it, bit for bit, and the fold
+    counts one limb scatter a touched ring row and limb that some valid
+    row of it holds a non-zero value in (a count: one)."""
+    from flink_tpu.ops.segment_ops import _limb_width
+
+    kind, n, values, shape = _LIMB_CASES[case]
+    rng = np.random.default_rng(len(case))
+    ring, cap = 4, shape.pop("cap", 64)
+    rows, slots, valid = _limb_batch(rng, n, ring, cap, **shape)
+    vals = (np.ones(n, np.int64) if values is None
+            else np.asarray(values(rng, n), np.int64))
+    plane = _full(rng, (ring, cap))
+    plane[:, 5] = [-1, 2**32 - 1, I64.max, I64.min]   # carries and wraps
+    got, ran = jax.jit(lambda p, *b: ring_fold(kind, p, *b, counted=True))(
+        Halves.split(jnp.asarray(plane)), jnp.asarray(rows),
+        jnp.asarray(slots), jnp.asarray(vals), jnp.asarray(valid))
+    assert isinstance(got, Halves)
+    want = plane.view(np.uint64).copy()
+    np.add.at(want, (rows[valid], slots[valid]), vals[valid].view(np.uint64))
+    np.testing.assert_array_equal(np.asarray(got), want.view(np.int64))
+    # the limbs that ran: by row, the limbs some valid value is not 0 in
+    w = _limb_width(n) if n else 32
+    limbs = [(vals.view(np.uint64) >> np.uint64(w * j))
+             & np.uint64((1 << w) - 1) for j in range(-(-64 // w))]
+    assert int(ran) == sum(
+        1 if kind == "count" else sum(
+            bool(limb[valid & (rows == r)].any()) for limb in limbs)
+        for r in range(ring) if (valid & (rows == r)).any())
+    if case == "q5_prices":
+        assert w == 25 and int(ran) == ring          # 22-bit values: one limb
+    if case in ("negative", "one_slot_all_ones_256"):
+        assert int(ran) == len(limbs) * len(np.unique(rows[valid]))
+
+
+def test_the_limbs_width_holds_any_number_of_the_batchs_rows():
+    """w = 32 - ceil(log2 n): n rows at a limb's largest value stay under
+    2^32, and one bit more would not (14 bits at X's 2^18 rows, 15 at the
+    mesh shard's 81,920 routed row slots)."""
+    from flink_tpu.ops.segment_ops import _limb_width
+
+    assert _limb_width(1 << 18) == 14 and _limb_width(81920) == 15
+    assert _limb_width(1) == 32 and _limb_width(2) == 31
+    for n in (1, 2, 3, 255, 256, 257, 81920, 1 << 18, (1 << 18) + 1):
+        w = _limb_width(n)
+        assert n * ((1 << w) - 1) < 1 << 32 <= n * ((1 << (w + 1)) - 1) + n
+
+
 PANE, RING = 1000, 8
 SCHEMA = Schema([("k", np.int64), ("v", np.int64)])
 

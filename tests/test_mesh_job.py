@@ -764,6 +764,12 @@ def test_mesh_blocks_count_the_ring_rows_their_fold_touches(case, ts, want):
         == len(want)
     assert after["fold_ring_rows_total"] - before["fold_ring_rows_total"] \
         == sum(want)
+    # the int64 SUM folds limb by limb, and each step hands back the limb
+    # scatters its shards ran (ones: one live limb a ring row a shard
+    # holds a row for); the counter takes the busiest shard's
+    limbs = after["fold_limb_scatters_total"] \
+        - before["fold_limb_scatters_total"]
+    assert len(want) <= limbs <= sum(want)
     # a block over more than two ring rows went up sorted, and the mesh
     # operator opens no window/RingSort for it (it sorts in its Upload)
     assert after["fold_sorted_batches_total"] \

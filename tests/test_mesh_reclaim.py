@@ -62,7 +62,7 @@ def _seeded_state(capacity: int, seed: int):
     for lo, n, panes in ((0, n_keys, (0, 4)), (0, n_keys // 3, (4, 6))):
         for start in range(lo, lo + n - D * B + 1, D * B):
             price = rng.integers(1, 1 << 40, (D, B))
-            state, _n, _r = agg.step(
+            state, _n, _r, _limbs = agg.step(
                 state, jnp.asarray(keys[start:start + D * B].reshape(D, B)),
                 {"revenue": jnp.asarray(price), "best": jnp.asarray(price)},
                 jnp.asarray(rng.integers(*panes, (D, B))),

@@ -77,7 +77,7 @@ def test_sharded_step_matches_host(agg8):
         valid = rng.rand(D, B) < 0.9
         all_k.append(keys[valid]); all_v.append(vals[valid])
         all_p.append(panes[valid])
-        state, processed, _rounds = agg.step(
+        state, processed, _rounds, _limbs = agg.step(
             state, jnp.asarray(keys), {"price": jnp.asarray(vals)},
             jnp.asarray(panes), jnp.asarray(valid))
         assert int(processed) == int(valid.sum())
@@ -121,7 +121,7 @@ def test_fire_merges_panes_and_retire(agg8):
     vals = np.ones((D, B))
     for pane in (0, 1, 2):
         panes = np.full((D, B), pane, np.int64)
-        state, _, _ = agg.step(state, jnp.asarray(keys),
+        state, *_ = agg.step(state, jnp.asarray(keys),
                             {"price": jnp.asarray(vals)},
                             jnp.asarray(panes),
                             jnp.ones((D, B), bool))
@@ -144,7 +144,7 @@ def test_overflow_reports_dropped():
     D, B = 8, 64
     rng = np.random.RandomState(1)
     keys = rng.randint(0, 10**9, (D, B)).astype(np.int64)
-    state, processed, _rounds = agg.step(
+    state, processed, _rounds, _limbs = agg.step(
         state, jnp.asarray(keys), {"v": jnp.ones((D, B))},
         jnp.zeros((D, B), np.int64), jnp.ones((D, B), bool))
     dropped = int(jax.device_get(state.dropped).sum())
@@ -217,7 +217,7 @@ def test_step_and_retire_donate_the_state(agg8):
     _mesh, agg = agg8
     keys = np.arange(8 * 32, dtype=np.int64).reshape(8, 32)
     old = agg.init_state()
-    new, processed, rounds = _one_step(agg, old, keys)
+    new, processed, rounds, _limbs = _one_step(agg, old, keys)
     assert int(processed) == keys.size and int(rounds) >= 1
     for leaf in jax.tree.leaves(old):
         assert leaf.is_deleted()
@@ -227,7 +227,7 @@ def test_step_and_retire_donate_the_state(agg8):
     # stepped on (donated) and retired: programs already enqueued on the
     # old buffers stay valid, only Python handles on them do not
     out, emit = agg.fire(new, np.array([0], np.int32))
-    newer, _p, _r = _one_step(agg, new, keys)
+    newer, _p, _r, _limbs = _one_step(agg, new, keys)
     retired = agg.retire_row(newer, 0)
     assert all(leaf.is_deleted() for leaf in jax.tree.leaves(newer.accs))
     assert not retired.table.is_deleted()
@@ -243,10 +243,10 @@ def test_unranked_fire_hands_back_a_table_of_its_own(agg8):
     fire."""
     _mesh, agg = agg8
     keys = np.arange(8 * 16, dtype=np.int64).reshape(8, 16)
-    state, _p, _r = _one_step(agg, agg.init_state(), keys)
+    state, _p, _r, _limbs = _one_step(agg, agg.init_state(), keys)
     table, emit, _res, _dropped, _occ = agg.fire_compact(
         state, np.array([0], np.int32), np.array([True]), None, None)
-    state, _p, _r = _one_step(agg, state, keys)
+    state, _p, _r, _limbs = _one_step(agg, state, keys)
     got = np.asarray(jax.device_get(table))[np.asarray(
         jax.device_get(emit))]
     assert sorted(got.tolist()) == list(range(keys.size))
@@ -269,7 +269,7 @@ def test_skewed_batch_takes_more_rounds_and_loses_nothing(n_dev):
     mine = pool[(groups >= agg.shard_ranges[1].start)
                 & (groups <= agg.shard_ranges[1].end)][:97]
     skewed = np.resize(mine, (n_dev, B))
-    state, processed, rounds = _one_step(agg, agg.init_state(), skewed)
+    state, processed, rounds, _limbs = _one_step(agg, agg.init_state(), skewed)
     cap_x = bucket_capacity(B, n_dev)
     assert int(rounds) == -(-B // cap_x) > 1
     assert int(processed) == n_dev * B
@@ -283,7 +283,7 @@ def test_skewed_batch_takes_more_rounds_and_loses_nothing(n_dev):
     assert counts[emit].sum() == n_dev * B and not emit[0].any()
     # the same rows spread round-robin over their owners take one round
     spread = pool[:n_dev * B].reshape(n_dev, B)
-    state, processed, rounds = _one_step(agg, state, spread)
+    state, processed, rounds, _limbs = _one_step(agg, state, spread)
     assert int(rounds) == 1 and int(processed) == n_dev * B
 
 
@@ -544,7 +544,7 @@ def test_mesh_step_folds_a_block_like_a_per_record_fold(block, n_dev):
         _fold_per_record(folded, keys, panes, vals, valid)
         cols = {n: jnp.asarray(vals) for n, kind in _FOLD_KINDS.items()
                 if kind != "count"}
-        state, processed, rounds = agg.step(
+        state, processed, rounds, _limbs = agg.step(
             state, jnp.asarray(keys), cols, jnp.asarray(panes),
             jnp.asarray(valid))
         assert int(processed) == int(valid.sum())

@@ -298,7 +298,16 @@ def _host_born_fold(devices, query: str):
         tuple(_plane_spec(dt, shape, one) for _k, dt, shape in sig),
         spec((rows,), jnp.int32), spec((rows,), jnp.int64),
         spec((rows,), jnp.bool_),
-        (None, spec((rows,), jnp.int64))).compile())
+        (None, spec((rows,), jnp.int64)),
+        *_limb_count(sig, spec)).compile())
+
+
+def _limb_count(sig, spec) -> tuple:
+    """The backend's running count of limb scatters, which a fold program
+    with an additive int64 plane (Q5's SUM) takes and hands back."""
+    from flink_tpu.state.tpu_backend import _folds_by_limbs
+
+    return (spec((1,), jnp.int64),) if _folds_by_limbs(sig) else ()
 
 
 @pytest.mark.parametrize("query", list(_FOLD_SIGS))
@@ -338,8 +347,13 @@ def test_host_born_fold_compiles_without_a_plane_copy_at_the_benchmark_shape(
     planes = _plane_bytes(sig)
     assert mem.alias_size_in_bytes >= planes     # every plane is donated
     # beside the planes: the ring row of each plane that is out being
-    # folded, and nothing the size of a plane
-    assert mem.temp_size_in_bytes < 1.01 * planes // ring
+    # folded, and nothing the size of a plane. An additive int64 plane
+    # (Q5's SUM) folds limb by limb since PR 54: its row's two words, the
+    # zeroed 32-bit row a limb is scattered into, and one copy of a word
+    # the compiler keeps between two limbs' conditionals: four 32-bit
+    # rows, where the parent's one 64-bit row was two
+    row_bytes = 4 * 4 if sig[1][0] == "sum" else planes // (ring * cap)
+    assert mem.temp_size_in_bytes < 1.01 * row_bytes * cap
 
 
 def _reclaim(devices):
@@ -994,8 +1008,11 @@ def test_no_mesh_program_splits_or_joins_a_plane(v5e_devices, program):
 #: presence plane folded by a scatter-max, where it was an int64 count);
 #: the Q5 ones are still the text of 4081619.
 _ONE_CHIP_DIGESTS_AT_4081619 = {
+    # PR 54 MEANT to change it: the int64 SUM's rows go limb by limb into
+    # a zeroed 32-bit row and are carried into the plane's two words, and
+    # the backend's count of limb scatters rides through the program
     "jit_fold.q5":
-        "fb346eaa0dfce0cfbe1a75ae84dd78c96d885bbcab2fcd0c8c19b2ce129e5d59",
+        "20a23f2efbf5a0c9140949e51e810cc35ba391e77e6a6ef585a70c7d47107d2f",
     "jit_fold.q7":
         "63c4c2cc153cc81669f8f51e18ee57e3f8d324a2aff7c4f2453724725dc6ca40",
     "jit_fire_fn.q5":
@@ -1041,7 +1058,8 @@ def _one_chip_digest_programs(devices) -> dict:
     for q, sig in sigs.items():
         lowered[f"jit_fold.{q}"] = fn(_fold_program(sig)).lower(
             planes(sig), spec((rows,), jnp.int32), spec((rows,), jnp.int64),
-            spec((rows,), jnp.bool_), (None, spec((rows,), jnp.int64)))
+            spec((rows,), jnp.bool_), (None, spec((rows,), jnp.int64)),
+            *_limb_count(sig, spec))
     q5 = sigs["q5"]
     for q, agg_sig, names, k, bits, panes in (
             ("q5", (("count", "bids"), ("sum", "revenue")),
@@ -1421,12 +1439,12 @@ def test_q7_mesh_programs_compile_at_the_benchmark_shape(v5e_devices,
             "jit_step": _Q7_SHARD_BYTES - 4096}[program]
 
 
-def _count_scatters(hlo: str) -> list:
-    """The result types of the scatters that lie under ``fold.count``."""
+def _fold_scatters(hlo: str, kind: str) -> list:
+    """The result types of the scatters that lie under ``fold.<kind>``."""
     import re
 
     return [m.group(1) for line in hlo.splitlines()
-            if "fold.scatter/fold.count" in line
+            if f"fold.scatter/fold.{kind}/" in line
             for m in [re.search(r"= (\(.*?\)|\S+) scatter\(", line)] if m]
 
 
@@ -1445,7 +1463,7 @@ def test_the_presence_plane_folds_as_one_32_bit_scatter(v5e_devices,
                 else _q7_mesh_program(v5e_devices, "jit_step"))
     hlo = compiled.as_text()
     rows = 1 << (24 if program == "jit_fold.q7" else 23)
-    scatters = _count_scatters(hlo)
+    scatters = _fold_scatters(hlo, "count")
     assert scatters and all(t.startswith(f"s32[{rows}]{{")
                             for t in scatters), scatters
     # the folds' two-word scatters (the mesh step has others: the probe's
@@ -1454,6 +1472,57 @@ def test_the_presence_plane_folds_as_one_32_bit_scatter(v5e_devices,
             if "/fold.scatter/" in line and re.search(
                 r"= \(u32\[\d+\]\S*, u32\[\d+\]\S*\) scatter\(", line)]
     assert wide and all("fold.scatter/fold.max" in line for line in wide)
+
+
+@pytest.mark.parametrize("program", ["jit_fold.q5", "jit_step.q5_mesh"])
+def test_an_additive_int64_plane_folds_with_32_bit_scatters(v5e_devices,
+                                                           program):
+    """X's fold and M's step at the cells' shapes (PR 54): a SUM's and a
+    COUNT's rows go into a 64-bit plane limb by limb, so every scatter
+    under `fold.sum` and `fold.count` is over ONE 32-bit row (`u32`: a
+    limb's zeroed delta row; `s32`: X's declared int32 COUNT) and no
+    two-word (variadic `u32`, `u32`) scatter of an `s64` row lies under
+    either: a 64-bit update costs the TPU six times a 32-bit one. The
+    limbs' scatters lie beneath `fold.limb` and the dense add into the
+    row's two words beneath `fold.carry`, both inside the kind's region.
+    Q's fold and Z's step keep the MAX's own two-word scatter under
+    `fold.max` (`test_the_presence_plane_folds_as_one_32_bit_scatter`)."""
+    import re
+
+    if program == "jit_fold.q5":
+        hlo, rows = _host_born_fold(v5e_devices, "q5").as_text(), 1 << 24
+    else:
+        hlo, rows = _mesh_step(v5e_devices)[1].as_text(), 1 << 23
+    for kind in ("sum", "count"):
+        scatters = _fold_scatters(hlo, kind)
+        assert scatters and all(
+            re.match(rf"[us]32\[{rows}\]\{{", t) for t in scatters), \
+            (kind, scatters)
+    wide = [line for line in hlo.splitlines()
+            if "/fold.scatter/" in line and re.search(
+                r"= \(u32\[\d+\]\S*, u32\[\d+\]\S*\) scatter\(", line)]
+    assert not wide, wide[0][:300]
+    assert re.search(
+        r"fold\.scatter/fold\.sum/[^\"]*fold\.limb/[^\"]*scatter", hlo)
+    assert re.search(r"fold\.scatter/fold\.sum/[^\"]*fold\.carry/", hlo)
+    assert bool(re.search(r"fold\.scatter/fold\.count/fold\.carry/", hlo)) \
+        == (program == "jit_step.q5_mesh")
+
+
+def test_the_q7_mesh_step_lowers_to_what_it_lowered_to(v5e_devices):
+    """Z's step holds a MAX beside a 32-bit presence plane, neither of
+    them additive 64-bit: PR 54, which folds such planes limb by limb and
+    hands M's and F's step a fourth result (the shards' limb scatters),
+    leaves this one the parent's text, letter for letter (sha256 of
+    `lower().as_text()` at commit 2997035), so it loads the parent's
+    executable: the proof that Z is that PR's control."""
+    import hashlib
+
+    agg, _sharded, args = _q7_mesh(v5e_devices)
+    text = agg.step_program().lower(
+        *args, agg._base_start, agg._base_len).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        "3e261f8eed0c3e96a07063da9edc0f88ce3d51be8e02f40bce1544841535d561"
 
 
 #: sha256 of `lower().as_text()` of the ranked mesh fire of M and F
